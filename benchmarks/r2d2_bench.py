@@ -40,8 +40,7 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from timing import marginal_time  # noqa: E402
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def make_items(rng, n, T, obs_dim, core_size):
@@ -187,9 +186,9 @@ def main(argv=None):
         ReplayClient,
         ReplayServer,
     )
-    from moolib_tpu.utils import apply_platform_env
+    from moolib_tpu.utils import init_compile_cache
 
-    apply_platform_env()
+    init_compile_cache()
     if jax.default_backend() == "cpu" and os.environ.get("MOOLIB_ALLOW_CPU") != "1":
         raise SystemExit(
             "r2d2_bench needs an accelerator backend "
@@ -297,14 +296,14 @@ def main(argv=None):
 
         def run(iters):
             t0 = time.perf_counter()
-            loss = None
             for _ in range(iters):
                 loss = step()
-            float(loss)  # force the chain with a scalar fetch
-            return time.perf_counter() - t0
+            jax.block_until_ready(loss)
+            return (time.perf_counter() - t0) / iters
 
         try:
-            sec = marginal_time(run, 4, 12)
+            run(2)  # compile + warm
+            sec = run(8)
         finally:
             for r in rpcs:
                 r.close()

@@ -24,8 +24,8 @@ peers train against one broker while a killer SIGKILLs a random peer every
 - **consistency**: at the end, every surviving peer's model version is
   within the window of the cohort max (stragglers mid-resync allowed).
 
-Restarted peers share a persistent XLA compile cache
-(``MOOLIB_COMPILE_CACHE``) so a restart pays model re-sync, not
+Restarted peers share the persistent XLA compile cache
+(``utils/compile_cache.py``) so a restart pays model re-sync, not
 recompilation — the seconds-scale recovery the reference's model
 redistribution promises (``src/accumulator.cc:464-488``).
 
@@ -69,12 +69,11 @@ def _spawn_worker(i: int, addr: str, outdir: str, args) -> subprocess.Popen:
         os.environ,
         PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
         JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
-        # Shared persistent compile cache (utils.init_compile_cache inside
-        # the example applies it): peer 0 compiles, the other N-1 cold
-        # starts and every kill/restart reload from disk — the restart
-        # recovery budget pays model re-sync, not recompilation.
-        MOOLIB_COMPILE_CACHE=os.path.join(outdir, "jax_cache"),
     )
+    # The persistent compile cache (utils.init_compile_cache inside the
+    # example) is shared by every peer: peer 0 compiles, the other N-1 cold
+    # starts and every kill/restart reload from disk — the restart recovery
+    # budget pays model re-sync, not recompilation.
     localdir = os.path.join(outdir, f"p{i}")
     os.makedirs(localdir, exist_ok=True)
     log = open(os.path.join(outdir, f"p{i}.log"), "a")

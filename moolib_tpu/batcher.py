@@ -111,7 +111,10 @@ def _resolve_device(device):
             kind = "tpu"
         devs = [d for d in jax.devices() if d.platform.startswith(kind)]
         if not devs:
-            devs = jax.devices()
+            raise ValueError(
+                f"device {device!r} requested but jax has no {kind!r} device "
+                f"(have {sorted({d.platform for d in jax.devices()})})"
+            )
         return devs[int(idx) if idx else 0]
     return device  # jax.Device or Sharding
 

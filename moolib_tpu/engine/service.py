@@ -140,9 +140,12 @@ class EngineService(ServeService):
                     self._queue.insert(0, req)
                     _M_DEPTH.inc()
                 return joined, answered
-            except Exception as e:  # noqa: BLE001 — a poisoned request
+            except ValueError as e:
+                # A request the engine refuses (oversized prompt or budget)
+                # fails alone; a device or compile failure is not caught and
+                # takes the replica down.
                 self._count_answered(1)
-                self._respond(req, None, f"generate failed: {e}")  # fails alone
+                self._respond(req, None, f"generate failed: {e}")
                 answered += 1
                 continue
             _M_PHASE.observe(time.monotonic() - t0, phase="prefill")

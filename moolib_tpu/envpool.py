@@ -98,14 +98,13 @@ def _jax_backend_initialized() -> bool:
     without importing or initializing jax."""
     if sys.modules.get("jax") is None:
         return False
-    try:
-        from jax._src import distributed, xla_bridge
+    # Private state, both present in the jax this repo is written for; a jax
+    # that moves them must fail here, not quietly change the start method.
+    from jax._src import distributed, xla_bridge
 
-        # jax.distributed.initialize() starts gRPC/heartbeat threads before
-        # any backend client exists — forking is already unsafe then.
-        return bool(xla_bridge._backends) or distributed.global_state.client is not None
-    except Exception:  # noqa: BLE001 — private API; fail toward the safe path
-        return True
+    # jax.distributed.initialize() starts gRPC/heartbeat threads before
+    # any backend client exists — forking is already unsafe then.
+    return bool(xla_bridge._backends) or distributed.global_state.client is not None
 
 _FIELD_RESERVED = ("reward", "done")
 _SHUTDOWN = -1
@@ -430,8 +429,21 @@ def _maybe_init_worker_compile_cache() -> None:
         utils.init_compile_cache()
 
 
+def _pin_worker_to_cpu() -> None:
+    """An accelerator belongs to one process — the pool's parent.  A worker
+    whose env uses jax comes up on the CPU platform and never asks for the
+    chip: the environment covers a jax imported later (forkserver workers,
+    envs that import it lazily), the config update a jax module inherited
+    through fork whose backend is not up yet."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+
+
 def _worker_main(create_env, worker_index, lo, hi, num_batches, conn, doorbells,
                  discover=False):
+    _pin_worker_to_cpu()
     _maybe_init_worker_compile_cache()
     task_queue, done_sems, seg = _attach_doorbells(doorbells, worker_index)
     runner = EnvRunner(

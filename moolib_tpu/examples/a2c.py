@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from .. import Accumulator, Broker, EnvPool, telemetry
+from .. import Accumulator, Broker, EnvPool, telemetry, utils
 from ..envs import CartPoleEnv
 from ..models import ActorCriticNet
 from ..ops import discounted_returns, entropy_loss, softmax_cross_entropy
@@ -76,21 +76,14 @@ def make_flags(argv=None):
                    help="deadman seconds per loop section (0 = off); expiry "
                    "dumps telemetry + thread stacks and raises "
                    "WatchdogTimeout (docs/RESILIENCE.md)")
-    p.add_argument("--compile_cache_dir", default=None,
-                   help="persistent XLA compile cache directory (also "
-                   "MOOLIB_COMPILE_CACHE): restarts skip recompilation "
-                   "(docs/RESILIENCE.md recovery budget)")
     return finalize_flags(p, argv)
 
 
 def train(flags, on_stats=None) -> dict:
     """Full training loop; returns final stats (for the integration test)."""
-    from ..utils import apply_platform_env, init_compile_cache
-
-    apply_platform_env()
     # Before the first jit: restarts skip recompilation via the persistent
-    # cache (--compile_cache_dir / MOOLIB_COMPILE_CACHE; no-op when unset).
-    init_compile_cache(flags.compile_cache_dir)
+    # cache (utils/compile_cache.py).
+    utils.init_compile_cache()
     # Opt-in exporters (MOOLIB_TELEMETRY_* env knobs, docs/TELEMETRY.md).
     telemetry.init_from_env()
     # kill -USR2 toggles an on-demand jax.profiler device-trace window.

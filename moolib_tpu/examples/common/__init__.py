@@ -4,6 +4,7 @@ TSV logging."""
 
 from __future__ import annotations
 
+import json
 import os
 import threading
 import time
@@ -57,6 +58,55 @@ def finalize_flags(parser, argv=None):
     for ov in kv_overrides:
         cfg.apply_override(ov)
     return cfg
+
+
+def placement_of(tree) -> dict:
+    """Where ``tree``'s arrays really live: the sorted platforms of their
+    devices (host numpy leaves count as ``"host"``) and the least number of
+    distinct devices holding addressable shards of any one jax array."""
+    platforms, per_leaf = set(), []
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            devs = {s.device for s in leaf.addressable_shards}
+            platforms.update(d.platform for d in devs)
+            per_leaf.append(len(devs))
+        else:
+            platforms.add("host")
+    return {"platforms": sorted(platforms),
+            "devices_per_array": min(per_leaf, default=0)}
+
+
+def run_report(result: dict, started: float) -> dict:
+    """What a run of an example was made of, as one JSON-ready dict: the
+    device jax computed on, wall and compile seconds, persistent compile
+    cache traffic, which native components were built, device memory, and
+    the example's own ``result``.  ``started`` is a ``time.monotonic()``
+    stamp from the top of ``main``.  Entry points print it through
+    :func:`print_report` so a harness reads facts, not log lines."""
+    from ... import native, telemetry
+
+    devices = jax.devices()
+    return {
+        "device": {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        },
+        "wall_s": round(time.monotonic() - started, 3),
+        **telemetry.devmon.compile_summary(),
+        "cache_dir": jax.config.jax_compilation_cache_dir,
+        "native": native.status(),
+        "memory": telemetry.devmon.sample_memory(),
+        "result": result,
+    }
+
+
+REPORT_PREFIX = "RUN_REPORT "
+
+
+def print_report(result: dict, started: float) -> None:
+    print(REPORT_PREFIX + json.dumps(run_report(result, started), default=str),
+          flush=True)
 
 
 class GlobalStatsAccumulator:

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from .. import EnvPool
+from .. import EnvPool, utils
 from ..envs import CartPoleEnv
 from ..models.qnet import RecurrentQNet
 from ..replay import ReplayBuffer, ReplayClient, ReplayServer
@@ -99,9 +99,7 @@ def td_loss(params, target_params, model, batch, discounting):
 
 
 def train(flags, on_stats=None) -> dict:
-    from ..utils import apply_platform_env
-
-    apply_platform_env()
+    utils.init_compile_cache()  # before the first jit (utils/compile_cache.py)
     envs = EnvPool(
         partial(CartPoleEnv, max_episode_steps=200),
         num_processes=flags.num_processes,

@@ -162,11 +162,6 @@ def make_flags(argv=None):
         help="log stats to wandb when the package is installed (gated no-op "
         "otherwise — reference experiment.py:269-276 opt-in)",
     )
-    p.add_argument("--compile_cache_dir", default=None,
-                   help="persistent XLA compile cache directory (also "
-                   "MOOLIB_COMPILE_CACHE): a restarted peer skips "
-                   "recompilation — the dominant cold-restart cost the "
-                   "soak's recovery SLO budgets (docs/RESILIENCE.md)")
     p.add_argument(
         "--device_rollout",
         type=_bool_flag,
@@ -399,12 +394,9 @@ def load_checkpoint(path, target=None):
 
 
 def train(flags, on_stats=None) -> dict:
-    from ...utils import apply_platform_env, init_compile_cache
-
-    apply_platform_env()
     # Before the first jit: restarts must hit the persistent compile cache
-    # (--compile_cache_dir / MOOLIB_COMPILE_CACHE; no-op when neither set).
-    init_compile_cache(flags.compile_cache_dir)
+    # (utils/compile_cache.py).
+    utils.init_compile_cache()
     # Opt-in exporters (MOOLIB_TELEMETRY_* env knobs, docs/TELEMETRY.md):
     # Prometheus /metrics endpoint, JSONL snapshots, SIGUSR1 dumps.
     tele = telemetry.init_from_env()
@@ -854,11 +846,16 @@ def train(flags, on_stats=None) -> dict:
     # device_get per stats/log tick — the per-SGD-step float(loss) sync they
     # replace stalled the learner stream on every step.
     pending_learn_stats: list = []
+    # For the returned summary: the newest learner loss, and where the
+    # learner's first batch was found (on the device path the batch IS the
+    # rollout buffer).
+    learn_seen = {"loss": None, "batch_placement": None}
 
     def _flush_learn_stats() -> None:
         if not pending_learn_stats:
             return
         for loss_v, pg_v, ent_v in jax.device_get(pending_learn_stats):
+            learn_seen["loss"] = float(loss_v)
             stats["loss"] += float(loss_v)
             stats["pg_loss"] += float(pg_v)
             stats["entropy_loss"] += float(ent_v)
@@ -982,6 +979,8 @@ def train(flags, on_stats=None) -> dict:
                             )
                         )
                     (loss, aux), grads = grad_fn(params, batch, initial_core)
+                    if learn_seen["batch_placement"] is None:
+                        learn_seen["batch_placement"] = common.placement_of(batch)
                     if "cost" not in devmon_cost:
                         # One lower() per geometry; cached per-signature in
                         # devmon so shape churn doesn't re-lower every step.
@@ -1296,11 +1295,15 @@ def train(flags, on_stats=None) -> dict:
         "sps": final_steps / max(time.time() - start, 1e-6),
         "steady_sps": None if steady is None else round(steady, 1),
         "mfu": devmon_cost.get("mfu"),
+        "loss": learn_seen["loss"],
+        "param_placement": common.placement_of(params),
+        "batch_placement": learn_seen["batch_placement"],
     }
 
 
 def main(argv=None):
-    train(make_flags(argv))
+    started = time.monotonic()
+    common.print_report(train(make_flags(argv)), started)
 
 
 if __name__ == "__main__":

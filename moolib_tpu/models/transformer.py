@@ -5,7 +5,8 @@ Long-context model surface for the framework's SP capability (the reference
 has no attention models at all, SURVEY.md §5.7). Attention selection:
 
 - ``attention="dense"`` — XLA dense (small T, debugging);
-- ``attention="flash"`` — pallas blockwise kernel, single chip;
+- ``attention="flash"`` — pallas blockwise kernel; per chip, batch-split
+  over ``dp`` when a mesh is passed at apply time;
 - ``attention="ring"`` — ring attention over the ``sp`` axis of a mesh
   passed at apply time (``model.apply(params, tokens, mesh=mesh)``), for
   sequences longer than one chip's HBM.
@@ -206,7 +207,7 @@ class Block(nn.Module):
             elif self.attention == "flash":
                 from ..ops.flash_attention import flash_attention
 
-                att = flash_attention(q, k, v, causal=True)
+                att = flash_attention(q, k, v, causal=True, mesh=mesh)
             else:
                 from ..parallel.ring_attention import full_attention
 

@@ -51,7 +51,7 @@ def _source_hash(path: str) -> str:
 def _build(src: str, out: str, extra_flags=()) -> bool:
     # MOOLIB_TPU_SANITIZE=thread|address builds every native component with
     # the given sanitizer (run python under the matching LD_PRELOAD runtime;
-    # see tests/test_native_sanitizers.py and docs/STATUS.md for the recipe).
+    # see tests/test_native_sanitizers.py for the recipe).
     san = os.environ.get("MOOLIB_TPU_SANITIZE")
     san_flags = (f"-fsanitize={san}",) if san else ()
     cmd = [
@@ -191,6 +191,19 @@ def get_shmq():
             return None
         _shmq = _load_shmq()
     return _shmq
+
+
+def status() -> dict:
+    """Which native components this process runs on (True = built and
+    loaded, False = the asyncio / pickle / multiprocessing fallback).
+    Builds on first use, like every consumer."""
+    from . import transport
+
+    return {
+        "transport": transport.get_lib() is not None,
+        "codec": get_codec() is not None,
+        "shm": get_shmq() is not None,
+    }
 
 
 class NativeSemaphore:

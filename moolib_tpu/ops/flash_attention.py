@@ -11,8 +11,10 @@ output block revisits and the scratch accumulators carry across iterations
 The reference framework has no attention at all (SURVEY.md §5.7) — this is
 new TPU-idiomatic capability for the long-context side of the framework.
 
-Layout [B, T, H, D]; falls back to the XLA dense path for shapes that don't
-tile (T not divisible by the block size, tiny D).
+Layout [B, T, H, D]; shapes that don't tile (T without a 128-multiple
+divisor) take the XLA dense path, counted in ``flash_dense_reroutes_total``.
+The kernels lower through Mosaic on the ``tpu`` platform and run in Pallas
+interpret mode on ``cpu`` (tests); any other platform raises.
 """
 
 from __future__ import annotations
@@ -25,7 +27,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry, utils
+
 _NEG_INF = -1e30
+
+_M_DENSE_REROUTES = telemetry.get_registry().counter(
+    "flash_dense_reroutes_total",
+    "flash_attention calls traced onto the O(T^2) dense path because the "
+    "sequence length has no 128-multiple block divisor",
+)
 
 
 def _largest_divisor(t: int, cap: int) -> int:
@@ -40,15 +50,8 @@ def _out_struct(shape, dtype, *operands):
     """ShapeDtypeStruct for a pallas output, carrying the union of the
     operands' varying-mesh-axes (vma) so the kernel works inside shard_map
     (ring attention calls it per chunk) as well as at top level."""
-    vma = frozenset()
-    for x in operands:
-        v = getattr(jax.typeof(x), "vma", None)
-        if v:
-            vma |= v
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # older jax without vma support
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _flash_kernel(
@@ -455,8 +458,16 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool | None = None,
     return_lse: bool = False,
+    mesh=None,
 ):
     """Blockwise attention; q/k/v: [B, T, H, D] → [B, T, H, D].
+
+    ``mesh``: pass the mesh when calling from a program XLA partitions over
+    one (a jitted step with sharded inputs).  XLA cannot partition a Mosaic
+    kernel, so the call is wrapped in ``shard_map``: the batch splits over
+    the mesh's ``dp`` axis and the heads over ``tp`` where those axes exist
+    and divide, every other axis computes replicated.  Inside a ``shard_map``
+    of your own (ring attention, pipeline stages) leave it None.
 
     Differentiable: the forward runs the pallas kernel (also emitting the
     row logsumexp); the backward runs two pallas kernels — a dq pass and a
@@ -470,6 +481,23 @@ def flash_attention(
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        def axis(name, dim):
+            return name if name in mesh.axis_names and dim % mesh.shape[name] == 0 else None
+
+        spec = P(axis("dp", B), None, axis("tp", H), None)
+        lse_spec = P(spec[0], None, spec[2])
+        return jax.shard_map(
+            functools.partial(
+                flash_attention, causal=causal, block_q=block_q, block_k=block_k,
+                interpret=interpret, return_lse=return_lse,
+            ),
+            mesh=mesh,
+            in_specs=(spec, spec, spec),
+            out_specs=(spec, lse_spec) if return_lse else spec,
+        )(q, k, v)
     # Defaults from a block sweep on TPU v5e (T=4096, causal): 128x128 blocks
     # leave grid overhead dominant (32k tiny steps, 7.7 ms); 512x1024 runs the
     # same shape in 1.8 ms while q+k+v+s blocks stay well under VMEM.  Use the
@@ -485,9 +513,9 @@ def flash_attention(
         block_k = _largest_divisor(Tk, 1024)
     # Blocks below the 128-lane tile (T with a large odd factor) aren't worth
     # a pallas launch — use the dense path.  An unusable *caller-supplied*
-    # block raises instead (the caller tuning blocks gets a signal, not a
-    # silent O(T²) reroute); an unusable auto-selected one keeps the
-    # documented silent fallback.
+    # block raises instead (the caller tuning blocks gets a signal, not an
+    # O(T²) reroute); an unusable auto-selected one takes the dense path,
+    # counted and logged so a run can assert it did not happen.
     bad_q = block_q < 128 or block_q % 128 or Tq % block_q
     bad_k = block_k < 128 or block_k % 128 or Tk % block_k
     if (bad_q and explicit_q) or (bad_k and explicit_k):
@@ -499,11 +527,22 @@ def flash_attention(
     if bad_q or bad_k:
         from ..parallel.ring_attention import dense_attention_lse, full_attention
 
+        _M_DENSE_REROUTES.inc()
+        utils.log_info(
+            "flash_attention: Tq=%d Tk=%d does not tile into 128-multiple "
+            "blocks; using dense attention", Tq, Tk,
+        )
         if return_lse:
             return dense_attention_lse(q, k, v, causal=causal)
         return full_attention(q, k, v, causal=causal)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"flash_attention has a Mosaic (tpu) lowering and a cpu "
+                f"interpret mode for tests; platform {backend!r} has neither"
+            )
+        interpret = backend == "cpu"
     if return_lse:
         return _flash_lse(q, k, v, causal, block_q, block_k, interpret)
     return _flash(q, k, v, causal, block_q, block_k, interpret)

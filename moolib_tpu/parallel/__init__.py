@@ -20,12 +20,9 @@ from .mesh import (  # noqa: F401
 )
 from .collectives import (  # noqa: F401
     all_gather_axis,
-    axis_size,
-    pcast,
     redistribute,
     reduce_scatter_axis,
     ring_permute,
-    shard_map,
     tree_pmean,
     tree_psum,
 )

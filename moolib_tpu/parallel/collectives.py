@@ -10,7 +10,6 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 
 from .. import telemetry
 
@@ -24,48 +23,6 @@ _M_PSUM = telemetry.get_registry().histogram(
 )
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at top
-    level with a ``check_vma`` kwarg; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map``, where the same switch (disable
-    the replication/varying-mesh-axes checker) is spelled ``check_rep``.
-    Every explicit shard_map region in the framework goes through this shim.
-    """
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        return impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as legacy_impl
-
-    return legacy_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-def axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` across versions: on 0.4.x fall back to
-    ``psum(1, axis)``, which constant-folds to a concrete int at trace time
-    (the classic idiom), so it stays usable in ``range()``/``fori_loop``
-    bounds."""
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:
-        return impl(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def pcast(x, axes, to: str = "varying"):
-    """``jax.lax.pcast`` across versions: 0.4.x has no varying-mesh-axes
-    types at all (shard_map's ``check_rep`` tracks replication separately),
-    so there is nothing to cast — identity."""
-    impl = getattr(jax.lax, "pcast", None)
-    if impl is None:
-        return x
-    return impl(x, axes, to=to)
-
-
 def tree_psum(tree: Any, axis_name: str) -> Any:
     return jax.tree_util.tree_map(lambda x: jax.lax.psum(x, axis_name), tree)
 
@@ -76,7 +33,7 @@ def tree_pmean(tree: Any, axis_name: str) -> Any:
 
 def ring_permute(x: jax.Array, axis_name: str, shift: int = 1) -> jax.Array:
     """Send ``x`` to the next device on the ring (ICI neighbour)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name, perm)
 

@@ -41,12 +41,9 @@ def initialize_distributed(
     initializes (this was the round-1 "cross-process CPU collectives hang":
     XLA:CPU defaults to no cross-process implementation at all).
     """
-    try:
-        platforms = jax.config.jax_platforms or ""
-        if "cpu" in platforms or platforms == "":
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — older jax without the option
-        pass
+    platforms = jax.config.jax_platforms or ""
+    if "cpu" in platforms or platforms == "":
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import collectives
-
 
 def pipeline_apply(
     stage_fn: Callable,
@@ -103,8 +101,8 @@ def pipeline_apply(
         # updates depend on axis_index, so the carry type must match) — and
         # over the data axis too when microbatches are sharded across it.
         carry_axes = (axis_name,) if data_axis is None else (axis_name, data_axis)
-        carry = collectives.pcast(jnp.zeros(act_shape, xs.dtype), carry_axes, to="varying")
-        outs = collectives.pcast(jnp.zeros_like(xs), axis_name, to="varying")
+        carry = jax.lax.pcast(jnp.zeros(act_shape, xs.dtype), carry_axes, to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), axis_name, to="varying")
 
         def tick(state, i):
             carry, outs = state
@@ -148,7 +146,7 @@ def pipeline_apply(
 
     param_specs = jax.tree_util.tree_map(lambda _: P(None, axis_name), grouped)
     xs_spec = P(None, data_axis) if data_axis is not None else P()
-    fn_mapped = collectives.shard_map(
+    fn_mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, xs_spec),

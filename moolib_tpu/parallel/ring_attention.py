@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import collectives
 
 _NEG_INF = -1e30
 
@@ -100,7 +99,7 @@ def ring_attention_sharded(
     """
     from ..ops.flash_attention import flash_attention
 
-    n = collectives.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
 
@@ -108,9 +107,9 @@ def ring_attention_sharded(
     # (the ring axis, plus the batch axis when B is sharded too) so the
     # fori_loop carry type matches after the updates inside.
     axes = (axis_name,) + ((batch_axis,) if batch_axis else ())
-    acc = collectives.pcast(jnp.zeros((B, Tq, H, D), jnp.float32), axes, to="varying")
-    s = collectives.pcast(jnp.zeros((B, Tq, H), jnp.float32), axes, to="varying")
-    mx = collectives.pcast(jnp.full((B, Tq, H), _NEG_INF, jnp.float32), axes, to="varying")
+    acc = jax.lax.pcast(jnp.zeros((B, Tq, H, D), jnp.float32), axes, to="varying")
+    s = jax.lax.pcast(jnp.zeros((B, Tq, H), jnp.float32), axes, to="varying")
+    mx = jax.lax.pcast(jnp.full((B, Tq, H), _NEG_INF, jnp.float32), axes, to="varying")
 
     def attend(k_c, v_c, causal_flag):
         # flash_attention owns the pallas-vs-dense fallback decision.
@@ -130,10 +129,10 @@ def ring_attention_sharded(
                     lambda kv: attend(kv[0], kv[1], False),  # past
                     lambda kv: attend(kv[0], kv[1], True),  # diagonal
                     lambda kv: (  # future: zero weight (varying like the rest)
-                        collectives.pcast(
+                        jax.lax.pcast(
                             jnp.zeros((B, Tq, H, D), q.dtype), axes, to="varying"
                         ),
-                        collectives.pcast(
+                        jax.lax.pcast(
                             jnp.full((B, Tq, H), _NEG_INF, jnp.float32),
                             axes,
                             to="varying",
@@ -185,7 +184,7 @@ def ring_attention(
     # fori_loop yet — jax's own suggested workaround.  The pcasts in the
     # sharded body keep the carries consistent when checking IS on (e.g. a
     # future jax default flip).
-    fn = collectives.shard_map(
+    fn = jax.shard_map(
         partial(
             ring_attention_sharded,
             axis_name=axis_name,

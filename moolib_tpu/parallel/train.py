@@ -358,7 +358,7 @@ def make_train_step(
             if "fn" not in compiled:
                 # Persistent compile cache so a multi-host restart replays
                 # the pjit'd step from disk instead of recompiling
-                # (utils/compile_cache.py; no-op unless configured).
+                # (utils/compile_cache.py).
                 init_compile_cache()
                 compiled["fn"] = jax.jit(
                     grad_step,

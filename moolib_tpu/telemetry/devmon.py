@@ -37,9 +37,11 @@ deferred into the functions that need them.
 from __future__ import annotations
 
 import os
+import re
 import sys
 import threading
 import time
+from collections import Counter
 from typing import Any, Dict, List, Optional, Tuple
 
 from . import metrics
@@ -47,6 +49,7 @@ from .flightrec import flight_event
 
 __all__ = [
     "StepCost",
+    "compile_summary",
     "dispatch_span",
     "install_compile_listeners",
     "install_from_env",
@@ -119,8 +122,10 @@ _M_STEP_BYTES = _REG.gauge(
 
 # Peak dense (bf16) FLOP/s and HBM bandwidth per chip, from public spec
 # sheets.  Substring-matched against ``device.device_kind`` — order matters
-# ("v5p" and "v5 lite" before "v5").  These tables are the canonical home;
-# impala_roofline.py and the benchmarks consume them from here.
+# ("v5p" and "v5 lite" before "v5").  These tables are the one home for the
+# numbers; bench.py, impala_roofline.py and the benchmarks read them here.
+# The CPU backend has no peak (MFU is None there); any other kind missing
+# from the tables is an error, never a default.
 _PEAK_FLOPS: List[Tuple[str, float]] = [
     ("v6e", 918e12),
     ("v6 lite", 918e12),
@@ -145,11 +150,6 @@ _PEAK_BW: List[Tuple[str, float]] = [
     ("v3", 900e9),
     ("v2", 700e9),
 ]
-# Unknown device kinds (the CPU backend above all) get a *nominal* peak so
-# step_mfu stays finite and tracks relative regressions; the absolute value
-# is meaningless there and publish_step says so via ``peak_source``.
-NOMINAL_PEAK_FLOPS = 1e12
-NOMINAL_PEAK_BW = 100e9
 
 _lock = threading.RLock()
 # fn name -> {"seen": set, "last": sig, "compiles": int, "recompiles": int,
@@ -196,6 +196,19 @@ def install_compile_listeners() -> bool:
             return False
         _listeners_installed = True
         return True
+
+
+def compile_summary() -> Dict[str, float]:
+    """What the compile listeners have counted in this process: seconds
+    spent compiling or fetching compiled programs, how many programs, and
+    the persistent cache's hits and misses (a miss is an entry written)."""
+    hist = _M_COMPILE_SECONDS.labels().get()
+    return {
+        "compile_s": hist["sum"],
+        "compiles": hist["count"],
+        "cache_hits": _M_CACHE_HITS.labels().get(),
+        "cache_misses": _M_CACHE_MISSES.labels().get(),
+    }
 
 
 def _leaf_sig(x) -> str:
@@ -461,52 +474,75 @@ def sample_memory() -> Dict[str, Dict[str, float]]:
 
 
 # --------------------------------------------------------------- step cost/MFU
+# Opcode position only (``... all-reduce(`` / ``all-reduce-start(``): names
+# and ``-done`` halves of async pairs must not count twice.
+_COLLECTIVE_RE = re.compile(
+    r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+    r"(?:-start)?\("
+)
+
+
 class StepCost:
-    """XLA-counted cost of one step: flops + bytes accessed."""
+    """What XLA compiled for one step: counted flops and bytes accessed,
+    the program's device memory (arguments + outputs + temporaries, minus
+    aliased bytes, from ``memory_analysis()``), the number of Mosaic kernel
+    call sites (``tpu_custom_call``) and the collectives by kind."""
 
-    __slots__ = ("flops", "bytes_accessed")
+    __slots__ = ("flops", "bytes_accessed", "memory_bytes", "kernels",
+                 "collectives")
 
-    def __init__(self, flops: float, bytes_accessed: float):
+    def __init__(self, flops: float, bytes_accessed: float,
+                 memory_bytes: Optional[int] = None, kernels: int = 0,
+                 collectives: Optional[Dict[str, int]] = None):
         self.flops = float(flops)
         self.bytes_accessed = float(bytes_accessed)
+        self.memory_bytes = memory_bytes
+        self.kernels = kernels
+        self.collectives = collectives or {}
 
     @property
     def arithmetic_intensity(self) -> Optional[float]:
         return self.flops / self.bytes_accessed if self.bytes_accessed else None
+
+    def program(self) -> Dict[str, Any]:
+        """The compiled program's facts as a JSON-ready dict."""
+        return {"memory_bytes": self.memory_bytes, "mosaic_kernels": self.kernels,
+                "collectives": dict(self.collectives)}
 
     def __repr__(self):
         return f"StepCost(flops={self.flops:.3g}, bytes_accessed={self.bytes_accessed:.3g})"
 
 
 def step_cost(name: str, jitted, *args, **kwargs) -> Optional["StepCost"]:
-    """XLA cost analysis of ``jitted(*args, **kwargs)``, cached per abstract
+    """XLA's account of ``jitted(*args, **kwargs)``, cached per abstract
     signature (lowering is pure: donated buffers are NOT consumed).  When
-    the step already compiled with these avals the ``.compile()`` here is a
-    jit-cache hit, so calling this after the first real step is cheap.
-    Returns None when the backend offers no usable analysis."""
-    try:
-        sig = (name, _signature(args, kwargs))
-    except Exception:  # noqa: BLE001 — unflattenable args: no analysis
-        return None
+    the step already compiled with these avals the ``.compile()`` here hits
+    the persistent compile cache, so calling this after the first real step
+    is cheap.  Returns None when the backend counts no flops; a step that
+    does not compile raises, as the step itself would."""
+    sig = (name, _signature(args, kwargs))
     with _lock:
         if sig in _COST_CACHE:
             return _COST_CACHE[sig]
+    compiled = jitted.lower(*args, **kwargs).compile()
+    analysis = compiled.cost_analysis()
+    if isinstance(analysis, (list, tuple)):
+        analysis = analysis[0] if analysis else {}
     cost = None
-    try:
-        lowered = jitted.lower(*args, **kwargs)
-        try:
-            analysis = lowered.compile().cost_analysis()
-        except Exception:  # noqa: BLE001 — fall back to unoptimized analysis
-            analysis = lowered.cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
-        if analysis:
-            flops = float(analysis.get("flops", 0.0))
-            byts = float(analysis.get("bytes accessed", 0.0))
-            if flops > 0:
-                cost = StepCost(flops, byts)
-    except Exception:  # noqa: BLE001 — cost analysis is best-effort
-        cost = None
+    flops = float((analysis or {}).get("flops", 0.0))
+    if flops > 0:
+        mem = compiled.memory_analysis()
+        text = compiled.as_text()
+        cost = StepCost(
+            flops,
+            float(analysis.get("bytes accessed", 0.0)),
+            memory_bytes=None if mem is None else int(
+                mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+            ),
+            kernels=text.count("tpu_custom_call"),
+            collectives=dict(Counter(_COLLECTIVE_RE.findall(text))),
+        )
     with _lock:
         _COST_CACHE[sig] = cost
     if cost is not None:
@@ -515,67 +551,45 @@ def step_cost(name: str, jitted, *args, **kwargs) -> Optional["StepCost"]:
     return cost
 
 
-def peak_flops(device_kind: Optional[str] = None) -> Tuple[float, str]:
-    """Peak dense FLOP/s for a device kind: ``MOOLIB_DEVMON_PEAK_FLOPS``
-    override > spec table > nominal (unknown kinds — CPU).  Returns
-    ``(flops_per_s, source)`` with source in {"env", "table", "nominal"}."""
-    env = os.environ.get("MOOLIB_DEVMON_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env), "env"
-        except ValueError:
-            pass
-    k = (device_kind or "").lower()
-    for sub, peak in _PEAK_FLOPS:
-        if sub in k:
-            return peak, "table"
-    return NOMINAL_PEAK_FLOPS, "nominal"
-
-
-def peak_bandwidth(device_kind: Optional[str] = None) -> Tuple[float, str]:
-    """Peak HBM bytes/s for a device kind (same resolution order as
-    :func:`peak_flops`; override knob ``MOOLIB_DEVMON_PEAK_BW``)."""
-    env = os.environ.get("MOOLIB_DEVMON_PEAK_BW")
-    if env:
-        try:
-            return float(env), "env"
-        except ValueError:
-            pass
-    k = (device_kind or "").lower()
-    for sub, bw in _PEAK_BW:
-        if sub in k:
-            return bw, "table"
-    return NOMINAL_PEAK_BW, "nominal"
-
-
-def _device_kind() -> Optional[str]:
-    try:
-        import jax
-
-        return jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — no backend: nominal peaks apply
+def _peak(table: List[Tuple[str, float]], device_kind: str) -> Optional[float]:
+    k = device_kind.lower()
+    if k == "cpu":
         return None
+    for sub, peak in table:
+        if sub in k:
+            return peak
+    raise ValueError(
+        f"device kind {device_kind!r} is not in devmon's peak tables; add its "
+        "published peak there (an MFU against an assumed peak is not reported)"
+    )
 
 
-def roofline(
-    flops: float, bytes_accessed: float, device_kind: Optional[str] = None
-) -> Dict[str, Any]:
+def peak_flops(device_kind: str) -> Optional[float]:
+    """Peak dense bf16 FLOP/s for a device kind from the spec table; None on
+    the CPU backend; ``ValueError`` for any other kind the table lacks."""
+    return _peak(_PEAK_FLOPS, device_kind)
+
+
+def peak_bandwidth(device_kind: str) -> Optional[float]:
+    """Peak HBM bytes/s for a device kind (same contract as
+    :func:`peak_flops`)."""
+    return _peak(_PEAK_BW, device_kind)
+
+
+def roofline(flops: float, bytes_accessed: float, device_kind: str) -> Dict[str, Any]:
     """Roofline classification for a step: arithmetic intensity vs the
     chip's ridge point (peak_flops / peak_bw).  AI below the ridge means the
-    step is HBM-bound; above, compute-bound."""
-    pf, pf_src = peak_flops(device_kind)
-    pb, pb_src = peak_bandwidth(device_kind)
-    out: Dict[str, Any] = {
-        "peak_flops": pf,
-        "peak_bw": pb,
-        "peak_source": pf_src if pf_src == pb_src else f"{pf_src}/{pb_src}",
-    }
+    step is HBM-bound; above, compute-bound.  On the CPU backend (no peaks)
+    only the arithmetic intensity is reported and ``bound`` is None."""
+    pf, pb = peak_flops(device_kind), peak_bandwidth(device_kind)
+    out: Dict[str, Any] = {"peak_flops": pf, "peak_bw": pb, "bound": None}
     if not bytes_accessed or not flops:
-        out["bound"] = None
         return out
     ai = flops / bytes_accessed
-    ridge = pf / pb
     out["arithmetic_intensity_flop_per_byte"] = ai
+    if pf is None:
+        return out
+    ridge = pf / pb
     out["ridge_flop_per_byte"] = ridge
     out["min_step_s_compute"] = flops / pf
     out["min_step_s_memory"] = bytes_accessed / pb
@@ -592,22 +606,26 @@ def publish_step(
 ) -> Optional[Dict[str, Any]]:
     """Combine an XLA step cost with a measured step time into the
     ``step_mfu{fn}`` / ``step_bytes_per_flop{fn}`` gauges plus the roofline
-    verdict.  Returns ``{"mfu", "bytes_per_flop", "bound", ...}`` (None when
-    there is nothing to publish)."""
+    verdict.  Returns ``{"mfu", "bytes_per_flop", "bound", ...}``, or None
+    when there is nothing to publish — degenerate inputs, or the CPU backend,
+    which has no peak to hold a step against."""
     if cost is None or step_seconds <= 0 or cost.flops <= 0:
         return None
     if device_kind is None:
-        device_kind = _device_kind()
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
     roof = roofline(cost.flops, cost.bytes_accessed, device_kind)
-    mfu = cost.flops / step_seconds / roof["peak_flops"]
     bpf = cost.bytes_accessed / cost.flops
-    _M_STEP_MFU.set(mfu, fn=name)
     _M_STEP_BPF.set(bpf, fn=name)
+    if roof["peak_flops"] is None:
+        return None
+    mfu = cost.flops / step_seconds / roof["peak_flops"]
+    _M_STEP_MFU.set(mfu, fn=name)
     return {
         "mfu": mfu,
         "bytes_per_flop": bpf,
-        "bound": roof.get("bound"),
-        "peak_source": roof["peak_source"],
+        "bound": roof["bound"],
         "roofline": roof,
     }
 

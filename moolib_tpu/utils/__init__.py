@@ -14,7 +14,7 @@ from typing import Optional
 
 from . import nest  # noqa: F401
 from .stats import RunningMeanStd, StatMean, StatSum  # noqa: F401
-from .compile_cache import compile_cache_dir, init_compile_cache  # noqa: F401
+from .compile_cache import init_compile_cache  # noqa: F401
 
 # ---------------------------------------------------------------------------
 # uid / naming  (reference: randomName(), src/util.h — 16 hex chars)
@@ -127,23 +127,6 @@ def get_max_threads() -> Optional[int]:
 # ---------------------------------------------------------------------------
 # Timer  (reference: moolib::Timer, src/util.h:50-68)
 # ---------------------------------------------------------------------------
-
-
-def apply_platform_env() -> None:
-    """Honor the JAX_PLATFORMS env var even when a sitecustomize imported jax
-    at interpreter start (which locks the env-var-based selection). Call at
-    the top of CLI entry points, before any jax computation."""
-    import os
-
-    want = os.environ.get("JAX_PLATFORMS")
-    if not want:
-        return
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", want)
-    except Exception:  # backends already initialized; keep whatever exists
-        pass
 
 
 class Timer:

@@ -1,98 +1,42 @@
-"""Persistent XLA compile cache wiring (docs/RESILIENCE.md "Recovery budget").
+"""Persistent XLA compile cache placement (docs/RESILIENCE.md "Recovery budget").
 
-A restarted peer's recovery time is dominated by two costs: re-acquiring the
-model (chunked sync, ``accumulator.py``) and re-running XLA compilation of
-its train step from scratch.  The second cost is pure waste — the restarted
-process compiles the *same* programs its previous incarnation already
-compiled — and jax ships the fix: a persistent on-disk compilation cache
-(``jax_compilation_cache_dir``).  This module is the ONE place that wires
-it, so every entry point (the three examples, soak/chaos children, respawned
-EnvPool workers) applies identical knobs:
+A restarted peer re-compiles the *same* programs its previous incarnation
+already compiled, and every process of one chip-tool call compiles what its
+siblings did; jax's on-disk compilation cache removes both.  The cache path
+is part of the cache key, so it has to be the same for every process that
+should share entries.  Placement is decided outside the program:
 
-- ``MOOLIB_COMPILE_CACHE=<dir>`` — enable the cache at ``<dir>`` (the soak
-  harness points every peer at one shared directory: peer 0 compiles, the
-  other N-1 cold starts and every kill/restart reload from disk).
-- ``--compile_cache_dir`` on the example CLIs — same knob, explicit arg
-  wins over the environment.
-- ``MOOLIB_COMPILE_CACHE_MIN_COMPILE_SECS`` (default ``0.5``) — only
-  persist compilations that took at least this long; tiny programs aren't
-  worth the disk round trip.
-- ``MOOLIB_COMPILE_CACHE_MIN_ENTRY_BYTES`` (default ``0``) — minimum
-  serialized-executable size to persist.
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself at import, in every
+  entry point, bench script and child process that inherits the environment.
+  This module then sets nothing.
+- unset: one fixed directory inside the checkout (``<repo>/.jax_cache``,
+  git-ignored) — never a temporary name, a pid or a per-run directory.
 
-``init_compile_cache()`` is idempotent and deliberately import-light: with
-no directory configured it returns ``None`` without importing jax, so the
-EnvPool worker main (which normally never touches jax) stays jax-free
-unless the operator opted in.
+Thresholds (what is worth persisting) are jax's own
+``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` /
+``JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES``.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-_ENV_DIR = "MOOLIB_COMPILE_CACHE"
-_ENV_MIN_SECS = "MOOLIB_COMPILE_CACHE_MIN_COMPILE_SECS"
-_ENV_MIN_BYTES = "MOOLIB_COMPILE_CACHE_MIN_ENTRY_BYTES"
+_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-_initialized_dir: Optional[str] = None
 
-
-def init_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir`` (or
-    ``$MOOLIB_COMPILE_CACHE`` when not given).  Returns the directory in
-    use, or ``None`` when no cache is configured.
-
-    Call before the first jit of the process — entry points do it right
-    after ``apply_platform_env()``.  Idempotent: the first configured
-    directory wins (jax's cache config is process-global); a later call
-    with a different directory logs and keeps the first.
-    """
-    global _initialized_dir
-    cache_dir = cache_dir or os.environ.get(_ENV_DIR) or None
-    if not cache_dir:
-        return None
-    cache_dir = os.path.abspath(cache_dir)
-    if _initialized_dir is not None:
-        if _initialized_dir != cache_dir:
-            from . import log_error
-
-            log_error(
-                "compile cache already initialized at %s; ignoring %s "
-                "(jax's cache dir is process-global)",
-                _initialized_dir, cache_dir,
-            )
-        return _initialized_dir
-
+def init_compile_cache() -> str:
+    """Make sure jax's persistent compilation cache has a directory, and
+    return it.  Call before the first jit of the process (jax decides once,
+    at its first compile, whether the cache is in use).  Idempotent."""
+    placed = os.environ.get(_ENV_DIR)
+    if placed:
+        return placed
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        min_secs = float(os.environ.get(_ENV_MIN_SECS, "0.5"))
-    except ValueError:
-        min_secs = 0.5
-    try:
-        min_bytes = int(os.environ.get(_ENV_MIN_BYTES, "0"))
-    except ValueError:
-        min_bytes = 0
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", min_bytes)
-    except Exception:  # noqa: BLE001 — knob absent on older jax
-        pass
-    _initialized_dir = cache_dir
-
-    from . import log_info
-
-    log_info(
-        "compile cache: %s (min compile %.2fs, min entry %d B) — restarts "
-        "skip recompilation of already-seen programs",
-        cache_dir, min_secs, min_bytes,
-    )
-    return cache_dir
-
-
-def compile_cache_dir() -> Optional[str]:
-    """The directory ``init_compile_cache`` wired, or None."""
-    return _initialized_dir
+    if jax.config.jax_compilation_cache_dir != DEFAULT_DIR:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return DEFAULT_DIR

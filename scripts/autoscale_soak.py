@@ -341,11 +341,9 @@ def main(argv=None) -> int:
     import tempfile
 
     flags.workdir = flags.workdir or tempfile.mkdtemp(prefix="autoscale_soak_")
-    # Shared compile cache: respawned workers skip XLA compilation, so the
+    # Respawned workers skip XLA compilation through the persistent compile
+    # cache every entry point shares (utils/compile_cache.py), so the
     # recovery bound budgets eviction + rejoin + model sync, not compiles.
-    os.environ.setdefault(
-        "MOOLIB_COMPILE_CACHE", os.path.join(flags.workdir, "jax_cache")
-    )
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     log(f"seed={flags.seed} target={flags.target_peers} workdir={flags.workdir}")
     soak = Soak(flags)

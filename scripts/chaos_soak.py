@@ -62,7 +62,6 @@ def free_port() -> int:
     return port
 
 
-CACHE_DIR = ""  # set in main(): shared persistent compile cache for children
 
 
 def child_env(faults: str = "", extra_env=None) -> dict:
@@ -71,10 +70,10 @@ def child_env(faults: str = "", extra_env=None) -> dict:
         PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
         JAX_PLATFORMS="cpu",
     )
-    if CACHE_DIR:
-        # Respawned/relaunched children skip recompilation — the recovery
-        # bound below budgets model re-sync, not XLA compile time.
-        env["MOOLIB_COMPILE_CACHE"] = CACHE_DIR
+    # Respawned/relaunched children skip recompilation through the
+    # persistent compile cache every entry point shares
+    # (utils/compile_cache.py) — the recovery bound below budgets model
+    # re-sync, not XLA compile time.
     if faults:
         env["MOOLIB_FAULTS"] = faults
     else:
@@ -488,14 +487,7 @@ def main(argv=None) -> int:
 
     from moolib_tpu.testing import FaultPlan
 
-    global CACHE_DIR
     workdir = flags.workdir or tempfile.mkdtemp(prefix="chaos_soak_")
-    # An operator/CI-provided cache dir wins: ci.sh points every run at one
-    # shared directory so cross-run warmth keeps first_compile inside the
-    # recovery bound; only fall back to a per-run cache when unset.
-    CACHE_DIR = os.environ.get("MOOLIB_COMPILE_CACHE") or os.path.join(
-        workdir, "jax_cache"
-    )
     plan = FaultPlan(flags.seed)
     log(f"seed={flags.seed} workdir={workdir} steps={flags.steps} "
         f"recovery_bound={flags.recovery_bound_s:.0f}s")

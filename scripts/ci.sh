@@ -283,14 +283,14 @@ fi
 step "chaos soak (seeded, ~80 s smoke: worker/peer kills + respawn SLO, RPC frame chaos, forced-kill resume, mid-shard-write kill + distributed checkpoint resume)"
 # Exits non-zero if any phase stalls past its watchdog/deadline, or the
 # respawned peer misses its recovery bound (docs/RESILIENCE.md recovery
-# budget).  The shared compile cache below is what keeps the respawn's
-# first_compile phase inside the bound — the soak exercises the same
-# mechanism production restarts rely on.
+# budget).  The persistent compile cache (utils/compile_cache.py: where
+# JAX_COMPILATION_CACHE_DIR says, else <repo>/.jax_cache) is what keeps the
+# respawn's first_compile phase inside the bound — the soak exercises the
+# same mechanism production restarts rely on.
 # MOOLIB_LOCKGRAPH=1: every threading.Lock/RLock in every soak process is
 # instrumented; an observed ABBA acquisition-order cycle fails the run at
 # teardown with both stacks (moolib_tpu/testing/lockgraph.py).
-MOOLIB_COMPILE_CACHE="${TMPDIR:-/tmp}/moolib_ci_jax_cache" \
-  MOOLIB_LOCKGRAPH=1 \
+MOOLIB_LOCKGRAPH=1 \
   python scripts/chaos_soak.py --smoke --recovery_bound_s 60 || fail=1
 
 step "autoscaler tests (policy decisions, graceful leave, vbatch stability across resize)"
@@ -301,8 +301,7 @@ step "autoscale soak (Poisson preemption: respawn SLO, sub-second graceful decom
 # within --recovery_bound_s), a graceful decommission that burned the
 # ping-eviction timeout instead of __broker_leave, or any vbatch_violation
 # in a worker log (docs/RESILIENCE.md "Autoscaling").
-MOOLIB_COMPILE_CACHE="${TMPDIR:-/tmp}/moolib_ci_jax_cache" \
-  python scripts/autoscale_soak.py --smoke --recovery_bound_s 90 || fail=1
+python scripts/autoscale_soak.py --smoke --recovery_bound_s 90 || fail=1
 
 step "serving plane tests (hot swap mid-traffic, typed admission rejects, req-id dedup, failover)"
 python -m pytest tests/test_serving.py -q || fail=1

@@ -403,9 +403,11 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     from moolib_tpu.examples.lm_serve import make_model
-    from moolib_tpu.utils import apply_platform_env
 
-    apply_platform_env()
+    # The harness computes on the host: an accelerator belongs to one
+    # process, and that is a replica child (which gets the caller's
+    # JAX_PLATFORMS through its environment), never this parent.
+    jax.config.update("jax_platforms", "cpu")
     model = make_model(flags)
     rng0 = np.random.default_rng(flags.seed)
     toks = jnp.asarray(

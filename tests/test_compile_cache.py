@@ -1,12 +1,11 @@
-"""Persistent compile cache (ISSUE 3 tentpole a): the utils helper wires
-jax's on-disk compilation cache so a restarted process skips recompilation —
-the dominant cold-restart cost in the soak's recovery budget.
+"""Persistent compile cache placement (``utils/compile_cache.py``).
 
-The smoke test is the soak-restart shape in miniature: two subprocess
-"incarnations" compile the same program with ``MOOLIB_COMPILE_CACHE`` set;
-the second must be measurably faster (cache hit) and the cache directory
-must hold entries after the first.  CPU-safe: jax's persistent cache works
-on the CPU backend (verified on the pinned jax).
+Where the cache lives is decided outside the program: where
+``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and the module sets
+no directory; unset, every process shares one fixed path inside the checkout.
+A restarted process — the soak's respawn, the next child of one chip-tool
+call — then skips recompilation.  Each case runs in a fresh interpreter:
+jax's cache configuration is process-global and decided at the first compile.
 """
 
 import os
@@ -14,45 +13,105 @@ import re
 import subprocess
 import sys
 
-import pytest
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIXED = os.path.join(ROOT, ".jax_cache")
 
-_CHILD = r"""
-import sys, time
-sys.path.insert(0, %(root)r)
+
+def _run(code: str, **env) -> str:
+    base = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    base.update(
+        JAX_PLATFORMS="cpu",
+        PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
+        **env,
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, env=base,
+        timeout=240, cwd=ROOT,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout
+
+
+_PLACEMENT = r"""
+import jax
+updates = []
+real = jax.config.update
+jax.config.update = lambda name, value: (updates.append(name), real(name, value))
 from moolib_tpu.utils import init_compile_cache
-d = init_compile_cache()
-assert d, "MOOLIB_COMPILE_CACHE not picked up"
-import jax, jax.numpy as jnp
-
-def f(x):
-    for i in range(80):
-        x = jnp.sin(x) @ x + i
-    return x.sum()
-
-t0 = time.perf_counter()
-jax.jit(f).lower(jnp.ones((64, 64))).compile()
-print("COMPILE_SECONDS=%%.4f" %% (time.perf_counter() - t0), flush=True)
+print("RETURNED=" + init_compile_cache())
+print("CONFIGURED=" + str(jax.config.jax_compilation_cache_dir))
+print("DIR_UPDATES=%d" % updates.count("jax_compilation_cache_dir"))
+init_compile_cache()  # idempotent
+print("DIR_UPDATES_AFTER_SECOND_CALL=%d" % updates.count("jax_compilation_cache_dir"))
 """
 
 
-_SHARDED_CHILD = r"""
-import sys
+def test_env_var_places_the_cache_and_the_module_sets_nothing(tmp_path):
+    placed = str(tmp_path / "from_outside")
+    out = _run(_PLACEMENT, JAX_COMPILATION_CACHE_DIR=placed)
+    assert f"RETURNED={placed}\n" in out
+    assert f"CONFIGURED={placed}\n" in out  # jax read the variable itself
+    assert "DIR_UPDATES=0\n" in out and "DIR_UPDATES_AFTER_SECOND_CALL=0\n" in out
+
+
+def test_unset_means_the_fixed_path_inside_the_checkout():
+    out = _run(_PLACEMENT)
+    assert f"RETURNED={FIXED}\n" in out
+    assert f"CONFIGURED={FIXED}\n" in out
+    assert "DIR_UPDATES=1\n" in out and "DIR_UPDATES_AFTER_SECOND_CALL=1\n" in out
+
+
+# A program no other test compiles (the cache is shared with the whole
+# suite), taking the time of its compile; persisted whatever it took.
+_INCARNATION = r"""
+import time
+from moolib_tpu.utils import init_compile_cache
+print("CACHE_DIR=" + init_compile_cache())
+import jax, jax.numpy as jnp
+from moolib_tpu.telemetry import devmon
+devmon.install_compile_listeners()
+
+def f(x):
+    for i in range(80):
+        x = jnp.sin(x) @ x + i * %(salt)r
+    return x.sum()
+
+x = jnp.ones((64, 64))  # its own small programs may hit or miss: not counted
+before = devmon.compile_summary()["cache_hits"]
+t0 = time.perf_counter()
+jax.jit(f).lower(x).compile()
+print("COMPILE_SECONDS=%%.4f" %% (time.perf_counter() - t0))
+print("HITS=%%d" %% (devmon.compile_summary()["cache_hits"] - before))
+"""
+
+
+def test_second_incarnation_at_the_fixed_path_hits(tmp_path):
+    """Soak-restart shape: two processes, nothing in common but the default
+    placement; the second must find what the first compiled."""
+    code = _INCARNATION % {"salt": float(os.getpid()) + 0.5}  # new to the cache
+    env = {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+    first, second = _run(code, **env), _run(code, **env)
+    for out in (first, second):
+        assert f"CACHE_DIR={FIXED}\n" in out
+    assert "HITS=0\n" in first
+    assert "HITS=1\n" in second
+    t1, t2 = (float(re.search(r"COMPILE_SECONDS=([0-9.]+)", o).group(1))
+              for o in (first, second))
+    if t1 >= 0.3:  # else too fast to compare
+        assert t2 < t1 * 0.7, f"no faster from the cache: {t1:.3f}s then {t2:.3f}s"
+
+
+_SHARDED_STEP = r"""
 import numpy as np
-sys.path.insert(0, %(root)r)
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from moolib_tpu import parallel
-from moolib_tpu.utils import compile_cache
 
 # The child never calls init_compile_cache itself: the sharded step path
-# must do the wiring on its own before its first jit.  All inputs are
-# plain numpy — jax memoizes its cache-enabled decision at the FIRST
-# compile of the process, so even a jnp.zeros() here would lock the cache
-# off before the step's init ran (which is exactly why the step does the
-# wiring before ITS first jit).
-assert compile_cache.compile_cache_dir() is None
+# does the placing on its own before its first jit.  All inputs are plain
+# numpy — jax decides whether the cache is in use at the FIRST compile of
+# the process, so even a jnp.zeros() here would lock it off first.
+assert jax.config.jax_compilation_cache_dir is None
 
 def loss_fn(params, batch, rng):
     pred = batch["x"] @ params["w"]
@@ -63,98 +122,57 @@ step = parallel.make_train_step(
     loss_fn, mesh=mesh, grad_spec="replicated", batch_spec=P(None, "dp")
 )
 params = {"w": np.zeros((64, 64), np.float32)}
-batch = {
-    "x": np.ones((1, 8, 64), np.float32),
-    "y": np.zeros((1, 8, 64), np.float32),
-}
+batch = {"x": np.ones((1, 8, 64), np.float32), "y": np.zeros((1, 8, 64), np.float32)}
 loss, aux, grads = step(params, batch, np.uint32(0))
 jax.block_until_ready(grads)
-d = compile_cache.compile_cache_dir()
-assert d, "sharded grad step did not initialize the compile cache"
-print("CACHE_DIR=" + d, flush=True)
+print("CONFIGURED=" + str(jax.config.jax_compilation_cache_dir))
 """
 
 
-def test_sharded_grad_step_initializes_cache(tmp_path):
-    """The mesh-sharded grad step (DESIGN.md §6d) must wire the persistent
-    cache itself before its first jit — a restarted pod-scale learner
-    replays the pjit'd step from disk without the caller remembering to."""
-    cache = str(tmp_path / "jax_cache")
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        MOOLIB_COMPILE_CACHE=cache,
-        MOOLIB_COMPILE_CACHE_MIN_COMPILE_SECS="0.0",
-        PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
-    )
-    out = subprocess.run(
-        [sys.executable, "-c", _SHARDED_CHILD % {"root": ROOT}],
-        capture_output=True, text=True, env=env, timeout=240,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "CACHE_DIR=" in out.stdout, out.stdout
-    assert os.listdir(cache), "sharded step persisted no cache entries"
+def test_sharded_grad_step_places_the_cache_itself():
+    """The mesh-sharded grad step (DESIGN.md §6d) places the cache before
+    its first jit — a restarted pod-scale learner replays the pjit'd step
+    from disk without the caller remembering to."""
+    out = _run(_SHARDED_STEP, XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    assert f"CONFIGURED={FIXED}\n" in out
 
 
-def _run_incarnation(cache_dir: str) -> float:
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        MOOLIB_COMPILE_CACHE=cache_dir,
-        # Persist every entry: the smoke's program must never be skipped as
-        # "too fast to be worth caching".
-        MOOLIB_COMPILE_CACHE_MIN_COMPILE_SECS="0.0",
-        PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
-    )
-    out = subprocess.run(
-        [sys.executable, "-c", _CHILD % {"root": ROOT}],
-        capture_output=True, text=True, env=env, timeout=240,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    m = re.search(r"COMPILE_SECONDS=([0-9.]+)", out.stdout)
-    assert m, out.stdout
-    return float(m.group(1))
+class _ProbeEnv:
+    """A jax-free env whose observation is what its worker process looks
+    like: [a jax backend exists, JAX_PLATFORMS is "cpu", jax's own platform
+    setting is "cpu"]."""
+
+    def reset(self):
+        import numpy as np
+
+        from moolib_tpu.envpool import _jax_backend_initialized
+
+        jax = sys.modules.get("jax")
+        return np.array([
+            float(_jax_backend_initialized()),
+            float(os.environ.get("JAX_PLATFORMS") == "cpu"),
+            float(jax is None or jax.config.jax_platforms == "cpu"),
+        ], np.float32)
+
+    def step(self, action):
+        return self.reset(), 0.0, False, {}
 
 
-def test_second_restart_compiles_from_cache(tmp_path):
-    """Soak-restart shape: incarnation 2 must hit the disk cache."""
-    cache = str(tmp_path / "jax_cache")
-    t1 = _run_incarnation(cache)
-    entries = os.listdir(cache)
-    assert entries, "first incarnation persisted nothing"
-    if t1 < 0.3:
-        pytest.skip(f"workload compiled in {t1:.3f}s — too fast to compare")
-    t2 = _run_incarnation(cache)
-    # 1.6s -> 0.3s on the dev box; 0.7 leaves slack for loaded CI while
-    # still requiring a real cache hit (a miss re-pays the full compile).
-    assert t2 < t1 * 0.7, (
-        f"second incarnation did not get measurably faster "
-        f"(first {t1:.3f}s, second {t2:.3f}s)"
-    )
+def test_envpool_worker_stays_off_the_accelerator():
+    """An accelerator belongs to the pool's parent.  Importing the package
+    loads the jax module in every worker, so what a jax-free env must never
+    cause is a jax *backend* — placing the compile cache included — and what
+    an env that does use jax must find is the CPU platform, whatever the
+    parent's environment says."""
+    import numpy as np
 
+    from moolib_tpu import EnvPool
 
-def test_init_compile_cache_noop_and_idempotent(tmp_path, monkeypatch):
-    from moolib_tpu.utils import compile_cache
-
-    monkeypatch.delenv("MOOLIB_COMPILE_CACHE", raising=False)
-    monkeypatch.setattr(compile_cache, "_initialized_dir", None)
-    assert compile_cache.init_compile_cache() is None
-    assert compile_cache.compile_cache_dir() is None
-    d = str(tmp_path / "c")
-    got = compile_cache.init_compile_cache(d)
-    assert got == os.path.abspath(d)
-    assert os.path.isdir(d)
-    # First configured directory wins (jax's cache config is process-global).
-    again = compile_cache.init_compile_cache(str(tmp_path / "other"))
-    assert again == os.path.abspath(d)
-    assert compile_cache.compile_cache_dir() == os.path.abspath(d)
-
-
-def test_env_var_configures(tmp_path, monkeypatch):
-    from moolib_tpu.utils import compile_cache
-
-    d = str(tmp_path / "from_env")
-    monkeypatch.setenv("MOOLIB_COMPILE_CACHE", d)
-    monkeypatch.setattr(compile_cache, "_initialized_dir", None)
-    assert compile_cache.init_compile_cache() == os.path.abspath(d)
+    pool = EnvPool(_ProbeEnv, num_processes=1, batch_size=2, num_batches=1)
+    try:
+        obs = pool.step(0, np.zeros(2, np.int64)).result()
+        backend, env_pinned, config_pinned = obs["state"][0].tolist()
+    finally:
+        pool.close()
+    assert backend == 0.0, "a jax-free env's worker initialised a jax backend"
+    assert env_pinned == 1.0 and config_pinned == 1.0

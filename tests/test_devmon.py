@@ -163,54 +163,67 @@ def test_step_cost_counts_flops_for_lm_like_step():
 def test_publish_step_finite_mfu_and_roofline():
     cost = devmon.StepCost(flops=1e9, bytes_accessed=1e8)
     out = devmon.publish_step("t.pub", cost, step_seconds=0.01,
-                              device_kind="weird-cpu")
+                              device_kind="TPU v5 lite")
     assert out is not None
-    assert 0 < out["mfu"] < 1  # 1e9/0.01/1e12 = 1e-4 against the nominal peak
-    assert out["peak_source"] == "nominal"
+    assert out["mfu"] == pytest.approx(1e9 / 0.01 / 197e12)
     assert out["bound"] in ("memory", "compute")
     snap = telemetry.get_registry().snapshot()
     vals = {
         s["labels"]["fn"]: s["value"] for s in snap["step_mfu"]["series"]
     }
     assert vals["t.pub"] == pytest.approx(out["mfu"])
+    # The CPU backend has no peak: no MFU is published for it, against any
+    # stand-in number (the process here runs on cpu, so the default kind
+    # resolves to it too).
+    assert devmon.publish_step("t.cpu", cost, 0.01, device_kind="cpu") is None
+    assert devmon.publish_step("t.cpu", cost, 0.01) is None
+    assert "t.cpu" not in {
+        s["labels"]["fn"]
+        for s in telemetry.get_registry().snapshot()["step_mfu"]["series"]
+    }
     # Degenerate inputs publish nothing instead of inf/NaN.
-    assert devmon.publish_step("t.pub", cost, 0.0) is None
-    assert devmon.publish_step("t.pub", None, 1.0) is None
+    assert devmon.publish_step("t.pub", cost, 0.0, "TPU v4") is None
+    assert devmon.publish_step("t.pub", None, 1.0, "TPU v4") is None
 
 
-def test_peak_tables_and_env_override(monkeypatch):
-    assert devmon.peak_flops("TPU v4") == (275e12, "table")
-    assert devmon.peak_flops("TPU v5 lite") == (197e12, "table")
-    assert devmon.peak_flops("TPU v5p") == (459e12, "table")
+def test_peak_tables_unknown_kind_raises():
+    assert devmon.peak_flops("TPU v4") == 275e12
+    assert devmon.peak_flops("TPU v5 lite") == 197e12
+    assert devmon.peak_flops("TPU v5p") == 459e12
     # Substring order matters: "v5e"/"v5p" must not fall through to the
     # bare "v5" (pod) row, and the v6 generation resolves across the
     # spellings device_kind uses ("TPU v6e", "TPU v6 lite").
-    assert devmon.peak_flops("TPU v5e") == (197e12, "table")
-    assert devmon.peak_flops("TPU v6e") == (918e12, "table")
-    assert devmon.peak_flops("TPU v6 lite") == (918e12, "table")
-    assert devmon.peak_bandwidth("TPU v5e") == (819e9, "table")
-    assert devmon.peak_bandwidth("TPU v5p") == (2765e9, "table")
-    assert devmon.peak_bandwidth("TPU v6e") == (1640e9, "table")
-    assert devmon.peak_flops("cpu") == (devmon.NOMINAL_PEAK_FLOPS, "nominal")
-    # MOOLIB_DEVMON_PEAK_* wins over every table row; garbage values fall
-    # back to the table instead of raising.
-    monkeypatch.setenv("MOOLIB_DEVMON_PEAK_FLOPS", "123e9")
-    assert devmon.peak_flops("TPU v4") == (123e9, "env")
-    monkeypatch.setenv("MOOLIB_DEVMON_PEAK_BW", "7e9")
-    assert devmon.peak_bandwidth("TPU v4") == (7e9, "env")
-    monkeypatch.setenv("MOOLIB_DEVMON_PEAK_FLOPS", "fast")
-    assert devmon.peak_flops("TPU v4") == (275e12, "table")
+    assert devmon.peak_flops("TPU v5e") == 197e12
+    assert devmon.peak_flops("TPU v6e") == 918e12
+    assert devmon.peak_flops("TPU v6 lite") == 918e12
+    assert devmon.peak_bandwidth("TPU v5e") == 819e9
+    assert devmon.peak_bandwidth("TPU v5p") == 2765e9
+    assert devmon.peak_bandwidth("TPU v6e") == 1640e9
+    # The CPU has no peak; a kind the table lacks is an error, not a default.
+    assert devmon.peak_flops("cpu") is None
+    assert devmon.peak_bandwidth("cpu") is None
+    for fn in (devmon.peak_flops, devmon.peak_bandwidth):
+        with pytest.raises(ValueError, match="not in devmon's peak tables"):
+            fn("NVIDIA H100")
+    with pytest.raises(ValueError):
+        devmon.publish_step(
+            "t.unknown", devmon.StepCost(1e9, 1e8), 0.01, device_kind="TPU v9"
+        )
 
 
 def test_roofline_classification():
-    # AI = 10, nominal ridge = 1e12/100e9 = 10 -> exactly at the ridge is
-    # compute; far below is memory-bound.
-    mem = devmon.roofline(1e6, 1e9, "cpu")
+    # v5e ridge = 197e12 / 819e9 ~ 240 flop/byte: far below is memory-bound,
+    # far above compute-bound.
+    mem = devmon.roofline(1e6, 1e9, "TPU v5e")
     assert mem["bound"] == "memory"
-    comp = devmon.roofline(1e12, 1e6, "cpu")
+    comp = devmon.roofline(1e12, 1e6, "TPU v5e")
     assert comp["bound"] == "compute"
     assert comp["roofline_mfu_ceiling"] == 1.0
-    assert devmon.roofline(0.0, 1e6, "cpu")["bound"] is None
+    assert devmon.roofline(0.0, 1e6, "TPU v5e")["bound"] is None
+    # No peaks on the CPU: intensity only, no verdict.
+    cpu = devmon.roofline(1e12, 1e6, "cpu")
+    assert cpu["bound"] is None and cpu["peak_flops"] is None
+    assert cpu["arithmetic_intensity_flop_per_byte"] == pytest.approx(1e6)
 
 
 # -------------------------------------------------------------- cohort skew

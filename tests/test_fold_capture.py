@@ -1,9 +1,9 @@
-"""fold_capture.py is the unattended bridge from battery logs to the
-committed chip record (BENCH_TPU.json) — a wrong fold silently corrupts
-the judge-facing evidence, so its guards are pinned here.
+"""fold_capture.py folds benchmark logs into a record file — a wrong fold
+silently corrupts the record, so its guards are pinned here.
 
-Runs the real CLI via subprocess (the battery's interface), one tmp
-capture dir per test.
+Runs the real CLI via subprocess, one tmp capture dir per test.  (The
+directory mode these tests drive goes with ROADMAP D5; ``--local`` is the
+mode ``scripts/ci.sh`` uses.)
 """
 
 import json

@@ -3,12 +3,15 @@ election, model sync, virtual batches) driving TransformerLM — the same
 wants/has plane the RL agents ride, proving it is model-agnostic.
 """
 
+import json
 import os
 import subprocess
 import sys
 import time
 
 from conftest import grab_port, subprocess_env
+
+from moolib_tpu.examples.common import REPORT_PREFIX
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -58,8 +61,10 @@ def test_two_peer_elastic_lm_cohort(tmp_path):
     # random-chance loss and a nonzero reduction count.
     for r, out in enumerate(outs):
         final = out.strip().splitlines()[-1]
-        assert "'steps': 250" in final, (r, final)
-        loss = float(final.split("'loss': ")[1].split(",")[0])
-        reduces = int(final.split("'reduces': ")[1].split(",")[0])
-        assert loss < 3.6, (r, final)  # clearly below the 4.13 chance floor
-        assert reduces >= 100, (r, final)
+        assert final.startswith(REPORT_PREFIX), (r, final)
+        report = json.loads(final[len(REPORT_PREFIX):])
+        result = report["result"]
+        assert report["device"]["platform"] == "cpu", (r, final)
+        assert result["steps"] == 250, (r, final)
+        assert result["loss"] < 3.6, (r, final)  # clearly below the 4.13 chance floor
+        assert result["reduces"] >= 100, (r, final)

@@ -9,7 +9,7 @@ regression gate for its locking:
 2. The same under ``-fsanitize=address,undefined``.
 3. A ctypes-boundary stress: the real ``NativeNet`` binding driving an
    ASAN-built engine inside a subprocess running under the libasan preload
-   (``MOOLIB_TPU_SANITIZE=address`` builds the lib; see docs/STATUS.md).
+   (``MOOLIB_TPU_SANITIZE=address`` builds the lib).
 
 Each is skipped (not failed) when the toolchain lacks the sanitizer runtime.
 """

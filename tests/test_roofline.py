@@ -1,7 +1,7 @@
 """Tests for the analytic MXU-geometry roofline (benchmarks/impala_roofline.py).
 
-The analytic ceiling is the denominator for every published MFU claim
-(docs/PERF.md), so its arithmetic is pinned here: layer inventory, the
+The analytic ceiling is what a measured learner MFU is held against, so its
+arithmetic is pinned here: layer inventory, the
 narrow-channel lane-occupancy caps, and the cross-check against XLA's own
 cost analysis of the exact benchmarked step.
 """
@@ -45,7 +45,7 @@ def test_flop_shares_sum_to_one():
 @pytest.mark.slow
 def test_xla_cost_analysis_corroborates():
     # XLA's counted FLOPs for the exact benchmarked fwd+bwd step should be
-    # ~3x the analytic forward pass (the approximation PERF.md states).
+    # ~3x the analytic forward pass (the stated approximation).
     import jax
 
     jax.config.update("jax_platforms", "cpu")

@@ -29,6 +29,7 @@ the serving plane is argmax today, matching ``lm_serve``).
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -40,7 +41,7 @@ from .. import telemetry
 from ..telemetry import devmon
 from ..models.transformer import TransformerLM
 from ..ops.paged_attention import PagedState
-from ..serving import bucket, bucket_shapes
+from ..serving import _M_PHASE, bucket, bucket_shapes
 from .kv_pool import BlockPool, PoolExhausted
 
 _REG = telemetry.get_registry()
@@ -331,20 +332,29 @@ class ContinuousBatchingEngine:
                 f"prompt + max_new_tokens = {total} exceeds the engine's "
                 f"sequence capacity {self.seq_capacity}"
             )
-        lb = bucket(tp, self.max_prompt_len)
-        pad = lb - tp
-        toks = np.pad(prompt, (0, pad))[None]
-        if pad:
-            self._stats["prefill_pad_tokens"] += pad
-            _M_PAD_TOKENS.inc(pad)
-        toks_dev = (toks if self._prefill_sharding is None
-                    else jax.device_put(toks, self._prefill_sharding))
-        ks, vs, tok0 = self._prefill_jit(
-            self._params_pre, toks_dev, np.int32(tp)
-        )
+        with telemetry.span("engine.submit"):
+            return self._submit(prompt, tp, max_new)
+
+    def _submit(self, prompt, tp: int, max_new: int):
+        """``submit`` past its checks, under its span: one child span for
+        each place the host can wait."""
+        total = tp + max_new
+        with telemetry.span("engine.prefill_dispatch"):
+            lb = bucket(tp, self.max_prompt_len)
+            pad = lb - tp
+            toks = np.pad(prompt, (0, pad))[None]
+            if pad:
+                self._stats["prefill_pad_tokens"] += pad
+                _M_PAD_TOKENS.inc(pad)
+            toks_dev = (toks if self._prefill_sharding is None
+                        else jax.device_put(toks, self._prefill_sharding))
+            ks, vs, tok0 = self._prefill_jit(
+                self._params_pre, toks_dev, np.int32(tp)
+            )
         self._stats["prefill_tokens"] += tp
         _M_PREFILL_TOKENS.inc(tp)
-        tok0 = int(tok0)
+        with telemetry.span("engine.first_token_fetch"):
+            tok0 = int(tok0)  # waits for the prefill
         emitted = [tok0]
         if max_new == 1 or (self.eos_id is not None and tok0 == self.eos_id):
             return None, emitted
@@ -354,20 +364,21 @@ class ContinuousBatchingEngine:
             ks, vs = jax.tree.map(lambda x: x[0], self._xfer.get())
         if not self._free_slots:
             raise NoFreeSlot(f"all {self.slots} slots occupied")
-        nbw = int(ks.shape[1])
-        n_alloc = self.pool.blocks_for(max(lb, total))
-        block_ids = self.pool.alloc(n_alloc)  # PoolExhausted -> stay queued
-        slot = self._free_slots.pop()
-        row = np.zeros(self.max_blocks_per_seq, np.int32)
-        row[:n_alloc] = block_ids
-        (self._cache, self._tables, self._lengths, self._active,
-         self._tokens, self._remaining) = self._join_jit(
-            self._cache, self._tables, self._lengths, self._active,
-            self._tokens, self._remaining,
-            np.int32(slot), row, np.int32(tp), np.int32(tok0),
-            np.int32(max_new - 1),
-            ks, vs, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
-        )
+        with telemetry.span("engine.join"):
+            nbw = int(ks.shape[1])
+            n_alloc = self.pool.blocks_for(max(lb, total))
+            block_ids = self.pool.alloc(n_alloc)  # PoolExhausted -> stay queued
+            slot = self._free_slots.pop()
+            row = np.zeros(self.max_blocks_per_seq, np.int32)
+            row[:n_alloc] = block_ids
+            (self._cache, self._tables, self._lengths, self._active,
+             self._tokens, self._remaining) = self._join_jit(
+                self._cache, self._tables, self._lengths, self._active,
+                self._tokens, self._remaining,
+                np.int32(slot), row, np.int32(tp), np.int32(tok0),
+                np.int32(max_new - 1),
+                ks, vs, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
+            )
         self._slot_blocks[slot] = block_ids
         self._emitted[slot] = emitted
         self._remaining_host[slot] = max_new - 1
@@ -382,30 +393,42 @@ class ContinuousBatchingEngine:
         emitted this step (slot -> token) and the slots that finished."""
         if not self._active_host.any():
             return {}, []
-        (self._cache, self._tables, self._lengths, self._active,
-         self._tokens, self._remaining, done) = self._step_jit(
-            self._params_dec, self._cache, self._tables, self._lengths,
-            self._active, self._tokens, self._remaining,
-        )
-        # host_span marks the decode loop's D2H wait as host-blocked for any
-        # open timeline capture window (telemetry.timeline).
+        with telemetry.span("engine.step"):
+            return self._step()
+
+    def _step(self):
+        """``step`` with a slot to advance, under its span."""
+        t0 = time.monotonic()
+        with telemetry.span("engine.step_dispatch"):
+            (self._cache, self._tables, self._lengths, self._active,
+             self._tokens, self._remaining, done) = self._step_jit(
+                self._params_dec, self._cache, self._tables, self._lengths,
+                self._active, self._tokens, self._remaining,
+            )
+        t1 = time.monotonic()
+        # host_span: the decode loop's D2H wait, as a tracer span and as
+        # host-blocked time for any open timeline capture window.
         with telemetry.timeline.host_span("engine.decode_fetch"):
             # mtlint: allow-host-sync(the decode loop's one intentional D2H: emitted tokens/done flags must reach the host to answer requests)
             nxt = np.asarray(self._tokens)
             done = np.asarray(done)  # mtlint: allow-host-sync(same fetch: part of the decode loop's one D2H)
+        t2 = time.monotonic()
+        _M_PHASE.observe(t1 - t0, phase="dispatch")
+        _M_PHASE.observe(t2 - t1, phase="fetch")
         emissions: Dict[int, int] = {}
         finished: List[int] = []
-        for s in np.nonzero(self._active_host)[0]:
-            tok = int(nxt[s])
-            emissions[int(s)] = tok
-            self._emitted[s].append(tok)
-            self._remaining_host[s] -= 1
-            if done[s]:
-                finished.append(int(s))
-                self._active_host[s] = False
-        self._stats["steps"] += 1
-        self._stats["decode_tokens"] += len(emissions)
-        _M_TOKENS.inc(len(emissions))
+        with telemetry.span("engine.step_host"):
+            for s in np.nonzero(self._active_host)[0]:
+                tok = int(nxt[s])
+                emissions[int(s)] = tok
+                self._emitted[s].append(tok)
+                self._remaining_host[s] -= 1
+                if done[s]:
+                    finished.append(int(s))
+                    self._active_host[s] = False
+            self._stats["steps"] += 1
+            self._stats["decode_tokens"] += len(emissions)
+            _M_TOKENS.inc(len(emissions))
         return emissions, finished
 
     def retire(self, slot: int) -> List[int]:

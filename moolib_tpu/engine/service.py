@@ -23,6 +23,7 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..rpc import Rpc
 from ..serving import (
     AdmissionController,
@@ -148,7 +149,11 @@ class EngineService(ServeService):
                 self._respond(req, None, f"generate failed: {e}")
                 answered += 1
                 continue
-            _M_PHASE.observe(time.monotonic() - t0, phase="prefill")
+            now = time.monotonic()
+            _M_PHASE.observe(now - t0, phase="prefill")
+            # Server-side time to first token: enqueue to the prefill's
+            # token on the host (queue wait included).
+            _M_PHASE.observe(now - req.t_enq, phase="first_token")
             if slot is None:
                 # Finished at prefill (budget 1 / immediate EOS).
                 self._count_answered(1)
@@ -177,50 +182,81 @@ class EngineService(ServeService):
         self._wake = asyncio.Event()
         served = 0
         eng = self._engine
+        # Start of the last pass that ran a decode step and left a slot
+        # waiting for its next token; the next pass's start closes the gap.
+        prev_start = None
         try:
             while not self._closed and (total is None or served < total):
-                with self._lock:
-                    self._maybe_swap_locked()
-                    self._sweep_done_locked(time.monotonic())
-                _joined, answered = self._admit_joins()
-                if not eng.active_count():
-                    if answered:
-                        served += answered
-                        continue
-                    try:
-                        await asyncio.wait_for(self._wake.wait(), timeout=0.05)
-                    except asyncio.TimeoutError:
-                        pass
-                    self._wake.clear()
-                    # Idle tick: let the serve_qps window close at zero and
-                    # the wait EMA decay, so the autoscaler's idle-shrink
-                    # signal sees true silence instead of the last busy
-                    # spell's frozen gauges.
-                    self._note_answered(0)
-                    if not self._queue:
-                        self._note_queue_wait(0.0)
+                if not eng.active_count() and not self._queue:
+                    with self._lock:
+                        self._maybe_swap_locked()
+                        self._sweep_done_locked(time.monotonic())
+                    await self._idle_tick()
                     continue
-                t0 = time.monotonic()
-                emissions, finished = eng.step()
-                dt = time.monotonic() - t0
-                if emissions:
-                    self.admission.note_service(dt, tokens=len(emissions))
-                    _M_PHASE.observe(dt, phase="device")
-                self._stats["iterations"] += 1
-                done = [(self._slot_req.pop(s), eng.retire(s))
-                        for s in finished]
-                if done:
-                    self._count_answered(len(done))
-                for req, toks in done:
-                    self._finish(req, toks)
-                served += answered + len(done)
-                # Yield so RPC callbacks and swap stagings interleave
-                # between decode steps.
-                await asyncio.sleep(0)
+                with telemetry.span("serve.iteration") as iteration:
+                    start = time.monotonic()
+                    if prev_start is not None:
+                        _M_PHASE.observe(start - prev_start, phase="iteration")
+                    with self._lock:
+                        self._maybe_swap_locked()
+                        self._sweep_done_locked(start)
+                    with telemetry.span("serve.admit"):
+                        joined, answered = self._admit_joins()
+                    served += answered
+                    active = eng.active_count()
+                    if active:
+                        finished = self._decode_and_reply()
+                        iteration.set(active=active, joined=joined,
+                                      finished=finished)
+                        served += finished
+                        # Yield so RPC callbacks and swap stagings interleave
+                        # between decode steps.
+                        await asyncio.sleep(0)
+                prev_start = start if active and eng.active_count() else None
+                if not active and not answered:
+                    # A queued request the engine cannot take yet.
+                    await self._idle_tick()
         finally:
-            self._loop = None
-            self._wake = None
+            with self._lock:
+                self._loop = None
+                self._wake = None
+            if self._closed:
+                self._fail_inflight()
         return self._stats["iterations"]
+
+    def _decode_and_reply(self) -> int:
+        """One decode step over the occupied slots, and the replies of those
+        that finished.  Returns how many requests it answered."""
+        eng = self._engine
+        t0 = time.monotonic()
+        emissions, finished = eng.step()
+        dt = time.monotonic() - t0
+        if emissions:
+            self.admission.note_service(dt, tokens=len(emissions))
+            _M_PHASE.observe(dt, phase="device")
+        self._stats["iterations"] += 1
+        if not finished:
+            return 0
+        with telemetry.span("serve.reply"):
+            done = [(self._slot_req.pop(s), eng.retire(s)) for s in finished]
+            self._count_answered(len(done))
+            for req, toks in done:
+                self._finish(req, toks)
+        return len(done)
+
+    async def _idle_tick(self) -> None:
+        """Nothing to decode: wait for a request (at most 50 ms), then let
+        the serve_qps window close at zero and the wait EMA decay, so the
+        autoscaler's idle-shrink signal sees true silence instead of the
+        last busy spell's frozen gauges."""
+        try:
+            await asyncio.wait_for(self._wake.wait(), timeout=0.05)
+        except asyncio.TimeoutError:
+            pass
+        self._wake.clear()
+        self._note_answered(0)
+        if not self._queue:
+            self._note_queue_wait(0.0)
 
     # ----------------------------------------------------------------- stats
     def stats(self):
@@ -230,10 +266,19 @@ class EngineService(ServeService):
         return out
 
     def close(self) -> None:
-        with self._lock:
-            inflight = dict(self._slot_req)
-            self._slot_req.clear()
+        """Safe from any thread.  While the service loop runs, the slot
+        table is its own: it sees ``_closed`` between two iterations and
+        answers the in-flight requests on its way out.  With no loop
+        running they are answered here."""
         super().close()
+        with self._lock:
+            running = self._loop is not None
+        if not running:
+            self._fail_inflight()
+
+    def _fail_inflight(self) -> None:
+        with self._lock:
+            inflight, self._slot_req = self._slot_req, {}
         for req in inflight.values():
             try:
                 self._respond(req, None, f"serve {self._name}: closed")

@@ -325,17 +325,19 @@ def train(flags, on_stats=None) -> dict:
             aux = 0.0
         # Next-token prediction, scored only where the answer is half a
         # sequence away: positions half-1 .. T-2 predict the repeated half.
-        pred = logits[:, half - 1 : -1]
-        tgt = tokens[:, half:]
-        logp = jax.nn.log_softmax(pred.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-        acc = (pred.argmax(-1) == tgt).mean()
-        return -ll.mean() + flags.moe_aux_weight * aux, acc
+        with jax.named_scope("lm_loss"):
+            pred = logits[:, half - 1 : -1]
+            tgt = tokens[:, half:]
+            logp = jax.nn.log_softmax(pred.astype(jnp.float32), axis=-1)
+            ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+            acc = (pred.argmax(-1) == tgt).mean()
+            return -ll.mean() + flags.moe_aux_weight * aux, acc
 
     def step(params, opt_state, tokens):
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, tokens)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss, acc
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss, acc
 
     # Durable state (docs/RESILIENCE.md): manifest-validated checkpoints;
     # resume picks the newest INTACT one (corruption costs one interval).
@@ -437,7 +439,8 @@ def train(flags, on_stats=None) -> dict:
                 params, opt_state, loss, acc = jstep(params, opt_state, tokens)
             steps_done = i + 1
             if steps_done % flags.log_interval == 0:
-                loss_v, acc_v = float(loss), float(acc)  # waits for the device
+                with timer.section("fetch_loss"):  # ends in a device wait
+                    loss_v, acc_v = float(loss), float(acc)
                 now = time.monotonic()
                 step_s = (now - tick_t) / (steps_done - tick_step)
                 tick_t, tick_step = now, steps_done

@@ -703,9 +703,8 @@ def train(flags, on_stats=None) -> dict:
     if flags.chunked:
         accumulator.set_chunked_allreduce(True)
     if flags.trace_dir:
-        # Trace the first seconds of training (compile + early steps); host
-        # spans mirror into the device trace while it runs.
-        telemetry.get_tracer().enable_jax_annotations(True)
+        # Trace the first seconds of training (compile + early steps); the
+        # tracer's host spans appear in it (telemetry/tracing.py).
         jax.profiler.start_trace(flags.trace_dir)
         trace_stop_at = time.monotonic() + 30.0
     else:
@@ -937,9 +936,6 @@ def train(flags, on_stats=None) -> dict:
             if trace_stop_at is not None and now > trace_stop_at:
                 trace_stop_at = None
                 jax.profiler.stop_trace()
-                # Stop paying per-span TraceAnnotation cost once no device
-                # trace is consuming the annotations.
-                telemetry.get_tracer().enable_jax_annotations(False)
                 print(f"profiler trace written to {flags.trace_dir}")
             if now - last_stats > flags.stats_interval:
                 last_stats = now
@@ -1224,7 +1220,6 @@ def train(flags, on_stats=None) -> dict:
                 jax.profiler.stop_trace()
             except Exception:
                 pass
-            telemetry.get_tracer().enable_jax_annotations(False)
         _signal.signal(_signal.SIGTERM, prev_sigterm)
         if dckpt is not None:
             s = dckpt.stats()

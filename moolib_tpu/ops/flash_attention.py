@@ -348,6 +348,7 @@ def _flash_bwd_dkv_kernel(
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@jax.named_scope("flash_attention")
 def _flash_backward(
     q, k, v, out, lse, g, g_lse, causal, block_q, block_k, interpret
 ):
@@ -548,6 +549,7 @@ def flash_attention(
     return _flash(q, k, v, causal, block_q, block_k, interpret)
 
 
+@jax.named_scope("flash_attention")
 def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
     B, Tq, H, D = q.shape
     Tk = k.shape[1]

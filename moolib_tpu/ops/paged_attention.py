@@ -87,6 +87,7 @@ def gathered_decode_attention(q, k_ctx, v_ctx, t):
     return att.reshape(B, T, H, hd).astype(q.dtype)
 
 
+@jax.named_scope("paged_attention")
 def paged_kv_write(pool, x, block_tables, lengths, active):
     """Scatter one new K (or V) row per slot into the block pool, in place.
 
@@ -104,6 +105,7 @@ def paged_kv_write(pool, x, block_tables, lengths, active):
     return pool.at[blk, off].set(x.astype(pool.dtype))
 
 
+@jax.named_scope("paged_attention")
 def paged_gather(pool, block_tables):
     """Gather each slot's blocks into a contiguous [S, T_ctx, Hk, hd] context
     (T_ctx = max_blocks_per_seq * block_size).  Positions past a slot's
@@ -113,6 +115,7 @@ def paged_gather(pool, block_tables):
     return ctx.reshape(S, nb * pool.shape[1], *pool.shape[2:])
 
 
+@jax.named_scope("paged_attention")
 def paged_attention(q, pool_k, pool_v, block_tables, lengths):
     """Decode attention against a paged KV pool: gather, then the shared
     grouped-query math.  q: [S, 1, H, hd]; returns [S, 1, H, hd]."""

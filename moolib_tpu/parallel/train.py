@@ -44,14 +44,17 @@ def _instrument_step(fn, name: Optional[str] = None):
         n = next(_STEP_SEQ)
         name = "parallel.train_step" + (f"#{n}" if n else "")
 
+    # Recompile detector (telemetry.devmon): a shape/dtype signature change
+    # here means XLA is retracing the train step mid-run.  Where ``fn`` is
+    # the jit itself the detector asks its cache (O(1) a call); a closure
+    # that builds its jit lazily has no cache to ask and pays for the
+    # signature at every call.  The wrapper also feeds the timeline capture
+    # windows' dispatch hook (the step anchors for overlap attribution).
+    step = devmon.instrument_jit(fn, name)
+
     def timed_step(*args, **kwargs):
-        # Recompile detector (telemetry.devmon): a shape/dtype signature
-        # change here means XLA is retracing the train step mid-run.
-        devmon.observe_call(name, args, kwargs)
-        # dispatch_span feeds the timeline capture windows (the step
-        # anchors for overlap/exposure attribution); free when none open.
-        with _M_DISPATCH.time(), devmon.dispatch_span(name):
-            out = fn(*args, **kwargs)
+        with _M_DISPATCH.time():
+            out = step(*args, **kwargs)
         _M_STEPS.inc()
         return out
 

@@ -135,7 +135,10 @@ _M_PHASE = _REG.histogram(
     "serve_phase_seconds",
     "per-request serve latency by phase: admission (handler entry -> "
     "enqueue), queue (enqueue -> batch take), batch_assembly (concat + "
-    "bucket pad), device (step_fn), reply (responses out)",
+    "bucket pad), device (step_fn), reply (responses out); engine arm: "
+    "prefill, first_token (enqueue -> first token on the host), iteration "
+    "(start of a decoding pass -> start of the next), dispatch and fetch "
+    "(per decode step)",
     labelnames=("phase",),
 )
 

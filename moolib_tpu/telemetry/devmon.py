@@ -7,7 +7,8 @@ registry (docs/TELEMETRY.md "Device performance plane"):
 - **Compile observability** — :func:`install_compile_listeners` subscribes
   to ``jax.monitoring`` (backend compile durations, persistent-cache
   hits/misses) and :func:`instrument_jit` wraps a jitted callable with a
-  recompile detector: every *new* abstract input signature increments
+  recompile detector that asks the jit's own cache whether a call compiled
+  (O(1) a call): every *new* abstract input signature increments
   ``jit_compiles_total{fn}``, and a signature change after the first compile
   emits one ``devmon.recompile`` flight event carrying the signature diff
   plus a stderr WARN — the dynamic counterpart of the static
@@ -50,7 +51,6 @@ from .flightrec import flight_event
 __all__ = [
     "StepCost",
     "compile_summary",
-    "dispatch_span",
     "install_compile_listeners",
     "install_from_env",
     "instrument_jit",
@@ -258,59 +258,48 @@ def set_dispatch_hook(hook) -> None:
     _dispatch_hook = hook
 
 
-class dispatch_span:
-    """Context manager equivalent of the `_InstrumentedJit` timing for call
-    sites that wrap their own dispatch (parallel/train.py's step closure):
-    feeds the dispatch hook when one is installed, otherwise free."""
-
-    __slots__ = ("_name", "_t0")
-
-    def __init__(self, name: str):
-        self._name = name
-        self._t0 = None
-
-    def __enter__(self):
-        if _dispatch_hook is not None:
-            self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        hook = _dispatch_hook
-        if hook is not None and self._t0 is not None:
-            try:
-                hook(self._name, self._t0, time.perf_counter_ns())
-            except Exception:  # noqa: BLE001 — listener must never break the step
-                pass
-        return False
+def _cache_size(fn) -> Optional[int]:
+    """How many programs a jit holds, or None for a callable that cannot say
+    (anything but ``jax.jit``'s own wrapper)."""
+    size = getattr(fn, "_cache_size", None)
+    return None if size is None else size()
 
 
 class _InstrumentedJit:
     """Callable wrapper around a jitted function that tracks abstract input
     signatures.  Attribute access (``lower``, ``_cache_size``, ...) forwards
-    to the wrapped jit so AOT paths and tests see the real object."""
+    to the wrapped jit so AOT paths and tests see the real object.
 
-    __slots__ = ("_fn", "_name")
+    A call costs two reads of the jit's cache size.  The signature (a
+    flatten of every argument and one string a leaf) is computed only when
+    this call compiled: the first call, and then whenever the cache grew,
+    from the arguments after the call (a donated array keeps its shape and
+    dtype).  A wrapped callable that has no ``_cache_size`` pays for the
+    signature at every call, as before."""
+
+    __slots__ = ("_fn", "_name", "_primed")
 
     def __init__(self, fn, name: str):
         self._fn = fn
         self._name = name
+        self._primed = False
 
     def __call__(self, *args, **kwargs):
-        try:
-            record_signature(self._name, _signature(args, kwargs))
-        except Exception:  # noqa: BLE001 — accounting must never break the step
-            pass
+        before = _cache_size(self._fn)
         hook = _dispatch_hook
-        if hook is None:
-            return self._fn(*args, **kwargs)
-        t0 = time.perf_counter_ns()
+        t0 = time.perf_counter_ns() if hook is not None else 0
         try:
-            return self._fn(*args, **kwargs)
+            out = self._fn(*args, **kwargs)
         finally:
-            try:
-                hook(self._name, t0, time.perf_counter_ns())
-            except Exception:  # noqa: BLE001 — listener must never break the step
-                pass
+            if hook is not None:
+                try:
+                    hook(self._name, t0, time.perf_counter_ns())
+                except Exception:  # noqa: BLE001 — listener must never break the step
+                    pass
+        if not self._primed or before is None or _cache_size(self._fn) != before:
+            self._primed = True
+            observe_call(self._name, args, kwargs)
+        return out
 
     def __getattr__(self, item):
         return getattr(self._fn, item)

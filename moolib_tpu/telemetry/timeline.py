@@ -644,17 +644,20 @@ def on_dispatch(name: str, t0_ns: int, t1_ns: int) -> None:
 
 
 class _PhaseSpan:
-    """Records (name, t0_ns, t1_ns) into the active window's comm/host
-    list; near-free when no window is open (one unlocked None check)."""
+    """One interval, one ``with``: a span of the same name in the tracer
+    (and through it in the profiler's trace), and while a capture window is
+    open also (name, t0_ns, t1_ns) in that window's comm/host list."""
 
-    __slots__ = ("_name", "_kind", "_t0")
+    __slots__ = ("_name", "_kind", "_t0", "_span")
 
     def __init__(self, name: str, kind: str):
         self._name = name
         self._kind = kind
         self._t0: Optional[int] = None
+        self._span = tracing.span(name)
 
     def __enter__(self):
+        self._span.__enter__()
         if _state["window"] is not None:
             self._t0 = time.perf_counter_ns()
         return self
@@ -666,19 +669,20 @@ class _PhaseSpan:
                 w = _state["window"]
                 if w is not None:
                     w[self._kind].append((self._name, self._t0, t1))
-        return False
+        return self._span.__exit__(*exc)
 
 
 def comm_span(name: str) -> "_PhaseSpan":
     """Mark the body as host-side collective communication for the active
-    timeline window (accumulator share-down, in-mesh redistribute).  A
-    no-op outside windows, so call sites wire it unconditionally."""
+    timeline window (accumulator share-down, in-mesh redistribute), and
+    record a tracer span of that name."""
     return _PhaseSpan(name, "comm")
 
 
 def host_span(name: str) -> "_PhaseSpan":
     """Mark the body as host-blocked device interaction (D2H fetch, infeed
-    wait) for the active timeline window.  No-op outside windows."""
+    wait) for the active timeline window, and record a tracer span of that
+    name."""
     return _PhaseSpan(name, "host")
 
 

@@ -4,16 +4,16 @@
 tracer owns the *host* side: nested spans around the train loop's act/learn/
 reduce phases, RPC rounds, env waits.  Spans export as Chrome trace-event
 JSON (``chrome://tracing`` / Perfetto "Complete" events), so a host trace
-can sit next to a ``jax.profiler`` capture — and when a jax trace is active
-and annotations are enabled, each span also enters a
-``jax.profiler.TraceAnnotation`` so the same names appear inside the device
-timeline (the merge path :func:`moolib_tpu.utils.profiling.annotate`
-documents).
+can sit next to a ``jax.profiler`` capture — and wherever jax is already
+imported each span also enters a ``jax.profiler.TraceAnnotation``, so a
+``jax.profiler.start_trace`` from anyone in the process (an operator's
+SIGUSR2 profile, a benchmark's traced window) shows the program's spans on
+the device events' clock with no call into the program.
 
 Recording is bounded (a ring of the newest ``capacity`` spans) and cheap:
 one ``perf_counter_ns`` pair plus a deque append per span; nesting depth is
-tracked per-thread with no locks on the hot path.  Stdlib only unless
-annotations are switched on.
+tracked per-thread with no locks on the hot path.  Stdlib only: jax is
+looked up in ``sys.modules`` and never imported from here.
 
 Distributed tracing
 -------------------
@@ -264,6 +264,11 @@ class _ActiveSpan:
         """This span's TraceContext while open (``None`` when untraced)."""
         return self._ctx
 
+    def set(self, **args) -> None:
+        """Attach args that are known only once the body has run (how many
+        requests an iteration joined); they land on the recorded span."""
+        self._args = {**(self._args or {}), **args}
+
     def __enter__(self):
         if self._mode == _ROOT:
             self._ctx = TraceContext(new_trace_id(), new_span_id())
@@ -337,7 +342,7 @@ class Tracer:
 
     def __init__(self, capacity: int = 65536):
         self._spans: deque = deque(maxlen=capacity)
-        self._annotate = False
+        self._annotate = True
         # Anchor pairing the monotonic span clock to wall time, captured
         # once: lets trace_merge rebase every process onto one unix-time
         # axis (perf_counter origins are arbitrary per process).
@@ -413,8 +418,8 @@ class Tracer:
 
     def enable_jax_annotations(self, enabled: bool = True) -> None:
         """Mirror every span into ``jax.profiler.TraceAnnotation`` so host
-        phases appear inside device traces.  Off by default: creating an
-        annotation per span costs even when no device trace is running."""
+        phases appear inside device traces.  On by default wherever jax is
+        imported; ``False`` is for a loop that would flood a trace."""
         self._annotate = bool(enabled)
 
     def clear(self) -> None:

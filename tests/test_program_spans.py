@@ -1,0 +1,359 @@
+"""The program's own spans and counters (docs/TELEMETRY.md "Program spans"):
+what one engine iteration and one train step record, that the tracer mirrors
+them into the profiler wherever jax is imported, that the recompile detector
+stays off the per-call path, the named scopes in the compiled HLO, and
+``EngineService.close`` from a second thread."""
+
+import asyncio
+import sys
+import threading
+import types
+
+import numpy as np
+import pytest
+
+from moolib_tpu import telemetry
+from moolib_tpu.telemetry import devmon, timeline, tracing
+
+ITERATION_TREE = {
+    "serve.iteration": None,
+    "serve.admit": "serve.iteration",
+    "engine.submit": "serve.admit",
+    "engine.prefill_dispatch": "engine.submit",
+    "engine.first_token_fetch": "engine.submit",
+    "engine.join": "engine.submit",
+    "engine.step": "serve.iteration",
+    "engine.step_dispatch": "engine.step",
+    "engine.decode_fetch": "engine.step",
+    "engine.step_host": "engine.step",
+    "serve.reply": "serve.iteration",
+}
+
+
+# ------------------------------------------------------------ tracer -> jax
+class _FakeAnnotation:
+    def __init__(self, log, name):
+        self.log, self.name = log, name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.mark.parametrize("jax_imported", [True, False])
+def test_spans_are_mirrored_where_jax_is_imported(monkeypatch, jax_imported):
+    log = []
+    if jax_imported:
+        fake = types.SimpleNamespace(profiler=types.SimpleNamespace(
+            TraceAnnotation=lambda name: _FakeAnnotation(log, name)))
+        monkeypatch.setitem(sys.modules, "jax", fake)
+    else:
+        monkeypatch.delitem(sys.modules, "jax", raising=False)
+    tracer = tracing.Tracer()  # the default: nobody switched anything on
+    with tracer.span("outer"):
+        with tracer.span("inner"):
+            pass
+    assert [s.name for s in tracer.spans()] == ["inner", "outer"]
+    if jax_imported:
+        assert log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("exit", "outer")]
+    else:
+        assert log == [] and "jax" not in sys.modules  # never imported from here
+
+
+@pytest.mark.parametrize("make", [timeline.host_span, timeline.comm_span])
+def test_timeline_phase_span_records_a_tracer_span(make):
+    telemetry.get_tracer().clear()
+    with make("t.phase"):
+        pass
+    assert [s.name for s in telemetry.get_tracer().spans()] == ["t.phase"]
+
+
+# ------------------------------------------------------- recompile detector
+def test_instrumented_jit_computes_no_signature_on_a_repeat_call(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    devmon.reset_for_tests()
+    telemetry.get_flight_recorder().clear()
+    calls = []
+    real = devmon._signature
+    monkeypatch.setattr(devmon, "_signature",
+                        lambda a, k: calls.append(1) or real(a, k))
+    f = devmon.instrument_jit(jax.jit(lambda x: x * 2, donate_argnums=(0,)), "t.o1")
+    f(jnp.ones((4, 4)))
+    assert len(calls) == 1  # the first call compiled
+    for _ in range(3):
+        f(jnp.ones((4, 4)))
+    assert len(calls) == 1  # a repeat call: two reads of the jit's cache size
+    f(jnp.ones((8, 4)))
+    assert len(calls) == 2  # the cache grew: signature, diff, event
+    events = [a for _t, n, a in telemetry.get_flight_recorder().events()
+              if n == "devmon.recompile"]
+    assert len(events) == 1 and events[0]["fn"] == "t.o1"
+    assert "(4, 4)/float32 -> (8, 4)/float32" in events[0]["diff"]
+    f(jnp.ones((4, 4)))  # back to a seen signature: served from the cache
+    assert len(calls) == 2
+    devmon.reset_for_tests()
+
+
+def test_a_callable_without_a_cache_still_gets_its_signature_every_call(monkeypatch):
+    devmon.reset_for_tests()
+    calls = []
+    real = devmon._signature
+    monkeypatch.setattr(devmon, "_signature",
+                        lambda a, k: calls.append(1) or real(a, k))
+    f = devmon.instrument_jit(lambda x: x, "t.closure2")
+    f(np.ones(3))
+    f(np.ones(3))
+    assert len(calls) == 2
+    devmon.reset_for_tests()
+
+
+# --------------------------------------------------------- engine iteration
+class _Ret:
+    """What the RPC layer hands a deferred handler: call it with the value,
+    or ``.error`` with a message.  Counts answers."""
+
+    def __init__(self):
+        self.answers = []
+
+    def __call__(self, value):
+        self.answers.append(("ok", value))
+
+    def error(self, message):
+        self.answers.append(("error", message))
+
+
+class _Rpc:
+    def define_deferred(self, name, fn):
+        pass
+
+    define = define_deferred
+
+    def undefine(self, name):
+        pass
+
+
+def _service(slots=3):
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.engine import ContinuousBatchingEngine, EngineService
+    from moolib_tpu.models.transformer import TransformerLM
+
+    model = TransformerLM(vocab_size=64, d_model=32, num_heads=4, num_kv_heads=2,
+                          num_layers=2, max_len=64, attention="dense",
+                          dtype=jnp.float32, pos_embedding="rotary")
+    params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    engine = ContinuousBatchingEngine(model, params, slots=slots, block_size=4,
+                                      max_seq_len=64, max_prompt_len=8)
+    return EngineService(_Rpc(), engine, default_max_new=4)
+
+
+def _phase_counts():
+    family = telemetry.get_registry().snapshot().get("serve_phase_seconds", {"series": []})
+    return {s["labels"]["phase"]: s["value"]["count"] for s in family["series"]}
+
+
+@pytest.fixture(scope="module")
+def one_busy_spell():
+    """Two requests (budgets 3 and 5) queued before the loop starts: one
+    pass admits both, then four decode steps; the tracer's spans, the phase
+    counts, and every ``serve_phase_seconds`` observation in order."""
+    service = _service()
+    rets = [_Ret(), _Ret()]
+    prompt = np.arange(1, 6, dtype=np.int32)
+    for ret, budget in zip(rets, (3, 5)):
+        service._on_request(ret, prompt, budget)
+    from moolib_tpu.engine import service as service_mod
+
+    observed = []
+    real = service_mod._M_PHASE.observe
+    service_mod._M_PHASE.observe = lambda v, **kw: observed.append((kw["phase"], v)) or real(v, **kw)
+    telemetry.get_tracer().clear()
+    before = _phase_counts()
+    try:
+        iterations = asyncio.run(asyncio.wait_for(service.loop(total=2), 120))
+    finally:
+        del service_mod._M_PHASE.observe
+    after = _phase_counts()
+    return {"iterations": iterations, "rets": rets, "observed": observed,
+            "spans": telemetry.get_tracer().spans(),
+            "counts": {k: v - before.get(k, 0) for k, v in after.items()}}
+
+
+def _parent(span, spans):
+    """The innermost span of the same thread that contains this one."""
+    around = [p for p in spans if p is not span and p.tid == span.tid
+              and p.start_ns <= span.start_ns
+              and p.start_ns + p.dur_ns >= span.start_ns + span.dur_ns]
+    return max(around, key=lambda p: p.start_ns).name if around else None
+
+
+def test_one_engine_iteration_yields_the_span_tree(one_busy_spell):
+    spans = [s for s in one_busy_spell["spans"] if s.name in ITERATION_TREE]
+    assert one_busy_spell["iterations"] == 4
+    assert all(len(r.answers) == 1 and r.answers[0][0] == "ok"
+               for r in one_busy_spell["rets"])
+    assert {s.name for s in spans} == set(ITERATION_TREE)
+    for s in spans:
+        assert _parent(s, spans) == ITERATION_TREE[s.name], s.name
+    count = lambda name: sum(s.name == name for s in spans)
+    assert count("serve.iteration") == count("engine.step") == 4
+    assert count("engine.submit") == 2 and count("serve.reply") == 2
+    first = min((s for s in spans if s.name == "serve.iteration"), key=lambda s: s.start_ns)
+    assert first.args == {"active": 2, "joined": 2, "finished": 0}
+
+
+def test_new_serve_phases_count_per_iteration_request_and_step(one_busy_spell):
+    counts = one_busy_spell["counts"]
+    assert counts["dispatch"] == counts["fetch"] == counts["device"] == 4  # per step
+    assert counts["first_token"] == counts["prefill"] == counts["queue"] == 2  # per request
+    # per gap between two passes that decoded while a slot still waited
+    assert counts["iteration"] == 3
+
+
+def test_first_token_is_never_under_queue_wait(one_busy_spell):
+    by_phase = lambda p: [v for phase, v in one_busy_spell["observed"] if phase == p]
+    queue, first = by_phase("queue"), by_phase("first_token")
+    assert len(queue) == len(first) == 2
+    assert all(f >= q for q, f in zip(queue, first))
+
+
+def test_close_from_a_second_thread_mid_loop():
+    """``close`` runs to its end on a second thread while the loop's thread
+    is inside an iteration whose step finishes slots (at PR 23 the loop then
+    died of a ``KeyError`` in ``_slot_req.pop``).  The slot table is the
+    loop's own: it answers the finished requests, then the rest "closed"."""
+    service = _service(slots=3)
+    rets = [_Ret() for _ in range(12)]
+    prompt = np.arange(1, 5, dtype=np.int32)
+    for ret in rets:
+        service._on_request(ret, prompt, 2)  # one decode step each: every step finishes slots
+    engine_step = service._engine.step
+
+    def step_then_close():
+        out = engine_step()
+        if service._stats["iterations"] == 1:  # the second iteration is in flight
+            closer = threading.Thread(target=service.close)
+            closer.start()
+            closer.join(timeout=30)
+            assert not closer.is_alive()
+        return out
+
+    service._engine.step = step_then_close
+    asyncio.run(asyncio.wait_for(service.loop(), 120))
+    assert service._slot_req == {}
+    assert all(len(r.answers) == 1 for r in rets)
+    kinds = [r.answers[0][0] for r in rets]
+    assert kinds[:6] == ["ok"] * 6  # two iterations of three slots were served
+    assert all(k == "error" and "closed" in v for k, v in (r.answers[0] for r in rets[6:]))
+    late = _Ret()
+    service._on_request(late, prompt, 2)
+    assert late.answers == [("error", "serve generate: closed")]
+    # With no loop running, close itself answers what is in flight.
+    idle = _service(slots=3)
+    waiting = _Ret()
+    idle._on_request(waiting, prompt, 4)
+    idle._admit_joins()
+    idle.close()
+    assert waiting.answers == [("error", "serve generate: closed")] and idle._slot_req == {}
+
+
+# ------------------------------------------------------- train loop, scopes
+@pytest.fixture(scope="module")
+def tiny_train():
+    """Two steps of ``lm.train`` at a toy size, with its jitted step and the
+    arguments of its first call kept for lowering."""
+    from moolib_tpu.examples import lm
+
+    kept = {}
+    real = devmon.instrument_jit
+
+    def keep(fn, name):
+        wrapped = real(fn, name)
+        if name != "lm.step":
+            return wrapped
+
+        def call(*args):
+            kept.setdefault("args", args)
+            return wrapped(*args)
+
+        kept["jit"] = fn
+        call.lower = fn.lower  # devmon.step_cost lowers through the wrapper
+        return call
+
+    flags = lm.make_flags([
+        "--vocab", "32", "--d_model", "32", "--heads", "2", "--layers", "1",
+        "--seq_len", "16", "--batch_size", "2", "--attention", "flash",
+        "--steps", "2", "--log_interval", "1", "--quiet"])
+    telemetry.get_tracer().clear()
+    devmon.instrument_jit = keep
+    try:
+        lm.train(flags)
+    finally:
+        devmon.instrument_jit = real
+    kept["spans"] = [s.name for s in telemetry.get_tracer().spans()]
+    return kept
+
+
+def test_train_loop_records_its_three_sections(tiny_train):
+    for name in ("make_batch", "train_step", "fetch_loss"):
+        assert tiny_train["spans"].count(name) == 2, name
+    family = telemetry.get_registry().snapshot()["loop_section_seconds"]
+    sections = {s["labels"]["section"] for s in family["series"]}
+    assert {"make_batch", "train_step", "fetch_loss"} <= sections
+
+
+def _op_names(text):
+    return [line.split('op_name="', 1)[1].split('"', 1)[0]
+            for line in text.splitlines() if 'op_name="' in line]
+
+
+def _paged_text():
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.ops import paged_attention as pa
+
+    def step(pool, x, q, tables, lengths, active):
+        pool = pa.paged_kv_write(pool, x, tables, lengths, active)
+        return pa.paged_attention(q, pool, pool, tables, lengths)
+
+    pool = jnp.zeros((5, 4, 2, 8))
+    args = (pool, jnp.ones((2, 2, 8)), jnp.ones((2, 1, 4, 8)),
+            jnp.array([[1, 2], [3, 4]], jnp.int32), jnp.array([3, 5], jnp.int32),
+            jnp.array([True, True]))
+    return jax.jit(step).lower(*args).compile().as_text()
+
+
+def _flash_text():
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.ones((1, 128, 1, 128), jnp.float32)
+    loss = lambda q, k, v: flash_attention(q, k, v, causal=True).sum()
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile().as_text()
+
+
+@pytest.mark.parametrize("scope,backward", [
+    ("paged_attention", False), ("flash_attention", True),
+    ("lm_loss", True), ("optimizer", False)])
+def test_named_scope_is_in_the_compiled_op_names(scope, backward, request):
+    if scope == "paged_attention":
+        text = _paged_text()
+    elif scope == "flash_attention":
+        text = _flash_text()
+    else:
+        kept = request.getfixturevalue("tiny_train")
+        text = kept["jit"].lower(*kept["args"]).compile().as_text()
+    names = [n for n in _op_names(text) if scope in n]
+    assert names, f"no operation of the compiled program is named under {scope!r}"
+    if backward:
+        assert any("transpose(" not in n for n in names)
+        assert any("transpose(" in n and scope in n.split("transpose(", 1)[1] for n in names)

@@ -74,6 +74,13 @@ _M_OCC = _REG.gauge(
 _M_BLOCKS_FREE = _REG.gauge(
     "serve_engine_blocks_free", "KV pool blocks on the free list"
 )
+_M_KV_LIVE = _REG.histogram(
+    "serve_engine_kv_live_share",
+    "per decode step: KV blocks holding a position the step attends over "
+    "(active slots only), over slots x max_blocks_per_seq — the share of the "
+    "capacity that the paged attention kernel reads",
+    buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0),
+)
 
 
 class NoFreeSlot(RuntimeError):
@@ -142,6 +149,7 @@ class ContinuousBatchingEngine:
         # rest decode; K/V cross through the device-path Batcher (counted
         # d2d, no host bounce).
         self._prefill_sharding = self._decode_sharding = None
+        self._decode_mesh = None  # handed to the paged kernel's shard_map
         self._xfer = None
         if mesh is not None and prefill_devices:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -150,6 +158,7 @@ class ContinuousBatchingEngine:
             from ..parallel.mesh import split_mesh
 
             pmesh, dmesh = split_mesh(mesh, prefill_devices)
+            self._decode_mesh = dmesh
             self._prefill_sharding = NamedSharding(pmesh, PartitionSpec())
             self._decode_sharding = NamedSharding(dmesh, PartitionSpec())
             self._xfer = Batcher(1, device=self._decode_sharding,
@@ -177,6 +186,7 @@ class ContinuousBatchingEngine:
         self._slot_blocks: List[List[int]] = [[] for _ in range(S)]
         self._emitted: List[List[int]] = [[] for _ in range(S)]
         self._remaining_host = np.zeros(S, np.int64)
+        self._lengths_host = np.zeros(S, np.int64)
         self._active_host = np.zeros(S, bool)
         self._stats = {
             "joins": 0, "retires": 0, "decode_tokens": 0,
@@ -225,6 +235,7 @@ class ContinuousBatchingEngine:
         logits, upd = self._dec.apply(
             {"params": params["params"], "cache": cache},
             tokens[:, None],
+            mesh=self._decode_mesh,
             paged=PagedState(tables, lengths, active),
             mutable=["cache"],
         )
@@ -382,6 +393,7 @@ class ContinuousBatchingEngine:
         self._slot_blocks[slot] = block_ids
         self._emitted[slot] = emitted
         self._remaining_host[slot] = max_new - 1
+        self._lengths_host[slot] = tp
         self._active_host[slot] = True
         self._stats["joins"] += 1
         _M_JOINS.inc()
@@ -418,7 +430,12 @@ class ContinuousBatchingEngine:
         emissions: Dict[int, int] = {}
         finished: List[int] = []
         with telemetry.span("engine.step_host"):
-            for s in np.nonzero(self._active_host)[0]:
+            stepped = np.nonzero(self._active_host)[0]
+            # The step attended over positions <= length in each active slot.
+            live = int((self._lengths_host[stepped] // self.block_size + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
+            _M_KV_LIVE.observe(live / (self.slots * self.max_blocks_per_seq))
+            self._lengths_host[stepped] += 1
+            for s in stepped:
                 tok = int(nxt[s])
                 emissions[int(s)] = tok
                 self._emitted[s].append(tok)

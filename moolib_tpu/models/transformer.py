@@ -136,7 +136,8 @@ class Block(nn.Module):
                 # Paged layout: K/V live in a pool shared by all decode
                 # slots; each slot addresses its blocks through the block
                 # table in ``paged``.  Same math as the dense branch below
-                # (both call gathered_decode_attention), different storage.
+                # (gathered_decode_attention defines it), computed by a
+                # fused kernel that reads only the live blocks.
                 if paged is None:
                     raise ValueError("kv_num_blocks > 0 needs paged= at apply time")
                 pk = self.variable(
@@ -158,7 +159,8 @@ class Block(nn.Module):
                     pv.value, v[:, 0], paged.block_tables, t, paged.active
                 )
                 att = paged_attention(
-                    q, pk.value, pv.value, paged.block_tables, t
+                    q, pk.value, pv.value, paged.block_tables, t, paged.active,
+                    mesh=mesh,
                 ).astype(x.dtype)
             else:
                 ck = self.variable(

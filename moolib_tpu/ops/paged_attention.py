@@ -1,21 +1,44 @@
-"""Paged KV-cache decode attention (block-table gather).
+"""Paged KV-cache decode attention (a fused kernel over the block pool).
 
 The serving engine's KV layout: instead of one dense ``[B, max_len, Hk, hd]``
 cache per sequence, K/V live in a shared device-resident pool of fixed-size
 token blocks ``[num_blocks, block_size, Hk, hd]`` and each decode *slot* owns
-an int32 row of block ids (its block table).  Attention gathers the slot's
-blocks back into a contiguous context and runs the exact same grouped-query
-math as the dense ``decode=True`` path in ``models.transformer.Block`` — the
-shared function :func:`gathered_decode_attention` is called by BOTH paths, so
-paged decode is bit-identical to the dense cache whenever the gathered context
-length equals the dense ``max_len`` (tests/test_paged_attention.py pins this).
+an int32 row of block ids (its block table).
 
-Why a gather kernel and not a fused pallas kernel: decode attention at serve
-batch sizes is bandwidth-bound on the KV pool read either way; the XLA gather
-lowers to the same HBM traffic on TPU and runs unmodified on CPU, which is
-where tier-1 CI executes.  The layout (pool + block tables + per-slot
-lengths) is exactly what a fused kernel would take, so one can slot in later
-without touching the engine.
+:func:`paged_attention` is one Pallas (Mosaic) kernel that reads the pool in
+place.  The block tables and lengths sit in SMEM; per slot the kernel copies
+only the blocks that hold live positions (``<= lengths[s]``) from HBM into a
+double-buffered VMEM window, some 16 blocks at a time, while the previous
+window is being reduced, and folds them into an online softmax (running
+maximum, sum and weighted sum in float32).  So a decode step costs the live
+blocks, not ``slots x max_blocks_per_seq``: an inactive slot is skipped (it
+reads nothing and gives 0) and a dead table entry is never dereferenced.  Nothing of the size of
+the gathered context exists in HBM.
+
+The mathematics is :func:`gathered_decode_attention`'s, which stays the one
+definition of it: the dense ``decode=True`` path of ``models.transformer.Block``
+calls it directly, and ``tests/test_paged_attention.py`` holds the kernel to it
+over a gathered context (:func:`paged_gather`).  Precision: K and V enter the
+products as stored (bfloat16 widened exactly to float32 on the VPU), the sums,
+the softmax statistics and the weights are float32 — what the XLA path gave at
+default matmul precision or tighter, but in another order of summation, so
+paged and dense decode agree to rounding and no longer bit for bit.
+
+Why the VPU and the pool's layout as it is: a block is one contiguous 64 KB
+copy, and a token's ``[Hk, hd]`` slice is exactly one (16, 128) bfloat16 tile,
+so scores are a multiply and a lane reduction over whole tiles with every KV
+head in flight at once and the softmax statistics are ``[Hk, 1]`` vectors; at
+one query position per slot the MXU would be fed a single row.  Where ``Hk``
+does not fill a tile (grouped and multi-query attention) consecutive tokens
+of a block are folded into the head axis, so that the kernel sees full tiles,
+and the partial softmaxes of a head's fold are merged after the kernel.
+
+Like ``ops.flash_attention`` the kernel lowers through Mosaic on ``tpu`` and
+runs in Pallas interpret mode on ``cpu`` (where tier-1 CI executes); any other
+platform raises.  One shape Mosaic refuses: a head size that is not a multiple
+of the 128 lanes (a block cannot be copied out of a lane-padded pool).  Such a
+call is traced onto :func:`gathered_decode_attention` over the XLA gather of
+the whole capacity, and counted in ``paged_gather_reroutes_total``.
 
 Block id 0 is the *null block*: never handed out by the allocator, and the
 write path redirects inactive slots' scatters at it, so a fixed-shape jitted
@@ -24,12 +47,25 @@ step over all S slots never branches on occupancy.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .. import telemetry
 
 _NEG_INF = -1e30
+
+_M_GATHER_REROUTES = telemetry.get_registry().counter(
+    "paged_gather_reroutes_total",
+    "paged_attention calls traced onto the XLA gather path (cost of the whole "
+    "slot capacity) because the head size is not a multiple of the 128 lanes "
+    "a Mosaic copy of a pool block needs",
+)
 
 
 class PagedState(NamedTuple):
@@ -41,8 +77,9 @@ class PagedState(NamedTuple):
         current step writes at position ``lengths`` and attends over
         ``<= lengths`` (the just-written token included).
     active: bool [S] — occupied slots.  Inactive slots still execute the
-        step (fixed shape); their writes land in the null block and their
-        outputs are ignored by the engine.
+        step (fixed shape); their writes land in the null block, the
+        attention kernel skips them, and their outputs are ignored by the
+        engine.
     """
 
     block_tables: jax.Array
@@ -57,9 +94,9 @@ def gathered_decode_attention(q, k_ctx, v_ctx, t):
     f32 here, like the dense path); t: scalar or [B] int — attend over
     positions ``<= t`` (everything past t contributes exactly 0: the -1e30
     masked scores underflow to 0 in the f32 softmax).  This is the one
-    definition of the decode-attention math; the dense ``decode=True`` branch
-    and the paged gather path both call it, which is what makes the two
-    cache layouts bit-exact against each other.
+    definition of the decode-attention math: the dense ``decode=True`` branch
+    calls it, and the paged kernel is tested against it over a gathered
+    context (and reroutes onto it for a head size Mosaic cannot copy).
     """
     B, T, H, hd = q.shape
     Hk = k_ctx.shape[2]
@@ -115,10 +152,260 @@ def paged_gather(pool, block_tables):
     return ctx.reshape(S, nb * pool.shape[1], *pool.shape[2:])
 
 
+def _paged_kernel(
+    order_ref, count_ref, tables_ref, lengths_ref, q_ref, pool_k_ref, pool_v_ref,
+    o_ref, lse_ref, k_buf, v_buf, sem, *, pages, fold, kv_heads,
+):
+    """All slots of one layer's decode attention.  In SMEM: order [S] (the
+    active slots first), count [1] (how many are active), tables [S, MB] and
+    lengths [S]; q [S, group, R, hd] in VMEM, scaled, float32; the pools
+    [NB, rows, R, hd] stay in HBM (R = fold * Hk: ``fold`` consecutive tokens
+    of a block ride in the head axis, ``rows * fold`` tokens a block);
+    k_buf/v_buf [2, pages, rows, R, hd] are the two VMEM windows."""
+    MB = tables_ref.shape[1]
+    group = q_ref.shape[1]
+    rows, R, hd = k_buf.shape[2:]
+    block_size = rows * fold
+
+    def live_blocks(s):
+        # At least one: the copies are chained from window to window, and a
+        # slot with none would break the chain.
+        return jnp.clip(lengths_ref[s] // block_size + 1, 1, MB)
+
+    def window_pages(s, w):
+        return jnp.minimum(live_blocks(s) - w * pages, pages)
+
+    def for_each_copy(s, w, buf, do):
+        """``do`` the copy of K and of V for every live block of window w of
+        slot s; each window's copies share a semaphore, so starting them and
+        waiting for them walk the same descriptors."""
+
+        def body(p, carry):
+            blk = tables_ref[s, w * pages + p]
+            do(pltpu.make_async_copy(pool_k_ref.at[blk], k_buf.at[buf, p], sem.at[0, buf]))
+            do(pltpu.make_async_copy(pool_v_ref.at[blk], v_buf.at[buf, p], sem.at[1, buf]))
+            return carry
+
+        jax.lax.fori_loop(0, window_pages(s, w), body, 0)
+
+    def start(s, w, buf):
+        for_each_copy(s, w, buf, lambda copy: copy.start())
+
+    def wait(s, w, buf):
+        for_each_copy(s, w, buf, lambda copy: copy.wait())
+
+    # Position of an element of a block inside the block: row * fold + the
+    # token of the fold its head-axis index belongs to.
+    offset = jax.lax.broadcasted_iota(jnp.int32, (rows, R, 1), 0) * fold
+    if fold > 1:
+        offset += jax.lax.broadcasted_iota(jnp.int32, (rows, R, 1), 1) // kv_heads
+
+    # Slots the loop never visits (inactive ones) read nothing and give 0.
+    o_ref[...] = jnp.zeros_like(o_ref)
+    lse_ref[...] = jnp.zeros_like(lse_ref)
+    count = count_ref[0]
+
+    @pl.when(count > 0)
+    def _():
+        start(order_ref[0], 0, 0)
+
+    def slot_body(i, n_windows_done):
+        s = order_ref[i]
+        length = lengths_ref[s]
+        n_windows = pl.cdiv(live_blocks(s), pages)
+        qs = [q_ref[s, g] for g in range(group)]  # each [R, hd]
+
+        def window_body(w, carry):
+            n_done, stats = carry
+            buf = n_done % 2
+            # The window after this one, which may be the next active slot's
+            # first (each has one: position 0 is always attended).
+            last = w + 1 >= n_windows
+            i_next = jnp.where(last, i + 1, i)
+            w_next = jnp.where(last, 0, w + 1)
+
+            @pl.when(i_next < count)
+            def _():
+                start(order_ref[i_next], w_next, 1 - buf)
+
+            wait(s, w, buf)
+
+            def page_body(p, stats):
+                k = k_buf[buf, p].astype(jnp.float32)  # [rows, R, hd]
+                v = v_buf[buf, p].astype(jnp.float32)
+                mask = (w * pages + p) * block_size + offset <= length
+                # The tail of the last live block is stale pool contents:
+                # a weight of 0 does not silence a NaN there, a select does.
+                v = jnp.where(mask, v, 0.0)
+                out = []
+                for g in range(group):
+                    m, l, acc = stats[g]
+                    sc = jnp.sum(k * qs[g][None], axis=-1, keepdims=True)
+                    sc = jnp.where(mask, sc, _NEG_INF)  # [rows, R, 1]
+                    m_new = jnp.maximum(m, jnp.max(sc, axis=0))  # [R, 1]
+                    corr = jnp.exp(m - m_new)
+                    p_att = jnp.exp(sc - m_new[None])
+                    l = l * corr + jnp.sum(p_att, axis=0)
+                    acc = acc * corr + jnp.sum(p_att * v, axis=0)  # [R, hd]
+                    out.append((m_new, l, acc))
+                return tuple(out)
+
+            stats = jax.lax.fori_loop(0, window_pages(s, w), page_body, stats)
+            return n_done + 1, stats
+
+        init = tuple(
+            (
+                jnp.full((R, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((R, 1), jnp.float32),
+                jnp.zeros((R, hd), jnp.float32),
+            )
+            for _ in range(group)
+        )
+        n_windows_done, stats = jax.lax.fori_loop(
+            0, n_windows, window_body, (n_windows_done, init)
+        )
+        for g, (m, l, acc) in enumerate(stats):
+            # A fold whose every position is masked has m = -1e30 and l > 0
+            # (exp(0) per masked entry) over acc = 0: its logsumexp weighs
+            # it out of the merge.
+            o_ref[s, g] = acc / l
+            lse_ref[s, g] = jnp.broadcast_to(m + jnp.log(l), (R, lse_ref.shape[-1]))
+        return n_windows_done
+
+    jax.lax.fori_loop(0, count, slot_body, 0)
+
+
+# One VMEM window of K (and one of V; two of each are held).
+_WINDOW_BYTES = 1 << 20
+
+
 @jax.named_scope("paged_attention")
-def paged_attention(q, pool_k, pool_v, block_tables, lengths):
-    """Decode attention against a paged KV pool: gather, then the shared
-    grouped-query math.  q: [S, 1, H, hd]; returns [S, 1, H, hd]."""
-    k_ctx = paged_gather(pool_k, block_tables)
-    v_ctx = paged_gather(pool_v, block_tables)
-    return gathered_decode_attention(q, k_ctx, v_ctx, lengths)
+def paged_attention(
+    q, pool_k, pool_v, block_tables, lengths, active=None, *, interpret=None,
+    mesh=None,
+):
+    """Decode attention against a paged KV pool, read in place by one fused
+    kernel (module docstring).  q: [S, 1, H, hd]; pool_k/pool_v:
+    [num_blocks, block_size, Hk, hd]; block_tables: int32 [S, max_blocks];
+    lengths: int32 [S], slot s attends over positions ``<= lengths[s]``
+    (clipped to the table's capacity); returns [S, 1, H, hd] in q's dtype.
+
+    ``active`` (bool [S], optional): an inactive slot is skipped, whatever
+    its stale row and length hold: it reads nothing and its output is 0,
+    for the caller to ignore.  ``interpret`` is chosen from
+    ``jax.default_backend()`` when None: Mosaic on ``tpu``, Pallas interpret
+    mode on ``cpu``.  ``mesh``: pass the mesh when the step is a program XLA
+    partitions over one (the engine's decode submesh): XLA cannot partition a
+    Mosaic call, so it is wrapped in a ``shard_map`` in which every device
+    computes the whole, replicated.  The block tables and lengths ride in
+    SMEM whole (8 KB at 32 slots x 64 blocks).
+    """
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        operands = (q, pool_k, pool_v, block_tables, lengths, active)
+        return jax.shard_map(
+            functools.partial(paged_attention, interpret=interpret),
+            mesh=mesh,
+            in_specs=tuple(None if x is None else P() for x in operands),
+            out_specs=P(),
+            check_vma=False,
+        )(*operands)
+    if interpret is None:
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"paged_attention has a Mosaic (tpu) lowering and a cpu "
+                f"interpret mode for tests; platform {backend!r} has neither"
+            )
+        interpret = backend == "cpu"
+    S, T, H, hd = q.shape
+    num_blocks, block_size, Hk, _ = pool_k.shape
+    if T != 1 or H % Hk:
+        raise ValueError(
+            f"paged_attention takes one query position a slot and H a multiple "
+            f"of Hk, got q {q.shape} against a pool {pool_k.shape}"
+        )
+    if not interpret and hd % 128:
+        _M_GATHER_REROUTES.inc()
+        return gathered_decode_attention(
+            q, paged_gather(pool_k, block_tables),
+            paged_gather(pool_v, block_tables), lengths,
+        )
+
+    if active is None:
+        active = jnp.ones((S,), jnp.bool_)
+    # Fill the (sublane, lane) tile of the pool's dtype with heads: fold
+    # consecutive tokens of a block into the head axis where Hk alone leaves
+    # it part empty.
+    tile_rows = 32 // pool_k.dtype.itemsize
+    fold = math.gcd(block_size, tile_rows // math.gcd(Hk, tile_rows))
+    page_bytes = (
+        block_size // fold * -(-fold * Hk // tile_rows) * tile_rows * hd
+        * pool_k.dtype.itemsize
+    )
+    pages = max(1, min(block_tables.shape[1], _WINDOW_BYTES // page_bytes))
+    return _paged_call(
+        q, pool_k, pool_v, block_tables, lengths, active,
+        fold=fold, pages=pages, interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("fold", "pages", "interpret"))
+def _paged_call(
+    q, pool_k, pool_v, block_tables, lengths, active, *, fold, pages, interpret
+):
+    """:func:`paged_attention` past its checks.  A jit of its own, so that the
+    layers of a model, which call it with the same shapes, share one trace of
+    the kernel and one lowering (a third of a second of set-up a layer)."""
+    S, _, H, hd = q.shape
+    num_blocks, block_size, Hk, _ = pool_k.shape
+    group = H // Hk
+    # The fold is a row-major reshape of the pool (a bitcast on the chip).
+    rows, R = block_size // fold, fold * Hk
+    pool_k = pool_k.reshape(num_blocks, rows, R, hd)
+    pool_v = pool_v.reshape(num_blocks, rows, R, hd)
+    # [S, 1, Hk * group, hd] -> [S, group, fold * Hk, hd]: scaled once, in
+    # float32, and repeated for each token of the fold.
+    qg = q.astype(jnp.float32).reshape(S, Hk, group, hd) * hd**-0.5
+    qg = jnp.tile(qg.transpose(0, 2, 1, 3), (1, 1, fold, 1))
+    # The kernel visits the active slots only, in slot order.
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    count = jnp.sum(active, dtype=jnp.int32).reshape(1)
+
+    window = (2, pages, rows, R, hd)
+    # The scope is what the profiler's trace calls the kernel's operation:
+    # paged_attention_<result type and shape>.
+    with jax.named_scope("paged_attention"):
+        out, lse = pl.pallas_call(
+            functools.partial(_paged_kernel, pages=pages, fold=fold, kv_heads=Hk),
+            out_shape=[
+                jax.ShapeDtypeStruct((S, group, R, hd), jnp.float32),
+                jax.ShapeDtypeStruct((S, group, R, 128), jnp.float32),
+            ],
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # order
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # count
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # block_tables
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # lengths
+                pl.BlockSpec(memory_space=pltpu.VMEM),  # q
+                pl.BlockSpec(memory_space=pl.ANY),  # pool_k: HBM, copied by hand
+                pl.BlockSpec(memory_space=pl.ANY),  # pool_v
+            ],
+            out_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM(window, pool_k.dtype),
+                pltpu.VMEM(window, pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+            interpret=interpret,
+        )(order, count, block_tables, lengths, qg, pool_k, pool_v)
+    # Merge a head's fold: each part is a softmax over its own positions,
+    # weighed by its logsumexp (fold == 1: a weight of exactly 1).
+    out = out.reshape(S, group, fold, Hk, hd)
+    weight = jax.nn.softmax(lse[..., :1].reshape(S, group, fold, Hk, 1), axis=2)
+    out = jnp.sum(out * weight, axis=2)  # [S, group, Hk, hd]
+    return out.transpose(0, 2, 1, 3).reshape(S, 1, H, hd).astype(q.dtype)

@@ -24,10 +24,10 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e device, with the persistent compile cache off: an
-    entry written by such a compile cannot be read back without a chip, and
-    the next run would warn about every one of them."""
+def topo():
+    """A described v5e:2x2, with the persistent compile cache off: an entry
+    written by a compile for it cannot be read back without a chip, and the
+    next run would warn about every one of them."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -38,13 +38,20 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
 
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e device."""
+    return SingleDeviceSharding(topo.devices[0])
+
+
 def _on(chip, tree):
-    """Shapes of ``tree`` as arguments placed on the described device."""
+    """Shapes of ``tree`` as arguments placed on the described device (or
+    under any other sharding)."""
     return jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree
     )
@@ -79,35 +86,97 @@ def test_flash_kernels_compile_through_mosaic(chip, t, direction):
     assert text.count("tpu_custom_call") >= (1 if direction == "forward" else 3)
 
 
-def test_paged_decode_step_compiles_at_serve_geometry(chip):
-    """One decode step's KV write + block-table gather + attention at the
-    slot and block geometry chip_smoke's serve phase runs: 8 slots, blocks
-    of 16 tokens, sequences up to 80 tokens, 8 KV heads of 128, f32."""
-    from moolib_tpu.ops.paged_attention import paged_attention, paged_kv_write
+# name: slots, blocks a slot, block, query heads, KV heads, head size, dtype.
+_PAGED_GEOMETRIES = {
+    # chip_smoke's serve phase: sequences up to 80 tokens, f32
+    "smoke": (8, 5, 16, 8, 8, 128, jnp.float32),
+    # lm_serve_steady: 32 slots x 1,024 positions, 16 heads of 128, bf16
+    "serve": (32, 64, 16, 16, 16, 128, jnp.bfloat16),
+    # grouped and multi-query (starcoderbase-1b has one KV head): tokens of
+    # a block fold into the head axis to fill the (16, 128) tile
+    "grouped": (32, 64, 16, 16, 4, 128, jnp.bfloat16),
+    "multi_query": (32, 64, 16, 16, 1, 128, jnp.bfloat16),
+}
 
-    slots, block, heads, hd = 8, 16, 8, 128
-    max_blocks = -(-80 // block)
-    pool = jnp.zeros((1 + slots * max_blocks, block, heads, hd), jnp.float32)
+
+def _paged_step_args(sharding, geometry):
+    slots, max_blocks, block, heads, kv_heads, hd, dtype = geometry
+    pool = jax.ShapeDtypeStruct(
+        (1 + slots * max_blocks, block, kv_heads, hd), dtype)
+    return pool, _on(sharding, (
+        jnp.zeros((slots, 1, heads, hd), dtype),
+        jnp.zeros((slots, kv_heads, hd), dtype),
+        jnp.zeros((slots, kv_heads, hd), dtype),
+        pool, pool,
+        jnp.zeros((slots, max_blocks), jnp.int32),
+        jnp.zeros((slots,), jnp.int32),
+        jnp.zeros((slots,), jnp.bool_),
+    ))
+
+
+def _paged_step(mesh=None):
+    from moolib_tpu.ops.paged_attention import paged_attention, paged_kv_write
 
     def step(q, k_new, v_new, pool_k, pool_v, tables, lengths, active):
         pool_k = paged_kv_write(pool_k, k_new, tables, lengths, active)
         pool_v = paged_kv_write(pool_v, v_new, tables, lengths, active)
-        return paged_attention(q, pool_k, pool_v, tables, lengths), pool_k, pool_v
+        # interpret=False steers the kernel onto Mosaic: left to itself it
+        # asks jax.default_backend(), which is the cpu in this process.
+        att = paged_attention(q, pool_k, pool_v, tables, lengths, active,
+                              interpret=False, mesh=mesh)
+        return att, pool_k, pool_v
 
-    compiled, _ = _compile(
-        jax.jit(step, donate_argnums=(3, 4)),
-        *_on(chip, (
-            jnp.zeros((slots, 1, heads, hd), jnp.float32),
-            jnp.zeros((slots, heads, hd), jnp.float32),
-            jnp.zeros((slots, heads, hd), jnp.float32),
-            pool, pool,
-            jnp.zeros((slots, max_blocks), jnp.int32),
-            jnp.zeros((slots,), jnp.int32),
-            jnp.zeros((slots,), jnp.bool_),
-        )),
-    )
+    return jax.jit(step, donate_argnums=(3, 4))
+
+
+@pytest.mark.parametrize("name", list(_PAGED_GEOMETRIES))
+def test_paged_decode_step_compiles_at_serve_geometry(chip, name):
+    """One decode step's KV write and the fused paged attention kernel, at
+    the geometries the chip runs: Mosaic takes the kernel, the pools update
+    in place, and nothing of the size of a gathered context is made (the
+    gather path held a float32 [slots x blocks, 16, heads, 128] temporary:
+    268 MB at the serving cell's geometry)."""
+    pool, args = _paged_step_args(chip, _PAGED_GEOMETRIES[name])
+    compiled, text = _compile(_paged_step(), *args)
+    assert text.count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    pool_bytes = pool.size * pool.dtype.itemsize
     # Donated pools update in place: the step's outputs alias its inputs.
-    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool.nbytes
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    # q, the output and the partial softmaxes of a fold, in float32.
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
+def test_paged_decode_step_compiles_replicated_over_a_mesh(topo):
+    """The engine's decode submesh (``prefill_devices``) holds everything
+    replicated; XLA refuses to partition a Mosaic call, so ``mesh=`` wraps it
+    in a ``shard_map``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.asarray(topo.devices[:2]), ("dp",))
+    _, args = _paged_step_args(
+        NamedSharding(mesh, PartitionSpec()), _PAGED_GEOMETRIES["serve"])
+    with pytest.raises(Exception, match="shard_map"):
+        _compile(_paged_step(), *args)
+    _, text = _compile(_paged_step(mesh), *args)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_paged_attention_reroutes_a_head_size_mosaic_cannot_copy(chip):
+    """A head size under the 128 lanes: no block can be copied out of the
+    lane-padded pool, so the call is traced onto the XLA gather, counted."""
+    from moolib_tpu import telemetry
+
+    def reroutes():
+        return telemetry.get_registry().counter_values().get(
+            "paged_gather_reroutes_total", 0.0)
+
+    before = reroutes()
+    _, args = _paged_step_args(chip, (8, 5, 16, 8, 8, 64, jnp.bfloat16))
+    _, text = _compile(_paged_step(), *args)
+    assert "tpu_custom_call" not in text
+    assert reroutes() == before + 1
 
 
 # R2D2's stored sequence (ROADMAP R4): burn-in 40 + unroll 80 frames of

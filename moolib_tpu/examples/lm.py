@@ -36,6 +36,13 @@ from ..watchdog import Watchdog
 from . import common
 
 
+_M_DONATED = telemetry.get_registry().gauge(
+    "lm_step_donated_bytes",
+    "bytes of train state (params + optimizer state) the compiled lm.train "
+    "step updates in place, a device",
+)
+
+
 def make_flags(argv=None):
     p = argparse.ArgumentParser(description="moolib_tpu long-context LM example")
     p.add_argument("--vocab", type=int, default=64)
@@ -192,6 +199,120 @@ def make_batch(rng: np.random.Generator, flags):
     return np.concatenate([prefix, prefix], axis=1).astype(np.int32)
 
 
+def make_model(flags) -> TransformerLM:
+    """The model these flags describe."""
+    return TransformerLM(
+        vocab_size=flags.vocab,
+        d_model=flags.d_model,
+        num_layers=flags.layers,
+        num_heads=flags.heads,
+        max_len=flags.seq_len,
+        attention=flags.attention,
+        moe_num_experts=flags.moe_experts,
+        pos_embedding=flags.pos,
+        remat=flags.remat,
+        remat_policy=flags.remat_policy,
+        num_kv_heads=flags.kv_heads or None,
+    )
+
+
+def _apply_kwargs(flags, mesh) -> dict:
+    # ring rotates K/V over the mesh's sp axis; flash needs the mesh to wrap
+    # its kernel in shard_map (XLA cannot partition a Mosaic call).  The
+    # pipeline applies blocks inside its own shard_map and passes none.
+    if flags.attention == "ring" or (flags.attention == "flash" and mesh is not None):
+        return {"mesh": mesh}
+    return {}
+
+
+def make_step(flags, model, opt, mesh=None):
+    """``loss_fn(params, tokens) -> (loss, acc)`` and ``step(params,
+    opt_state, tokens) -> (params, opt_state, loss, acc)`` of these flags on
+    this mesh, as plain functions: :func:`jit_step` compiles the second."""
+    dp = mesh.shape.get("dp", 1) if mesh is not None else 1
+    pp = mesh.shape.get("pp", 1) if mesh is not None else 1
+    apply_kwargs = _apply_kwargs(flags, mesh)
+    half = flags.seq_len // 2
+
+    def loss_fn(params, tokens):
+        if pp > 1:
+            from ..models.transformer import pipeline_lm_apply
+
+            logits = pipeline_lm_apply(
+                model,
+                params,
+                tokens,
+                mesh,
+                num_microbatches=flags.microbatches or 2 * pp,
+                data_axis="dp" if dp > 1 else None,
+                circular_repeats=flags.pp_repeats,
+                remat=flags.remat,  # the pipeline rebuilds blocks itself
+                remat_policy=flags.remat_policy,
+            )
+            aux = 0.0
+        elif flags.moe_experts:
+            logits, col = model.apply(
+                params, tokens, mutable=["losses"], **apply_kwargs
+            )
+            aux = sum(
+                jnp.sum(jnp.asarray(v))
+                for v in jax.tree_util.tree_leaves(col.get("losses", {}))
+            )
+        else:
+            logits = model.apply(params, tokens, **apply_kwargs)  # [B, T, V]
+            aux = 0.0
+        # Next-token prediction, scored only where the answer is half a
+        # sequence away: positions half-1 .. T-2 predict the repeated half.
+        with jax.named_scope("lm_loss"):
+            pred = logits[:, half - 1 : -1]
+            tgt = tokens[:, half:]
+            logp = jax.nn.log_softmax(pred.astype(jnp.float32), axis=-1)
+            ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+            acc = (pred.argmax(-1) == tgt).mean()
+            return -ll.mean() + flags.moe_aux_weight * aux, acc
+
+    def step(params, opt_state, tokens):
+        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, tokens)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss, acc
+
+    return loss_fn, step
+
+
+def jit_step(step, params, flags, mesh=None):
+    """``step`` jitted for ``params`` (arrays, or their shapes) on ``mesh``,
+    and ``put``, which places a batch where the jit wants it.
+
+    ``params`` and ``opt_state`` are DONATED: the new state takes the old
+    state's memory, so the caller must rebind both from the outputs of every
+    call and read the old ones no more.  That lets step N+1 be queued while
+    step N runs; without it the runtime holds the call until step N has
+    ended and the host has dropped its inputs, and the device idles once a
+    step."""
+    if mesh is None:
+        return jax.jit(step, donate_argnums=(0, 1)), lambda x: x
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    rep = parallel.replicated(mesh)
+    tok_sharding = NamedSharding(
+        mesh, P("dp", None) if mesh.shape.get("dp", 1) > 1 else P()
+    )
+    # Expert weights shard over ep when the mesh has that axis (EP);
+    # the rest of the params stay replicated.
+    if flags.moe_experts and "ep" in mesh.axis_names:
+        p_sh = parallel.moe_shardings(params, mesh, "ep")
+    else:
+        p_sh = jax.tree_util.tree_map(lambda _: rep, params)
+    jstep = jax.jit(
+        step,
+        in_shardings=(p_sh, None, tok_sharding),
+        out_shardings=(p_sh, None, rep, rep),
+        donate_argnums=(0, 1),
+    )
+    return jstep, lambda x: jax.device_put(x, tok_sharding)
+
+
 def train(flags, on_stats=None) -> dict:
     # Before the first jit: restarts skip recompilation via the persistent
     # cache (utils/compile_cache.py).
@@ -267,77 +388,16 @@ def train(flags, on_stats=None) -> dict:
                 "divisible by the dp axis size"
             )
 
-    model = TransformerLM(
-        vocab_size=flags.vocab,
-        d_model=flags.d_model,
-        num_layers=flags.layers,
-        num_heads=flags.heads,
-        max_len=flags.seq_len,
-        attention=flags.attention,
-        moe_num_experts=flags.moe_experts,
-        pos_embedding=flags.pos,
-        remat=flags.remat,
-        remat_policy=flags.remat_policy,
-        num_kv_heads=flags.kv_heads or None,
-    )
+    model = make_model(flags)
     rng = np.random.default_rng(flags.seed)
     tokens0 = jnp.asarray(make_batch(rng, flags))
-    # ring rotates K/V over the mesh's sp axis; flash needs the mesh to wrap
-    # its kernel in shard_map (XLA cannot partition a Mosaic call).  The
-    # pipeline applies blocks inside its own shard_map and passes none.
-    apply_kwargs = (
-        {"mesh": mesh}
-        if flags.attention == "ring" or (flags.attention == "flash" and mesh is not None)
-        else {}
+    params = model.init(
+        jax.random.key(flags.seed), tokens0, **_apply_kwargs(flags, mesh)
     )
-    params = model.init(jax.random.key(flags.seed), tokens0, **apply_kwargs)
     opt = optax.adamw(flags.learning_rate)
     opt_state = opt.init(params)
 
-    half = flags.seq_len // 2
-
-    def loss_fn(params, tokens):
-        if pp > 1:
-            from ..models.transformer import pipeline_lm_apply
-
-            logits = pipeline_lm_apply(
-                model,
-                params,
-                tokens,
-                mesh,
-                num_microbatches=microbatches,
-                data_axis="dp" if axes.get("dp", 1) > 1 else None,
-                circular_repeats=flags.pp_repeats,
-                remat=flags.remat,  # the pipeline rebuilds blocks itself
-                remat_policy=flags.remat_policy,
-            )
-            aux = 0.0
-        elif flags.moe_experts:
-            logits, col = model.apply(
-                params, tokens, mutable=["losses"], **apply_kwargs
-            )
-            aux = sum(
-                jnp.sum(jnp.asarray(v))
-                for v in jax.tree_util.tree_leaves(col.get("losses", {}))
-            )
-        else:
-            logits = model.apply(params, tokens, **apply_kwargs)  # [B, T, V]
-            aux = 0.0
-        # Next-token prediction, scored only where the answer is half a
-        # sequence away: positions half-1 .. T-2 predict the repeated half.
-        with jax.named_scope("lm_loss"):
-            pred = logits[:, half - 1 : -1]
-            tgt = tokens[:, half:]
-            logp = jax.nn.log_softmax(pred.astype(jnp.float32), axis=-1)
-            ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-            acc = (pred.argmax(-1) == tgt).mean()
-            return -ll.mean() + flags.moe_aux_weight * aux, acc
-
-    def step(params, opt_state, tokens):
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, tokens)
-        with jax.named_scope("optimizer"):
-            updates, opt_state = opt.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state, loss, acc
+    loss_fn, step = make_step(flags, model, opt, mesh)
 
     # Durable state (docs/RESILIENCE.md): manifest-validated checkpoints;
     # resume picks the newest INTACT one (corruption costs one interval).
@@ -384,39 +444,24 @@ def train(flags, on_stats=None) -> dict:
                               on_stats=on_stats, ckpt=ckpt, start_step=start_step,
                               mesh=mesh, dckpt=dckpt)
 
-    if mesh is None:
-        jstep = jax.jit(step)
-        put = lambda x: x
-    else:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        rep = parallel.replicated(mesh)
-        tok_sharding = NamedSharding(
-            mesh, P("dp", None) if axes.get("dp", 1) > 1 else P()
-        )
-        # Expert weights shard over ep when the mesh has that axis (EP);
-        # the rest of the params stay replicated.
-        if flags.moe_experts and "ep" in mesh.axis_names:
-            p_sh = parallel.moe_shardings(params, mesh, "ep")
-        else:
-            p_sh = jax.tree_util.tree_map(lambda _: rep, params)
-        jstep = jax.jit(
-            step,
-            in_shardings=(p_sh, None, tok_sharding),
-            out_shardings=(p_sh, None, rep, rep),
-        )
-        put = lambda x: jax.device_put(x, tok_sharding)
+    jstep, put = jit_step(step, params, flags, mesh)
     jstep = telemetry.devmon.instrument_jit(jstep, "lm.step")
 
     # Compile outside the clock (jit time would dominate tokens_per_s on
-    # short runs); the warmup step's outputs are discarded.
-    _, _, wl, _ = jstep(params, opt_state, put(tokens0))
-    float(wl)
+    # short runs), ahead of time and on the first batch: the state is
+    # donated, so a warm-up that executed would consume ``params``.  The
+    # loop's first call finds this program and is the first execution.
     # Device performance plane: XLA-counted step cost (flops + bytes) for
-    # the MFU/roofline numbers in the log line and out["mfu"].
+    # the MFU/roofline numbers in the log line and out["mfu"], and the bytes
+    # of donated state the outputs reuse (0: a donation XLA could not use).
     step_cost = telemetry.devmon.step_cost(
         "lm.step", jstep, params, opt_state, put(tokens0)
     )
+    donated = None if step_cost is None else step_cost.donated_bytes
+    donated_s = ""
+    if donated is not None:
+        _M_DONATED.set(donated)
+        donated_s = f" donated={donated / 1e9:.3f}GB"
     start = time.time()
     last_ckpt = start
     loss = acc = None
@@ -468,7 +513,7 @@ def train(flags, on_stats=None) -> dict:
                         )
                     print(
                         f"step={steps_done} loss={loss_v:.4f} "
-                        f"acc={acc_v:.3f}{mfu_s}{tl_s}",
+                        f"acc={acc_v:.3f}{mfu_s}{donated_s}{tl_s}",
                         flush=True,
                     )
                 if on_stats is not None:
@@ -511,6 +556,7 @@ def train(flags, on_stats=None) -> dict:
         * flags.batch_size * flags.seq_len / max(elapsed, 1e-6),
         "losses": losses,
         "program": None if step_cost is None else step_cost.program(),
+        "donated_bytes": donated,
         "flash_dense_reroutes": telemetry.get_registry().counter_values().get(
             "flash_dense_reroutes_total", 0.0
         ),

@@ -474,18 +474,22 @@ _COLLECTIVE_RE = re.compile(
 class StepCost:
     """What XLA compiled for one step: counted flops and bytes accessed,
     the program's device memory (arguments + outputs + temporaries, minus
-    aliased bytes, from ``memory_analysis()``), the number of Mosaic kernel
-    call sites (``tpu_custom_call``) and the collectives by kind."""
+    aliased bytes, from ``memory_analysis()``), the aliased bytes themselves
+    (outputs that took a donated argument's memory; 0 where a donation could
+    not be used), the number of Mosaic kernel call sites
+    (``tpu_custom_call``) and the collectives by kind."""
 
-    __slots__ = ("flops", "bytes_accessed", "memory_bytes", "kernels",
-                 "collectives")
+    __slots__ = ("flops", "bytes_accessed", "memory_bytes", "donated_bytes",
+                 "kernels", "collectives")
 
     def __init__(self, flops: float, bytes_accessed: float,
                  memory_bytes: Optional[int] = None, kernels: int = 0,
-                 collectives: Optional[Dict[str, int]] = None):
+                 collectives: Optional[Dict[str, int]] = None,
+                 donated_bytes: Optional[int] = None):
         self.flops = float(flops)
         self.bytes_accessed = float(bytes_accessed)
         self.memory_bytes = memory_bytes
+        self.donated_bytes = donated_bytes
         self.kernels = kernels
         self.collectives = collectives or {}
 
@@ -531,6 +535,7 @@ def step_cost(name: str, jitted, *args, **kwargs) -> Optional["StepCost"]:
             ),
             kernels=text.count("tpu_custom_call"),
             collectives=dict(Counter(_COLLECTIVE_RE.findall(text))),
+            donated_bytes=None if mem is None else int(mem.alias_size_in_bytes),
         )
     with _lock:
         _COST_CACHE[sig] = cost

@@ -4,6 +4,8 @@ The recall task (second half of each sequence repeats the first) is only
 solvable by attending T/2 positions back — a broken ring schedule or broken
 gradients through it cannot beat chance (~1/62)."""
 
+import pytest
+
 from moolib_tpu.examples.lm import make_flags, train
 
 
@@ -251,3 +253,121 @@ def test_tp_sharded_serving_matches_local_generate(free_port):
     finally:
         client.close()
         server.close()
+
+
+# ---- the train state is donated (ISSUE 28): the step updates it in place ----
+
+_SMALL = ["--seq_len", "16", "--batch_size", "8", "--seed", "7", "--quiet"]
+_PATHS = {
+    "plain": ["--mesh", "", "--attention", "dense"],
+    "dp2": ["--mesh", "dp=2", "--attention", "flash"],
+}
+
+
+class _StepSpy:
+    """Stands where ``devmon.instrument_jit`` puts its wrapper around the
+    step ``train`` built: after every call, the bytes of the ``params`` and
+    ``opt_state`` passed in and whether the call consumed every leaf."""
+
+    def __init__(self, fn):
+        self.fn = fn
+        self.calls = []
+
+    def __call__(self, params, opt_state, tokens):
+        import jax
+
+        state = jax.tree_util.tree_leaves((params, opt_state))
+        nbytes = sum(x.nbytes for x in state)
+        out = self.fn(params, opt_state, tokens)
+        self.calls.append((nbytes, all(x.is_deleted() for x in state)))
+        return out
+
+    def __getattr__(self, name):  # .lower, for the ahead-of-time compile
+        return getattr(self.fn, name)
+
+
+def _train_spied(monkeypatch, argv):
+    from moolib_tpu.telemetry import devmon
+
+    devmon.reset_for_tests()  # its cost cache is keyed by shapes alone
+    spies = []
+
+    def instrument(fn, name):
+        spies.append(_StepSpy(fn))
+        return spies[-1]
+
+    monkeypatch.setattr(devmon, "instrument_jit", instrument)
+    out = train(make_flags(argv))
+    (spy,) = spies
+    return out, spy
+
+
+@pytest.mark.parametrize("path", list(_PATHS))
+def test_lm_step_consumes_params_and_opt_state(monkeypatch, path):
+    """Every leaf of the state passed to the step is gone after the call,
+    from the first call on (under a mesh the second call runs a second
+    program, compiled for the sharding the first one returned), and what the
+    compiled step aliases is the whole state."""
+    out, spy = _train_spied(
+        monkeypatch, _SMALL + _PATHS[path] + ["--steps", "4", "--log_interval", "2"])
+    assert len(spy.calls) == 4  # no executed warm-up: a loop step is a call
+    assert all(consumed for _, consumed in spy.calls), spy.calls
+    state_bytes = spy.calls[0][0]
+    assert {n for n, _ in spy.calls} == {state_bytes}
+    assert out["donated_bytes"] == state_bytes > 0
+    assert out["param_placement"]["devices_per_array"] == (2 if path == "dp2" else 1)
+
+
+@pytest.mark.parametrize("path", list(_PATHS))
+def test_lm_train_reports_the_losses_of_the_undonated_step(monkeypatch, path):
+    """``train``'s losses equal, to the bit, those of the same ``step`` jitted
+    with nothing donated and driven by the same batches: the first batch is
+    drawn and feeds the compile alone, and no weight moves before step 1."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from moolib_tpu import parallel
+    from moolib_tpu.examples import lm
+
+    argv = _SMALL + _PATHS[path] + ["--steps", "5", "--log_interval", "1"]
+    out, _ = _train_spied(monkeypatch, argv)
+
+    flags = make_flags(argv)
+    mesh = parallel.parse_mesh_spec(flags.mesh)
+    model, opt = lm.make_model(flags), optax.adamw(flags.learning_rate)
+    rng = np.random.default_rng(flags.seed)
+    first = jnp.asarray(lm.make_batch(rng, flags))
+    params = model.init(
+        jax.random.key(flags.seed), first, **lm._apply_kwargs(flags, mesh))
+    opt_state = opt.init(params)
+    _, step = lm.make_step(flags, model, opt, mesh)
+    _, put = lm.jit_step(step, params, flags, mesh)
+    # The same shardings, nothing donated: params and opt_state stay readable.
+    if mesh is None:
+        plain = jax.jit(step)
+    else:
+        rep = parallel.replicated(mesh)
+        plain = jax.jit(
+            step,
+            in_shardings=(rep, None, put(first).sharding),
+            out_shardings=(rep, None, rep, rep),
+        )
+    want = []
+    for i in range(flags.steps):
+        old = params
+        params, opt_state, loss, _ = plain(
+            params, opt_state, put(jnp.asarray(lm.make_batch(rng, flags))))
+        want.append((i + 1, float(loss)))
+        assert not jax.tree_util.tree_leaves(old)[0].is_deleted()
+    assert out["losses"] == want
+
+
+def test_lm_step_donated_bytes_gauge_and_result(monkeypatch):
+    from moolib_tpu import telemetry
+
+    out, spy = _train_spied(
+        monkeypatch, _SMALL + _PATHS["plain"] + ["--steps", "2", "--log_interval", "1"])
+    series = telemetry.get_registry().snapshot()["lm_step_donated_bytes"]["series"]
+    assert [s["value"] for s in series] == [out["donated_bytes"]] == [spy.calls[0][0]]

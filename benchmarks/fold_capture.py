@@ -464,45 +464,6 @@ def merge_qps_rows(old_lines, new_lines):
     return kept + list(new_lines)
 
 
-def parse_step_overlap(path):
-    """timeline_smoke stdout: one ``{"metric": "step_overlap", ...}`` JSON
-    row per cohort peer (overlap/exposure attribution from the fused
-    host+device timeline).  Same salvage policy as the other parsers:
-    non-JSON and garbled lines are dropped."""
-    keep = []
-    try:
-        with open(path) as f:
-            for line in f.read().splitlines():
-                if not line.startswith("{"):
-                    continue
-                try:
-                    row = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if row.get("metric") == "step_overlap":
-                    keep.append(json.dumps(row))
-    except OSError:
-        return None
-    return keep or None
-
-
-def _overlap_row_key(line):
-    """Merge key for a step_overlap section row: the reporting peer."""
-    try:
-        row = json.loads(line)
-    except json.JSONDecodeError:
-        return line
-    return row.get("peer")
-
-
-def merge_overlap_rows(old_lines, new_lines):
-    """step_overlap rows merge per peer: a fresh capture replaces its own
-    peers' rows and keeps any stored peer it didn't re-measure."""
-    fresh = {_overlap_row_key(l) for l in new_lines}
-    kept = [l for l in (old_lines or []) if _overlap_row_key(l) not in fresh]
-    return kept + list(new_lines)
-
-
 def fold_local(log_path, json_path):
     """Merge a fresh local capture into BENCH_LOCAL.json: only the section
     the log belongs to — ``allreduce_rpc`` for an allreduce_bench capture,
@@ -519,23 +480,12 @@ def fold_local(log_path, json_path):
             data = json.load(f)
     else:
         data = {}
-    overlap_lines = parse_step_overlap(log_path)
-    agent_lines = None if overlap_lines else parse_agent_lines(log_path)
-    r2d2_lines = (
-        None if (overlap_lines or agent_lines) else parse_r2d2_local(log_path)
-    )
+    agent_lines = parse_agent_lines(log_path)
+    r2d2_lines = None if agent_lines else parse_r2d2_local(log_path)
     qps_lines = (
-        None
-        if (overlap_lines or agent_lines or r2d2_lines)
-        else parse_serve_qps(log_path)
+        None if (agent_lines or r2d2_lines) else parse_serve_qps(log_path)
     )
-    if overlap_lines:
-        section, cmd, lines = (
-            "step_overlap",
-            "scripts/timeline_smoke.py --smoke",
-            overlap_lines,
-        )
-    elif agent_lines:
+    if agent_lines:
         section, cmd, lines = (
             "agent_small",
             "benchmarks/agent_bench.py --scale small --rollout all",
@@ -561,8 +511,7 @@ def fold_local(log_path, json_path):
         lines = parse_allreduce(log_path)
         if not lines:
             raise SystemExit(
-                f"no step_overlap, allreduce, agent, or serve_qps rows "
-                f"found in {log_path}"
+                f"no allreduce, agent, or serve_qps rows found in {log_path}"
             )
         section, cmd = "allreduce_rpc", "benchmarks/allreduce_bench.py rpc"
     sec = dict(data.get(section, {}))
@@ -579,8 +528,6 @@ def fold_local(log_path, json_path):
         lines = merge_agent_rows(sec.get("stdout"), lines)
     elif section == "allreduce_rpc":
         lines = merge_allreduce_sections(sec.get("stdout"), lines)
-    elif section == "step_overlap":
-        lines = merge_overlap_rows(sec.get("stdout"), lines)
     sec["stdout"] = lines
     sec["stderr"] = []
     try:

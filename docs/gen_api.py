@@ -74,7 +74,6 @@ MODULES = [
     ("moolib_tpu.telemetry.devmon", "Telemetry: device performance plane"),
     ("moolib_tpu.telemetry.flightrec", "Telemetry: flight recorder"),
     ("moolib_tpu.telemetry.profiling", "Telemetry: on-demand device profiling"),
-    ("moolib_tpu.telemetry.timeline", "Telemetry: fused step timeline / overlap attribution"),
     ("moolib_tpu.telemetry.recovery", "Telemetry: recovery-phase accounting"),
     ("moolib_tpu.utils", "Utilities"),
     ("moolib_tpu.utils.nest", "Utilities: nest"),

@@ -1308,22 +1308,12 @@ class Accumulator:
         t0 = time.monotonic()
         d2h = 0
         fill_s = 0.0
-        tl = telemetry.timeline
 
         def _launch(i):
             u = units[i]
-            mark = tl.comm_mark()
-            cf = u["fire"]()
+            u["fire"]()
             u["t"] = time.monotonic()
             launch_order.append(i)
-            if cf is not None and mark is not None:
-                # Retroactive per-bucket comm span: launch -> sub-op
-                # completion.  Overlap attribution (timeline.ingest_window)
-                # unions these against the step's compute span, so wire time
-                # hidden under backward lands in overlapped_comm_seconds.
-                cf.add_done_callback(
-                    lambda f, m=mark: tl.comm_interval("accum.stream_bucket", m)
-                )
 
         try:
             while True:
@@ -2569,10 +2559,8 @@ class Accumulator:
             # executor must not have its queue wait counted against it.
             round_.t0 = time.monotonic()
         try:
-            # Marks the collective for any open timeline capture window
-            # (telemetry.timeline): this is host wall time in communication,
-            # classified as exposed unless compute overlaps it.
-            with telemetry.timeline.comm_span("accum.ici_allreduce"):
+            # Host wall time of the in-mesh collective.
+            with telemetry.span("accum.ici_allreduce"):
                 summed = self._ici_allreduce(arrays, round_)
             with self._lock:
                 # Feeds the adaptive progress bound: healthy rounds this

@@ -418,9 +418,8 @@ class ContinuousBatchingEngine:
                 self._active, self._tokens, self._remaining,
             )
         t1 = time.monotonic()
-        # host_span: the decode loop's D2H wait, as a tracer span and as
-        # host-blocked time for any open timeline capture window.
-        with telemetry.timeline.host_span("engine.decode_fetch"):
+        # The decode loop's D2H wait.
+        with telemetry.span("engine.decode_fetch"):
             # mtlint: allow-host-sync(the decode loop's one intentional D2H: emitted tokens/done flags must reach the host to answer requests)
             nxt = np.asarray(self._tokens)
             done = np.asarray(done)  # mtlint: allow-host-sync(same fetch: part of the decode loop's one D2H)

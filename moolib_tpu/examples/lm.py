@@ -502,18 +502,9 @@ def train(flags, on_stats=None) -> dict:
                         if mfu_info is not None
                         else ""
                     )
-                    # Overlap attribution from the last timeline window,
-                    # when MOOLIB_TIMELINE_INTERVAL enabled the plane.
-                    tl = telemetry.timeline.status()
-                    tl_s = ""
-                    if tl["windows"] and tl["last_report"] is not None:
-                        tl_s = (
-                            f" exposed_comm="
-                            f"{tl['last_report']['exposed_comm_seconds']:.4f}s"
-                        )
                     print(
                         f"step={steps_done} loss={loss_v:.4f} "
-                        f"acc={acc_v:.3f}{mfu_s}{donated_s}{tl_s}",
+                        f"acc={acc_v:.3f}{mfu_s}{donated_s}",
                         flush=True,
                     )
                 if on_stats is not None:

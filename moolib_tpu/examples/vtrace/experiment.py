@@ -1154,22 +1154,12 @@ def train(flags, on_stats=None) -> dict:
                         if mfu_info is not None
                         else ""
                     )
-                    # Overlap attribution, when periodic timeline windows
-                    # are on (MOOLIB_TIMELINE_INTERVAL): exposed comm
-                    # seconds from the last ingested window.
-                    tl = telemetry.timeline.status()
-                    tl_s = ""
-                    if tl["windows"] and tl["last_report"] is not None:
-                        tl_s = (
-                            f" exposed_comm="
-                            f"{tl['last_report']['exposed_comm_seconds']:.4f}s"
-                        )
                     print(
                         f"steps={int(stats['steps_done'].value)} sps={sps:.0f} "
                         f"return={ret if ret is None else round(ret, 2)} "
                         f"sgd={int(stats['sgd_steps'].value)} "
                         f"loss={stats['loss'].result()} "
-                        f"fleet_env_steps={int(fleet_env)}{mfu_s}{tl_s} "
+                        f"fleet_env_steps={int(fleet_env)}{mfu_s} "
                         f"[{timer.report()}]",
                         flush=True,
                     )

@@ -63,10 +63,8 @@ def redistribute(tree: Any, shardings: Any, block: bool = False) -> Any:
     is_single = not isinstance(shardings, (dict, list, tuple)) and not hasattr(
         shardings, "keys"
     )
-    # comm_span marks the share-down for any open timeline capture window
-    # (telemetry.timeline); together with accum_psum_seconds this is the
-    # host half of the exposed-vs-overlapped cross-check.
-    with _M_PSUM.time(), telemetry.timeline.comm_span("parallel.redistribute"):
+    # The share-down's host wall time (the copies too, with ``block``).
+    with _M_PSUM.time(), telemetry.span("parallel.redistribute"):
         if is_single:
             out = jax.tree_util.tree_map(lambda x: jax.device_put(x, shardings), tree)
         else:

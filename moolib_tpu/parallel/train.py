@@ -48,8 +48,7 @@ def _instrument_step(fn, name: Optional[str] = None):
     # here means XLA is retracing the train step mid-run.  Where ``fn`` is
     # the jit itself the detector asks its cache (O(1) a call); a closure
     # that builds its jit lazily has no cache to ask and pays for the
-    # signature at every call.  The wrapper also feeds the timeline capture
-    # windows' dispatch hook (the step anchors for overlap attribution).
+    # signature at every call.
     step = devmon.instrument_jit(fn, name)
 
     def timed_step(*args, **kwargs):

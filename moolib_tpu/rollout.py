@@ -139,9 +139,8 @@ class PendingAction:
     def realize(self) -> np.ndarray:
         if self._host is None:
             t0 = time.monotonic()
-            # host_span marks this D2H wait as host-blocked for any open
-            # timeline capture window (telemetry.timeline).
-            with telemetry.timeline.host_span("rollout.act_fetch"):
+            # The actor's wait for its actions to reach the host.
+            with telemetry.span("rollout.act_fetch"):
                 # mtlint: allow-host-sync(the realize seam IS the intentional D2H, counted on actor_d2h_bytes_total)
                 self._host = np.asarray(self._dev)
             _M_REALIZE.observe(time.monotonic() - t0)

@@ -38,9 +38,6 @@ once; everything defaults to off):
   thread).
 - ``MOOLIB_DEVMON_INTERVAL`` / ``MOOLIB_DEVMON_HBM_WARN_FRACTION`` —
   device performance plane knobs (:mod:`moolib_tpu.telemetry.devmon`).
-- ``MOOLIB_TIMELINE_INTERVAL`` / ``MOOLIB_TIMELINE_WINDOW_S`` — periodic
-  fused host+device overlap capture windows
-  (:mod:`moolib_tpu.telemetry.timeline`).
 
 The metric name reference lives in docs/TELEMETRY.md.
 """
@@ -91,7 +88,6 @@ from .cohort import CohortCounters  # noqa: F401
 from .aggregator import CohortAggregator, install_rpc_handlers  # noqa: F401
 from . import devmon  # noqa: F401
 from . import profiling  # noqa: F401
-from . import timeline  # noqa: F401
 from .recovery import (  # noqa: F401
     RECOVERY_BUCKETS,
     RECOVERY_PHASES,
@@ -137,7 +133,6 @@ __all__ = [
     "prometheus_text",
     "serve_http",
     "span",
-    "timeline",
 ]
 
 _init_lock = threading.Lock()
@@ -192,12 +187,6 @@ def init_from_env() -> dict:
             devmon.install_from_env()
         except Exception as e:  # noqa: BLE001 — same degradation contract
             _warn(f"devmon disabled ({e!r})")
-        try:
-            # Fused host+device step timeline: periodic overlap/exposure
-            # capture windows (MOOLIB_TIMELINE_INTERVAL; off by default).
-            timeline.install_from_env()
-        except Exception as e:  # noqa: BLE001 — same degradation contract
-            _warn(f"timeline disabled ({e!r})")
         return {"http_port": _http_port, "run_dir": run_dir}
 
 

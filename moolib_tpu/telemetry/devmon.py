@@ -56,7 +56,6 @@ __all__ = [
     "instrument_jit",
     "last_recompile",
     "observe_call",
-    "set_dispatch_hook",
     "peak_bandwidth",
     "peak_flops",
     "publish_step",
@@ -244,20 +243,6 @@ def _diff_sigs(old, new) -> str:
     return "; ".join(parts) or "signatures differ"
 
 
-# Optional (fn, t0_ns, t1_ns) listener for instrumented dispatches — the
-# seam telemetry.timeline uses to anchor capture windows onto train steps.
-# Module-global read (no lock) on the call path; None means untimed.
-_dispatch_hook = None
-
-
-def set_dispatch_hook(hook) -> None:
-    """Install (or clear, with None) the dispatch listener.  The hook is
-    called as ``hook(name, t0_ns, t1_ns)`` with perf_counter_ns bounds of
-    each instrumented call; it must be cheap and must not raise."""
-    global _dispatch_hook
-    _dispatch_hook = hook
-
-
 def _cache_size(fn) -> Optional[int]:
     """How many programs a jit holds, or None for a callable that cannot say
     (anything but ``jax.jit``'s own wrapper)."""
@@ -286,16 +271,7 @@ class _InstrumentedJit:
 
     def __call__(self, *args, **kwargs):
         before = _cache_size(self._fn)
-        hook = _dispatch_hook
-        t0 = time.perf_counter_ns() if hook is not None else 0
-        try:
-            out = self._fn(*args, **kwargs)
-        finally:
-            if hook is not None:
-                try:
-                    hook(self._name, t0, time.perf_counter_ns())
-                except Exception:  # noqa: BLE001 — listener must never break the step
-                    pass
+        out = self._fn(*args, **kwargs)
         if not self._primed or before is None or _cache_size(self._fn) != before:
             self._primed = True
             observe_call(self._name, args, kwargs)
@@ -710,9 +686,7 @@ def summary_text() -> str:
 def reset_for_tests() -> None:
     """Drop detector / cost-cache / watermark state (test isolation only;
     registered metrics reset separately via the registry)."""
-    global _dispatch_hook
     stop()
-    _dispatch_hook = None
     with _lock:
         _JIT_STATE.clear()
         _COST_CACHE.clear()

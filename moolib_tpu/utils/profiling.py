@@ -1,18 +1,13 @@
-"""Tracing/profiling hooks.
+"""The training loop's section timer.
 
 The reference has no tracer — only ``debug_info`` dumps and log timings
-(SURVEY.md §5.1).  The TPU build replaces that with first-class hooks:
-
-- :func:`trace` — context manager around ``jax.profiler`` emitting a
-  TensorBoard-loadable trace of XLA execution (compile, HBM, ICI waits).
-- :class:`StepTimer` — cheap wall-clock section timing with EMA summaries,
-  for the python-side loop (act/learn/reduce shares).  Registry-backed:
-  every section also lands in the telemetry registry
-  (``loop_section_seconds{section=...}``) and records a host span, so the
-  loop breakdown exports through Prometheus/Chrome-trace without the loop
-  doing anything beyond ``timer.section(...)``.
-- :func:`annotate` — ``jax.profiler.TraceAnnotation`` passthrough so loop
-  phases show up inside device traces.
+(SURVEY.md §5.1).  :class:`StepTimer` is cheap wall-clock section timing
+with EMA summaries, for the python-side loop (act/learn/reduce shares).
+Registry-backed: every section also lands in the telemetry registry
+(``loop_section_seconds{section=...}``) and records a host span, so the
+loop breakdown exports through Prometheus/Chrome-trace without the loop
+doing anything beyond ``timer.section(...)``.  A device trace is the
+operator's window in :mod:`moolib_tpu.telemetry.profiling`.
 """
 
 from __future__ import annotations
@@ -22,24 +17,7 @@ import time
 from collections import defaultdict
 from typing import Dict, Iterator, Optional
 
-import jax
-
 from .. import telemetry
-
-
-@contextlib.contextmanager
-def trace(log_dir: str, host_tracer_level: int = 2) -> Iterator[None]:
-    """Capture a jax profiler trace into ``log_dir`` (view with TensorBoard)."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Label a region so it appears inside the device trace timeline."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 class StepTimer:

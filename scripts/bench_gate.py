@@ -150,29 +150,6 @@ def parse_allreduce_rows(lines: List[str]) -> Dict[Tuple, Dict[str, Any]]:
     return out
 
 
-def parse_step_overlap_rows(lines: List[str]) -> Dict[Tuple, Dict[str, Any]]:
-    """step_overlap rows keyed by peer (the way merge_overlap_rows keys
-    them); throughput: steps_per_s, latency: exposed_comm_s_per_step.
-    Exposed-comm-per-step gating as latency catches overlap regressions
-    (more comm left uncovered by compute) even when step rate holds."""
-    out: Dict[Tuple, Dict[str, Any]] = {}
-    for row in _json_rows(lines):
-        if row.get("metric") != "step_overlap":
-            continue
-        key = (row.get("peer"),)
-        thr: Dict[str, float] = {}
-        v = row.get("steps_per_s")
-        if isinstance(v, (int, float)) and v > 0:
-            thr["steps_per_s"] = float(v)
-        lat: Dict[str, float] = {}
-        v = row.get("exposed_comm_s_per_step")
-        if isinstance(v, (int, float)) and v > 0:
-            lat["exposed_comm_s_per_step"] = float(v)
-        if thr or lat:
-            out[key] = {"throughput": thr, "latency": lat}
-    return out
-
-
 def parse_r2d2_rows(lines: List[str]) -> Dict[Tuple, Dict[str, Any]]:
     """r2d2_learner rows keyed (metric, arm) — the way merge_r2d2_rows
     keys them; gated field: the per-arm replay-plane SPS (throughput).
@@ -192,7 +169,6 @@ def parse_r2d2_rows(lines: List[str]) -> Dict[Tuple, Dict[str, Any]]:
 SECTION_RULES = {
     "agent_small": parse_agent_rows,
     "r2d2_learner": parse_r2d2_rows,
-    "step_overlap": parse_step_overlap_rows,
     "serve_qps": parse_qps_rows,
     "allreduce_rpc": parse_allreduce_rows,
     "allreduce_ici": parse_allreduce_rows,
@@ -227,22 +203,16 @@ def capture_from_logs(paths: List[str]) -> Dict[str, Any]:
     for path in paths:
         if not os.path.exists(path):
             raise GateError(f"log not found: {path}")
-        overlap = fold_capture.parse_step_overlap(path)
-        agent = None if overlap else fold_capture.parse_agent_lines(path)
-        r2d2 = (
-            None if (overlap or agent) else fold_capture.parse_r2d2_local(path)
-        )
+        agent = fold_capture.parse_agent_lines(path)
+        r2d2 = None if agent else fold_capture.parse_r2d2_local(path)
         qps = (
-            None if (overlap or agent or r2d2)
-            else fold_capture.parse_serve_qps(path)
+            None if (agent or r2d2) else fold_capture.parse_serve_qps(path)
         )
         allr = (
-            None if (overlap or agent or r2d2 or qps)
+            None if (agent or r2d2 or qps)
             else fold_capture.parse_allreduce(path)
         )
-        if overlap:
-            section, lines = "step_overlap", overlap
-        elif agent:
+        if agent:
             section, lines = "agent_small", agent
         elif r2d2:
             section, lines = "r2d2_learner", r2d2
@@ -252,8 +222,8 @@ def capture_from_logs(paths: List[str]) -> Dict[str, Any]:
             section, lines = "allreduce_rpc", allr
         else:
             raise GateError(
-                f"no step_overlap, agent, r2d2, serve_qps, or allreduce "
-                f"rows found in {path}"
+                f"no agent, r2d2, serve_qps, or allreduce rows found in "
+                f"{path}"
             )
         sec = data.setdefault(section, {"stdout": []})
         sec["stdout"] = list(sec["stdout"]) + lines

@@ -9,8 +9,6 @@ per peer from its ``__telemetry_snapshot``:
 - MFU and HBM in-use/peak from the device performance plane (devmon),
 - per-peer fused step seconds and the cohort ``cohort_step_skew_ratio``
   (straggler attribution, ``CohortAggregator.step_skew``),
-- exposed-comm fraction from the fused step timeline
-  (``step_time_fraction{bucket="comm"}``, telemetry.timeline),
 - serving QPS / phase p99 / engine slot occupancy for serve replicas,
 - the tail of every peer's flight-recorder ring, merged and time-sorted.
 
@@ -59,18 +57,6 @@ def _counter_total(met: Dict[str, Any], name: str) -> Optional[float]:
     return _gauge_sum(met, name)
 
 
-def _labeled_gauge(
-    met: Dict[str, Any], name: str, key: str, value: str
-) -> Optional[float]:
-    out = None
-    for s in _series(met, name):
-        if (s.get("labels") or {}).get(key) == value:
-            v = s.get("value")
-            if isinstance(v, (int, float)):
-                out = v if out is None else max(out, v)
-    return out
-
-
 def _hist_quantile(met: Dict[str, Any], name: str, q: float) -> Optional[float]:
     """Approximate quantile over ALL series of one histogram family,
     merged (bucket upper-bound interpolation — console precision)."""
@@ -115,8 +101,8 @@ def _fmt(v: Optional[float], spec: str = ".2f", scale: float = 1.0) -> str:
 
 COLUMNS = (
     ("PEER", 18), ("ROLE", 8), ("ST/S", 7), ("MFU%", 6), ("HBM", 8),
-    ("PEAK", 8), ("STEP_S", 8), ("SKEW", 5), ("EXPC%", 6), ("QPS", 7),
-    ("P99MS", 7), ("OCC%", 5),
+    ("PEAK", 8), ("STEP_S", 8), ("SKEW", 5), ("QPS", 7), ("P99MS", 7),
+    ("OCC%", 5),
 )
 
 
@@ -184,7 +170,6 @@ class Console:
             "hbm": _gauge_sum(met, "hbm_bytes_in_use"),
             "hbm_peak": _gauge_sum(met, "hbm_bytes_peak"),
             "step_s": sk.get("step_seconds"),
-            "exposed": _labeled_gauge(met, "step_time_fraction", "bucket", "comm"),
             "qps": _gauge_max(met, "serve_qps"),
             "p99": _hist_quantile(met, "serve_phase_seconds", 0.99),
             "occupancy": _gauge_max(met, "serve_engine_slot_occupancy"),
@@ -204,7 +189,6 @@ def _row_cells(disp: Dict[str, Any]) -> List[str]:
         _fmt_bytes(disp["hbm_peak"]),
         _fmt(disp["step_s"], ".4f"),
         "-",  # per-row skew flag filled by the caller (straggler mark)
-        _fmt(disp["exposed"], ".1f", 100.0),
         _fmt(disp["qps"], ".1f"),
         _fmt(disp["p99"], ".1f", 1000.0),
         _fmt(disp["occupancy"], ".0f", 100.0),

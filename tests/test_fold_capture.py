@@ -224,11 +224,10 @@ def test_existing_sections_survive_partial_fold(tmp_path):
     assert data["impala_learner"]["curated_note"] == "keep me"  # merged over
 
 
-def overlap_line(peer, steps_per_s=25.0, exposed=2e-4):
+def qps_line(target, achieved):
     return json.dumps({
-        "metric": "step_overlap", "peer": peer, "steps_per_s": steps_per_s,
-        "exposed_comm_s_per_step": exposed, "overlapped_comm_s_per_step": 0.0,
-        "comm_vs_psum_ratio": 0.95, "windows": 2})
+        "metric": "serve_qps", "qps_target": target, "engine": False,
+        "qps_achieved": achieved, "p99_ms": 40.0})
 
 
 def run_fold_local(log, out):
@@ -238,29 +237,27 @@ def run_fold_local(log, out):
     )
 
 
-def test_local_fold_detects_and_merges_step_overlap_rows(tmp_path):
-    log = tmp_path / "timeline_smoke.log"
+def test_local_fold_detects_and_merges_serve_qps_rows(tmp_path):
+    log = tmp_path / "serve_qps.log"
     out = tmp_path / "BENCH_LOCAL.json"
     out.write_text(json.dumps({
         "rpc_loopback": {"cmd": "x", "stdout": ["keep"], "rc": 0},
-        "step_overlap": {"cmd": "scripts/timeline_smoke.py --smoke",
-                         "stdout": [overlap_line("tl-peer-0", 11.0),
-                                    overlap_line("tl-peer-9", 9.0)],
-                         "rc": 0}}))
-    # Driver chatter around the rows must be salvaged through, and the
-    # step_overlap shape must win detection over the other local sections.
+        "serve_qps": {"cmd": "benchmarks/serve_bench.py --qps 50 400",
+                      "stdout": [qps_line(50, 11.0), qps_line(400, 9.0)],
+                      "rc": 0}}))
+    # Driver chatter around the rows must be salvaged through.
     log.write_text("\n".join([
-        "peer 0: ready", overlap_line("tl-peer-0", 25.0),
-        "not json {", overlap_line("tl-peer-1", 23.0),
-        "TIMELINE SMOKE OK"]) + "\n")
+        "replica 0: ready", qps_line(50, 25.0),
+        "not json {", qps_line(100, 23.0), "SERVE BENCH OK"]) + "\n")
     r = run_fold_local(log, out)
     assert r.returncode == 0, r.stderr
-    assert "step_overlap" in r.stdout
+    assert "serve_qps" in r.stdout
     data = json.loads(out.read_text())
     assert data["rpc_loopback"]["stdout"] == ["keep"]  # other sections intact
-    rows = {json.loads(l)["peer"]: json.loads(l)
-            for l in data["step_overlap"]["stdout"]}
-    # Re-measured peers replaced, unmeasured stored peer kept.
-    assert rows["tl-peer-0"]["steps_per_s"] == 25.0
-    assert rows["tl-peer-1"]["steps_per_s"] == 23.0
-    assert rows["tl-peer-9"]["steps_per_s"] == 9.0
+    assert data["serve_qps"]["cmd"].endswith("--qps 50 100")  # THIS capture's
+    rows = {json.loads(l)["qps_target"]: json.loads(l)
+            for l in data["serve_qps"]["stdout"]}
+    # Re-measured targets replaced, the stored target not re-measured kept.
+    assert rows[50]["qps_achieved"] == 25.0
+    assert rows[100]["qps_achieved"] == 23.0
+    assert rows[400]["qps_achieved"] == 9.0

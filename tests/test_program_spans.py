@@ -1,10 +1,14 @@
 """The program's own spans and counters (docs/TELEMETRY.md "Program spans"):
 what one engine iteration and one train step record, that the tracer mirrors
 them into the profiler wherever jax is imported, that the recompile detector
-stays off the per-call path, the named scopes in the compiled HLO, and
-``EngineService.close`` from a second thread."""
+stays off the per-call path, the named scopes in the compiled HLO,
+``EngineService.close`` from a second thread, and that every span and registry
+series a file of ``chipbench/metrics/`` names is still emitted under that name."""
 
 import asyncio
+import glob
+import json
+import os
 import sys
 import threading
 import types
@@ -13,7 +17,7 @@ import numpy as np
 import pytest
 
 from moolib_tpu import telemetry
-from moolib_tpu.telemetry import devmon, timeline, tracing
+from moolib_tpu.telemetry import devmon, tracing
 
 ITERATION_TREE = {
     "serve.iteration": None,
@@ -63,12 +67,31 @@ def test_spans_are_mirrored_where_jax_is_imported(monkeypatch, jax_imported):
         assert log == [] and "jax" not in sys.modules  # never imported from here
 
 
-@pytest.mark.parametrize("make", [timeline.host_span, timeline.comm_span])
-def test_timeline_phase_span_records_a_tracer_span(make):
+def _fetch_an_action():
+    import jax.numpy as jnp
+
+    from moolib_tpu import rollout
+
+    rollout.PendingAction(jnp.arange(3)).realize()
+
+
+def _redistribute_a_leaf():
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.parallel import collectives
+
+    collectives.redistribute(
+        {"w": jnp.ones(4)}, jax.sharding.SingleDeviceSharding(jax.devices()[1]))
+
+
+@pytest.mark.parametrize("name,call", [
+    ("rollout.act_fetch", _fetch_an_action),
+    ("parallel.redistribute", _redistribute_a_leaf)])
+def test_call_site_records_its_tracer_span(name, call):
     telemetry.get_tracer().clear()
-    with make("t.phase"):
-        pass
-    assert [s.name for s in telemetry.get_tracer().spans()] == ["t.phase"]
+    call()
+    assert [s.name for s in telemetry.get_tracer().spans()].count(name) == 1
 
 
 # ------------------------------------------------------- recompile detector
@@ -153,8 +176,8 @@ def _service(slots=3):
     return EngineService(_Rpc(), engine, default_max_new=4)
 
 
-def _phase_counts():
-    family = telemetry.get_registry().snapshot().get("serve_phase_seconds", {"series": []})
+def _phase_counts(snapshot):
+    family = snapshot.get("serve_phase_seconds", {"series": []})
     return {s["labels"]["phase"]: s["value"]["count"] for s in family["series"]}
 
 
@@ -174,14 +197,16 @@ def one_busy_spell():
     real = service_mod._M_PHASE.observe
     service_mod._M_PHASE.observe = lambda v, **kw: observed.append((kw["phase"], v)) or real(v, **kw)
     telemetry.get_tracer().clear()
-    before = _phase_counts()
+    registry_before = telemetry.get_registry().snapshot()
     try:
         iterations = asyncio.run(asyncio.wait_for(service.loop(total=2), 120))
     finally:
         del service_mod._M_PHASE.observe
-    after = _phase_counts()
+    registry_after = telemetry.get_registry().snapshot()
+    before, after = _phase_counts(registry_before), _phase_counts(registry_after)
     return {"iterations": iterations, "rets": rets, "observed": observed,
             "spans": telemetry.get_tracer().spans(),
+            "registry": (registry_before, registry_after),
             "counts": {k: v - before.get(k, 0) for k, v in after.items()}}
 
 
@@ -357,3 +382,50 @@ def test_named_scope_is_in_the_compiled_op_names(scope, backward, request):
     if backward:
         assert any("transpose(" not in n for n in names)
         assert any("transpose(" in n and scope in n.split("transpose(", 1)[1] for n in names)
+
+
+# ------------------------------------------- what the benchmark reads by name
+def _metric_files(*readers):
+    """The benchmark's metric files of those readers, by metric name.  Read,
+    never written: a later metric is held to the same contract unasked."""
+    root = os.path.join(os.path.dirname(__file__), "..", "chipbench", "metrics")
+    specs = {}
+    for path in sorted(glob.glob(os.path.join(root, "*.json"))):
+        with open(path) as f:
+            spec = json.load(f)
+        if spec.get("reader") in readers:
+            specs[os.path.basename(path)[:-len(".json")]] = spec
+    return specs
+
+
+_SPAN_NAMES = sorted({name for spec in _metric_files("span_time").values()
+                      for name in spec["spans"] + spec.get("among", [])})
+_REGISTRY_METRICS = _metric_files("histogram_mean", "gauge_mean")
+
+
+@pytest.mark.parametrize("name", _SPAN_NAMES)
+def test_every_span_the_benchmark_selects_is_recorded(name, one_busy_spell, tiny_train):
+    """``span_time`` gives ``None`` for a name no span has, and the harness
+    then leaves the metric out of the line in silence."""
+    recorded = {s.name for s in one_busy_spell["spans"]} | set(tiny_train["spans"])
+    assert name in recorded
+
+
+@pytest.mark.parametrize("metric", sorted(_REGISTRY_METRICS))
+def test_every_registry_series_the_benchmark_reads_was_observed(metric, one_busy_spell):
+    """Through the benchmark's own readers, the way its serving runner feeds
+    them: the registry before and after the window, and the gauges sampled
+    from a snapshot inside it."""
+    from chipbench.readers import gauge_mean, histogram_mean
+
+    spec = _REGISTRY_METRICS[metric]
+    before, after = one_busy_spell["registry"]
+    if spec["reader"] == "histogram_mean":
+        measured = types.SimpleNamespace(counters_before=before, counters_after=after)
+        value = histogram_mean.read(spec, {"measured": measured})
+    else:
+        family = after.get(spec["gauge"])
+        samples = [family["series"][0]["value"]] if family and family["series"] else []
+        value = gauge_mean.read(spec, {"measured": types.SimpleNamespace(
+            samples={spec["gauge"]: samples})})
+    assert value is not None and value >= 0

@@ -416,7 +416,7 @@ def test_streaming_sharding_change_raises_typed_error(free_port, small_buckets):
 
 
 # ------------------------------------------------------- train-step overlap
-def test_make_train_step_overlap_grads_end_to_end(free_port, small_buckets):
+def test_overlap_grads_train_step_end_to_end(free_port, small_buckets):
     import jax.numpy as jnp
 
     from moolib_tpu import parallel
@@ -460,7 +460,7 @@ def test_make_train_step_overlap_grads_end_to_end(free_port, small_buckets):
         close_all(broker, accs)
 
 
-def test_make_train_step_overlap_guards():
+def test_overlap_grads_train_step_guards():
     import optax
 
     from moolib_tpu import parallel

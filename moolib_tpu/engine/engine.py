@@ -23,6 +23,25 @@ pool blocks.  With ``mesh=`` and ``prefill_devices=``, prefill runs on a
 the d2d :class:`..batcher.Batcher` (the PR-7 Sebulba seam generalized to
 serving; ``batcher_d2d_bytes_total`` counts the crossing).
 
+**One decode step ahead.**  The step samples on the device and feeds its
+own outputs (tokens, lengths, active flags, budgets) to the next call as
+donated arrays, so nothing in it waits for the host.  ``step()`` therefore
+dispatches step N+1 BEFORE it waits for step N's tokens: the host's work
+between two steps (the fetch's wake-up, replies, the next jit call) runs
+under the chip's step instead of beside it.  What the host reads of a step
+is its **packet**, a small un-donated ``[3, S]`` int32 output (token
+emitted, was the slot active before the step, is it done) whose copy to
+the host starts right after the dispatch.  At most one step is in flight
+between two calls.  The host's mirrors decide whether a step ahead is
+worth dispatching (a slot whose budget the step in flight exhausts is not
+counted); a finish by ``eos_id`` cannot be foreseen, so a step may be in
+flight in which a slot, or every slot, is already inactive on the device:
+it writes to the null block, emits nothing, and its packet says so (the
+host books a step from the packet, never from its mirrors).  A join
+dispatched while a step is in flight lands behind it in the donated chain;
+a slot freed at the fetch of step N is inactive in step N+1, so its blocks
+can be handed to a new request at once.
+
 Greedy decoding only (temperature sampling would need per-slot rng lanes;
 the serving plane is argmax today, matching ``lm_serve``).
 """
@@ -64,6 +83,16 @@ _M_JOINS = _REG.counter(
 )
 _M_RETIRES = _REG.counter(
     "serve_engine_retires_total", "sequences retired (EOS or budget)"
+)
+_M_STEPS_AHEAD = _REG.counter(
+    "serve_engine_steps_ahead_total",
+    "decode steps dispatched while the previous step was still unfetched "
+    "(over the steps: the share of the loop that runs one step ahead)",
+)
+_M_EMPTY_STEPS = _REG.counter(
+    "serve_engine_empty_steps_total",
+    "decode steps booked whose packet had no active slot: dispatched ahead "
+    "of a finish by EOS that the host could not foresee",
 )
 _M_SLOTS = _REG.gauge(
     "serve_engine_slots_active", "decode slots currently occupied"
@@ -188,9 +217,13 @@ class ContinuousBatchingEngine:
         self._remaining_host = np.zeros(S, np.int64)
         self._lengths_host = np.zeros(S, np.int64)
         self._active_host = np.zeros(S, bool)
+        # The step dispatched but not yet booked: its packet, and the slots
+        # the mirrors expect it to advance ([S] bool).
+        self._flight: Optional[Tuple[jax.Array, np.ndarray]] = None
         self._stats = {
             "joins": 0, "retires": 0, "decode_tokens": 0,
             "prefill_tokens": 0, "prefill_pad_tokens": 0, "steps": 0,
+            "steps_ahead": 0, "empty_steps": 0,
         }
 
         # devmon wrappers: the decode step must stay ONE compile for the
@@ -247,8 +280,11 @@ class ContinuousBatchingEngine:
         done = active & (remaining <= 0)
         if self.eos_id is not None:
             done = done | (active & (nxt == self.eos_id))
+        # What the host reads of this step.  Not donated: ``nxt`` is consumed
+        # by the next step, which may be dispatched before the host looks.
+        packet = jnp.stack([nxt, act, done.astype(jnp.int32)])
         active = active & ~done
-        return upd["cache"], tables, lengths, active, nxt, remaining, done
+        return upd["cache"], tables, lengths, active, nxt, remaining, packet
 
     def _prefill_impl(self, params, toks, tp):
         """toks [1, Lb] (bucket-padded prompt), tp the true length.  Returns
@@ -395,41 +431,67 @@ class ContinuousBatchingEngine:
         self._remaining_host[slot] = max_new - 1
         self._lengths_host[slot] = tp
         self._active_host[slot] = True
+        if self._flight is not None:
+            # The join landed behind the step in flight, which saw the slot
+            # inactive whatever the mirrors expected of its last occupant.
+            self._flight[1][slot] = False
         self._stats["joins"] += 1
         _M_JOINS.inc()
         self._update_gauges()
         return slot, emitted
 
     def step(self) -> Tuple[Dict[int, int], List[int]]:
-        """One fixed-shape decode step over every slot.  Returns the tokens
-        emitted this step (slot -> token) and the slots that finished."""
-        if not self._active_host.any():
+        """Book ONE fixed-shape decode step over every slot, the oldest not
+        yet booked.  Returns the tokens it emitted (slot -> token) and the
+        slots that finished; ``{}, []`` when nothing is active.  The step
+        after it is dispatched before this one's tokens are waited for, and
+        stays in flight until the next call (module docstring)."""
+        if self._flight is None and not self._active_host.any():
             return {}, []
         with telemetry.span("engine.step"):
             return self._step()
 
-    def _step(self):
-        """``step`` with a slot to advance, under its span."""
+    def _launch(self) -> jax.Array:
+        """The decode jit's call, and the start of its packet's copy to the
+        host.  Returns the packet."""
+        (self._cache, self._tables, self._lengths, self._active,
+         self._tokens, self._remaining, packet) = self._step_jit(
+            self._params_dec, self._cache, self._tables, self._lengths,
+            self._active, self._tokens, self._remaining,
+        )
+        packet.copy_to_host_async()
+        return packet
+
+    def _dispatch(self, stepping: np.ndarray) -> None:
+        """Put a step in flight that the mirrors expect to advance
+        ``stepping``."""
         t0 = time.monotonic()
         with telemetry.span("engine.step_dispatch"):
-            (self._cache, self._tables, self._lengths, self._active,
-             self._tokens, self._remaining, done) = self._step_jit(
-                self._params_dec, self._cache, self._tables, self._lengths,
-                self._active, self._tokens, self._remaining,
-            )
+            self._flight = self._launch(), stepping
+        _M_PHASE.observe(time.monotonic() - t0, phase="dispatch")
+
+    def _step(self):
+        """``step`` with a step to book, under its span."""
+        if self._flight is None:
+            self._dispatch(self._active_host.copy())
+        (packet, stepping), self._flight = self._flight, None
+        # Slots the step after this one would advance, by the mirrors: still
+        # budgeted once this one is counted.  (A finish by EOS is not seen.)
+        ahead = self._active_host & (self._remaining_host - stepping > 0)
+        if ahead.any():
+            self._dispatch(ahead)
+            self._stats["steps_ahead"] += 1
+            _M_STEPS_AHEAD.inc()
         t1 = time.monotonic()
         # The decode loop's D2H wait.
         with telemetry.span("engine.decode_fetch"):
-            # mtlint: allow-host-sync(the decode loop's one intentional D2H: emitted tokens/done flags must reach the host to answer requests)
-            nxt = np.asarray(self._tokens)
-            done = np.asarray(done)  # mtlint: allow-host-sync(same fetch: part of the decode loop's one D2H)
-        t2 = time.monotonic()
-        _M_PHASE.observe(t1 - t0, phase="dispatch")
-        _M_PHASE.observe(t2 - t1, phase="fetch")
+            # mtlint: allow-host-sync(the decode loop's one intentional D2H: a step's packet of emitted tokens, was-active and done flags must reach the host to answer requests; its copy started at the dispatch and the next step is already queued)
+            nxt, was_active, done = np.asarray(packet)
+        _M_PHASE.observe(time.monotonic() - t1, phase="fetch")
         emissions: Dict[int, int] = {}
         finished: List[int] = []
         with telemetry.span("engine.step_host"):
-            stepped = np.nonzero(self._active_host)[0]
+            stepped = np.nonzero(was_active)[0]
             # The step attended over positions <= length in each active slot.
             live = int((self._lengths_host[stepped] // self.block_size + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
             _M_KV_LIVE.observe(live / (self.slots * self.max_blocks_per_seq))
@@ -445,6 +507,9 @@ class ContinuousBatchingEngine:
             self._stats["steps"] += 1
             self._stats["decode_tokens"] += len(emissions)
             _M_TOKENS.inc(len(emissions))
+            if not emissions:
+                self._stats["empty_steps"] += 1
+                _M_EMPTY_STEPS.inc()
         return emissions, finished
 
     def retire(self, slot: int) -> List[int]:
@@ -503,12 +568,16 @@ class ContinuousBatchingEngine:
             shapes += 1
         # One real step compiles the decode path and clears the warmup joins
         # (zero budget -> done immediately; writes landed in the null block).
-        (self._cache, self._tables, self._lengths, self._active,
-         self._tokens, self._remaining, _done) = self._step_jit(
-            self._params_dec, self._cache, self._tables, self._lengths,
-            self._active, self._tokens, self._remaining,
-        )
+        # It is launched and fetched as the loop does it, booked nowhere, and
+        # leaves no step in flight.
+        np.asarray(self._launch())  # mtlint: allow-host-sync(warm-up, outside the decode loop: the packet's first D2H)
         return shapes + 1
+
+    def close(self) -> None:
+        """Drop the step in flight, if any, unbooked: its tokens are never
+        emitted, so the sequences in the slots cannot continue.  For a
+        service on its way out, before it fails what is in its slots."""
+        self._flight = None
 
     # ----------------------------------------------------------------- stats
     def stats(self) -> Dict[str, Any]:

@@ -277,6 +277,9 @@ class EngineService(ServeService):
             self._fail_inflight()
 
     def _fail_inflight(self) -> None:
+        # The engine's step in flight goes first: booked later, it would
+        # finish slots whose requests are answered here.
+        self._engine.close()
         with self._lock:
             inflight, self._slot_req = self._slot_req, {}
         for req in inflight.values():

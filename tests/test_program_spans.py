@@ -288,6 +288,34 @@ def test_close_from_a_second_thread_mid_loop():
     assert waiting.answers == [("error", "serve generate: closed")] and idle._slot_req == {}
 
 
+def test_close_with_a_step_in_flight_answers_every_request_once():
+    """One request finishes in the iteration that sees the close, two are in
+    the step in flight, one is queued.  The engine drops that step unbooked,
+    then every request has exactly one answer."""
+    service = _service(slots=3)
+    eng = service._engine
+    rets = [_Ret() for _ in range(4)]
+    prompt = np.arange(1, 5, dtype=np.int32)
+    for ret, budget in zip(rets, (2, 9, 9, 9)):
+        service._on_request(ret, prompt, budget)
+    engine_step = eng.step
+
+    def step_then_close():
+        out = engine_step()
+        assert eng._flight is not None
+        service.close()  # the loop runs: it answers on its way out
+        return out
+
+    eng.step = step_then_close
+    asyncio.run(asyncio.wait_for(service.loop(), 120))
+    assert eng._flight is None and service._slot_req == {}
+    assert [len(r.answers) for r in rets] == [1, 1, 1, 1]
+    kind, out = rets[0].answers[0]
+    assert kind == "ok" and len(out) == len(prompt) + 2
+    assert all(r.answers[0][0] == "error" and "closed" in r.answers[0][1]
+               for r in rets[1:])
+
+
 # ------------------------------------------------------- train loop, scopes
 @pytest.fixture(scope="module")
 def tiny_train():

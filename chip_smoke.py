@@ -80,7 +80,15 @@ FULL = dict(
     # (prompt length, token budget) per request; all in flight at once on
     # the engine, so slots join and retire at different steps.
     serve=dict(_LM_WIDTH, seq_len=64, max_new_tokens=16, slots=8,
-               requests=[(5, 4), (17, 9), (33, 16), (64, 12), (17, 16), (40, 1)]),
+               requests=[(5, 4), (17, 9), (33, 16), (64, 12), (17, 16), (40, 1)],
+               # The latent-attention + dropless-experts preset
+               # (models.latent_moe.tiny_config) beside it: what the chip adds
+               # to the CPU tests is the Mosaic lowering of the latent cache
+               # layout and of the grouped matmul.  Each emitted token must
+               # lie within this many standard deviations of the float32
+               # reference's largest logit (bfloat16 on the chip picks a near
+               # tie; a wrong row or expert picks a token several down).
+               latent_gap_sigma=0.5),
     mesh=dict(_LM_WIDTH, seq_len=2048, batch_size=4, steps=10,
               ring_seq_len=4096, ring_steps=5),
 )
@@ -416,7 +424,52 @@ def phase_serve(s: Smoke) -> dict:
                  f"request {i} (prompt {length}, budget {budget}): engine "
                  f"{got} != generate() {ref[:budget]}")
         matched += budget
-    return s.facts("serve", ready, requests=len(z["requests"]), tokens_matched=matched)
+    latent = _serve_latent(s)
+    return s.facts("serve", ready, requests=len(z["requests"]), tokens_matched=matched,
+                   **latent)
+
+
+def _serve_latent(s: Smoke) -> dict:
+    """The tiny latent-attention preset through ``lm_serve --engine
+    --config``: the same requests, each token checked by the client against
+    the plain float32 reference (computed on the CPU from the same seed)."""
+    z = s.sizes["serve"]
+    config = os.path.join(s.log_dir, "latent_moe_tiny.json")
+    s.run(
+        "serve_latent_preset",
+        [sys.executable, "-c",
+         "import json; from moolib_tpu.models.latent_moe import tiny_config; "
+         f"json.dump(tiny_config(), open({config!r}, 'w'))"],
+        timeout=120, env={"JAX_PLATFORMS": "cpu"})
+    port = _free_port()
+    name = "serve_latent"
+    replica = s.start(name, [
+        sys.executable, "-m", "moolib_tpu.examples.lm_serve",
+        "--listen", f"127.0.0.1:{port}", "--name", name, "--engine",
+        "--config", config, "--slots", str(z["slots"]),
+        "--seq_len", str(z["seq_len"]), "--max_new_tokens", str(z["max_new_tokens"]),
+        "--seed", "0"])
+    try:
+        ready = s.wait_ready(replica, timeout=600)
+        s.check_device(name, ready)
+        spec = dict(address=f"127.0.0.1:{port}", replica=name, requests=z["requests"],
+                    budgets=True, config=config, seed=0)
+        client = s.run(
+            f"{name}_client",
+            [sys.executable, os.path.abspath(__file__), "--client", json.dumps(spec)],
+            timeout=300, env={"JAX_PLATFORMS": "cpu"})
+        _require(replica.proc.poll() is None, name, "the replica died while serving")
+        report = client.report()
+    finally:
+        replica.reap()
+    for (length, budget), got in zip(z["requests"], report["tokens"]):
+        _require(len(got) == budget, name,
+                 f"prompt {length}: {len(got)} tokens for a budget of {budget}")
+    _require(report["gap_sigma_max"] <= z["latent_gap_sigma"], name,
+             f"a token lies {report['gap_sigma_max']:.3f} standard deviations under "
+             f"the reference's largest logit (limit {z['latent_gap_sigma']})")
+    return {"latent_tokens_checked": sum(b for _, b in z["requests"]),
+            "latent_gap_sigma_max": round(report["gap_sigma_max"], 4)}
 
 
 def client_main(spec: dict) -> None:
@@ -434,8 +487,13 @@ def client_main(spec: dict) -> None:
         rpc, fn="generate", replicas=[spec["replica"]], deadline_s=240.0,
         attempt_timeout=240.0, max_attempts=1, metadata=spec["budgets"],
     )
+    config = None
+    if spec.get("config"):
+        with open(spec["config"]) as f:
+            config = json.load(f)
     rng = np.random.default_rng(1)
-    prompts = [rng.integers(2, spec["vocab"], length).astype(np.int32)
+    vocab = config["vocab_size"] if config else spec["vocab"]
+    prompts = [rng.integers(2, vocab, length).astype(np.int32)
                for length, _ in spec["requests"]]
     try:
         if spec["budgets"]:
@@ -448,7 +506,32 @@ def client_main(spec: dict) -> None:
         client.close()
         rpc.close()
     tokens = [out[len(p):].tolist() for p, out in zip(prompts, outs)]
-    print(REPORT_PREFIX + json.dumps({"tokens": tokens}), flush=True)
+    report = {"tokens": tokens}
+    if config:
+        report["gap_sigma_max"] = _latent_gap(config, spec["seed"], outs, prompts)
+    print(REPORT_PREFIX + json.dumps(report), flush=True)
+
+
+def _latent_gap(config: dict, seed: int, outs, prompts) -> float:
+    """On the CPU: the replica's weights again from its seed, the plain
+    reference's logits for every sequence, and the largest (reference
+    maximum - reference logit of the emitted token) / deviation."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench.reference import glm_moe_lite
+    from moolib_tpu.models.latent_moe import LatentMoELM
+
+    params = jax.jit(LatentMoELM.from_config(config).init)(jax.random.key(seed))
+    worst = 0.0
+    for out, prompt in zip(outs, prompts):
+        want = np.asarray(glm_moe_lite.logits(params, jnp.asarray(out[:-1]), config))
+        want = want[len(prompt) - 1:]
+        emitted = out[len(prompt):]
+        gap = (want.max(-1) - want[np.arange(len(emitted)), emitted]) / want.std(-1)
+        worst = max(worst, float(gap.max()))
+    return worst
 
 
 # ------------------------------------------------------- four-chip phases

@@ -42,6 +42,28 @@ dispatched while a step is in flight lands behind it in the donated chain;
 a slot freed at the fetch of step N is inactive in step N+1, so its blocks
 can be handed to a new request at once.
 
+**What a model offers.**  The engine knows no architecture.  It takes a
+``models.TransformerLM`` (wrapped by ``models.transformer.PagedTransformerLM``)
+or any object with ``max_len`` (the positions it can address) and
+
+- ``cache_spec(num_blocks, block_size)``: a pytree of shapes, the block axis
+  first in every leaf: the pools are allocated from it (per-head K and V, or
+  one latent row a token a layer: the layout is the model's);
+- ``prefill(params, toks [1, Lb], tp, block_size)`` -> (the prompt's cache
+  rows as ``ceil(Lb / block_size)`` blocks, in whatever pytree the model's
+  ``write_rows`` takes; logits [V] at ``tp - 1``; int32 counters or None);
+- ``write_rows(cache, rows, block_ids)`` -> cache: a join's scatter of those
+  blocks into the pools (few, stacked arrays keep a join's jit call cheap:
+  a row pytree of one array a layer cost ``submit`` 1.3 ms);
+- ``decode(params, cache, tokens [S], paged, mesh=None)`` -> (logits [S, V],
+  cache, int32 counters or None), with ``step_counters`` /
+  ``prefill_counters`` their lengths.
+
+Counters ride what the host fetches anyway (extra rows of a step's packet,
+extra entries beside a prefill's first token): no copy is added.  What they
+mean is the model's: it receives them back through ``observe_step(counters)``
+and ``observe_prefill(counters, prompt_len)``.
+
 Greedy decoding only (temperature sampling would need per-slot rng lanes;
 the serving plane is argmax today, matching ``lm_serve``).
 """
@@ -58,7 +80,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..telemetry import devmon
-from ..models.transformer import TransformerLM
+from ..models.transformer import PagedTransformerLM, TransformerLM
 from ..ops.paged_attention import PagedState
 from ..serving import _M_PHASE, bucket, bucket_shapes
 from .kv_pool import BlockPool, PoolExhausted
@@ -103,6 +125,13 @@ _M_OCC = _REG.gauge(
 _M_BLOCKS_FREE = _REG.gauge(
     "serve_engine_blocks_free", "KV pool blocks on the free list"
 )
+_M_ROWS_LIVE = _REG.histogram(
+    "serve_engine_live_row_share",
+    "per decode step: cache positions the step attends over (active slots "
+    "only) over slots x positions a slot: the rows the attention kernel "
+    "reads, whatever a row holds",
+    buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0),
+)
 _M_KV_LIVE = _REG.histogram(
     "serve_engine_kv_live_share",
     "per decode step: KV blocks holding a position the step attends over "
@@ -126,14 +155,15 @@ class ContinuousBatchingEngine:
     stats are safe from other threads.
     """
 
-    def __init__(self, model: TransformerLM, params, *, slots: int = 8,
+    def __init__(self, model, params, *, slots: int = 8,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  max_seq_len: Optional[int] = None,
                  max_prompt_len: Optional[int] = None,
+                 min_prompt_len: int = 1,
                  eos_id: Optional[int] = None,
                  mesh=None, prefill_devices: int = 0):
-        if model.moe_num_experts:
-            raise ValueError("the engine does not support MoE models yet")
+        if isinstance(model, TransformerLM):
+            model = PagedTransformerLM(model)
         self.model = model
         self.slots = int(slots)
         if self.slots < 1:
@@ -151,28 +181,12 @@ class ContinuousBatchingEngine:
             num_blocks = 1 + self.slots * self.max_blocks_per_seq
         self.pool = BlockPool(num_blocks, self.block_size)
         self.max_prompt_len = int(max_prompt_len or self.seq_capacity)
+        # The shortest prompt the traffic sends: warm-up compiles no prefill
+        # bucket below its bucket (a shorter prompt still runs, in that one).
+        self.min_prompt_len = max(1, min(int(min_prompt_len), self.max_prompt_len))
         self.eos_id = eos_id
-        self._L = model.num_layers
-        self._Hk = model.num_kv_heads or model.num_heads
-        self._hd = model.d_model // model.num_heads
-
-        self._dec = TransformerLM(
-            vocab_size=model.vocab_size, d_model=model.d_model,
-            num_heads=model.num_heads, num_kv_heads=model.num_kv_heads,
-            num_layers=model.num_layers, max_len=model.max_len,
-            attention="dense",  # unused: decode attention is the paged kernel
-            dtype=model.dtype, pos_embedding=model.pos_embedding,
-            decode=True, kv_num_blocks=num_blocks,
-            kv_block_size=self.block_size,
-        )
-        self._pre = TransformerLM(
-            vocab_size=model.vocab_size, d_model=model.d_model,
-            num_heads=model.num_heads, num_kv_heads=model.num_kv_heads,
-            num_layers=model.num_layers, max_len=model.max_len,
-            attention="flash" if model.attention == "ring" else model.attention,
-            dtype=model.dtype, pos_embedding=model.pos_embedding,
-            collect_kv=True,
-        )
+        self._n_step_counters = getattr(model, "step_counters", 0)
+        self._n_prefill_counters = getattr(model, "prefill_counters", 0)
 
         # Optional disaggregated prefill: first N mesh devices prefill, the
         # rest decode; K/V cross through the device-path Batcher (counted
@@ -196,14 +210,10 @@ class ContinuousBatchingEngine:
         self.set_params(params)
 
         S, MB = self.slots, self.max_blocks_per_seq
-        cache: Dict[str, Dict[str, jax.Array]] = {}
-        shape = (num_blocks, self.block_size, self._Hk, self._hd)
-        for i in range(self._L):
-            cache[f"block{i}"] = {
-                "pool_k": jnp.zeros(shape, model.dtype),
-                "pool_v": jnp.zeros(shape, model.dtype),
-            }
-        self._cache = self._place_decode(cache)
+        # The pools, as the model lays them out (block axis first).
+        self._cache = self._place_decode(jax.tree.map(
+            lambda spec: jnp.zeros(spec.shape, spec.dtype),
+            model.cache_spec(num_blocks, self.block_size)))
         self._tables = self._place_decode(jnp.zeros((S, MB), jnp.int32))
         self._lengths = self._place_decode(jnp.zeros((S,), jnp.int32))
         self._active = self._place_decode(jnp.zeros((S,), jnp.bool_))
@@ -265,15 +275,11 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------ jit bodies
     def _step_impl(self, params, cache, tables, lengths, active, tokens,
                    remaining):
-        logits, upd = self._dec.apply(
-            {"params": params["params"], "cache": cache},
-            tokens[:, None],
-            mesh=self._decode_mesh,
-            paged=PagedState(tables, lengths, active),
-            mutable=["cache"],
-        )
+        logits, cache, counters = self.model.decode(
+            params, cache, tokens, PagedState(tables, lengths, active),
+            mesh=self._decode_mesh)
         act = active.astype(jnp.int32)
-        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         nxt = jnp.where(active, nxt, tokens)
         lengths = lengths + act
         remaining = remaining - act
@@ -283,51 +289,36 @@ class ContinuousBatchingEngine:
         # What the host reads of this step.  Not donated: ``nxt`` is consumed
         # by the next step, which may be dispatched before the host looks.
         packet = jnp.stack([nxt, act, done.astype(jnp.int32)])
+        if self._n_step_counters:
+            # The model's counters ride the packet: whole rows after the three.
+            S = self.slots
+            rows = -(-self._n_step_counters // S)
+            counters = jnp.pad(counters.astype(jnp.int32),
+                               (0, rows * S - self._n_step_counters))
+            packet = jnp.concatenate([packet, counters.reshape(rows, S)])
         active = active & ~done
-        return upd["cache"], tables, lengths, active, nxt, remaining, packet
+        return cache, tables, lengths, active, nxt, remaining, packet
 
     def _prefill_impl(self, params, toks, tp):
         """toks [1, Lb] (bucket-padded prompt), tp the true length.  Returns
-        pool-shaped K/V ([L, nbw, bs, Hk, hd]) and the first greedy token
-        (argmax of the logits at tp-1 — identical to ``generate()``)."""
-        logits, col = self._pre.apply(
-            {"params": params["params"]}, toks, mutable=["kv"]
-        )
-        last = jnp.take(logits[0], tp - 1, axis=0)
-        tok0 = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        Lb = toks.shape[1]
-        nbw = -(-Lb // self.block_size)
-        pad = nbw * self.block_size - Lb
-        ks = jnp.stack(
-            [col["kv"][f"block{i}"]["k"][0][0] for i in range(self._L)]
-        )
-        vs = jnp.stack(
-            [col["kv"][f"block{i}"]["v"][0][0] for i in range(self._L)]
-        )
-        if pad:
-            widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-            ks, vs = jnp.pad(ks, widths), jnp.pad(vs, widths)
-        shape = (self._L, nbw, self.block_size, self._Hk, self._hd)
-        return (ks.reshape(shape).astype(self.model.dtype),
-                vs.reshape(shape).astype(self.model.dtype), tok0)
+        the prompt's cache rows in the pools' own layout (``ceil(Lb /
+        block_size)`` blocks a leaf) and the first greedy token (argmax of
+        the logits at tp-1 — identical to ``generate()``), followed in the
+        same vector by the model's prefill counters where it has any."""
+        rows, logits, counters = self.model.prefill(
+            params, toks, tp, self.block_size)
+        tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if self._n_prefill_counters:
+            tok0 = jnp.concatenate([tok0[None], counters.astype(jnp.int32)])
+        return rows, tok0
 
     def _join_impl(self, cache, tables, lengths, active, tokens, remaining,
-                   slot, row, tp, tok0, rem0, ks, vs, block_ids):
-        """Donated in-place join: scatter the prefilled K/V blocks into the
+                   slot, row, tp, tok0, rem0, rows, block_ids):
+        """Donated in-place join: scatter the prefilled blocks into the
         pools and light the slot.  ``slot``/``tp``/``tok0``/``rem0`` are
         traced scalars and ``row``/``block_ids`` traced vectors — a join
         never recompiles (one trace per block-count bucket)."""
-        new_cache = {}
-        for i in range(self._L):
-            c = cache[f"block{i}"]
-            new_cache[f"block{i}"] = {
-                "pool_k": c["pool_k"].at[block_ids].set(
-                    ks[i].astype(c["pool_k"].dtype)
-                ),
-                "pool_v": c["pool_v"].at[block_ids].set(
-                    vs[i].astype(c["pool_v"].dtype)
-                ),
-            }
+        new_cache = self.model.write_rows(cache, rows, block_ids)
         tables = jax.lax.dynamic_update_slice(tables, row[None, :], (slot, 0))
         lengths = lengths.at[slot].set(tp)
         active = active.at[slot].set(True)
@@ -336,12 +327,18 @@ class ContinuousBatchingEngine:
         return new_cache, tables, lengths, active, tokens, remaining
 
     # --------------------------------------------------------------- serving
+    def _bucket(self, prompt_len: int) -> int:
+        """The prefill shape of a prompt: ``serving.bucket``'s, and never one
+        below the shortest prompt's (warm-up compiled none there)."""
+        return max(bucket(prompt_len, self.max_prompt_len),
+                   bucket(self.min_prompt_len, self.max_prompt_len))
+
     def can_accept(self, prompt_len: int, max_new: int) -> bool:
         """A free slot AND enough free blocks for the worst case of this
         request (its bucket-padded prompt or its full budget)."""
         if not self._free_slots:
             return False
-        lb = bucket(int(prompt_len), self.max_prompt_len)
+        lb = self._bucket(int(prompt_len))
         need = self.pool.blocks_for(max(lb, int(prompt_len) + int(max_new)))
         return self.pool.available() >= need
 
@@ -387,7 +384,7 @@ class ContinuousBatchingEngine:
         each place the host can wait."""
         total = tp + max_new
         with telemetry.span("engine.prefill_dispatch"):
-            lb = bucket(tp, self.max_prompt_len)
+            lb = self._bucket(tp)
             pad = lb - tp
             toks = np.pad(prompt, (0, pad))[None]
             if pad:
@@ -395,24 +392,28 @@ class ContinuousBatchingEngine:
                 _M_PAD_TOKENS.inc(pad)
             toks_dev = (toks if self._prefill_sharding is None
                         else jax.device_put(toks, self._prefill_sharding))
-            ks, vs, tok0 = self._prefill_jit(
+            rows, first = self._prefill_jit(
                 self._params_pre, toks_dev, np.int32(tp)
             )
         self._stats["prefill_tokens"] += tp
         _M_PREFILL_TOKENS.inc(tp)
         with telemetry.span("engine.first_token_fetch"):
-            tok0 = int(tok0)  # waits for the prefill
+            # mtlint: allow-host-sync(the prefill's one D2H: its first token, and the model's prefill counters in the same vector)
+            first = np.asarray(first)  # waits for the prefill
+        tok0 = int(first.reshape(-1)[0])
+        if self._n_prefill_counters:
+            self.model.observe_prefill(first[1:], tp)
         emitted = [tok0]
         if max_new == 1 or (self.eos_id is not None and tok0 == self.eos_id):
             return None, emitted
         if self._xfer is not None:
             # Prefill submesh -> decode submesh, one device-path crossing.
-            self._xfer.stack((ks, vs))
-            ks, vs = jax.tree.map(lambda x: x[0], self._xfer.get())
+            self._xfer.stack(rows)
+            rows = jax.tree.map(lambda x: x[0], self._xfer.get())
         if not self._free_slots:
             raise NoFreeSlot(f"all {self.slots} slots occupied")
         with telemetry.span("engine.join"):
-            nbw = int(ks.shape[1])
+            nbw = self.pool.blocks_for(lb)
             n_alloc = self.pool.blocks_for(max(lb, total))
             block_ids = self.pool.alloc(n_alloc)  # PoolExhausted -> stay queued
             slot = self._free_slots.pop()
@@ -424,7 +425,7 @@ class ContinuousBatchingEngine:
                 self._tokens, self._remaining,
                 np.int32(slot), row, np.int32(tp), np.int32(tok0),
                 np.int32(max_new - 1),
-                ks, vs, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
+                rows, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
             )
         self._slot_blocks[slot] = block_ids
         self._emitted[slot] = emitted
@@ -486,7 +487,8 @@ class ContinuousBatchingEngine:
         # The decode loop's D2H wait.
         with telemetry.span("engine.decode_fetch"):
             # mtlint: allow-host-sync(the decode loop's one intentional D2H: a step's packet of emitted tokens, was-active and done flags must reach the host to answer requests; its copy started at the dispatch and the next step is already queued)
-            nxt, was_active, done = np.asarray(packet)
+            packet = np.asarray(packet)
+        nxt, was_active, done = packet[:3]
         _M_PHASE.observe(time.monotonic() - t1, phase="fetch")
         emissions: Dict[int, int] = {}
         finished: List[int] = []
@@ -495,6 +497,12 @@ class ContinuousBatchingEngine:
             # The step attended over positions <= length in each active slot.
             live = int((self._lengths_host[stepped] // self.block_size + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
             _M_KV_LIVE.observe(live / (self.slots * self.max_blocks_per_seq))
+            _M_ROWS_LIVE.observe(
+                int((self._lengths_host[stepped] + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
+                / (self.slots * self.seq_capacity))
+            if self._n_step_counters and len(stepped):
+                self.model.observe_step(
+                    packet[3:].reshape(-1)[:self._n_step_counters])
             self._lengths_host[stepped] += 1
             for s in stepped:
                 tok = int(nxt[s])
@@ -542,28 +550,28 @@ class ContinuousBatchingEngine:
         number of distinct compiled shapes."""
         shapes = 0
         seen_nbw = set()
-        for lb in sorted(set(bucket_shapes(self.max_prompt_len))):
+        for lb in sorted({self._bucket(b) for b in bucket_shapes(self.max_prompt_len)}):
             toks = np.zeros((1, lb), np.int32)
             toks_dev = (toks if self._prefill_sharding is None
                         else jax.device_put(toks, self._prefill_sharding))
-            ks, vs, _ = self._prefill_jit(
+            rows, _ = self._prefill_jit(
                 self._params_pre, toks_dev, np.int32(lb)
             )
             shapes += 1
-            nbw = int(ks.shape[1])
+            nbw = self.pool.blocks_for(lb)
             if nbw in seen_nbw:
                 continue
             seen_nbw.add(nbw)
             if self._xfer is not None:
-                self._xfer.stack((ks, vs))
-                ks, vs = jax.tree.map(lambda x: x[0], self._xfer.get())
+                self._xfer.stack(rows)
+                rows = jax.tree.map(lambda x: x[0], self._xfer.get())
             row = np.zeros(self.max_blocks_per_seq, np.int32)
             (self._cache, self._tables, self._lengths, self._active,
              self._tokens, self._remaining) = self._join_jit(
                 self._cache, self._tables, self._lengths, self._active,
                 self._tokens, self._remaining,
                 np.int32(0), row, np.int32(0), np.int32(0), np.int32(0),
-                ks, vs, np.zeros(nbw, np.int32),
+                rows, np.zeros(nbw, np.int32),
             )
             shapes += 1
         # One real step compiles the decode path and clears the warmup joins

@@ -258,6 +258,13 @@ def main(argv=None):
         "generate",
     )
     p.add_argument(
+        "--config", default="",
+        help="with --engine: build the model from this configuration file "
+        "(published keys, as chipbench/configs/*.json: a latent-attention "
+        "decoder with dropless experts, models/latent_moe.py) instead of "
+        "from --vocab/--d_model/--layers/--heads",
+    )
+    p.add_argument(
         "--slots", type=int, default=0,
         help="engine decode slots (0 = --batch_size)",
     )
@@ -298,14 +305,27 @@ def main(argv=None):
     utils.init_compile_cache()  # before the first jit (utils/compile_cache.py)
     telemetry.init_from_env()  # opt-in exporters (docs/TELEMETRY.md)
 
-    model = make_model(flags)
+    if flags.config and not (flags.engine and flags.listen):
+        raise SystemExit("--config builds a model only the engine serves: "
+                         "pass --engine and --listen with it")
+    model = None if flags.config else make_model(flags)
     if flags.listen:
         from .. import parallel
 
         mesh = parallel.parse_mesh_spec(flags.mesh)
-        rng = np.random.default_rng(flags.seed)
-        toks = jnp.asarray(rng.integers(0, flags.vocab, (1, flags.seq_len), dtype=np.int32))
-        params = model.init(jax.random.key(flags.seed), toks)
+        if flags.config:
+            from ..models.latent_moe import LatentMoELM
+
+            # bfloat16 as the configurations state it, on the chip; the CPU
+            # backend has no bfloat16 x bfloat16 -> float32 product.
+            model = LatentMoELM.from_config(
+                flags.config, max_len=flags.seq_len + flags.max_new_tokens,
+                dtype=jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32)
+            params = jax.jit(model.init)(jax.random.key(flags.seed))
+        else:
+            rng = np.random.default_rng(flags.seed)
+            toks = jnp.asarray(rng.integers(0, flags.vocab, (1, flags.seq_len), dtype=np.int32))
+            params = model.init(jax.random.key(flags.seed), toks)
         rpc = Rpc()
         rpc.set_name(flags.name)
         rpc.listen(flags.listen)

@@ -351,6 +351,82 @@ class TransformerLM(nn.Module):
         return head(x.astype(jnp.float32))
 
 
+class PagedTransformerLM:
+    """What ``engine.ContinuousBatchingEngine`` asks of a model, for a
+    :class:`TransformerLM`: pools ``pool_k`` / ``pool_v`` of ``[num_blocks,
+    block_size, Hk, hd]`` a layer, prefill through the ``collect_kv`` twin
+    (the whole prompt, teacher-forced), one-token decode through the
+    ``decode=True`` twin over the paged pools."""
+
+    step_counters = 0
+    prefill_counters = 0
+
+    def __init__(self, model: "TransformerLM"):
+        if model.moe_num_experts:
+            raise ValueError(
+                "TransformerLM's capacity-dropping SwitchMoE has no decode "
+                "form; models.latent_moe holds the dropless expert layer")
+        self.model = model
+        self.max_len = model.max_len
+        self._pre = self._twin(
+            attention="flash" if model.attention == "ring" else model.attention,
+            collect_kv=True)
+
+    def _twin(self, **kw):
+        m = self.model
+        return TransformerLM(
+            vocab_size=m.vocab_size, d_model=m.d_model, num_heads=m.num_heads,
+            num_kv_heads=m.num_kv_heads, num_layers=m.num_layers,
+            max_len=m.max_len, dtype=m.dtype, pos_embedding=m.pos_embedding,
+            **kw)
+
+    def cache_spec(self, num_blocks: int, block_size: int):
+        m = self.model
+        pool = jax.ShapeDtypeStruct(
+            (num_blocks, block_size, m.num_kv_heads or m.num_heads,
+             m.d_model // m.num_heads), m.dtype)
+        return {f"block{i}": {"pool_k": pool, "pool_v": pool}
+                for i in range(m.num_layers)}
+
+    def prefill(self, params, toks, tp, block_size: int):
+        """Rows: K and V of all layers stacked, ``[L, nbw, block_size, Hk,
+        hd]`` each."""
+        logits, col = self._pre.apply(
+            {"params": params["params"]}, toks, mutable=["kv"])
+        Lb = toks.shape[1]
+        nbw = -(-Lb // block_size)
+
+        def blocks(which):
+            x = jnp.stack([col["kv"][f"block{i}"][which][0][0]
+                           for i in range(self.model.num_layers)])
+            x = jnp.pad(x, ((0, 0), (0, nbw * block_size - Lb), (0, 0), (0, 0)))
+            return x.reshape(x.shape[0], nbw, block_size, *x.shape[2:]).astype(
+                self.model.dtype)
+
+        return (blocks("k"), blocks("v")), jnp.take(logits[0], tp - 1, axis=0), None
+
+    def write_rows(self, cache, rows, block_ids):
+        ks, vs = rows
+        new_cache = {}
+        for i in range(self.model.num_layers):
+            c = cache[f"block{i}"]
+            new_cache[f"block{i}"] = {
+                "pool_k": c["pool_k"].at[block_ids].set(ks[i].astype(c["pool_k"].dtype)),
+                "pool_v": c["pool_v"].at[block_ids].set(vs[i].astype(c["pool_v"].dtype)),
+            }
+        return new_cache
+
+    def decode(self, params, cache, tokens, paged, mesh=None):
+        pool = cache["block0"]["pool_k"]
+        dec = self._twin(
+            attention="dense",  # unused: decode attention is the paged kernel
+            decode=True, kv_num_blocks=pool.shape[0], kv_block_size=pool.shape[1])
+        logits, upd = dec.apply(
+            {"params": params["params"], "cache": cache}, tokens[:, None],
+            mesh=mesh, paged=paged, mutable=["cache"])
+        return logits[:, 0], upd["cache"], None
+
+
 def generate(
     model: TransformerLM,
     params,

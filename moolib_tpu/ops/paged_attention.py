@@ -409,3 +409,190 @@ def _paged_call(
     weight = jax.nn.softmax(lse[..., :1].reshape(S, group, fold, Hk, 1), axis=2)
     out = jnp.sum(out * weight, axis=2)  # [S, group, Hk, hd]
     return out.transpose(0, 2, 1, 3).reshape(S, 1, H, hd).astype(q.dtype)
+
+
+# --------------------------------------------------------------------------
+# The latent layout: every query head over ONE shared row a token
+# --------------------------------------------------------------------------
+# Latent attention (MLA) caches, for a token and a layer, one row
+# ``[c_kv | RoPE(k_r)]`` that all heads share.  In decode the up-projection
+# of the keys is absorbed into the query, so a head's score is its absorbed
+# query against the whole row and its value is the row's first ``value_width``
+# entries: per-head K and V never exist.  The pool is
+# ``[num_blocks, layers, block_size, W]`` (W the row, padded to the 128 lanes:
+# a block's rows of one layer are one contiguous copy), and one kernel call
+# serves one layer.  With H heads against one row the products are matrices
+# ([H, W] x [W, tokens] and [H, tokens] x [tokens, value_width]), so this
+# kernel feeds the MXU where the per-head kernel above uses the VPU.
+
+
+# Tokens in one VMEM window of the latent kernel (two are held).
+_LATENT_WINDOW_TOKENS = 512
+
+
+def latent_kv_write(pool, rows, layer, block_tables, lengths, active):
+    """Write one new row per slot into ``pool`` [NB, L, bs, W] at ``layer``,
+    in place under donation (or as a loop's carry).  rows: [S, W]."""
+    bs = pool.shape[2]
+    blk = jnp.take_along_axis(block_tables, (lengths // bs)[:, None], axis=1)[:, 0]
+    blk = jnp.where(active, blk, 0)
+    return pool.at[blk, layer, lengths % bs].set(rows.astype(pool.dtype))
+
+
+def latent_gathered_attention(q, pool, layer, block_tables, lengths, *,
+                              value_width, scale):
+    """The mathematics of :func:`latent_paged_attention` over an XLA gather
+    of the whole capacity: the kernel's oracle in the tests, never the chip's
+    path.  Returns [S, H, value_width] float32."""
+    S, nb = block_tables.shape
+    ctx = pool[block_tables, layer].astype(jnp.float32)  # [S, nb, bs, W]
+    ctx = ctx.reshape(S, nb * pool.shape[2], pool.shape[3])
+    scores = jnp.einsum("shw,stw->sht", q.astype(jnp.float32), ctx,
+                        precision=jax.lax.Precision.HIGHEST) * scale
+    mask = jnp.arange(ctx.shape[1])[None, None, :] <= lengths[:, None, None]
+    p_att = jax.nn.softmax(jnp.where(mask, scores, _NEG_INF), axis=-1)
+    return jnp.einsum("sht,stv->shv", p_att, ctx[..., :value_width],
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _latent_kernel(
+    order_ref, count_ref, tables_ref, lengths_ref, layer_ref, q_ref, pool_ref,
+    o_ref, buf, sem, *, pages, value_width, scale,
+):
+    """All slots of one layer.  SMEM: order [S] (active slots first), count
+    [1], tables [S, MB], lengths [S], layer [1]; q [S, Hp, W] in VMEM; the
+    pool [NB, L, bs, W] stays in HBM; buf [2, pages * bs, W] are the two
+    windows of ``pages`` blocks."""
+    MB = tables_ref.shape[1]
+    Hp = q_ref.shape[1]
+    bs = pool_ref.shape[2]
+    layer = layer_ref[0]
+
+    def live_blocks(s):
+        return jnp.clip(lengths_ref[s] // bs + 1, 1, MB)
+
+    def for_each_copy(s, w, b, do):
+        def body(p, carry):
+            blk = tables_ref[s, w * pages + p]
+            do(pltpu.make_async_copy(
+                pool_ref.at[blk, layer], buf.at[b, pl.ds(p * bs, bs)], sem.at[b]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(live_blocks(s) - w * pages, pages), body, 0)
+
+    def start(s, w, b):
+        for_each_copy(s, w, b, lambda copy: copy.start())
+
+    def wait(s, w, b):
+        for_each_copy(s, w, b, lambda copy: copy.wait())
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    count = count_ref[0]
+    position = jax.lax.broadcasted_iota(jnp.int32, (pages * bs, 1), 0)
+    column = jax.lax.broadcasted_iota(jnp.int32, (1, pages * bs), 1)
+
+    @pl.when(count > 0)
+    def _():
+        start(order_ref[0], 0, 0)
+
+    def slot_body(i, n_done):
+        s = order_ref[i]
+        length = lengths_ref[s]
+        n_windows = pl.cdiv(live_blocks(s), pages)
+        q = q_ref[s]  # [Hp, W]
+
+        def window_body(w, carry):
+            n_done, m, l, acc = carry
+            b = n_done % 2
+            last = w + 1 >= n_windows
+            i_next = jnp.where(last, i + 1, i)
+
+            @pl.when(i_next < count)
+            def _():
+                start(order_ref[i_next], jnp.where(last, 0, w + 1), 1 - b)
+
+            wait(s, w, b)
+            base = w * pages * bs
+            # Rows past the length are a stale tail or a page that was not
+            # copied (whatever VMEM held): zeroed, since 0 x NaN is NaN.
+            k = jnp.where(base + position <= length, buf[b], jnp.zeros_like(buf[b]))
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [Hp, P]
+            sc = jnp.where(base + column <= length, sc, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            p_att = jnp.exp(sc - m_new)
+            l = l * corr + jnp.sum(p_att, axis=-1, keepdims=True)
+            acc = acc * corr + jax.lax.dot_general(
+                p_att.astype(k.dtype), k[:, :value_width],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            return n_done + 1, m_new, l, acc
+
+        n_done, _m, l, acc = jax.lax.fori_loop(
+            0, n_windows, window_body,
+            (n_done, jnp.full((Hp, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((Hp, 1), jnp.float32),
+             jnp.zeros((Hp, value_width), jnp.float32)))
+        o_ref[s] = acc / l
+        return n_done
+
+    jax.lax.fori_loop(0, count, slot_body, 0)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("value_width", "scale", "interpret"))
+def latent_paged_attention(
+    q, pool, layer, block_tables, lengths, active, *, value_width, scale,
+    interpret=None,
+):
+    """Decode attention of every head against the shared latent rows of
+    ``layer``, read from the pool in place.  q: [S, H, W] (absorbed queries,
+    laid out as the rows are); pool: [num_blocks, layers, block_size, W] with
+    W a multiple of 128; block_tables [S, MB], lengths [S] (slot s attends
+    over positions ``<= lengths[s]``), active [S] bool (an inactive slot reads
+    nothing and gives 0); ``layer`` a traced int32 scalar.  Returns
+    [S, H, value_width] float32: the softmax-weighted sum of the rows' first
+    ``value_width`` entries.  Products are in the pool's dtype on the MXU
+    with float32 accumulation; masks and the softmax are float32.  Mosaic on
+    ``tpu``, Pallas interpret mode on ``cpu``; a jit of its own so that the
+    layers of a model share one lowering."""
+    if interpret is None:
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"latent_paged_attention has a Mosaic (tpu) lowering and a cpu "
+                f"interpret mode for tests; platform {backend!r} has neither")
+        interpret = backend == "cpu"
+    S, H, W = q.shape
+    NB, L, bs, Wp = pool.shape
+    if W != Wp or (not interpret and (W % 128 or value_width % 128)):
+        raise ValueError(
+            f"latent_paged_attention: q {q.shape} against a pool {pool.shape}: "
+            "the row must be the pool's, in whole 128-lane tiles")
+    tile_rows = 32 // pool.dtype.itemsize
+    Hp = -(-H // tile_rows) * tile_rows
+    qp = jnp.pad(q.astype(pool.dtype), ((0, 0), (0, Hp - H), (0, 0)))
+    pages = max(1, min(block_tables.shape[1], _LATENT_WINDOW_TOKENS // bs))
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    count = jnp.sum(active, dtype=jnp.int32).reshape(1)
+    with jax.named_scope("mla_decode_attention"):
+        out = pl.pallas_call(
+            functools.partial(_latent_kernel, pages=pages,
+                              value_width=value_width, scale=scale),
+            out_shape=jax.ShapeDtypeStruct((S, Hp, value_width), jnp.float32),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 5 + [
+                pl.BlockSpec(memory_space=pltpu.VMEM),  # q
+                pl.BlockSpec(memory_space=pl.ANY),  # the pool: HBM, copied by hand
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+            interpret=interpret,
+            name="mla_decode_attention",
+        )(order, count, block_tables, lengths,
+          jnp.asarray(layer, jnp.int32).reshape(1), qp, pool)
+    return out[:, :H]
+

@@ -12,11 +12,14 @@ Use :func:`moe_param_spec` for the PartitionSpecs of the expert weights.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 
@@ -134,3 +137,175 @@ def moe_shardings(params, mesh, ep_axis: str = "ep", base=None):
         lambda o, b: b if o is None else o, overlay, base,
         is_leaf=lambda x: x is None or isinstance(x, Sharding),
     )
+
+
+# --------------------------------------------------------------------------
+# Dropless top-k routing: sort by expert, grouped matmul, unsort
+# --------------------------------------------------------------------------
+# ``SwitchMoE`` above dispatches through a dense [tokens, experts, capacity]
+# one-hot and drops what overflows a capacity.  The layer below drops nothing
+# at any load: the token-expert pairs are sorted by expert, each projection is
+# ONE grouped matmul over the sorted rows (group g = the rows that chose
+# expert g, multiplied by expert g's matrix), and the rows go back to their
+# tokens.  The grouped matmul visits (row tile, expert) pairs that hold rows
+# and no others, so the weights of an expert no token chose are never read: a
+# decode step's time follows the experts its few tokens touch.
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _group_work(group_sizes, m_tiles: int, tm: int):
+    """The (expert, row tile) pairs a grouped matmul visits, in row order.
+    Returns int32 vectors of the static length ``m_tiles + G - 1`` (the most
+    there can be): expert, row tile, first and one-past-last row of the
+    expert, and the count of real pairs.  Entries past the count repeat the
+    last real pair, so that the kernel's block indices do not move there and
+    nothing is copied for them."""
+    G = group_sizes.shape[0]
+    W = m_tiles + G - 1
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    first = starts // tm
+    tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    upto = jnp.cumsum(tiles)  # pairs of experts 0..g
+    n_work = upto[-1]
+    i = jnp.minimum(jnp.arange(W, dtype=jnp.int32), n_work - 1)
+    gid = jnp.searchsorted(upto, i, side="right").astype(jnp.int32)
+    tile = first[gid] + i - (upto[gid] - tiles[gid])
+    return (gid, tile.astype(jnp.int32), starts[gid].astype(jnp.int32),
+            ends[gid].astype(jnp.int32), n_work.astype(jnp.int32).reshape(1))
+
+
+def _gmm_kernel(gid_ref, tile_ref, start_ref, end_ref, n_ref, layer_ref, x_ref,
+                w_ref, o_ref, *, tm):
+    i = pl.program_id(1)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        prod = jax.lax.dot_general(
+            x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        row = tile_ref[i] * tm + jax.lax.broadcasted_iota(jnp.int32, prod.shape, 0)
+        mine = (row >= start_ref[i]) & (row < end_ref[i])
+        # A row tile's block stays in VMEM while consecutive experts fill in
+        # their rows of it; the first of them clears what it does not own.
+        fresh = (i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != tile_ref[i])
+        keep = jnp.where(fresh, jnp.zeros_like(prod), o_ref[...].astype(jnp.float32))
+        o_ref[...] = jnp.where(mine, prod, keep).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
+def grouped_matmul(x, w, group_sizes, layer=None, *, tm=None, tn=512,
+                   interpret=None):
+    """``out[r] = x[r] @ w[g]`` for the rows r of group g, where the rows of
+    x [M, K] are sorted by group and ``group_sizes`` [G] int32 sums to M (rows
+    past the sum are undefined); w: [G, K, N], or the stacked matrices of
+    every layer [L, G, K, N] with ``layer`` a traced index (under a scan a
+    sliced ``w[layer]`` would be copied whole, every expert of it, each
+    iteration).  One Pallas (Mosaic) kernel, named ``moe_expert_matmul`` in
+    the profiler's trace; it reads ``w[g]`` only for groups that hold rows,
+    once for every row tile they span.  A jit of its own, so that layers
+    share one lowering."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if w.ndim == 3:
+        w, layer = w[None], 0
+    M, K = x.shape
+    _, G, _, N = w.shape
+    if tm is None:
+        tm = min(256, _round_up(M, 16))
+    tn = min(tn, N)
+    if N % tn or (not interpret and (tn % 128 or K % 128)):
+        raise ValueError(f"grouped_matmul: N={N} must tile by tn={tn}, and K={K} "
+                         "and tn by the 128 lanes")
+    Mp = _round_up(M, tm)
+    if Mp != M:
+        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
+    m_tiles = Mp // tm
+    work = _group_work(group_sizes.astype(jnp.int32), m_tiles, tm)
+    W = m_tiles + G - 1
+    with jax.named_scope("moe_expert_matmul"):
+        out = pl.pallas_call(
+            functools.partial(_gmm_kernel, tm=tm),
+            out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=6,
+                grid=(N // tn, W),
+                in_specs=[
+                    pl.BlockSpec((tm, K), lambda n, i, g, t, s, e, c, l: (t[i], 0)),
+                    pl.BlockSpec((None, None, K, tn),
+                                 lambda n, i, g, t, s, e, c, l: (l[0], g[i], 0, n)),
+                ],
+                out_specs=pl.BlockSpec((tm, tn), lambda n, i, g, t, s, e, c, l: (t[i], n)),
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=48 << 20),
+            interpret=interpret,
+            name="moe_expert_matmul",
+        )(*work, jnp.asarray(layer, jnp.int32).reshape(1), x, w)
+    return out[:M]
+
+
+def _silu_gate(gu, dtype):
+    """``silu(gate) * up`` of gate | up side by side, in float32."""
+    gu = gu.astype(jnp.float32)
+    f = gu.shape[-1] // 2
+    return (jax.nn.silu(gu[..., :f]) * gu[..., f:]).astype(dtype)
+
+
+def swiglu(x, w_gate_up, w_down):
+    """``(silu(x W_g) * (x W_u)) W_d`` with W_g | W_u side by side in one
+    matrix [D, 2F]; products accumulate in float32."""
+    gu = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
+    return jnp.dot(_silu_gate(gu, x.dtype), w_down, preferred_element_type=jnp.float32)
+
+
+def sigmoid_topk_route(x32, w_router, bias, top_k: int, scale: float):
+    """The ``noaux_tc`` router with one group: scores ``sigmoid(x W_g)`` in
+    float32 at the highest matmul precision (a near tie decides which expert
+    runs); the ``top_k`` largest of ``score + bias`` are chosen, and weighed
+    by their scores WITHOUT the bias, normalised to sum to 1, times ``scale``.
+    Returns (experts [T, k] int32, weights [T, k] float32)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x32.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    return chosen.astype(jnp.int32), weights
+
+
+def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
+                 layer=None, interpret=None):
+    """One expert layer over tokens x32 [T, D] (float32, already normed).
+
+    ``p``: ``router`` [D, E] and ``router_bias`` [E] (float32), ``experts_gu``
+    [E, D, 2F], ``experts_down`` [E, F, D] (or both stacked over layers, with
+    ``layer`` the index: see :func:`grouped_matmul`), ``shared_gu`` [D, 2F],
+    ``shared_down`` [F, D].  Returns (y [T, D] float32, tokens an expert
+    [E] int32).  ``valid`` [T] bool leaves pad tokens out of the count (they
+    are still computed: the shapes are fixed)."""
+    T, D = x32.shape
+    E = p["router"].shape[-1]
+    dtype = p["experts_gu"].dtype
+    experts, weights = sigmoid_topk_route(
+        x32, p["router"], p["router_bias"], top_k, scale)
+    flat = experts.reshape(-1)  # pair j belongs to token j // top_k
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    load = sizes
+    if valid is not None:  # pad tokens' pairs are counted under a bin of their own
+        counted = jnp.where(jnp.repeat(valid, top_k), flat, E)
+        load = jnp.bincount(counted, length=E + 1)[:E].astype(jnp.int32)
+    x = x32.astype(dtype)
+    rows = x[order // top_k]  # [T k, D], sorted by expert
+    gu = grouped_matmul(rows, p["experts_gu"], sizes, layer, interpret=interpret)
+    down = grouped_matmul(_silu_gate(gu, dtype), p["experts_down"], sizes, layer,
+                          interpret=interpret)
+    back = jnp.argsort(order)  # where pair j went
+    pairs = down[back].reshape(T, top_k, D).astype(jnp.float32)
+    routed = jnp.sum(pairs * weights[..., None], axis=1)
+    return routed + swiglu(x, p["shared_gu"], p["shared_down"]), load

@@ -198,6 +198,10 @@ def bucket_shapes(cap: int) -> List[int]:
 # --------------------------------------------------------------------------
 # admission control
 # --------------------------------------------------------------------------
+# note_service: the most one sample may read, in units of the EMA before it.
+_EMA_SAMPLE_CAP = 5.0
+
+
 class AdmissionController:
     """Bounded admission in front of the batching queue.
 
@@ -249,6 +253,12 @@ class AdmissionController:
             if self._ema is None:
                 self._ema = value
             else:
+                # One sample moves the estimate by at most its own size: a
+                # step that straddled a host stall (seconds for a few tokens,
+                # a hundred times the EMA) would otherwise reject the next
+                # request by its deadline.  A real slowdown still doubles the
+                # estimate every three samples.
+                value = min(value, _EMA_SAMPLE_CAP * self._ema)
                 self._ema += self.alpha * (value - self._ema)
 
     def ema_batch_seconds(self) -> Optional[float]:

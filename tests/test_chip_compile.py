@@ -179,6 +179,50 @@ def test_paged_attention_reroutes_a_head_size_mosaic_cannot_copy(chip):
     assert reroutes() == before + 1
 
 
+def test_latent_decode_step_compiles_at_serve_geometry(chip):
+    """One layer's latent row write and the latent paged attention kernel at
+    ``glm_serve_docqa``'s geometry (32 slots x 48 blocks of 64 tokens, 7
+    layers, rows of 640 = 576 values + 64 lanes of padding, 20 heads): Mosaic
+    takes the kernel, the one pool updates in place, nothing of the size of a
+    gathered context is made."""
+    from moolib_tpu.ops.paged_attention import latent_kv_write, latent_paged_attention
+
+    def step(q, rows, pool, layer, tables, lengths, active):
+        pool = latent_kv_write(pool, rows, layer, tables, lengths, active)
+        att = latent_paged_attention(q, pool, layer, tables, lengths, active,
+                                     value_width=512, scale=1 / 16, interpret=False)
+        return att, pool
+
+    pool = jax.ShapeDtypeStruct((1 + 32 * 48, 7, 64, 640), jnp.bfloat16)
+    args = _on(chip, (
+        jnp.zeros((32, 20, 640), jnp.bfloat16), jnp.zeros((32, 640), jnp.bfloat16), pool,
+        jnp.zeros((), jnp.int32), jnp.zeros((32, 48), jnp.int32),
+        jnp.zeros((32,), jnp.int32), jnp.zeros((32,), jnp.bool_)))
+    compiled, text = _compile(jax.jit(step, donate_argnums=(2,)), *args)
+    assert text.count("tpu_custom_call") == 1 and "mla_decode_attention" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool.size * 2
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize("rows,k,n", [(128, 2048, 3072), (128, 1536, 2048),
+                                      (8192, 2048, 3072)])
+def test_grouped_matmul_compiles_at_serve_geometry(chip, rows, k, n):
+    """The experts' grouped matmul over the stacked matrices of 6 layers of
+    64 experts (decode: 32 slots x 4 experts a token; prefill: a 2,048-token
+    bucket): Mosaic takes it, and no layer's experts are sliced out of the
+    stack (a copy of 805 MB a layer under the scan)."""
+    from moolib_tpu.parallel.moe import grouped_matmul
+
+    args = _on(chip, (jnp.zeros((rows, k), jnp.bfloat16),
+                      jax.ShapeDtypeStruct((6, 64, k, n), jnp.bfloat16),
+                      jnp.zeros((64,), jnp.int32), jnp.zeros((), jnp.int32)))
+    compiled, text = _compile(
+        lambda x, w, sizes, layer: grouped_matmul(x, w, sizes, layer, interpret=False), *args)
+    assert text.count("tpu_custom_call") == 1 and "moe_expert_matmul" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 # R2D2's stored sequence (ROADMAP R4): burn-in 40 + unroll 80 frames of
 # 84x84x4 uint8, ~3.4 MB each; a 2,048-sequence ring is 6.9 GB of the chip's
 # 16 GB.  Insert 16 sequences, sample and re-prioritise 64.

@@ -29,7 +29,7 @@ TINY = dict(
                total_steps=200, min_sgd_steps=5),
     lm=dict(_W, seq_len=256, batch_size=2, steps=8),
     serve=dict(_W, seq_len=8, max_new_tokens=4, slots=2,
-               requests=[(3, 2), (8, 4), (5, 1)]),
+               requests=[(3, 2), (8, 4), (5, 1)], latent_gap_sigma=0.5),
     mesh=dict(_W, seq_len=256, batch_size=4, steps=3, ring_seq_len=512, ring_steps=2),
 )
 

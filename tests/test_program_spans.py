@@ -210,6 +210,30 @@ def one_busy_spell():
             "counts": {k: v - before.get(k, 0) for k, v in after.items()}}
 
 
+@pytest.fixture(scope="module")
+def one_expert_spell():
+    """A model with routed experts through the same engine (the tiny preset
+    of ``models/latent_moe.py``): one request, prefilled and decoded to its
+    budget; the registry before and after.  Its counters (experts touched a
+    step, the fullest expert of a prefill) exist for no other model."""
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.engine import ContinuousBatchingEngine
+    from moolib_tpu.models.latent_moe import LatentMoELM, tiny_config
+
+    model = LatentMoELM.from_config(tiny_config(), dtype=jnp.float32)
+    params = jax.jit(model.init)(jax.random.key(0))
+    engine = ContinuousBatchingEngine(model, params, slots=2, block_size=8,
+                                      max_seq_len=32, max_prompt_len=16)
+    before = telemetry.get_registry().snapshot()
+    slot, _ = engine.submit(np.arange(2, 9, dtype=np.int32), 3)
+    while not engine.step()[1]:
+        pass
+    engine.retire(slot)
+    return {"registry": (before, telemetry.get_registry().snapshot())}
+
+
 def _parent(span, spans):
     """The innermost span of the same thread that contains this one."""
     around = [p for p in spans if p is not span and p.tid == span.tid
@@ -440,17 +464,22 @@ def test_every_span_the_benchmark_selects_is_recorded(name, one_busy_spell, tiny
 
 
 @pytest.mark.parametrize("metric", sorted(_REGISTRY_METRICS))
-def test_every_registry_series_the_benchmark_reads_was_observed(metric, one_busy_spell):
+def test_every_registry_series_the_benchmark_reads_was_observed(
+        metric, one_busy_spell, one_expert_spell):
     """Through the benchmark's own readers, the way its serving runner feeds
     them: the registry before and after the window, and the gauges sampled
-    from a snapshot inside it."""
+    from a snapshot inside it.  A series that only a model with experts
+    observes is looked for in that model's spell."""
     from chipbench.readers import gauge_mean, histogram_mean
 
     spec = _REGISTRY_METRICS[metric]
     before, after = one_busy_spell["registry"]
     if spec["reader"] == "histogram_mean":
-        measured = types.SimpleNamespace(counters_before=before, counters_after=after)
-        value = histogram_mean.read(spec, {"measured": measured})
+        for before, after in (one_busy_spell["registry"], one_expert_spell["registry"]):
+            measured = types.SimpleNamespace(counters_before=before, counters_after=after)
+            value = histogram_mean.read(spec, {"measured": measured})
+            if value is not None:
+                break
     else:
         family = after.get(spec["gauge"])
         samples = [family["series"][0]["value"]] if family and family["series"] else []

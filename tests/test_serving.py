@@ -102,6 +102,28 @@ def test_admission_controller_estimates_and_rejects():
     assert ac.ema_batch_seconds() == pytest.approx(0.1 + 0.25 * 0.4)
 
 
+def test_admission_one_stalled_sample_does_not_reject_the_next_request():
+    """A decode step that straddled a host stall reports seconds-per-token a
+    hundred times the EMA (PERF.md section 7: the refusals of PRs 26, 27).
+    One such sample may move the estimate by at most its own size, so the
+    next request's deadline still holds; a lasting slowdown is still seen."""
+    ac = AdmissionController(max_queue=256, per_token=True,
+                             pending_tokens=lambda: 2000)
+    for _ in range(8):
+        ac.note_service(0.0092, tokens=4)  # 2.3 ms a token
+    assert ac.ema_batch_seconds() == pytest.approx(0.0023)
+    assert ac.admit(0, deadline_s=96.0) is None  # 2000 x 2.3 ms = 4.6 s
+    ac.note_service(1.2, tokens=4)  # the stalled step: 0.3 s a token
+    assert ac.ema_batch_seconds() <= 2 * 0.0023
+    assert ac.admit(0, deadline_s=96.0) is None
+    # Unbounded, the same sample read 0.0023 + 0.25 * 0.2977 = 0.0767 s a
+    # token, 153 s for the pending tokens: a typed reject.
+    for _ in range(40):  # a slowdown that lasts is followed
+        ac.note_service(1.2, tokens=4)
+    assert ac.ema_batch_seconds() == pytest.approx(0.3, rel=0.05)
+    assert ac.admit(0, deadline_s=96.0) == "deadline"
+
+
 def test_bucket_policy_canonical_in_serving():
     assert [bucket(n, 16) for n in (1, 2, 3, 5, 9, 16, 40)] == [
         1, 2, 4, 8, 16, 16, 16,

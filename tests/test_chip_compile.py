@@ -330,3 +330,40 @@ def test_lm_train_step_updates_its_state_in_place(chip, monkeypatch, batch, most
     print(f"lm.train step B={batch}: resident {resident / 1e9:.2f} GB, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
     assert resident < most_gb * 1e9
+
+
+@pytest.mark.parametrize("layers", [1, 2])
+def test_lm_decode_step_converts_no_embedding_table(chip, monkeypatch, layers):
+    """``PagedTransformerLM.decode`` at ``lm_serve_steady``'s widths (d 2,048
+    as 16 heads of 128, vocabulary 50,257, 32 slots x 64 blocks of 16,
+    float32 weights, bfloat16 compute): the step gathers its 32 rows from the
+    float32 table and converts the rows.  Through ``nn.Embed`` it held a
+    bfloat16 copy of the whole table, 206 MB written every step."""
+    from moolib_tpu.models.transformer import PagedTransformerLM, TransformerLM
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    # The paged kernel asks jax.default_backend() whether to go through
+    # Mosaic or interpret mode; in this process that is the cpu.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, per, block = 32, 64, 16
+    lm = TransformerLM(vocab_size=50257, d_model=2048, num_heads=16, num_layers=layers,
+                       max_len=2048, attention="dense", dtype=jnp.bfloat16)
+    model = PagedTransformerLM(lm)
+    params = jax.eval_shape(
+        lambda: lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    cache = model.cache_spec(1 + slots * per, block)
+    state = PagedState(jnp.zeros((slots, per), jnp.int32), jnp.zeros((slots,), jnp.int32),
+                       jnp.zeros((slots,), jnp.bool_))
+
+    def step(params, cache, tokens, state):
+        return model.decode(params, cache, tokens, state)[:2]
+
+    compiled, text = _compile(
+        jax.jit(step, donate_argnums=(1,)),
+        *_on(chip, (params, cache, jnp.zeros((slots,), jnp.int32), state)))
+    assert text.count("tpu_custom_call") == layers  # the paged kernel, once a layer
+    assert "bf16[50257,2048]" not in text
+    # Read 0.77 and 2.18 MB, and 207.0 and 208.1 MB through nn.Embed.  A
+    # bfloat16 copy of the position table alone would be 8.4 MB (its shape is
+    # also proj's, which a fusion converts as it reads: the text cannot tell).
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

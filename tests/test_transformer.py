@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from moolib_tpu import parallel
 from moolib_tpu.models.transformer import TransformerLM
@@ -548,3 +549,123 @@ def test_generate_sharded_composes_with_gqa():
     want = generate(model, params, prompt, 8)
     got = generate_sharded(model, params, prompt, 8, mesh)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# The lookups gather rows from the table as stored, then convert the rows
+# (RowEmbed); nn.Embed converts the whole table first.  Distinct row counts
+# tell the two tables apart.
+_V, _M, _D = 96, 48, 64
+
+
+def _lookup_path(path, dtype):
+    """``(fn, params)``: ``fn(params)`` runs ``path`` over a model of compute
+    dtype ``dtype`` with float32 parameters."""
+    from moolib_tpu.models.transformer import PagedTransformerLM, pipeline_lm_apply
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    model = TransformerLM(
+        vocab_size=_V, d_model=_D, num_heads=2, num_layers=2, max_len=_M,
+        attention="dense", dtype=dtype,
+    )
+    # Repeated ids, and the table's last row.
+    tokens = jnp.asarray([[5, 5, 7, _V - 1, 0, 5, 9, 9]] * 2, jnp.int32)
+    params = model.init(jax.random.key(1), tokens)
+    if path == "apply":
+        return lambda p: model.apply(p, tokens), params
+    if path == "pipeline":
+        mesh = parallel.make_mesh({"pp": 2}, devices=jax.devices()[:2])
+        return lambda p: pipeline_lm_apply(
+            model, p, tokens, mesh, num_microbatches=2), params
+    paged = PagedTransformerLM(model)
+    if path == "prefill":
+        return lambda p: paged.prefill(p, tokens[:1], jnp.int32(6), 4)[:2], params
+    assert path == "decode"
+    slots, per, block = 2, 4, 4
+    cache = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), paged.cache_spec(1 + slots * per, block))
+    state = PagedState(
+        jnp.arange(1, 1 + slots * per, dtype=jnp.int32).reshape(slots, per),
+        jnp.asarray([3, _M - 1], jnp.int32), jnp.ones((slots,), bool))
+    return lambda p: paged.decode(
+        p, cache, jnp.asarray([5, _V - 1], jnp.int32), state)[:2], params
+
+
+def _table_conversions(jaxpr, shape):
+    """``convert_element_type`` equations over an operand of ``shape``, in
+    ``jaxpr`` and every jaxpr nested in it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "convert_element_type" and eqn.invars[0].aval.shape == shape:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _table_conversions(sub, shape)
+    return found
+
+
+@pytest.mark.parametrize("path", ["apply", "prefill", "decode", "pipeline"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("table", ["token", "position"])
+def test_lookup_gathers_rows_before_it_converts_them(monkeypatch, table, dtype, path):
+    """Against ``take(table.astype(dtype), ids)``, what ``nn.Embed`` does: the
+    same bits out, the same parameter tree, and no conversion of anything of
+    the table's shape in the traced call."""
+    import flax.linen as nn
+
+    from moolib_tpu.models import transformer
+
+    rows = {"token": _V, "position": _M}[table]
+    fn, params = _lookup_path(path, dtype)
+    new = jax.jit(fn)(params)
+    jaxpr = jax.make_jaxpr(fn)(params).jaxpr
+    assert not _table_conversions(jaxpr, (rows, _D))
+
+    # The old form for this table alone; the other keeps the new.
+    row_embed = transformer.RowEmbed
+    monkeypatch.setattr(
+        transformer, "RowEmbed",
+        lambda n, d, **kw: (nn.Embed if n == rows else row_embed)(n, d, **kw))
+    old_fn, old_params = _lookup_path(path, dtype)
+    assert jax.tree.structure(old_params) == jax.tree.structure(params)
+    # Same names, shapes, dtypes and draws: checkpoints load.
+    jax.tree.map(np.testing.assert_array_equal, old_params, params)
+    jax.tree.map(np.testing.assert_array_equal, jax.jit(old_fn)(params), new)
+    if dtype == jnp.bfloat16:  # the check above can see what it looks for
+        assert _table_conversions(jax.make_jaxpr(old_fn)(params).jaxpr, (rows, _D))
+
+
+def test_lookup_gradient_is_the_old_forms():
+    """The train step's arithmetic does not change: the table's gradient is
+    ``nn.Embed``'s bit for bit (the rows' bfloat16 cotangents summed into a
+    bfloat16 table, repeated ids included, and the sum converted), which lies
+    within bfloat16 round-off of the float32 sum.  Plain autodiff of the
+    gather-first form would make the float32 sum, and under ``dp`` all-reduce
+    a float32 ``[vocab, d]`` gradient where the step has a bfloat16 one."""
+    import flax.linen as nn
+
+    from moolib_tpu.models.transformer import RowEmbed
+
+    ids = jnp.asarray([[3, 3, 3, 3, 0, 7, 3, 7]] * 4, jnp.int32)  # row 3: 20 times
+    table = jax.random.normal(jax.random.key(0), (_V, _D), jnp.float32)
+    weight = jax.random.normal(jax.random.key(1), (*ids.shape, _D), jnp.float32)
+
+    def grad_of(cls, dtype):
+        layer = cls(_V, _D, dtype=dtype)
+        loss = lambda t: (layer.apply({"params": {"embedding": t}}, ids)
+                          * weight.astype(dtype)).astype(jnp.float32).sum()
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(table).jaxpr
+        return np.asarray(jax.jit(jax.grad(loss))(table)), jaxpr
+
+    new, jaxpr = grad_of(RowEmbed, jnp.bfloat16)
+    assert new.dtype == np.float32
+    np.testing.assert_array_equal(new, grad_of(nn.Embed, jnp.bfloat16)[0])
+    np.testing.assert_array_equal(
+        grad_of(RowEmbed, jnp.float32)[0], grad_of(nn.Embed, jnp.float32)[0])
+    # The backward converts the float32 table no more than the forward does.
+    assert not [e for e in _table_conversions(jaxpr, (_V, _D))
+                if e.invars[0].aval.dtype == jnp.float32]
+    g = weight.astype(jnp.bfloat16).astype(jnp.float32)  # the rows' cotangent
+    want = np.asarray(jnp.zeros((_V, _D), jnp.float32).at[ids].add(g))
+    # bfloat16 keeps 8 bits, so each of row 3's 20 partial sums rounds by up
+    # to 2**-9 of its size: the sum reads 0.75% off the largest entry.
+    off = np.abs(new - want).max() / np.abs(want).max()
+    assert 2.0**-12 < off < 2.0**-6

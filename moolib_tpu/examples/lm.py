@@ -41,6 +41,11 @@ _M_DONATED = telemetry.get_registry().gauge(
     "bytes of train state (params + optimizer state) the compiled lm.train "
     "step updates in place, a device",
 )
+_M_STATE = telemetry.get_registry().gauge(
+    "lm_state_device_bytes",
+    "bytes of train state (params + optimizer state) one device holds: the "
+    "whole state, or under a mesh with dp > 1 that device's cut of it",
+)
 
 
 def make_flags(argv=None):
@@ -225,6 +230,37 @@ def _apply_kwargs(flags, mesh) -> dict:
     return {}
 
 
+def weight_shardings(params, flags, mesh):
+    """``params``' shardings under ``mesh``, twice.  ``whole``, as the loss
+    takes the weights: replicated, but for expert weights cut over ``ep``
+    where the mesh has that axis.  ``cut``, as the train state holds them in
+    and out of the step and between steps: every large leaf cut over ``dp``
+    besides (``parallel.param_shardings``' "fsdp" rule; on a mesh whose
+    ``dp`` is 1 it is ``whole``).  From shapes alone."""
+    if flags.moe_experts and "ep" in mesh.axis_names:
+        whole = parallel.moe_shardings(params, mesh, "ep")
+    else:
+        whole = parallel.param_shardings(params, mesh)
+    return whole, parallel.param_shardings(params, mesh, "fsdp", base=whole)
+
+
+def state_shardings(params, opt_state, flags, mesh):
+    """Where the train state lives under ``mesh``: ``params`` cut over ``dp``
+    (:func:`weight_shardings`), a moment of ``opt_state`` as its parameter,
+    the count replicated."""
+    _, cut = weight_shardings(params, flags, mesh)
+    return cut, parallel.mirror_shardings(opt_state, params, cut, mesh)
+
+
+def state_device_bytes(*trees) -> int:
+    """Bytes of ``trees``' arrays the fullest device holds."""
+    held = {}
+    for x in jax.tree_util.tree_leaves(trees):
+        for shard in x.addressable_shards:
+            held[shard.device] = held.get(shard.device, 0) + shard.data.nbytes
+    return max(held.values(), default=0)
+
+
 def make_step(flags, model, opt, mesh=None):
     """``loss_fn(params, tokens) -> (loss, acc)`` and ``step(params,
     opt_state, tokens) -> (params, opt_state, loss, acc)`` of these flags on
@@ -272,7 +308,21 @@ def make_step(flags, model, opt, mesh=None):
             return -ll.mean() + flags.moe_aux_weight * aux, acc
 
     def step(params, opt_state, tokens):
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, tokens)
+        weights = params
+        if dp > 1:
+            # The state comes in cut over dp (jit_step).  The loss takes the
+            # weights whole, so each is gathered once and the forward and
+            # backward passes are the replicated step's; each gradient goes
+            # back to the chip that owns the slice (a reduce-scatter where
+            # the replicated step all-reduced), and the optimizer below runs
+            # over a dp-th of every leaf.  The shardings of the arguments
+            # alone do not say this: the partitioner then keeps the weights
+            # cut and moves the activations.
+            whole, cut = weight_shardings(params, flags, mesh)
+            weights = jax.lax.with_sharding_constraint(params, whole)
+        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(weights, tokens)
+        if dp > 1:
+            grads = jax.lax.with_sharding_constraint(grads, cut)
         with jax.named_scope("optimizer"):
             updates, opt_state = opt.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state, loss, acc
@@ -280,16 +330,21 @@ def make_step(flags, model, opt, mesh=None):
     return loss_fn, step
 
 
-def jit_step(step, params, flags, mesh=None):
-    """``step`` jitted for ``params`` (arrays, or their shapes) on ``mesh``,
-    and ``put``, which places a batch where the jit wants it.
+def jit_step(step, params, opt_state, flags, mesh=None):
+    """``step`` jitted for ``params`` and ``opt_state`` (arrays, or their
+    shapes) on ``mesh``, and ``put``, which places a batch where the jit
+    wants it.
 
     ``params`` and ``opt_state`` are DONATED: the new state takes the old
     state's memory, so the caller must rebind both from the outputs of every
     call and read the old ones no more.  That lets step N+1 be queued while
     step N runs; without it the runtime holds the call until step N has
     ended and the host has dropped its inputs, and the device idles once a
-    step."""
+    step.
+
+    Under a mesh the state goes in and comes out under
+    :func:`state_shardings`: cut over ``dp``, so a device holds, and the
+    optimizer updates, a dp-th of every large leaf."""
     if mesh is None:
         return jax.jit(step, donate_argnums=(0, 1)), lambda x: x
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -298,16 +353,11 @@ def jit_step(step, params, flags, mesh=None):
     tok_sharding = NamedSharding(
         mesh, P("dp", None) if mesh.shape.get("dp", 1) > 1 else P()
     )
-    # Expert weights shard over ep when the mesh has that axis (EP);
-    # the rest of the params stay replicated.
-    if flags.moe_experts and "ep" in mesh.axis_names:
-        p_sh = parallel.moe_shardings(params, mesh, "ep")
-    else:
-        p_sh = jax.tree_util.tree_map(lambda _: rep, params)
+    p_sh, o_sh = state_shardings(params, opt_state, flags, mesh)
     jstep = jax.jit(
         step,
-        in_shardings=(p_sh, None, tok_sharding),
-        out_shardings=(p_sh, None, rep, rep),
+        in_shardings=(p_sh, o_sh, tok_sharding),
+        out_shardings=(p_sh, o_sh, rep, rep),
         donate_argnums=(0, 1),
     )
     return jstep, lambda x: jax.device_put(x, tok_sharding)
@@ -444,8 +494,18 @@ def train(flags, on_stats=None) -> dict:
                               on_stats=on_stats, ckpt=ckpt, start_step=start_step,
                               mesh=mesh, dckpt=dckpt)
 
-    jstep, put = jit_step(step, params, flags, mesh)
+    if mesh is not None:
+        # Fresh or restored, the state goes onto the mesh once, as the step
+        # takes and returns it: the ahead-of-time compile below and every
+        # call of the loop then see the same committed arguments, and the
+        # step compiles once.
+        params, opt_state = jax.device_put(
+            (params, opt_state), state_shardings(params, opt_state, flags, mesh)
+        )
+    jstep, put = jit_step(step, params, opt_state, flags, mesh)
     jstep = telemetry.devmon.instrument_jit(jstep, "lm.step")
+    state_bytes = state_device_bytes(params, opt_state)
+    _M_STATE.set(state_bytes)
 
     # Compile outside the clock (jit time would dominate tokens_per_s on
     # short runs), ahead of time and on the first batch: the state is
@@ -458,10 +518,10 @@ def train(flags, on_stats=None) -> dict:
         "lm.step", jstep, params, opt_state, put(tokens0)
     )
     donated = None if step_cost is None else step_cost.donated_bytes
-    donated_s = ""
+    state_s = f" state={state_bytes / 1e9:.2f}GB/dev"
     if donated is not None:
         _M_DONATED.set(donated)
-        donated_s = f" donated={donated / 1e9:.3f}GB"
+        state_s = f" donated={donated / 1e9:.3f}GB" + state_s
     start = time.time()
     last_ckpt = start
     loss = acc = None
@@ -504,7 +564,7 @@ def train(flags, on_stats=None) -> dict:
                     )
                     print(
                         f"step={steps_done} loss={loss_v:.4f} "
-                        f"acc={acc_v:.3f}{mfu_s}{donated_s}",
+                        f"acc={acc_v:.3f}{mfu_s}{state_s}",
                         flush=True,
                     )
                 if on_stats is not None:
@@ -548,6 +608,7 @@ def train(flags, on_stats=None) -> dict:
         "losses": losses,
         "program": None if step_cost is None else step_cost.program(),
         "donated_bytes": donated,
+        "state_device_bytes": state_bytes,
         "flash_dense_reroutes": telemetry.get_registry().counter_values().get(
             "flash_dense_reroutes_total", 0.0
         ),

@@ -27,6 +27,12 @@ from .collectives import (  # noqa: F401
     tree_psum,
 )
 from .ring_attention import full_attention, ring_attention, ring_attention_sharded  # noqa: F401
-from .train import auto_shardings, fsdp_spec, make_train_step, param_shardings  # noqa: F401
+from .train import (  # noqa: F401
+    auto_shardings,
+    fsdp_spec,
+    make_train_step,
+    mirror_shardings,
+    param_shardings,
+)
 from .moe import SwitchMoE, moe_param_spec, moe_shardings  # noqa: F401
 from .pipeline import pipeline_apply  # noqa: F401

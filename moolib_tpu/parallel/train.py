@@ -60,35 +60,62 @@ def _instrument_step(fn, name: Optional[str] = None):
     return timed_step
 
 
-def fsdp_spec(x, axis: str = "dp", min_size: int = 2**16) -> P:
-    """ZeRO-3-style spec: shard the largest divisible axis of big params."""
+def fsdp_spec(
+    x, axis: str = "dp", min_size: int = 2**16, size: int = 1, held: P = P()
+) -> P:
+    """ZeRO-style spec of one leaf: a leaf of at least ``min_size`` elements
+    is cut over ``axis`` on its largest dimension that ``size`` (the mesh
+    axis's size) divides and ``held`` (the spec other mesh axes already give
+    the leaf) leaves free.  Any other leaf keeps ``held``: a small one, or
+    one with no such dimension (a vocabulary of 50,257 is cut on d_model)."""
     shape = np.shape(x)
-    if not shape or np.prod(shape) < min_size:
-        return P()
-    best = max(range(len(shape)), key=lambda i: shape[i])
-    spec = [None] * len(shape)
-    spec[best] = axis
+    spec = list(held) + [None] * (len(shape) - len(held))
+    free = [i for i, d in enumerate(shape) if spec[i] is None and d % size == 0]
+    if not free or np.prod(shape) < min_size:
+        return held
+    spec[max(free, key=lambda i: shape[i])] = axis
     return P(*spec)
 
 
 def param_shardings(
-    params, mesh: Mesh, mode: str = "replicated", axis: str = "dp"
+    params, mesh: Mesh, mode: str = "replicated", axis: str = "dp", base=None
 ):
     """Pytree of NamedShardings for the model params: "replicated" (pure DP)
-    or "fsdp" (largest-axis sharding for big leaves)."""
+    or "fsdp" (:func:`fsdp_spec` over the mesh's ``axis`` for every leaf).
+    ``base``, a pytree of NamedShardings like ``params`` (``moe_shardings``'
+    ep cut), is what "fsdp" cuts further; without it, and on a mesh whose
+    ``axis`` is 1 or missing, a leaf it does not cut is replicated."""
+    whole = jax.tree_util.tree_map(lambda _: replicated(mesh), params)
     if mode == "replicated":
-        return jax.tree_util.tree_map(lambda _: replicated(mesh), params)
+        return whole
     if mode == "fsdp":
-        def spec_of(x):
-            s = fsdp_spec(x, axis)
-            # Only keep the sharding if the axis divides evenly.
-            for dim, name in zip(np.shape(x), s):
-                if name is not None and dim % mesh.shape[name]:
-                    return replicated(mesh)
-            return NamedSharding(mesh, s)
-
-        return jax.tree_util.tree_map(spec_of, params)
+        base = whole if base is None else base
+        size = mesh.shape.get(axis, 1)
+        if size == 1:
+            return base
+        return jax.tree_util.tree_map(
+            lambda x, b: NamedSharding(
+                mesh, fsdp_spec(x, axis, size=size, held=b.spec)
+            ),
+            params, base,
+        )
     raise ValueError(f"unknown mode {mode!r}")
+
+
+def mirror_shardings(state, params, shardings, mesh: Mesh):
+    """Shardings for an optimizer ``state`` whose moments mirror ``params``:
+    every subtree of ``state`` with the structure of ``params`` (AdamW's mu
+    and nu) takes ``shardings``, the params' own; any other leaf (a step
+    count) is replicated."""
+    like = jax.tree_util.tree_structure(params)
+
+    def mirrors(x):
+        return jax.tree_util.tree_structure(x) == like
+
+    return jax.tree_util.tree_map(
+        lambda x: shardings if mirrors(x) else replicated(mesh), state,
+        is_leaf=mirrors,
+    )
 
 
 def auto_shardings(

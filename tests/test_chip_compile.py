@@ -285,9 +285,12 @@ _TRAIN_ARGV = [
 ]
 
 
-def _lm_train_step(monkeypatch, sharding, batch):
-    """``lm.train``'s own jitted step and its state as shapes under
-    ``sharding``, and the bytes of ``params`` and ``opt_state``."""
+def _lm_train_step(monkeypatch, sharding, batch, layers=4, mesh=None):
+    """``lm.train``'s own jitted step and its state as shapes, on one device
+    under ``sharding`` or on ``mesh`` as ``train`` places it there, and the
+    bytes of ``params`` and ``opt_state`` one device holds."""
+    import math
+
     import optax
 
     from moolib_tpu.examples import lm
@@ -295,16 +298,26 @@ def _lm_train_step(monkeypatch, sharding, batch):
     # The flash kernel asks jax.default_backend() whether to go through
     # Mosaic or interpret mode; in this process that is the cpu.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    flags = lm.make_flags(_TRAIN_ARGV + ["--batch_size", str(batch)])
+    flags = lm.make_flags(
+        _TRAIN_ARGV + ["--batch_size", str(batch), "--layers", str(layers)])
     model, opt = lm.make_model(flags), optax.adamw(flags.learning_rate)
     tokens = jax.ShapeDtypeStruct((batch, flags.seq_len), jnp.int32)
-    params = jax.eval_shape(lambda t: model.init(jax.random.key(0), t), tokens)
+    params = jax.eval_shape(
+        lambda t: model.init(jax.random.key(0), t, **lm._apply_kwargs(flags, mesh)), tokens)
     opt_state = jax.eval_shape(opt.init, params)
-    _, step = lm.make_step(flags, model, opt)
-    jstep, _ = lm.jit_step(step, params, flags)
+    _, step = lm.make_step(flags, model, opt, mesh)
+    jstep, _ = lm.jit_step(step, params, opt_state, flags, mesh)
+    state = (params, opt_state)
+    if mesh is not None:
+        sharding = lm.state_shardings(params, opt_state, flags, mesh)
+    else:
+        sharding = jax.tree_util.tree_map(lambda _: sharding, state)
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), state, sharding)
     state_bytes = sum(
-        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves((params, opt_state)))
-    return jstep, _on(sharding, (params, opt_state)), state_bytes
+        math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(state))
+    return jstep, state, state_bytes
 
 
 # batch: the most memory_analysis() may count, GB.  B=4 is the cells' batch:
@@ -330,6 +343,83 @@ def test_lm_train_step_updates_its_state_in_place(chip, monkeypatch, batch, most
     print(f"lm.train step B={batch}: resident {resident / 1e9:.2f} GB, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
     assert resident < most_gb * 1e9
+
+
+def _computations(text):
+    """``(computation's name, instruction line)`` over a compiled module's text."""
+    name = None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY ")):
+            name = line.removeprefix("ENTRY ").lstrip("%").split(" ")[0]
+        elif " = " in line:
+            yield name, line
+
+
+def _arrays(type_text):
+    """``(dtype, dims)`` of every array in an instruction's result type."""
+    import re
+
+    return [(dt, tuple(int(d) for d in dims.split(",") if d))
+            for dt, dims in re.findall(r"\b([a-z]+\d+|pred)\[([\d,]*)\]", type_text)]
+
+
+# layers: the state one chip holds (GB) and the temporaries of the step that
+# kept the whole state on every chip (GB; 4 layers: the cell, 9.77 GB a chip
+# where this step counts 7.17).  The cell's depth compiles for half a minute.
+@pytest.mark.parametrize("layers,state_gb,whole_temp_gb", [
+    (1, 0.79, 3.706), pytest.param(4, 1.24, 4.830, marks=pytest.mark.slow)])
+def test_lm_train_step_over_dp4_updates_a_quarter_of_the_state(
+        topo, monkeypatch, layers, state_gb, whole_temp_gb):
+    """``lm_train_dp4``'s step (``--mesh dp=4``, B=16): a chip holds and
+    updates a quarter of every large leaf, every gradient matrix is
+    reduce-scattered (on this compiler a fusion ``all-reduce-scatter`` around
+    an all-reduce and a slice) in the dtype the whole-state step all-reduced
+    it in, the weights are gathered and the activations stay where they are."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+    jstep, (params, opt_state), state_bytes = _lm_train_step(
+        monkeypatch, None, 16, layers, mesh)
+    tokens = jax.ShapeDtypeStruct(
+        (16, 2048), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
+    compiled, text = _compile(jstep, params, opt_state, tokens)
+    assert text.count("tpu_custom_call") >= 3 * layers  # the flash kernels, under shard_map
+    mem = compiled.memory_analysis()
+    assert 0.98 * state_gb * 1e9 < state_bytes < 1.02 * state_gb * 1e9
+    assert mem.argument_size_in_bytes < 1.02 * state_bytes
+    # Every leaf is aliased; the chip pads some (f32[512,50257] to 50,304 lanes).
+    assert state_bytes <= mem.alias_size_in_bytes < 1.01 * state_bytes
+    print(f"lm.train step dp=4 layers={layers}: state {state_bytes / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < 1.3 * whole_temp_gb * 1e9
+
+    scattered = []
+    for where, line in _computations(text):
+        op = re.search(r" = (.*?) (all-reduce|all-gather|all-to-all)(?:-start)?\(", line)
+        if op is None:
+            continue
+        arrays = _arrays(op.group(1))
+        # The failure of shardings without constraints: activations and logits
+        # ([16, 2048, ...]) crossing the chips in place of the weights.
+        assert not any(dims[:2] == (16, 2048) for _, dims in arrays), line[:200]
+        matrices = [a for a in arrays if len(a[1]) >= 2 and np.prod(a[1]) >= 2 ** 16]
+        if op.group(2) == "all-reduce" and matrices:
+            assert where.startswith("all-reduce-scatter"), line[:200]
+            scattered += matrices
+    # The head's gradient crosses in float32, the blocks' in bfloat16, as the
+    # whole-state step's all-reduces carried them (chip pads [8192,2048] by 128 rows).
+    assert {dt for dt, _ in scattered} == {"f32", "bf16"}
+    assert [dims for dt, dims in scattered if dt == "f32"] == [(2048, 50257)]
+    assert sum(dims == (2048, 6144) for _, dims in scattered) == layers  # qkv, once a block
+    assert re.search(r"\(input[.\d]*: f32\[2048,50257\]\) -> f32\[512,50257\]", text)
+    # AdamW writes quarter leaves, never a whole head or table.
+    assert "f32[512,50257]" in text and "f32[50257,512]" in text
+    for line in text.splitlines():
+        if "/optimizer/" in line and " = " in line:
+            assert "f32[2048,50257]" not in line.split(" = ")[1].split("(")[0], line[:200]
 
 
 @pytest.mark.parametrize("layers", [1, 2])

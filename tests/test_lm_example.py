@@ -255,19 +255,26 @@ def test_tp_sharded_serving_matches_local_generate(free_port):
         server.close()
 
 
-# ---- the train state is donated (ISSUE 28): the step updates it in place ----
+# ---- the train state is donated (ISSUE 28): the step updates it in place;
+# under a mesh it lives cut over dp (ISSUE 36) ----
 
-_SMALL = ["--seq_len", "16", "--batch_size", "8", "--seed", "7", "--quiet"]
+# d_model 128 makes the FFN kernels 2^16 elements, the least the rule cuts;
+# no power of two divides a vocabulary of 521, so table and head are cut on
+# d_model, as the train cells' 50,257 x 2,048 are.
+_SMALL = ["--seq_len", "16", "--batch_size", "8", "--seed", "7", "--quiet",
+          "--d_model", "128", "--vocab", "521"]
 _PATHS = {
     "plain": ["--mesh", "", "--attention", "dense"],
     "dp2": ["--mesh", "dp=2", "--attention", "flash"],
+    "dp4": ["--mesh", "dp=4", "--attention", "flash"],
 }
 
 
 class _StepSpy:
     """Stands where ``devmon.instrument_jit`` puts its wrapper around the
     step ``train`` built: after every call, the bytes of the ``params`` and
-    ``opt_state`` passed in and whether the call consumed every leaf."""
+    ``opt_state`` passed in that one device held, the devices that held
+    them, and whether the call consumed every leaf."""
 
     def __init__(self, fn):
         self.fn = fn
@@ -276,8 +283,10 @@ class _StepSpy:
     def __call__(self, params, opt_state, tokens):
         import jax
 
+        from moolib_tpu.examples import lm
+
         state = jax.tree_util.tree_leaves((params, opt_state))
-        nbytes = sum(x.nbytes for x in state)
+        nbytes = lm.state_device_bytes(params, opt_state)
         out = self.fn(params, opt_state, tokens)
         self.calls.append((nbytes, all(x.is_deleted() for x in state)))
         return out
@@ -302,27 +311,56 @@ def _train_spied(monkeypatch, argv):
     return out, spy
 
 
+def _state_shapes(argv):
+    """The flags, and the shapes of the ``params`` and ``opt_state`` they describe."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from moolib_tpu.examples import lm
+
+    flags = make_flags(argv)
+    model, opt = lm.make_model(flags), optax.adamw(flags.learning_rate)
+    tokens = jax.ShapeDtypeStruct((flags.batch_size, flags.seq_len), jnp.int32)
+    params = jax.eval_shape(lambda t: model.init(jax.random.key(0), t), tokens)
+    return flags, params, jax.eval_shape(opt.init, params)
+
+
 @pytest.mark.parametrize("path", list(_PATHS))
 def test_lm_step_consumes_params_and_opt_state(monkeypatch, path):
     """Every leaf of the state passed to the step is gone after the call,
-    from the first call on (under a mesh the second call runs a second
-    program, compiled for the sharding the first one returned), and what the
-    compiled step aliases is the whole state."""
-    out, spy = _train_spied(
-        monkeypatch, _SMALL + _PATHS[path] + ["--steps", "4", "--log_interval", "2"])
+    from the first call on; every call gets the state as the one before
+    returned it, so under a mesh too the step compiles once; and what the
+    compiled step aliases is all a device holds: the whole state, or under
+    ``dp`` that device's cut of it."""
+    argv = _SMALL + _PATHS[path] + ["--steps", "4", "--log_interval", "2"]
+    out, spy = _train_spied(monkeypatch, argv)
     assert len(spy.calls) == 4  # no executed warm-up: a loop step is a call
     assert all(consumed for _, consumed in spy.calls), spy.calls
-    state_bytes = spy.calls[0][0]
-    assert {n for n, _ in spy.calls} == {state_bytes}
-    assert out["donated_bytes"] == state_bytes > 0
-    assert out["param_placement"]["devices_per_array"] == (2 if path == "dp2" else 1)
+    assert spy.fn._cache_size() == 1  # one program: the state was placed first
+    device_bytes = spy.calls[0][0]
+    assert {n for n, _ in spy.calls} == {device_bytes}
+    assert out["donated_bytes"] == out["state_device_bytes"] == device_bytes > 0
+    dp = {"plain": 1, "dp2": 2, "dp4": 4}[path]
+    assert out["param_placement"]["devices_per_array"] == dp
+    # Table, head, FFN kernels and their moments are cut; qkv (128 x 384),
+    # proj, the positions, every vector and the count are whole.
+    import jax
+
+    whole = sum(x.size * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(_state_shapes(argv)[1:]))
+    small = 3 * 4 * (2 * (128 * 384 + 128 * 128 + 13 * 128) + 16 * 128 + 2 * 128 + 521) + 4
+    assert device_bytes == small + (whole - small) // dp
 
 
 @pytest.mark.parametrize("path", list(_PATHS))
-def test_lm_train_reports_the_losses_of_the_undonated_step(monkeypatch, path):
-    """``train``'s losses equal, to the bit, those of the same ``step`` jitted
-    with nothing donated and driven by the same batches: the first batch is
-    drawn and feeds the compile alone, and no weight moves before step 1."""
+def test_lm_train_reports_the_losses_of_the_replicated_undonated_step(monkeypatch, path):
+    """``train``'s losses equal those of the plain step (every device holds
+    and updates the whole state, gradients all-reduced) jitted with nothing
+    donated and driven by the same batches: the first batch is drawn and
+    feeds the compile alone, and no weight moves before step 1.  To the bit
+    on one device and at ``dp=2``, where the sum of two commutes; at
+    ``dp=4`` the reduce-scatter may add in another order."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -342,9 +380,15 @@ def test_lm_train_reports_the_losses_of_the_undonated_step(monkeypatch, path):
     params = model.init(
         jax.random.key(flags.seed), first, **lm._apply_kwargs(flags, mesh))
     opt_state = opt.init(params)
-    _, step = lm.make_step(flags, model, opt, mesh)
-    _, put = lm.jit_step(step, params, flags, mesh)
-    # The same shardings, nothing donated: params and opt_state stay readable.
+    loss_fn, cut_step = lm.make_step(flags, model, opt, mesh)
+    _, put = lm.jit_step(cut_step, params, opt_state, flags, mesh)
+
+    def step(params, opt_state, tokens):
+        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, tokens)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss, acc
+
+    # Nothing donated: params and opt_state stay readable.
     if mesh is None:
         plain = jax.jit(step)
     else:
@@ -361,13 +405,47 @@ def test_lm_train_reports_the_losses_of_the_undonated_step(monkeypatch, path):
             params, opt_state, put(jnp.asarray(lm.make_batch(rng, flags))))
         want.append((i + 1, float(loss)))
         assert not jax.tree_util.tree_leaves(old)[0].is_deleted()
-    assert out["losses"] == want
+    if path == "dp4":
+        assert [s for s, _ in out["losses"]] == [s for s, _ in want]
+        np.testing.assert_allclose(
+            [v for _, v in out["losses"]], [v for _, v in want], rtol=2e-6)
+    else:
+        assert out["losses"] == want
 
 
-def test_lm_step_donated_bytes_gauge_and_result(monkeypatch):
+def test_lm_state_is_cut_on_d_model_where_dp_cannot_divide_the_vocabulary():
+    """A vocabulary no power of two divides (101) leaves table and head
+    their other axis: both are cut on ``d_model``, moments with them; the
+    count, the vectors and the small position table stay whole."""
+    from jax.sharding import PartitionSpec as P
+
+    from moolib_tpu import parallel
+    from moolib_tpu.examples import lm
+
+    flags, params, opt_state = _state_shapes(
+        ["--vocab", "101", "--d_model", "1024", "--heads", "8", "--layers", "1",
+         "--seq_len", "32", "--mesh", "dp=4", "--attention", "flash"])
+    mesh = parallel.parse_mesh_spec(flags.mesh)
+    p_sh, o_sh = lm.state_shardings(params, opt_state, flags, mesh)
+    for tree in (p_sh, o_sh[0].mu, o_sh[0].nu):
+        tree = tree["params"]
+        assert tree["embed"]["embedding"].spec == P(None, "dp")  # [101, 1024]
+        assert tree["lm_head"]["kernel"].spec == P("dp", None)  # [1024, 101]
+        assert tree["block0"]["qkv"]["kernel"].spec == P(None, "dp")  # [1024, 3072]
+        assert tree["block0"]["Dense_1"]["kernel"].spec == P("dp", None)  # [4096, 1024]
+        assert tree["pos"]["embedding"].spec == P()  # [32, 1024]: under 2^16
+        assert tree["lm_head"]["bias"].spec == P()
+    assert o_sh[0].count.spec == P()
+
+
+@pytest.mark.parametrize("path", ["plain", "dp2"])
+def test_lm_state_bytes_gauges_and_result(monkeypatch, path):
     from moolib_tpu import telemetry
 
     out, spy = _train_spied(
-        monkeypatch, _SMALL + _PATHS["plain"] + ["--steps", "2", "--log_interval", "1"])
-    series = telemetry.get_registry().snapshot()["lm_step_donated_bytes"]["series"]
-    assert [s["value"] for s in series] == [out["donated_bytes"]] == [spy.calls[0][0]]
+        monkeypatch, _SMALL + _PATHS[path] + ["--steps", "2", "--log_interval", "1"])
+    snapshot = telemetry.get_registry().snapshot()
+    for gauge, key in (("lm_step_donated_bytes", "donated_bytes"),
+                       ("lm_state_device_bytes", "state_device_bytes")):
+        series = snapshot[gauge]["series"]
+        assert [s["value"] for s in series] == [out[key]] == [spy.calls[0][0]]

@@ -88,15 +88,48 @@ def test_sharded_train_step_dp_equals_single():
     np.testing.assert_allclose(np.asarray(p1["w"]), np.asarray(p2["w"]), rtol=1e-5)
 
 
-def test_fsdp_param_shardings():
-    mesh = parallel.make_mesh({"dp": 8})
-    params = {
-        "big": jnp.zeros((1024, 256)),  # big enough to shard
-        "small": jnp.zeros((4,)),
-    }
-    sh = parallel.param_shardings(params, mesh, "fsdp")
-    assert sh["big"].spec == P("dp", None)
-    assert sh["small"].spec == P()
+# The rule (parallel/train.py:fsdp_spec): shape, what another mesh axis
+# already holds of the leaf, and the spec on a mesh of dp=4 x ep=2.
+@pytest.mark.parametrize("shape,held,want", [
+    ((1024, 256), P(), P("dp", None)),  # the largest axis, dp divides it
+    ((50257, 2048), P(), P(None, "dp")),  # only a smaller one: the table
+    ((2048, 50257), P(), P("dp", None)),  # ... and the head
+    ((50257, 1023), P(), P()),  # none: whole
+    ((255, 256), P(), P()),  # under 2^16 elements: whole
+    ((256, 256), P(), P("dp", None)),  # 2^16: cut, the first of two equals
+    ((4,), P(), P()),
+    ((), P(), P()),
+    ((8, 512, 64), P("ep", None, None), P("ep", "dp", None)),  # ep keeps its axis
+    ((1024, 64, 4), P("ep"), P("ep", "dp", None)),  # ... even the largest
+    ((1024, 63, 5), P("ep", None, None), P("ep", None, None)),  # nothing left for dp
+])
+def test_fsdp_param_shardings(shape, held, want):
+    from jax.sharding import NamedSharding
+
+    mesh = parallel.make_mesh({"dp": 4, "ep": 2})
+    params = {"w": jax.ShapeDtypeStruct(shape, jnp.float32)}
+    base = {"w": NamedSharding(mesh, held)}
+    assert parallel.param_shardings(params, mesh, "fsdp", base=base)["w"].spec == want
+    if held == P():  # no base: replicated is what fsdp cuts
+        assert parallel.param_shardings(params, mesh, "fsdp")["w"].spec == want
+    # A mesh with no dp to cut over, or a dp of one, leaves the base.
+    for spec in ("ep=2", "dp=1,ep=2"):
+        other = parallel.parse_mesh_spec(spec)
+        base = {"w": NamedSharding(other, held)}
+        assert parallel.param_shardings(params, other, "fsdp", base=base) == base
+
+
+def test_optimizer_moments_mirror_their_parameters_shardings():
+    import optax
+
+    mesh = parallel.parse_mesh_spec("dp=4")
+    params = {"a": {"w": jnp.zeros((1024, 256)), "b": jnp.zeros((4,))}}
+    p_sh = parallel.param_shardings(params, mesh, "fsdp")
+    state = jax.eval_shape(optax.adamw(1e-3).init, params)
+    o_sh = parallel.mirror_shardings(state, params, p_sh, mesh)
+    assert jax.tree_util.tree_structure(o_sh) == jax.tree_util.tree_structure(state)
+    assert o_sh[0].mu == o_sh[0].nu == p_sh
+    assert o_sh[0].count.spec == P()
 
 
 def test_fsdp_train_step_runs():

@@ -74,6 +74,7 @@ MODULES = [
     ("moolib_tpu.telemetry.aggregator", "Telemetry: RPC cohort aggregator"),
     ("moolib_tpu.telemetry.devmon", "Telemetry: device performance plane"),
     ("moolib_tpu.telemetry.flightrec", "Telemetry: flight recorder"),
+    ("moolib_tpu.telemetry.hostmon", "Telemetry: host monitor (did the process stand still)"),
     ("moolib_tpu.telemetry.profiling", "Telemetry: on-demand device profiling"),
     ("moolib_tpu.telemetry.recovery", "Telemetry: recovery-phase accounting"),
     ("moolib_tpu.utils", "Utilities"),

@@ -35,6 +35,16 @@ from ..serving import (
 from .engine import ContinuousBatchingEngine, NoFreeSlot
 from .kv_pool import PoolExhausted
 
+_M_LOOP = telemetry.get_registry().counter(
+    "serve_loop_seconds_total",
+    "seconds EngineService.loop spent in each of its three states: busy (a "
+    "pass that had work), empty (no slot active, nothing queued), blocked "
+    "(a queued request the engine cannot take, no slot active)",
+    labelnames=("state",),
+)
+_LOOP_SECONDS = {state: _M_LOOP.labels(state=state)
+                 for state in ("busy", "empty", "blocked")}
+
 
 class EngineService(ServeService):
     """See module docstring.  ``step_fn``/``params`` of the base class are
@@ -177,7 +187,16 @@ class EngineService(ServeService):
         """Serve until ``total`` requests have been answered (None =
         forever).  Returns the number of decode iterations — with mixed
         budgets this is far below baseline's requests x max-budget steps,
-        which is the engine's whole throughput story."""
+        which is the engine's whole throughput story.
+
+        At every instant the loop is in one of three states, each under a
+        span and a share of ``serve_loop_seconds_total{state}`` (added to
+        once a pass, start of pass to start of the next, so the three sum to
+        the loop's lifetime): *busy*, a pass that had work
+        (``serve.iteration``); *empty*, no slot active and nothing queued
+        (``serve.empty``); *blocked*, a queued request the engine cannot
+        take and no slot active (``serve.blocked``)."""
+        telemetry.ensure_host_monitor()
         self._loop = asyncio.get_event_loop()
         self._wake = asyncio.Event()
         served = 0
@@ -185,16 +204,28 @@ class EngineService(ServeService):
         # Start of the last pass that ran a decode step and left a slot
         # waiting for its next token; the next pass's start closes the gap.
         prev_start = None
+        state, since = None, 0.0  # of the pass in progress
+
+        def enter(new, now):
+            nonlocal state, since
+            if state is not None:
+                _LOOP_SECONDS[state].inc(now - since)
+            state, since = new, now
+
         try:
             while not self._closed and (total is None or served < total):
                 if not eng.active_count() and not self._queue:
-                    with self._lock:
-                        self._maybe_swap_locked()
-                        self._sweep_done_locked(time.monotonic())
-                    await self._idle_tick()
+                    with telemetry.span("serve.empty"):
+                        now = time.monotonic()
+                        enter("empty", now)
+                        with self._lock:
+                            self._maybe_swap_locked()
+                            self._sweep_done_locked(now)
+                        await self._idle_tick()
                     continue
                 with telemetry.span("serve.iteration") as iteration:
                     start = time.monotonic()
+                    enter("busy", start)
                     if prev_start is not None:
                         _M_PHASE.observe(start - prev_start, phase="iteration")
                     with self._lock:
@@ -215,8 +246,11 @@ class EngineService(ServeService):
                 prev_start = start if active and eng.active_count() else None
                 if not active and not answered:
                     # A queued request the engine cannot take yet.
-                    await self._idle_tick()
+                    with telemetry.span("serve.blocked"):
+                        enter("blocked", time.monotonic())
+                        await self._idle_tick()
         finally:
+            enter(None, time.monotonic())
             with self._lock:
                 self._loop = None
                 self._wake = None

@@ -368,6 +368,7 @@ def train(flags, on_stats=None) -> dict:
     # cache (utils/compile_cache.py).
     utils.init_compile_cache()
     telemetry.init_from_env()  # opt-in exporters (docs/TELEMETRY.md)
+    telemetry.ensure_host_monitor()  # host.tick, host.gc: did the process stand still
     # kill -USR2 toggles an on-demand jax.profiler device-trace window.
     telemetry.profiling.install_signal_toggle()
     from ..testing import faults as _faults

@@ -137,8 +137,10 @@ _M_PHASE = _REG.histogram(
     "enqueue), queue (enqueue -> batch take), batch_assembly (concat + "
     "bucket pad), device (step_fn), reply (responses out); engine arm: "
     "prefill, first_token (enqueue -> first token on the host), iteration "
-    "(start of a decoding pass -> start of the next), dispatch and fetch "
-    "(per decode step)",
+    "(start of a decoding pass -> start of the next; its highest bucket is "
+    "the longest the service thread stood between two tokens), dispatch and "
+    "fetch (per decode step).  The loop's three states (busy, empty, "
+    "blocked) are serve_loop_seconds_total's",
     labelnames=("phase",),
 )
 
@@ -657,6 +659,7 @@ class ServeService:
         forever, until :meth:`close`).  Returns the number of service
         iterations — with concurrent callers this is smaller than the
         request count, which is the point of dynamic batching."""
+        telemetry.ensure_host_monitor()
         self._loop = asyncio.get_event_loop()
         self._wake = asyncio.Event()
         served = 0

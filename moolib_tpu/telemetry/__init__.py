@@ -88,6 +88,7 @@ from .cohort import CohortCounters  # noqa: F401
 from .aggregator import CohortAggregator, install_rpc_handlers  # noqa: F401
 from . import devmon  # noqa: F401
 from . import profiling  # noqa: F401
+from .hostmon import ensure_host_monitor  # noqa: F401
 from .recovery import (  # noqa: F401
     RECOVERY_BUCKETS,
     RECOVERY_PHASES,
@@ -120,6 +121,7 @@ __all__ = [
     "devmon",
     "dump_diagnostics",
     "encode_context",
+    "ensure_host_monitor",
     "flight_event",
     "flush",
     "get_flight_recorder",

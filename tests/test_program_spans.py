@@ -2,15 +2,18 @@
 what one engine iteration and one train step record, that the tracer mirrors
 them into the profiler wherever jax is imported, that the recompile detector
 stays off the per-call path, the named scopes in the compiled HLO,
-``EngineService.close`` from a second thread, and that every span and registry
-series a file of ``chipbench/metrics/`` names is still emitted under that name."""
+``EngineService.close`` from a second thread, the service loop's three states
+(busy, empty, blocked), and that every span and registry series a file of
+``chipbench/metrics/`` names is still emitted under that name."""
 
 import asyncio
+import gc
 import glob
 import json
 import os
 import sys
 import threading
+import time
 import types
 
 import numpy as np
@@ -181,31 +184,51 @@ def _phase_counts(snapshot):
     return {s["labels"]["phase"]: s["value"]["count"] for s in family["series"]}
 
 
+def _loop_seconds(snapshot):
+    family = snapshot.get("serve_loop_seconds_total", {"series": []})
+    return {s["labels"]["state"]: s["value"] for s in family["series"]}
+
+
 @pytest.fixture(scope="module")
 def one_busy_spell():
-    """Two requests (budgets 3 and 5) queued before the loop starts: one
-    pass admits both, then four decode steps; the tracer's spans, the phase
-    counts, and every ``serve_phase_seconds`` observation in order."""
+    """An empty spell of two idle ticks and a collection, then two requests
+    (budgets 3 and 5) in one arrival: one pass admits both, then four decode
+    steps, with the host monitor on as ``loop`` starts it; the tracer's spans,
+    the phase counts, every ``serve_phase_seconds`` observation in order, and
+    the loop's lifetime by the caller's clock."""
     service = _service()
     rets = [_Ret(), _Ret()]
     prompt = np.arange(1, 6, dtype=np.int32)
-    for ret, budget in zip(rets, (3, 5)):
-        service._on_request(ret, prompt, budget)
     from moolib_tpu.engine import service as service_mod
 
     observed = []
     real = service_mod._M_PHASE.observe
     service_mod._M_PHASE.observe = lambda v, **kw: observed.append((kw["phase"], v)) or real(v, **kw)
+
+    async def spell():
+        async def arrive():
+            await asyncio.sleep(0.12)
+            gc.collect()
+            for ret, budget in zip(rets, (3, 5)):
+                service._on_request(ret, prompt, budget)
+
+        arrival = asyncio.ensure_future(arrive())
+        iterations = await asyncio.wait_for(service.loop(total=2), 120)
+        await arrival
+        return iterations
+
     telemetry.get_tracer().clear()
     registry_before = telemetry.get_registry().snapshot()
+    t0 = time.monotonic()
     try:
-        iterations = asyncio.run(asyncio.wait_for(service.loop(total=2), 120))
+        iterations = asyncio.run(spell())
     finally:
         del service_mod._M_PHASE.observe
+    lifetime = time.monotonic() - t0
     registry_after = telemetry.get_registry().snapshot()
     before, after = _phase_counts(registry_before), _phase_counts(registry_after)
     return {"iterations": iterations, "rets": rets, "observed": observed,
-            "spans": telemetry.get_tracer().spans(),
+            "spans": telemetry.get_tracer().spans(), "lifetime": lifetime,
             "registry": (registry_before, registry_after),
             "counts": {k: v - before.get(k, 0) for k, v in after.items()}}
 
@@ -270,6 +293,54 @@ def test_first_token_is_never_under_queue_wait(one_busy_spell):
     queue, first = by_phase("queue"), by_phase("first_token")
     assert len(queue) == len(first) == 2
     assert all(f >= q for q, f in zip(queue, first))
+
+
+def test_three_states_sum_to_the_loops_lifetime(one_busy_spell):
+    """One ``inc`` a pass, start of pass to start of the next: busy + empty +
+    blocked is the loop's lifetime, and every pass lies under its span."""
+    before, after = (_loop_seconds(r) for r in one_busy_spell["registry"])
+    rose = {state: after[state] - before.get(state, 0.0) for state in after}
+    assert set(rose) == {"busy", "empty", "blocked"}
+    assert rose["empty"] >= 0.1 and rose["busy"] > 0 and rose["blocked"] == 0
+    assert sum(rose.values()) == pytest.approx(one_busy_spell["lifetime"], rel=0.01)
+    spans = one_busy_spell["spans"]
+    empty = [s for s in spans if s.name == "serve.empty"]
+    assert len(empty) >= 2 and not [s for s in spans if s.name == "serve.blocked"]
+    assert sum(s.dur_ns for s in empty) / 1e9 == pytest.approx(rose["empty"], rel=0.05)
+    first = min(s.start_ns for s in spans if s.name == "serve.iteration")
+    assert all(s.start_ns + s.dur_ns <= first for s in empty)  # one state at a time
+
+
+@pytest.fixture(scope="module")
+def one_blocked_spell():
+    """A queued request the engine cannot take (``can_accept`` says no, as it
+    does of a request the pool cannot hold yet) and no slot active, until the
+    service is closed 0.12 s on."""
+    service = _service(slots=1)
+    ret = _Ret()
+    service._on_request(ret, np.arange(1, 6, dtype=np.int32), 3)
+    service._engine.can_accept = lambda tp, mn: False
+    before = _loop_seconds(telemetry.get_registry().snapshot())
+    telemetry.get_tracer().clear()
+
+    async def spell():
+        asyncio.get_event_loop().call_later(0.12, service.close)
+        await asyncio.wait_for(service.loop(), 60)
+
+    asyncio.run(spell())
+    return {"ret": ret, "spans": telemetry.get_tracer().spans(),
+            "seconds": (before, _loop_seconds(telemetry.get_registry().snapshot()))}
+
+
+def test_a_request_the_pool_cannot_hold_is_a_blocked_spell(one_blocked_spell):
+    before, after = one_blocked_spell["seconds"]
+    blocked = [s for s in one_blocked_spell["spans"] if s.name == "serve.blocked"]
+    assert len(blocked) >= 2
+    assert after["blocked"] - before.get("blocked", 0.0) == pytest.approx(
+        sum(s.dur_ns for s in blocked) / 1e9, rel=0.05)
+    assert after["empty"] == before.get("empty", 0.0)
+    answers = one_blocked_spell["ret"].answers
+    assert answers and answers[0][0] == "error" and "closed" in answers[0][1]
 
 
 def test_close_from_a_second_thread_mid_loop():
@@ -450,16 +521,19 @@ def _metric_files(*readers):
     return specs
 
 
-_SPAN_NAMES = sorted({name for spec in _metric_files("span_time").values()
-                      for name in spec["spans"] + spec.get("among", [])})
-_REGISTRY_METRICS = _metric_files("histogram_mean", "gauge_mean")
+_SPAN_NAMES = sorted({name for spec in _metric_files("span_time", "span_tail").values()
+                      for key in ("spans", "among", "dispatch", "witness")
+                      for name in spec.get(key, [])})
+_REGISTRY_METRICS = _metric_files("histogram_mean", "gauge_mean", "registry_delta")
 
 
 @pytest.mark.parametrize("name", _SPAN_NAMES)
-def test_every_span_the_benchmark_selects_is_recorded(name, one_busy_spell, tiny_train):
+def test_every_span_the_benchmark_selects_is_recorded(
+        name, one_busy_spell, one_blocked_spell, tiny_train):
     """``span_time`` gives ``None`` for a name no span has, and the harness
     then leaves the metric out of the line in silence."""
-    recorded = {s.name for s in one_busy_spell["spans"]} | set(tiny_train["spans"])
+    recorded = ({s.name for s in one_busy_spell["spans"] + one_blocked_spell["spans"]}
+                | set(tiny_train["spans"]))
     assert name in recorded
 
 
@@ -470,14 +544,15 @@ def test_every_registry_series_the_benchmark_reads_was_observed(
     them: the registry before and after the window, and the gauges sampled
     from a snapshot inside it.  A series that only a model with experts
     observes is looked for in that model's spell."""
-    from chipbench.readers import gauge_mean, histogram_mean
+    from chipbench.readers import gauge_mean, histogram_mean, registry_delta
 
     spec = _REGISTRY_METRICS[metric]
     before, after = one_busy_spell["registry"]
-    if spec["reader"] == "histogram_mean":
+    if spec["reader"] in ("histogram_mean", "registry_delta"):
+        reader = histogram_mean if spec["reader"] == "histogram_mean" else registry_delta
         for before, after in (one_busy_spell["registry"], one_expert_spell["registry"]):
             measured = types.SimpleNamespace(counters_before=before, counters_after=after)
-            value = histogram_mean.read(spec, {"measured": measured})
+            value = reader.read(spec, {"measured": measured})
             if value is not None:
                 break
     else:

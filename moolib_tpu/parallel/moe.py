@@ -13,7 +13,7 @@ Use :func:`moe_param_spec` for the PartitionSpecs of the expert weights.
 from __future__ import annotations
 
 import functools
-from typing import Any, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
@@ -162,7 +162,8 @@ def _group_work(group_sizes, m_tiles: int, tm: int):
     there can be): expert, row tile, first and one-past-last row of the
     expert, and the count of real pairs.  Entries past the count repeat the
     last real pair, so that the kernel's block indices do not move there and
-    nothing is copied for them."""
+    nothing is copied for them (no group holds a row: every entry is the last
+    group's first tile, and the count is 0)."""
     G = group_sizes.shape[0]
     W = m_tiles + G - 1
     ends = jnp.cumsum(group_sizes)
@@ -171,8 +172,8 @@ def _group_work(group_sizes, m_tiles: int, tm: int):
     tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
     upto = jnp.cumsum(tiles)  # pairs of experts 0..g
     n_work = upto[-1]
-    i = jnp.minimum(jnp.arange(W, dtype=jnp.int32), n_work - 1)
-    gid = jnp.searchsorted(upto, i, side="right").astype(jnp.int32)
+    i = jnp.minimum(jnp.arange(W, dtype=jnp.int32), jnp.maximum(n_work - 1, 0))
+    gid = jnp.minimum(jnp.searchsorted(upto, i, side="right"), G - 1).astype(jnp.int32)
     tile = first[gid] + i - (upto[gid] - tiles[gid])
     return (gid, tile.astype(jnp.int32), starts[gid].astype(jnp.int32),
             ends[gid].astype(jnp.int32), n_work.astype(jnp.int32).reshape(1))
@@ -196,8 +197,47 @@ def _gmm_kernel(gid_ref, tile_ref, start_ref, end_ref, n_ref, layer_ref, x_ref,
         o_ref[...] = jnp.where(mine, prod, keep).astype(o_ref.dtype)
 
 
+_GMM_VMEM_LIMIT = 48 << 20  # of a v5e's 128 MiB; Mosaic's default is 16 MiB
+
+
+class GroupedMatmulPlan(NamedTuple):
+    """How :func:`grouped_matmul` blocks a call: the row tile, the width of a
+    weight block, the grid steps the call takes and the VMEM its blocks need."""
+    tm: int
+    tn: int
+    steps: int
+    vmem_bytes: int
+
+
+def _gmm_vmem_bytes(tm: int, tn: int, K: int, itemsize: int) -> int:
+    """Weight block, row tile and output tile, each double buffered by the
+    pipeline, and the float32 product with the two selects over it."""
+    return 2 * itemsize * (K * tn + tm * K + tm * tn) + 3 * 4 * tm * tn
+
+
+def grouped_matmul_plan(M: int, K: int, N: int, G: int, itemsize: int,
+                        tm: int | None = None, tn: int | None = None
+                        ) -> GroupedMatmulPlan:
+    """The blocking of ``[M, K] x [G, K, N]`` from its shapes alone (``tm`` or
+    ``tn`` given: the plan of that choice).  A group's matrix is ONE block
+    wherever VMEM holds it twice over, so the work list of ``m_tiles + G - 1``
+    entries is walked once; a wider matrix is cut into the fewest column
+    strips that fit, multiples of the 128 lanes that divide N, and the list is
+    walked once a strip (the strips are the OUTER grid dimension: a row tile's
+    output block stays in VMEM while consecutive groups fill it)."""
+    if tm is None:
+        tm = min(256, _round_up(M, 16))
+    if tn is None:
+        strips = [N] + [n for n in range(N - N % 128, 0, -128) if N % n == 0 and n < N]
+        tn = next((n for n in strips
+                   if _gmm_vmem_bytes(tm, n, K, itemsize) <= _GMM_VMEM_LIMIT), strips[-1])
+    tn = min(tn, N)
+    steps = -(-N // tn) * (_round_up(M, tm) // tm + G - 1)
+    return GroupedMatmulPlan(tm, tn, steps, _gmm_vmem_bytes(tm, tn, K, itemsize))
+
+
 @functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def grouped_matmul(x, w, group_sizes, layer=None, *, tm=None, tn=512,
+def grouped_matmul(x, w, group_sizes, layer=None, *, tm=None, tn=None,
                    interpret=None):
     """``out[r] = x[r] @ w[g]`` for the rows r of group g, where the rows of
     x [M, K] are sorted by group and ``group_sizes`` [G] int32 sums to M (rows
@@ -206,17 +246,20 @@ def grouped_matmul(x, w, group_sizes, layer=None, *, tm=None, tn=512,
     sliced ``w[layer]`` would be copied whole, every expert of it, each
     iteration).  One Pallas (Mosaic) kernel, named ``moe_expert_matmul`` in
     the profiler's trace; it reads ``w[g]`` only for groups that hold rows,
-    once for every row tile they span.  A jit of its own, so that layers
-    share one lowering."""
+    once for every row tile they span.  ``tm`` and ``tn`` default to
+    :func:`grouped_matmul_plan`'s: a group's whole matrix a grid step where
+    it fits (a decode call of 64 experts is 64 steps; as strips of 512
+    columns it was 384 or 256, every strip walking the list's empty entries
+    again, and copies of 1.5 MB reached 77% of the HBM's bandwidth where one
+    of 6.3 MB reaches 90%).  A jit of its own, so that layers share one
+    lowering."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if w.ndim == 3:
         w, layer = w[None], 0
     M, K = x.shape
     _, G, _, N = w.shape
-    if tm is None:
-        tm = min(256, _round_up(M, 16))
-    tn = min(tn, N)
+    tm, tn, _, _ = grouped_matmul_plan(M, K, N, G, x.dtype.itemsize, tm, tn)
     if N % tn or (not interpret and (tn % 128 or K % 128)):
         raise ValueError(f"grouped_matmul: N={N} must tile by tn={tn}, and K={K} "
                          "and tn by the 128 lanes")
@@ -242,7 +285,7 @@ def grouped_matmul(x, w, group_sizes, layer=None, *, tm=None, tn=512,
             ),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
-                vmem_limit_bytes=48 << 20),
+                vmem_limit_bytes=_GMM_VMEM_LIMIT),
             interpret=interpret,
             name="moe_expert_matmul",
         )(*work, jnp.asarray(layer, jnp.int32).reshape(1), x, w)
@@ -286,26 +329,32 @@ def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
     [E, D, 2F], ``experts_down`` [E, F, D] (or both stacked over layers, with
     ``layer`` the index: see :func:`grouped_matmul`), ``shared_gu`` [D, 2F],
     ``shared_down`` [F, D].  Returns (y [T, D] float32, tokens an expert
-    [E] int32).  ``valid`` [T] bool leaves pad tokens out of the count (they
-    are still computed: the shapes are fixed)."""
+    [E] int32).  ``valid`` [T] bool leaves pad tokens (a prompt's bucket past
+    its length, a decode slot nobody holds) out of the count AND out of the
+    groups: their rows take the shared expert alone, so the experts only a pad
+    token chose are not read (an idle slot keeps its last token: at half
+    occupancy a decode step read 46 experts a layer where its active slots'
+    tokens had chosen 27)."""
     T, D = x32.shape
     E = p["router"].shape[-1]
     dtype = p["experts_gu"].dtype
     experts, weights = sigmoid_topk_route(
         x32, p["router"], p["router_bias"], top_k, scale)
     flat = experts.reshape(-1)  # pair j belongs to token j // top_k
+    if valid is not None:
+        # A pad token's pairs sort last, under a bin of their own past every
+        # group: no expert's matrix is read for a row nobody will look at.
+        flat = jnp.where(jnp.repeat(valid, top_k), flat, E)
     order = jnp.argsort(flat, stable=True)
-    sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
-    load = sizes
-    if valid is not None:  # pad tokens' pairs are counted under a bin of their own
-        counted = jnp.where(jnp.repeat(valid, top_k), flat, E)
-        load = jnp.bincount(counted, length=E + 1)[:E].astype(jnp.int32)
+    load = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
     x = x32.astype(dtype)
     rows = x[order // top_k]  # [T k, D], sorted by expert
-    gu = grouped_matmul(rows, p["experts_gu"], sizes, layer, interpret=interpret)
-    down = grouped_matmul(_silu_gate(gu, dtype), p["experts_down"], sizes, layer,
+    gu = grouped_matmul(rows, p["experts_gu"], load, layer, interpret=interpret)
+    down = grouped_matmul(_silu_gate(gu, dtype), p["experts_down"], load, layer,
                           interpret=interpret)
     back = jnp.argsort(order)  # where pair j went
     pairs = down[back].reshape(T, top_k, D).astype(jnp.float32)
+    if valid is not None:  # rows past the groups' sum are undefined
+        pairs = jnp.where(valid[:, None, None], pairs, 0.0)
     routed = jnp.sum(pairs * weights[..., None], axis=1)
     return routed + swiglu(x, p["shared_gu"], p["shared_down"]), load

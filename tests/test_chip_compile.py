@@ -205,22 +205,46 @@ def test_latent_decode_step_compiles_at_serve_geometry(chip):
     assert mem.temp_size_in_bytes < 4 << 20
 
 
+def _compile_grouped_matmul(chip, rows, k, n, layers=6, experts=64):
+    from moolib_tpu.parallel.moe import grouped_matmul
+
+    args = _on(chip, (jnp.zeros((rows, k), jnp.bfloat16),
+                      jax.ShapeDtypeStruct((layers, experts, k, n), jnp.bfloat16),
+                      jnp.zeros((experts,), jnp.int32), jnp.zeros((), jnp.int32)))
+    return _compile(
+        lambda x, w, sizes, layer: grouped_matmul(x, w, sizes, layer, interpret=False), *args)
+
+
 @pytest.mark.parametrize("rows,k,n", [(128, 2048, 3072), (128, 1536, 2048),
                                       (8192, 2048, 3072)])
 def test_grouped_matmul_compiles_at_serve_geometry(chip, rows, k, n):
     """The experts' grouped matmul over the stacked matrices of 6 layers of
     64 experts (decode: 32 slots x 4 experts a token; prefill: a 2,048-token
-    bucket): Mosaic takes it, and no layer's experts are sliced out of the
-    stack (a copy of 805 MB a layer under the scan)."""
-    from moolib_tpu.parallel.moe import grouped_matmul
+    bucket): Mosaic takes it under the plan's blocking (an expert's whole
+    matrix a grid step), and no layer's experts are sliced out of the stack
+    (a copy of 805 MB a layer under the scan)."""
+    from moolib_tpu.parallel.moe import _GMM_VMEM_LIMIT, grouped_matmul_plan
 
-    args = _on(chip, (jnp.zeros((rows, k), jnp.bfloat16),
-                      jax.ShapeDtypeStruct((6, 64, k, n), jnp.bfloat16),
-                      jnp.zeros((64,), jnp.int32), jnp.zeros((), jnp.int32)))
-    compiled, text = _compile(
-        lambda x, w, sizes, layer: grouped_matmul(x, w, sizes, layer, interpret=False), *args)
+    compiled, text = _compile_grouped_matmul(chip, rows, k, n)
     assert text.count("tpu_custom_call") == 1 and "moe_expert_matmul" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    plan = grouped_matmul_plan(rows, k, n, 64, 2)
+    assert plan.tn == n and plan.vmem_bytes < _GMM_VMEM_LIMIT
+    if rows == 128:  # decode: the work list once, 64 entries, where strips of 512 took 384 / 256
+        assert plan.steps <= 64 + 3
+
+
+def test_grouped_matmul_falls_back_to_strips_for_experts_wider_than_vmem(chip):
+    """An expert of 4,096 x 8,192 is 67 MB: the rule cuts it into the fewest
+    column strips whose double buffer fits, and Mosaic takes that too (2
+    layers of 16 such experts: 64 of them would not fit the chip)."""
+    from moolib_tpu.parallel.moe import _GMM_VMEM_LIMIT, grouped_matmul_plan
+
+    plan = grouped_matmul_plan(128, 4096, 8192, 16, 2)
+    assert plan.tn == 2048 and plan.vmem_bytes < _GMM_VMEM_LIMIT
+    assert 2 * 4096 * 4096 * 2 > _GMM_VMEM_LIMIT  # the next wider strip cannot be held twice
+    _compiled, text = _compile_grouped_matmul(chip, 128, 4096, 8192, layers=2, experts=16)
+    assert text.count("tpu_custom_call") == 1 and "moe_expert_matmul" in text
 
 
 # R2D2's stored sequence (ROADMAP R4): burn-in 40 + unroll 80 frames of

@@ -152,17 +152,87 @@ def test_a_bfloat16_router_fails_the_tolerance():
     assert float(jnp.max(jnp.abs(rounded - exact))) > 10 * TOL
 
 
-@pytest.mark.parametrize("sizes", [[0, 17, 0, 3, 0, 20, 0, 0], [40, 0, 0, 0, 0, 0, 0, 0],
-                                   [5, 5, 5, 5, 5, 5, 5, 5]])
-def test_grouped_matmul_visits_only_groups_with_rows(sizes):
-    x = jax.random.normal(jax.random.key(0), (40, 128), jnp.float32)
-    w = jax.random.normal(jax.random.key(1), (8, 128, 256), jnp.float32)
+@pytest.mark.parametrize("n_valid", [5, 1, 0])
+def test_a_pad_token_opens_no_expert(n_valid):
+    """Experts that only pad tokens chose are poisoned: they are not read,
+    the valid tokens' outputs are bit for bit those of the layer without the
+    mask, and a pad token gets the shared expert alone."""
+    T, k = 32, 2
+    p, x = _skewed_layer(jax.random.key(5), T=T)
+    p = {**p, "router_bias": jnp.zeros_like(p["router_bias"])}  # spread the tokens
+    valid = jnp.arange(T) < n_valid
+    run = jax.jit(lambda p, x, valid: moe_mod.dropless_moe(x, p, top_k=k, scale=1.8,
+                                                           valid=valid))
+    chosen, _ = moe_mod.sigmoid_topk_route(x, p["router"], p["router_bias"], k, 1.8)
+    by_valid = np.zeros(8, bool)
+    by_valid[np.asarray(chosen)[:n_valid].reshape(-1)] = True
+    assert 0 < (~by_valid).sum()  # some expert has pad tokens alone
+    poisoned = {**p, **{name: p[name].at[~by_valid].set(jnp.nan)
+                        for name in ("experts_gu", "experts_down")}}
+    got, load = run(poisoned, x, valid)
+    every, _ = jax.jit(lambda p, x: moe_mod.dropless_moe(x, p, top_k=k, scale=1.8))(p, x)
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_array_equal(np.asarray(got)[:n_valid], np.asarray(every)[:n_valid])
+    shared = moe_mod.swiglu(x, p["shared_gu"], p["shared_down"])
+    np.testing.assert_allclose(np.asarray(got)[n_valid:], np.asarray(shared)[n_valid:],
+                               atol=1e-6)
+    assert int(load.sum()) == k * n_valid and not np.asarray(load)[~by_valid].any()
+
+
+# The last three span several row tiles under either blocking (the default
+# row tile is 256: prefill's shape class), with experts that straddle a tile's
+# edge, one that fills a tile exactly and empty ones between them.
+_GROUP_SIZES = [[0, 17, 0, 3, 0, 20, 0, 0], [40, 0, 0, 0, 0, 0, 0, 0],
+                [5, 5, 5, 5, 5, 5, 5, 5],
+                [0, 300, 0, 100, 0, 150, 50, 0], [256, 0, 255, 0, 0, 2, 0, 87],
+                [0, 0, 0, 0, 0, 0, 1, 700]]
+_BLOCKINGS = {"tm16_tn128": {"tm": 16, "tn": 128}, "plan": {}}
+
+
+def _grouped_case(sizes, dtype):
+    x = jax.random.normal(jax.random.key(0), (sum(sizes), 128), dtype)
+    w = jax.random.normal(jax.random.key(1), (8, 128, 256), dtype)
     # An expert no row chose is never read: poison it.
     w = w.at[np.asarray(sizes) == 0].set(jnp.nan)
-    out = moe_mod.grouped_matmul(x, w, jnp.asarray(sizes, jnp.int32), tm=16, tn=128)
+    return x, w, jnp.asarray(sizes, jnp.int32)
+
+
+@pytest.mark.parametrize("blocking", list(_BLOCKINGS))
+@pytest.mark.parametrize("sizes", _GROUP_SIZES)
+def test_grouped_matmul_visits_only_groups_with_rows(sizes, blocking):
+    x, w, group_sizes = _grouped_case(sizes, jnp.float32)
+    out = moe_mod.grouped_matmul(x, w, group_sizes, **_BLOCKINGS[blocking])
     gid = np.repeat(np.arange(8), sizes)
     want = np.einsum("mk,mkn->mn", np.asarray(x), np.nan_to_num(np.asarray(w))[gid])
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-4)
+
+
+@pytest.mark.parametrize("sizes", _GROUP_SIZES)
+def test_grouped_matmul_blocking_does_not_change_a_bfloat16_result(sizes):
+    """Blocking N reorders no sum over K: the plan's one block an expert and
+    strips of 128 columns give the same bits."""
+    x, w, group_sizes = _grouped_case(sizes, jnp.bfloat16)
+    whole = moe_mod.grouped_matmul(x, w, group_sizes)
+    strips = moe_mod.grouped_matmul(x, w, group_sizes, tn=128)
+    assert whole.dtype == jnp.bfloat16 and not np.isnan(np.asarray(whole, np.float32)).any()
+    np.testing.assert_array_equal(np.asarray(whole, np.float32), np.asarray(strips, np.float32))
+
+
+@pytest.mark.parametrize("shape,tm,tn,steps", [
+    ((128, 2048, 3072, 64, 2), 128, 3072, 64),   # decode, gate | up: one block an expert
+    ((128, 1536, 2048, 64, 2), 128, 2048, 64),   # decode, down
+    ((8192, 2048, 3072, 64, 2), 256, 3072, 95),  # prefill of a 2,048-token bucket
+    ((128, 4096, 8192, 64, 2), 128, 2048, 256),  # wider than VMEM: the fewest strips
+    ((24, 64, 96, 8, 4), 32, 96, 8),             # the tiny preset: N off the 128 lanes
+])
+def test_grouped_matmul_plan_is_one_block_an_expert_where_vmem_holds_it(shape, tm, tn, steps):
+    plan = moe_mod.grouped_matmul_plan(*shape)
+    assert plan[:3] == (tm, tn, steps)
+    assert shape[2] % plan.tn == 0 and plan.vmem_bytes <= moe_mod._GMM_VMEM_LIMIT
+    # an explicit choice is planned as given: the strips of 512 before PR 38
+    M, K, N, G, itemsize = shape
+    old = moe_mod.grouped_matmul_plan(*shape, tn=min(512, N))
+    assert old.tn == min(512, N) and old.steps == steps // (N // tn) * (N // old.tn)
 
 
 def test_latent_kernel_matches_its_gathered_oracle(monkeypatch):

@@ -195,10 +195,18 @@ def metrics_for(bench: Dict, cell: Dict, group: str) -> List[Dict]:
             if "workloads" not in m or cell["name"] in m["workloads"]]
 
 
+def metric_spec(name: str) -> Dict:
+    """``metrics/<name>.json``; a file that says ``{"same_as": "<other>"}``
+    is read as that metric's file (one quantity under two names, where its
+    cells are judged on different end-to-end metrics)."""
+    spec = load_json(BENCH_DIR, "metrics", name + ".json")
+    return metric_spec(spec["same_as"]) if "same_as" in spec else spec
+
+
 def read_metric(entry: Dict, ctx: Dict) -> Optional[float]:
     """Find the metric's file by its name, its reader by the file's
     ``reader``, and read.  ``None`` when there is nothing to read."""
-    spec = load_json(BENCH_DIR, "metrics", entry["name"] + ".json")
+    spec = metric_spec(entry["name"])
     reader = importlib.import_module(f"chipbench.readers.{spec['reader']}")
     return reader.read(spec, ctx)
 

@@ -130,7 +130,7 @@ def run(*, cell, config, traffic, seed, seconds, traced, devices, setup) -> harn
             env={**os.environ, "JAX_PLATFORMS": "cpu"})
     replica = None
     try:
-        schedule = traffic_mod.serve_schedule(traffic, seed, seconds)
+        schedule = traffic_mod.serve_schedule(traffic, seconds)
         child.stdin.write(json.dumps({
             "schedule": schedule, "seed": seed, "vocab": config["vocab_size"],
             "deadline_s": drain_s + seconds + lead_s,

@@ -1,17 +1,21 @@
 """Runner ``serve_config``: runner ``serve``'s replica and load (one engine
 replica as ``lm_serve --engine`` builds it, under open-loop load from
 ``chipbench/loadgen.py``), for a model that the program builds from the
-configuration's own file: ``moolib_tpu.models.latent_moe.LatentMoELM
-.from_config``, the function ``lm_serve --engine --config`` calls.
+configuration's own file: ``"model": "<module>:<class>"`` names the program's
+class, and its ``from_config(config, max_len=..., **uses)`` is the function
+``lm_serve --engine --config`` calls (``glm-4.7-flash.json``:
+``moolib_tpu.models.latent_moe:LatentMoELM``).  The next architecture's cell
+is a configuration, a reference, a traffic file and entries: no runner.
 
 What differs from ``serve``: the model and its reference come from the
 configuration (``"reference"`` names the module under ``chipbench/reference``
 whose ``logits(params, tokens, config, rows=...)`` is compared); weights are
 bfloat16, made on the device by one jitted init from the seed; the engine
 learns the traffic's shortest prompt beside its longest, so that warm-up
-compiles only the prefill buckets the mix reaches.  The program's modules are
-imported BEFORE the runner listens or starts the generator: on a program that
-lacks them the cell ends at once, non-zero, with no child left behind.
+compiles only the prefill buckets the mix reaches.  The program's modules, the
+configuration's model first, are imported BEFORE the runner listens or starts
+the generator: on a program that lacks one the cell ends at once, non-zero,
+with no child left behind.
 
 Correctness, outside the window: the file's ``reference_requests`` go through
 ``submit`` / ``step`` / ``retire`` with fillers in every other slot, and every
@@ -41,6 +45,13 @@ from typing import Dict
 from chipbench import harness, readers
 from chipbench import traffic as traffic_mod
 from chipbench.runners.serve import REPLICA, _free_port, _spanned
+
+
+def load_model(config: Dict):
+    """The program's class that the configuration's file names under
+    ``"model"``, as ``<module>:<class>``."""
+    module, _, name = config["model"].partition(":")
+    return getattr(importlib.import_module(module), name)
 
 
 def reference_gaps(engine, params, config, traffic, seed):
@@ -105,7 +116,7 @@ def run(*, cell, config, traffic, seed, seconds, traced, devices, setup) -> harn
     import jax
 
     with setup.phase("program_imports"):
-        from moolib_tpu.models.latent_moe import LatentMoELM  # first: absent on an older program
+        model_class = load_model(config)  # first: absent on an older program
         from moolib_tpu import telemetry
         from moolib_tpu.engine import ContinuousBatchingEngine, EngineService
         from moolib_tpu.rpc import Rpc
@@ -128,7 +139,7 @@ def run(*, cell, config, traffic, seed, seconds, traced, devices, setup) -> harn
             env={**os.environ, "JAX_PLATFORMS": "cpu"})
     replica = None
     try:
-        schedule = traffic_mod.serve_schedule(traffic, seed, seconds)
+        schedule = traffic_mod.serve_schedule(traffic, seconds)
         child.stdin.write(json.dumps({
             "schedule": schedule, "seed": seed, "vocab": config["vocab_size"],
             "deadline_s": drain_s + seconds + lead_s,
@@ -136,7 +147,7 @@ def run(*, cell, config, traffic, seed, seconds, traced, devices, setup) -> harn
             "stop_s": lead_s + seconds + max(drain_s, trace_s + 1.0)}) + "\n")
         child.stdin.flush()
         with setup.phase("init_weights"):
-            model = LatentMoELM.from_config(
+            model = model_class.from_config(
                 config, max_len=traffic["positions_per_slot"],
                 **config["uses"][traffic["use"]])
             params = jax.jit(model.init)(jax.random.key(harness.fold_seed(seed)))

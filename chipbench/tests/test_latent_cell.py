@@ -102,8 +102,8 @@ def test_the_runner_imports_the_programs_new_module_before_it_listens():
     run = next(n for n in ast.parse(open(path).read()).body
                if isinstance(n, ast.FunctionDef) and n.name == "run")
     src = ast.get_source_segment(open(path).read(), run)
-    assert src.index("moolib_tpu.models.latent_moe") < src.index("rpc.listen(")
-    assert src.index("moolib_tpu.models.latent_moe") < src.index("subprocess.Popen(")
+    assert src.index("load_model(config)") < src.index("rpc.listen(")
+    assert src.index("load_model(config)") < src.index("subprocess.Popen(")
 
 
 def test_trace_means_are_the_traced_windows_own():

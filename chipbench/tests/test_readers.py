@@ -60,3 +60,28 @@ def test_unknown_device_kind_is_an_error(ctx):
     ctx["device"]["kind"] = "TPU v9"
     with pytest.raises(KeyError):
         mfu.read({"flops": "gpt_train_flops_per_token"}, ctx)
+
+
+# Operation texts of ``lm_serve_steady``'s decode step as the chip's trace names
+# them (TPU v5 lite, jax 0.9): the kernel's custom call under its scope's name,
+# the in-place K/V write and a q/k/v slice, which the shapes of PR 23's pattern
+# selected in the kernel's place, and the latent layout's kernel.
+PAGED_KERNEL = ("%paged_attention.5 = (f32[32,1,16,128]{3,2,1,0:T(8,128)}, f32[32,1,16,128]{3,2,1,0:T(8,128)}) "
+                "custom-call(%order, %count, %tables, %lengths, %q, %pool_k, %pool_v), "
+                'custom_call_target="tpu_custom_call"')
+KV_WRITE = "%fusion.41 = bf16[2049,16,16,128]{3,2,1,0:T(8,128)(2,1)} fusion(%pool, %k, %at), kind=kLoop"
+QKV_SLICE = "%slice.7 = bf16[32,16,128]{2,1,0:T(8,128)(2,1)} slice(%fusion.12), slice={[0:32], [0:16], [0:128]}"
+CONSUMER = "%fusion.44 = bf16[32,2048]{1,0} fusion(%paged_attention.5, %w), kind=kOutput"
+LATENT_KERNEL = "%mla_decode_attention.2 = f32[32,32,512]{2,1,0} custom-call(%q, %pool)"
+
+
+def test_paged_attn_share_reads_the_kernel_by_its_name(ctx):
+    spec = harness.load_json(harness.BENCH_DIR, "metrics", "paged_attn_share.json")
+    ctx["measured"].trace = {"busy_s": 4.0, "op_seconds": [
+        (PAGED_KERNEL, 0.6), (KV_WRITE, 0.2), (QKV_SLICE, 0.1), (CONSUMER, 1.0), (LATENT_KERNEL, 0.3)]}
+    assert trace_share.read(spec, ctx) == pytest.approx(15.0)
+    # an older program, or the latent layout alone: the kernel is not there, and 0 is what it took
+    ctx["measured"].trace["op_seconds"] = [(KV_WRITE, 0.2), (LATENT_KERNEL, 0.3)]
+    assert trace_share.read(spec, ctx) == 0.0
+    latent = harness.load_json(harness.BENCH_DIR, "metrics", "mla_decode_attn_share.json")
+    assert trace_share.read(latent, ctx) == pytest.approx(7.5)

@@ -89,3 +89,44 @@ def test_self_time_window_collectives_and_gap_owner():
     assert (edge, count) == (1e-5, 2) and seconds == pytest.approx(300e-9)
     assert sum(c for _e, c, _s in s["idle_gap_sizes"]) == 2
     assert tr.reduce({"devices": {0: []}, "spans": []}, n_devices=1) is None
+
+
+# Instruction texts of ``lm_train_dp4``'s step as this compiler writes them
+# (compiled for a described v5e:2x2, as ``tests/test_chip_compile.py`` does;
+# layouts' tilings and the long ``backend_config`` cut): a reduce-scatter is a
+# fusion that CALLS ``all-reduce-scatter.N``, with no ``(`` after the name.
+REDUCE_SCATTER_HEAD = (
+    '%fusion.10 = f32[512,50257]{0,1:T(8,128)} fusion(%fusion.18), kind=kCustom, '
+    'calls=%all-reduce-scatter.4, metadata={op_name="jit(step)/transpose(jvp(TransformerLM))'
+    '/lm_head/dot_general" stack_frame_id=124}, backend_config={"flag_configs":[],'
+    '"collective_algorithm_config":{"emitter":"SingleInputAllReduceScatterFusion"}}')
+REDUCE_SCATTER_QKV = (
+    '%fusion.9 = bf16[2048,1536]{1,0:T(8,128)(2,1)} fusion(%fusion.113), kind=kCustom, '
+    'calls=%all-reduce-scatter.3, metadata={op_name="jit(step)/transpose(jvp(TransformerLM))'
+    '/block0/qkv/dot_general" stack_frame_id=41}')
+ALL_TO_ALL = ('%all-to-all = bf16[4,2048,4,512]{1,3,0,2:T(8,128)(2,1)S(1)} all-to-all(%copy.112), '
+              'channel_id=28, replica_groups=[1,4]<=[4], dimensions={2}')
+# compute that overlaps a gather, and a plain fusion: neither holds the line for a collective
+OVERLAPPED = ('%fusion.222 = (bf16[4,2048,6144]{2,1,0:T(8,128)(2,1)}, bf16[512,2048]{0,1:T(8,128)(2,1)S(1)}) '
+              'fusion(%p.1, %p.2), kind=kCustom, calls=%async_collective_fusion.222')
+PLAIN = '%fusion.113 = bf16[2048,6144]{1,0:T(8,128)(2,1)} fusion(%a, %b), kind=kOutput, calls=%fused_computation.97'
+
+
+@pytest.mark.parametrize("text,collective", [
+    (REDUCE_SCATTER_HEAD, True), (REDUCE_SCATTER_QKV, True), (ALL_TO_ALL, True),
+    ("%all-gather-done.3 = bf16[50257,2048]{1,0} all-gather-done(%all-gather-start.3)", True),
+    ("%reduce-scatter.1 = f32[512]{0} reduce-scatter(%x), dimensions={0}", True),
+    (OVERLAPPED, False), (PLAIN, False),
+    ("%fusion.7 = f32[8]{0} fusion(%all-reduce.2), kind=kLoop, calls=%fused_computation.1", False),
+])
+def test_collective_pattern_on_recorded_texts(text, collective):
+    assert bool(tr.COLLECTIVE.search(text)) is collective
+
+
+def test_a_fusion_that_calls_a_reduce_scatter_counts_as_exposed():
+    ops = [(REDUCE_SCATTER_HEAD, 0.0, 500.0), (OVERLAPPED, 500.0, 300.0),
+           (REDUCE_SCATTER_QKV, 800.0, 100.0), (ALL_TO_ALL, 900.0, 50.0), (PLAIN, 950.0, 50.0)]
+    s = tr.reduce({"devices": {0: ops}, "spans": [("chipbench.trace_window", 0.0, 1000.0)]}, 1)
+    assert s["collective_exposed_s"] == pytest.approx(650e-9)   # 6.0% of the window before: the all-to-all
+    assert s["busy_s"] == pytest.approx(1000e-9)
+    assert dict(s["top_ops"])["fusion_f32_512_50257_"] == pytest.approx(500e-9)

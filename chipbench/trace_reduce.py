@@ -21,8 +21,9 @@ The reduction:
   stable names yet; a metric selects operations by a pattern on the HLO text;
 - collective time counts as exposed: on the chip's one in-order operation
   line a collective's ``-start`` returns at once and its ``-done`` (or a
-  synchronous collective) holds the line for as long as nothing else can
-  run, and the part that is hidden never appears on that line;
+  synchronous collective, or a fusion that calls one: a reduce-scatter on
+  this compiler) holds the line for as long as nothing else can run, and
+  the part that is hidden never appears on that line;
 - each idle gap is given to the innermost of the benchmark's own spans
   (``chipbench.*``) that was open at its midpoint, on any host thread, or to
   ``_no_benchmark_span_``; gaps are also counted by length (``idle_gap_sizes``:
@@ -41,9 +42,12 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "chipbench."
 WINDOW_SPAN = SPAN_PREFIX + "trace_window"
-COLLECTIVE = re.compile(
-    r"\b(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
-    r"(-start|-done)?\(")
+_COLLECTIVES = r"(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
+# The operation itself, or a fusion that calls one: this compiler runs a
+# reduce-scatter as ``fusion(...), kind=kCustom, calls=%all-reduce-scatter.4``
+# (an all-reduce and a slice).  A fusion that only overlaps one
+# (``calls=%async_collective_fusion.7``) is compute and is not taken.
+COLLECTIVE = re.compile(rf"\b{_COLLECTIVES}(-start|-done)?\(|\bcalls=%?{_COLLECTIVES}")
 _HLO = re.compile(r"^%?(?P<name>[\w.\-]+) = (?P<result>.*)$")
 _SHAPE = re.compile(r"([a-z]+\d*)\[([\d,]*)\]")
 _LAYOUT = re.compile(r"\{[^{}]*\}")

@@ -9,14 +9,24 @@ budget.  The schedule it gives has, for EVERY seed:
   the distributions' quantiles at ``(i + 0.5) / n``, paired by a permutation
   drawn from the file's ``pairing_seed`` and not from ``--seed``.
 
-``--seed`` decides which pair arrives when, the arrival offsets, and (in
-``loadgen.py``) the token ids.  So runs with different seeds offer the same
-tokens at the same mean rate in another order, and differ in nothing else.
+Which pair arrives when, and the arrival offsets, are one arrival trace that
+belongs to the FILE: both are drawn from its ``schedule_seed`` and from nothing
+else, so every run of a cell replays the same schedule, entry for entry, as a
+benchmark on a recorded public trace does.  A run's median follows the pattern
+of arrivals (how many slots are in use when), and a schedule drawn from
+``--seed`` made it a property of the seed: 1.6-3.7% from seed to seed where
+one seed repeats to 0.1-0.8% (PERF.md, sections 2 and 6).  ``--seed`` draws the token
+ids (:func:`prompt_tokens`) and, in the runners, the weights: every run routes
+other tokens through other weights along the same trace.  A file's
+``schedule_seed`` is chosen on the chip as the median of five candidates
+(``tools/repeat.py --schedule-seeds``; the readings are in PERF.md), so that the trace
+stands for its mix and not for a lucky draw.  A serving file without one is
+an error.
 
 Requests before the window (``lead_s``, so that the window opens on a system
 already in its steady state) and after it (the generator keeps offering while
-the window's requests drain) take pairs from the same multiset in a seeded
-order; they are sent and not counted.
+the window's requests drain) take pairs from the same multiset, in an order
+and at times of the same trace; they are sent and not counted.
 """
 
 from __future__ import annotations
@@ -60,10 +70,13 @@ def arrivals(spec: Dict, n: int, span_s: float, rng: np.random.Generator) -> np.
     return np.minimum(offsets, np.nextafter(span_s, 0.0))  # a vanishing last gap rounds up
 
 
-def serve_schedule(traffic: Dict, seed: int, seconds: float) -> List[Dict]:
+def serve_schedule(traffic: Dict, seconds: float) -> List[Dict]:
     """Requests in order of their due time.  Each: ``due_s`` (offset from the
     generator's start), ``prompt_len``, ``budget``, ``counted`` (due inside
-    the window) and ``index`` (seeds its token ids)."""
+    the window) and ``index`` (with ``--seed``, seeds its token ids).  The
+    run's seed is no argument: the schedule is the file's."""
+    if "schedule_seed" not in traffic:
+        raise ValueError("a serving traffic file names its arrival trace: no 'schedule_seed'")
     rate = float(traffic["rate_per_s"])
     lead_s = float(traffic.get("lead_s", 0.0))
     tail_s = float(traffic["drain_limit_s"])
@@ -71,7 +84,7 @@ def serve_schedule(traffic: Dict, seed: int, seconds: float) -> List[Dict]:
     if n < 1:
         raise ValueError("the window holds no request at this rate")
     multiset = pairs(traffic, n)
-    rng = np.random.default_rng([int(seed), 0x5EED])
+    rng = np.random.default_rng([int(traffic["schedule_seed"]), 0x5EED])
     out: List[Dict] = []
 
     def add(count: int, start: float, span: float, counted: bool) -> None:

@@ -47,17 +47,34 @@ can be handed to a new request at once.
 or any object with ``max_len`` (the positions it can address) and
 
 - ``cache_spec(num_blocks, block_size)``: a pytree of shapes, the block axis
-  first in every leaf: the pools are allocated from it (per-head K and V, or
-  one latent row a token a layer: the layout is the model's);
+  first in every leaf: the paged pools are allocated from it (per-head K and
+  V, or one latent row a token a layer: the layout is the model's), and a
+  sequence holds the blocks its length needs;
+- optionally ``state_spec(slots)``: a pytree of shapes, the SLOT axis first in
+  every leaf: what a sequence holds whatever its length (a linear attention's
+  recurrent state, a convolution's tail).  The engine allocates it beside the
+  pools and the model's cache is then ``kv_pool.SlotCache(blocks, slots)``, one
+  pytree in the donated chain; a model without it gets the pools alone, as
+  before, and compiles the programs it always did;
 - ``prefill(params, toks [1, Lb], tp, block_size)`` -> (the prompt's cache
-  rows as ``ceil(Lb / block_size)`` blocks, in whatever pytree the model's
-  ``write_rows`` takes; logits [V] at ``tp - 1``; int32 counters or None);
+  rows as ``ceil(Lb / block_size)`` blocks, with the state after position
+  ``tp - 1`` where the model keeps one, in whatever pytree the model's
+  ``write_rows`` and ``write_state`` take; logits [V] at ``tp - 1``; int32
+  counters or None);
 - ``write_rows(cache, rows, block_ids)`` -> cache: a join's scatter of those
   blocks into the pools (few, stacked arrays keep a join's jit call cheap:
   a row pytree of one array a layer cost ``submit`` 1.3 ms);
+- with ``state_spec``, ``write_state(cache, rows, slot)`` -> cache: the same
+  join overwrites the slot's row of every slot-axis leaf with the prefill's,
+  whole, so nothing of the slot's last holder is ever read.  It runs in the
+  join's jit, in the same donated chain as ``write_rows``: a join dispatched
+  behind a step in flight lands behind it.  A retire does no device work;
 - ``decode(params, cache, tokens [S], paged, mesh=None)`` -> (logits [S, V],
   cache, int32 counters or None), with ``step_counters`` /
-  ``prefill_counters`` their lengths.
+  ``prefill_counters`` their lengths.  Slot-axis leaves advance for the slots
+  ``paged.active`` names and for no other: a step dispatched ahead may find a
+  slot inactive, and a freed slot's row must stay whatever it is until the
+  next join replaces it.
 
 Counters ride what the host fetches anyway (extra rows of a step's packet,
 extra entries beside a prefill's first token): no copy is added.  What they
@@ -70,6 +87,7 @@ the serving plane is argmax today, matching ``lm_serve``).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -83,7 +101,7 @@ from ..telemetry import devmon
 from ..models.transformer import PagedTransformerLM, TransformerLM
 from ..ops.paged_attention import PagedState
 from ..serving import _M_PHASE, bucket, bucket_shapes
-from .kv_pool import BlockPool, PoolExhausted
+from .kv_pool import BlockPool, PoolExhausted, SlotCache
 
 _REG = telemetry.get_registry()
 # Registration is idempotent: serving.py declares the same counter for the
@@ -210,10 +228,14 @@ class ContinuousBatchingEngine:
         self.set_params(params)
 
         S, MB = self.slots, self.max_blocks_per_seq
-        # The pools, as the model lays them out (block axis first).
+        # The pools, as the model lays them out (block axis first), and
+        # where it keeps one the state a slot owns (slot axis first).
+        spec = model.cache_spec(num_blocks, self.block_size)
+        self._slot_state = hasattr(model, "state_spec")
+        if self._slot_state:
+            spec = SlotCache(spec, model.state_spec(S))
         self._cache = self._place_decode(jax.tree.map(
-            lambda spec: jnp.zeros(spec.shape, spec.dtype),
-            model.cache_spec(num_blocks, self.block_size)))
+            lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), spec))
         self._tables = self._place_decode(jnp.zeros((S, MB), jnp.int32))
         self._lengths = self._place_decode(jnp.zeros((S,), jnp.int32))
         self._active = self._place_decode(jnp.zeros((S,), jnp.bool_))
@@ -319,6 +341,9 @@ class ContinuousBatchingEngine:
         traced scalars and ``row``/``block_ids`` traced vectors — a join
         never recompiles (one trace per block-count bucket)."""
         new_cache = self.model.write_rows(cache, rows, block_ids)
+        if self._slot_state:
+            with jax.named_scope("engine_state_write"):
+                new_cache = self.model.write_state(new_cache, rows, slot)
         tables = jax.lax.dynamic_update_slice(tables, row[None, :], (slot, 0))
         lengths = lengths.at[slot].set(tp)
         active = active.at[slot].set(True)
@@ -419,14 +444,18 @@ class ContinuousBatchingEngine:
             slot = self._free_slots.pop()
             row = np.zeros(self.max_blocks_per_seq, np.int32)
             row[:n_alloc] = block_ids
-            (self._cache, self._tables, self._lengths, self._active,
-             self._tokens, self._remaining) = self._join_jit(
-                self._cache, self._tables, self._lengths, self._active,
-                self._tokens, self._remaining,
-                np.int32(slot), row, np.int32(tp), np.int32(tok0),
-                np.int32(max_new - 1),
-                rows, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
-            )
+            # Where the model keeps a state a slot, the join's dispatch is
+            # also the state's write: a span of its own says what it costs.
+            with (telemetry.span("engine.state_write") if self._slot_state
+                  else contextlib.nullcontext()):
+                (self._cache, self._tables, self._lengths, self._active,
+                 self._tokens, self._remaining) = self._join_jit(
+                    self._cache, self._tables, self._lengths, self._active,
+                    self._tokens, self._remaining,
+                    np.int32(slot), row, np.int32(tp), np.int32(tok0),
+                    np.int32(max_new - 1),
+                    rows, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
+                )
         self._slot_blocks[slot] = block_ids
         self._emitted[slot] = emitted
         self._remaining_host[slot] = max_new - 1

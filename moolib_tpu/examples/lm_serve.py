@@ -48,6 +48,8 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import importlib
+import json
 import time
 from typing import Optional
 
@@ -260,8 +262,8 @@ def main(argv=None):
     p.add_argument(
         "--config", default="",
         help="with --engine: build the model from this configuration file "
-        "(published keys, as chipbench/configs/*.json: a latent-attention "
-        "decoder with dropless experts, models/latent_moe.py) instead of "
+        "(published keys, as chipbench/configs/*.json; its \"model\" names "
+        "the class: models/latent_moe.py, models/hybrid_kda.py) instead of "
         "from --vocab/--d_model/--layers/--heads",
     )
     p.add_argument(
@@ -314,11 +316,15 @@ def main(argv=None):
 
         mesh = parallel.parse_mesh_spec(flags.mesh)
         if flags.config:
-            from ..models.latent_moe import LatentMoELM
-
+            # The file names the class that builds it ("model":
+            # "<module>:<class>"), as the benchmark's runner reads it.
+            with open(flags.config) as f:
+                module, _, name = json.load(f).get(
+                    "model", "moolib_tpu.models.latent_moe:LatentMoELM").partition(":")
+            model_class = getattr(importlib.import_module(module), name)
             # bfloat16 as the configurations state it, on the chip; the CPU
             # backend has no bfloat16 x bfloat16 -> float32 product.
-            model = LatentMoELM.from_config(
+            model = model_class.from_config(
                 flags.config, max_len=flags.seq_len + flags.max_new_tokens,
                 dtype=jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32)
             params = jax.jit(model.init)(jax.random.key(flags.seed))

@@ -322,7 +322,7 @@ def sigmoid_topk_route(x32, w_router, bias, top_k: int, scale: float):
 
 
 def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
-                 layer=None, interpret=None):
+                 layer=None, held_from=None, interpret=None):
     """One expert layer over tokens x32 [T, D] (float32, already normed).
 
     ``p``: ``router`` [D, E] and ``router_bias`` [E] (float32), ``experts_gu``
@@ -334,13 +334,28 @@ def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
     groups: their rows take the shared expert alone, so the experts only a pad
     token chose are not read (an idle slot keeps its last token: at half
     occupancy a decode step read 46 experts a layer where its active slots'
-    tokens had chosen 27)."""
+    tokens had chosen 27).
+
+    ``held_from``: this chip's share of an expert-parallel layer.  The router
+    keeps its whole width E and its ``top_k``; ``experts_gu`` / ``experts_down``
+    hold the G matrices of experts ``held_from .. held_from + G - 1`` alone.
+    A pair whose expert is absent goes to the same bin past every group as a
+    pad token's, so nothing is read or computed for it, and what the absent
+    experts would add is left out of ``y``; the weights stay normalised over
+    all ``top_k`` chosen, held or not.  The count is then of the held experts
+    [G].  On one chip the layer runs without its exchange.  ``None``: every
+    expert of the router is here."""
     T, D = x32.shape
-    E = p["router"].shape[-1]
+    E = p["experts_gu"].shape[-3]  # the groups: the experts whose matrices are here
     dtype = p["experts_gu"].dtype
     experts, weights = sigmoid_topk_route(
         x32, p["router"], p["router_bias"], top_k, scale)
     flat = experts.reshape(-1)  # pair j belongs to token j // top_k
+    if held_from is not None:
+        local = flat - held_from
+        flat = jnp.where((local >= 0) & (local < E), local, E)
+    elif p["router"].shape[-1] != E:
+        raise ValueError("the router is wider than the experts given: say which are held")
     if valid is not None:
         # A pad token's pairs sort last, under a bin of their own past every
         # group: no expert's matrix is read for a row nobody will look at.
@@ -354,7 +369,9 @@ def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
                           interpret=interpret)
     back = jnp.argsort(order)  # where pair j went
     pairs = down[back].reshape(T, top_k, D).astype(jnp.float32)
-    if valid is not None:  # rows past the groups' sum are undefined
+    if held_from is not None:  # rows past the groups' sum are undefined
+        pairs = jnp.where((flat < E).reshape(T, top_k, 1), pairs, 0.0)
+    elif valid is not None:
         pairs = jnp.where(valid[:, None, None], pairs, 0.0)
     routed = jnp.sum(pairs * weights[..., None], axis=1)
     return routed + swiglu(x, p["shared_gu"], p["shared_down"]), load

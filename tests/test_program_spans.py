@@ -257,6 +257,33 @@ def one_expert_spell():
     return {"registry": (before, telemetry.get_registry().snapshot())}
 
 
+@pytest.fixture(scope="module")
+def one_state_spell():
+    """The tiny hybrid decoder (``models/hybrid_kda.py``: a recurrent state a
+    slot, a share of the experts) through the engine: one request to its
+    budget; the registry before and after.  Its counters (slots holding live
+    state, held pairs, held experts touched) exist for no other model."""
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.engine import ContinuousBatchingEngine
+    from moolib_tpu.models.hybrid_kda import HybridKdaMoELM, tiny_config
+
+    model = HybridKdaMoELM.from_config(
+        {**tiny_config(), "num_hidden_layers": 4}, dtype=jnp.float32, max_len=128)
+    params = jax.jit(model.init)(jax.random.key(0))
+    engine = ContinuousBatchingEngine(model, params, slots=2, block_size=16,
+                                      max_seq_len=128, max_prompt_len=64)
+    before = telemetry.get_registry().snapshot()
+    telemetry.get_tracer().clear()
+    slot, _ = engine.submit(np.arange(2, 40, dtype=np.int32), 3)
+    while not engine.step()[1]:
+        pass
+    engine.retire(slot)
+    return {"registry": (before, telemetry.get_registry().snapshot()),
+            "spans": telemetry.get_tracer().spans()}
+
+
 def _parent(span, spans):
     """The innermost span of the same thread that contains this one."""
     around = [p for p in spans if p is not span and p.tid == span.tid
@@ -529,28 +556,29 @@ _REGISTRY_METRICS = _metric_files("histogram_mean", "gauge_mean", "registry_delt
 
 @pytest.mark.parametrize("name", _SPAN_NAMES)
 def test_every_span_the_benchmark_selects_is_recorded(
-        name, one_busy_spell, one_blocked_spell, tiny_train):
+        name, one_busy_spell, one_blocked_spell, one_state_spell, tiny_train):
     """``span_time`` gives ``None`` for a name no span has, and the harness
     then leaves the metric out of the line in silence."""
-    recorded = ({s.name for s in one_busy_spell["spans"] + one_blocked_spell["spans"]}
-                | set(tiny_train["spans"]))
+    recorded = ({s.name for s in one_busy_spell["spans"] + one_blocked_spell["spans"]
+                 + one_state_spell["spans"]} | set(tiny_train["spans"]))
     assert name in recorded
 
 
 @pytest.mark.parametrize("metric", sorted(_REGISTRY_METRICS))
 def test_every_registry_series_the_benchmark_reads_was_observed(
-        metric, one_busy_spell, one_expert_spell):
+        metric, one_busy_spell, one_expert_spell, one_state_spell):
     """Through the benchmark's own readers, the way its serving runner feeds
     them: the registry before and after the window, and the gauges sampled
-    from a snapshot inside it.  A series that only a model with experts
-    observes is looked for in that model's spell."""
+    from a snapshot inside it.  A series that only a model with experts, or
+    one with a state a slot, observes is looked for in that model's spell."""
     from chipbench.readers import gauge_mean, histogram_mean, registry_delta
 
     spec = _REGISTRY_METRICS[metric]
     before, after = one_busy_spell["registry"]
     if spec["reader"] in ("histogram_mean", "registry_delta"):
         reader = histogram_mean if spec["reader"] == "histogram_mean" else registry_delta
-        for before, after in (one_busy_spell["registry"], one_expert_spell["registry"]):
+        for before, after in (one_busy_spell["registry"], one_expert_spell["registry"],
+                              one_state_spell["registry"]):
             measured = types.SimpleNamespace(counters_before=before, counters_after=after)
             value = reader.read(spec, {"measured": measured})
             if value is not None:

@@ -42,6 +42,24 @@ dispatched while a step is in flight lands behind it in the donated chain;
 a slot freed at the fetch of step N is inactive in step N+1, so its blocks
 can be handed to a new request at once.
 
+**An admission the host does not wait for.**  ``submit`` dispatches the
+prefill and the join back to back and returns: the join takes the first
+token from the prefill's DEVICE output, so the device's queue reads step
+N+1, prefill, join and, as soon as the loop reaches ``step()``, step N+2,
+with nothing between them (two admissions in one pass queue four programs).
+The first token (with the model's prefill counters in the same vector)
+starts its copy to the host at the prefill's dispatch and is read where the
+wait costs the device nothing: in the next ``step()``, after step N+2 has
+been dispatched behind the join and step N+1's packet is home, before that
+packet is booked (so a slot's first token always precedes its first decode
+token).  Until then the slot's ``emitted`` list is empty.  What the host
+cannot know before the token is home the device decides: with ``eos_id``
+the join lights the slot only if the token is not EOS; the host learns it
+at the read and reports the slot in that ``step()``'s ``finished``.  A
+budget of 1 needs no slot, is known before the prefill, and is the one
+admission that waits: it is answered at once.  ``joins_ahead`` counts the
+joins dispatched without a wait, beside ``joins``.
+
 **What a model offers.**  The engine knows no architecture.  It takes a
 ``models.TransformerLM`` (wrapped by ``models.transformer.PagedTransformerLM``)
 or any object with ``max_len`` (the positions it can address) and
@@ -120,6 +138,12 @@ _M_PREFILL_TOKENS = _REG.counter(
 )
 _M_JOINS = _REG.counter(
     "serve_engine_joins_total", "sequences joined to a decode slot"
+)
+_M_JOINS_AHEAD = _REG.counter(
+    "serve_engine_joins_ahead_total",
+    "joins dispatched behind their prefill without a device wait: the first "
+    "token is read in the next step(), with a step queued behind the join "
+    "(over the joins: the share of admissions that the host did not wait for)",
 )
 _M_RETIRES = _REG.counter(
     "serve_engine_retires_total", "sequences retired (EOS or budget)"
@@ -249,11 +273,15 @@ class ContinuousBatchingEngine:
         self._remaining_host = np.zeros(S, np.int64)
         self._lengths_host = np.zeros(S, np.int64)
         self._active_host = np.zeros(S, bool)
+        # First tokens not read yet, by slot in the order joined: the
+        # prefill's device output (its copy to the host under way) and the
+        # prompt's length.  The next ``step()`` reads them.
+        self._first: Dict[int, Tuple[jax.Array, int]] = {}
         # The step dispatched but not yet booked: its packet, and the slots
         # the mirrors expect it to advance ([S] bool).
         self._flight: Optional[Tuple[jax.Array, np.ndarray]] = None
         self._stats = {
-            "joins": 0, "retires": 0, "decode_tokens": 0,
+            "joins": 0, "joins_ahead": 0, "retires": 0, "decode_tokens": 0,
             "prefill_tokens": 0, "prefill_pad_tokens": 0, "steps": 0,
             "steps_ahead": 0, "empty_steps": 0,
         }
@@ -335,18 +363,24 @@ class ContinuousBatchingEngine:
         return rows, tok0
 
     def _join_impl(self, cache, tables, lengths, active, tokens, remaining,
-                   slot, row, tp, tok0, rem0, rows, block_ids):
+                   slot, row, tp, first, rem0, rows, block_ids):
         """Donated in-place join: scatter the prefilled blocks into the
-        pools and light the slot.  ``slot``/``tp``/``tok0``/``rem0`` are
-        traced scalars and ``row``/``block_ids`` traced vectors — a join
-        never recompiles (one trace per block-count bucket)."""
+        pools and light the slot.  ``first`` is the prefill's own output (the
+        first token, then the model's counters): the host has not seen it.
+        ``slot``/``tp``/``rem0`` are traced scalars and ``row``/``block_ids``
+        traced vectors — a join never recompiles (one trace per block-count
+        bucket)."""
+        tok0 = first.reshape(-1)[0]
         new_cache = self.model.write_rows(cache, rows, block_ids)
         if self._slot_state:
             with jax.named_scope("engine_state_write"):
                 new_cache = self.model.write_state(new_cache, rows, slot)
         tables = jax.lax.dynamic_update_slice(tables, row[None, :], (slot, 0))
         lengths = lengths.at[slot].set(tp)
-        active = active.at[slot].set(True)
+        # A first token that is EOS finished the request: the slot stays dark
+        # and the host reports it finished when it reads the token.
+        active = active.at[slot].set(
+            True if self.eos_id is None else tok0 != self.eos_id)
         tokens = tokens.at[slot].set(tok0)
         remaining = remaining.at[slot].set(rem0)
         return new_cache, tables, lengths, active, tokens, remaining
@@ -379,11 +413,15 @@ class ContinuousBatchingEngine:
     def submit(self, prompt, max_new: int) -> Tuple[Optional[int], List[int]]:
         """Prefill ``prompt`` (1-D int tokens) and join a decode slot.
 
-        Returns ``(slot, emitted)``: ``emitted`` always carries the first
-        greedy token; ``slot`` is None when the request finished at prefill
-        (budget of 1, or immediate EOS) and never occupied a slot.  Raises
-        :class:`NoFreeSlot` / :class:`PoolExhausted` when full (the caller
-        keeps the request queued) and ``ValueError`` for oversized prompts.
+        Returns ``(slot, emitted)``.  With a budget of 1 ``slot`` is None
+        and ``emitted`` holds the one token: the request is answered and
+        never occupied a slot.  Otherwise the prefill and the join are
+        dispatched and nothing is waited for: ``emitted`` is the slot's own
+        list, empty until the first token has been read, which the next
+        ``step()`` does (module docstring); a first token equal to
+        ``eos_id`` comes back in that ``step()``'s ``finished``.  Raises :class:`NoFreeSlot` / :class:`PoolExhausted`
+        when full (the caller keeps the request queued) and ``ValueError``
+        for oversized prompts.
         """
         # mtlint: allow-host-sync(host token staging: the prompt arrives as a python/host sequence; the upload happens inside _join_jit)
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -406,7 +444,7 @@ class ContinuousBatchingEngine:
 
     def _submit(self, prompt, tp: int, max_new: int):
         """``submit`` past its checks, under its span: one child span for
-        each place the host can wait."""
+        each of the host's dispatches."""
         total = tp + max_new
         with telemetry.span("engine.prefill_dispatch"):
             lb = self._bucket(tp)
@@ -420,21 +458,18 @@ class ContinuousBatchingEngine:
             rows, first = self._prefill_jit(
                 self._params_pre, toks_dev, np.int32(tp)
             )
+            first.copy_to_host_async()
         self._stats["prefill_tokens"] += tp
         _M_PREFILL_TOKENS.inc(tp)
-        with telemetry.span("engine.first_token_fetch"):
-            # mtlint: allow-host-sync(the prefill's one D2H: its first token, and the model's prefill counters in the same vector)
-            first = np.asarray(first)  # waits for the prefill
-        tok0 = int(first.reshape(-1)[0])
-        if self._n_prefill_counters:
-            self.model.observe_prefill(first[1:], tp)
-        emitted = [tok0]
-        if max_new == 1 or (self.eos_id is not None and tok0 == self.eos_id):
-            return None, emitted
+        if max_new == 1:
+            # No slot to join, and known before the prefill: the one
+            # admission that waits for its token.
+            return None, [self._read_first(first, tp)]
+        joined = first  # what the join reads; ``first`` is what the host reads
         if self._xfer is not None:
             # Prefill submesh -> decode submesh, one device-path crossing.
-            self._xfer.stack(rows)
-            rows = jax.tree.map(lambda x: x[0], self._xfer.get())
+            self._xfer.stack((rows, first))
+            rows, joined = jax.tree.map(lambda x: x[0], self._xfer.get())
         if not self._free_slots:
             raise NoFreeSlot(f"all {self.slots} slots occupied")
         with telemetry.span("engine.join"):
@@ -452,14 +487,17 @@ class ContinuousBatchingEngine:
                  self._tokens, self._remaining) = self._join_jit(
                     self._cache, self._tables, self._lengths, self._active,
                     self._tokens, self._remaining,
-                    np.int32(slot), row, np.int32(tp), np.int32(tok0),
+                    np.int32(slot), row, np.int32(tp), joined,
                     np.int32(max_new - 1),
                     rows, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
                 )
         self._slot_blocks[slot] = block_ids
-        self._emitted[slot] = emitted
+        emitted = self._emitted[slot] = []
+        self._first[slot] = first, tp
         self._remaining_host[slot] = max_new - 1
         self._lengths_host[slot] = tp
+        # By the mirrors the slot is lit; a first token that is EOS left it
+        # dark on the device, which the host learns where it reads the token.
         self._active_host[slot] = True
         if self._flight is not None:
             # The join landed behind the step in flight, which saw the slot
@@ -467,8 +505,36 @@ class ContinuousBatchingEngine:
             self._flight[1][slot] = False
         self._stats["joins"] += 1
         _M_JOINS.inc()
+        if self._xfer is None:
+            # (Through the prefill hand-off the d2d put may wait: not counted.)
+            self._stats["joins_ahead"] += 1
+            _M_JOINS_AHEAD.inc()
         self._update_gauges()
         return slot, emitted
+
+    def _read_first(self, first: jax.Array, tp: int) -> int:
+        """A prefill's first token, on the host; the model's prefill counters
+        ride the same vector and are handed back to it here."""
+        with telemetry.span("engine.first_token_fetch"):
+            # mtlint: allow-host-sync(the prefill's one D2H: its first token, and the model's prefill counters in the same vector; its copy started at the prefill's dispatch, and but for a budget of 1 the next step is already queued behind the join)
+            first = np.asarray(first)
+        if self._n_prefill_counters:
+            self.model.observe_prefill(first[1:], tp)
+        return int(first.reshape(-1)[0])
+
+    def _book_first(self, slot: int) -> bool:
+        """Put the slot's first token into its ``emitted`` list, if it is
+        still unread.  True if the token is EOS: the join left the slot dark,
+        the request is finished."""
+        pending = self._first.pop(slot, None)
+        if pending is None:
+            return False
+        tok0 = self._read_first(*pending)
+        self._emitted[slot].append(tok0)
+        if self.eos_id is None or tok0 != self.eos_id:
+            return False
+        self._active_host[slot] = False
+        return True
 
     def step(self) -> Tuple[Dict[int, int], List[int]]:
         """Book ONE fixed-shape decode step over every slot, the oldest not
@@ -520,7 +586,10 @@ class ContinuousBatchingEngine:
         nxt, was_active, done = packet[:3]
         _M_PHASE.observe(time.monotonic() - t1, phase="fetch")
         emissions: Dict[int, int] = {}
-        finished: List[int] = []
+        # The slots joined since the last call: the step just dispatched lies
+        # behind their joins, so the wait for a prefill leaves the device busy;
+        # no packet booked before this one holds a token of theirs.
+        finished: List[int] = [s for s in list(self._first) if self._book_first(s)]
         with telemetry.span("engine.step_host"):
             stepped = np.nonzero(was_active)[0]
             # The step attended over positions <= length in each active slot.
@@ -553,6 +622,7 @@ class ContinuousBatchingEngine:
         """Free the slot's blocks and return its emitted tokens.  Pure host
         bookkeeping: the device state was already cleared by the step that
         finished the slot (donated in-place), nothing round-trips."""
+        self._book_first(slot)  # a slot retired before any step() came
         toks = self._emitted[slot]
         self.pool.free(self._slot_blocks[slot])
         self._slot_blocks[slot] = []
@@ -583,7 +653,7 @@ class ContinuousBatchingEngine:
             toks = np.zeros((1, lb), np.int32)
             toks_dev = (toks if self._prefill_sharding is None
                         else jax.device_put(toks, self._prefill_sharding))
-            rows, _ = self._prefill_jit(
+            rows, first = self._prefill_jit(
                 self._params_pre, toks_dev, np.int32(lb)
             )
             shapes += 1
@@ -592,14 +662,14 @@ class ContinuousBatchingEngine:
                 continue
             seen_nbw.add(nbw)
             if self._xfer is not None:
-                self._xfer.stack(rows)
-                rows = jax.tree.map(lambda x: x[0], self._xfer.get())
+                self._xfer.stack((rows, first))
+                rows, first = jax.tree.map(lambda x: x[0], self._xfer.get())
             row = np.zeros(self.max_blocks_per_seq, np.int32)
             (self._cache, self._tables, self._lengths, self._active,
              self._tokens, self._remaining) = self._join_jit(
                 self._cache, self._tables, self._lengths, self._active,
                 self._tokens, self._remaining,
-                np.int32(0), row, np.int32(0), np.int32(0), np.int32(0),
+                np.int32(0), row, np.int32(0), first, np.int32(0),
                 rows, np.zeros(nbw, np.int32),
             )
             shapes += 1

@@ -5,9 +5,10 @@ is inherited unchanged — bounded admission with typed overload rejects,
 req-id dedup, per-request deadlines, ``{name}_stats``, and staged weights —
 while the service loop is replaced: instead of take-a-batch / run-to-the-
 longest, each iteration drains admitted requests into free decode slots
-(prefill + join) and advances ALL occupied slots by one fixed-shape decode
-step.  Hot swaps still land between iterations (here: between decode
-steps); in-flight sequences continue under the new weights.
+(prefill + join, both dispatched and neither waited for) and advances ALL
+occupied slots by one fixed-shape decode step.  Hot swaps still land
+between iterations (here: between decode steps); in-flight sequences
+continue under the new weights.
 
 The admission controller runs in per-token units: the wait estimate is
 ``(queued budgets + active remaining budgets) * EMA seconds-per-token``,
@@ -62,6 +63,9 @@ class EngineService(ServeService):
         )
         self._engine = engine
         self._slot_req: Dict[int, _Request] = {}
+        # Requests joined since the last decode step: their first tokens are
+        # not on the host yet; the engine's next ``step()`` reads them.
+        self._first_due: List[_Request] = []
         # Per-token admission: pending_tokens is called under self._lock
         # (from admit/estimate_wait inside _on_request) — it only reads.
         self.admission = AdmissionController(
@@ -160,17 +164,17 @@ class EngineService(ServeService):
                 answered += 1
                 continue
             now = time.monotonic()
+            # Host time of ``submit``: two dispatches, no device wait.
             _M_PHASE.observe(now - t0, phase="prefill")
-            # Server-side time to first token: enqueue to the prefill's
-            # token on the host (queue wait included).
-            _M_PHASE.observe(now - req.t_enq, phase="first_token")
             if slot is None:
-                # Finished at prefill (budget 1 / immediate EOS).
+                # Finished at prefill (budget 1): the one submit that waits.
+                _M_PHASE.observe(now - req.t_enq, phase="first_token")
                 self._count_answered(1)
                 self._finish(req, emitted)
                 answered += 1
             else:
                 self._slot_req[slot] = req
+                self._first_due.append(req)
                 joined += 1
 
     def _count_answered(self, n: int) -> None:
@@ -264,7 +268,13 @@ class EngineService(ServeService):
         eng = self._engine
         t0 = time.monotonic()
         emissions, finished = eng.step()
-        dt = time.monotonic() - t0
+        now = time.monotonic()
+        dt = now - t0
+        # Server-side time to first token: enqueue to the prefill's token on
+        # the host (queue wait included); that ``step()`` read it.
+        for req in self._first_due:
+            _M_PHASE.observe(now - req.t_enq, phase="first_token")
+        self._first_due.clear()
         if emissions:
             self.admission.note_service(dt, tokens=len(emissions))
             _M_PHASE.observe(dt, phase="device")
@@ -316,6 +326,7 @@ class EngineService(ServeService):
         self._engine.close()
         with self._lock:
             inflight, self._slot_req = self._slot_req, {}
+            self._first_due = []
         for req in inflight.values():
             try:
                 self._respond(req, None, f"serve {self._name}: closed")

@@ -1,4 +1,5 @@
-"""The decode loop one step ahead (moolib_tpu/engine/engine.py) — ISSUE 30.
+"""The decode loop one step ahead (moolib_tpu/engine/engine.py) — ISSUE 30,
+and the admission the host does not wait for — ISSUE 42.
 
 ``step()`` dispatches step N+1 before it waits for step N's packet.  What
 must hold whatever is in flight: every call books exactly one step, tokens
@@ -6,6 +7,13 @@ equal ``generate()``'s, a join lands behind the step in flight, a finish by
 EOS that the host could not foresee costs one empty step and nothing else,
 and new weights apply from the next dispatch.  (Closing a service with a step
 in flight: ``tests/test_program_spans.py``, beside the other close test.)
+
+``submit`` dispatches the prefill and the join back to back: the join takes
+the first token from the prefill's device output, and the host reads it in
+the next ``step()``, once a step is queued behind the join.  Until then the
+slot's ``emitted`` list is empty; a first token that is EOS leaves the slot dark on
+the device and comes back as that step's ``finished``; a budget of 1 joins no
+slot and is answered at once.
 """
 
 import numpy as np
@@ -138,10 +146,12 @@ def test_an_unforeseen_eos_costs_one_empty_step_and_the_slot_is_reused(lm):
     eos = int(emitted_ref[3])  # the fourth token: the third decode step's
     assert eos not in emitted_ref[:3]
     eng = _engine(lm, eos_id=eos)
-    slot, first = eng.submit(prompt, 12)
-    assert first == [int(emitted_ref[0])]
+    slot, emitted = eng.submit(prompt, 12)
+    assert emitted == []  # not read yet: the join took it on the device
     blocks = list(eng._slot_blocks[slot])
-    finished = []
+    emissions, finished = eng.step()
+    # The first step() brought the first token home, ahead of the step's own.
+    assert emitted == [int(t) for t in emitted_ref[:2]] and not finished
     while not finished:
         emissions, finished = eng.step()
     assert emissions == {slot: eos} and finished == [slot]
@@ -219,3 +229,219 @@ def test_new_weights_apply_from_the_next_dispatch(lm):
         tokens.append(emissions[slot])
     assert tokens == [int(old[0]), int(old[1]), int(old[2]), 0, 0, 0]
     assert eng.retire(slot) == tokens and eng._flight is None
+
+
+# ------------------------------------------- an admission nobody waits for
+def _drain(eng, live, outs):
+    """Step until every slot of ``live`` (slot -> prompt) has finished."""
+    for _ in range(64):
+        if not live:
+            return
+        _, finished = eng.step()
+        for s in finished:
+            prompt = live.pop(s)
+            outs.append(np.concatenate([prompt, np.asarray(eng.retire(s), np.int32)]))
+    raise AssertionError("engine never drained")
+
+
+@pytest.mark.parametrize("in_flight", [False, True])
+def test_a_join_reads_its_first_token_in_the_next_step(lm, in_flight):
+    """With a step in flight the join lands behind it: the next ``step()``
+    books that step, which did not advance the slot, and reads the slot's
+    first token after dispatching the step that will; with none in flight it
+    dispatches and books the slot's first step, the first token ahead of the
+    step's own.  Either way ``submit`` reads nothing and the tokens are
+    ``generate()``'s."""
+    eng = _engine(lm)
+    eng.warmup()
+    compiled = eng._join_jit._cache_size(), eng._prefill_jit._cache_size()
+    first0 = _counter("serve_engine_joins_ahead_total")
+    rng = np.random.default_rng(5)
+    a = rng.integers(1, V, size=6).astype(np.int32)
+    b = rng.integers(1, V, size=11).astype(np.int32)
+    ref_b = _reference(lm, b, 5)
+    live, outs = {}, []
+    if in_flight:
+        slot_a, _ = eng.submit(a, 9)
+        live[slot_a] = a
+        eng.step()
+        assert eng._flight is not None
+    telemetry.get_tracer().clear()
+    slot, emitted = eng.submit(b, 5)
+    assert [s.name for s in telemetry.get_tracer().spans()
+            if s.name == "engine.first_token_fetch"] == []
+    assert emitted == [] and emitted is eng._emitted[slot]
+    live[slot] = b
+    emissions, _ = eng.step()
+    if in_flight:
+        # The step booked was dispatched before the join: not this slot's.
+        assert slot not in emissions and emitted == [int(ref_b[len(b)])]
+        emissions, _ = eng.step()
+    assert emitted == [int(ref_b[len(b)]), int(ref_b[len(b) + 1])]
+    assert emissions[slot] == emitted[1]
+    _drain(eng, live, outs)
+    want = {tuple(_reference(lm, a, 9)), tuple(ref_b)} if in_flight else {tuple(ref_b)}
+    assert {tuple(o) for o in outs} == want
+    st = eng.stats()
+    assert st["joins_ahead"] == st["joins"] == len(outs)
+    assert _counter("serve_engine_joins_ahead_total") - first0 == len(outs)
+    # Warm-up called the join as ``submit`` does: nothing compiled since.
+    assert (eng._join_jit._cache_size(), eng._prefill_jit._cache_size()) == compiled
+    eng.pool.check_invariants()
+
+
+def test_two_admissions_in_one_pass_are_read_in_one_step(lm):
+    """Prefill, join, prefill, join behind the step in flight, then one
+    ``step()``: it dispatches the step that advances both and reads both
+    first tokens, in the order joined."""
+    eng = _engine(lm)
+    rng = np.random.default_rng(7)
+    reqs = [(rng.integers(1, V, size=n).astype(np.int32), mn)
+            for n, mn in ((4, 8), (7, 4), (13, 6))]
+    refs = [_reference(lm, p, mn) for p, mn in reqs]
+    slot0, _ = eng.submit(*reqs[0])
+    eng.step()
+    telemetry.get_tracer().clear()
+    slot1, em1 = eng.submit(*reqs[1])
+    slot2, em2 = eng.submit(*reqs[2])
+    assert em1 == [] and em2 == [] and list(eng._first) == [slot1, slot2]
+    emissions, _ = eng.step()  # books the step in flight: neither slot's
+    assert set(emissions) == {slot0} and not eng._first
+    assert em1 == [int(refs[1][7])] and em2 == [int(refs[2][13])]
+    spans = telemetry.get_tracer().spans()
+    fetches = [s for s in spans if s.name == "engine.first_token_fetch"]
+    step, = [s for s in spans if s.name == "engine.step"]
+    ahead, = [s for s in spans if s.name == "engine.step_dispatch"]
+    assert len(fetches) == 2 and all(
+        ahead.start_ns + ahead.dur_ns <= f.start_ns
+        and f.start_ns + f.dur_ns <= step.start_ns + step.dur_ns for f in fetches)
+    eng.step()
+    assert em1 == [int(t) for t in refs[1][7:9]]
+    assert em2 == [int(t) for t in refs[2][13:15]]
+    live = {slot0: reqs[0][0], slot1: reqs[1][0], slot2: reqs[2][0]}
+    outs = []
+    _drain(eng, live, outs)
+    assert {tuple(o) for o in outs} == {tuple(r) for r in refs}
+    assert eng.stats()["joins_ahead"] == eng.stats()["joins"] == 3
+
+
+def test_a_budget_of_one_joins_no_slot_and_is_answered_at_once(lm):
+    eng = _engine(lm, eos_id=1)
+    prompt = np.arange(2, 9, dtype=np.int32)
+    before = eng.stats()
+    slot, emitted = eng.submit(prompt, 1)
+    assert slot is None
+    assert emitted == [int(_reference(lm, prompt, 1)[-1])]
+    after = eng.stats()
+    assert after["joins"] == before["joins"]
+    assert after["joins_ahead"] == before["joins_ahead"]
+    assert eng.pool.available() == eng.pool.num_blocks - 1 and eng.active_count() == 0
+    assert eng.step() == ({}, [])
+
+
+@pytest.mark.parametrize("in_flight", [False, True])
+def test_a_first_token_that_is_eos_finishes_the_slot_once(lm, in_flight):
+    """The host cannot know it when it dispatches the join: the device leaves
+    the slot dark, the ``step()`` that reads the token reports the slot finished,
+    once, and ``retire`` gives back the blocks and the slot."""
+    prompt = np.asarray([42, 4, 61, 36, 57, 18], np.int32)
+    other = np.asarray([62, 4, 18, 25], np.int32)
+    eos = int(_reference(lm, prompt, 2)[len(prompt)])
+    other_ref = _reference(lm, other, 9)
+    assert eos not in other_ref[len(other):]
+    eng = _engine(lm, eos_id=eos)
+    free0 = eng.pool.available()
+    got = {}
+    if in_flight:
+        neighbour, _ = eng.submit(other, 9)
+        eng.step()
+    slot, emitted = eng.submit(prompt, 12)
+    assert slot is not None and emitted == [] and eng.active_count() == 1 + in_flight
+    reported = []
+    for _ in range(16):
+        emissions, finished = eng.step()
+        assert slot not in emissions  # it never decodes
+        reported += [s for s in finished if s == slot]
+        for s in finished:
+            got[s] = eng.retire(s)
+        if not eng.active_count() and eng._flight is None:
+            break
+    assert reported == [slot] and got[slot] == [eos]
+    if in_flight:
+        assert got[neighbour] == [int(t) for t in other_ref[len(other):]]
+    eng.pool.check_invariants()
+    assert eng.pool.available() == free0
+    # The freed slot serves a request whose first token is no EOS.
+    again = {eng.submit(other, 9)[0]: other for _ in range(eng.slots)}
+    assert slot in again and len(again) == eng.slots
+    outs = []
+    _drain(eng, again, outs)
+    for out in outs:
+        np.testing.assert_array_equal(out, other_ref)
+    st = eng.stats()
+    assert st["joins_ahead"] == st["joins"] == st["retires"] == 1 + in_flight + eng.slots
+
+
+def test_retire_before_any_step_returns_the_first_token(lm):
+    """A caller that retires a slot no step has advanced still gets the
+    first token: ``retire`` reads it if no booking has."""
+    eng = _engine(lm)
+    prompt = np.arange(3, 12, dtype=np.int32)
+    slot, emitted = eng.submit(prompt, 4)
+    assert emitted == []
+    toks = eng.retire(slot)
+    assert toks == [int(_reference(lm, prompt, 1)[-1])] and toks is emitted
+    eng.close()
+    eng.pool.check_invariants()
+
+
+class _CountingLM:
+    """A paged transformer that reports two prefill counters: the prompt's
+    length as the device saw it, and a constant."""
+
+    step_counters = 0
+    prefill_counters = 2
+
+    def __init__(self, model):
+        from moolib_tpu.models.transformer import PagedTransformerLM
+
+        self._inner = PagedTransformerLM(model)
+        self.max_len = model.max_len
+        self.seen = []
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def prefill(self, params, toks, tp, block_size):
+        rows, logits, _ = self._inner.prefill(params, toks, tp, block_size)
+        return rows, logits, jnp.stack([tp, jnp.int32(7)])
+
+    def observe_prefill(self, counters, prompt_len):
+        self.seen.append(([int(c) for c in counters], prompt_len))
+
+
+def test_prefill_counters_reach_the_model_once_a_request(lm):
+    """They ride the first token's vector, so they arrive where it is read:
+    in the next ``step()`` for a joined slot, inside ``submit`` for a budget
+    of 1."""
+    model, params = lm
+    counting = _CountingLM(model)
+    eng = ContinuousBatchingEngine(counting, params, slots=3, block_size=4,
+                                   max_seq_len=64, max_prompt_len=16)
+    rng = np.random.default_rng(3)
+    reqs = [(rng.integers(1, V, size=n).astype(np.int32), mn)
+            for n, mn in ((5, 4), (12, 1), (9, 3))]
+    live, outs = {}, []
+    for prompt, mn in reqs:
+        slot, emitted = eng.submit(prompt, mn)
+        if slot is None:
+            outs.append(np.concatenate([prompt, np.asarray(emitted, np.int32)]))
+            assert counting.seen == [([12, 7], 12)]  # the one that waited
+        else:
+            live[slot] = prompt
+    assert len(counting.seen) == 1
+    _drain(eng, live, outs)
+    assert sorted(counting.seen) == [([5, 7], 5), ([9, 7], 9), ([12, 7], 12)]
+    assert {tuple(o) for o in outs} == {tuple(_reference(lm, p, mn)) for p, mn in reqs}
+    st = eng.stats()
+    assert st["joins"] == st["joins_ahead"] == 2  # the budget of 1 joined nothing

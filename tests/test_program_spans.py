@@ -27,11 +27,12 @@ ITERATION_TREE = {
     "serve.admit": "serve.iteration",
     "engine.submit": "serve.admit",
     "engine.prefill_dispatch": "engine.submit",
-    "engine.first_token_fetch": "engine.submit",
     "engine.join": "engine.submit",
     "engine.step": "serve.iteration",
     "engine.step_dispatch": "engine.step",
     "engine.decode_fetch": "engine.step",
+    # Read in the next step(), behind its dispatch: ``submit`` waits for nothing.
+    "engine.first_token_fetch": "engine.step",
     "engine.step_host": "engine.step",
     "serve.reply": "serve.iteration",
 }
@@ -305,6 +306,14 @@ def test_one_engine_iteration_yields_the_span_tree(one_busy_spell):
     assert count("engine.submit") == 2 and count("serve.reply") == 2
     first = min((s for s in spans if s.name == "serve.iteration"), key=lambda s: s.start_ns)
     assert first.args == {"active": 2, "joined": 2, "finished": 0}
+    # The pass that joined both slots read their first tokens in its step(),
+    # after the dispatch and the packet's wait, never inside a submit.
+    fetches = [s for s in spans if s.name == "engine.first_token_fetch"]
+    assert len(fetches) == 2 and all(
+        first.start_ns <= s.start_ns <= first.start_ns + first.dur_ns for s in fetches)
+    assert not [s for s in fetches if _parent(s, spans) == "engine.submit"]
+    packet = min((s for s in spans if s.name == "engine.decode_fetch"), key=lambda s: s.start_ns)
+    assert all(s.start_ns >= packet.start_ns + packet.dur_ns for s in fetches)
 
 
 def test_new_serve_phases_count_per_iteration_request_and_step(one_busy_spell):

@@ -62,8 +62,10 @@ MODULES = [
     ("moolib_tpu.models.qnet", "Models: recurrent Q-network (R2D2)"),
     ("moolib_tpu.models.transformer", "Models: Transformer LM"),
     ("moolib_tpu.models.latent_moe", "Models: latent-attention decoder with dropless experts"),
+    ("moolib_tpu.models.retention_lm", "Models: power-retention decoder (a state a slot, no paged cache)"),
     ("moolib_tpu.ops.vtrace", "Ops: V-trace"),
     ("moolib_tpu.ops.flash_attention", "Ops: Flash attention (pallas)"),
+    ("moolib_tpu.ops.retention", "Ops: power retention, degree 2 (pallas)"),
     ("moolib_tpu.ops.returns", "Ops: returns / losses"),
     ("moolib_tpu.ops.xent", "Ops: chunked cross-entropy (LM head)"),
     ("moolib_tpu.telemetry", "Telemetry (package)"),

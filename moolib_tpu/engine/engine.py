@@ -62,26 +62,41 @@ joins dispatched without a wait, beside ``joins``.
 
 **What a model offers.**  The engine knows no architecture.  It takes a
 ``models.TransformerLM`` (wrapped by ``models.transformer.PagedTransformerLM``)
-or any object with ``max_len`` (the positions it can address) and
+or any object with ``max_len`` (the positions it can address) and one of the
+three shapes a model's memory can take, decided by what it offers:
 
-- ``cache_spec(num_blocks, block_size)``: a pytree of shapes, the block axis
-  first in every leaf: the paged pools are allocated from it (per-head K and
-  V, or one latent row a token a layer: the layout is the model's), and a
-  sequence holds the blocks its length needs;
-- optionally ``state_spec(slots)``: a pytree of shapes, the SLOT axis first in
-  every leaf: what a sequence holds whatever its length (a linear attention's
-  recurrent state, a convolution's tail).  The engine allocates it beside the
-  pools and the model's cache is then ``kv_pool.SlotCache(blocks, slots)``, one
-  pytree in the donated chain; a model without it gets the pools alone, as
-  before, and compiles the programs it always did;
+- **paged** (``cache_spec`` alone): ``cache_spec(num_blocks, block_size)`` is a
+  pytree of shapes, the block axis first in every leaf: the paged pools are
+  allocated from it (per-head K and V, or one latent row a token a layer: the
+  layout is the model's), a sequence holds the blocks its length needs, and
+  admission is by free slots AND free blocks;
+- **paged with a state a slot** (``cache_spec`` and ``state_spec``):
+  ``state_spec(slots)`` is a pytree of shapes, the SLOT axis first in every
+  leaf: what a sequence holds whatever its length (a linear attention's
+  recurrent state, a convolution's tail) beside the rows that grow.  The
+  engine allocates it beside the pools and the model's cache is
+  ``kv_pool.SlotCache(blocks, slots)``, one pytree in the donated chain;
+- **a state a slot alone** (``state_spec`` and no ``cache_spec``): every layer
+  keeps a fixed state and nothing grows.  There is no ``BlockPool``, no block
+  table among the step's or the join's arguments and no ``write_rows`` call;
+  the model's cache is the ``state_spec`` pytree itself; admission is by a
+  free slot alone, ``submit`` allocates a slot and nothing else, ``retire``
+  frees nothing, and ``max_len`` bounds positions (what the rotary embedding
+  can address), not memory.  ``block_size`` and ``num_blocks`` are unread.
+  How many sequences a chip serves is then set by the state's bytes a slot
+  (``serve_engine_state_bytes``), not by context length.
+
+and, for whichever leaves it has:
+
 - ``prefill(params, toks [1, Lb], tp, block_size)`` -> (the prompt's cache
   rows as ``ceil(Lb / block_size)`` blocks, with the state after position
   ``tp - 1`` where the model keeps one, in whatever pytree the model's
   ``write_rows`` and ``write_state`` take; logits [V] at ``tp - 1``; int32
   counters or None);
-- ``write_rows(cache, rows, block_ids)`` -> cache: a join's scatter of those
-  blocks into the pools (few, stacked arrays keep a join's jit call cheap:
-  a row pytree of one array a layer cost ``submit`` 1.3 ms);
+- with ``cache_spec``, ``write_rows(cache, rows, block_ids)`` -> cache: a
+  join's scatter of those blocks into the pools (few, stacked arrays keep a
+  join's jit call cheap: a row pytree of one array a layer cost ``submit``
+  1.3 ms);
 - with ``state_spec``, ``write_state(cache, rows, slot)`` -> cache: the same
   join overwrites the slot's row of every slot-axis leaf with the prefill's,
   whole, so nothing of the slot's last holder is ever read.  It runs in the
@@ -89,7 +104,9 @@ or any object with ``max_len`` (the positions it can address) and
   behind a step in flight lands behind it.  A retire does no device work;
 - ``decode(params, cache, tokens [S], paged, mesh=None)`` -> (logits [S, V],
   cache, int32 counters or None), with ``step_counters`` /
-  ``prefill_counters`` their lengths.  Slot-axis leaves advance for the slots
+  ``prefill_counters`` their lengths.  ``paged`` is a ``PagedState`` whose
+  ``block_tables`` is None without pools (``lengths`` are the positions,
+  ``active`` the slots that step).  Slot-axis leaves advance for the slots
   ``paged.active`` names and for no other: a step dispatched ahead may find a
   slot inactive, and a freed slot's row must stay whatever it is until the
   next join replaces it.
@@ -106,6 +123,7 @@ the serving plane is argmax today, matching ``lm_serve``).
 from __future__ import annotations
 
 import contextlib
+import itertools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -114,7 +132,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import telemetry
+from .. import telemetry, utils
 from ..telemetry import devmon
 from ..models.transformer import PagedTransformerLM, TransformerLM
 from ..ops.paged_attention import PagedState
@@ -167,6 +185,11 @@ _M_OCC = _REG.gauge(
 _M_BLOCKS_FREE = _REG.gauge(
     "serve_engine_blocks_free", "KV pool blocks on the free list"
 )
+_M_STATE_BYTES = _REG.gauge(
+    "serve_engine_state_bytes",
+    "bytes of the slot-axis leaves the engine holds (a model's ``state_spec``, "
+    "every slot's row): what a sequence costs whatever its length",
+)
 _M_ROWS_LIVE = _REG.histogram(
     "serve_engine_live_row_share",
     "per decode step: cache positions the step attends over (active slots "
@@ -181,6 +204,9 @@ _M_KV_LIVE = _REG.histogram(
     "capacity that the paged attention kernel reads",
     buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0),
 )
+
+
+_ROWS_AHEAD_BYTES = 1 << 30  # a state's rows that joins dispatched ahead may hold
 
 
 class NoFreeSlot(RuntimeError):
@@ -218,10 +244,15 @@ class ContinuousBatchingEngine:
                 f"max_len={model.max_len} (learned-pos table / rotary cap)"
             )
         self.max_blocks_per_seq = -(-self.seq_capacity // self.block_size)
-        if num_blocks is None:
-            # Worst case: every slot at full capacity, plus the null block.
-            num_blocks = 1 + self.slots * self.max_blocks_per_seq
-        self.pool = BlockPool(num_blocks, self.block_size)
+        # What the model's memory is made of (module docstring): pools of
+        # blocks, a state a slot, or both.
+        self._slot_state = hasattr(model, "state_spec")
+        self.pool: Optional[BlockPool] = None
+        if hasattr(model, "cache_spec"):
+            if num_blocks is None:
+                # Worst case: every slot at full capacity, plus the null block.
+                num_blocks = 1 + self.slots * self.max_blocks_per_seq
+            self.pool = BlockPool(num_blocks, self.block_size)
         self.max_prompt_len = int(max_prompt_len or self.seq_capacity)
         # The shortest prompt the traffic sends: warm-up compiles no prefill
         # bucket below its bucket (a shorter prompt still runs, in that one).
@@ -254,13 +285,33 @@ class ContinuousBatchingEngine:
         S, MB = self.slots, self.max_blocks_per_seq
         # The pools, as the model lays them out (block axis first), and
         # where it keeps one the state a slot owns (slot axis first).
-        spec = model.cache_spec(num_blocks, self.block_size)
-        self._slot_state = hasattr(model, "state_spec")
+        state = model.state_spec(S) if self._slot_state else None
+        spec = state
+        if self.pool is not None:
+            spec = model.cache_spec(num_blocks, self.block_size)
+            if self._slot_state:
+                spec = SlotCache(spec, state)
+        self.state_bytes = sum(
+            leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(state))
+        # A join's rows live from its prefill's dispatch until the join has
+        # run, and an admission waits for neither: where a slot's state is
+        # large, joins dispatched ahead without bound would each hold a row of
+        # it (24 x 203 MB in a burst that fills every slot).  At most this
+        # many prefills are in flight unread, about a GB of rows; the pools'
+        # rows are small and are not bounded.
+        self._joins_unread_max = max(1, _ROWS_AHEAD_BYTES * S // max(self.state_bytes, 1))
         if self._slot_state:
-            spec = SlotCache(spec, model.state_spec(S))
+            _M_STATE_BYTES.set(self.state_bytes)
+            utils.log_info(
+                "engine: %d slots hold %.3f GB of state, %.1f MB a slot%s", S,
+                self.state_bytes / 1e9, self.state_bytes / S / 1e6,
+                "" if self.pool is not None else "; no paged pool")
         self._cache = self._place_decode(jax.tree.map(
             lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), spec))
-        self._tables = self._place_decode(jnp.zeros((S, MB), jnp.int32))
+        # Without pools there is no table: None is an empty pytree, so the
+        # step's and the join's programs take no such argument.
+        self._tables = (self._place_decode(jnp.zeros((S, MB), jnp.int32))
+                        if self.pool is not None else None)
         self._lengths = self._place_decode(jnp.zeros((S,), jnp.int32))
         self._active = self._place_decode(jnp.zeros((S,), jnp.bool_))
         self._tokens = self._place_decode(jnp.zeros((S,), jnp.int32))
@@ -273,7 +324,9 @@ class ContinuousBatchingEngine:
         self._remaining_host = np.zeros(S, np.int64)
         self._lengths_host = np.zeros(S, np.int64)
         self._active_host = np.zeros(S, bool)
-        # First tokens not read yet, by slot in the order joined: the
+        # First tokens not read yet, by slot IN THE ORDER JOINED (a dict keeps
+        # insertion order, ``_submit`` only appends and ``_book_first`` only
+        # pops: ``_submit``'s backpressure counts back from the newest): the
         # prefill's device output (its copy to the host under way) and the
         # prompt's length.  The next ``step()`` reads them.
         self._first: Dict[int, Tuple[jax.Array, int]] = {}
@@ -369,13 +422,16 @@ class ContinuousBatchingEngine:
         first token, then the model's counters): the host has not seen it.
         ``slot``/``tp``/``rem0`` are traced scalars and ``row``/``block_ids``
         traced vectors — a join never recompiles (one trace per block-count
-        bucket)."""
+        bucket; without pools ``row`` and ``block_ids`` are None and there is
+        one trace)."""
         tok0 = first.reshape(-1)[0]
-        new_cache = self.model.write_rows(cache, rows, block_ids)
+        new_cache = cache
+        if self.pool is not None:
+            new_cache = self.model.write_rows(cache, rows, block_ids)
+            tables = jax.lax.dynamic_update_slice(tables, row[None, :], (slot, 0))
         if self._slot_state:
             with jax.named_scope("engine_state_write"):
                 new_cache = self.model.write_state(new_cache, rows, slot)
-        tables = jax.lax.dynamic_update_slice(tables, row[None, :], (slot, 0))
         lengths = lengths.at[slot].set(tp)
         # A first token that is EOS finished the request: the slot stays dark
         # and the host reports it finished when it reads the token.
@@ -393,10 +449,14 @@ class ContinuousBatchingEngine:
                    bucket(self.min_prompt_len, self.max_prompt_len))
 
     def can_accept(self, prompt_len: int, max_new: int) -> bool:
-        """A free slot AND enough free blocks for the worst case of this
-        request (its bucket-padded prompt or its full budget)."""
+        """A free slot AND, where the model has pools, enough free blocks for
+        the worst case of this request (its bucket-padded prompt or its full
+        budget).  A request longer than the engine's positions is not held
+        back here: ``submit`` refuses it, and it fails alone."""
         if not self._free_slots:
             return False
+        if self.pool is None:
+            return True
         lb = self._bucket(int(prompt_len))
         need = self.pool.blocks_for(max(lb, int(prompt_len) + int(max_new)))
         return self.pool.available() >= need
@@ -446,6 +506,13 @@ class ContinuousBatchingEngine:
         """``submit`` past its checks, under its span: one child span for
         each of the host's dispatches."""
         total = tp + max_new
+        if len(self._first) >= self._joins_unread_max:
+            # That many admissions back, the prefill has to be done before one
+            # more is queued (its join, right behind it, then frees its rows).
+            back = len(self._first) - self._joins_unread_max
+            with telemetry.span("engine.join_backpressure"):
+                # mtlint: allow-host-sync(backpressure on joins dispatched ahead, where a slot's state is hundreds of MB: bounds the rows alive on the device; never reached with a step() between two admissions)
+                next(itertools.islice(self._first.values(), back, None))[0].block_until_ready()
         with telemetry.span("engine.prefill_dispatch"):
             lb = self._bucket(tp)
             pad = lb - tp
@@ -473,12 +540,14 @@ class ContinuousBatchingEngine:
         if not self._free_slots:
             raise NoFreeSlot(f"all {self.slots} slots occupied")
         with telemetry.span("engine.join"):
-            nbw = self.pool.blocks_for(lb)
-            n_alloc = self.pool.blocks_for(max(lb, total))
-            block_ids = self.pool.alloc(n_alloc)  # PoolExhausted -> stay queued
+            block_ids, row, written = [], None, None
+            if self.pool is not None:
+                n_alloc = self.pool.blocks_for(max(lb, total))
+                block_ids = self.pool.alloc(n_alloc)  # PoolExhausted -> stay queued
+                row = np.zeros(self.max_blocks_per_seq, np.int32)
+                row[:n_alloc] = block_ids
+                written = np.asarray(block_ids[:self.pool.blocks_for(lb)], np.int32)  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
             slot = self._free_slots.pop()
-            row = np.zeros(self.max_blocks_per_seq, np.int32)
-            row[:n_alloc] = block_ids
             # Where the model keeps a state a slot, the join's dispatch is
             # also the state's write: a span of its own says what it costs.
             with (telemetry.span("engine.state_write") if self._slot_state
@@ -488,8 +557,7 @@ class ContinuousBatchingEngine:
                     self._cache, self._tables, self._lengths, self._active,
                     self._tokens, self._remaining,
                     np.int32(slot), row, np.int32(tp), joined,
-                    np.int32(max_new - 1),
-                    rows, np.asarray(block_ids[:nbw], np.int32),  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
+                    np.int32(max_new - 1), rows, written,
                 )
         self._slot_blocks[slot] = block_ids
         emitted = self._emitted[slot] = []
@@ -592,12 +660,13 @@ class ContinuousBatchingEngine:
         finished: List[int] = [s for s in list(self._first) if self._book_first(s)]
         with telemetry.span("engine.step_host"):
             stepped = np.nonzero(was_active)[0]
-            # The step attended over positions <= length in each active slot.
-            live = int((self._lengths_host[stepped] // self.block_size + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
-            _M_KV_LIVE.observe(live / (self.slots * self.max_blocks_per_seq))
-            _M_ROWS_LIVE.observe(
-                int((self._lengths_host[stepped] + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
-                / (self.slots * self.seq_capacity))
+            if self.pool is not None:
+                # The step attended over positions <= length in each active slot.
+                live = int((self._lengths_host[stepped] // self.block_size + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
+                _M_KV_LIVE.observe(live / (self.slots * self.max_blocks_per_seq))
+                _M_ROWS_LIVE.observe(
+                    int((self._lengths_host[stepped] + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
+                    / (self.slots * self.seq_capacity))
             if self._n_step_counters and len(stepped):
                 self.model.observe_step(
                     packet[3:].reshape(-1)[:self._n_step_counters])
@@ -619,12 +688,15 @@ class ContinuousBatchingEngine:
         return emissions, finished
 
     def retire(self, slot: int) -> List[int]:
-        """Free the slot's blocks and return its emitted tokens.  Pure host
-        bookkeeping: the device state was already cleared by the step that
-        finished the slot (donated in-place), nothing round-trips."""
+        """Free the slot and its blocks, where it holds any, and return its
+        emitted tokens.  Pure host bookkeeping: the device state was already
+        cleared by the step that finished the slot (donated in-place), nothing
+        round-trips; a state a slot owns stays where it is until the next join
+        overwrites it."""
         self._book_first(slot)  # a slot retired before any step() came
         toks = self._emitted[slot]
-        self.pool.free(self._slot_blocks[slot])
+        if self.pool is not None:
+            self.pool.free(self._slot_blocks[slot])
         self._slot_blocks[slot] = []
         self._emitted[slot] = []
         self._remaining_host[slot] = 0
@@ -638,15 +710,18 @@ class ContinuousBatchingEngine:
         n = int(self._active_host.sum())  # mtlint: allow-host-sync(host-side numpy mirror)
         _M_SLOTS.set(n)
         _M_OCC.set(n / self.slots)
-        _M_BLOCKS_FREE.set(self.pool.available())
+        if self.pool is not None:
+            _M_BLOCKS_FREE.set(self.pool.available())
 
     # ---------------------------------------------------------------- warmup
     def warmup(self) -> int:
         """Compile every shape serving can hit: the decode step, one prefill
-        per prompt bucket, one join per block-count bucket.  Warmup joins
-        target the null block with a zero budget, so the single decode step
-        that follows retires them without touching real state.  Returns the
-        number of distinct compiled shapes."""
+        per prompt bucket, one join per block-count bucket (one join in all
+        without pools: a state's shape does not follow the prompt's).  Warmup
+        joins target the null block with a zero budget, so the single decode
+        step that follows retires them without touching real state (slot 0's
+        row of a state is overwritten: the next join replaces it whole).
+        Returns the number of distinct compiled shapes."""
         shapes = 0
         seen_nbw = set()
         for lb in sorted({self._bucket(b) for b in bucket_shapes(self.max_prompt_len)}):
@@ -657,20 +732,20 @@ class ContinuousBatchingEngine:
                 self._params_pre, toks_dev, np.int32(lb)
             )
             shapes += 1
-            nbw = self.pool.blocks_for(lb)
+            nbw = self.pool.blocks_for(lb) if self.pool is not None else None
             if nbw in seen_nbw:
                 continue
             seen_nbw.add(nbw)
             if self._xfer is not None:
                 self._xfer.stack((rows, first))
                 rows, first = jax.tree.map(lambda x: x[0], self._xfer.get())
-            row = np.zeros(self.max_blocks_per_seq, np.int32)
+            row = None if nbw is None else np.zeros(self.max_blocks_per_seq, np.int32)
             (self._cache, self._tables, self._lengths, self._active,
              self._tokens, self._remaining) = self._join_jit(
                 self._cache, self._tables, self._lengths, self._active,
                 self._tokens, self._remaining,
                 np.int32(0), row, np.int32(0), first, np.int32(0),
-                rows, np.zeros(nbw, np.int32),
+                rows, None if nbw is None else np.zeros(nbw, np.int32),
             )
             shapes += 1
         # One real step compiles the decode path and clears the warmup joins
@@ -689,7 +764,10 @@ class ContinuousBatchingEngine:
     # ----------------------------------------------------------------- stats
     def stats(self) -> Dict[str, Any]:
         out = dict(self._stats)
-        out.update(self.pool.stats())
+        if self.pool is not None:
+            out.update(self.pool.stats())
+        if self._slot_state:
+            out["state_bytes"] = self.state_bytes
         out["slots"] = self.slots
         out["slots_active"] = self.active_count()
         out["slot_occupancy"] = self.active_count() / self.slots

@@ -14,7 +14,9 @@ target for inactive slots in the fixed-shape decode step.
 leaf: the paged pools this allocator hands blocks of, and a fixed-size state
 a decode SLOT owns (a linear attention's recurrent state), which needs no
 allocator: the slot's id is its address, a join overwrites the row whole and
-a retire leaves it where it is.
+a retire leaves it where it is.  A model that keeps ONLY such a state has
+neither: its cache is the slot-axis pytree itself and the engine builds no
+``BlockPool`` for it (``engine.py``, "What a model offers").
 """
 
 from __future__ import annotations

@@ -607,3 +607,78 @@ def test_hybrid_warmup_builds_eleven_programs_for_the_longgen_mix(monkeypatch):
     assert engine.warmup() == 11
     assert calls["prefill"] == [256, 512, 1024, 2048, 4096]
     assert calls["join"] == [2, 4, 8, 16, 32]
+
+
+# ---------------------------------------------------------------------------
+# brumby-14b-base in the engine (PR 44): the decode step of 24 slots and the
+# largest prefill of brumby_serve_statebound, at the published widths
+# ---------------------------------------------------------------------------
+def _retention(monkeypatch):
+    import json
+    import os
+
+    from moolib_tpu.models.retention_lm import PowerRetentionLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # Mosaic, not interpret mode
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "brumby-14b-base.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "chipbench", "traffic", "serve_statebound.json")) as f:
+        traffic = json.load(f)
+    model = PowerRetentionLM.from_config(
+        config, max_len=traffic["positions_per_slot"], **config["uses"]["serve"])
+    return model, jax.eval_shape(model.init, jax.random.key(0)), traffic
+
+
+def test_retention_decode_step_holds_its_state_as_one_aliased_leaf_and_no_table(chip, monkeypatch):
+    """7.08 GB of weights and 4.95 GB of state and normaliser: the step's
+    arguments are 12.03 GB of the chip's 16, the state leaf is ONE float32
+    argument aliased to its output and copied nowhere (the kernel's in-place
+    update survives XLA under the scan over six layers), and no argument is a
+    block table."""
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    model, params, traffic = _retention(monkeypatch)
+    S = traffic["slots"]
+    cache = model.state_spec(S)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    paged = PagedState(None, i32(S), jax.ShapeDtypeStruct((S,), jnp.bool_))
+    compiled, text = _compile(
+        jax.jit(model.decode, donate_argnums=(1,)), *_on(chip, (params, cache, i32(S), paged)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert 7.05e9 < weights < 7.1e9 and 4.9e9 < held < 5.0e9
+    mem = compiled.memory_analysis()
+    assert 12.0e9 < mem.argument_size_in_bytes < 12.1e9
+    assert mem.alias_size_in_bytes >= held  # state and normaliser are updated where they lie
+    assert mem.temp_size_in_bytes < 100 << 20
+    assert len(re.findall(r"%retention_decode[.\d]* = ", text)) == 1  # one call, under the scan
+    arguments = text.split("ENTRY")[1].split("\n")[0].split(" -> ")[0]
+    state, norm = "f32[24,6,8,65,128,128]", "f32[24,6,8,72,128]"
+    assert arguments.count(state) == 1 and arguments.count(norm) == 1
+    assert "s32[24," not in arguments  # lengths and tokens are s32[24]: nothing is [slots, blocks]
+    copies = re.findall(r"= (\w+\[[\d,]*\])[^ ]* copy\(", text)
+    assert not {state, norm} & set(copies)
+    sizes = lambda found: [int(np.prod([int(d) for d in s.split("[")[1][:-1].split(",") if d]))
+                           for s in found]
+    assert max(sizes(copies)) <= 24 * 40 * 128  # a step's q: no weight, no state
+    converts = re.findall(r"= (\w+\[[\d,]*\])[^ ]* convert\(", text)
+    assert max(sizes(converts), default=0) < 151936 * 5120
+
+
+def test_retention_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
+    """A prompt of 4,096 positions: the quadratic kernel over 40 heads and the
+    state kernel over 8, both through Mosaic; weights, temporaries, a GB of
+    rows dispatched ahead and the engine's 4.95 GB of state stay under 15.5 GB."""
+    model, params, traffic = _retention(monkeypatch)
+    Lb = traffic["prompt_tokens"]["max"]
+    compiled, text = _compile(
+        jax.jit(lambda p, toks, tp: model.prefill(p, toks, tp, traffic["block_size"])),
+        *_on(chip, (params, jax.ShapeDtypeStruct((1, Lb), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert 0.2e9 < mem.output_size_in_bytes < 0.21e9  # a slot's row: 203 MB and the normaliser
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 4.95e9 + (1 << 30) < 15.5e9
+    assert len(re.findall(r"%retention_prefill[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%retention_prefill_state[.\d]* = ", text)) == 1

@@ -8,37 +8,50 @@ an int32 row of block ids (its block table).
 :func:`paged_attention` is one Pallas (Mosaic) kernel that reads the pool in
 place.  The block tables and lengths sit in SMEM; per slot the kernel copies
 only the blocks that hold live positions (``<= lengths[s]``) from HBM into a
-double-buffered VMEM window, some 16 blocks at a time, while the previous
-window is being reduced, and folds them into an online softmax (running
-maximum, sum and weighted sum in float32).  So a decode step costs the live
-blocks, not ``slots x max_blocks_per_seq``: an inactive slot is skipped (it
-reads nothing and gives 0) and a dead table entry is never dereferenced.  Nothing of the size of
-the gathered context exists in HBM.
+double-buffered VMEM window of a megabyte, while the previous window is being
+reduced, and folds them into an online softmax (running maximum, sum and
+weighted sum in float32).  So a decode step costs the live blocks, not
+``slots x max_blocks_per_seq``: an inactive slot is skipped (it reads nothing
+and gives 0) and a dead table entry is never dereferenced.  Nothing of the
+size of the gathered context exists in HBM.
 
 The mathematics is :func:`gathered_decode_attention`'s, which stays the one
 definition of it: the dense ``decode=True`` path of ``models.transformer.Block``
-calls it directly, and ``tests/test_paged_attention.py`` holds the kernel to it
-over a gathered context (:func:`paged_gather`).  Precision: K and V enter the
-products as stored (bfloat16 widened exactly to float32 on the VPU), the sums,
-the softmax statistics and the weights are float32 — what the XLA path gave at
-default matmul precision or tighter, but in another order of summation, so
-paged and dense decode agree to rounding and no longer bit for bit.
+calls it directly, and ``tests/test_paged_attention.py`` holds the kernel to
+it over a gathered context (:func:`paged_gather`).
 
-Why the VPU and the pool's layout as it is: a block is one contiguous 64 KB
-copy, and a token's ``[Hk, hd]`` slice is exactly one (16, 128) bfloat16 tile,
-so scores are a multiply and a lane reduction over whole tiles with every KV
-head in flight at once and the softmax statistics are ``[Hk, 1]`` vectors; at
-one query position per slot the MXU would be fed a single row.  Where ``Hk``
-does not fill a tile (grouped and multi-query attention) consecutive tokens
-of a block are folded into the head axis, so that the kernel sees full tiles,
-and the partial softmaxes of a head's fold are merged after the kernel.
+The two products are matmuls on the MXU, for every shape.  A window's K (and
+V) is one ``[tokens x Hk, hd]`` operand exactly as the pool stores it (a block
+``[block_size, Hk, hd]`` is already that, row-major): no transpose, no fold of
+tokens into heads, nothing merged after the kernel.  Scores are ``q [H, hd]``
+against a chunk of it in one ``dot_general``, scaled in float32 after the
+product; a column whose K/V head is not the row's (its group's, where a K/V
+head serves ``H // Hk`` query heads), or whose position is past the length,
+is set to -1e30 before the maximum; the weights go to the pool's dtype and
+meet V in a second ``dot_general``.  Every head is multiplied against every
+K/V head's rows, ``Hk`` times the score columns that count: a K/V tile is
+loaded onto the MXU once either way and the softmax of the rest lies under
+the copies of the next window, so the kernel is bound by the copies at one
+query head a K/V head (16 of each) as at eight (PERF.md, PR 45, which also
+has what the per-head kernel on the VPU read, that this one replaced).
+Precision, the latent kernel's contract: products in the pool's dtype (q is
+cast to it) with float32 accumulation; masks, scale and softmax statistics
+float32; a float32 pool multiplies in float32 (``Precision.HIGHEST``).  V
+rows past the length (a last block's stale tail, a page of the window that
+was not copied) are selected to zero before the product, since ``0 x NaN`` is
+NaN on the MXU as on the VPU.  A block whose ``block_size x Hk`` rows do not
+fill whole sublane tiles of the pool's dtype (16 rows of bfloat16, 8 of
+float32) is copied into a page of the window that does, and the padding is
+masked like a position past the length.
 
 Like ``ops.flash_attention`` the kernel lowers through Mosaic on ``tpu`` and
 runs in Pallas interpret mode on ``cpu`` (where tier-1 CI executes); any other
-platform raises.  One shape Mosaic refuses: a head size that is not a multiple
-of the 128 lanes (a block cannot be copied out of a lane-padded pool).  Such a
-call is traced onto :func:`gathered_decode_attention` over the XLA gather of
-the whole capacity, and counted in ``paged_gather_reroutes_total``.
+platform raises.  What Mosaic cannot copy is a head size that is not a
+multiple of the 128 lanes (no block can be copied out of a lane-padded pool):
+such a call is traced onto :func:`gathered_decode_attention` over the XLA
+gather of the whole capacity.  ``paged_attention_traces_total{path}`` counts
+at trace time which of the two a call took (``mxu``, ``gather``; a shape has
+one, never a mix), and ``paged_gather_reroutes_total`` the second alone.
 
 Block id 0 is the *null block*: never handed out by the allocator, and the
 write path redirects inactive slots' scatters at it, so a fixed-shape jitted
@@ -48,7 +61,6 @@ step over all S slots never branches on occupancy.
 from __future__ import annotations
 
 import functools
-import math
 from typing import NamedTuple
 
 import jax
@@ -65,6 +77,14 @@ _M_GATHER_REROUTES = telemetry.get_registry().counter(
     "paged_attention calls traced onto the XLA gather path (cost of the whole "
     "slot capacity) because the head size is not a multiple of the 128 lanes "
     "a Mosaic copy of a pool block needs",
+)
+_M_TRACES = telemetry.get_registry().counter(
+    "paged_attention_traces_total",
+    "paged_attention calls traced, by the path the operands' shapes chose: "
+    "mxu (the fused kernel, both products matmuls), gather (a head size "
+    "Mosaic cannot copy: the XLA gather, also counted in "
+    "paged_gather_reroutes_total)",
+    labelnames=("path",),
 )
 
 
@@ -154,18 +174,25 @@ def paged_gather(pool, block_tables):
 
 def _paged_kernel(
     order_ref, count_ref, tables_ref, lengths_ref, q_ref, pool_k_ref, pool_v_ref,
-    o_ref, lse_ref, k_buf, v_buf, sem, *, pages, fold, kv_heads,
+    o_ref, k_buf, v_buf, sem, *, pages, chunk, page_rows, kv_heads, group, scale,
+    precision,
 ):
     """All slots of one layer's decode attention.  In SMEM: order [S] (the
     active slots first), count [1] (how many are active), tables [S, MB] and
-    lengths [S]; q [S, group, R, hd] in VMEM, scaled, float32; the pools
-    [NB, rows, R, hd] stay in HBM (R = fold * Hk: ``fold`` consecutive tokens
-    of a block ride in the head axis, ``rows * fold`` tokens a block);
-    k_buf/v_buf [2, pages, rows, R, hd] are the two VMEM windows."""
+    lengths [S]; q [S, Hp, hd] in VMEM in the pool's dtype, unscaled (head h
+    reads K/V head h // group; rows past H are padding); the pools
+    [NB, rows, hd] stay in HBM, a block as it is stored (rows =
+    block_size * Hk: row r is token r // Hk of the block, K/V head r % Hk);
+    k_buf/v_buf [2, pages * page_rows, hd] are the two VMEM windows, a page
+    ``page_rows`` >= rows apart (whole sublane tiles), reduced ``chunk`` pages
+    at a time: every head against every row of a chunk in one product, the
+    columns of other heads' K/V heads masked out of the softmax."""
     MB = tables_ref.shape[1]
-    group = q_ref.shape[1]
-    rows, R, hd = k_buf.shape[2:]
-    block_size = rows * fold
+    Hp, hd = o_ref.shape[1:]
+    rows = pool_k_ref.shape[1]
+    block_size = rows // kv_heads
+    C = chunk * page_rows
+    never = 1 << 30  # a position that is never live
 
     def live_blocks(s):
         # At least one: the copies are chained from window to window, and a
@@ -182,8 +209,9 @@ def _paged_kernel(
 
         def body(p, carry):
             blk = tables_ref[s, w * pages + p]
-            do(pltpu.make_async_copy(pool_k_ref.at[blk], k_buf.at[buf, p], sem.at[0, buf]))
-            do(pltpu.make_async_copy(pool_v_ref.at[blk], v_buf.at[buf, p], sem.at[1, buf]))
+            page = pl.ds(pl.multiple_of(p * page_rows, page_rows), rows)
+            do(pltpu.make_async_copy(pool_k_ref.at[blk], k_buf.at[buf, page], sem.at[0, buf]))
+            do(pltpu.make_async_copy(pool_v_ref.at[blk], v_buf.at[buf, page], sem.at[1, buf]))
             return carry
 
         jax.lax.fori_loop(0, window_pages(s, w), body, 0)
@@ -194,15 +222,22 @@ def _paged_kernel(
     def wait(s, w, buf):
         for_each_copy(s, w, buf, lambda copy: copy.wait())
 
-    # Position of an element of a block inside the block: row * fold + the
-    # token of the fold its head-axis index belongs to.
-    offset = jax.lax.broadcasted_iota(jnp.int32, (rows, R, 1), 0) * fold
-    if fold > 1:
-        offset += jax.lax.broadcasted_iota(jnp.int32, (rows, R, 1), 1) // kv_heads
+    def chunk_position(row):
+        """Of row ``row`` of a chunk, the position inside the chunk of the
+        token it holds; never live where it is a page's padding."""
+        page, r = row // page_rows, row % page_rows
+        return jnp.where(r < rows, page * block_size + r // kv_heads, never)
+
+    # A chunk's row is a column of the scores: live for the query heads of
+    # its K/V head's group only.
+    col = jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 0)
+    position = jnp.where(
+        col % page_rows % kv_heads == head // group, chunk_position(col), never)
+    row_position = chunk_position(jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0))
 
     # Slots the loop never visits (inactive ones) read nothing and give 0.
     o_ref[...] = jnp.zeros_like(o_ref)
-    lse_ref[...] = jnp.zeros_like(lse_ref)
     count = count_ref[0]
 
     @pl.when(count > 0)
@@ -211,9 +246,12 @@ def _paged_kernel(
 
     def slot_body(i, n_windows_done):
         s = order_ref[i]
-        length = lengths_ref[s]
+        # Clipped to the table's capacity: a window's last chunk may hold a
+        # page past the table's end that nothing copied, and only the length
+        # keeps such a page out of the softmax.
+        length = jnp.minimum(lengths_ref[s], MB * block_size - 1)
         n_windows = pl.cdiv(live_blocks(s), pages)
-        qs = [q_ref[s, g] for g in range(group)]  # each [R, hd]
+        q = q_ref[s]  # [Hp, hd]
 
         def window_body(w, carry):
             n_done, stats = carry
@@ -230,46 +268,44 @@ def _paged_kernel(
 
             wait(s, w, buf)
 
-            def page_body(p, stats):
-                k = k_buf[buf, p].astype(jnp.float32)  # [rows, R, hd]
-                v = v_buf[buf, p].astype(jnp.float32)
-                mask = (w * pages + p) * block_size + offset <= length
-                # The tail of the last live block is stale pool contents:
-                # a weight of 0 does not silence a NaN there, a select does.
-                v = jnp.where(mask, v, 0.0)
-                out = []
-                for g in range(group):
-                    m, l, acc = stats[g]
-                    sc = jnp.sum(k * qs[g][None], axis=-1, keepdims=True)
-                    sc = jnp.where(mask, sc, _NEG_INF)  # [rows, R, 1]
-                    m_new = jnp.maximum(m, jnp.max(sc, axis=0))  # [R, 1]
-                    corr = jnp.exp(m - m_new)
-                    p_att = jnp.exp(sc - m_new[None])
-                    l = l * corr + jnp.sum(p_att, axis=0)
-                    acc = acc * corr + jnp.sum(p_att * v, axis=0)  # [R, hd]
-                    out.append((m_new, l, acc))
-                return tuple(out)
+            def chunk_body(c, stats):
+                m, l, acc = stats
+                at = pl.ds(pl.multiple_of(c * C, C), C)
+                # Positions of the chunk that the slot attends over: 0 .. left.
+                left = length - (w * pages + c * chunk) * block_size
+                k = k_buf[buf, at]  # [C, hd]
+                v = v_buf[buf, at]
+                # Rows past the length are a stale tail, a page's padding or
+                # a page that was not copied (whatever VMEM held): V's are
+                # zeroed, since 0 x NaN is NaN; K's reach only their own
+                # column of the scores, which the select below overwrites.
+                v = jnp.where(row_position <= left, v, jnp.zeros_like(v))
+                sc = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32) * scale  # [Hp, C]
+                sc = jnp.where(position <= left, sc, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+                corr = jnp.exp(m - m_new)
+                p_att = jnp.exp(sc - m_new)
+                l = l * corr + jnp.sum(p_att, axis=-1, keepdims=True)
+                acc = acc * corr + jax.lax.dot_general(
+                    p_att.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    precision=precision, preferred_element_type=jnp.float32)
+                return m_new, l, acc
 
-            stats = jax.lax.fori_loop(0, window_pages(s, w), page_body, stats)
+            stats = jax.lax.fori_loop(
+                0, pl.cdiv(window_pages(s, w), chunk), chunk_body, stats)
             return n_done + 1, stats
 
-        init = tuple(
-            (
-                jnp.full((R, 1), _NEG_INF, jnp.float32),
-                jnp.zeros((R, 1), jnp.float32),
-                jnp.zeros((R, hd), jnp.float32),
-            )
-            for _ in range(group)
+        init = (
+            jnp.full((Hp, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((Hp, 1), jnp.float32),
+            jnp.zeros((Hp, hd), jnp.float32),
         )
-        n_windows_done, stats = jax.lax.fori_loop(
+        n_windows_done, (_m, l, acc) = jax.lax.fori_loop(
             0, n_windows, window_body, (n_windows_done, init)
         )
-        for g, (m, l, acc) in enumerate(stats):
-            # A fold whose every position is masked has m = -1e30 and l > 0
-            # (exp(0) per masked entry) over acc = 0: its logsumexp weighs
-            # it out of the merge.
-            o_ref[s, g] = acc / l
-            lse_ref[s, g] = jnp.broadcast_to(m + jnp.log(l), (R, lse_ref.shape[-1]))
+        o_ref[s] = acc / l
         return n_windows_done
 
     jax.lax.fori_loop(0, count, slot_body, 0)
@@ -277,6 +313,9 @@ def _paged_kernel(
 
 # One VMEM window of K (and one of V; two of each are held).
 _WINDOW_BYTES = 1 << 20
+# Rows of a window reduced in one pair of products: the scores of a chunk are
+# [heads, rows] float32 and live in vector registers between the two.
+_CHUNK_ROWS = 2048
 
 
 @jax.named_scope("paged_attention")
@@ -289,6 +328,8 @@ def paged_attention(
     [num_blocks, block_size, Hk, hd]; block_tables: int32 [S, max_blocks];
     lengths: int32 [S], slot s attends over positions ``<= lengths[s]``
     (clipped to the table's capacity); returns [S, 1, H, hd] in q's dtype.
+    Products are in the pool's dtype (q is cast to it) with float32
+    accumulation, the softmax float32.
 
     ``active`` (bool [S], optional): an inactive slot is skipped, whatever
     its stale row and length hold: it reads nothing and its output is 0,
@@ -328,87 +369,82 @@ def paged_attention(
         )
     if not interpret and hd % 128:
         _M_GATHER_REROUTES.inc()
+        _M_TRACES.inc(path="gather")
         return gathered_decode_attention(
             q, paged_gather(pool_k, block_tables),
             paged_gather(pool_v, block_tables), lengths,
         )
+    _M_TRACES.inc(path="mxu")
 
     if active is None:
         active = jnp.ones((S,), jnp.bool_)
-    # Fill the (sublane, lane) tile of the pool's dtype with heads: fold
-    # consecutive tokens of a block into the head axis where Hk alone leaves
-    # it part empty.
-    tile_rows = 32 // pool_k.dtype.itemsize
-    fold = math.gcd(block_size, tile_rows // math.gcd(Hk, tile_rows))
-    page_bytes = (
-        block_size // fold * -(-fold * Hk // tile_rows) * tile_rows * hd
-        * pool_k.dtype.itemsize
-    )
-    pages = max(1, min(block_tables.shape[1], _WINDOW_BYTES // page_bytes))
+    itemsize = pool_k.dtype.itemsize
+    tile_rows = 32 // itemsize  # sublanes of a (sublane, lane) tile of the dtype
+    # A page of the window: a block's rows, padded to whole tiles where
+    # block_size x Hk leaves one part empty.
+    page_rows = -(-block_size * Hk // tile_rows) * tile_rows
+    fit = max(1, min(block_tables.shape[1], _WINDOW_BYTES // (page_rows * hd * itemsize)))
+    chunk = max(1, min(fit, _CHUNK_ROWS // page_rows))
     return _paged_call(
         q, pool_k, pool_v, block_tables, lengths, active,
-        fold=fold, pages=pages, interpret=interpret,
+        pages=fit // chunk * chunk,  # whole chunks
+        chunk=chunk, page_rows=page_rows, interpret=interpret,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("fold", "pages", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("pages", "chunk", "page_rows", "interpret"))
 def _paged_call(
-    q, pool_k, pool_v, block_tables, lengths, active, *, fold, pages, interpret
+    q, pool_k, pool_v, block_tables, lengths, active, *, pages, chunk, page_rows,
+    interpret,
 ):
-    """:func:`paged_attention` past its checks.  A jit of its own, so that the
-    layers of a model, which call it with the same shapes, share one trace of
-    the kernel and one lowering (a third of a second of set-up a layer)."""
+    """:func:`paged_attention` past its checks.  A jit of its own, so that
+    the layers of a model, which call it with the same shapes, share one
+    trace of the kernel and one lowering (a third of a second of set-up a
+    layer)."""
     S, _, H, hd = q.shape
     num_blocks, block_size, Hk, _ = pool_k.shape
-    group = H // Hk
-    # The fold is a row-major reshape of the pool (a bitcast on the chip).
-    rows, R = block_size // fold, fold * Hk
-    pool_k = pool_k.reshape(num_blocks, rows, R, hd)
-    pool_v = pool_v.reshape(num_blocks, rows, R, hd)
-    # [S, 1, Hk * group, hd] -> [S, group, fold * Hk, hd]: scaled once, in
-    # float32, and repeated for each token of the fold.
-    qg = q.astype(jnp.float32).reshape(S, Hk, group, hd) * hd**-0.5
-    qg = jnp.tile(qg.transpose(0, 2, 1, 3), (1, 1, fold, 1))
-    # The kernel visits the active slots only, in slot order.
+    dtype = pool_k.dtype
+    # A block as one [tokens x Hk, hd] operand: a row-major reshape of the
+    # pool (a bitcast on the chip).
+    pool_k = pool_k.reshape(num_blocks, block_size * Hk, hd)
+    pool_v = pool_v.reshape(num_blocks, block_size * Hk, hd)
+    # Heads fill whole sublane tiles of the pool's dtype; the padding's
+    # output is cut off below.
+    tile_rows = 32 // dtype.itemsize
+    Hp = -(-H // tile_rows) * tile_rows
+    qp = jnp.pad(q.astype(dtype).reshape(S, H, hd), ((0, 0), (0, Hp - H), (0, 0)))
+    # The active slots first, in slot order: the kernel visits those only.
     order = jnp.argsort(~active, stable=True).astype(jnp.int32)
     count = jnp.sum(active, dtype=jnp.int32).reshape(1)
-
-    window = (2, pages, rows, R, hd)
+    window = (2, pages * page_rows, hd)
+    kernel = functools.partial(
+        _paged_kernel, pages=pages, chunk=chunk, page_rows=page_rows, kv_heads=Hk,
+        group=H // Hk, scale=hd**-0.5,
+        # float32 operands multiply in float32 (Mosaic's default is one
+        # bfloat16 pass); bfloat16 products are exact in the accumulator.
+        precision=jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None,
+    )
     # The scope is what the profiler's trace calls the kernel's operation:
     # paged_attention_<result type and shape>.
     with jax.named_scope("paged_attention"):
-        out, lse = pl.pallas_call(
-            functools.partial(_paged_kernel, pages=pages, fold=fold, kv_heads=Hk),
-            out_shape=[
-                jax.ShapeDtypeStruct((S, group, R, hd), jnp.float32),
-                jax.ShapeDtypeStruct((S, group, R, 128), jnp.float32),
-            ],
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),  # order
-                pl.BlockSpec(memory_space=pltpu.SMEM),  # count
-                pl.BlockSpec(memory_space=pltpu.SMEM),  # block_tables
-                pl.BlockSpec(memory_space=pltpu.SMEM),  # lengths
+        out = pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((S, Hp, hd), jnp.float32),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 4 + [
                 pl.BlockSpec(memory_space=pltpu.VMEM),  # q
-                pl.BlockSpec(memory_space=pl.ANY),  # pool_k: HBM, copied by hand
-                pl.BlockSpec(memory_space=pl.ANY),  # pool_v
+                pl.BlockSpec(memory_space=pl.ANY),  # the pools: HBM, copied by hand
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=[
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[
-                pltpu.VMEM(window, pool_k.dtype),
-                pltpu.VMEM(window, pool_v.dtype),
+                pltpu.VMEM(window, dtype),
+                pltpu.VMEM(window, dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
             interpret=interpret,
-        )(order, count, block_tables, lengths, qg, pool_k, pool_v)
-    # Merge a head's fold: each part is a softmax over its own positions,
-    # weighed by its logsumexp (fold == 1: a weight of exactly 1).
-    out = out.reshape(S, group, fold, Hk, hd)
-    weight = jax.nn.softmax(lse[..., :1].reshape(S, group, fold, Hk, 1), axis=2)
-    out = jnp.sum(out * weight, axis=2)  # [S, group, Hk, hd]
-    return out.transpose(0, 2, 1, 3).reshape(S, 1, H, hd).astype(q.dtype)
+        )(order, count, block_tables, lengths, qp, pool_k, pool_v)
+    return out[:, :H].reshape(S, 1, H, hd).astype(q.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -423,7 +459,7 @@ def _paged_call(
 # a block's rows of one layer are one contiguous copy), and one kernel call
 # serves one layer.  With H heads against one row the products are matrices
 # ([H, W] x [W, tokens] and [H, tokens] x [tokens, value_width]), so this
-# kernel feeds the MXU where the per-head kernel above uses the VPU.
+# kernel feeds the MXU, as the kernel above does.
 
 
 # Tokens in one VMEM window of the latent kernel (two are held).

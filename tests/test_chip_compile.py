@@ -95,10 +95,16 @@ _PAGED_GEOMETRIES = {
     "smoke": (8, 5, 16, 8, 8, 128, jnp.float32),
     # lm_serve_steady: 32 slots x 1,024 positions, 16 heads of 128, bf16
     "serve": (32, 64, 16, 16, 16, 128, jnp.bfloat16),
-    # grouped and multi-query (starcoderbase-1b has one KV head): tokens of
-    # a block fold into the head axis to fill the (16, 128) tile
+    # grouped and multi-query (starcoderbase-1b has one KV head): a block's
+    # [tokens x KV heads, 128] rows are one operand as stored
     "grouped": (32, 64, 16, 16, 4, 128, jnp.bfloat16),
     "multi_query": (32, 64, 16, 16, 1, 128, jnp.bfloat16),
+    # multi-query in blocks of 8: a block's 8 rows are half a bfloat16
+    # sublane tile, copied into a padded page of the window
+    "padded_page": (32, 64, 8, 16, 1, 128, jnp.bfloat16),
+    # solar_serve_longgen's GQA layer: 64 slots x 6,144 positions in blocks
+    # of 128, 64 query heads over 8 KV heads
+    "solar": (64, 48, 128, 64, 8, 128, jnp.bfloat16),
 }
 
 
@@ -146,7 +152,7 @@ def test_paged_decode_step_compiles_at_serve_geometry(chip, name):
     pool_bytes = pool.size * pool.dtype.itemsize
     # Donated pools update in place: the step's outputs alias its inputs.
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
-    # q, the output and the partial softmaxes of a fold, in float32.
+    # q in the pool's dtype and the output in float32, heads padded to a tile.
     assert mem.temp_size_in_bytes < 4 << 20
 
 

@@ -329,7 +329,7 @@ class HybridKdaMoELM:
                 h = self._gqa_prefill(p, h)[0]
             else:
                 p = jax.tree.map(lambda x: x[period, k - 1], params["kda"])
-                h = self._kda_prefill(p, h, None, T)[0]
+                h = self._kda_prefill(p, h, T)[0]
             # An expert's score before the sigmoid is a sum over the hidden
             # size, near enough normal over tokens: its (1 - k/E) quantile from
             # the mean and deviation of ALL the sample, not from the few
@@ -410,21 +410,19 @@ class HybridKdaMoELM:
             jnp.repeat(v, group, axis=1)[None], causal=True)[0]
         return h + self._gqa_output(p, xn, att), k, v
 
-    def _kda_prefill(self, p, h, valid, last):
+    def _kda_prefill(self, p, h, last):
         """A KDA layer's mixer over a whole prompt h [T, D] (T a multiple of
-        the chunk) whose positions ``valid`` [T] (None: all) are real, the
-        first ``last`` of them: (h + y, the state [H, d_v, d_k] and the
-        convolution's tail [3, 3 W] after position last - 1)."""
+        the chunk) whose first ``last`` positions are real: (h + y, the state
+        [H, d_v, d_k] and the convolution's tail [3, 3 W] after position
+        last - 1).  The kernel holds the state still over the padding and
+        runs no chunk that lies wholly in it."""
         T = h.shape[0]
         xn = self._norm(h, p["attn_norm"])
         raw = jnp.pad(self._dot(xn, p["w_qkv"]), ((_CONV - 1, 0), (0, 0)))
         qkv = sum(raw[i:i + T] * p["conv"][i] for i in range(_CONV))
         tail = jax.lax.dynamic_slice_in_dim(raw, last, _CONV - 1, axis=0)
         q, k, v, g, beta = self._kda_inputs(p, xn, qkv)
-        if valid is not None:  # padding moves neither state nor tail
-            g = jnp.where(valid[:, None, None], g, 0.0)
-            beta = jnp.where(valid[:, None], beta, 0.0)
-        o, state = kda.chunked_kda(q, k, v, g, beta)
+        o, state = kda.chunked_kda(q, k, v, g, beta, length=last)
         return h + self._kda_output(p, xn, o), state.transpose(0, 2, 1), tail
 
     def _forward(self, params, toks, tp):
@@ -449,7 +447,7 @@ class HybridKdaMoELM:
 
             def body(h, xs):
                 p, layer = xs
-                h, state, tail = self._kda_prefill(p, h, valid, last)
+                h, state, tail = self._kda_prefill(p, h, last)
                 h, load = self._ffn(p, experts, h, layer, valid)
                 return h, (state, tail, load)
 

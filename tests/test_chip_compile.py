@@ -555,10 +555,10 @@ def test_hybrid_decode_step_updates_state_and_pools_in_place(chip, monkeypatch):
 
 def test_hybrid_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
     """A prompt of 4,096 positions: flash attention over 64 heads, the chunked
-    delta rule of three layers (its sub-block tensors fused into their
-    reductions, not held whole: 2.1 GB would be), 32,768 (token, expert) rows
-    through the grouped matmul.  Weights, temporaries and the engine's 2.47 GB
-    of cache stay under the chip's 16 GB."""
+    delta rule of three layers as one kernel under the layers' scan (what it
+    makes of a chunk stays in VMEM), 32,768 (token, expert) rows through the
+    grouped matmul.  Weights, temporaries and the engine's 2.47 GB of cache
+    stay under the chip's 16 GB."""
     model, params, traffic = _hybrid(monkeypatch)
     Lb = traffic["prompt_tokens"]["max"]
     compiled, text = _compile(
@@ -568,9 +568,32 @@ def test_hybrid_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2.5e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 2.48e9 < 15.5e9
-    assert "f32[64,64,4,16,16,128]" not in re.findall(r"= (f32\[[\d,]*\])", text.split("ENTRY")[1])
+    # one call, under the scan, its result in the chunk layout
+    assert re.findall(r"%kda_prefill[.\d]* = \((f32\[[\d,]*\])", text) == ["f32[64,64,64,128]"]
     assert len(re.findall(r"%flash_attention[\w.]* = ", text)) >= 1
     assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[32768,", text)) == 4
+
+
+@pytest.mark.parametrize("bucket", [4096, 256])
+def test_kda_prefill_kernel_compiles_through_mosaic_at_the_cells_buckets(chip, bucket):
+    """The chunked delta rule alone at the published widths (64 heads of
+    128), the largest and the smallest bucket of ``solar_serve_longgen``, with
+    a length and a state to start from: a block that does not tile or a
+    kernel over its VMEM is refused here."""
+    from moolib_tpu.ops import kda
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    x = f32(bucket, 64, 128)
+    compiled, text = _compile(
+        lambda q, k, v, g, beta, length, state: kda.chunked_kda(
+            q, k, v, g, beta, length=length, state=state, interpret=False),
+        x, x, x, x, f32(bucket, 64), jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),
+        f32(64, 128, 128))
+    assert re.findall(r"%kda_prefill[.\d]* = \((f32\[[\d,]*\])", text) == [
+        f"f32[64,{bucket // kda.CHUNK},64,128]"]
+    # q, k, v, g laid out again as rows of 8,192 lanes (in the model they are
+    # born so) and o in both layouts: nothing of a chunk's algebra is held
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6 * bucket * 64 * 128 * 4 + (8 << 20)
 
 
 def test_hybrid_init_balances_the_bias_inside_the_chip(chip, monkeypatch):

@@ -102,6 +102,49 @@ def test_chunked_prefill_equals_the_token_by_token_recurrence(T):
     np.testing.assert_allclose(got_S, want_S, atol=2e-5)
 
 
+# T, the real positions of it (None: all), a state to start from, the spread of
+# the log-decays (2.0: below -50 a step)
+@pytest.mark.parametrize("T,length,start,spread", [
+    (128, None, True, 1.0),    # a state to start from
+    (256, 128, False, 1.0),    # shorter by whole chunks
+    (256, 150, True, 1.0),     # ... and by part of one
+    (192, 1, False, 1.0),      # one real position
+    (128, 0, True, 1.0),       # none: the state comes back as it went in
+    (256, None, False, 1.0),   # 4 chunks
+    (2048, 1990, False, 1.0),  # 32 chunks
+    (256, 170, True, 2.0),     # log-decays below -50 a step, a length and a state
+])
+def test_prefill_kernel_takes_a_length_and_a_state(T, length, start, spread):
+    """Positions from ``length`` on are whatever the bucket holds (here: as
+    lively as the real ones, unmasked): the state is what position length - 1
+    left, the rows of ``o`` below it are the recurrence's, and the chunks
+    wholly past it were not run, so their rows are 0."""
+    q, k, v, g, beta = _kda_inputs(T, seed=11, spread=spread)
+    S0 = (jax.random.normal(jax.random.key(12), (2, 128, 128)) if start
+          else jnp.zeros((2, 128, 128)))
+    n = T if length is None else length
+    want_o, want_S = _highest(kda.recurrent_kda, q[:n], k[:n], v[:n], g[:n], beta[:n], S0)
+    got_o, got_S = kda.chunked_kda(
+        q, k, v, g, beta, length=None if length is None else jnp.int32(length),
+        state=S0 if start else None)
+    assert spread < 2.0 or float(g[:n].min()) < -50.0
+    np.testing.assert_allclose(got_o[:n], want_o, atol=2e-6)
+    np.testing.assert_allclose(got_S, want_S, atol=2e-5)
+    skipped = -(-n // kda.CHUNK) * kda.CHUNK
+    np.testing.assert_array_equal(np.asarray(got_o[skipped:]), 0.0)
+
+
+def test_prefill_in_two_calls_is_the_prefill_in_one():
+    """The second call starts from the state the first one left: what a
+    prompt prefilled in pieces between decode steps will do."""
+    q, k, v, g, beta = _kda_inputs(256, seed=13, spread=1.0)
+    whole_o, whole_S = kda.chunked_kda(q, k, v, g, beta)
+    first_o, S = kda.chunked_kda(*(x[:128] for x in (q, k, v, g, beta)))
+    second_o, S = kda.chunked_kda(*(x[128:] for x in (q, k, v, g, beta)), state=S)
+    np.testing.assert_allclose(jnp.concatenate([first_o, second_o]), whole_o, atol=1e-6)
+    np.testing.assert_allclose(S, whole_S, atol=2e-6)
+
+
 @pytest.mark.parametrize("active", [(True, False, True, True), (False,) * 4, (True,) * 4])
 def test_decode_kernel_in_interpret_mode_equals_the_jnp_step(active):
     q, k, v, g, beta = _kda_inputs(4, seed=3, spread=1.0)

@@ -61,6 +61,7 @@ MODULES = [
     ("moolib_tpu.models.impala", "Models: IMPALA ResNet"),
     ("moolib_tpu.models.qnet", "Models: recurrent Q-network (R2D2)"),
     ("moolib_tpu.models.transformer", "Models: Transformer LM"),
+    ("moolib_tpu.models.decoder_parts", "Models: what the file-built decoders share"),
     ("moolib_tpu.models.latent_moe", "Models: latent-attention decoder with dropless experts"),
     ("moolib_tpu.models.retention_lm", "Models: power-retention decoder (a state a slot, no paged cache)"),
     ("moolib_tpu.ops.vtrace", "Ops: V-trace"),

@@ -18,10 +18,7 @@ engine removes both wastes:
 Prefill is a separate shape-bucketed jitted path (``serving.bucket`` — the
 canonical bucketing policy) over the full prompt, reusing the model's own
 ``collect_kv`` teacher-forced forward; its K/V rows scatter straight into
-pool blocks.  With ``mesh=`` and ``prefill_devices=``, prefill runs on a
-``split_mesh`` submesh and the K/V hand off to the decode submesh through
-the d2d :class:`..batcher.Batcher` (the PR-7 Sebulba seam generalized to
-serving; ``batcher_d2d_bytes_total`` counts the crossing).
+pool blocks.
 
 **One decode step ahead.**  The step samples on the device and feeds its
 own outputs (tokens, lengths, active flags, budgets) to the next call as
@@ -75,7 +72,7 @@ three shapes a model's memory can take, decided by what it offers:
   leaf: what a sequence holds whatever its length (a linear attention's
   recurrent state, a convolution's tail) beside the rows that grow.  The
   engine allocates it beside the pools and the model's cache is
-  ``kv_pool.SlotCache(blocks, slots)``, one pytree in the donated chain;
+  ``decoder_parts.SlotCache(blocks, slots)``, one pytree in the donated chain;
 - **a state a slot alone** (``state_spec`` and no ``cache_spec``): every layer
   keeps a fixed state and nothing grows.  There is no ``BlockPool``, no block
   table among the step's or the join's arguments and no ``write_rows`` call;
@@ -102,8 +99,8 @@ and, for whichever leaves it has:
   whole, so nothing of the slot's last holder is ever read.  It runs in the
   join's jit, in the same donated chain as ``write_rows``: a join dispatched
   behind a step in flight lands behind it.  A retire does no device work;
-- ``decode(params, cache, tokens [S], paged, mesh=None)`` -> (logits [S, V],
-  cache, int32 counters or None), with ``step_counters`` /
+- ``decode(params, cache, tokens [S], paged)`` -> (logits [S, V], cache,
+  int32 counters or None), with ``step_counters`` /
   ``prefill_counters`` their lengths.  ``paged`` is a ``PagedState`` whose
   ``block_tables`` is None without pools (``lengths`` are the positions,
   ``active`` the slots that step).  Slot-axis leaves advance for the slots
@@ -134,10 +131,11 @@ import jax.numpy as jnp
 
 from .. import telemetry, utils
 from ..telemetry import devmon
+from ..models.decoder_parts import SlotCache
 from ..models.transformer import PagedTransformerLM, TransformerLM
 from ..ops.paged_attention import PagedState
 from ..serving import _M_PHASE, bucket, bucket_shapes
-from .kv_pool import BlockPool, PoolExhausted, SlotCache
+from .kv_pool import BlockPool, PoolExhausted
 
 _REG = telemetry.get_registry()
 # Registration is idempotent: serving.py declares the same counter for the
@@ -228,8 +226,7 @@ class ContinuousBatchingEngine:
                  max_seq_len: Optional[int] = None,
                  max_prompt_len: Optional[int] = None,
                  min_prompt_len: int = 1,
-                 eos_id: Optional[int] = None,
-                 mesh=None, prefill_devices: int = 0):
+                 eos_id: Optional[int] = None):
         if isinstance(model, TransformerLM):
             model = PagedTransformerLM(model)
         self.model = model
@@ -261,25 +258,6 @@ class ContinuousBatchingEngine:
         self._n_step_counters = getattr(model, "step_counters", 0)
         self._n_prefill_counters = getattr(model, "prefill_counters", 0)
 
-        # Optional disaggregated prefill: first N mesh devices prefill, the
-        # rest decode; K/V cross through the device-path Batcher (counted
-        # d2d, no host bounce).
-        self._prefill_sharding = self._decode_sharding = None
-        self._decode_mesh = None  # handed to the paged kernel's shard_map
-        self._xfer = None
-        if mesh is not None and prefill_devices:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            from ..batcher import Batcher
-            from ..parallel.mesh import split_mesh
-
-            pmesh, dmesh = split_mesh(mesh, prefill_devices)
-            self._decode_mesh = dmesh
-            self._prefill_sharding = NamedSharding(pmesh, PartitionSpec())
-            self._decode_sharding = NamedSharding(dmesh, PartitionSpec())
-            self._xfer = Batcher(1, device=self._decode_sharding,
-                                 name="engine_prefill_xfer")
-
         self.set_params(params)
 
         S, MB = self.slots, self.max_blocks_per_seq
@@ -306,16 +284,14 @@ class ContinuousBatchingEngine:
                 "engine: %d slots hold %.3f GB of state, %.1f MB a slot%s", S,
                 self.state_bytes / 1e9, self.state_bytes / S / 1e6,
                 "" if self.pool is not None else "; no paged pool")
-        self._cache = self._place_decode(jax.tree.map(
-            lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), spec))
+        self._cache = jax.tree.map(lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), spec)
         # Without pools there is no table: None is an empty pytree, so the
         # step's and the join's programs take no such argument.
-        self._tables = (self._place_decode(jnp.zeros((S, MB), jnp.int32))
-                        if self.pool is not None else None)
-        self._lengths = self._place_decode(jnp.zeros((S,), jnp.int32))
-        self._active = self._place_decode(jnp.zeros((S,), jnp.bool_))
-        self._tokens = self._place_decode(jnp.zeros((S,), jnp.int32))
-        self._remaining = self._place_decode(jnp.zeros((S,), jnp.int32))
+        self._tables = jnp.zeros((S, MB), jnp.int32) if self.pool is not None else None
+        self._lengths = jnp.zeros((S,), jnp.int32)
+        self._active = jnp.zeros((S,), jnp.bool_)
+        self._tokens = jnp.zeros((S,), jnp.int32)
+        self._remaining = jnp.zeros((S,), jnp.int32)
 
         # Host mirrors (slot bookkeeping never round-trips device state).
         self._free_slots: List[int] = list(range(S - 1, -1, -1))
@@ -358,29 +334,18 @@ class ContinuousBatchingEngine:
             "engine.join",
         )
 
-    # ------------------------------------------------------------- placement
-    def _place_decode(self, x):
-        if self._decode_sharding is None:
-            return x
-        return jax.device_put(x, self._decode_sharding)
-
     def set_params(self, params) -> None:
         """Install new weights (host or device pytree).  Called between
         iterations by the service's hot-swap hook — the KV pools and slot
         state are untouched, so in-flight sequences continue under the new
         weights (same contract as the baseline's mid-stream swap)."""
-        if self._decode_sharding is not None:
-            self._params_dec = jax.device_put(params, self._decode_sharding)
-            self._params_pre = jax.device_put(params, self._prefill_sharding)
-        else:
-            self._params_dec = self._params_pre = params
+        self._params = params
 
     # ------------------------------------------------------------ jit bodies
     def _step_impl(self, params, cache, tables, lengths, active, tokens,
                    remaining):
         logits, cache, counters = self.model.decode(
-            params, cache, tokens, PagedState(tables, lengths, active),
-            mesh=self._decode_mesh)
+            params, cache, tokens, PagedState(tables, lengths, active))
         act = active.astype(jnp.int32)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         nxt = jnp.where(active, nxt, tokens)
@@ -520,11 +485,7 @@ class ContinuousBatchingEngine:
             if pad:
                 self._stats["prefill_pad_tokens"] += pad
                 _M_PAD_TOKENS.inc(pad)
-            toks_dev = (toks if self._prefill_sharding is None
-                        else jax.device_put(toks, self._prefill_sharding))
-            rows, first = self._prefill_jit(
-                self._params_pre, toks_dev, np.int32(tp)
-            )
+            rows, first = self._prefill_jit(self._params, toks, np.int32(tp))
             first.copy_to_host_async()
         self._stats["prefill_tokens"] += tp
         _M_PREFILL_TOKENS.inc(tp)
@@ -532,11 +493,6 @@ class ContinuousBatchingEngine:
             # No slot to join, and known before the prefill: the one
             # admission that waits for its token.
             return None, [self._read_first(first, tp)]
-        joined = first  # what the join reads; ``first`` is what the host reads
-        if self._xfer is not None:
-            # Prefill submesh -> decode submesh, one device-path crossing.
-            self._xfer.stack((rows, first))
-            rows, joined = jax.tree.map(lambda x: x[0], self._xfer.get())
         if not self._free_slots:
             raise NoFreeSlot(f"all {self.slots} slots occupied")
         with telemetry.span("engine.join"):
@@ -556,7 +512,7 @@ class ContinuousBatchingEngine:
                  self._tokens, self._remaining) = self._join_jit(
                     self._cache, self._tables, self._lengths, self._active,
                     self._tokens, self._remaining,
-                    np.int32(slot), row, np.int32(tp), joined,
+                    np.int32(slot), row, np.int32(tp), first,
                     np.int32(max_new - 1), rows, written,
                 )
         self._slot_blocks[slot] = block_ids
@@ -573,10 +529,8 @@ class ContinuousBatchingEngine:
             self._flight[1][slot] = False
         self._stats["joins"] += 1
         _M_JOINS.inc()
-        if self._xfer is None:
-            # (Through the prefill hand-off the d2d put may wait: not counted.)
-            self._stats["joins_ahead"] += 1
-            _M_JOINS_AHEAD.inc()
+        self._stats["joins_ahead"] += 1
+        _M_JOINS_AHEAD.inc()
         self._update_gauges()
         return slot, emitted
 
@@ -620,7 +574,7 @@ class ContinuousBatchingEngine:
         host.  Returns the packet."""
         (self._cache, self._tables, self._lengths, self._active,
          self._tokens, self._remaining, packet) = self._step_jit(
-            self._params_dec, self._cache, self._tables, self._lengths,
+            self._params, self._cache, self._tables, self._lengths,
             self._active, self._tokens, self._remaining,
         )
         packet.copy_to_host_async()
@@ -725,20 +679,13 @@ class ContinuousBatchingEngine:
         shapes = 0
         seen_nbw = set()
         for lb in sorted({self._bucket(b) for b in bucket_shapes(self.max_prompt_len)}):
-            toks = np.zeros((1, lb), np.int32)
-            toks_dev = (toks if self._prefill_sharding is None
-                        else jax.device_put(toks, self._prefill_sharding))
             rows, first = self._prefill_jit(
-                self._params_pre, toks_dev, np.int32(lb)
-            )
+                self._params, np.zeros((1, lb), np.int32), np.int32(lb))
             shapes += 1
             nbw = self.pool.blocks_for(lb) if self.pool is not None else None
             if nbw in seen_nbw:
                 continue
             seen_nbw.add(nbw)
-            if self._xfer is not None:
-                self._xfer.stack((rows, first))
-                rows, first = jax.tree.map(lambda x: x[0], self._xfer.get())
             row = None if nbw is None else np.zeros(self.max_blocks_per_seq, np.int32)
             (self._cache, self._tables, self._lengths, self._active,
              self._tokens, self._remaining) = self._join_jit(

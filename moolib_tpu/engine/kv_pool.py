@@ -9,32 +9,11 @@ accounts as ``serve_pad_tokens_total``.
 
 Block id 0 is reserved as the null block: never allocated, the scatter
 target for inactive slots in the fixed-shape decode step.
-
-:class:`SlotCache` is the device cache of a model that keeps two kinds of
-leaf: the paged pools this allocator hands blocks of, and a fixed-size state
-a decode SLOT owns (a linear attention's recurrent state), which needs no
-allocator: the slot's id is its address, a join overwrites the row whole and
-a retire leaves it where it is.  A model that keeps ONLY such a state has
-neither: its cache is the slot-axis pytree itself and the engine builds no
-``BlockPool`` for it (``engine.py``, "What a model offers").
 """
 
 from __future__ import annotations
 
-from typing import Any, List, NamedTuple
-
-
-class SlotCache(NamedTuple):
-    """Both kinds of cache leaf, one pytree in the engine's donated chain.
-
-    blocks: the paged pools, block axis first in every leaf
-        (``model.cache_spec(num_blocks, block_size)``).
-    slots: what a slot owns, slot axis first in every leaf
-        (``model.state_spec(slots)``).
-    """
-
-    blocks: Any
-    slots: Any
+from typing import List
 
 
 class PoolExhausted(RuntimeError):

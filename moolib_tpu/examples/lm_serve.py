@@ -245,8 +245,8 @@ def main(argv=None):
     p.add_argument(
         "--mesh",
         default="",
-        help='serve tensor-parallel over these axes, e.g. "tp=8" '
-        "(server side only; params sharded via auto_shardings)",
+        help='serve tensor-parallel over these axes, e.g. "tp=8" (server '
+        "side only, not with --engine; params sharded via auto_shardings)",
     )
     p.add_argument("--seed", type=int, default=0)
     p.add_argument(
@@ -275,11 +275,6 @@ def main(argv=None):
         help="engine KV pool block size in tokens",
     )
     p.add_argument(
-        "--prefill_devices", type=int, default=0,
-        help="with --engine and --mesh: run prefill on the first N mesh "
-        "devices and decode on the rest (d2d K/V handoff)",
-    )
-    p.add_argument(
         "--service_delay_ms", type=float, default=0.0,
         help="add this many milliseconds to every service iteration — a "
         "load-testing hook that makes saturation (and so the autoscaler's "
@@ -304,12 +299,15 @@ def main(argv=None):
             "pass --listen, --connect, or --broker/--broker_addrs (client mode)")
     if flags.listen is not None and flags.connect is not None:
         raise SystemExit("--listen and --connect are mutually exclusive")
-    utils.init_compile_cache()  # before the first jit (utils/compile_cache.py)
-    telemetry.init_from_env()  # opt-in exporters (docs/TELEMETRY.md)
-
     if flags.config and not (flags.engine and flags.listen):
         raise SystemExit("--config builds a model only the engine serves: "
                          "pass --engine and --listen with it")
+    if flags.engine and flags.mesh:
+        raise SystemExit("--engine serves from one device: --mesh is the "
+                         "batch-synchronous arm's")
+    utils.init_compile_cache()  # before the first jit (utils/compile_cache.py)
+    telemetry.init_from_env()  # opt-in exporters (docs/TELEMETRY.md)
+
     model = None if flags.config else make_model(flags)
     if flags.listen:
         from .. import parallel
@@ -368,7 +366,6 @@ def main(argv=None):
                     slots=flags.slots or flags.batch_size,
                     block_size=flags.block_size,
                     max_prompt_len=flags.seq_len,
-                    mesh=mesh, prefill_devices=flags.prefill_devices,
                 )
                 engine.warmup()
                 if flags.service_delay_ms > 0:

@@ -37,18 +37,17 @@ convolution tail float32.
 from __future__ import annotations
 
 import dataclasses
-import json
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
 from .. import telemetry
-from ..engine.kv_pool import SlotCache
 from ..ops import kda
 from ..ops.flash_attention import flash_attention
 from ..ops.paged_attention import PagedState, paged_attention, paged_kv_write
 from ..parallel.moe import dropless_moe
+from . import decoder_parts as parts
 
 _REG = telemetry.get_registry()
 _M_HELD_PAIRS = _REG.histogram(
@@ -116,11 +115,7 @@ class HybridKdaMoELM:
         that holds the published keys; ``overrides`` replace single sizes (a
         test's depth, the engine's ``max_len``).  A key the model cannot
         honour is refused by name."""
-        if not isinstance(config, dict):
-            with open(config) as f:
-                config = json.load(f)
-        dtype = overrides.pop("dtype", jnp.bfloat16)
-        config = {**config, **overrides}
+        config, dtype = parts.load_config(config, overrides)
         lin = config["linear_attn_config"]
         refused = {
             "use_rope": config.get("use_rope", False) is not False,
@@ -139,9 +134,7 @@ class HybridKdaMoELM:
         period = config.get("gqa_interval", 3) + 1
         refused["gqa_layers"] = gqa != list(range(0, depth, period))
         refused["num_hidden_layers"] = depth % period != 0  # whole periods only
-        bad = sorted(k for k, v in refused.items() if v)
-        if bad:
-            raise ValueError(f"HybridKdaMoELM does not implement the file's {', '.join(bad)}")
+        parts.refuse(cls.__name__, refused)
         return cls(
             vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
             num_hidden_layers=depth, num_attention_heads=config["num_attention_heads"],
@@ -212,20 +205,16 @@ class HybridKdaMoELM:
                 (slots, self.kda_layers, _CONV - 1, 3 * self.kda_width), jnp.float32),
         }
 
-    def write_rows(self, cache: SlotCache, rows, block_ids) -> SlotCache:
+    def write_rows(self, cache: parts.SlotCache, rows, block_ids) -> parts.SlotCache:
         blocks = jax.tree.map(
             lambda pool, new: pool.at[block_ids].set(new.astype(pool.dtype)),
             cache.blocks, rows["blocks"])
         return cache._replace(blocks=blocks)
 
-    def write_state(self, cache: SlotCache, rows, slot) -> SlotCache:
+    def write_state(self, cache: parts.SlotCache, rows, slot) -> parts.SlotCache:
         """The join's other half: the slot's row of every slot-axis leaf
         becomes the prefill's, whole."""
-        slots = jax.tree.map(
-            lambda leaf, new: jax.lax.dynamic_update_index_in_dim(
-                leaf, new.astype(leaf.dtype), slot, 0),
-            cache.slots, rows["slots"])
-        return cache._replace(slots=slots)
+        return cache._replace(slots=parts.write_slot_rows(cache.slots, rows["slots"], slot))
 
     # -------------------------------------------------------------- weights
     def init(self, key) -> Dict:
@@ -239,19 +228,7 @@ class HybridKdaMoELM:
         H, hd, Hk = self.num_attention_heads, self.head_dim, self.num_key_value_heads
         W, r, E = self.kda_width, self.gate_rank, self.router_experts
         P, K = self.periods, self.period - 1
-        keys = iter(jax.random.split(key, 64))
-
-        def w(shape, fan_in, dtype=None):
-            def draw(key, shape):
-                x = jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
-                return x.astype(dtype or self.dtype)
-
-            if len(shape) < 3:
-                return draw(next(keys), shape)
-            # A slice of the leading axis at a time: the float32 draw of a
-            # whole stack of experts would not fit beside the weights.
-            return jax.lax.map(lambda k: draw(k, shape[1:]),
-                               jax.random.split(next(keys), shape[0]))
+        keys, w = parts.weight_drawer(key, 64, self.dtype)
 
         def ffn(lead):
             return {
@@ -344,12 +321,14 @@ class HybridKdaMoELM:
 
     # ------------------------------------------------------------- pieces
     def _norm(self, x, scale):
-        x = x.astype(jnp.float32)
-        var = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.rms_norm_eps) * scale
+        return parts.rms_norm(x, scale, self.rms_norm_eps)
 
     def _dot(self, x, w):
-        return jnp.dot(x.astype(self.dtype), w, preferred_element_type=jnp.float32)
+        return parts.dot(x, w, self.dtype)
+
+    def _head(self, params, h):
+        return parts.head_logits(
+            h, params["final_norm"], params["head"], self.rms_norm_eps, self.dtype)
 
     def _ffn(self, p, experts, h, layer, valid):
         """The expert layer every layer ends in; ``layer`` indexes the
@@ -464,21 +443,18 @@ class HybridKdaMoELM:
         length.  Returns (rows for :meth:`write_rows` and :meth:`write_state`,
         logits [V] float32 at position tp - 1, counters [layers] int32: the
         fullest held expert's tokens by layer, pad tokens not counted)."""
-        Lb = toks.shape[1]
         h, ks, vs, state, tail, load = self._forward(params, toks[0], tp)
-        nbw = -(-Lb // block_size)
 
-        def blocks(x):  # [Lb, Hk, hd] -> [nbw, block_size, Hk, hd]
-            x = jnp.pad(x, ((0, nbw * block_size - Lb), (0, 0), (0, 0)))
-            return x.reshape(nbw, block_size, *x.shape[1:])
+        def blocks(xs):  # [Lb, Hk, hd] -> [nbw, block_size, Hk, hd], by period
+            return tuple(parts.rows_to_blocks(x, block_size, axis=0) for x in xs)
 
-        rows = {"blocks": {"k": tuple(map(blocks, ks)), "v": tuple(map(blocks, vs))},
+        rows = {"blocks": {"k": blocks(ks), "v": blocks(vs)},
                 "slots": {"kda": state, "conv": tail}}
-        last = self._norm(jnp.take(h, tp - 1, axis=0), params["final_norm"])
-        return rows, self._dot(last, params["head"]), jnp.max(load, axis=-1).astype(jnp.int32)
+        logits = self._head(params, jnp.take(h, tp - 1, axis=0))
+        return rows, logits, jnp.max(load, axis=-1).astype(jnp.int32)
 
     # -------------------------------------------------------------- decode
-    def decode(self, params, cache: SlotCache, tokens, paged: PagedState, mesh=None):
+    def decode(self, params, cache: parts.SlotCache, tokens, paged: PagedState, mesh=None):
         """One token a slot.  tokens [S]; returns (logits [S, V] float32, the
         cache with this step's K/V written and the active slots' state and
         tail advanced, counters: :attr:`step_counters`)."""
@@ -534,18 +510,16 @@ class HybridKdaMoELM:
             jnp.sum(active, dtype=jnp.int32)[None],
             jnp.sum(load, axis=-1, dtype=jnp.int32),
             jnp.sum(load > 0, axis=-1, dtype=jnp.int32)])
-        cache = SlotCache(
+        cache = parts.SlotCache(
             blocks={"k": tuple(pools_k), "v": tuple(pools_v)},
             slots={"kda": state, "conv": conv})
-        logits = self._dot(self._norm(h, params["final_norm"]), params["head"])
-        return logits, cache, counters
+        return self._head(params, h), cache, counters
 
     # ---------------------------------------------------- the whole forward
     def logits(self, params, toks):
         """Teacher-forced logits [T, V] of one sequence toks [T] through the
         prefill path (tests)."""
-        h = self._forward(params, toks, None)[0]
-        return self._dot(self._norm(h, params["final_norm"]), params["head"])
+        return self._head(params, self._forward(params, toks, None)[0])
 
 
 def tiny_config() -> Dict:

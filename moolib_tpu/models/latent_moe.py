@@ -29,7 +29,6 @@ and the logits in float32.  Parameter names follow the equations
 from __future__ import annotations
 
 import dataclasses
-import json
 from typing import Any, Dict
 
 import jax
@@ -43,6 +42,7 @@ from ..ops.paged_attention import (
     latent_paged_attention,
 )
 from ..parallel.moe import dropless_moe, swiglu
+from . import decoder_parts as parts
 
 _LANES = 128
 
@@ -90,21 +90,19 @@ class LatentMoELM:
     def from_config(cls, config, **overrides) -> "LatentMoELM":
         """Build from a configuration (a dict, or the path of its JSON file)
         that holds the published keys; ``overrides`` replace single sizes
-        (a test's depth, the engine's ``max_len``)."""
-        if not isinstance(config, dict):
-            with open(config) as f:
-                config = json.load(f)
-        if config.get("n_group", 1) != 1 or config.get("topk_group", 1) != 1:
-            raise ValueError("group-limited routing (n_group > 1) is not implemented")
-        if config.get("rope_scaling") is not None:
-            raise ValueError("rope_scaling is not implemented")
-        if config.get("n_shared_experts", 1) != 1 or config.get("first_k_dense_replace", 1) != 1:
-            raise ValueError("one shared expert and one leading dense layer are implemented")
+        (a test's depth, the engine's ``max_len``).  A key the model cannot
+        honour is refused by name."""
+        config, dtype = parts.load_config(config, overrides)
+        parts.refuse(cls.__name__, {
+            "n_group": config.get("n_group", 1) != 1 or config.get("topk_group", 1) != 1,
+            "rope_scaling": config.get("rope_scaling") is not None,
+            "n_shared_experts": config.get("n_shared_experts", 1) != 1,
+            "first_k_dense_replace": config.get("first_k_dense_replace", 1) != 1,
+        })
         names = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in config.items() if k in names and k != "dtype"}
         kw.setdefault("max_len", min(config.get("max_position_embeddings", 8192), 8192))
-        kw.update(overrides)
-        return cls(**kw)
+        return cls(dtype=dtype, **kw)
 
     # ------------------------------------------------------------ geometry
     @property
@@ -159,19 +157,7 @@ class LatentMoELM:
         nope, vd, ql = self.qk_nope_head_dim, self.v_head_dim, self.q_lora_rank
         F, Fd, E = self.moe_intermediate_size, self.intermediate_size, self.n_routed_experts
         Lm = self.num_hidden_layers - 1
-        keys = iter(jax.random.split(key, 64))
-
-        def w(shape, fan_in, dtype=None):
-            def draw(key, shape):
-                x = jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
-                return x.astype(dtype or self.dtype)
-
-            if len(shape) < 3:
-                return draw(next(keys), shape)
-            # A slice of the leading axis at a time: the float32 draw of a
-            # whole stack of experts would not fit beside the weights.
-            return jax.lax.map(lambda k: draw(k, shape[1:]),
-                               jax.random.split(next(keys), shape[0]))
+        keys, w = parts.weight_drawer(key, 64, self.dtype)
 
         def attn(lead):
             return {
@@ -208,23 +194,14 @@ class LatentMoELM:
 
     # ------------------------------------------------------------- pieces
     def _norm(self, x, scale):
-        x = x.astype(jnp.float32)
-        var = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.rms_norm_eps) * scale
-
-    def _rope(self, x, pos):
-        """Rotary embedding, half-split pairs (i, i + r/2); x [..., r] with
-        ``pos`` broadcastable against its leading axes."""
-        half = x.shape[-1] // 2
-        freq = self.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-        ang = jnp.asarray(pos, jnp.float32)[..., None] * freq
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        x = x.astype(jnp.float32)
-        a, b = x[..., :half], x[..., half:]
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+        return parts.rms_norm(x, scale, self.rms_norm_eps)
 
     def _dot(self, x, w):
-        return jnp.dot(x.astype(self.dtype), w, preferred_element_type=jnp.float32)
+        return parts.dot(x, w, self.dtype)
+
+    def _head(self, params, h):
+        return parts.head_logits(
+            h, params["final_norm"], params["head"], self.rms_norm_eps, self.dtype)
 
     def _latent(self, p, xn, pos):
         """Queries and this layer's cache rows for normed inputs xn [T, D] at
@@ -235,10 +212,11 @@ class LatentMoELM:
         cq = self._norm(self._dot(xn, p["w_dq"]), p["q_norm"])
         q = self._dot(cq, p["w_uq"]).reshape(T, H, self.qk_nope_head_dim + r)
         q_nope, q_rope = q[..., : self.qk_nope_head_dim], q[..., self.qk_nope_head_dim:]
-        q_rope = self._rope(q_rope, pos[:, None])
+        q_rope = parts.rope_half_split(q_rope, pos[:, None], self.rope_theta)
         ckv = self._dot(xn, p["w_dkv"])
         rows = jnp.concatenate(
-            [self._norm(ckv[:, :c], p["kv_norm"]), self._rope(ckv[:, c:], pos),
+            [self._norm(ckv[:, :c], p["kv_norm"]),
+             parts.rope_half_split(ckv[:, c:], pos, self.rope_theta),
              jnp.zeros((T, self.row_width - c - r), jnp.float32)], axis=-1)
         return q_nope, q_rope, rows.astype(self.dtype)
 
@@ -305,12 +283,9 @@ class LatentMoELM:
             return h, (rows, jnp.max(load))
 
         h, (rows, fullest) = jax.lax.scan(body, h, (sliced, self._expert_layers()))
-        rows = jnp.concatenate([rows0[None], rows])  # [L, Lb, W]
-        nbw = -(-Lb // block_size)
-        rows = jnp.pad(rows, ((0, 0), (0, nbw * block_size - Lb), (0, 0)))
-        rows = rows.reshape(self.num_hidden_layers, nbw, block_size, -1)
-        last = self._norm(jnp.take(h, tp - 1, axis=0), params["final_norm"])
-        logits = self._dot(last, params["head"])
+        rows = parts.rows_to_blocks(  # [L, Lb, W] -> [L, nbw, block_size, W]
+            jnp.concatenate([rows0[None], rows]), block_size, axis=1)
+        logits = self._head(params, jnp.take(h, tp - 1, axis=0))
         return rows.transpose(1, 0, 2, 3), logits, fullest.astype(jnp.int32)
 
     # -------------------------------------------------------------- decode
@@ -356,8 +331,7 @@ class LatentMoELM:
 
         (h, pool), touched = jax.lax.scan(
             body, (h, pool), (sliced, self._expert_layers()))
-        logits = self._dot(self._norm(h, params["final_norm"]), params["head"])
-        return logits, pool, touched.astype(jnp.int32)
+        return self._head(params, h), pool, touched.astype(jnp.int32)
 
     # ---------------------------------------------------- the whole forward
     def logits(self, params, toks):
@@ -371,7 +345,7 @@ class LatentMoELM:
         h, _ = jax.lax.scan(
             lambda h, xs: (self._prefill_layer({**xs[0], **whole}, h, pos, None, xs[1])[0], None),
             h, (sliced, self._expert_layers()))
-        return self._dot(self._norm(h, params["final_norm"]), params["head"])
+        return self._head(params, h)
 
 
 def tiny_config() -> Dict:

@@ -30,7 +30,6 @@ state, its normaliser and the logits in float32.
 from __future__ import annotations
 
 import dataclasses
-import json
 from typing import Any, Dict
 
 import jax
@@ -40,6 +39,7 @@ from .. import telemetry
 from ..ops import retention
 from ..ops.paged_attention import PagedState
 from ..parallel.moe import swiglu
+from . import decoder_parts as parts
 
 _M_STATE_LIVE = telemetry.get_registry().histogram(
     "serve_engine_state_live_slots",
@@ -74,12 +74,8 @@ class PowerRetentionLM:
         that holds the published keys; ``overrides`` replace single sizes (a
         test's depth, the engine's ``max_len``).  A key the model cannot
         honour is refused by name."""
-        if not isinstance(config, dict):
-            with open(config) as f:
-                config = json.load(f)
-        dtype = overrides.pop("dtype", jnp.bfloat16)
-        config = {**config, **overrides}
-        refused = {
+        config, dtype = parts.load_config(config, overrides)
+        parts.refuse(cls.__name__, {
             "attention_bias": config.get("attention_bias", False) is not False,
             "hidden_act": config.get("hidden_act", "silu") != "silu",
             "rope_scaling": config.get("rope_scaling") is not None,
@@ -87,10 +83,7 @@ class PowerRetentionLM:
             "tie_word_embeddings": config.get("tie_word_embeddings", False) is not False,
             "num_key_value_heads": config["num_attention_heads"] % config["num_key_value_heads"] != 0,
             "max_len": config.get("max_len", 0) > config.get("max_position_embeddings", 1 << 30),
-        }
-        bad = sorted(k for k, v in refused.items() if v)
-        if bad:
-            raise ValueError(f"PowerRetentionLM does not implement the file's {', '.join(bad)}")
+        })
         return cls(
             vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
             intermediate_size=config["intermediate_size"],
@@ -118,10 +111,7 @@ class PowerRetentionLM:
 
     def write_state(self, cache, rows, slot):
         """The join: the slot's row of every leaf becomes the prefill's, whole."""
-        return jax.tree.map(
-            lambda leaf, new: jax.lax.dynamic_update_index_in_dim(
-                leaf, new.astype(leaf.dtype), slot, 0),
-            cache, rows)
+        return parts.write_slot_rows(cache, rows, slot)
 
     # -------------------------------------------------------------- weights
     def init(self, key) -> Dict:
@@ -133,19 +123,7 @@ class PowerRetentionLM:
         prompt in three tokens).  Jit it: the weights are made on the device."""
         D, F, L = self.hidden_size, self.intermediate_size, self.num_hidden_layers
         H, G, hd = self.num_attention_heads, self.num_key_value_heads, self.head_dim
-        keys = iter(jax.random.split(key, 16))
-
-        def w(shape, fan_in, dtype=None, scale=1.0):
-            def draw(key, shape):
-                x = jax.random.normal(key, shape, jnp.float32) * (scale * fan_in ** -0.5)
-                return x.astype(dtype or self.dtype)
-
-            if len(shape) < 3:
-                return draw(next(keys), shape)
-            # A slice of the leading axis at a time: the float32 draw of a
-            # whole stack would not fit beside the weights.
-            return jax.lax.map(lambda k: draw(k, shape[1:]),
-                               jax.random.split(next(keys), shape[0]))
+        keys, w = parts.weight_drawer(key, 16, self.dtype)
 
         forget = jnp.exp(jax.random.uniform(
             next(keys), (L, G), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
@@ -171,22 +149,14 @@ class PowerRetentionLM:
 
     # ------------------------------------------------------------- pieces
     def _norm(self, x, scale):
-        x = x.astype(jnp.float32)
-        var = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.rms_norm_eps) * scale
-
-    def _rope(self, x, pos):
-        """Rotary embedding, half-split pairs (i, i + d/2); x [T, heads, d]
-        at positions pos [T]."""
-        half = x.shape[-1] // 2
-        freq = self.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-        ang = jnp.asarray(pos, jnp.float32)[:, None, None] * freq
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        a, b = x[..., :half], x[..., half:]
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+        return parts.rms_norm(x, scale, self.rms_norm_eps)
 
     def _dot(self, x, w):
-        return jnp.dot(x.astype(self.dtype), w, preferred_element_type=jnp.float32)
+        return parts.dot(x, w, self.dtype)
+
+    def _head(self, params, h):
+        return parts.head_logits(
+            h, params["final_norm"], params["head"], self.rms_norm_eps, self.dtype)
 
     def _mixer_inputs(self, p, xn, pos):
         """What retention takes, from normed inputs xn [T, D] at positions pos
@@ -197,8 +167,9 @@ class PowerRetentionLM:
         # folds the head-major reshapes into the dots and transposes the weights.
         q, kv = jax.lax.optimization_barrier((self._dot(xn, p["w_q"]), self._dot(xn, p["w_kv"])))
         k, v = jnp.split(kv.reshape(T, 2 * G, hd), 2, axis=1)
-        q = self._rope(self._norm(q.reshape(T, H, hd), p["q_norm"]), pos)
-        k = self._rope(self._norm(k, p["k_norm"]), pos)
+        q = parts.rope_half_split(
+            self._norm(q.reshape(T, H, hd), p["q_norm"]), pos[:, None], self.rope_theta)
+        k = parts.rope_half_split(self._norm(k, p["k_norm"]), pos[:, None], self.rope_theta)
         gate = jnp.dot(xn, p["w_gate"], precision=jax.lax.Precision.HIGHEST) + p["b_gate"]
         return q, k, v, jax.nn.log_sigmoid(gate)
 
@@ -235,8 +206,8 @@ class PowerRetentionLM:
         is paged).  Returns (the rows :meth:`write_state` takes, logits [V]
         float32 at position tp - 1, None: no counters)."""
         h, (state, norm) = self._forward(params, toks[0], tp)
-        last = self._norm(jnp.take(h, tp - 1, axis=0), params["final_norm"])
-        return {"state": state, "norm": norm}, self._dot(last, params["head"]), None
+        logits = self._head(params, jnp.take(h, tp - 1, axis=0))
+        return {"state": state, "norm": norm}, logits, None
 
     # -------------------------------------------------------------- decode
     def decode(self, params, cache, tokens, paged: PagedState, mesh=None):
@@ -259,15 +230,14 @@ class PowerRetentionLM:
         layers = jnp.arange(self.num_hidden_layers, dtype=jnp.int32)
         (h, state, norm), _ = jax.lax.scan(
             body, (h, cache["state"], cache["norm"]), (params["layers"], layers))
-        logits = self._dot(self._norm(h, params["final_norm"]), params["head"])
-        return logits, {"state": state, "norm": norm}, jnp.sum(active, dtype=jnp.int32)[None]
+        return (self._head(params, h), {"state": state, "norm": norm},
+                jnp.sum(active, dtype=jnp.int32)[None])
 
     # ---------------------------------------------------- the whole forward
     def logits(self, params, toks):
         """Teacher-forced logits [T, V] of one sequence toks [T] (a power of
         two, or a multiple of 256) through the prefill path (tests)."""
-        h = self._forward(params, toks, None)[0]
-        return self._dot(self._norm(h, params["final_norm"]), params["head"])
+        return self._head(params, self._forward(params, toks, None)[0])
 
 
 def tiny_config() -> Dict:

@@ -29,6 +29,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .decoder_parts import rows_to_blocks
+
 
 def apply_rotary(x: jax.Array, base: float = 10000.0, offset=0) -> jax.Array:
     """Rotary position embedding (RoPE, Su et al. 2021) on [B, T, H, D].
@@ -161,7 +163,6 @@ class Block(nn.Module):
                 )
                 att = paged_attention(
                     q, pk.value, pv.value, paged.block_tables, t, paged.active,
-                    mesh=mesh,
                 ).astype(x.dtype)
             else:
                 ck = self.variable(
@@ -430,15 +431,11 @@ class PagedTransformerLM:
         hd]`` each."""
         logits, col = self._pre.apply(
             {"params": params["params"]}, toks, mutable=["kv"])
-        Lb = toks.shape[1]
-        nbw = -(-Lb // block_size)
 
         def blocks(which):
             x = jnp.stack([col["kv"][f"block{i}"][which][0][0]
                            for i in range(self.model.num_layers)])
-            x = jnp.pad(x, ((0, 0), (0, nbw * block_size - Lb), (0, 0), (0, 0)))
-            return x.reshape(x.shape[0], nbw, block_size, *x.shape[2:]).astype(
-                self.model.dtype)
+            return rows_to_blocks(x, block_size, axis=1).astype(self.model.dtype)
 
         return (blocks("k"), blocks("v")), jnp.take(logits[0], tp - 1, axis=0), None
 
@@ -453,14 +450,14 @@ class PagedTransformerLM:
             }
         return new_cache
 
-    def decode(self, params, cache, tokens, paged, mesh=None):
+    def decode(self, params, cache, tokens, paged):
         pool = cache["block0"]["pool_k"]
         dec = self._twin(
             attention="dense",  # unused: decode attention is the paged kernel
             decode=True, kv_num_blocks=pool.shape[0], kv_block_size=pool.shape[1])
         logits, upd = dec.apply(
             {"params": params["params"], "cache": cache}, tokens[:, None],
-            mesh=mesh, paged=paged, mutable=["cache"])
+            paged=paged, mutable=["cache"])
         return logits[:, 0], upd["cache"], None
 
 

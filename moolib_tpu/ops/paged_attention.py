@@ -319,10 +319,8 @@ _CHUNK_ROWS = 2048
 
 
 @jax.named_scope("paged_attention")
-def paged_attention(
-    q, pool_k, pool_v, block_tables, lengths, active=None, *, interpret=None,
-    mesh=None,
-):
+def paged_attention(q, pool_k, pool_v, block_tables, lengths, active=None, *,
+                    interpret=None):
     """Decode attention against a paged KV pool, read in place by one fused
     kernel (module docstring).  q: [S, 1, H, hd]; pool_k/pool_v:
     [num_blocks, block_size, Hk, hd]; block_tables: int32 [S, max_blocks];
@@ -335,23 +333,9 @@ def paged_attention(
     its stale row and length hold: it reads nothing and its output is 0,
     for the caller to ignore.  ``interpret`` is chosen from
     ``jax.default_backend()`` when None: Mosaic on ``tpu``, Pallas interpret
-    mode on ``cpu``.  ``mesh``: pass the mesh when the step is a program XLA
-    partitions over one (the engine's decode submesh): XLA cannot partition a
-    Mosaic call, so it is wrapped in a ``shard_map`` in which every device
-    computes the whole, replicated.  The block tables and lengths ride in
-    SMEM whole (8 KB at 32 slots x 64 blocks).
+    mode on ``cpu``.  The block tables and lengths ride in SMEM whole (8 KB
+    at 32 slots x 64 blocks).
     """
-    if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-
-        operands = (q, pool_k, pool_v, block_tables, lengths, active)
-        return jax.shard_map(
-            functools.partial(paged_attention, interpret=interpret),
-            mesh=mesh,
-            in_specs=tuple(None if x is None else P() for x in operands),
-            out_specs=P(),
-            check_vma=False,
-        )(*operands)
     if interpret is None:
         backend = jax.default_backend()
         if backend not in ("tpu", "cpu"):

@@ -123,7 +123,7 @@ def _paged_step_args(sharding, geometry):
     ))
 
 
-def _paged_step(mesh=None):
+def _paged_step():
     from moolib_tpu.ops.paged_attention import paged_attention, paged_kv_write
 
     def step(q, k_new, v_new, pool_k, pool_v, tables, lengths, active):
@@ -132,7 +132,7 @@ def _paged_step(mesh=None):
         # interpret=False steers the kernel onto Mosaic: left to itself it
         # asks jax.default_backend(), which is the cpu in this process.
         att = paged_attention(q, pool_k, pool_v, tables, lengths, active,
-                              interpret=False, mesh=mesh)
+                              interpret=False)
         return att, pool_k, pool_v
 
     return jax.jit(step, donate_argnums=(3, 4))
@@ -154,22 +154,6 @@ def test_paged_decode_step_compiles_at_serve_geometry(chip, name):
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     # q in the pool's dtype and the output in float32, heads padded to a tile.
     assert mem.temp_size_in_bytes < 4 << 20
-
-
-def test_paged_decode_step_compiles_replicated_over_a_mesh(topo):
-    """The engine's decode submesh (``prefill_devices``) holds everything
-    replicated; XLA refuses to partition a Mosaic call, so ``mesh=`` wraps it
-    in a ``shard_map``."""
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-    mesh = Mesh(np.asarray(topo.devices[:2]), ("dp",))
-    _, args = _paged_step_args(
-        NamedSharding(mesh, PartitionSpec()), _PAGED_GEOMETRIES["serve"])
-    with pytest.raises(Exception, match="shard_map"):
-        _compile(_paged_step(), *args)
-    _, text = _compile(_paged_step(mesh), *args)
-    assert text.count("tpu_custom_call") == 1
 
 
 def test_paged_attention_reroutes_a_head_size_mosaic_cannot_copy(chip):
@@ -521,7 +505,7 @@ def test_hybrid_decode_step_updates_state_and_pools_in_place(chip, monkeypatch):
     its outputs, copies no leaf of it (the delta-rule kernel's in-place update
     survives XLA under the scan over a period's three KDA layers), converts no
     table and transposes no weight."""
-    from moolib_tpu.engine.kv_pool import SlotCache
+    from moolib_tpu.models.decoder_parts import SlotCache
     from moolib_tpu.ops.paged_attention import PagedState
 
     model, params, traffic = _hybrid(monkeypatch)

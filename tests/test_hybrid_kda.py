@@ -26,7 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chipbench.reference import solar_open2 as ref  # noqa: E402
 from moolib_tpu.engine import ContinuousBatchingEngine  # noqa: E402
-from moolib_tpu.engine.kv_pool import SlotCache  # noqa: E402
+from moolib_tpu.models.decoder_parts import SlotCache  # noqa: E402
 from moolib_tpu.models.hybrid_kda import HybridKdaMoELM, tiny_config  # noqa: E402
 from moolib_tpu.ops import kda  # noqa: E402
 from moolib_tpu.ops.paged_attention import PagedState  # noqa: E402

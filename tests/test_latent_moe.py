@@ -51,7 +51,7 @@ def test_builds_from_the_published_keys(model):
     assert model.row_width == 256  # padded to whole 128-lane tiles
     spec = model.cache_spec(9, 8)
     assert spec.shape == (9, CFG["num_hidden_layers"], 8, 256)
-    with pytest.raises(ValueError, match="group-limited"):
+    with pytest.raises(ValueError, match="LatentMoELM does not implement the file's n_group$"):
         LatentMoELM.from_config({**CFG, "n_group": 2})
 
 

@@ -449,3 +449,19 @@ def test_lm_state_bytes_gauges_and_result(monkeypatch, path):
                        ("lm_state_device_bytes", "state_device_bytes")):
         series = snapshot[gauge]["series"]
         assert [s["value"] for s in series] == [out[key]] == [spy.calls[0][0]]
+
+
+@pytest.mark.parametrize("argv, sentence", [
+    (["--listen", "127.0.0.1:1", "--engine", "--mesh", "tp=2"],
+     "--engine serves from one device"),
+    (["--listen", "127.0.0.1:1", "--config", "some.json"],
+     "--config builds a model only the engine serves"),
+])
+def test_lm_serve_refuses_at_start_what_the_engine_does_not_serve(argv, sentence):
+    """Before anything is built: the engine runs on one device (a ``--mesh``
+    is the batch-synchronous arm's), and a configuration file builds a model
+    that only the engine serves."""
+    from moolib_tpu.examples import lm_serve
+
+    with pytest.raises(SystemExit, match=sentence):
+        lm_serve.main(argv)

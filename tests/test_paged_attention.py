@@ -243,22 +243,6 @@ def test_kernel_windows_span_many_blocks_and_lengths_clip(monkeypatch, patch):
     assert np.array_equal(np.asarray(a)[3], np.asarray(b)[3])
 
 
-def test_kernel_under_a_mesh_computes_replicated():
-    """XLA cannot partition a Mosaic call: with ``mesh=`` the call is a
-    ``shard_map`` in which every device computes the whole (the engine's
-    decode submesh under ``prefill_devices``)."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-    q, clean, _, tables, lengths, active = _kernel_case(2, 4, jnp.float32)
-    mesh = Mesh(np.asarray(jax.devices()[:2]), ("dp",))
-    rep = NamedSharding(mesh, PartitionSpec())
-    args = jax.device_put((q, *clean, tables, lengths, active), rep)
-    out = jax.jit(lambda *a: paged_attention(*a, mesh=mesh))(*args)
-    one = _traced_anew(q, *clean, tables, lengths, active)
-    assert out.sharding.is_equivalent_to(rep, out.ndim)
-    assert np.array_equal(np.asarray(out), np.asarray(one))
-
-
 def test_kernel_rejects_what_it_cannot_attend():
     q, clean, _, tables, lengths, _ = _kernel_case(2, 4, jnp.float32)
     with pytest.raises(ValueError, match="one query position"):

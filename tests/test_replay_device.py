@@ -262,6 +262,10 @@ def test_concurrent_add_sample_update_is_serialized():
     )
     errs = []
     stop = threading.Event()
+    # What each thread has done, by the thread alone: the run lasts until the
+    # ring has been filled over (eight adds of 8 into 64) and every sampler
+    # has gone round once, however slow the machine is.
+    adds, rounds = [0], [0, 0]
 
     def adder():
         rng = np.random.default_rng(4)
@@ -271,10 +275,11 @@ def test_concurrent_add_sample_update_is_serialized():
                     [{"x": np.zeros(4, np.float32)} for _ in range(8)],
                     (rng.random(8) + 0.1).astype(np.float32),
                 )
+                adds[0] += 1
         except Exception as e:  # noqa: BLE001 — the assertion payload
             errs.append(e)
 
-    def sampler():
+    def sampler(i):
         try:
             while not stop.is_set():
                 _, idx, w = shard.sample(8)
@@ -282,17 +287,23 @@ def test_concurrent_add_sample_update_is_serialized():
                     idx, np.asarray(w).astype(np.float32) + 0.5
                 )
                 shard.total_host()
+                rounds[i] += 1
         except Exception as e:  # noqa: BLE001
             errs.append(e)
 
     threads = [
         threading.Thread(target=adder),
-        threading.Thread(target=sampler),
-        threading.Thread(target=sampler),
+        threading.Thread(target=sampler, args=(0,)),
+        threading.Thread(target=sampler, args=(1,)),
     ]
     for t in threads:
         t.start()
-    time.sleep(0.5)
+    deadline = time.monotonic() + 60
+    while not errs and (adds[0] < 8 or min(rounds) < 1):
+        if time.monotonic() > deadline:
+            stop.set()
+            pytest.fail(f"after 60 s: {adds[0]} adds, sampler rounds {rounds}")
+        time.sleep(0.01)
     stop.set()
     for t in threads:
         t.join(10)

@@ -1,6 +1,8 @@
 """What the decoders built from a published configuration file share
-(``latent_moe``, ``hybrid_kda``, ``retention_lm``): everything that is not a
-mixer, as plain functions.  Nothing here knows the serving engine: ``engine/``
+(``latent_moe``, ``hybrid_kda``, ``retention_lm``, ``swa_moe``): everything
+that is not a mixer, as plain functions, and the grouped-query layer's pieces
+that two of them run (its projections, its paged decode, the counters of an
+expert layer of which this chip holds a share).  Nothing here knows the serving engine: ``engine/``
 imports ``models/``, never the other way.  Matmul inputs are in the model's
 ``dtype`` with float32 accumulation; RMSNorm, RoPE and the logits float32.
 """
@@ -8,10 +10,34 @@ imports ``models/``, never the other way.  Matmul inputs are in the model's
 from __future__ import annotations
 
 import json
+import math
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from .. import telemetry
+from ..ops.paged_attention import paged_attention, paged_kv_write
+
+_REG = telemetry.get_registry()
+_M_HELD_PAIRS = _REG.histogram(
+    "serve_engine_held_pair_share",
+    "per decode step and expert layer: (token, expert) pairs of active slots' "
+    "tokens whose expert is held here, over all their pairs",
+    buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0),
+)
+_M_HELD_TOUCHED = _REG.histogram(
+    "serve_engine_held_experts_touched",
+    "per decode step and expert layer: held experts that at least one active "
+    "slot's token chose (the expert matrices the step reads)",
+    buckets=(1, 2, 4, 8, 16, 24, 32, 40, 48, 56, 64, 128, 256),
+)
+_M_HELD_PREFILL_LOAD = _REG.histogram(
+    "serve_engine_held_prefill_expert_load",
+    "per prefill and expert layer: the fullest held expert's tokens over the "
+    "mean (prompt tokens x experts a token / the router's experts)",
+    buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0),
+)
 
 
 class SlotCache(NamedTuple):
@@ -89,6 +115,36 @@ def rope_half_split(x, pos, theta):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
+def yarn_inv_freq(rotated: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's table of ``rotated // 2`` inverse frequencies, fixed whatever the
+    length: ``theta ** (-2i / rotated)`` where a pair turns more than
+    ``beta_fast`` times over the ``original`` positions, that over ``factor``
+    where it turns fewer than ``beta_slow`` times, and a linear ramp between
+    the two correction dimensions (rounded outwards) in between."""
+    def correction_dim(turns):
+        return rotated * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rotated - 1)
+    plain = theta ** (-jnp.arange(0, rotated, 2, dtype=jnp.float32) / rotated)
+    ramp = jnp.clip((jnp.arange(rotated // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rope_table(x, pos, inv_freq, scale: float = 1.0):
+    """:func:`rope_half_split` over the FIRST ``2 len(inv_freq)`` entries of x
+    [..., r] at the given table of inverse frequencies, cos and sin times
+    ``scale``; the rest of the row passes unrotated.  float32."""
+    half = inv_freq.shape[0]
+    ang = jnp.asarray(pos, jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    x = x.astype(jnp.float32)
+    a, b, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest], axis=-1)
+
+
 def dot(x, w, dtype):
     return jnp.dot(x.astype(dtype), w, preferred_element_type=jnp.float32)
 
@@ -115,3 +171,64 @@ def write_slot_rows(leaves, rows, slot):
         lambda leaf, new: jax.lax.dynamic_update_index_in_dim(
             leaf, new.astype(leaf.dtype), slot, 0),
         leaves, rows)
+
+
+# ------------------------------------------------- the grouped-query layer
+def gqa_qkv(xn, w_q, w_kv, heads: int, kv_heads: int, head_dim: int, dtype):
+    """Normed inputs xn [T, D] through W_q and W_k | W_v (side by side in
+    ``w_kv``): q [T, heads, hd], k and v [T, kv_heads, hd], float32."""
+    T = xn.shape[0]
+    # The barrier keeps the products as [T, heads x hd]: left to itself XLA
+    # folds the attention kernels' head-major reshapes into the dots and
+    # transposes W_q and W_kv (84 MB) in every decode step instead.
+    q, kv = jax.lax.optimization_barrier((dot(xn, w_q, dtype), dot(xn, w_kv, dtype)))
+    k, v = jnp.split(kv.reshape(T, 2 * kv_heads, head_dim), 2, axis=1)
+    return q.reshape(T, heads, head_dim), k, v
+
+
+def paged_gqa_decode(pool_k, pool_v, q, k, v, paged):
+    """One decode step of a layer over paged K/V: this step's k and v [S, Hk,
+    hd] written at ``paged.lengths`` (an inactive slot's into the null block),
+    then q [S, H, hd] against the pools.  Returns (attention [S, H, hd] in the
+    pools' dtype, pool_k, pool_v)."""
+    pool_k = paged_kv_write(pool_k, k, paged.block_tables, paged.lengths, paged.active)
+    pool_v = paged_kv_write(pool_v, v, paged.block_tables, paged.lengths, paged.active)
+    att = paged_attention(q[:, None].astype(pool_k.dtype), pool_k, pool_v,
+                          paged.block_tables, paged.lengths, paged.active)[:, 0]
+    return att, pool_k, pool_v
+
+
+def write_pool_blocks(pools, rows, block_ids):
+    """A join's ``write_rows``: a prompt's blocks into the pools, leaf by leaf."""
+    return jax.tree.map(
+        lambda pool, new: pool.at[block_ids].set(new.astype(pool.dtype)), pools, rows)
+
+
+# --------------------------------------- an expert layer's share, counted
+def held_experts(params):
+    """The held experts' matrices, stacked over the expert layers, as
+    ``dropless_moe`` takes them beside a layer's own weights."""
+    return {k: params[k] for k in ("experts_gu", "experts_down")}
+
+
+def held_step_counters(load):
+    """From tokens a held expert by layer ``load`` [L, G]: by layer the held
+    (token, expert) pairs, then the held experts touched, int32 [2 L]."""
+    return jnp.concatenate([jnp.sum(load, axis=-1, dtype=jnp.int32),
+                            jnp.sum(load > 0, axis=-1, dtype=jnp.int32)])
+
+
+def observe_held_step(counters, live: int, top_k: int) -> None:
+    """:func:`held_step_counters`, back on the host, of a step with ``live``
+    active slots."""
+    pairs, touched = counters[:len(counters) // 2], counters[len(counters) // 2:]
+    for n_pairs, n_touched in zip(pairs, touched):
+        _M_HELD_PAIRS.observe(int(n_pairs) / max(1, live * top_k))
+        _M_HELD_TOUCHED.observe(int(n_touched))
+
+
+def observe_held_prefill(counters, prompt_len: int, top_k: int, router_experts: int) -> None:
+    """The fullest held expert's tokens by layer, over the mean."""
+    mean = prompt_len * top_k / router_experts
+    for fullest in counters:
+        _M_HELD_PREFILL_LOAD.observe(float(fullest) / mean)

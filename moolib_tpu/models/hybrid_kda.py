@@ -45,30 +45,11 @@ import jax.numpy as jnp
 from .. import telemetry
 from ..ops import kda
 from ..ops.flash_attention import flash_attention
-from ..ops.paged_attention import PagedState, paged_attention, paged_kv_write
+from ..ops.paged_attention import PagedState
 from ..parallel.moe import dropless_moe
 from . import decoder_parts as parts
 
-_REG = telemetry.get_registry()
-_M_HELD_PAIRS = _REG.histogram(
-    "serve_engine_held_pair_share",
-    "per decode step and expert layer: (token, expert) pairs of active slots' "
-    "tokens whose expert is held here, over all their pairs",
-    buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0),
-)
-_M_HELD_TOUCHED = _REG.histogram(
-    "serve_engine_held_experts_touched",
-    "per decode step and expert layer: held experts that at least one active "
-    "slot's token chose (the expert matrices the step reads)",
-    buckets=(1, 2, 4, 8, 16, 24, 32, 40, 48, 56, 64, 128, 256),
-)
-_M_HELD_PREFILL_LOAD = _REG.histogram(
-    "serve_engine_held_prefill_expert_load",
-    "per prefill and expert layer: the fullest held expert's tokens over the "
-    "mean (prompt tokens x experts a token / the router's experts)",
-    buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0),
-)
-_M_STATE_LIVE = _REG.histogram(
+_M_STATE_LIVE = telemetry.get_registry().histogram(
     "serve_engine_state_live_slots",
     "per decode step: slots holding live recurrent state (the active ones: "
     "the states the delta-rule kernel reads and writes, a layer)",
@@ -180,16 +161,13 @@ class HybridKdaMoELM:
     def observe_step(self, counters) -> None:
         """A decode step's counters, back on the host (the engine fetched
         them with the step's packet)."""
-        live, L = int(counters[0]), self.num_hidden_layers
+        live = int(counters[0])
         _M_STATE_LIVE.observe(live)
-        for pairs, touched in zip(counters[1:1 + L], counters[1 + L:]):
-            _M_HELD_PAIRS.observe(int(pairs) / max(1, live * self.num_experts_per_tok))
-            _M_HELD_TOUCHED.observe(int(touched))
+        parts.observe_held_step(counters[1:], live, self.num_experts_per_tok)
 
     def observe_prefill(self, counters, prompt_len: int) -> None:
-        mean = prompt_len * self.num_experts_per_tok / self.router_experts
-        for fullest in counters:
-            _M_HELD_PREFILL_LOAD.observe(float(fullest) / mean)
+        parts.observe_held_prefill(
+            counters, prompt_len, self.num_experts_per_tok, self.router_experts)
 
     def cache_spec(self, num_blocks: int, block_size: int):
         pool = jax.ShapeDtypeStruct(
@@ -206,10 +184,8 @@ class HybridKdaMoELM:
         }
 
     def write_rows(self, cache: parts.SlotCache, rows, block_ids) -> parts.SlotCache:
-        blocks = jax.tree.map(
-            lambda pool, new: pool.at[block_ids].set(new.astype(pool.dtype)),
-            cache.blocks, rows["blocks"])
-        return cache._replace(blocks=blocks)
+        return cache._replace(
+            blocks=parts.write_pool_blocks(cache.blocks, rows["blocks"], block_ids))
 
     def write_state(self, cache: parts.SlotCache, rows, slot) -> parts.SlotCache:
         """The join's other half: the slot's row of every slot-axis leaf
@@ -296,7 +272,7 @@ class HybridKdaMoELM:
         scores follow from its output."""
         T = toks.shape[0]
         h = params["embed"][toks].astype(jnp.float32)
-        experts = self._experts(params)
+        experts = parts.held_experts(params)
         tail = jax.scipy.stats.norm.ppf(1.0 - self.num_experts_per_tok / self.router_experts)
         biases = []
         for layer in range(self.num_hidden_layers):
@@ -360,21 +336,12 @@ class HybridKdaMoELM:
         return self._dot(o, p["w_o"])
 
     def _gqa_qkv(self, p, xn):
-        T, Hk, hd = xn.shape[0], self.num_key_value_heads, self.head_dim
-        # The barrier keeps the products as [T, heads x hd]: left to itself XLA
-        # folds the attention kernels' head-major reshapes into the dots and
-        # transposes W_q and W_kv (84 MB) in every decode step instead.
-        q, kv = jax.lax.optimization_barrier(
-            (self._dot(xn, p["w_q"]), self._dot(xn, p["w_kv"])))
-        k, v = jnp.split(kv.reshape(T, 2 * Hk, hd), 2, axis=1)
-        return q.reshape(T, self.num_attention_heads, hd), k, v
+        return parts.gqa_qkv(xn, p["w_q"], p["w_kv"], self.num_attention_heads,
+                             self.num_key_value_heads, self.head_dim, self.dtype)
 
     def _gqa_output(self, p, xn, att):
         gate = jax.nn.sigmoid(self._dot(xn, p["w_gate"]))
         return self._dot(att.reshape(xn.shape[0], -1) * gate, p["w_o"])
-
-    def _experts(self, params):
-        return {k: params[k] for k in ("experts_gu", "experts_down")}
 
     # ------------------------------------------------------------- prefill
     def _gqa_prefill(self, p, h):
@@ -416,7 +383,7 @@ class HybridKdaMoELM:
         valid = None if tp is None and not pad else pos < (T if tp is None else tp)
         last = T if tp is None else tp
         h = params["embed"][jnp.pad(toks, (0, pad))].astype(jnp.float32)
-        experts = self._experts(params)
+        experts = parts.held_experts(params)
         ks, vs, states, tails, loads = [], [], [], [], []
         for period in range(self.periods):
             p = jax.tree.map(lambda x: x[period], params["gqa"])
@@ -463,7 +430,7 @@ class HybridKdaMoELM:
         S = tokens.shape[0]
         active = paged.active
         h = params["embed"][tokens].astype(jnp.float32)
-        experts = self._experts(params)
+        experts = parts.held_experts(params)
         pools_k, pools_v = list(cache.blocks["k"]), list(cache.blocks["v"])
         state, conv = cache.slots["kda"], cache.slots["conv"]
         loads = []
@@ -471,13 +438,8 @@ class HybridKdaMoELM:
             p = jax.tree.map(lambda x: x[period], params["gqa"])
             xn = self._norm(h, p["attn_norm"])
             q, k, v = self._gqa_qkv(p, xn)
-            pools_k[period] = paged_kv_write(
-                pools_k[period], k, paged.block_tables, paged.lengths, active)
-            pools_v[period] = paged_kv_write(
-                pools_v[period], v, paged.block_tables, paged.lengths, active)
-            att = paged_attention(
-                q[:, None].astype(self.dtype), pools_k[period], pools_v[period],
-                paged.block_tables, paged.lengths, active)[:, 0]
+            att, pools_k[period], pools_v[period] = parts.paged_gqa_decode(
+                pools_k[period], pools_v[period], q, k, v, paged)
             h = h + self._gqa_output(p, xn, att)
             h, load = self._ffn(p, experts, h, period * self.period, active)
             loads.append(load[None])
@@ -507,9 +469,7 @@ class HybridKdaMoELM:
             loads.append(load)
         load = jnp.concatenate(loads, axis=0)  # [L, G]
         counters = jnp.concatenate([
-            jnp.sum(active, dtype=jnp.int32)[None],
-            jnp.sum(load, axis=-1, dtype=jnp.int32),
-            jnp.sum(load > 0, axis=-1, dtype=jnp.int32)])
+            jnp.sum(active, dtype=jnp.int32)[None], parts.held_step_counters(load)])
         cache = parts.SlotCache(
             blocks={"k": tuple(pools_k), "v": tuple(pools_v)},
             slots={"kda": state, "conv": conv})

@@ -11,6 +11,13 @@ output block revisits and the scratch accumulators carry across iterations
 The reference framework has no attention at all (SURVEY.md §5.7) — this is
 new TPU-idiomatic capability for the long-context side of the framework.
 
+``window=W`` (a sliding window: a query sees itself and the W - 1 keys
+before it) is a forward of its own, :func:`_flash_window_kernel`: its grid's
+key axis is as long as the key blocks ONE query block's window can touch, and
+the index map starts it at that block's first, so a key block outside every
+window of the query block is never copied or multiplied.  It has no backward:
+differentiation raises.  ``window=None`` traces the program it always did.
+
 Layout [B, T, H, D]; shapes that don't tile (T without a 128-multiple
 divisor) take the XLA dense path, counted in ``flash_dense_reroutes_total``.
 The kernels lower through Mosaic on the ``tpu`` platform and run in Pallas
@@ -111,11 +118,66 @@ def _flash_kernel(
         # lane-replicated (block_q, 128) layout — no in-kernel transpose.
         lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
 
+def _window_blocks(qi, window, block_q, block_k, maximum=max):
+    """(first, last) of the key blocks that query block ``qi``'s windows
+    touch; ``maximum=jnp.maximum`` where ``qi`` is traced."""
+    first = maximum(qi * block_q - (window - 1), 0) // block_k
+    return first, ((qi + 1) * block_q - 1) // block_k
 
-def _blockwise_attention(q, k, v, causal, block_q, block_k, return_lse=False):
+
+def _flash_window_kernel(
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+    *, scale, window, block_q, block_k,
+):
+    """Causal attention under a sliding window: grid (batch*heads, q blocks,
+    the key blocks a query block's windows can touch).  Step ``j`` of the key
+    axis holds key block ``first + j`` of this query block (the index map put
+    it there); past the diagonal's block the map repeats that block, nothing
+    is copied and nothing runs."""
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    first, last = _window_blocks(qi, window, block_q, block_k, jnp.maximum)
+    ki = first + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ki <= last)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [bq, bk] f32
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s, _NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        # A row with no key in this block keeps zero weight.
+        p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _blockwise_attention(q, k, v, causal, block_q, block_k, return_lse=False,
+                         window=None):
     """Pure-jax chunked streaming-softmax attention — the differentiable
     reference the backward pass uses (same math as the kernel; O(block)
-    score memory thanks to the scan + checkpointed inner step)."""
+    score memory thanks to the scan + checkpointed inner step).  ``window``: a
+    query sees itself and the ``window - 1`` keys before it (with ``causal``)."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     scale = D**-0.5
@@ -141,6 +203,8 @@ def _blockwise_attention(q, k, v, causal, block_q, block_k, return_lse=False):
                     q_pos = qi * block_q + jnp.arange(block_q)
                     k_pos = ki * block_k + jnp.arange(block_k)
                     mask = q_pos[:, None] >= k_pos[None, :]
+                    if window is not None:
+                        mask &= q_pos[:, None] - k_pos[None, :] < window
                     s = jnp.where(mask[None, None], s, _NEG_INF)
                 return online_softmax_update(
                     s, v_blk, acc, l, m, zero_masked_rows=causal
@@ -208,6 +272,21 @@ def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, g):
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_window(q, k, v, window, block_q, block_k, interpret):
+    return _flash_window_forward(q, k, v, window, block_q, block_k, interpret)
+
+
+def _flash_window_no_vjp(*_):
+    raise NotImplementedError(
+        "flash_attention(window=...) has no backward: the windowed kernel is a "
+        "forward for serving prefill; a gradient would need windowed dq and "
+        "dk/dv passes (differentiate window=None)")
+
+
+_flash_window.defvjp(_flash_window_no_vjp, _flash_window_no_vjp)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -460,8 +539,15 @@ def flash_attention(
     interpret: bool | None = None,
     return_lse: bool = False,
     mesh=None,
+    window: int | None = None,
 ):
     """Blockwise attention; q/k/v: [B, T, H, D] → [B, T, H, D].
+
+    ``window``: the keys a query sees, itself included (a sliding window of
+    ``window`` positions; needs ``causal`` and Tq == Tk).  Key blocks outside
+    every window of a query block are skipped, not masked.  Forward only:
+    differentiating a windowed call raises, and ``return_lse`` and ``mesh``
+    are not offered with it.
 
     ``mesh``: pass the mesh when calling from a program XLA partitions over
     one (a jitted step with sharded inputs).  XLA cannot partition a Mosaic
@@ -482,6 +568,13 @@ def flash_attention(
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
+    if window is not None:
+        if not causal or Tq != Tk or return_lse or mesh is not None or window < 1:
+            raise ValueError(
+                "flash_attention(window=...) is causal self-attention over one "
+                "sequence (Tq == Tk, window >= 1), without return_lse or mesh")
+        if window >= Tq:
+            window = None  # every key a query may see is inside its window
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
@@ -511,7 +604,9 @@ def flash_attention(
     if block_q is None:
         block_q = _largest_divisor(Tq, 512)
     if block_k is None:
-        block_k = _largest_divisor(Tk, 1024)
+        # Under a window a key block wider than the window is mostly masked.
+        cap_k = 1024 if window is None else min(1024, max(128, window // 128 * 128))
+        block_k = _largest_divisor(Tk, cap_k)
     # Blocks below the 128-lane tile (T with a large odd factor) aren't worth
     # a pallas launch — use the dense path.  An unusable *caller-supplied*
     # block raises instead (the caller tuning blocks gets a signal, not an
@@ -535,6 +630,8 @@ def flash_attention(
         )
         if return_lse:
             return dense_attention_lse(q, k, v, causal=causal)
+        if window is not None:  # the oracle, one block the whole length
+            return _blockwise_attention(q, k, v, True, Tq, Tk, window=window)
         return full_attention(q, k, v, causal=causal)
     if interpret is None:
         backend = jax.default_backend()
@@ -546,7 +643,45 @@ def flash_attention(
         interpret = backend == "cpu"
     if return_lse:
         return _flash_lse(q, k, v, causal, block_q, block_k, interpret)
+    if window is not None:
+        return _flash_window(q, k, v, window, block_q, block_k, interpret)
     return _flash(q, k, v, causal, block_q, block_k, interpret)
+
+
+@jax.named_scope("flash_attention")
+def _flash_window_forward(q, k, v, window, block_q, block_k, interpret):
+    B, T, H, D = q.shape
+
+    def to_bh(x):
+        return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+
+    qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
+    spans = [_window_blocks(i, window, block_q, block_k) for i in range(T // block_q)]
+
+    def key_block(b, i, j):
+        first, last = _window_blocks(i, window, block_q, block_k, jnp.maximum)
+        return b, jnp.minimum(first + j, last), 0
+
+    out = pl.pallas_call(
+        functools.partial(_flash_window_kernel, scale=D**-0.5, window=window,
+                          block_q=block_q, block_k=block_k),
+        # the key axis: as many blocks as the widest query block's windows touch
+        grid=(B * H, T // block_q, max(last - first + 1 for first, last in spans)),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, D), key_block),
+            pl.BlockSpec((1, block_k, D), key_block),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+        out_shape=_out_struct((B * H, T, D), q.dtype, qb, kb, vb),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qb, kb, vb)
+    return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
 @jax.named_scope("flash_attention")

@@ -321,9 +321,28 @@ def sigmoid_topk_route(x32, w_router, bias, top_k: int, scale: float):
     return chosen.astype(jnp.int32), weights
 
 
+def softmax_topk_route(x32, w_router, bias, top_k: int, scale: float):
+    """The router of the qwen2_moe / qwen3_moe lineage: a softmax over the
+    router's whole width in float32 at the highest matmul precision, then the
+    ``top_k`` largest (of ``probability + bias``: a zero bias leaves the
+    choice to the probabilities), weighed by their probabilities WITHOUT the
+    bias, renormalised over the chosen to sum to 1, times ``scale``.
+    :func:`sigmoid_topk_route`'s signature and return."""
+    s = jax.nn.softmax(jnp.dot(
+        x32.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    return chosen.astype(jnp.int32), weights
+
+
 def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
-                 layer=None, held_from=None, interpret=None):
+                 layer=None, held_from=None, interpret=None,
+                 route=sigmoid_topk_route):
     """One expert layer over tokens x32 [T, D] (float32, already normed).
+    ``route`` scores and chooses: :func:`sigmoid_topk_route` or
+    :func:`softmax_topk_route`.
 
     ``p``: ``router`` [D, E] and ``router_bias`` [E] (float32), ``experts_gu``
     [E, D, 2F], ``experts_down`` [E, F, D] (or both stacked over layers, with
@@ -348,8 +367,7 @@ def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
     T, D = x32.shape
     E = p["experts_gu"].shape[-3]  # the groups: the experts whose matrices are here
     dtype = p["experts_gu"].dtype
-    experts, weights = sigmoid_topk_route(
-        x32, p["router"], p["router_bias"], top_k, scale)
+    experts, weights = route(x32, p["router"], p["router_bias"], top_k, scale)
     flat = experts.reshape(-1)  # pair j belongs to token j // top_k
     if held_from is not None:
         local = flat - held_from

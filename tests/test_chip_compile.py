@@ -695,3 +695,84 @@ def test_retention_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 4.95e9 + (1 << 30) < 15.5e9
     assert len(re.findall(r"%retention_prefill[.\d]* = ", text)) == 1
     assert len(re.findall(r"%retention_prefill_state[.\d]* = ", text)) == 1
+
+
+# ---------------------------------------------------------------------------
+# laguna-s-2.1 in the engine (PR 48): the decode step of 64 slots and the
+# largest prefill of laguna_serve_mixedlen, at the published widths
+# ---------------------------------------------------------------------------
+def _sliding(monkeypatch):
+    import json
+    import os
+
+    from moolib_tpu.models.swa_moe import SlidingGqaMoELM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "laguna-s-2.1.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "chipbench", "traffic", "serve_mixedlen.json")) as f:
+        traffic = json.load(f)
+    model = SlidingGqaMoELM.from_config(
+        config, max_len=traffic["positions_per_slot"], **config["uses"]["serve"])
+    return model, jax.eval_shape(model.init, jax.random.key(0)), traffic
+
+
+def test_sliding_decode_step_holds_its_rings_as_aliased_leaves_read_in_place(chip, monkeypatch):
+    """6.41 GB of weights, 4.83 GB of the full layers' pools and 0.81 GB of
+    the sliding layers' rings: the step aliases all 5.64 GB of cache to its
+    outputs and copies no leaf of it: the rings go through the scan over a
+    period's three sliding layers as carries, take their row by a scatter in
+    place, and are read by the paged kernel whole (the reshape to blocks of
+    128 rows is a bitcast, the layer is the block table's); no period's
+    weights are sliced out of a stack and no weight is transposed."""
+    from moolib_tpu.models.decoder_parts import SlotCache
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    model, params, traffic = _sliding(monkeypatch)
+    S, bs = traffic["slots"], traffic["block_size"]
+    per = traffic["positions_per_slot"] // bs
+    cache = SlotCache(model.cache_spec(1 + S * per, bs), model.state_spec(S))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    paged = PagedState(i32(S, per), i32(S), jax.ShapeDtypeStruct((S,), jnp.bool_))
+    compiled, text = _compile(
+        jax.jit(model.decode, donate_argnums=(1,)), *_on(chip, (params, cache, i32(S), paged)))
+    nbytes = lambda tree: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    assert 6.40e9 < nbytes(params) < 6.42e9
+    assert nbytes(cache.blocks) == 4833411072 and nbytes(cache.slots) == 805306368
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(cache)  # every cache leaf is updated where it lies
+    assert mem.temp_size_in_bytes < 200 << 20
+    # the ring's calls (72 heads in 80 sublane rows), one under each period's
+    # scan, and the three full layers' (48)
+    assert len(re.findall(r"%paged_attention[.\d]* = f32\[64,80,128\]", text)) == 2
+    assert len(re.findall(r"%paged_attention[.\d]* = f32\[64,48,128\]", text)) == 3
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = ", text)) == 8  # 2 x (scan body + full)
+    copies = re.findall(r"= (\w+\[[\d,]*\])[^ ]* copy\(", text)
+    ring, blocks, pool = "bf16[64,6,512,8,128]", "bf16[1536,128,8,128]", "bf16[3073,128,8,128]"
+    assert not {ring, blocks, pool} & set(copies)
+    sizes = lambda found: [int(np.prod([int(d) for d in s.split("[")[1][:-1].split(",") if d]))
+                           for s in found]
+    assert max(sizes(copies)) <= 3072 * 256 * 3  # nothing of a weight's size
+    converts = re.findall(r"= (\w+\[[\d,]*\])[^ ]* convert\(", text)
+    assert max(sizes(converts), default=0) < 12544 * 3072
+
+
+def test_sliding_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
+    """A prompt of 4,096 positions: the windowed flash kernel over 72 heads
+    under each period's scan (one result: it has no logsumexp), the causal one
+    over 48 in the three full layers, 40,960 (token, expert) rows through the
+    grouped matmul.  Weights, temporaries and the engine's 5.64 GB of cache
+    stay under the chip's 16 GB."""
+    model, params, traffic = _sliding(monkeypatch)
+    Lb = traffic["prompt_tokens"]["max"]
+    compiled, text = _compile(
+        jax.jit(lambda p, toks, tp: model.prefill(p, toks, tp, traffic["block_size"])),
+        *_on(chip, (params, jax.ShapeDtypeStruct((1, Lb), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 5.64e9 < 15.5e9
+    assert len(re.findall(r"%flash_attention[\w.]* = bf16\[72,4096,128\]", text)) == 2
+    assert len(re.findall(r"%flash_attention[\w.]* = \(bf16\[48,4096,128\]", text)) == 3
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[40960,", text)) == 8

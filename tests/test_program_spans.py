@@ -285,6 +285,29 @@ def one_state_spell():
             "spans": telemetry.get_tracer().spans()}
 
 
+@pytest.fixture(scope="module")
+def one_ring_spell():
+    """The tiny sliding-window decoder (``models/swa_moe.py``: rings a slot
+    beside paged pools) through the engine: one request to its budget; the
+    registry before and after.  Its ring counters exist for no other model."""
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.engine import ContinuousBatchingEngine
+    from moolib_tpu.models.swa_moe import SlidingGqaMoELM, tiny_config
+
+    model = SlidingGqaMoELM.from_config(tiny_config(), dtype=jnp.float32, max_len=128)
+    params = jax.jit(model.init)(jax.random.key(0))
+    engine = ContinuousBatchingEngine(model, params, slots=2, block_size=16,
+                                      max_seq_len=128, max_prompt_len=64)
+    before = telemetry.get_registry().snapshot()
+    slot, _ = engine.submit(np.arange(2, 40, dtype=np.int32), 3)
+    while not engine.step()[1]:
+        pass
+    engine.retire(slot)
+    return {"registry": (before, telemetry.get_registry().snapshot())}
+
+
 def _parent(span, spans):
     """The innermost span of the same thread that contains this one."""
     around = [p for p in spans if p is not span and p.tid == span.tid
@@ -575,11 +598,12 @@ def test_every_span_the_benchmark_selects_is_recorded(
 
 @pytest.mark.parametrize("metric", sorted(_REGISTRY_METRICS))
 def test_every_registry_series_the_benchmark_reads_was_observed(
-        metric, one_busy_spell, one_expert_spell, one_state_spell):
+        metric, one_busy_spell, one_expert_spell, one_state_spell, one_ring_spell):
     """Through the benchmark's own readers, the way its serving runner feeds
     them: the registry before and after the window, and the gauges sampled
-    from a snapshot inside it.  A series that only a model with experts, or
-    one with a state a slot, observes is looked for in that model's spell."""
+    from a snapshot inside it.  A series that only a model with experts, one
+    with a state a slot, or one with rings observes is looked for in that
+    model's spell."""
     from chipbench.readers import gauge_mean, histogram_mean, registry_delta
 
     spec = _REGISTRY_METRICS[metric]
@@ -587,7 +611,7 @@ def test_every_registry_series_the_benchmark_reads_was_observed(
     if spec["reader"] in ("histogram_mean", "registry_delta"):
         reader = histogram_mean if spec["reader"] == "histogram_mean" else registry_delta
         for before, after in (one_busy_spell["registry"], one_expert_spell["registry"],
-                              one_state_spell["registry"]):
+                              one_state_spell["registry"], one_ring_spell["registry"]):
             measured = types.SimpleNamespace(counters_before=before, counters_after=after)
             value = reader.read(spec, {"measured": measured})
             if value is not None:

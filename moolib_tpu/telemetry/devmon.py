@@ -37,6 +37,7 @@ deferred into the functions that need them.
 
 from __future__ import annotations
 
+import math
 import os
 import re
 import sys
@@ -441,10 +442,38 @@ def sample_memory() -> Dict[str, Dict[str, float]]:
 # --------------------------------------------------------------- step cost/MFU
 # Opcode position only (``... all-reduce(`` / ``all-reduce-start(``): names
 # and ``-done`` halves of async pairs must not count twice.
-_COLLECTIVE_RE = re.compile(
-    r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
-    r"(?:-start)?\("
-)
+_COLLECTIVES = "all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter"
+_COLLECTIVE_RE = re.compile(rf" ({_COLLECTIVES})(?:-start)?\(")
+_SYNC_COLLECTIVE_RE = re.compile(rf" = (.*?) (?:{_COLLECTIVES})\(")  # its result's type
+_ARRAY_RE = re.compile(r"\b[a-z]+(\d+)\[([\d,]*)\]")  # bits and dims of f32[512,2048]
+
+
+def sync_collectives(text: str) -> Tuple[int, int]:
+    """``(count, bytes)`` of a compiled module's collectives that hold the
+    device's operation line from start to end: every collective instruction
+    that is neither the ``-start`` / ``-done`` half of a pair nor inside a
+    computation an overlapping fusion calls (``calls=%async_collective_fusion.N``,
+    or an ``%async-collective-start`` / ``-done`` fusion's).  Bytes are those
+    of the collective's own result: an all-reduce and a slice count the
+    all-reduce."""
+    lines = text.splitlines()
+    overlapped = set()
+    for line in lines:
+        if "calls=%async_collective_fusion" in line or line.lstrip().startswith(
+                ("%async-collective-", "ROOT %async-collective-")):
+            overlapped.update(re.findall(r"calls=%([\w.\-]+)", line))
+    count = nbytes = 0
+    inside = None
+    for line in lines:
+        if line.startswith(("%", "ENTRY ")):
+            inside = line.removeprefix("ENTRY ").lstrip("%").split(" ")[0]
+        m = None if inside in overlapped else _SYNC_COLLECTIVE_RE.search(line)
+        if m is not None:
+            count += 1
+            nbytes += sum(
+                math.prod(int(d) for d in dims.split(",") if d) * ((int(bits) + 7) // 8)
+                for bits, dims in _ARRAY_RE.findall(m.group(1)))
+    return count, nbytes
 
 
 class StepCost:
@@ -453,21 +482,24 @@ class StepCost:
     aliased bytes, from ``memory_analysis()``), the aliased bytes themselves
     (outputs that took a donated argument's memory; 0 where a donation could
     not be used), the number of Mosaic kernel call sites
-    (``tpu_custom_call``) and the collectives by kind."""
+    (``tpu_custom_call``), the collectives by kind, and how many of them
+    hold the operation line with how many bytes (:func:`sync_collectives`)."""
 
     __slots__ = ("flops", "bytes_accessed", "memory_bytes", "donated_bytes",
-                 "kernels", "collectives")
+                 "kernels", "collectives", "sync_collectives")
 
     def __init__(self, flops: float, bytes_accessed: float,
                  memory_bytes: Optional[int] = None, kernels: int = 0,
                  collectives: Optional[Dict[str, int]] = None,
-                 donated_bytes: Optional[int] = None):
+                 donated_bytes: Optional[int] = None,
+                 sync_collectives: Tuple[int, int] = (0, 0)):
         self.flops = float(flops)
         self.bytes_accessed = float(bytes_accessed)
         self.memory_bytes = memory_bytes
         self.donated_bytes = donated_bytes
         self.kernels = kernels
         self.collectives = collectives or {}
+        self.sync_collectives = sync_collectives
 
     @property
     def arithmetic_intensity(self) -> Optional[float]:
@@ -476,7 +508,9 @@ class StepCost:
     def program(self) -> Dict[str, Any]:
         """The compiled program's facts as a JSON-ready dict."""
         return {"memory_bytes": self.memory_bytes, "mosaic_kernels": self.kernels,
-                "collectives": dict(self.collectives)}
+                "collectives": dict(self.collectives),
+                "sync_collectives": self.sync_collectives[0],
+                "sync_collective_bytes": self.sync_collectives[1]}
 
     def __repr__(self):
         return f"StepCost(flops={self.flops:.3g}, bytes_accessed={self.bytes_accessed:.3g})"
@@ -512,6 +546,7 @@ def step_cost(name: str, jitted, *args, **kwargs) -> Optional["StepCost"]:
             kernels=text.count("tpu_custom_call"),
             collectives=dict(Counter(_COLLECTIVE_RE.findall(text))),
             donated_bytes=None if mem is None else int(mem.alias_size_in_bytes),
+            sync_collectives=sync_collectives(text),
         )
     with _lock:
         _COST_CACHE[sig] = cost

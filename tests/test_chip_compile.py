@@ -380,29 +380,66 @@ def _arrays(type_text):
             for dt, dims in re.findall(r"\b([a-z]+\d+|pred)\[([\d,]*)\]", type_text)]
 
 
-# layers: the state one chip holds (GB) and the temporaries of the step that
-# kept the whole state on every chip (GB; 4 layers: the cell, 9.77 GB a chip
-# where this step counts 7.17).  The cell's depth compiles for half a minute.
-@pytest.mark.parametrize("layers,state_gb,whole_temp_gb", [
-    (1, 0.79, 3.706), pytest.param(4, 1.24, 4.830, marks=pytest.mark.slow)])
-def test_lm_train_step_over_dp4_updates_a_quarter_of_the_state(
-        topo, monkeypatch, layers, state_gb, whole_temp_gb):
-    """``lm_train_dp4``'s step (``--mesh dp=4``, B=16): a chip holds and
-    updates a quarter of every large leaf, every gradient matrix is
-    reduce-scattered (on this compiler a fusion ``all-reduce-scatter`` around
-    an all-reduce and a slice) in the dtype the whole-state step all-reduced
-    it in, the weights are gathered and the activations stay where they are."""
-    import re
+_DP4_STEPS = {}  # layers: a test's compile of half a minute, shared with the next
 
-    import numpy as np
+
+def _dp4_step(topo, monkeypatch, layers):
+    """``lm_train_dp4``'s step (``--mesh dp=4``, B=16) compiled for the
+    described chips as ``lm.jit_step`` jits it: the compiled step, its text
+    and the bytes of state a chip holds."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    if layers in _DP4_STEPS:
+        return _DP4_STEPS[layers]
     mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
     jstep, (params, opt_state), state_bytes = _lm_train_step(
         monkeypatch, None, 16, layers, mesh)
     tokens = jax.ShapeDtypeStruct(
         (16, 2048), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
-    compiled, text = _compile(jstep, params, opt_state, tokens)
+    _DP4_STEPS[layers] = _compile(jstep, params, opt_state, tokens) + (state_bytes,)
+    return _DP4_STEPS[layers]
+
+
+def _crossings(text):
+    """``channel: (kind, computation, [(dtype, dims)])`` of every all-reduce
+    and reduce-scatter of a matrix of 2^16 elements or more, the matrix as it
+    is before the reduction (a reduce-scatter's result times the four chips
+    on its cut axis).  An overlapped reduce-scatter stands in its start's, its
+    fusion's and its end's computations under one channel: counted once."""
+    crossed = {}
+    for where, line in _computations(text):
+        op = re.search(
+            r" = (.*?) (all-reduce|reduce-scatter|all-gather|all-to-all)(?:-start)?\(", line)
+        if op is None:
+            continue
+        arrays = _arrays(op.group(1))
+        # The failure of shardings without constraints: activations and logits
+        # ([16, 2048, ...]) crossing the chips in place of the weights.
+        assert not any(dims[:2] == (16, 2048) for _, dims in arrays), line[:200]
+        if op.group(2) == "reduce-scatter":
+            cut = int(re.search(r"dimensions=\{(\d+)\}", line).group(1))
+            arrays = [(dt, dims[:cut] + (4 * dims[cut],) + dims[cut + 1:]) for dt, dims in arrays]
+        matrices = [a for a in arrays if len(a[1]) >= 2 and np.prod(a[1]) >= 2 ** 16]
+        if op.group(2) in ("all-reduce", "reduce-scatter") and matrices:
+            channel = re.search(r"channel_id=(\d+)", line).group(1)
+            crossed.setdefault(channel, (op.group(2), where, matrices))
+    return crossed
+
+
+# layers: the state one chip holds (GB) and the temporaries of the step that
+# kept the whole state on every chip (GB; 4 layers: the cell, 9.77 GB a chip
+# where this step counts 7.13).  The cell's depth compiles for a minute.
+@pytest.mark.parametrize("layers,state_gb,whole_temp_gb", [
+    (1, 0.79, 3.706), pytest.param(4, 1.24, 4.830, marks=pytest.mark.slow)])
+def test_lm_train_step_over_dp4_updates_a_quarter_of_the_state(
+        topo, monkeypatch, layers, state_gb, whole_temp_gb):
+    """``lm_train_dp4``'s step: a chip holds and updates a quarter of every
+    large leaf, every gradient matrix is reduce-scattered once, in the dtype
+    the whole-state step all-reduced it in (on this compiler either a
+    ``reduce-scatter`` inside an overlapping fusion or, on the operation line,
+    a fusion ``all-reduce-scatter`` around an all-reduce and a slice), the
+    weights are gathered and the activations stay where they are."""
+    compiled, text, state_bytes = _dp4_step(topo, monkeypatch, layers)
     assert text.count("tpu_custom_call") >= 3 * layers  # the flash kernels, under shard_map
     mem = compiled.memory_analysis()
     assert 0.98 * state_gb * 1e9 < state_bytes < 1.02 * state_gb * 1e9
@@ -413,30 +450,90 @@ def test_lm_train_step_over_dp4_updates_a_quarter_of_the_state(
           f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
     assert mem.temp_size_in_bytes < 1.3 * whole_temp_gb * 1e9
 
-    scattered = []
-    for where, line in _computations(text):
-        op = re.search(r" = (.*?) (all-reduce|all-gather|all-to-all)(?:-start)?\(", line)
-        if op is None:
-            continue
-        arrays = _arrays(op.group(1))
-        # The failure of shardings without constraints: activations and logits
-        # ([16, 2048, ...]) crossing the chips in place of the weights.
-        assert not any(dims[:2] == (16, 2048) for _, dims in arrays), line[:200]
-        matrices = [a for a in arrays if len(a[1]) >= 2 and np.prod(a[1]) >= 2 ** 16]
-        if op.group(2) == "all-reduce" and matrices:
-            assert where.startswith("all-reduce-scatter"), line[:200]
-            scattered += matrices
+    crossed = _crossings(text)
+    for kind, where, _ in crossed.values():
+        if kind == "all-reduce":  # never a whole matrix all-reduced and kept whole
+            assert where.startswith("all-reduce-scatter"), (kind, where)
+    scattered = [m for _, _, matrices in crossed.values() for m in matrices]
     # The head's gradient crosses in float32, the blocks' in bfloat16, as the
     # whole-state step's all-reduces carried them (chip pads [8192,2048] by 128 rows).
     assert {dt for dt, _ in scattered} == {"f32", "bf16"}
     assert [dims for dt, dims in scattered if dt == "f32"] == [(2048, 50257)]
-    assert sum(dims == (2048, 6144) for _, dims in scattered) == layers  # qkv, once a block
+    # once a block: qkv, proj, the FFN's two; once: the positions and the head
+    assert len(scattered) == 4 * layers + 2, scattered
+    for dims in [(2048, 6144), (2048, 8192), (8320, 2048)]:
+        assert scattered.count(("bf16", dims)) == layers, (dims, scattered)
     assert re.search(r"\(input[.\d]*: f32\[2048,50257\]\) -> f32\[512,50257\]", text)
     # AdamW writes quarter leaves, never a whole head or table.
     assert "f32[512,50257]" in text and "f32[50257,512]" in text
     for line in text.splitlines():
         if "/optimizer/" in line and " = " in line:
             assert "f32[2048,50257]" not in line.split(" = ")[1].split("(")[0], line[:200]
+
+
+def test_lm_train_step_over_dp4_reduces_the_blocks_gradients_beside_matmuls(topo, monkeypatch):
+    """The ENTRY schedule of the one-layer step: each of the block's large
+    gradients is reduce-scattered by a start / end pair with a matmul fusion
+    between them that carries the reduction (``calls=%async_collective_fusion``
+    around a ``convolution`` and the ``reduce-scatter``), so the operation
+    line computes while the gradient crosses.  What this compiler still
+    leaves on the line as ``calls=%all-reduce-scatter`` is pinned too: the
+    head's float32 gradient, whose matmul the scheduler puts last of all, and
+    one [2048,2048] (PERF.md section 7)."""
+    _, text, _ = _dp4_step(topo, monkeypatch, 1)
+    bodies = {}
+    for where, line in _computations(text):
+        bodies.setdefault(where, []).append(line)
+    entry = bodies[re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)]
+
+    def reduces(computation):
+        """What ``computation`` reduce-scatters: the result's ``(dtype, dims)``."""
+        return [_arrays(m.group(1))[0] for line in bodies.get(computation, [])
+                if (m := re.search(r" = (.*?) reduce-scatter\(", line))]
+
+    overlapped, open_pairs, on_the_line = [], {}, []
+    for line in entry:
+        name = line.split(" = ")[0].strip().removeprefix("ROOT ").lstrip("%")
+        called = (re.findall(r"calls=%([\w.\-]+)", line) or [""])[0]
+        if name.startswith("async-collective-start") and reduces(called):
+            open_pairs[tuple(reduces(called))] = False
+        elif called.startswith("async_collective_fusion") and tuple(reduces(called)) in open_pairs:
+            assert any(" convolution(" in body for body in bodies[called]), line[:200]
+            open_pairs[tuple(reduces(called))] = True
+        elif name.startswith("async-collective-done") and tuple(reduces(called)) in open_pairs:
+            assert open_pairs.pop(tuple(reduces(called))), f"nothing computes beside {name}"
+            overlapped += reduces(called)
+        elif called.startswith("all-reduce-scatter"):
+            on_the_line += [a for body in bodies[called] if " all-reduce(" in body
+                            for a in _arrays(body.split(" = ")[1].split(" all-reduce(")[0])]
+    assert not open_pairs
+    # qkv, the FFN's two (the chip pads [8192,2048] by 128 rows) and the positions, a quarter each
+    assert sorted(overlapped) == sorted([
+        ("bf16", (2048, 1536)), ("bf16", (2048, 2048)), ("bf16", (2080, 2048)),
+        ("bf16", (1, 512, 2048))]), overlapped
+    assert sorted(on_the_line) == [("bf16", (2048, 2048)), ("f32", (2048, 50257))], on_the_line
+
+
+def test_lm_step_is_compiled_with_options_only_over_dp_on_tpus(topo, monkeypatch):
+    """``jit_step`` hands the compiler ``_DP_COMPILER_OPTIONS`` for a mesh of
+    TPUs whose ``dp`` is over 1 and nothing anywhere else: not for one chip
+    (``lm_train_t2048``'s step is the program it was), not for a mesh of CPU
+    devices (that compiler knows no ``xla_tpu_*`` option), not where ``dp`` is 1."""
+    from jax.sharding import Mesh
+
+    from moolib_tpu.examples import lm
+
+    seen = []
+    jit = jax.jit
+    monkeypatch.setattr(jax, "jit", lambda fn, **kw: (seen.append(kw), jit(fn, **kw))[1])
+    tpus, cpus = np.array(topo.devices), np.array(jax.devices("cpu")[:4])
+    for mesh in (None, Mesh(tpus.reshape(4), ("dp",)), Mesh(cpus.reshape(4), ("dp",)),
+                 Mesh(tpus.reshape(1, 4), ("dp", "sp")), Mesh(tpus.reshape(2, 2), ("dp", "sp"))):
+        _lm_train_step(monkeypatch, None if mesh is not None else SingleDeviceSharding(tpus[0]),
+                       16, 1, mesh)
+    options = [kw.get("compiler_options") for kw in seen if "donate_argnums" in kw]
+    assert options == [None, lm._DP_COMPILER_OPTIONS, None, None, lm._DP_COMPILER_OPTIONS]
+    assert set(seen[0]) == {"donate_argnums"}  # the one-chip step: jax.jit(step, donate_argnums=(0, 1))
 
 
 @pytest.mark.parametrize("layers", [1, 2])

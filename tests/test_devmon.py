@@ -160,6 +160,72 @@ def test_step_cost_counts_flops_for_lm_like_step():
     )
 
 
+_SCHEDULE = """\
+%fused_computation.1 (p: bf16[2048,2048]) -> bf16[2048,8192] {
+  %ag = bf16[2048,8192]{1,0} all-gather(%p), channel_id=1, dimensions={1}
+}
+
+%async_collective_fusion.7 (p0: bf16[8320,2048], p1: bf16[4,2048,2048]) -> (f32[2048,50257], bf16[2080,2048]) {
+  %convolution.1 = f32[2048,50257]{0,1} convolution(%p1, %p1), window={size=4}
+  %reduce-scatter.3 = bf16[2080,2048]{1,0:T(8,128)(2,1)S(1)} reduce-scatter(%p0), channel_id=2, dimensions={0}
+}
+
+%fused_computation.2 (p: bf16[8320,2048]) -> bf16[2080,2048] {
+  %reduce-scatter.4 = bf16[2080,2048]{1,0} reduce-scatter(%p), channel_id=2, dimensions={0}
+}
+
+%all-reduce-scatter.4.clone (input: f32[2048,50257]) -> f32[512,50257] {
+  %all-reduce.9 = f32[2048,50257]{0,1:T(8,128)} all-reduce(%input), channel_id=3, to_apply=%add
+  ROOT %dynamic-slice.1 = f32[512,50257]{0,1} dynamic-slice(%all-reduce.9, %i, %z)
+}
+
+ENTRY %main (a: bf16[512,2048]) -> f32[512,50257] {
+  %all-gather.68 = bf16[50257,2048]{1,0:T(8,128)(2,1)} all-gather(%convert.7), channel_id=4, dimensions={1}
+  %async-collective-start = (bf16[2048,2048], bf16[2048,8192]) fusion(%a), kind=kCustom, calls=%fused_computation.1
+  %fusion.7 = (f32[2048,50257], bf16[2080,2048]) fusion(%b, %c), kind=kOutput, calls=%async_collective_fusion.7
+  %async-collective-done.3 = bf16[2080,2048]{1,0} fusion(%fusion.7), kind=kCustom, calls=%fused_computation.2
+  %collective-permute-start = (bf16[96,2048], bf16[96,2048]) collective-permute-start(%d), channel_id=5
+  %collective-permute-done = bf16[96,2048] collective-permute-done(%collective-permute-start)
+  %all-reduce.30 = (f32[2048]{0}, f32[2048]{0}) all-reduce(%e, %f), channel_id=6, to_apply=%add
+  ROOT %fusion.10 = f32[512,50257]{0,1} fusion(%fusion.7), kind=kCustom, calls=%all-reduce-scatter.4.clone
+}
+"""
+
+
+def test_sync_collectives_counts_what_no_fusion_overlaps():
+    """Of a TPU step's collectives, the ones on the operation line: the
+    table's gather, the head's all-reduce inside its ``all-reduce-scatter``
+    fusion and a pair of vectors' all-reduce, with their own results' bytes.
+    Not the gather an ``async-collective-start`` opens, not the
+    reduce-scatter a matmul fusion carries and its end closes, not a
+    ``-start`` / ``-done`` pair."""
+    count, nbytes = devmon.sync_collectives(_SCHEDULE)
+    assert count == 3
+    assert nbytes == 50257 * 2048 * 2 + 2048 * 50257 * 4 + 2 * 2048 * 4
+    assert devmon.sync_collectives("ENTRY %main () -> f32[] {\n  %c = f32[] constant(0)\n}") == (0, 0)
+
+
+def test_step_cost_reports_the_sync_collectives_of_a_sharded_step():
+    """On this backend every collective of a partitioned step is one: the
+    sum over four devices shows in ``StepCost.sync_collectives`` and in
+    ``program()``, which ``lm.train`` logs and returns."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    x = jax.device_put(jnp.ones((8, 64), jnp.float32), NamedSharding(mesh, P("dp", None)))
+    j = jax.jit(lambda x: (x @ x.T).sum(0), out_shardings=NamedSharding(mesh, P()))
+    sc = devmon.step_cost("t.sharded", j, x)
+    if sc is None:
+        pytest.skip("cost analysis unavailable on this backend")
+    held, nbytes = sc.sync_collectives
+    assert held == sum(sc.collectives.values()) >= 1 and nbytes > 0
+    assert sc.program()["sync_collectives"] == held
+    assert sc.program()["sync_collective_bytes"] == nbytes
+
+
 def test_publish_step_finite_mfu_and_roofline():
     cost = devmon.StepCost(flops=1e9, bytes_accessed=1e8)
     out = devmon.publish_step("t.pub", cost, step_seconds=0.01,

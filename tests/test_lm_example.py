@@ -413,6 +413,58 @@ def test_lm_train_reports_the_losses_of_the_replicated_undonated_step(monkeypatc
         assert out["losses"] == want
 
 
+def test_lm_dp4_step_gives_the_one_device_steps_loss_and_parameters():
+    """Two steps of the ``dp=4`` step on four CPU devices, as ``jit_step``
+    jits it there (with no compile option: this compiler knows none of the
+    TPU's), against two of the one-device step from the same state and
+    batches: the losses to the tolerance of the mesh tests above, and the
+    matrices (what the step reduce-scatters) to a thousandth of the learning
+    rate but for a few weights in ten thousand.  AdamW's first steps move a
+    weight by about the learning rate whatever its gradient's size: where a
+    gradient is rounding away from nothing (the keys' bias, which softmax
+    cannot see, is all such), a sum added in another order moves the weight
+    the other way, by at most the two steps' learning rates."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from moolib_tpu import parallel
+    from moolib_tpu.examples import lm
+
+    got = {}
+    for path, mesh_spec in (("plain", ""), ("dp4", "dp=4")):
+        flags = make_flags(_SMALL + ["--mesh", mesh_spec, "--attention", "dense"])
+        mesh = parallel.parse_mesh_spec(flags.mesh)
+        # float32 compute: in bfloat16 this backend's matmuls round by the
+        # rows a device holds, 3e-4 of the loss between 2 and 8 sequences
+        model = lm.make_model(flags).clone(dtype=jnp.float32)
+        opt = optax.adamw(flags.learning_rate)
+        rng = np.random.default_rng(flags.seed)
+        batches = [jnp.asarray(lm.make_batch(rng, flags)) for _ in range(2)]
+        params = model.init(
+            jax.random.key(flags.seed), batches[0], **lm._apply_kwargs(flags, mesh))
+        opt_state = opt.init(params)
+        if mesh is not None:
+            params, opt_state = jax.device_put(
+                (params, opt_state), lm.state_shardings(params, opt_state, flags, mesh))
+        _, step = lm.make_step(flags, model, opt, mesh)
+        jstep, put = lm.jit_step(step, params, opt_state, flags, mesh)
+        losses = []
+        for tokens in batches:
+            params, opt_state, loss, _ = jstep(params, opt_state, put(tokens))
+            losses.append(float(loss))
+        got[path] = losses, jax.device_get(params), flags.learning_rate
+    (want_losses, want, lr), (losses, params, _) = got["plain"], got["dp4"]
+    np.testing.assert_allclose(losses, want_losses, rtol=2e-6)
+    assert losses[1] != losses[0]
+    for a, b in zip(jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(want)):
+        off = np.abs(np.asarray(a) - np.asarray(b))
+        assert off.max() <= 2 * lr, (off.max(), a.shape)
+        if a.ndim >= 2:
+            assert (off > 1e-3 * lr).mean() < 3e-4, ((off > 1e-3 * lr).mean(), a.shape)
+
+
 def test_lm_state_is_cut_on_d_model_where_dp_cannot_divide_the_vocabulary():
     """A vocabulary no power of two divides (101) leaves table and head
     their other axis: both are cut on ``d_model``, moments with them; the

@@ -239,6 +239,19 @@ def make_model(flags) -> TransformerLM:
     )
 
 
+def _flash_traces(since=None) -> dict:
+    """``flash_attention_traces_total`` by path: how the flash kernels address
+    their operands (in_place, or head_major copies for a head size off the
+    128 lanes), counted as a program is traced.  The paths traced since the
+    reading ``since``."""
+    prefix = 'flash_attention_traces_total{path="'
+    now = {name[len(prefix):-2]: int(n)
+           for name, n in telemetry.get_registry().counter_values().items()
+           if name.startswith(prefix)}
+    return {p: n - (since or {}).get(p, 0) for p, n in sorted(now.items())
+            if n > (since or {}).get(p, 0)}
+
+
 def _apply_kwargs(flags, mesh) -> dict:
     # ring rotates K/V over the mesh's sp axis; flash needs the mesh to wrap
     # its kernel in shard_map (XLA cannot partition a Mosaic call).  The
@@ -399,6 +412,7 @@ def train(flags, on_stats=None) -> dict:
     _faults.install_from_env()  # opt-in chaos (MOOLIB_FAULTS; no-op unset)
     if flags.seq_len % 2:
         raise ValueError("--seq_len must be even")
+    flash_traces0 = _flash_traces()
     elastic = bool(
         flags.address or flags.connect or getattr(flags, "broker_addrs", None)
     )
@@ -595,9 +609,12 @@ def train(flags, on_stats=None) -> dict:
                         if mfu_info is not None
                         else ""
                     )
+                    flash_s = ",".join(
+                        f"{p}:{n}" for p, n in _flash_traces(flash_traces0).items())
                     print(
                         f"step={steps_done} loss={loss_v:.4f} "
-                        f"acc={acc_v:.3f}{mfu_s}{state_s}",
+                        f"acc={acc_v:.3f}{mfu_s}{state_s}"
+                        + (f" flash={flash_s}" if flash_s else ""),
                         flush=True,
                     )
                 if on_stats is not None:
@@ -645,6 +662,7 @@ def train(flags, on_stats=None) -> dict:
         "flash_dense_reroutes": telemetry.get_registry().counter_values().get(
             "flash_dense_reroutes_total", 0.0
         ),
+        "flash_traces": _flash_traces(flash_traces0),
         "param_placement": common.placement_of(params),
         "batch_placement": batch_placement,
     }

@@ -118,8 +118,9 @@ class Block(nn.Module):
             raise ValueError(f"num_heads={H} must be divisible by num_kv_heads={Hk}")
         group = H // Hk
         y = nn.LayerNorm(dtype=jnp.float32)(x)
-        qkv = nn.Dense((H + 2 * Hk) * hd, dtype=self.dtype, name="qkv")(y)
-        qkv = qkv.reshape(B, T, H + 2 * Hk, hd)
+        # [B, T, (H + 2 Hk) * hd], columns [q heads | k heads | v heads]
+        packed = nn.Dense((H + 2 * Hk) * hd, dtype=self.dtype, name="qkv")(y)
+        qkv = packed.reshape(B, T, H + 2 * Hk, hd)
         q, k, v = qkv[:, :, :H], qkv[:, :, H : H + Hk], qkv[:, :, H + Hk :]
 
         if self.decode:
@@ -196,26 +197,37 @@ class Block(nn.Module):
                 # (unrepeated — the cache stays Hk heads).
                 self.sow("kv", "k", k.astype(self.dtype))
                 self.sow("kv", "v", v.astype(self.dtype))
-            if group > 1:
-                # Training/prefill path: the attention kernels take equal
-                # head counts — repeat KV across each group (transient; the
-                # cache and the params stay at Hk heads).
-                k = jnp.repeat(k, group, axis=2)
-                v = jnp.repeat(v, group, axis=2)
-            if self.attention == "ring":
-                from ..parallel.ring_attention import ring_attention
+            if self.attention == "flash":
+                # The kernels read a shared K/V head where it lies; without
+                # rotary they index the projection itself, and q, k, v above
+                # are never cut out of it.
+                from ..ops.flash_attention import (
+                    flash_attention,
+                    flash_attention_packed,
+                )
 
-                if mesh is None:
-                    raise ValueError("attention='ring' needs mesh= at apply time")
-                att = ring_attention(q, k, v, mesh, axis_name="sp", causal=True)
-            elif self.attention == "flash":
-                from ..ops.flash_attention import flash_attention
-
-                att = flash_attention(q, k, v, causal=True, mesh=mesh)
+                if self.rotary:
+                    att = flash_attention(q, k, v, causal=True, mesh=mesh)
+                else:
+                    att = flash_attention_packed(
+                        packed, H, Hk, causal=True, mesh=mesh)
             else:
-                from ..parallel.ring_attention import full_attention
+                if group > 1:
+                    # Ring and dense attention take equal head counts —
+                    # repeat KV across each group (transient; the cache and
+                    # the params stay at Hk heads).
+                    k = jnp.repeat(k, group, axis=2)
+                    v = jnp.repeat(v, group, axis=2)
+                if self.attention == "ring":
+                    from ..parallel.ring_attention import ring_attention
 
-                att = full_attention(q, k, v, causal=True)
+                    if mesh is None:
+                        raise ValueError("attention='ring' needs mesh= at apply time")
+                    att = ring_attention(q, k, v, mesh, axis_name="sp", causal=True)
+                else:
+                    from ..parallel.ring_attention import full_attention
+
+                    att = full_attention(q, k, v, causal=True)
         att = att.reshape(B, T, D)
         x = x + nn.Dense(D, dtype=self.dtype, name="proj")(att)
 

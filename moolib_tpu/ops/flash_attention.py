@@ -18,16 +18,35 @@ the index map starts it at that block's first, so a key block outside every
 window of the query block is never copied or multiplied.  It has no backward:
 differentiation raises.  ``window=None`` traces the program it always did.
 
-Layout [B, T, H, D]; shapes that don't tile (T without a 128-multiple
-divisor) take the XLA dense path, counted in ``flash_dense_reroutes_total``.
-The kernels lower through Mosaic on the ``tpu`` platform and run in Pallas
-interpret mode on ``cpu`` (tests); any other platform raises.
+Operands [B, T, H, D] (:func:`flash_attention`; K and V may hold fewer heads,
+each shared by a group of query heads) or one packed projection
+[B, T, (H + 2 Hk) * D], columns ``[q heads | k heads | v heads]``
+(:func:`flash_attention_packed`).  Where ``D % 128 == 0`` the three kernels
+(forward, dq, dk/dv) index those arrays where they lie (``in_place``): a
+head's [block, D] tile is a lane-aligned block of the [B, T, heads * D]
+array, cut out by the BlockSpec's index map (batch row, block, column of the
+head), and the result, dq, dk and dv are written the same way, so nothing is
+transposed to a head-major copy and back, a grouped K/V head is read by its
+group's query heads and not repeated, and the packed entry hands the kernels
+the projection's own output three times over.  Any other head size takes the
+``head_major`` form: [B * H, T, D] copies, the same kernels, other index maps
+(one lane-aligned block cannot be cut out of ``H * D`` columns there), as the
+windowed forward does at every head size.
+``flash_attention_traces_total{path}`` counts the calls traced by form.  The
+row logsumexp leaves the forward, and enters the backward with ``delta``, as
+rows: [B * H, 1, T] float32.
+
+Shapes that don't tile (T without a 128-multiple divisor) take the XLA dense
+path, counted in ``flash_dense_reroutes_total``.  The kernels lower through
+Mosaic on the ``tpu`` platform and run in Pallas interpret mode on ``cpu``
+(tests); any other platform raises.
 """
 
 from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +61,14 @@ _M_DENSE_REROUTES = telemetry.get_registry().counter(
     "flash_dense_reroutes_total",
     "flash_attention calls traced onto the O(T^2) dense path because the "
     "sequence length has no 128-multiple block divisor",
+)
+_M_TRACES = telemetry.get_registry().counter(
+    "flash_attention_traces_total",
+    "flash_attention calls traced onto the kernels, by how the head size lets "
+    "them address their operands: in_place (a multiple of 128: blocks of the "
+    "[B, T, heads * D] arrays as they lie), head_major ([B * H, T, D] copies: "
+    "any other head size, and every windowed call)",
+    labelnames=("path",),
 )
 
 
@@ -114,9 +141,11 @@ def _flash_kernel(
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
         o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
-        # Row logsumexp for the backward pass, written in the scratch's own
-        # lane-replicated (block_q, 128) layout — no in-kernel transpose.
-        lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+        # Row logsumexp for the backward pass.  The scratch holds it down the
+        # sublanes, replicated over the 128 lanes; the backward reads rows:
+        # one transpose in VMEM a query block, and block_q floats leave.
+        lse = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+        lse_ref[0] = lse.T[:1]
 
 def _window_blocks(qi, window, block_q, block_k, maximum=max):
     """(first, last) of the key blocks that query block ``qi``'s windows
@@ -243,32 +272,174 @@ def _use_oracle_bwd() -> bool:
     return os.environ.get("MOOLIB_TPU_FLASH_BWD", "pallas") == "jax"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)[0]
+class _Call(NamedTuple):
+    """What is static of one call of the causal / full kernels: it selects
+    their grids, blocks and index maps.  ``packed``: the operands are one
+    projection [B, Tq, (H + 2 Hk) * D], not (q, k, v) [B, T, heads, D], and
+    the result is [B, Tq, H * D], not [B, Tq, H, D]."""
+
+    B: int
+    Tq: int
+    Tk: int
+    H: int
+    Hk: int
+    D: int
+    packed: bool
+    causal: bool
+    block_q: int
+    block_k: int
+    interpret: bool
+    return_lse: bool
+
+    @property
+    def in_place(self) -> bool:
+        """The kernels index the operands as they lie; else head-major copies."""
+        return self.D % 128 == 0
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse_raw = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-    return out, (q, k, v, out, lse_raw)
+def _to_bh(x):
+    """[B, T, heads, D] -> [B * heads, T, D]"""
+    B, T, heads, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * heads, T, D)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, g):
-    q, k, v, out, lse = res
+def _repeat_kv(k, v, group):
+    """K and V with every head repeated for its ``group`` query heads."""
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
+def _unpack(qkv, H, Hk):
+    """(q, k, v) [B, T, heads, D] out of the packed [B, T, (H + 2 Hk) * D]."""
+    B, T, columns = qkv.shape
+    x = qkv.reshape(B, T, H + 2 * Hk, columns // (H + 2 * Hk))
+    return x[:, :, :H], x[:, :, H:H + Hk], x[:, :, H + Hk:]
+
+
+def _kernel_arrays(operands, c):
+    """The arrays the kernels index as q, k and v, and the column, in heads,
+    at which each one's heads start: the operands as they lie ([B, T, heads *
+    D]; the packed projection three times), or head-major copies."""
+    if c.packed:
+        return operands * 3, (0, c.H, c.H + c.Hk)
+    if c.in_place:
+        return tuple(x.reshape(*x.shape[:2], -1) for x in operands), (0, 0, 0)
+    return tuple(_to_bh(x) for x in operands), (0, 0, 0)
+
+
+def _of_result(x, c):
+    """An array of the result's form (the result, its cotangent) as the
+    kernels index it."""
+    return x.reshape(c.B, c.Tq, c.H * c.D) if c.in_place else _to_bh(x)
+
+
+def _of_kernel(x, heads, c):
+    """What a kernel wrote for ``heads`` heads, in the operands' form."""
+    if c.packed:
+        return x
+    if c.in_place:
+        return x.reshape(c.B, -1, heads, c.D)
+    return x.reshape(c.B, heads, -1, c.D).transpose(0, 2, 1, 3)
+
+
+def _heads_shape(c, heads, T):
+    return (c.B, T, heads * c.D) if c.in_place else (c.B * heads, T, c.D)
+
+
+def _tile(c, heads, first, block, where):
+    """BlockSpec of one head's [block, D] tile.  ``where(*grid indices)``
+    names it: (batch row, head, block along T).  In place that is block
+    ``(row, block, first + head)`` of a [B, T, columns] array, ``first`` the
+    column, in heads, of the operand's head 0; head-major, block ``(row *
+    heads + head, block, 0)`` of [B * heads, T, D]."""
+
+    def index(*ids):
+        b, h, i = where(*ids)
+        return (b, i, first + h) if c.in_place else (b * heads + h, i, 0)
+
+    return pl.BlockSpec((1, block, c.D), index)
+
+
+def _last_causal_block(causal, qi, ki, block_q, block_k):
+    """The key block that step ``ki`` of query block ``qi``'s sweep copies.
+    Causal: a key block wholly above the diagonal is not computed (the
+    kernels' ``pl.when``); the index map points at the last one that is, a
+    block already in VMEM, so nothing is copied for it either."""
+    if not causal:
+        return ki
+    return jnp.minimum(ki, jax.lax.div((qi + 1) * block_q - 1, block_k))
+
+
+def _query_sweep(c, block_q, block_k):
+    """Index maps of a grid (batch row x query head, q block, k block), the
+    forward's and the dq pass's: where a step's q tile and K/V tile are (for
+    :func:`_tile`), and the BlockSpec of the q block's row table."""
+    H, group = c.H, c.H // c.Hk
+
+    def q_at(n, i, j):
+        return jax.lax.div(n, H), jax.lax.rem(n, H), i
+
+    def kv_at(n, i, j):
+        return (jax.lax.div(n, H), jax.lax.div(jax.lax.rem(n, H), group),
+                _last_causal_block(c.causal, i, j, block_q, block_k))
+
+    return q_at, kv_at, pl.BlockSpec((1, 1, block_q), lambda n, i, j: (n, 0, i))
+
+
+def _rows(x, c):
+    """A per-row table [B, Tq, H] as the kernels read it: [B * H, 1, Tq] f32.
+    The row tables ride with a unit dimension: TPU lowering constrains the
+    last two block dims (divisible by (8, 128) or equal to the array dims), so
+    a 2-D (1, bq) block over [B * H, Tq] is illegal when B * H > 1 — the unit
+    dim must sit in the constrained sublane slot, where 1 == 1 passes."""
+    return x.astype(jnp.float32).transpose(0, 2, 1).reshape(c.B * c.H, 1, c.Tq)
+
+
+def _lse_by_position(lse, c):
+    """The kernels' row logsumexp [B * H, 1, Tq] as the public [B, Tq, H]."""
+    return lse.reshape(c.B, c.H, c.Tq).transpose(0, 2, 1)
+
+
+def _oracle(operands, c):
+    """The call in pure jax (the blockwise oracle), results in ``_flash``'s form."""
+    q, k, v = _unpack(*operands, c.H, c.Hk) if c.packed else operands
+    k, v = _repeat_kv(k, v, c.H // c.Hk)
+    res = _blockwise_attention(
+        q, k, v, c.causal, c.block_q, c.block_k, return_lse=c.return_lse)
+    out, lse = res if c.return_lse else (res, None)
+    if c.packed:
+        out = out.reshape(c.B, c.Tq, c.H * c.D)
+    return (out, lse) if c.return_lse else out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _flash(operands, c):
+    """``operands``: (q, k, v), or (qkv,) where ``c.packed``.  The result in
+    their form and, with ``c.return_lse``, the row logsumexp [B, Tq, H] as a
+    differentiable second output: ring attention combines per-chunk results
+    by logsumexp weights, so gradients flow through it (the lse cotangent
+    folds into the backward kernels' delta term — no extra kernel).  Without
+    it the training hot path never materializes a zero lse cotangent."""
+    return _flash_vjp_fwd(operands, c)[0]
+
+
+def _flash_vjp_fwd(operands, c):
+    out, lse = _flash_forward(operands, c)
+    res = (operands, out, lse)
+    return ((out, _lse_by_position(lse, c)) if c.return_lse else out), res
+
+
+def _flash_vjp_bwd(c, res, g):
+    operands, out, lse = res
     if _use_oracle_bwd():
         # Oracle path: VJP of the blockwise-jax formulation (recomputes the
         # streaming softmax in pure XLA; same FLOPs class, O(block) score
         # memory).  Kept for parity testing against the pallas kernels.
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _blockwise_attention(
-                q_, k_, v_, causal, block_q, block_k
-            ),
-            q, k, v,
-        )
+        _, vjp = jax.vjp(lambda ops: _oracle(ops, c), operands)
         return vjp(g)
-    return _flash_backward(
-        q, k, v, out, lse, g, None, causal, block_q, block_k, interpret
-    )
+    g_out, g_lse = g if c.return_lse else (g, None)
+    return (_flash_backward(operands, out, lse, g_out, g_lse, c),)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -287,45 +458,6 @@ def _flash_window_no_vjp(*_):
 
 
 _flash_window.defvjp(_flash_window_no_vjp, _flash_window_no_vjp)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
-    """Like ``_flash`` but returns (out [B,Tq,H,D], lse [B,Tq,H]) with lse a
-    differentiable output: ring attention combines per-chunk results by
-    logsumexp weights, so gradients flow through it (the lse cotangent folds
-    into the backward kernels' delta term — no extra kernel).  A separate
-    custom_vjp so the plain path never materializes/consumes a zero lse
-    cotangent on the training hot path."""
-    out, lse_raw = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-    B, Tq, H, D = q.shape
-    return out, lse_raw.reshape(B, H, Tq).transpose(0, 2, 1)
-
-
-def _flash_lse_vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse_raw = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-    B, Tq, H, D = q.shape
-    lse_pub = lse_raw.reshape(B, H, Tq).transpose(0, 2, 1)
-    return (out, lse_pub), (q, k, v, out, lse_raw)
-
-
-def _flash_lse_vjp_bwd(causal, block_q, block_k, interpret, res, g):
-    q, k, v, out, lse = res
-    g_out, g_lse = g
-    if _use_oracle_bwd():
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _blockwise_attention(
-                q_, k_, v_, causal, block_q, block_k, return_lse=True
-            ),
-            q, k, v,
-        )
-        return vjp((g_out, g_lse))
-    return _flash_backward(
-        q, k, v, out, lse, g_out, g_lse, causal, block_q, block_k, interpret
-    )
-
-
-_flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 
 
 def _flash_bwd_dq_kernel(
@@ -377,17 +509,20 @@ def _flash_bwd_dq_kernel(
 
 def _flash_bwd_dkv_kernel(
     k_ref, q_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, *, scale, causal, block_q, block_k,
+    dk_scr, dv_scr, *, scale, causal, block_q, block_k, q_blocks,
 ):
-    """dk/dv pass: one kv block per (batch*head, ki), q blocks stream innermost.
+    """dk/dv pass: one kv block per (batch*kv head, ki); the q blocks of the
+    head's group of query heads stream innermost, one head's ``q_blocks``
+    after the other's.
 
     Same transposed-scores layout as the dq pass; dk and dv accumulate in
-    f32 scratch across the q sweep.
+    f32 scratch across the sweep.
     """
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi = jax.lax.rem(step, q_blocks)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -421,25 +556,23 @@ def _flash_bwd_dkv_kernel(
     else:
         _compute()
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 @jax.named_scope("flash_attention")
-def _flash_backward(
-    q, k, v, out, lse, g, g_lse, causal, block_q, block_k, interpret
-):
+def _flash_backward(operands, out, lse, g, g_lse, c):
     """Pallas flash backward: dq pass + dk/dv pass (FlashAttention-2 style).
+    The cotangents in the operands' form: (dq, dk, dv), packed (dqkv,).
 
     ``g_lse`` is the cotangent of the lse output ([B,Tq,H] or None): since
     dL/ds_j = p_j((g·v_j) - (g·out) + g_lse), it folds into the delta row
     table as ``delta - g_lse`` — the kernels are unchanged.
     """
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    scale = D**-0.5
+    B, Tq, Tk, H, Hk, D = c.B, c.Tq, c.Tk, c.H, c.Hk, c.D
+    group = H // Hk
     # Backward blocks capped at 512x512 (env-tunable for on-chip sweeps;
     # read at TRACE time — the jit cache does not key on env vars, so a
     # sweep must re-trace per value: fresh process, cleared caches, or AOT
@@ -454,79 +587,123 @@ def _flash_backward(
     # dividing T, so 128 divides T.
     cap_q = max(128, int(os.environ.get("MOOLIB_TPU_FLASH_BWD_BLOCK_Q", 512)))
     cap_k = max(128, int(os.environ.get("MOOLIB_TPU_FLASH_BWD_BLOCK_K", 512)))
-    bq = _largest_divisor(Tq, min(block_q, cap_q))
-    bk = _largest_divisor(Tk, min(block_k, cap_k))
+    bq = _largest_divisor(Tq, min(c.block_q, cap_q))
+    bk = _largest_divisor(Tk, min(c.block_k, cap_k))
+    nq = Tq // bq
 
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
-
-    qb, kb, vb, dob = to_bh(q), to_bh(k), to_bh(v), to_bh(g)
-    # delta_i = Σ_d dO_i · O_i — row table, like lse, in [B*H, Tq] layout.
-    delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).transpose(0, 2, 1).reshape(B * H, Tq)
+    (qa, ka, va), (q0, k0, v0) = _kernel_arrays(operands, c)
+    do = _of_result(g, c)
+    # delta_i = Σ_d dO_i · O_i — row table, like lse.  Summed as [.., H, 8, D]:
+    # eight rows of T beside D are what a float32 tile of [B, T, H * D] holds,
+    # so the products are reduced where they lie; summed as [B, T, H, D] XLA
+    # first re-tiles them all (a 67-MB copy a block at B=4, T=2048, H=16).
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+        B, Tq // 8, 8, H, D).transpose(0, 1, 3, 2, 4).sum(axis=-1)
+    delta = delta.transpose(0, 2, 1, 3).reshape(B * H, 1, Tq)
     if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32).transpose(0, 2, 1).reshape(
-            B * H, Tq
-        )
+        delta = delta - _rows(g_lse, c)
+    kwargs = dict(scale=D**-0.5, causal=c.causal, block_q=bq, block_k=bk)
+    every = (ka, qa, va, do, delta)
 
-    kwargs = dict(scale=scale, causal=causal, block_q=bq, block_k=bk)
-    # The row tables ride as [B*H, 1, T]: TPU lowering constrains the last
-    # two block dims (divisible by (8, 128) or equal to the array dims), so
-    # a 2-D (1, bq) block over [B*H, T] is illegal when B*H > 1 — the unit
-    # dim must sit in the constrained sublane slot, where 1 == 1 passes.
-    lse = lse.reshape(B * H, 1, Tq)
-    delta = delta.reshape(B * H, 1, Tq)
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
-
+    # dq: a program a (batch row x query head, q block), the head's K/V
+    # head's blocks innermost.
+    q_at, kv_at, row_spec = _query_sweep(c, bq, bk)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kwargs),
-        grid=(B * H, Tq // bq, Tk // bk),
+        grid=(B * H, nq, Tk // bk),
         in_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),  # k
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),  # q
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),  # v
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),  # do
+            _tile(c, Hk, k0, bk, kv_at),  # k
+            _tile(c, H, q0, bq, q_at),  # q
+            _tile(c, Hk, v0, bk, kv_at),  # v
+            _tile(c, H, 0, bq, q_at),  # do
             row_spec,  # lse
             row_spec,  # delta
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=_out_struct((B * H, Tq, D), q.dtype, kb, qb, vb, dob, delta),
+        out_specs=_tile(c, H, 0, bq, q_at),
+        out_shape=_out_struct(_heads_shape(c, H, Tq), qa.dtype, *every),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-    )(kb, qb, vb, dob, lse, delta)
+        interpret=c.interpret,
+    )(ka, qa, va, do, lse, delta)
 
-    qrow_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, j))
+    # dk, dv: a program a (batch row x K/V head, k block); innermost, the q
+    # blocks of every query head of its group, so a shared head's gradient
+    # is summed in the float32 scratch.
+    def k_at(n, i, j):
+        return jax.lax.div(n, Hk), jax.lax.rem(n, Hk), i
+
+    def q_block(i, j):
+        # Causal: a q block wholly before k block i is not computed; the map
+        # points at the first one that is, so nothing is copied for it.
+        qi = jax.lax.rem(j, nq)
+        if not c.causal:
+            return qi
+        return jnp.maximum(qi, jnp.minimum(jax.lax.div(i * bk, bq), nq - 1))
+
+    def q_of_k_at(n, i, j):
+        head = jax.lax.rem(n, Hk) * group + jax.lax.div(j, nq)
+        return jax.lax.div(n, Hk), head, q_block(i, j)
+
+    def qrow_at(n, i, j):
+        b, head, qi = q_of_k_at(n, i, j)
+        return b * H + head, 0, qi
+
+    qrow_spec = pl.BlockSpec((1, 1, bq), qrow_at)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **kwargs),
-        grid=(B * H, Tk // bk, Tq // bq),
+        functools.partial(_flash_bwd_dkv_kernel, q_blocks=nq, **kwargs),
+        grid=(B * Hk, Tk // bk, group * nq),
         in_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),  # k
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, j, 0)),  # q
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),  # v
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, j, 0)),  # do
+            _tile(c, Hk, k0, bk, k_at),  # k
+            _tile(c, H, q0, bq, q_of_k_at),  # q
+            _tile(c, Hk, v0, bk, k_at),  # v
+            _tile(c, H, 0, bq, q_of_k_at),  # do
             qrow_spec,  # lse
             qrow_spec,  # delta
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),
-        ],
+        out_specs=[_tile(c, Hk, 0, bk, k_at), _tile(c, Hk, 0, bk, k_at)],
         out_shape=[
-            _out_struct((B * H, Tk, D), k.dtype, kb, qb, vb, dob, delta),
-            _out_struct((B * H, Tk, D), v.dtype, kb, qb, vb, dob, delta),
+            _out_struct(_heads_shape(c, Hk, Tk), ka.dtype, *every),
+            _out_struct(_heads_shape(c, Hk, Tk), va.dtype, *every),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        interpret=interpret,
-    )(kb, qb, vb, dob, lse, delta)
+        interpret=c.interpret,
+    )(ka, qa, va, do, lse, delta)
 
-    def from_bh(x, T):
-        return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    if c.packed:
+        # The projection's cotangent: the one copy the backward makes.
+        return (jnp.concatenate([dq, dk, dv], axis=-1),)
+    return _of_kernel(dq, H, c), _of_kernel(dk, Hk, c), _of_kernel(dv, Hk, c)
 
-    return from_bh(dq, Tq), from_bh(dk, Tk), from_bh(dv, Tk)
+
+def _auto_blocks(Tq, Tk, window=None):
+    """Defaults from a block sweep on TPU v5e (T=4096, causal): 128x128 blocks
+    leave grid overhead dominant (32k tiny steps, 7.7 ms); 512x1024 runs the
+    same shape in 1.8 ms while q+k+v+s blocks stay well under VMEM.  The
+    largest 128-multiple divisor of T up to the tuned size, so lengths like
+    1536 or 2560 still ride the kernel; 0 for a T without one (e.g. 250, or
+    160 < 2*128)."""
+    # Under a window a key block wider than the window is mostly masked.
+    cap_k = 1024 if window is None else min(1024, max(128, window // 128 * 128))
+    return _largest_divisor(Tq, 512), _largest_divisor(Tk, cap_k)
+
+
+def _interpret(interpret):
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"flash_attention has a Mosaic (tpu) lowering and a cpu "
+            f"interpret mode for tests; platform {backend!r} has neither"
+        )
+    return backend == "cpu"
+
+
+def _mesh_axis(mesh, name, dim):
+    """``name`` where the mesh has that axis and it divides ``dim``."""
+    return name if name in mesh.axis_names and dim % mesh.shape[name] == 0 else None
 
 
 def flash_attention(
@@ -541,7 +718,18 @@ def flash_attention(
     mesh=None,
     window: int | None = None,
 ):
-    """Blockwise attention; q/k/v: [B, T, H, D] → [B, T, H, D].
+    """Blockwise attention; q: [B, T, H, D], k/v: [B, T, Hk, D] → [B, T, H, D].
+
+    ``Hk`` divides ``H``: K/V head ``j`` serves query heads ``j * H / Hk`` up
+    to the next one's (grouped-query attention; ``Hk == H`` is multi-head).
+    The kernels read a shared head where it lies, once a query head, and sum
+    its gradient over its group: nothing is repeated.
+
+    A head size that is a multiple of 128 is indexed in place: the kernels'
+    blocks are cut out of q, k, v as [B, T, heads * D] (a free reshape) and
+    the result and the gradients are written in that form.  Any other head
+    size goes through head-major [B * H, T, D] copies (module docstring;
+    ``flash_attention_traces_total{path}``).
 
     ``window``: the keys a query sees, itself included (a sliding window of
     ``window`` positions; needs ``causal`` and Tq == Tk).  Key blocks outside
@@ -567,7 +755,10 @@ def flash_attention(
     uses to merge chunk results across ICI hops.
     """
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Hk = k.shape[1:3]
+    if H % Hk or v.shape[2] != Hk:
+        raise ValueError(
+            f"flash_attention: {H} query heads over K/V heads {Hk}, {v.shape[2]}")
     if window is not None:
         if not causal or Tq != Tk or return_lse or mesh is not None or window < 1:
             raise ValueError(
@@ -578,10 +769,8 @@ def flash_attention(
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        def axis(name, dim):
-            return name if name in mesh.axis_names and dim % mesh.shape[name] == 0 else None
-
-        spec = P(axis("dp", B), None, axis("tp", H), None)
+        # tp cuts the K/V heads, so a chip holds whole groups.
+        spec = P(_mesh_axis(mesh, "dp", B), None, _mesh_axis(mesh, "tp", Hk), None)
         lse_spec = P(spec[0], None, spec[2])
         return jax.shard_map(
             functools.partial(
@@ -592,21 +781,11 @@ def flash_attention(
             in_specs=(spec, spec, spec),
             out_specs=(spec, lse_spec) if return_lse else spec,
         )(q, k, v)
-    # Defaults from a block sweep on TPU v5e (T=4096, causal): 128x128 blocks
-    # leave grid overhead dominant (32k tiny steps, 7.7 ms); 512x1024 runs the
-    # same shape in 1.8 ms while q+k+v+s blocks stay well under VMEM.  Use the
-    # largest 128-multiple divisor of T up to the tuned size so lengths like
-    # 1536 or 2560 still ride the kernel; T without such a divisor (e.g. 250,
-    # or 160 < 2*128) takes the dense fallback rather than handing Mosaic a
-    # non-tile-aligned block.
     explicit_q = block_q is not None
     explicit_k = block_k is not None
-    if block_q is None:
-        block_q = _largest_divisor(Tq, 512)
-    if block_k is None:
-        # Under a window a key block wider than the window is mostly masked.
-        cap_k = 1024 if window is None else min(1024, max(128, window // 128 * 128))
-        block_k = _largest_divisor(Tk, cap_k)
+    auto_q, auto_k = _auto_blocks(Tq, Tk, window)
+    block_q = block_q if explicit_q else auto_q
+    block_k = block_k if explicit_k else auto_k
     # Blocks below the 128-lane tile (T with a large odd factor) aren't worth
     # a pallas launch — use the dense path.  An unusable *caller-supplied*
     # block raises instead (the caller tuning blocks gets a signal, not an
@@ -628,34 +807,74 @@ def flash_attention(
             "flash_attention: Tq=%d Tk=%d does not tile into 128-multiple "
             "blocks; using dense attention", Tq, Tk,
         )
+        k, v = _repeat_kv(k, v, H // Hk)
         if return_lse:
             return dense_attention_lse(q, k, v, causal=causal)
         if window is not None:  # the oracle, one block the whole length
             return _blockwise_attention(q, k, v, True, Tq, Tk, window=window)
         return full_attention(q, k, v, causal=causal)
-    if interpret is None:
-        backend = jax.default_backend()
-        if backend not in ("tpu", "cpu"):
-            raise RuntimeError(
-                f"flash_attention has a Mosaic (tpu) lowering and a cpu "
-                f"interpret mode for tests; platform {backend!r} has neither"
-            )
-        interpret = backend == "cpu"
-    if return_lse:
-        return _flash_lse(q, k, v, causal, block_q, block_k, interpret)
+    interpret = _interpret(interpret)
     if window is not None:
+        _M_TRACES.inc(path="head_major")
+        k, v = _repeat_kv(k, v, H // Hk)
         return _flash_window(q, k, v, window, block_q, block_k, interpret)
-    return _flash(q, k, v, causal, block_q, block_k, interpret)
+    c = _Call(B, Tq, Tk, H, Hk, D, False, causal, block_q, block_k, interpret,
+              return_lse)
+    _M_TRACES.inc(path="in_place" if c.in_place else "head_major")
+    return _flash((q, k, v), c)
+
+
+def flash_attention_packed(
+    qkv: jax.Array,
+    num_heads: int,
+    num_kv_heads: int | None = None,
+    causal: bool = True,
+    interpret: bool | None = None,
+    mesh=None,
+):
+    """Self-attention over a packed projection: qkv [B, T, (H + 2 Hk) * D],
+    columns ``[q heads | k heads | v heads]`` (``Block``'s ``qkv`` Dense as
+    it leaves the matmul) → [B, T, H * D], as the output projection takes it.
+
+    Where the kernels index in place (``D % 128 == 0``, T tiles) they take the
+    one array three times, each under its own column map, and the backward
+    writes dq, dk and dv in those columns' form and concatenates them: no
+    slice, transpose or repeat of the projection is made.  Anything else — a
+    head size off the lanes, a T that takes the dense path, a mesh whose
+    ``tp`` cuts the heads (the packed columns are not one head axis) — is
+    :func:`flash_attention` of the three slices.  ``mesh`` as there.
+    """
+    B, T, C = qkv.shape
+    H, Hk = num_heads, num_kv_heads or num_heads
+    D = C // (H + 2 * Hk)
+    if H % Hk or D * (H + 2 * Hk) != C:
+        raise ValueError(
+            f"flash_attention_packed: {C} columns are not {H} + 2 x {Hk} heads")
+    block_q, block_k = _auto_blocks(T, T)
+    cut_heads = mesh is not None and _mesh_axis(mesh, "tp", Hk) and mesh.shape["tp"] > 1
+    if D % 128 or not (block_q and block_k) or cut_heads:
+        return flash_attention(
+            *_unpack(qkv, H, Hk), causal=causal, interpret=interpret, mesh=mesh,
+        ).reshape(B, T, H * D)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        spec = P(_mesh_axis(mesh, "dp", B), None, None)
+        return jax.shard_map(
+            functools.partial(
+                flash_attention_packed, num_heads=H, num_kv_heads=Hk,
+                causal=causal, interpret=interpret),
+            mesh=mesh, in_specs=(spec,), out_specs=spec,
+        )(qkv)
+    _M_TRACES.inc(path="in_place")
+    return _flash((qkv,), _Call(B, T, T, H, Hk, D, True, causal, block_q, block_k,
+                                _interpret(interpret), False))
 
 
 @jax.named_scope("flash_attention")
 def _flash_window_forward(q, k, v, window, block_q, block_k, interpret):
     B, T, H, D = q.shape
-
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-
-    qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
+    qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     spans = [_window_blocks(i, window, block_q, block_k) for i in range(T // block_q)]
 
     def key_block(b, i, j):
@@ -685,41 +904,38 @@ def _flash_window_forward(q, k, v, window, block_q, block_k, interpret):
 
 
 @jax.named_scope("flash_attention")
-def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    scale = D**-0.5
-
-    # [B, T, H, D] -> [B*H, T, D]
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
-
-    qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
-    grid = (B * H, Tq // block_q, Tk // block_k)
+def _flash_forward(operands, c):
+    """(the result in the operands' form: [B, Tq, H, D], packed [B, Tq, H *
+    D]; the row logsumexp as the backward reads it, [B * H, 1, Tq] f32)"""
+    arrays, (q0, k0, v0) = _kernel_arrays(operands, c)
+    H = c.H
+    # A program a (batch row x query head, q block), the blocks of the head's
+    # K/V head innermost.
+    q_at, kv_at, row_spec = _query_sweep(c, c.block_q, c.block_k)
     out, lse = pl.pallas_call(
         functools.partial(
-            _flash_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k
+            _flash_kernel, scale=c.D**-0.5, causal=c.causal,
+            block_q=c.block_q, block_k=c.block_k,
         ),
-        grid=grid,
+        grid=(c.B * H, c.Tq // c.block_q, c.Tk // c.block_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            _tile(c, H, q0, c.block_q, q_at),
+            _tile(c, c.Hk, k0, c.block_k, kv_at),
+            _tile(c, c.Hk, v0, c.block_k, kv_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
+            _tile(c, H, 0, c.block_q, q_at),
+            row_spec,
         ],
         out_shape=[
-            _out_struct((B * H, Tq, D), q.dtype, qb, kb, vb),
-            _out_struct((B * H, Tq, 128), jnp.float32, qb, kb, vb),
+            _out_struct(_heads_shape(c, H, c.Tq), arrays[0].dtype, *arrays),
+            _out_struct((c.B * H, 1, c.Tq), jnp.float32, *arrays),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((c.block_q, 128), jnp.float32),
+            pltpu.VMEM((c.block_q, 128), jnp.float32),
+            pltpu.VMEM((c.block_q, c.D), jnp.float32),
         ],
-        interpret=interpret,
-    )(qb, kb, vb)
-    # lse comes out lane-replicated; one lane is the [B*H, Tq] row table.
-    return out.reshape(B, H, Tq, D).transpose(0, 2, 1, 3), lse[:, :, 0]
+        interpret=c.interpret,
+    )(*arrays)
+    return _of_kernel(out, H, c), lse

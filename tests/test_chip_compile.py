@@ -71,22 +71,35 @@ def _compile(fn, *args):
 # long-context T the LM cells will use.
 @pytest.mark.parametrize("t", [2048, 8192])
 @pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_flash_kernels_compile_through_mosaic(chip, t, direction):
-    from moolib_tpu.ops.flash_attention import flash_attention
-
-    x = jax.ShapeDtypeStruct((2, t, 8, 128), jnp.bfloat16, sharding=chip)
+@pytest.mark.parametrize("entry", ["arrays", "packed"])
+def test_flash_kernels_compile_through_mosaic(chip, t, direction, entry):
+    """Both entries index in place at this head size: blocks (1, block, 128)
+    cut out of [B, T, 8 x 128] arrays, of the packed projection
+    [B, T, 24 x 128] under three column maps, the logsumexp transposed to a
+    row in VMEM."""
+    from moolib_tpu.ops.flash_attention import flash_attention, flash_attention_packed
 
     # interpret=False steers the kernel onto Mosaic: left to itself it asks
     # jax.default_backend(), which is the cpu in this process.
-    def attend(q, k, v):
-        return flash_attention(q, k, v, causal=True, interpret=False)
+    if entry == "packed":
+        args = (jax.ShapeDtypeStruct((2, t, 24 * 128), jnp.bfloat16, sharding=chip),)
 
-    def loss_grads(q, k, v):
-        return jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+        def attend(qkv):
+            return flash_attention_packed(qkv, 8, causal=True, interpret=False)
+    else:
+        args = (jax.ShapeDtypeStruct((2, t, 8, 128), jnp.bfloat16, sharding=chip),) * 3
 
-    _, text = _compile(attend if direction == "forward" else loss_grads, x, x, x)
+        def attend(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss_grads(*a):
+        return jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(), tuple(range(len(a))))(*a)
+
+    _, text = _compile(attend if direction == "forward" else loss_grads, *args)
     # forward: one kernel; backward: the forward again plus the dq and dk/dv passes
-    assert text.count("tpu_custom_call") >= (1 if direction == "forward" else 3)
+    assert text.count("tpu_custom_call") == (1 if direction == "forward" else 3)
+    # the kernels' operands and results are the arrays' own form, never [B * H, T, D]
+    assert "bf16[16,%d,128]" % t not in text
 
 
 # name: slots, blocks a slot, block, query heads, KV heads, head size, dtype.
@@ -302,6 +315,22 @@ _TRAIN_ARGV = [
 ]
 
 
+def _relayout_copies(text, batch):
+    """The entry computation's synchronous ``copy`` operations that re-tile
+    the attention's operands and results of a device's ``batch`` rows at
+    T=2048, 16 heads of 128, by result shape (some carry no ``op_name``).
+    Seven a block before the kernels indexed the projections' own layout: the
+    ``qkv`` output re-tiled to be sliced, q, k, v and the result's cotangent
+    to [B, H, T, D] and the result back, the logsumexp the forward wrote over
+    128 lanes; and the product dO x O that ``delta`` sums, re-tiled to
+    [.., H, D] when it is summed in that shape."""
+    shapes = {f"bf16[{batch},2048,16,128]", f"bf16[{256 * batch},8,48,128]",
+              f"f32[{16 * batch},2048,128]", f"f32[{256 * batch},8,16,128]"}
+    entry = re.search(r"^ENTRY [^\n]*\n(.*?)^}", text, re.M | re.S).group(1)
+    return [line.strip()[:160] for line in entry.splitlines()
+            if (m := re.search(r" = (\w+\[[\d,]*\])\S* copy\(", line)) and m.group(1) in shapes]
+
+
 def _lm_train_step(monkeypatch, sharding, batch, layers=4, mesh=None):
     """``lm.train``'s own jitted step and its state as shapes, on one device
     under ``sharding`` or on ``mesh`` as ``train`` places it there, and the
@@ -349,7 +378,10 @@ def test_lm_train_step_updates_its_state_in_place(chip, monkeypatch, batch, most
     jstep, (params, opt_state), state_bytes = _lm_train_step(monkeypatch, chip, batch)
     tokens = jax.ShapeDtypeStruct((batch, 2048), jnp.int32, sharding=chip)
     compiled, text = _compile(jstep, params, opt_state, tokens)
-    assert text.count("tpu_custom_call") >= 3 * 4  # flash forward, dq, dk/dv a block
+    assert text.count("tpu_custom_call") == 3 * 4  # flash forward, dq, dk/dv a block
+    # and nothing re-tiled around them (28 such copies, 2.7 GB of traffic, at
+    # B=4 before; dq, dk and dv are concatenated inside their consumers)
+    assert not _relayout_copies(text, batch)
     mem = compiled.memory_analysis()
     # 411.5 M parameters x (weights + two AdamW moments) x 4 bytes, and a count.
     assert 4.9e9 < state_bytes < 5.0e9
@@ -440,7 +472,8 @@ def test_lm_train_step_over_dp4_updates_a_quarter_of_the_state(
     a fusion ``all-reduce-scatter`` around an all-reduce and a slice), the
     weights are gathered and the activations stay where they are."""
     compiled, text, state_bytes = _dp4_step(topo, monkeypatch, layers)
-    assert text.count("tpu_custom_call") >= 3 * layers  # the flash kernels, under shard_map
+    assert text.count("tpu_custom_call") == 3 * layers  # the flash kernels, under shard_map
+    assert not _relayout_copies(text, 4)  # seven a block before, as on one chip
     mem = compiled.memory_analysis()
     assert 0.98 * state_gb * 1e9 < state_bytes < 1.02 * state_gb * 1e9
     assert mem.argument_size_in_bytes < 1.02 * state_bytes
@@ -857,8 +890,8 @@ def test_sliding_decode_step_holds_its_rings_as_aliased_leaves_read_in_place(chi
 
 def test_sliding_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
     """A prompt of 4,096 positions: the windowed flash kernel over 72 heads
-    under each period's scan (one result: it has no logsumexp), the causal one
-    over 48 in the three full layers, 40,960 (token, expert) rows through the
+    under each period's scan (head-major, one result: it has no logsumexp),
+    the causal one over 48, in place, in the three full layers, 40,960 (token, expert) rows through the
     grouped matmul.  Weights, temporaries and the engine's 5.64 GB of cache
     stay under the chip's 16 GB."""
     model, params, traffic = _sliding(monkeypatch)
@@ -871,5 +904,6 @@ def test_sliding_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
     assert mem.temp_size_in_bytes < 2.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 5.64e9 < 15.5e9
     assert len(re.findall(r"%flash_attention[\w.]* = bf16\[72,4096,128\]", text)) == 2
-    assert len(re.findall(r"%flash_attention[\w.]* = \(bf16\[48,4096,128\]", text)) == 3
+    # the causal kernel writes [1, T, 48 x 128], as the output projection reads it
+    assert len(re.findall(r"%flash_attention[\w.]* = \(bf16\[1,4096,6144\]", text)) == 3
     assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[40960,", text)) == 8

@@ -184,3 +184,49 @@ def test_flash_bf16_on_chip():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=2e-2, atol=2e-2
     )
+
+
+@pytest.mark.parametrize("kv_heads", [16, 4])
+def test_flash_packed_in_place_at_the_train_cells_shape_on_chip(kv_heads):
+    """The train cells' call (B=4 here 2, T=2048, 16 heads of 128) as
+    ``Block`` makes it: the packed projection through Mosaic's in-place
+    blocks, the logsumexp transposed to a row in VMEM, and with 4 K/V heads
+    the grouped dk/dv sweep.  The result and the projection's cotangent
+    against dense attention of the three slices, K and V repeated."""
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.ops.flash_attention import flash_attention, flash_attention_packed
+    from moolib_tpu.parallel.ring_attention import dense_attention_lse
+
+    dev = _tpu_device()
+    B, T, H, D = 2, 2048, 16, 128
+    rng = np.random.default_rng(kv_heads)
+    mk = lambda *shape: jax.device_put(
+        jnp.asarray(rng.normal(size=shape).astype(np.float32) * 0.5), dev)
+    qkv, g = mk(B, T, (H + 2 * kv_heads) * D), mk(B, T, H * D)
+
+    def slices(qkv):
+        x = qkv.reshape(B, T, H + 2 * kv_heads, D)
+        return x[:, :, :H], x[:, :, H:H + kv_heads], x[:, :, H + kv_heads:]
+
+    def dense(qkv):
+        q, k, v = slices(qkv)
+        k, v = (jnp.repeat(x, H // kv_heads, axis=2) for x in (k, v))
+        out, lse = dense_attention_lse(q, k, v, causal=True)
+        return out.reshape(B, T, H * D), lse
+
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(lambda x: flash_attention_packed(x, H, kv_heads), qkv)
+        (ref, ref_lse), vjp_ref = jax.vjp(dense, qkv)
+        (got,), (want,) = vjp(g), vjp_ref((g, jnp.zeros_like(ref_lse)))
+        # the row logsumexp and its cotangent, through the three arrays' entry
+        g_lse = mk(B, T, H)
+        (_, lse), vjp_lse = jax.vjp(
+            lambda x: flash_attention(*slices(x), return_lse=True), qkv)
+        (got_lse,) = vjp_lse((g.reshape(B, T, H, D), g_lse))
+        (want_lse,) = vjp_ref((g, g_lse))
+    for a, b, name in ((out, ref, "out"), (got, want, "dqkv"), (lse, ref_lse, "lse"),
+                       (got_lse, want_lse, "dqkv with a cotangent on lse")):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)

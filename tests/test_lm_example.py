@@ -517,3 +517,27 @@ def test_lm_serve_refuses_at_start_what_the_engine_does_not_serve(argv, sentence
 
     with pytest.raises(SystemExit, match=sentence):
         lm_serve.main(argv)
+
+
+@pytest.mark.parametrize("heads,path", [(2, "in_place"), (4, "head_major")])
+def test_lm_train_says_how_the_flash_kernels_address_their_operands(capsys, heads, path):
+    """d_model 256 as 2 heads of 128: ``Block`` hands the kernels its packed
+    projection and they index it in place; as 4 heads of 64 they go through
+    head-major copies.  ``flash_attention_traces_total{path}`` by path in
+    ``train``'s result and in its log line, beside ``donated=``."""
+    from moolib_tpu import telemetry
+
+    prefix = 'flash_attention_traces_total{path="%s"}'
+    before = telemetry.get_registry().counter_values()
+    out = train(make_flags([
+        "--seq_len", "128", "--batch_size", "2", "--seed", "7", "--d_model", "256",
+        "--heads", str(heads), "--layers", "1", "--vocab", "64", "--mesh", "",
+        "--attention", "flash", "--steps", "1", "--log_interval", "1"]))
+    after = telemetry.get_registry().counter_values()
+    other = {"in_place": "head_major", "head_major": "in_place"}[path]
+    assert after[prefix % path] > before.get(prefix % path, 0.0)
+    assert after.get(prefix % other, 0.0) == before.get(prefix % other, 0.0)
+    assert out["flash_traces"] == {path: after[prefix % path] - before.get(prefix % path, 0.0)}
+    assert out["flash_dense_reroutes"] == before.get("flash_dense_reroutes_total", 0.0)
+    (line,) = [l for l in capsys.readouterr().out.splitlines() if l.startswith("step=1 ")]
+    assert " donated=" in line and line.split(" flash=")[1].split(":")[0] == path

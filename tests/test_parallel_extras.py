@@ -8,7 +8,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from moolib_tpu import parallel
 from moolib_tpu.checkpoint import Checkpointer
-from moolib_tpu.ops.flash_attention import flash_attention
+from moolib_tpu.ops.flash_attention import flash_attention, flash_attention_packed
 
 
 def test_switch_moe_routing_and_shapes():
@@ -220,6 +220,204 @@ def test_flash_attention_matches_dense():
         out = flash_attention(q, k, v, causal=causal)
         ref = parallel.full_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+def _attention_case(B, T, H, Hk, D, seed):
+    """q, K and V at ``Hk`` heads, and cotangents of the result and of the
+    row logsumexp."""
+    rngs = jax.random.split(jax.random.key(seed), 5)
+    q, g = (jax.random.normal(r, (B, T, H, D)) for r in rngs[:2])
+    k, v = (jax.random.normal(r, (B, T, Hk, D)) for r in rngs[2:4])
+    return q, k, v, g, jax.random.normal(rngs[4], (B, T, H))
+
+
+def _packed(*arrays):
+    """[B, T, heads, D] arrays side by side as [B, T, columns]."""
+    return jnp.concatenate([x.reshape(*x.shape[:2], -1) for x in arrays], axis=-1)
+
+
+def _traces():
+    from moolib_tpu import telemetry
+
+    values = telemetry.get_registry().counter_values()
+    return {p: values.get('flash_attention_traces_total{path="%s"}' % p, 0.0)
+            for p in ("in_place", "head_major")}
+
+
+# A head size of 128: the kernels index q, k, v as [B, T, H * D] where they
+# lie.  Every batch size, head count and length of the list at least twice,
+# T = 1,280 (forward blocks of 640, backward of 256) and 2,048 (512 x 1,024,
+# 512 x 512), both masks.
+@pytest.mark.parametrize("B,H,T,causal", [
+    (1, 1, 1280, True), (1, 3, 2048, False), (3, 1, 2048, True), (3, 3, 1280, False),
+    (1, 16, 2048, True), (3, 16, 1280, True), (1, 16, 1280, False), (3, 3, 2048, True)])
+def test_flash_in_place_matches_the_blockwise_oracle(B, H, T, causal):
+    """Forward, the row logsumexp, dq, dk and dv of the in-place kernels,
+    with a cotangent on the logsumexp too (it folds into ``delta``), against
+    the pure-jax streaming softmax at the same blocks."""
+    from moolib_tpu.ops.flash_attention import _auto_blocks, _blockwise_attention
+
+    q, k, v, g, g_lse = _attention_case(B, T, H, H, 128, seed=B * H + T)
+    before = _traces()
+    (out, lse), vjp = jax.vjp(
+        lambda *a: flash_attention(*a, causal=causal, return_lse=True), q, k, v)
+    assert _traces()["in_place"] > before["in_place"]
+    assert _traces()["head_major"] == before["head_major"]
+    (want, want_lse), want_vjp = jax.vjp(
+        lambda *a: _blockwise_attention(*a, causal, *_auto_blocks(T, T), return_lse=True),
+        q, k, v)
+    assert lse.shape == (B, T, H) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), rtol=2e-5, atol=2e-5)
+    for a, b, name in zip(vjp((g, g_lse)), want_vjp((g, g_lse)), "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("Tq,Tk", [(256, 512), (512, 256), (384, 384)])
+def test_flash_causal_copies_no_block_it_skips_at_unequal_lengths(Tq, Tk):
+    """Causal, blocks of 128: a key block above the diagonal is neither
+    computed nor copied (its step's index map points at the last block that
+    is), in the dk/dv sweep a query block before the key block likewise, and
+    with more keys than queries the last key blocks see no query at all: dk
+    and dv are zero there.  Against the oracle at the same blocks."""
+    from moolib_tpu.ops.flash_attention import _blockwise_attention
+
+    rngs = jax.random.split(jax.random.key(Tq + Tk), 4)
+    q, g = (jax.random.normal(r, (2, Tq, 2, 128)) for r in rngs[:2])
+    k, v = (jax.random.normal(r, (2, Tk, 2, 128)) for r in rngs[2:])
+    out, vjp = jax.vjp(
+        lambda *a: flash_attention(*a, causal=True, block_q=128, block_k=128), q, k, v)
+    want, want_vjp = jax.vjp(lambda *a: _blockwise_attention(*a, True, 128, 128), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5)
+    for a, b, name in zip(vjp(g), want_vjp(g), "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+    if Tk > Tq:
+        assert not np.asarray(vjp(g)[1][:, Tq:]).any()
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, and every primitive's
+    name outside the kernels' own bodies."""
+    calls, names = [], []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            calls.append(eqn)
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    more, inner = _pallas_calls(sub)
+                    calls += more
+                    names += inner
+    return calls, names
+
+
+@pytest.mark.parametrize("H,Hk,D,causal", [
+    (16, 4, 128, True), (2, 2, 128, False), (4, 1, 256, True), (4, 2, 64, True)])
+def test_flash_packed_equals_three_arrays(H, Hk, D, causal):
+    """``flash_attention_packed`` of [q heads | k heads | v heads] against
+    ``flash_attention`` of the three arrays with K and V repeated a group:
+    the result, and the projection's cotangent as the three side by side
+    with a shared head's summed over its group.  D = 64 is the three slices
+    through the head-major form."""
+    B, T = 2, 256
+    q, k, v, g, _ = _attention_case(B, T, H, Hk, D, seed=H + Hk)
+    qkv = _packed(q, k, v)
+    out, vjp = jax.vjp(lambda x: flash_attention_packed(x, H, Hk, causal=causal), qkv)
+    assert out.shape == (B, T, H * D)
+
+    def repeated(q, k, v):
+        k, v = (jnp.repeat(x, H // Hk, axis=2) for x in (k, v))
+        return flash_attention(q, k, v, causal=causal)
+
+    want, want_vjp = jax.vjp(repeated, q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(want.reshape(B, T, H * D)), rtol=2e-6, atol=2e-6)
+    (d_qkv,) = vjp(g.reshape(B, T, H * D))
+    np.testing.assert_allclose(
+        np.asarray(d_qkv), np.asarray(_packed(*want_vjp(g))), rtol=2e-5, atol=2e-5)
+    # K and V at their own head count take the same kernels.
+    grouped, grouped_vjp = jax.vjp(lambda *a: flash_attention(*a, causal=causal), q, k, v)
+    np.testing.assert_array_equal(np.asarray(grouped.reshape(B, T, H * D)), np.asarray(out))
+    np.testing.assert_allclose(
+        np.asarray(_packed(*grouped_vjp(g))), np.asarray(d_qkv), rtol=1e-6, atol=1e-6)
+
+
+def test_flash_packed_hands_the_kernels_the_projection_itself():
+    """16 query heads over 4 K/V heads, forward and backward: each of the
+    three kernels takes the one packed array as q, k and v, and outside the
+    kernels nothing slices, repeats or gathers; the backward's one copy is the
+    concatenation of dq, dk and dv (which XLA fuses into its consumers)."""
+    B, T, H, Hk, D = 1, 256, 16, 4, 128
+    qkv = jnp.zeros((B, T, (H + 2 * Hk) * D), jnp.bfloat16)
+    g = jnp.zeros((B, T, H * D), jnp.bfloat16)
+    pulled_back = lambda x, g: jax.vjp(lambda x: flash_attention_packed(x, H, Hk), x)[1](g)
+    calls, names = _pallas_calls(jax.make_jaxpr(pulled_back)(qkv, g).jaxpr)
+    assert len(calls) == 3
+    for call in calls:
+        q, k, v = call.invars[:3]
+        assert q is k is v and q.aval.shape == qkv.shape
+    assert names.count("concatenate") == 1
+    assert not {"slice", "gather", "dynamic_slice", "broadcast_in_dim"} & set(names), names
+    # forward, dq: a program a query head; dk/dv: a K/V head, its group's q blocks innermost
+    assert [call.params["grid_mapping"].grid for call in calls] == [
+        (16, 1, 1), (16, 1, 1), (4, 1, 4)]
+    assert [[o.aval.shape for o in call.outvars] for call in calls] == [
+        [(B, T, H * D), (B * H, 1, T)], [(B, T, H * D)], [(B, T, Hk * D)] * 2]
+
+
+@pytest.mark.parametrize("entry,D,window,path", [
+    ("arrays", 128, None, "in_place"), ("arrays", 256, None, "in_place"),
+    ("arrays", 64, None, "head_major"), ("arrays", 128, 128, "head_major"),
+    ("packed", 128, None, "in_place"), ("packed", 64, None, "head_major")])
+def test_flash_path_is_chosen_by_head_size_and_counted(entry, D, window, path):
+    """``flash_attention_traces_total{path}``: a head size on the 128 lanes is
+    indexed in place; any other, and every windowed call, goes through
+    head-major copies (``interpret=False`` is traced and lowered for no
+    platform: nothing runs)."""
+    x = jnp.zeros((2, 256, 2, D), jnp.bfloat16)
+    before = _traces()
+    if entry == "packed":
+        jax.make_jaxpr(lambda x: flash_attention_packed(x, 2, interpret=False))(_packed(x, x, x))
+    else:
+        jax.make_jaxpr(lambda x: flash_attention(x, x, x, window=window, interpret=False))(x)
+    after = _traces()
+    assert {p: after[p] - before[p] for p in after} == {p: float(p == path) for p in after}
+
+
+@pytest.mark.parametrize("D", [128, 64])
+@pytest.mark.parametrize("entry", ["arrays", "packed"])
+def test_flash_grouped_backward_matches_the_oracle(monkeypatch, entry, D):
+    """4 query heads over 2 K/V heads through both forms and both entries:
+    the pallas backward, which sums a shared head's gradient in its float32
+    scratch, against the blockwise-jax VJP, which repeats K and V and lets
+    autodiff sum."""
+    B, T, H, Hk = 2, 256, 4, 2
+    q, k, v, g, g_lse = _attention_case(B, T, H, Hk, D, seed=D)
+    grads = {}
+    for mode in ("pallas", "jax"):
+        monkeypatch.setenv("MOOLIB_TPU_FLASH_BWD", mode)
+        if entry == "packed":
+            _, vjp = jax.vjp(lambda x: flash_attention_packed(x, H, Hk), _packed(q, k, v))
+            grads[mode] = vjp(g.reshape(B, T, H * D))
+        else:
+            _, vjp = jax.vjp(lambda *a: flash_attention(*a, return_lse=True), q, k, v)
+            grads[mode] = vjp((g, g_lse))
+    for a, b in zip(grads["pallas"], grads["jax"]):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
+
+
+def test_flash_attention_refuses_heads_that_do_not_group():
+    q = jnp.zeros((1, 256, 4, 128))
+    with pytest.raises(ValueError, match="4 query heads over K/V heads 3"):
+        flash_attention(q, q[:, :, :3], q[:, :, :3])
+    with pytest.raises(ValueError, match="are not 4 \\+ 2 x 3 heads"):
+        flash_attention_packed(jnp.zeros((1, 256, 10 * 128)), 4, 3)
 
 
 def test_checkpointer_roundtrip(tmp_path):

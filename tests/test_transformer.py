@@ -28,6 +28,37 @@ def test_dense_and_flash_agree():
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_f), rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("pos", ["learned", "rotary"])
+@pytest.mark.parametrize("kv_heads", [None, 1])
+@pytest.mark.parametrize("mesh_spec", [None, "dp=2"])
+def test_flash_block_at_a_lane_aligned_head_size_agrees_with_dense(pos, kv_heads, mesh_spec):
+    """d_model 256 as 2 heads of 128: ``Block`` hands the kernels its ``qkv``
+    projection as it leaves the matmul (learned positions) or the rotated q
+    and k beside v at their own head counts (rotary), one or two K/V heads,
+    alone and under ``shard_map`` over ``dp``.  Logits and the parameters'
+    gradients against the dense model, which repeats K and V."""
+    def mk(attention):
+        return TransformerLM(
+            vocab_size=64, d_model=256, num_heads=2, num_layers=1, num_kv_heads=kv_heads,
+            attention=attention, dtype=jnp.float32, pos_embedding=pos, max_len=128)
+
+    mesh = mesh_spec and parallel.make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    tokens = jax.random.randint(jax.random.key(0), (2, 128), 0, 64)
+    params = mk("dense").init(jax.random.key(1), tokens)
+
+    def loss(params, model, **kw):
+        logits = model.apply(params, tokens, **kw)
+        return jnp.mean(jax.nn.log_softmax(logits)[..., 0]), logits
+
+    (_, want), want_grads = jax.value_and_grad(loss, has_aux=True)(params, mk("dense"))
+    kw = {"mesh": mesh} if mesh else {}
+    (_, got), grads = jax.jit(jax.value_and_grad(
+        lambda p: loss(p, mk("flash"), **kw), has_aux=True))(params)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-5)
+
+
 def test_causality():
     """Future tokens must not affect past logits."""
     model = _model("flash")

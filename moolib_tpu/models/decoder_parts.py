@@ -1,5 +1,5 @@
 """What the decoders built from a published configuration file share
-(``latent_moe``, ``hybrid_kda``, ``retention_lm``, ``swa_moe``): everything
+(``latent_moe``, ``hybrid_kda``, ``retention_lm``, ``swa_moe``, ``jamba``): everything
 that is not a mixer, as plain functions, and the grouped-query layer's pieces
 that two of them run (its projections, its paged decode, the counters of an
 expert layer of which this chip holds a share).  Nothing here knows the serving engine: ``engine/``
@@ -37,6 +37,12 @@ _M_HELD_PREFILL_LOAD = _REG.histogram(
     "per prefill and expert layer: the fullest held expert's tokens over the "
     "mean (prompt tokens x experts a token / the router's experts)",
     buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0),
+)
+_M_STATE_LIVE = _REG.histogram(
+    "serve_engine_state_live_slots",
+    "per decode step: slots holding live recurrent state (the active ones: "
+    "the states the model's decode kernel reads and writes, a layer)",
+    buckets=(1, 2, 4, 8, 16, 24, 32, 48, 64, 96, 128, 256),
 )
 
 
@@ -149,9 +155,16 @@ def dot(x, w, dtype):
     return jnp.dot(x.astype(dtype), w, preferred_element_type=jnp.float32)
 
 
-def head_logits(h, final_norm, head, eps, dtype):
-    """The final norm and the head over h [..., D]: float32 logits [..., V]."""
-    return dot(rms_norm(h, final_norm, eps), head, dtype)
+def head_logits(h, final_norm, head, eps, dtype, tied: bool = False):
+    """The final norm and the head [D, V] over h [..., D]: float32 logits
+    [..., V].  ``tied``: ``head`` is the embedding table [V, D] and the
+    contraction runs over its second axis where it lies (no transpose of it
+    is made)."""
+    x = rms_norm(h, final_norm, eps)
+    if not tied:
+        return dot(x, head, dtype)
+    return jax.lax.dot_general(x.astype(dtype), head, (((x.ndim - 1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def rows_to_blocks(x, block_size: int, axis: int):
@@ -162,6 +175,19 @@ def rows_to_blocks(x, block_size: int, axis: int):
     pad[axis] = (0, nbw * block_size - x.shape[axis])
     return jnp.pad(x, pad).reshape(
         x.shape[:axis] + (nbw, block_size) + x.shape[axis + 1:])
+
+
+def observe_state_live(live) -> None:
+    """A decode step's count of slots holding live state, back on the host
+    (a model that keeps a state a slot hands it back in its step counters)."""
+    _M_STATE_LIVE.observe(int(live))
+
+
+def step_bias(key, shape, low: float = 0.001, high: float = 0.1):
+    """A softplus'd step's bias as the Mamba lineage's initialiser draws it:
+    the inverse softplus of a step log-uniform in ``low`` .. ``high``, float32."""
+    step = jnp.exp(jax.random.uniform(key, shape, jnp.float32, jnp.log(low), jnp.log(high)))
+    return step + jnp.log(-jnp.expm1(-step))
 
 
 def write_slot_rows(leaves, rows, slot):
@@ -202,6 +228,17 @@ def write_pool_blocks(pools, rows, block_ids):
     """A join's ``write_rows``: a prompt's blocks into the pools, leaf by leaf."""
     return jax.tree.map(
         lambda pool, new: pool.at[block_ids].set(new.astype(pool.dtype)), pools, rows)
+
+
+def write_cache_rows(cache: SlotCache, rows, block_ids) -> SlotCache:
+    """``write_rows`` of a model whose cache is a :class:`SlotCache` and whose
+    prefill hands back ``{"blocks": ..., "slots": ...}``."""
+    return cache._replace(blocks=write_pool_blocks(cache.blocks, rows["blocks"], block_ids))
+
+
+def write_cache_state(cache: SlotCache, rows, slot) -> SlotCache:
+    """``write_state`` of such a model: the join's other half."""
+    return cache._replace(slots=write_slot_rows(cache.slots, rows["slots"], slot))
 
 
 # --------------------------------------- an expert layer's share, counted

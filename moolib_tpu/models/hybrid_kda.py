@@ -42,19 +42,11 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from .. import telemetry
 from ..ops import kda
 from ..ops.flash_attention import flash_attention
 from ..ops.paged_attention import PagedState
 from ..parallel.moe import dropless_moe
 from . import decoder_parts as parts
-
-_M_STATE_LIVE = telemetry.get_registry().histogram(
-    "serve_engine_state_live_slots",
-    "per decode step: slots holding live recurrent state (the active ones: "
-    "the states the delta-rule kernel reads and writes, a layer)",
-    buckets=(1, 2, 4, 8, 16, 24, 32, 48, 64, 96, 128, 256),
-)
 
 _CONV = 4  # the short convolution's taps; the tail is the last _CONV - 1 inputs
 
@@ -162,7 +154,7 @@ class HybridKdaMoELM:
         """A decode step's counters, back on the host (the engine fetched
         them with the step's packet)."""
         live = int(counters[0])
-        _M_STATE_LIVE.observe(live)
+        parts.observe_state_live(live)
         parts.observe_held_step(counters[1:], live, self.num_experts_per_tok)
 
     def observe_prefill(self, counters, prompt_len: int) -> None:
@@ -184,13 +176,12 @@ class HybridKdaMoELM:
         }
 
     def write_rows(self, cache: parts.SlotCache, rows, block_ids) -> parts.SlotCache:
-        return cache._replace(
-            blocks=parts.write_pool_blocks(cache.blocks, rows["blocks"], block_ids))
+        return parts.write_cache_rows(cache, rows, block_ids)
 
     def write_state(self, cache: parts.SlotCache, rows, slot) -> parts.SlotCache:
         """The join's other half: the slot's row of every slot-axis leaf
         becomes the prefill's, whole."""
-        return cache._replace(slots=parts.write_slot_rows(cache.slots, rows["slots"], slot))
+        return parts.write_cache_state(cache, rows, slot)
 
     # -------------------------------------------------------------- weights
     def init(self, key) -> Dict:
@@ -224,15 +215,14 @@ class HybridKdaMoELM:
             **ffn((P,)),
         }
         lead = (P, K)
-        dt = jnp.exp(jax.random.uniform(
-            next(keys), lead + (W,), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+        dt_bias = parts.step_bias(next(keys), lead + (W,))  # drawn first: the order is the tree's
         kda_p = {
             "attn_norm": jnp.ones(lead + (D,), jnp.float32),
             "w_qkv": w(lead + (D, 3 * W), D),
             "conv": w(lead + (_CONV, 3 * W), _CONV, jnp.float32),
             "w_f1": w(lead + (D, r), D),
             "w_f2": w(lead + (r, W), r),
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "dt_bias": dt_bias,
             "a_log": jnp.log(jax.random.uniform(
                 next(keys), lead + (self.kda_heads,), jnp.float32, 1.0, 16.0)),
             "w_beta": w(lead + (D, self.kda_heads), D),

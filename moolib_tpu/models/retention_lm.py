@@ -35,18 +35,10 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from .. import telemetry
 from ..ops import retention
 from ..ops.paged_attention import PagedState
 from ..parallel.moe import swiglu
 from . import decoder_parts as parts
-
-_M_STATE_LIVE = telemetry.get_registry().histogram(
-    "serve_engine_state_live_slots",
-    "per decode step: slots holding live recurrent state (the active ones: "
-    "the states the retention decode kernel reads and writes, a layer)",
-    buckets=(1, 2, 4, 8, 16, 24, 32, 48, 64, 96, 128, 256),
-)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +92,7 @@ class PowerRetentionLM:
     def observe_step(self, counters) -> None:
         """A decode step's counters, back on the host (the engine fetched
         them with the step's packet)."""
-        _M_STATE_LIVE.observe(int(counters[0]))
+        parts.observe_state_live(counters[0])
 
     def state_spec(self, slots: int):
         lead, d = (slots, self.num_hidden_layers, self.num_key_value_heads), self.head_dim

@@ -232,12 +232,11 @@ class SlidingGqaMoELM:
         return {"k": ring, "v": ring}
 
     def write_rows(self, cache: parts.SlotCache, rows, block_ids) -> parts.SlotCache:
-        return cache._replace(
-            blocks=parts.write_pool_blocks(cache.blocks, rows["blocks"], block_ids))
+        return parts.write_cache_rows(cache, rows, block_ids)
 
     def write_state(self, cache: parts.SlotCache, rows, slot) -> parts.SlotCache:
         """The join's other half: the slot's rings become the prefill's, whole."""
-        return cache._replace(slots=parts.write_slot_rows(cache.slots, rows["slots"], slot))
+        return parts.write_cache_state(cache, rows, slot)
 
     # -------------------------------------------------------------- weights
     def init(self, key) -> Dict:

@@ -907,3 +907,110 @@ def test_sliding_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
     # the causal kernel writes [1, T, 48 x 128], as the output projection reads it
     assert len(re.findall(r"%flash_attention[\w.]* = \(bf16\[1,4096,6144\]", text)) == 3
     assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[40960,", text)) == 8
+
+
+# ---------------------------------------------------------------------------
+# ai21-jamba2-3b in the engine (PR 51): the decode step of 256 slots and the
+# largest prefill of jamba_serve_reasoning, the whole model at the published widths
+# ---------------------------------------------------------------------------
+def _jamba(monkeypatch):
+    import json
+    import os
+
+    from moolib_tpu.models.jamba import JambaLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "ai21-jamba2-3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "chipbench", "traffic", "serve_reasoning.json")) as f:
+        traffic = json.load(f)
+    model = JambaLM.from_config(
+        config, max_len=traffic["positions_per_slot"], **config["uses"]["serve"])
+    return model, jax.eval_shape(model.init, jax.random.key(0)), traffic
+
+
+def test_jamba_decode_step_updates_its_state_in_place_and_copies_no_table(chip, monkeypatch):
+    """6.06 GB of weights (all 28 layers, the tied table once), 2.18 GB of
+    scan state, 0.41 GB of convolution tails and 1.07 GB of K/V pools: the
+    step aliases all 3.66 GB of cache to its outputs and copies no leaf of it
+    (the scan kernel's in-place update survives XLA under the scans over the
+    runs of 7, 13 and 6 Mamba layers; the state leaves lie at their unpadded
+    bytes, 16 states on sublanes); the head contracts against the [65536, 2560]
+    table where it lies: no copy, transpose or convert of it; no run's weights
+    are sliced out of a stack."""
+    from moolib_tpu.models.decoder_parts import SlotCache
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    model, params, traffic = _jamba(monkeypatch)
+    S, bs = traffic["slots"], traffic["block_size"]
+    per = traffic["positions_per_slot"] // bs
+    cache = SlotCache(model.cache_spec(1 + S * per, bs), model.state_spec(S))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    paged = PagedState(i32(S, per), i32(S), jax.ShapeDtypeStruct((S,), jnp.bool_))
+    compiled, text = _compile(
+        jax.jit(model.decode, donate_argnums=(1,)), *_on(chip, (params, cache, i32(S), paged)))
+    nbytes = lambda tree: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    assert 6.05e9 < nbytes(params) < 6.08e9  # bfloat16, but for 5 M float32 scales and rates
+    assert cache.slots["ssm"].shape == (256, 26, 16, 5120) and cache.slots["conv"].shape == (256, 26, 3, 5120)
+    assert nbytes(cache.slots["ssm"]) == 2181038080 and nbytes(cache.slots["conv"]) == 408944640
+    assert nbytes(cache.blocks) == 4 * 4097 * 256 * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(cache)  # every cache leaf is updated where it lies
+    # the leaves at their unpadded bytes: arguments are the weights, the cache
+    # and a few KB of tables (a tail padded 3 -> 8 sublanes would add 0.68 GB)
+    assert mem.argument_size_in_bytes < nbytes(params) + nbytes(cache) + (64 << 20)
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert len(re.findall(r"%ssm_decode[.\d]* = ", text)) == 3  # one call under each run's scan
+    assert len(re.findall(r"%paged_attention[.\d]* = f32\[256,32,128\]", text)) == 2
+    copies = re.findall(r"= (\w+\[[\d,]*\])[^ ]* copy\(", text)
+    state, tail, pool = "f32[256,26,16,5120]", "f32[256,26,3,5120]", "bf16[4097,256,1,128]"
+    assert not {state, tail, pool, "bf16[65536,2560]"} & set(copies)
+    sizes = lambda found: [int(np.prod([int(d) for d in s.split("[")[1][:-1].split(",") if d]))
+                           for s in found]
+    assert max(sizes(copies)) <= 256 * 10240  # nothing of a weight's size
+    for op in ("transpose", "convert"):
+        found = re.findall(rf"= (\w+\[[\d,]*\])[^ ]* {op}\(", text)
+        assert max(sizes(found), default=0) < 2560 * 2560, op
+
+
+def test_jamba_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
+    """A prompt of 2,048 positions (the mix's largest bucket): the chunked
+    scan as ONE kernel under each run's scan over layers (what it makes of a
+    chunk stays in VMEM: exp(dt A) is never written to HBM), flash attention
+    over 20 heads and one K/V head in the two attention layers.  Weights,
+    temporaries and the engine's 3.66 GB of cache stay under the chip's 16 GB."""
+    model, params, traffic = _jamba(monkeypatch)
+    Lb = traffic["prompt_tokens"]["max"]
+    compiled, text = _compile(
+        jax.jit(lambda p, toks, tp: model.prefill(p, toks, tp, traffic["block_size"])),
+        *_on(chip, (params, jax.ShapeDtypeStruct((1, Lb), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 3.67e9 < 15.5e9
+    # one call under each run's scan over layers, its result y [positions, channels]
+    assert len(re.findall(r"%ssm_prefill[.\d]* = \(f32\[2048,5120\]\S* f32\[16,5120\]\S* custom-call", text)) == 3
+    assert len(re.findall(r"%flash_attention[\w.]* = ", text)) == 2
+    # nothing the size of [positions, channels, states] is ever materialised
+    assert not re.findall(r"f32\[2048,5120,16\]|f32\[2048,16,5120\]", text)
+
+
+@pytest.mark.parametrize("bucket", [4096, 32])
+def test_ssm_prefill_kernel_compiles_through_mosaic_at_the_cells_buckets(chip, bucket):
+    """The chunked scan alone at the published widths (5,120 channels of 16
+    states), the largest bucket a slot can hold and the mix's smallest, with a
+    length and a state to start from: a block that does not tile or a kernel
+    over its VMEM is refused here."""
+    from moolib_tpu.ops import selective_scan as ssm
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    x = f32(bucket, 5120)
+    compiled, text = _compile(
+        lambda u, dt, z, A, B, C, D, length, state: ssm.ssm_prefill(
+            u, dt, z, A, B, C, D, length=length, state=state, interpret=False),
+        x, x, x, f32(16, 5120), f32(bucket, 16), f32(bucket, 16), f32(5120),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=chip), f32(16, 5120))
+    assert re.findall(r"%ssm_prefill[.\d]* = \((f32\[[\d,]*\])", text) == [f"f32[{bucket},5120]"]
+    # B and C transposed to [16, positions], nothing else: u, dt, z go in as they are
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * bucket * 16 * 4 + (1 << 20)

@@ -5,7 +5,7 @@ The batch-synchronous baseline (``serving.ServeService`` + a jitted
 finishes, in a dense per-sequence cache sized for the worst case.  This
 engine removes both wastes:
 
-- **Slots, not batches.**  Decode is ONE fixed-shape jitted call over ``S``
+- **Slots, not batches.**  Decode is a fixed-shape jitted call over ``S``
   slots.  A sequence joins a free slot the moment its prefill lands and
   retires the moment it emits EOS or exhausts its token budget — no convoy
   behind a long neighbor.  Slot occupancy, lengths, and block tables are
@@ -38,6 +38,36 @@ host books a step from the packet, never from its mirrors).  A join
 dispatched while a step is in flight lands behind it in the donated chain;
 a slot freed at the fetch of step N is inactive in step N+1, so its blocks
 can be handed to a new request at once.
+
+**The rows of a step are the occupied slots', in tiles of 128.**  A product
+of ``[S, d]`` activations with a weight matrix is bound by reading the weights
+only while ``S`` stays under the chip's ridge (240 operations a byte on a v5e:
+at 256 rows the MXU takes as long as the read), and a slot nobody holds is a
+row of work nobody asked for.  So the step is compiled once for every ROW
+COUNT ``R``: the multiples of 128 below ``S``, and ``S`` itself.  An engine
+of at most 128 slots has one row count, and its one program is the step over
+every slot, with no gather in it; so has an engine whose model does not say
+``decodes_rows`` (below).  Each dispatch picks the smallest ``R`` that holds
+the slots the host's mirrors expect the step to advance.  **The invariant
+that makes this safe: at every dispatch the mirrors' set is a SUPERSET of
+the device's ``active`` at that step.**  A slot leaves the device's set at
+the end of the step that finishes it and the mirrors' set only when that
+step's packet is booked; a join lights the mirror in ``submit``, before its
+jit call is made; a finish by EOS, and a first token that is EOS, are seen
+late by the host alone.  In the ``R``-row program the rows are chosen ON THE
+DEVICE, ``argsort(~active, stable)[:R]``: the active slots in slot order,
+then DISTINCT inactive ones (a fill that named one slot twice would let a
+padded row's write-back race a live row's).  Tokens, lengths, flags and table
+rows are gathered by it (a few KB), the model decodes ``R`` rows, and the
+next tokens are scattered back to ``[S]``; the packet, the lengths, the
+budgets and the flags stay ``[S]``, the host sends no slot list and books a
+step as before.  Should the invariant ever fail, the active slots past the
+first ``R`` simply do not step (their flags, lengths and budgets stand, the
+packet says they were not active): a token late, none wrong; and the packet
+carries the fact home, ``stats()["row_overflows"]`` and
+``serve_engine_row_overflows_total``, which must read 0.
+``serve_engine_decode_rows`` and ``stats()["steps_by_rows"]`` say how often
+each row count ran.
 
 **An admission the host does not wait for.**  ``submit`` dispatches the
 prefill and the join back to back and returns: the join takes the first
@@ -99,14 +129,19 @@ and, for whichever leaves it has:
   whole, so nothing of the slot's last holder is ever read.  It runs in the
   join's jit, in the same donated chain as ``write_rows``: a join dispatched
   behind a step in flight lands behind it.  A retire does no device work;
-- ``decode(params, cache, tokens [S], paged)`` -> (logits [S, V], cache,
+- ``decode(params, cache, tokens [R], paged)`` -> (logits [R, V], cache,
   int32 counters or None), with ``step_counters`` /
-  ``prefill_counters`` their lengths.  ``paged`` is a ``PagedState`` whose
-  ``block_tables`` is None without pools (``lengths`` are the positions,
-  ``active`` the slots that step).  Slot-axis leaves advance for the slots
-  ``paged.active`` names and for no other: a step dispatched ahead may find a
-  slot inactive, and a freed slot's row must stay whatever it is until the
-  next join replaces it.
+  ``prefill_counters`` their lengths.  ``paged`` is a ``PagedState`` of ``R``
+  rows whose ``block_tables`` is None without pools (``lengths`` are the
+  positions, ``active`` the rows that step) and whose ``slots`` is the slot
+  of each row, or None: row i is slot i, ``R`` is ``S``.  That is all a model
+  ever sees unless its class says ``decodes_rows = True`` (beside
+  ``step_counters``): only such a model is handed fewer rows than slots.
+  Pool leaves need nothing for it, since a row's block table stands between
+  it and its K/V; a slot-axis leaf is read and written at ``paged.slots``.
+  Slot-axis leaves advance for the slots of the rows ``paged.active`` names
+  and for no other: a step dispatched ahead may find a slot inactive, and a
+  freed slot's row must stay whatever it is until the next join replaces it.
 
 Counters ride what the host fetches anyway (extra rows of a step's packet,
 extra entries beside a prefill's first token): no copy is added.  What they
@@ -174,6 +209,19 @@ _M_EMPTY_STEPS = _REG.counter(
     "decode steps booked whose packet had no active slot: dispatched ahead "
     "of a finish by EOS that the host could not foresee",
 )
+_M_DECODE_ROWS = _REG.histogram(
+    "serve_engine_decode_rows",
+    "per decode step dispatched: the rows of the program chosen, the smallest "
+    "compiled row count (multiples of 128, then the slots) that holds the "
+    "slots the host's mirrors expect the step to advance",
+    buckets=(128, 256, 384, 512, 768, 1024),
+)
+_M_ROW_OVERFLOWS = _REG.counter(
+    "serve_engine_row_overflows_total",
+    "decode steps that found more active slots on the device than the rows "
+    "they were compiled for: the mirrors were no superset of the device's "
+    "set.  Must read 0",
+)
 _M_SLOTS = _REG.gauge(
     "serve_engine_slots_active", "decode slots currently occupied"
 )
@@ -205,6 +253,7 @@ _M_KV_LIVE = _REG.histogram(
 
 
 _ROWS_AHEAD_BYTES = 1 << 30  # a state's rows that joins dispatched ahead may hold
+_ROW_TILE = 128  # a decode step's rows come in tiles of the MXU's 128 (module docstring)
 
 
 class NoFreeSlot(RuntimeError):
@@ -214,7 +263,8 @@ class NoFreeSlot(RuntimeError):
 class ContinuousBatchingEngine:
     """See module docstring.  Host-side driver owning the device state
     (KV pools, block tables, per-slot lengths/tokens/budgets) and the three
-    jitted paths: bucketed prefill, donated join, fixed-shape decode step.
+    jitted paths: bucketed prefill, donated join, fixed-shape decode step (one
+    program a row count).
 
     Single-threaded by contract: one loop (``EngineService``) calls
     ``submit``/``step``/``retire``; only ``set_params`` and the read-only
@@ -257,6 +307,15 @@ class ContinuousBatchingEngine:
         self.eos_id = eos_id
         self._n_step_counters = getattr(model, "step_counters", 0)
         self._n_prefill_counters = getattr(model, "prefill_counters", 0)
+        # The row counts the decode step is compiled for, ascending (module
+        # docstring): one, the slots, unless the model decodes rows and there
+        # are more slots than a tile.
+        self._row_counts: Tuple[int, ...] = (self.slots,)
+        if getattr(model, "decodes_rows", False):
+            self._row_counts = tuple(range(_ROW_TILE, self.slots, _ROW_TILE)) + (self.slots,)
+        # With more than one, the engine's own counter rides the packet behind
+        # the model's: did the step find more active slots than it had rows.
+        self._n_packet_counters = self._n_step_counters + (len(self._row_counts) > 1)
 
         self.set_params(params)
 
@@ -312,16 +371,18 @@ class ContinuousBatchingEngine:
         self._stats = {
             "joins": 0, "joins_ahead": 0, "retires": 0, "decode_tokens": 0,
             "prefill_tokens": 0, "prefill_pad_tokens": 0, "steps": 0,
-            "steps_ahead": 0, "empty_steps": 0,
+            "steps_ahead": 0, "empty_steps": 0, "row_overflows": 0,
+            "steps_by_rows": {rows: 0 for rows in self._row_counts},
         }
 
-        # devmon wrappers: the decode step must stay ONE compile for the
-        # engine's lifetime (tests assert _cache_size, which forwards
-        # through the wrapper); prefill/join legitimately compile per
-        # bucket, and the detector's flight events name any trace beyond
-        # that contract.
+        # devmon wrappers: the decode step must stay ONE compile A ROW COUNT
+        # for the engine's lifetime (``rows`` is static; tests assert
+        # _cache_size, which forwards through the wrapper); prefill/join
+        # legitimately compile per bucket, and the detector's flight events
+        # name any trace beyond that contract.
         self._step_jit = devmon.instrument_jit(
-            jax.jit(self._step_impl, donate_argnums=(1, 2, 3, 4, 5, 6)),
+            jax.jit(self._step_impl, donate_argnums=(1, 2, 3, 4, 5, 6),
+                    static_argnums=(7,)),
             "engine.step",
         )
         # Prefill/join jits cache by shape: one trace per prompt bucket
@@ -343,27 +404,45 @@ class ContinuousBatchingEngine:
 
     # ------------------------------------------------------------ jit bodies
     def _step_impl(self, params, cache, tables, lengths, active, tokens,
-                   remaining):
-        logits, cache, counters = self.model.decode(
-            params, cache, tokens, PagedState(tables, lengths, active))
-        act = active.astype(jnp.int32)
+                   remaining, rows):
+        """The step over ``rows`` rows (static): every slot, or the first
+        ``rows`` of the active slots first (module docstring).  ``stepping``
+        [S] are the slots this step advances: the active ones, all of them
+        unless the mirrors' invariant failed."""
+        S = self.slots
+        slot, stepping, row_tokens = None, active, tokens
+        paged = PagedState(tables, lengths, active)
+        if rows < S:
+            slot = jnp.argsort(~active, stable=True)[:rows].astype(jnp.int32)
+            row_tokens = tokens[slot]
+            paged = PagedState(None if tables is None else tables[slot],
+                               lengths[slot], active[slot], slot)
+            stepping = jnp.zeros_like(active).at[slot].set(paged.active, unique_indices=True)
+        logits, cache, counters = self.model.decode(params, cache, row_tokens, paged)
+        act = stepping.astype(jnp.int32)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(active, nxt, tokens)
+        nxt = jnp.where(paged.active, nxt, row_tokens)
+        if slot is not None:
+            nxt = tokens.at[slot].set(nxt, unique_indices=True)
         lengths = lengths + act
         remaining = remaining - act
-        done = active & (remaining <= 0)
+        done = stepping & (remaining <= 0)
         if self.eos_id is not None:
-            done = done | (active & (nxt == self.eos_id))
+            done = done | (stepping & (nxt == self.eos_id))
         # What the host reads of this step.  Not donated: ``nxt`` is consumed
         # by the next step, which may be dispatched before the host looks.
         packet = jnp.stack([nxt, act, done.astype(jnp.int32)])
-        if self._n_step_counters:
-            # The model's counters ride the packet: whole rows after the three.
-            S = self.slots
-            rows = -(-self._n_step_counters // S)
-            counters = jnp.pad(counters.astype(jnp.int32),
-                               (0, rows * S - self._n_step_counters))
-            packet = jnp.concatenate([packet, counters.reshape(rows, S)])
+        if self._n_packet_counters:
+            # Counters ride the packet, whole rows after the three: the
+            # model's, then with several row counts the engine's own.
+            counters = (counters.astype(jnp.int32) if self._n_step_counters
+                        else jnp.zeros((0,), jnp.int32))
+            if len(self._row_counts) > 1:
+                overflow = jnp.sum(active, dtype=jnp.int32) > rows
+                counters = jnp.concatenate([counters, overflow.astype(jnp.int32)[None]])
+            extra = -(-self._n_packet_counters // S)
+            counters = jnp.pad(counters, (0, extra * S - self._n_packet_counters))
+            packet = jnp.concatenate([packet, counters.reshape(extra, S)])
         active = active & ~done
         return cache, tables, lengths, active, nxt, remaining, packet
 
@@ -559,7 +638,7 @@ class ContinuousBatchingEngine:
         return True
 
     def step(self) -> Tuple[Dict[int, int], List[int]]:
-        """Book ONE fixed-shape decode step over every slot, the oldest not
+        """Book ONE fixed-shape decode step, the oldest not
         yet booked.  Returns the tokens it emitted (slot -> token) and the
         slots that finished; ``{}, []`` when nothing is active.  The step
         after it is dispatched before this one's tokens are waited for, and
@@ -569,23 +648,36 @@ class ContinuousBatchingEngine:
         with telemetry.span("engine.step"):
             return self._step()
 
-    def _launch(self) -> jax.Array:
-        """The decode jit's call, and the start of its packet's copy to the
-        host.  Returns the packet."""
+    def _launch(self, rows: int) -> jax.Array:
+        """The decode jit's call over ``rows`` rows, and the start of its
+        packet's copy to the host.  Returns the packet."""
         (self._cache, self._tables, self._lengths, self._active,
          self._tokens, self._remaining, packet) = self._step_jit(
             self._params, self._cache, self._tables, self._lengths,
-            self._active, self._tokens, self._remaining,
+            self._active, self._tokens, self._remaining, rows,
         )
         packet.copy_to_host_async()
         return packet
+
+    def _rows_for(self, stepping: np.ndarray) -> int:
+        """The smallest compiled row count that holds ``stepping``, the slots
+        the mirrors expect the step about to be dispatched to advance.  They
+        are a superset of the device's ``active`` at that step (module
+        docstring), so the count bounds the device's."""
+        if len(self._row_counts) == 1:
+            return self.slots
+        need = int(stepping.sum())  # mtlint: allow-host-sync(host-side numpy mirror)
+        return next(rows for rows in self._row_counts if rows >= need)
 
     def _dispatch(self, stepping: np.ndarray) -> None:
         """Put a step in flight that the mirrors expect to advance
         ``stepping``."""
         t0 = time.monotonic()
         with telemetry.span("engine.step_dispatch"):
-            self._flight = self._launch(), stepping
+            rows = self._rows_for(stepping)
+            self._flight = self._launch(rows), stepping
+        self._stats["steps_by_rows"][rows] += 1
+        _M_DECODE_ROWS.observe(rows)
         _M_PHASE.observe(time.monotonic() - t0, phase="dispatch")
 
     def _step(self):
@@ -621,9 +713,12 @@ class ContinuousBatchingEngine:
                 _M_ROWS_LIVE.observe(
                     int((self._lengths_host[stepped] + 1).sum())  # mtlint: allow-host-sync(host-side numpy mirror)
                     / (self.slots * self.seq_capacity))
+            counters = packet[3:].reshape(-1)
             if self._n_step_counters and len(stepped):
-                self.model.observe_step(
-                    packet[3:].reshape(-1)[:self._n_step_counters])
+                self.model.observe_step(counters[:self._n_step_counters])
+            if len(self._row_counts) > 1 and counters[self._n_step_counters]:
+                self._stats["row_overflows"] += 1
+                _M_ROW_OVERFLOWS.inc()
             self._lengths_host[stepped] += 1
             for s in stepped:
                 tok = int(nxt[s])
@@ -669,7 +764,8 @@ class ContinuousBatchingEngine:
 
     # ---------------------------------------------------------------- warmup
     def warmup(self) -> int:
-        """Compile every shape serving can hit: the decode step, one prefill
+        """Compile every shape serving can hit: the decode step at every row
+        count, one prefill
         per prompt bucket, one join per block-count bucket (one join in all
         without pools: a state's shape does not follow the prompt's).  Warmup
         joins target the null block with a zero budget, so the single decode
@@ -695,12 +791,13 @@ class ContinuousBatchingEngine:
                 rows, None if nbw is None else np.zeros(nbw, np.int32),
             )
             shapes += 1
-        # One real step compiles the decode path and clears the warmup joins
-        # (zero budget -> done immediately; writes landed in the null block).
-        # It is launched and fetched as the loop does it, booked nowhere, and
-        # leaves no step in flight.
-        np.asarray(self._launch())  # mtlint: allow-host-sync(warm-up, outside the decode loop: the packet's first D2H)
-        return shapes + 1
+        # One real step a row count compiles the decode path; the first clears
+        # the warmup joins (zero budget -> done immediately; writes landed in
+        # the null block).  Each is launched and fetched as the loop does it,
+        # booked nowhere, and leaves no step in flight.
+        for rows in self._row_counts:
+            np.asarray(self._launch(rows))  # mtlint: allow-host-sync(warm-up, outside the decode loop: the packet's first D2H)
+        return shapes + len(self._row_counts)
 
     def close(self) -> None:
         """Drop the step in flight, if any, unbooked: its tokens are never
@@ -710,7 +807,7 @@ class ContinuousBatchingEngine:
 
     # ----------------------------------------------------------------- stats
     def stats(self) -> Dict[str, Any]:
-        out = dict(self._stats)
+        out = dict(self._stats, steps_by_rows=dict(self._stats["steps_by_rows"]))
         if self.pool is not None:
             out.update(self.pool.stats())
         if self._slot_state:

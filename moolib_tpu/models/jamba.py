@@ -23,14 +23,20 @@ model offers the serving engine both kinds of cache leaf (``engine/engine.py``):
   every Mamba layer ``[slots, mamba_layers, d_state, d_inner]`` float32 (the
   states on sublanes, the channels on lanes: as ``[.., d_inner, 16]`` the leaf
   would be padded eightfold in HBM) and the convolution's tail, the last
-  three inputs, ``[slots, mamba_layers, 3, d_inner]``;
+  three inputs, ``[slots, mamba_layers, 3 d_inner / 128, 128]``: tap t in rows
+  ``t d_inner / 128`` onwards, so that a slot's tail of a layer is one
+  contiguous block of whole tiles (``ops.selective_scan.conv_tail_write``);
 - :meth:`prefill` hands back the K/V rows with the state after position
   ``tp - 1`` and the tail there (a bucket's padding has the step ``dt``
   zeroed, so it moves nothing); :meth:`write_rows` scatters the former by
   block and :meth:`write_state` overwrites the slot's row with the latter,
   whatever the slot's last holder left there;
-- :meth:`decode`: one token a slot; the state and the tail of ACTIVE slots
-  advance in place, the others' are left as they are.
+- :meth:`decode`: one token a ROW; the state and the tail of ACTIVE rows'
+  slots advance in place, the others' are left as they are.  The rows are the
+  slots, or fewer (:data:`JambaLM.decodes_rows`): ``paged.slots`` then names
+  each row's slot, the tails of those slots alone are gathered and written
+  back (the active rows' alone, by ``conv_tail_write``), and the scan kernel
+  reads a row's state at its slot.
 
 Precision: weights and matmul inputs in ``dtype`` (bfloat16), products
 accumulated in float32; the residual stream, every RMSNorm, the convolution
@@ -46,7 +52,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.layout import Layout, with_layout_constraint
 
 from .. import telemetry
 from ..ops import selective_scan as ssm
@@ -86,6 +91,7 @@ class JambaLM:
 
     step_counters = 1  # slots holding live state
     prefill_counters = 1  # the real positions the prefill's scan ran over
+    decodes_rows = True  # decode addresses its slot-axis leaves through paged.slots
 
     @classmethod
     def from_config(cls, config, **overrides) -> "JambaLM":
@@ -166,7 +172,8 @@ class JambaLM:
         lead = (slots, self.mamba_layers)
         return {
             "ssm": jax.ShapeDtypeStruct(lead + (self.mamba_d_state, self.d_inner), jnp.float32),
-            "conv": jax.ShapeDtypeStruct(lead + (_CONV - 1, self.d_inner), jnp.float32),
+            "conv": jax.ShapeDtypeStruct(
+                lead + ((_CONV - 1) * self.d_inner // ssm.LANES, ssm.LANES), jnp.float32),
         }
 
     def write_rows(self, cache: parts.SlotCache, rows, block_ids) -> parts.SlotCache:
@@ -321,37 +328,43 @@ class JambaLM:
             return tuple(parts.rows_to_blocks(x, block_size, axis=0) for x in xs)
 
         rows = {"blocks": {"k": blocks(ks), "v": blocks(vs)},
-                "slots": {"ssm": state, "conv": tail}}
+                "slots": {"ssm": state, "conv": tail.reshape(tail.shape[0], -1, ssm.LANES)}}
         return (rows, self._head(params, jnp.take(h, tp - 1, axis=0)),
                 jnp.asarray(tp, jnp.int32).reshape(1))
 
     # -------------------------------------------------------------- decode
-    def _mamba_decode(self, p, h, state, conv, layer, active):
-        """One token a slot through a Mamba layer's mixer: h [S, D], the state
-        and tail leaves whole, ``layer`` this layer's index into them."""
-        Ci = self.d_inner
+    def _mamba_decode(self, p, h, state, conv, layer, paged):
+        """One token a row through a Mamba layer's mixer: h [R, D], the state
+        and tail leaves whole, ``layer`` this layer's index into them,
+        ``paged.active`` [R] the rows that step and ``paged.slots`` [R] each
+        row's slot in the leaves (None: row i is slot i).  (The step's
+        ``PagedState`` whole, not its two fields: a subclass that wraps this
+        method hands it on unread.)"""
+        Ci, R = self.d_inner, h.shape[0]
+        active, slots = paged.active, paged.slots
         uz = self._dot(self._norm(h, p["mixer_norm"]), p["w_in"])
-        # The tail's leaf through every run's scan as the chip holds it: a
-        # float32 [slots, layers, 3, d_inner] lies on a TPU with the 3 outside
-        # the (8, 128) tiles, [layers, 3, slots, d_inner], unpadded.  Left to
-        # itself XLA keeps that under the first run's scan and the row-major
-        # order under the others, and copies the leaf whole between them twice
-        # a step (0.8 GB moved; the step's temporaries 550 MB, now 2 MB).
-        conv = with_layout_constraint(conv, Layout(major_to_minor=(1, 2, 0, 3)))
-        tail = jax.lax.dynamic_index_in_dim(conv, layer, 1, keepdims=False)
-        window = jnp.concatenate([tail, uz[:, None, :Ci]], axis=1)  # [S, 4, d_inner]
-        u = jax.nn.silu(jnp.sum(window * p["conv"], axis=1) + p["conv_bias"])
-        tail = jnp.where(active[:, None, None], window[:, 1:], tail)
-        conv = jax.lax.dynamic_update_index_in_dim(conv, tail, layer, 1)
+        # The tails the step needs, in the leaf's own tiles ([.., 3, channels /
+        # 128, 128]: nothing of them is laid out anew): the layer's, or with
+        # fewer rows than slots the rows' slots' alone.
+        if slots is None:
+            tail = jax.lax.dynamic_index_in_dim(conv, layer, 1, keepdims=False)
+        else:
+            tail = conv[slots, layer]
+        tiles = lambda x, *lead: x.reshape(lead + (Ci // ssm.LANES, ssm.LANES))
+        window = jnp.concatenate(  # [R, 4, channels / 128, 128]
+            [tiles(tail, R, _CONV - 1), tiles(uz[:, :Ci], R, 1)], axis=1)
+        u = jax.nn.silu(jnp.sum(window * tiles(p["conv"], _CONV), axis=1) + tiles(p["conv_bias"]))
+        u = u.reshape(R, Ci)
+        conv = ssm.conv_tail_write(conv, window[:, 1:].reshape(tail.shape), layer, active, slots)
         dt, B, C, A = self._scan_inputs(p, u)
-        y, state = ssm.ssm_decode(u, dt, A, B, C, state, layer, active)
+        y, state = ssm.ssm_decode(u, dt, A, B, C, state, layer, active, slots)
         y = (y + p["d"] * u) * jax.nn.silu(uz[:, Ci:])
         return h + self._dot(y, p["w_out"]), state, conv
 
     def decode(self, params, cache: parts.SlotCache, tokens, paged: PagedState, mesh=None):
-        """One token a slot.  tokens [S]; returns (logits [S, V] float32, the
-        cache with this step's K/V written and the active slots' state and
-        tail advanced, counters: the slots holding live state)."""
+        """One token a row.  tokens [R]; returns (logits [R, V] float32, the
+        cache with this step's K/V written and the active rows' state and
+        tail advanced at their slots, counters: the slots holding live state)."""
         if mesh is not None:
             raise ValueError("the Mamba decoder runs on one device")
         active = paged.active
@@ -362,7 +375,7 @@ class JambaLM:
         def body(carry, xs):
             h, state, conv = carry
             p, layer = xs
-            h, state, conv = self._mamba_decode(p, h, state, conv, layer, active)
+            h, state, conv = self._mamba_decode(p, h, state, conv, layer, paged)
             return (self._ffn(p, h), state, conv), None
 
         first = 0

@@ -61,7 +61,7 @@ step over all S slots never branches on occupancy.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -100,11 +100,18 @@ class PagedState(NamedTuple):
         step (fixed shape); their writes land in the null block, the
         attention kernel skips them, and their outputs are ignored by the
         engine.
+    slots: int32 [R] or None — the slot of each ROW, where the step runs over
+        fewer rows than the engine has slots (``engine/engine.py``): the
+        three fields above then have R rows, distinct slots all, the active
+        ones first.  Pool leaves need nothing of it (a row's table stands
+        between it and its K/V); a leaf with a slot axis is read and written
+        at ``slots``.  None: row i is slot i.
     """
 
     block_tables: jax.Array
     lengths: jax.Array
     active: jax.Array
+    slots: Optional[jax.Array] = None
 
 
 def gathered_decode_attention(q, k_ctx, v_ctx, t):

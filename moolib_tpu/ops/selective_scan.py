@@ -22,19 +22,35 @@ sublanes.
 
 :func:`ssm_decode` is one Pallas (Mosaic) call a layer for a decode step,
 named ``ssm_decode``.  The state leaf ``[slots, layers, N, C]`` stays in HBM
-and is input-output aliased; the kernel walks the ACTIVE slots alone (their
+and is input-output aliased; the kernel walks the ACTIVE rows alone (their
 order and count in SMEM, as ``kda_decode`` and the paged attention kernel have
-them), copies a slot's ``[N, C]`` block of the layer into one of two VMEM
+them, and beside the order the SLOT of each: a step may run over fewer rows
+than the leaf has slots, and then reads what a row brings by row and its state
+by slot), copies a slot's ``[N, C]`` block of the layer into one of two VMEM
 buffers while the slot before it is computed, updates it there and copies it
 back: a slot nobody holds is neither read nor written, and the loop has as
 many turns as slots are live (a grid over all slots would pay a grid step
 for every empty one, 256 times a layer).  The layer is an index
 into the leaf, so nothing of it is sliced out under a scan over layers.  What
 a slot brings to the step (``dt``, ``dt u``) and takes away (``y``) is laid as
-``[slots, C / 128, 128]``, one contiguous copy a slot; ``B_t`` and ``C_t`` of
-all slots are one small ``[2 N, slots]`` operand in VMEM, of which a slot's
+``[rows, C / 128, 128]``, one contiguous copy a row; ``B_t`` and ``C_t`` of
+all rows are one small ``[2 N, rows]`` operand in VMEM, of which a row's
 column is taken by a masked sum.  :func:`ssm_step` is the same step in
 ``jax.numpy``, which the tests hold the kernel to.
+
+:func:`conv_tail_write` is the other thing a Mamba layer's decode step keeps
+a slot: the short convolution's TAIL, its last three inputs.  The leaf is
+``[slots, layers, 3 C / 128, 128]`` float32: a slot's tail of a layer is one
+contiguous block of whole (8, 128) tiles (60 KB at 5,120 channels), tap t in
+rows ``t C / 128`` onwards.  (As ``[slots, layers, 3, C]`` the chip laid the 3
+outside the tiles and the SLOTS on the sublanes, so one slot's tail was 120
+pieces of 512 bytes, and a scatter of 128 slots' tails cost 4.1 ms a step
+where rewriting all 256 cost 1.3: PERF.md, PR 52.)  The step reads the tails
+it needs in XLA (a slice of the layer, or a gather of the rows' slots) and
+this kernel, named ``conv_tail_write``, writes the ACTIVE rows' new tails
+back where they lie: one HBM-to-HBM copy a row, eight in flight, the leaf
+aliased; a slot nobody holds, or no row names, is not touched.
+:func:`tail_step` is the same in ``jax.numpy``.
 
 :func:`ssm_prefill` is the prefill, one Pallas (Mosaic) call a layer named
 ``ssm_prefill``: the grid runs over blocks of channels (parallel: channels do
@@ -69,7 +85,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 128  # positions a grid step of the prefill; a shorter sequence is one chunk
-_LANES = 128
+LANES = 128  # a vector register's lanes: the channels come in tiles of them
 _PREFILL_CHANNELS = 512  # channels a grid step: the state of a block is 8 vector registers
 
 
@@ -93,44 +109,48 @@ def selective_scan_reference(u, dt, A, B, C, D, state):
 # --------------------------------------------------------------------------
 
 
-def ssm_step(u, dt, A, B, C, state, layer, active):
-    """:func:`ssm_decode` in ``jax.numpy``: one token a slot.  u, dt: [S, C];
-    A: [N, C] (transposed, as the state is: module docstring); B, C: [S, N];
-    state: [S, L, N, C] float32; active: [S] bool.  Returns (y [S, C] without
-    the ``D u`` term, 0 for slots that are not active; the state with
-    ``layer``'s rows of the active slots advanced)."""
-    h = state[:, layer]
+def ssm_step(u, dt, A, B, C, state, layer, active, slots=None):
+    """:func:`ssm_decode` in ``jax.numpy``: one token a row.  u, dt: [R, C];
+    A: [N, C] (transposed, as the state is: module docstring); B, C: [R, N];
+    state: [S, L, N, C] float32; active: [R] bool; slots: [R] int32, the slot
+    of each row, distinct (None: row i is slot i).  Returns (y [R, C] without
+    the ``D u`` term, 0 for rows that are not active; the state with
+    ``layer``'s rows of the active rows' slots advanced)."""
+    slots = jnp.arange(u.shape[0]) if slots is None else slots
+    h = state[slots, layer]
     new = jnp.exp(dt[:, None, :] * A) * h + (dt * u)[:, None, :] * B[:, :, None]
     y = jnp.sum(new * C[:, :, None], axis=1)
     keep = active[:, None, None]
-    return jnp.where(active[:, None], y, 0.0), state.at[:, layer].set(jnp.where(keep, new, h))
+    return (jnp.where(active[:, None], y, 0.0),
+            state.at[slots, layer].set(jnp.where(keep, new, h)))
 
 
-def _ssm_decode_kernel(order_ref, count_ref, layer_ref, a_ref, bc_ref, x_hbm, h_hbm,
+def _ssm_decode_kernel(row_ref, slot_ref, count_ref, layer_ref, a_ref, bc_ref, x_hbm, h_hbm,
                        y_hbm, ho_hbm, h_buf, x_buf, y_buf, sem):
     # ``ho_hbm`` is the state's leaf again (output 1 is aliased to ``h_hbm``): a
     # slot is read through the one name and written through the other, once.
+    # The i-th turn is row ``row_ref[i]``, whose state is slot ``slot_ref[i]``'s.
     N = a_ref.shape[0]
-    S = bc_ref.shape[1]
+    R = bc_ref.shape[1]
     tiles = x_buf.shape[2]  # C / 128
     count, layer = count_ref[0], layer_ref[0]
 
     def copies_in(i, buf):
-        s = order_ref[i]
+        r, s = row_ref[i], slot_ref[i]
         return (pltpu.make_async_copy(h_hbm.at[s, layer], h_buf.at[buf], sem.at[0, buf]),
-                pltpu.make_async_copy(x_hbm.at[s], x_buf.at[buf], sem.at[1, buf]))
+                pltpu.make_async_copy(x_hbm.at[r], x_buf.at[buf], sem.at[1, buf]))
 
     def copies_out(i, buf):
-        s = order_ref[i]
+        r, s = row_ref[i], slot_ref[i]
         return (pltpu.make_async_copy(h_buf.at[buf], ho_hbm.at[s, layer], sem.at[2, buf]),
-                pltpu.make_async_copy(y_buf.at[buf], y_hbm.at[s], sem.at[3, buf]))
+                pltpu.make_async_copy(y_buf.at[buf], y_hbm.at[r], sem.at[3, buf]))
 
     @pl.when(count > 0)
     def _():
         for copy in copies_in(0, 0):
             copy.start()
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (2 * N, S), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (2 * N, R), 1)
 
     def slot(i, carry):
         buf = i % 2
@@ -147,12 +167,12 @@ def _ssm_decode_kernel(order_ref, count_ref, layer_ref, a_ref, bc_ref, x_hbm, h_
 
         for copy in copies_in(i, buf):
             copy.wait()
-        # the slot's B | C: its column of [2 N, S], the states down the sublanes
-        col = jnp.sum(jnp.where(lane == order_ref[i], bc_ref[...], 0.0), axis=1, keepdims=True)
-        b = jnp.broadcast_to(col[:N], (N, _LANES))
-        c = jnp.broadcast_to(col[N:], (N, _LANES))
+        # the row's B | C: its column of [2 N, R], the states down the sublanes
+        col = jnp.sum(jnp.where(lane == row_ref[i], bc_ref[...], 0.0), axis=1, keepdims=True)
+        b = jnp.broadcast_to(col[:N], (N, LANES))
+        c = jnp.broadcast_to(col[N:], (N, LANES))
         for j in range(tiles):  # 128 channels at a time: two registers of state
-            at = slice(j * _LANES, (j + 1) * _LANES)
+            at = slice(j * LANES, (j + 1) * LANES)
             dt = x_buf[buf, 0, j:j + 1, :]
             h = jnp.exp(dt * a_ref[:, at]) * h_buf[buf, :, at] + x_buf[buf, 1, j:j + 1, :] * b
             h_buf[buf, :, at] = h
@@ -169,54 +189,133 @@ def _ssm_decode_kernel(order_ref, count_ref, layer_ref, a_ref, bc_ref, x_hbm, h_
             copy.wait()
 
 
+def _turns(name, active, slots, leaf):
+    """What a decode kernel walks, for SMEM: the active rows in row order,
+    the slot of each (the row itself without ``slots``), and their count."""
+    if slots is None and leaf.shape[0] != active.shape[0]:
+        raise ValueError(f"{name}: {active.shape[0]} rows over a leaf of {leaf.shape[0]} slots "
+                         "need each row's slot")
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    where = order if slots is None else slots.astype(jnp.int32)[order]
+    return order, where, jnp.sum(active, dtype=jnp.int32).reshape(1)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssm_decode(u, dt, A, B, C, state, layer, active, *, interpret=None):
-    """One decode step of one layer over every slot: shapes as
+def ssm_decode(u, dt, A, B, C, state, layer, active, slots=None, *, interpret=None):
+    """One decode step of one layer over the step's rows: shapes as
     :func:`ssm_step`, ``layer`` a traced index into the state's layer axis
     (under a scan a sliced ``state[:, layer]`` would be copied whole each
     iteration).  The state is updated in place where the caller donates it (it
-    is aliased to the kernel's output); the rows of ``y`` of slots that are not
-    ``active`` are 0 and their state is neither read nor written.  One kernel,
-    named ``ssm_decode`` in the profiler's trace.  C a multiple of the 128
-    lanes."""
+    is aliased to the kernel's output); the rows of ``y`` that are not
+    ``active`` are 0 and their slots' state is neither read nor written, nor is
+    that of a slot no row names.  One kernel, named ``ssm_decode`` in the
+    profiler's trace.  C a multiple of the 128 lanes."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    S, channels = u.shape
+    R, channels = u.shape
     N = A.shape[0]
-    if channels % _LANES or state.shape[2:] != (N, channels):
+    if channels % LANES or state.shape[2:] != (N, channels):
         raise ValueError(
             f"ssm_decode wants a state [slots, layers, {N}, channels] with the channels a "
-            f"multiple of {_LANES}, got {state.shape} for {channels} channels")
+            f"multiple of {LANES}, got {state.shape} for {channels} channels")
+    order, where, count = _turns("ssm_decode", active, slots, state)
     f32 = lambda a: a.astype(jnp.float32)
-    tiles = channels // _LANES
-    x = jnp.stack([f32(dt), f32(dt) * f32(u)], axis=1).reshape(S, 2, tiles, _LANES)
-    bc = jnp.concatenate([f32(B), f32(C)], axis=1).T  # [2 N, S]
-    # The kernel visits the active slots only, in slot order.
-    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
-    count = jnp.sum(active, dtype=jnp.int32).reshape(1)
+    tiles = channels // LANES
+    x = jnp.stack([f32(dt), f32(dt) * f32(u)], axis=1).reshape(R, 2, tiles, LANES)
+    bc = jnp.concatenate([f32(B), f32(C)], axis=1).T  # [2 N, R]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     with jax.named_scope("ssm_decode"):
         y, state = pl.pallas_call(
             _ssm_decode_kernel,
-            out_shape=[jax.ShapeDtypeStruct((S, tiles, _LANES), jnp.float32),
+            out_shape=[jax.ShapeDtypeStruct((R, tiles, LANES), jnp.float32),
                        jax.ShapeDtypeStruct(state.shape, state.dtype)],
-            in_specs=[smem, smem, smem, vmem, vmem, hbm, hbm],
+            in_specs=[smem, smem, smem, smem, vmem, vmem, hbm, hbm],
             out_specs=[hbm, hbm],
             scratch_shapes=[
                 pltpu.VMEM((2, N, channels), jnp.float32),
-                pltpu.VMEM((2, 2, tiles, _LANES), jnp.float32),
-                pltpu.VMEM((2, tiles, _LANES), jnp.float32),
+                pltpu.VMEM((2, 2, tiles, LANES), jnp.float32),
+                pltpu.VMEM((2, tiles, LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA((4, 2)),
             ],
-            # Operand 6 of the call is the state, and comes back as output 1.
-            input_output_aliases={6: 1},
+            # Operand 7 of the call is the state, and comes back as output 1.
+            input_output_aliases={7: 1},
             interpret=interpret,
             name="ssm_decode",
-        )(order, count, jnp.asarray(layer, jnp.int32).reshape(1), f32(A), bc, x, state)
-    # a slot the loop never visited left its rows of y as they were: anything
-    return jnp.where(active[:, None], y.reshape(S, channels), 0.0), state
+        )(order, where, count, jnp.asarray(layer, jnp.int32).reshape(1), f32(A), bc, x, state)
+    # a row the loop never visited left its row of y as it was: anything
+    return jnp.where(active[:, None], y.reshape(R, channels), 0.0), state
+
+
+# --------------------------------------------------------------------------
+# decode: the convolution's tail, the active rows' written back in place
+# --------------------------------------------------------------------------
+
+_TAIL_COPIES = 8  # copies in flight
+
+
+def tail_step(conv, new, layer, active, slots=None):
+    """:func:`conv_tail_write` in ``jax.numpy``.  conv: [S, L, 3 C / 128, 128]
+    float32; new: [R, 3 C / 128, 128], the rows' tails after this step;
+    active: [R] bool; slots: [R] int32, distinct (None: row i is slot i).
+    Returns conv with ``layer``'s tail of the active rows' slots replaced."""
+    slots = jnp.arange(new.shape[0]) if slots is None else slots
+    old = conv[slots, layer]
+    return conv.at[slots, layer].set(jnp.where(active[:, None, None], new, old))
+
+
+def _tail_write_kernel(row_ref, slot_ref, count_ref, layer_ref, new_hbm, conv_hbm, out_hbm, sem):
+    # ``out_hbm`` is the leaf again (aliased to ``conv_hbm``, which is not read).
+    del conv_hbm
+    count, layer = count_ref[0], layer_ref[0]
+
+    def copy(i):
+        return pltpu.make_async_copy(
+            new_hbm.at[row_ref[i]], out_hbm.at[slot_ref[i], layer], sem.at[i % _TAIL_COPIES])
+
+    def turn(i, carry):
+        @pl.when(i >= _TAIL_COPIES)
+        def _():  # its semaphore is the copy's of eight turns ago
+            copy(i - _TAIL_COPIES).wait()
+
+        copy(i).start()
+        return carry
+
+    jax.lax.fori_loop(0, count, turn, 0)
+    for j in range(_TAIL_COPIES):
+        @pl.when(count > j)
+        def _():
+            copy(count - 1 - j).wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def conv_tail_write(conv, new, layer, active, slots=None, *, interpret=None):
+    """The active rows' new tails into the leaf, in place where the caller
+    donates it: shapes as :func:`tail_step`, ``layer`` a traced index.  One
+    kernel, named ``conv_tail_write`` in the profiler's trace; it computes
+    nothing."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if conv.shape[2:] != new.shape[1:] or new.shape[2] != LANES:
+        raise ValueError(
+            f"conv_tail_write wants tails [rows, 3 channels / {LANES}, {LANES}] for a leaf "
+            f"[slots, layers, 3 channels / {LANES}, {LANES}], got {new.shape} for {conv.shape}")
+    order, where, count = _turns("conv_tail_write", active, slots, conv)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    with jax.named_scope("conv_tail_write"):
+        return pl.pallas_call(
+            _tail_write_kernel,
+            out_shape=jax.ShapeDtypeStruct(conv.shape, conv.dtype),
+            in_specs=[smem, smem, smem, smem, hbm, hbm],
+            out_specs=hbm,
+            scratch_shapes=[pltpu.SemaphoreType.DMA((_TAIL_COPIES,))],
+            # Operand 5 of the call is the leaf, and comes back as the output.
+            input_output_aliases={5: 0},
+            interpret=interpret,
+            name="conv_tail_write",
+        )(order, where, count, jnp.asarray(layer, jnp.int32).reshape(1), new.astype(conv.dtype), conv)
 
 
 # --------------------------------------------------------------------------
@@ -271,7 +370,7 @@ def ssm_prefill(u, dt, z, A, B, C, D, *, length=None, state=None, interpret=None
     T, channels = u.shape
     N = A.shape[0]
     cb = min(channels, _PREFILL_CHANNELS)
-    if channels % cb or (not interpret and cb % _LANES):
+    if channels % cb or (not interpret and cb % LANES):
         raise ValueError(
             f"ssm_prefill: {channels} channels are not blocks of {cb} on whole lanes")
     chunk = CHUNK if T >= CHUNK else -(-T // 8) * 8

@@ -746,7 +746,7 @@ def test_hybrid_warmup_builds_eleven_programs_for_the_longgen_mix(monkeypatch):
     calls = {"prefill": [], "join": []}
     engine._prefill_jit = lambda params, toks, tp: (calls["prefill"].append(toks.shape[1]), None)
     engine._join_jit = lambda *a: (calls["join"].append(len(a[-1])), a[:6])[1]
-    engine._launch = lambda: np.zeros(1)
+    engine._launch = lambda rows: np.zeros(1)
     assert engine.warmup() == 11
     assert calls["prefill"] == [256, 512, 1024, 2048, 4096]
     assert calls["join"] == [2, 4, 8, 16, 32]
@@ -930,7 +930,8 @@ def _jamba(monkeypatch):
     return model, jax.eval_shape(model.init, jax.random.key(0)), traffic
 
 
-def test_jamba_decode_step_updates_its_state_in_place_and_copies_no_table(chip, monkeypatch):
+@pytest.mark.parametrize("rows", [256, 128])
+def test_jamba_decode_step_updates_its_state_in_place_and_copies_no_table(chip, monkeypatch, rows):
     """6.06 GB of weights (all 28 layers, the tied table once), 2.18 GB of
     scan state, 0.41 GB of convolution tails and 1.07 GB of K/V pools: the
     step aliases all 3.66 GB of cache to its outputs and copies no leaf of it
@@ -938,7 +939,12 @@ def test_jamba_decode_step_updates_its_state_in_place_and_copies_no_table(chip, 
     runs of 7, 13 and 6 Mamba layers; the state leaves lie at their unpadded
     bytes, 16 states on sublanes); the head contracts against the [65536, 2560]
     table where it lies: no copy, transpose or convert of it; no run's weights
-    are sliced out of a stack."""
+    are sliced out of a stack.  Over 128 ROWS of the 256 slots (``paged.slots``:
+    the engine's step while at most 128 slots are occupied) the same holds:
+    both slot-axis leaves stay whole, aliased and uncopied, the rows' tails
+    are a gather of 128 blocks of 60 KB a layer, and no product has 256 rows.
+    Either way the new tails go back through ``conv_tail_write``, into the leaf
+    ``[256, 26, 120, 128]`` where it lies."""
     from moolib_tpu.models.decoder_parts import SlotCache
     from moolib_tpu.ops.paged_attention import PagedState
 
@@ -947,12 +953,13 @@ def test_jamba_decode_step_updates_its_state_in_place_and_copies_no_table(chip, 
     per = traffic["positions_per_slot"] // bs
     cache = SlotCache(model.cache_spec(1 + S * per, bs), model.state_spec(S))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    paged = PagedState(i32(S, per), i32(S), jax.ShapeDtypeStruct((S,), jnp.bool_))
+    paged = PagedState(i32(rows, per), i32(rows), jax.ShapeDtypeStruct((rows,), jnp.bool_),
+                       None if rows == S else i32(rows))
     compiled, text = _compile(
-        jax.jit(model.decode, donate_argnums=(1,)), *_on(chip, (params, cache, i32(S), paged)))
+        jax.jit(model.decode, donate_argnums=(1,)), *_on(chip, (params, cache, i32(rows), paged)))
     nbytes = lambda tree: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
     assert 6.05e9 < nbytes(params) < 6.08e9  # bfloat16, but for 5 M float32 scales and rates
-    assert cache.slots["ssm"].shape == (256, 26, 16, 5120) and cache.slots["conv"].shape == (256, 26, 3, 5120)
+    assert cache.slots["ssm"].shape == (256, 26, 16, 5120) and cache.slots["conv"].shape == (256, 26, 120, 128)
     assert nbytes(cache.slots["ssm"]) == 2181038080 and nbytes(cache.slots["conv"]) == 408944640
     assert nbytes(cache.blocks) == 4 * 4097 * 256 * 128 * 2
     mem = compiled.memory_analysis()
@@ -960,18 +967,24 @@ def test_jamba_decode_step_updates_its_state_in_place_and_copies_no_table(chip, 
     # the leaves at their unpadded bytes: arguments are the weights, the cache
     # and a few KB of tables (a tail padded 3 -> 8 sublanes would add 0.68 GB)
     assert mem.argument_size_in_bytes < nbytes(params) + nbytes(cache) + (64 << 20)
-    assert mem.temp_size_in_bytes < 1 << 30
+    assert mem.temp_size_in_bytes < (1 << 30 if rows == S else 64 << 20)
     assert len(re.findall(r"%ssm_decode[.\d]* = ", text)) == 3  # one call under each run's scan
-    assert len(re.findall(r"%paged_attention[.\d]* = f32\[256,32,128\]", text)) == 2
+    assert len(re.findall(rf"%paged_attention[.\d]* = f32\[{rows},32,128\]", text)) == 2
     copies = re.findall(r"= (\w+\[[\d,]*\])[^ ]* copy\(", text)
-    state, tail, pool = "f32[256,26,16,5120]", "f32[256,26,3,5120]", "bf16[4097,256,1,128]"
+    state, tail, pool = "f32[256,26,16,5120]", "f32[256,26,120,128]", "bf16[4097,256,1,128]"
     assert not {state, tail, pool, "bf16[65536,2560]"} & set(copies)
+    # the tails' write-back, one call under each run's scan, the leaf whole in and out
+    assert len(re.findall(r"%conv_tail_write[.\d]* = f32\[256,26,120,128\]", text)) == 3
     sizes = lambda found: [int(np.prod([int(d) for d in s.split("[")[1][:-1].split(",") if d]))
                            for s in found]
-    assert max(sizes(copies)) <= 256 * 10240  # nothing of a weight's size
+    assert max(sizes(copies)) <= rows * 10240  # nothing of a weight's size
     for op in ("transpose", "convert"):
         found = re.findall(rf"= (\w+\[[\d,]*\])[^ ]* {op}\(", text)
         assert max(sizes(found), default=0) < 2560 * 2560, op
+    if rows < S:
+        # the leaves whole, in and out: nothing of them was sliced to the rows
+        assert len(re.findall(r"%ssm_decode[.\d]* = \(f32\[128,40,128\]\S* f32\[256,26,16,5120\]", text)) == 3
+        assert not re.findall(r"\[256,(?:2560|5120|8192|10240|16384)\]", text)  # no product over 256 rows
 
 
 def test_jamba_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
@@ -1014,3 +1027,70 @@ def test_ssm_prefill_kernel_compiles_through_mosaic_at_the_cells_buckets(chip, b
     assert re.findall(r"%ssm_prefill[.\d]* = \((f32\[[\d,]*\])", text) == [f"f32[{bucket},5120]"]
     # B and C transposed to [16, positions], nothing else: u, dt, z go in as they are
     assert compiled.memory_analysis().temp_size_in_bytes <= 4 * bucket * 16 * 4 + (1 << 20)
+
+
+# ---------------------------------------------------------------------------
+# the engine's decode step where there is ONE row count (PR 52): the text of
+# before there were any.  Lowered here, on the CPU, at the tiny sizes of the
+# models' own tests: what is held is the program's text, not a compile.
+# ---------------------------------------------------------------------------
+def _step_of_before(eng):
+    """``ContinuousBatchingEngine._step_impl`` as PR 51 had it: every slot a
+    row, the packet the three rows and the model's counters."""
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    def _step_impl(params, cache, tables, lengths, active, tokens, remaining):
+        logits, cache, counters = eng.model.decode(
+            params, cache, tokens, PagedState(tables, lengths, active))
+        act = active.astype(jnp.int32)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        nxt = jnp.where(active, nxt, tokens)
+        lengths = lengths + act
+        remaining = remaining - act
+        done = active & (remaining <= 0)
+        if eng.eos_id is not None:
+            done = done | (active & (nxt == eng.eos_id))
+        packet = jnp.stack([nxt, act, done.astype(jnp.int32)])
+        if eng._n_step_counters:
+            S = eng.slots
+            rows = -(-eng._n_step_counters // S)
+            counters = jnp.pad(counters.astype(jnp.int32), (0, rows * S - eng._n_step_counters))
+            packet = jnp.concatenate([packet, counters.reshape(rows, S)])
+        active = active & ~done
+        return cache, tables, lengths, active, nxt, remaining, packet
+
+    return jax.jit(_step_impl, donate_argnums=(1, 2, 3, 4, 5, 6))
+
+
+# the cells' slot counts: lm_serve_* and glm 32, solar and laguna 64, brumby 24;
+# the Mamba decoder, which decodes rows, at the one tile it has no cell at
+@pytest.mark.parametrize("module,name,slots,eos", [
+    ("transformer", "TransformerLM", 32, None), ("latent_moe", "LatentMoELM", 32, 3),
+    ("hybrid_kda", "HybridKdaMoELM", 64, None), ("swa_moe", "SlidingGqaMoELM", 64, 3),
+    ("retention_lm", "PowerRetentionLM", 24, None), ("jamba", "JambaLM", 128, 3),
+])
+def test_an_engine_of_one_row_count_lowers_the_step_of_before(module, name, slots, eos):
+    """No gather of rows, no scatter of tokens, no counter of the engine's
+    own: at most 128 slots, or a model that does not decode rows, is the
+    program every serving cell but ``jamba_serve_reasoning`` ran before."""
+    import importlib
+
+    from moolib_tpu.engine import ContinuousBatchingEngine
+
+    mod = importlib.import_module("moolib_tpu.models." + module)
+    if module == "transformer":
+        model = mod.TransformerLM(vocab_size=64, d_model=32, num_heads=4, num_kv_heads=2,
+                                  num_layers=2, max_len=64, attention="dense",
+                                  dtype=jnp.float32, pos_embedding="rotary")
+        params = model.init(jax.random.key(1), jnp.zeros((1, 8), jnp.int32))
+    else:
+        model = getattr(mod, name).from_config(mod.tiny_config(), dtype=jnp.float32, max_len=64)
+        params = jax.jit(model.init)(jax.random.key(0))
+    eng = ContinuousBatchingEngine(model, params, slots=slots, block_size=16, max_seq_len=64,
+                                   max_prompt_len=32, eos_id=eos)
+    assert eng._row_counts == (slots,) and eng._n_packet_counters == eng._n_step_counters
+    state = (eng._params, eng._cache, eng._tables, eng._lengths, eng._active, eng._tokens,
+             eng._remaining)
+    text = eng._step_jit.lower(*state, slots).as_text()
+    assert text == _step_of_before(eng).lower(*state).as_text()
+    assert "jit__step_impl" in text  # the same module name, so the texts can be equal

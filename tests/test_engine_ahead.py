@@ -14,6 +14,11 @@ the next ``step()``, once a step is queued behind the join.  Until then the
 slot's ``emitted`` list is empty; a first token that is EOS leaves the slot dark on
 the device and comes back as that step's ``finished``; a budget of 1 joins no
 slot and is answered at once.
+
+The step's rows (ISSUE 52): an engine of more than 128 slots over a model that
+says ``decodes_rows`` compiles the step once a row count and picks, a
+dispatch, the smallest that holds the slots its mirrors expect to advance;
+the last section holds it to an engine pinned to every slot, token for token.
 """
 
 import numpy as np
@@ -52,6 +57,10 @@ def _reference(lm, prompt, budget):
 def _counter(name):
     series = telemetry.get_registry().snapshot()[name]["series"]
     return series[0]["value"] if series else 0
+
+
+def _histogram(name):
+    return _counter(name) or {"count": 0, "sum": 0.0}
 
 
 @pytest.mark.parametrize("budget", [2, 3, 7])
@@ -445,3 +454,169 @@ def test_prefill_counters_reach_the_model_once_a_request(lm):
     assert {tuple(o) for o in outs} == {tuple(_reference(lm, p, mn)) for p, mn in reqs}
     st = eng.stats()
     assert st["joins"] == st["joins_ahead"] == 2  # the budget of 1 joined nothing
+
+
+# ------------------------------------------ rows follow the occupied slots
+class _RowsLM:
+    """A paged transformer whose class says it decodes rows.  It holds pools
+    alone, so a row's block table is all it needs: the engine's half of the
+    row counts shows without a slot-axis leaf (``tests/test_jamba.py`` has
+    the model with one)."""
+
+    step_counters = 0
+    prefill_counters = 0
+    decodes_rows = True
+
+    def __init__(self, model):
+        from moolib_tpu.models.transformer import PagedTransformerLM
+
+        self._inner = PagedTransformerLM(model)
+        self.max_len = model.max_len
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+SLOTS = 256
+
+
+def _rows_engine(lm, pinned=False, **kw):
+    model, params = lm
+    eng = ContinuousBatchingEngine(_RowsLM(model), params, slots=SLOTS, block_size=4,
+                                   max_seq_len=32, max_prompt_len=8, **kw)
+    if pinned:  # every step at the full row count, as before there was another
+        eng._rows_for = lambda stepping: eng.slots
+    return eng
+
+
+def _watch(eng):
+    """Check, at every dispatch, what the choice of a row count rests on:
+    the mirrors' set holds the device's, so the rows chosen hold its count.
+    Returns the list the row counts are appended to."""
+    chosen, launch = [], eng._launch
+
+    def checked(rows):
+        device = np.asarray(eng._active)
+        stepping = eng._active_host if eng._flight is None else None
+        assert int(device.sum()) <= rows, (int(device.sum()), rows)
+        if stepping is not None:
+            assert not (device & ~stepping).any()
+        chosen.append(rows)
+        return launch(rows)
+
+    eng._launch = checked
+    return chosen
+
+
+def _waves(eng, waves, until):
+    """Submit each wave of (prompt, budget) once fewer than ``until`` of the
+    slots are lit, a step in flight from the second wave on; step to the end.
+    Returns the emitted tokens by request."""
+    live, outs, n = {}, {}, 0
+    waves = list(waves)
+    for _ in range(400):
+        if waves and eng.active_count() < until:
+            for prompt, budget in waves.pop(0):
+                slot, _ = eng.submit(prompt, budget)
+                live[slot] = n
+                n += 1
+        if not live:
+            break
+        _, finished = eng.step()
+        for s in finished:
+            outs[live.pop(s)] = eng.retire(s)
+    assert not live and not waves
+    return outs
+
+
+def _requests(n, seed, budgets):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(1, V, size=int(rng.integers(1, 9))).astype(np.int32),
+             int(budgets[i % len(budgets)])) for i in range(n)]
+
+
+@pytest.mark.parametrize("eos", [None, 22])
+def test_the_rows_follow_the_occupied_slots_and_no_token_changes(lm, eos):
+    """256 slots; 150 requests of short and long budgets, 120 more once 50
+    are left (joins behind a step in flight, between a step of 128 rows and
+    one of 256), then the drain: the row count goes 256, 128, 256, 128, and
+    every request emits what an engine pinned to 256 rows emits.  With an EOS
+    id slots finish on the device a step before the host knows: the mirrors
+    stay a superset, so a step may run 256 rows over 128 live slots, never
+    the reverse."""
+    waves = [_requests(150, 1, (3, 3, 12)), _requests(120, 2, (4, 9))]
+    pinned = _rows_engine(lm, pinned=True, eos_id=eos)
+    want = _waves(pinned, waves, until=51)
+    assert pinned._step_jit._cache_size() == 1
+    assert pinned.stats()["steps_by_rows"] == {128: 0, 256: pinned.stats()["steps"]}
+
+    eng = _rows_engine(lm, eos_id=eos)
+    # warm-up compiles both row counts, and its return counts them
+    assert eng.warmup() == (eng._prefill_jit._cache_size() + eng._join_jit._cache_size()
+                            + eng._step_jit._cache_size())
+    assert eng._step_jit._cache_size() == 2 and eng._flight is None
+    before = _histogram("serve_engine_decode_rows")
+    chosen = _watch(eng)
+    got = _waves(eng, waves, until=51)
+    assert got == want
+    crossings = [r for i, r in enumerate(chosen) if i == 0 or r != chosen[i - 1]]
+    assert crossings == [256, 128, 256, 128]
+    st = eng.stats()
+    assert st["row_overflows"] == 0 and _counter("serve_engine_row_overflows_total") == 0
+    assert st["steps_by_rows"] == {r: chosen.count(r) for r in (128, 256)}
+    # every step dispatched is booked, but one still in flight behind an EOS
+    assert sum(st["steps_by_rows"].values()) == st["steps"] + (eng._flight is not None)
+    after = _histogram("serve_engine_decode_rows")
+    assert after["count"] - before["count"] == len(chosen)
+    assert after["sum"] - before["sum"] == sum(chosen)
+    assert eng._step_jit._cache_size() == 2  # nothing compiled since the warm-up
+    if eos is not None:
+        assert any(out[-1] == eos for out in got.values())
+        assert st["empty_steps"] == pinned.stats()["empty_steps"]
+    for i in (0, 7, 151):  # and what they emit is generate()'s
+        prompt, budget = (waves[0] + waves[1])[i]
+        if eos is None:
+            np.testing.assert_array_equal(
+                np.concatenate([prompt, got[i]]), _reference(lm, prompt, budget))
+    eng.pool.check_invariants()
+    assert eng.pool.available() == eng.pool.num_blocks - 1
+
+
+def test_a_step_with_too_few_rows_is_late_and_counted_never_wrong(lm):
+    """The invariant broken by hand: 150 lit slots and a step of 128 rows.
+    The 22 slots past the rows do not step, their packet says so, the next
+    steps bring them up; every request's tokens are the pinned engine's and
+    the counter that must read 0 does not."""
+    wave = [_requests(150, 3, (5, 7))]
+    want = _waves(_rows_engine(lm, pinned=True), wave, until=1)
+    eng = _rows_engine(lm)
+    eng._rows_for = lambda stepping: 128
+    zero = _counter("serve_engine_row_overflows_total")
+    got = _waves(eng, wave, until=1)
+    assert got == want
+    st = eng.stats()
+    assert st["row_overflows"] > 0 and st["steps_by_rows"][256] == 0
+    assert _counter("serve_engine_row_overflows_total") - zero == st["row_overflows"]
+    # no step late, the longest request's tokens after its first are the steps
+    assert st["steps"] > max(len(o) for o in want.values()) - 1
+
+
+@pytest.mark.parametrize("slots,decodes,counts", [
+    (3, True, (3,)), (128, True, (128,)), (129, True, (128, 129)),
+    (256, True, (128, 256)), (300, True, (128, 256, 300)), (256, False, (256,)),
+])
+def test_row_counts_by_slots_and_by_what_the_model_says(lm, slots, decodes, counts):
+    """Multiples of 128 below the slots, then the slots; one count, the
+    slots, for a model that does not say it decodes rows (its packet is then
+    the three rows of before)."""
+    model, params = lm
+    wrapped = _RowsLM(model) if decodes else model
+    eng = ContinuousBatchingEngine(wrapped, params, slots=slots, block_size=4,
+                                   max_seq_len=16, max_prompt_len=8)
+    assert eng._row_counts == counts
+    lit = np.zeros(slots, bool)
+    for n in (1, 127, 128, 129, 256, 257, 300):
+        if n <= slots:
+            lit[:n] = True
+            assert eng._rows_for(lit) == next(c for c in counts if c >= n)
+    assert eng._n_packet_counters == (len(counts) > 1)

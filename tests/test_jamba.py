@@ -71,7 +71,7 @@ def test_builds_from_the_published_keys_and_the_order_rule(model):
     assert (model.head_dim, model.d_inner, model.step_counters) == (128, 256, 1)
     spec = model.state_spec(5)
     assert spec["ssm"].shape == (5, 6, 16, 256) and spec["ssm"].dtype == jnp.float32
-    assert spec["conv"].shape == (5, 6, 3, 256)
+    assert spec["conv"].shape == (5, 6, 6, 128)  # three taps of 256 channels, as whole tiles of 128 lanes
     pools = model.cache_spec(9, 16)
     assert len(pools["k"]) == 2 and pools["k"][0].shape == (9, 16, 1, 128)
     # the published file: attention at layers 7 and 21 of 28, 5,120 channels, 3.03 B parameters
@@ -166,6 +166,67 @@ def test_engine_submit_step_retire_matches_the_reference(engine, params, lengths
         assert len(out[i]) == budget
         # every emitted token is the reference's argmax, up to a near tie
         assert _gaps(params, prompt, out[i]).max() < TOL
+
+
+def _waves(eng, waves, until):
+    """Submit each wave of (prompt, budget) once fewer than ``until`` slots
+    are lit (a step in flight from the second wave on); step to the end.
+    Returns ({request: emitted}, the row count of every step dispatched)."""
+    live, outs, n, chosen, launch = {}, {}, 0, [], eng._launch
+
+    def watched(rows):
+        # what the choice rests on: the rows hold the device's active slots
+        assert int(np.asarray(eng._active).sum()) <= rows
+        chosen.append(rows)
+        return launch(rows)
+
+    eng._launch = watched
+    waves = list(waves)
+    while waves or live:
+        if waves and eng.active_count() < until:
+            for prompt, budget in waves.pop(0):
+                slot, _ = eng.submit(prompt, budget)
+                live[slot], n = n, n + 1
+        _, finished = eng.step()
+        for slot in finished:
+            outs[live.pop(slot)] = eng.retire(slot)
+    return outs, chosen
+
+
+def test_the_rows_of_a_step_are_the_occupied_slots_and_no_token_changes(model, params):
+    """256 slots.  140 requests (prompts of 1 to 20 tokens: shorter than the
+    convolution, and longer), 110 more once 40 are left, joined behind a step
+    in flight between a step of 128 rows and one of 256, then the drain: the
+    row count goes 256, 128, 256, 128, and every request emits, token for
+    token, what an engine pinned to 256 rows emits: a row's tail and state
+    are its slot's, whichever row of the step it is, and a padded row's
+    write-back (its own tail and state, unchanged) touches no live slot's."""
+    rng = np.random.default_rng(5)
+
+    def requests(n, budgets):
+        return [(_tokens(int(rng.integers(1, 21)), seed=int(rng.integers(1 << 30))),
+                 budgets[i % len(budgets)]) for i in range(n)]
+
+    waves = [requests(140, (3, 3, 3, 10)), requests(110, (4, 8))]
+    kw = {"slots": 256, "max_seq_len": 32, "max_prompt_len": 32, "min_prompt_len": 17}
+    pinned = _engine(model, params, **kw)
+    pinned._rows_for = lambda stepping: pinned.slots
+    want, rows = _waves(pinned, waves, until=41)
+    assert set(rows) == {256} and pinned._step_jit._cache_size() == 1
+
+    eng = _engine(model, params, **kw)
+    assert eng.warmup() == 1 + 1 + 2  # the one bucket, its join, two row counts
+    assert eng._step_jit._cache_size() == 2
+    got, rows = _waves(eng, waves, until=41)
+    assert [r for i, r in enumerate(rows) if i == 0 or r != rows[i - 1]] == [256, 128, 256, 128]
+    assert got == want
+    stats = eng.stats()
+    assert stats["row_overflows"] == 0 and eng._step_jit._cache_size() == 2
+    assert stats["steps_by_rows"] == {128: rows.count(128), 256: rows.count(256)}
+    assert eng.pool.available() == eng.pool.num_blocks - 1
+    # and what they emit is the reference's argmax, up to a near tie
+    for i in (0, 139, 249):
+        assert _gaps(params, (waves[0] + waves[1])[i][0], got[i]).max() < TOL
 
 
 def _teacher_forced(model, params, lengths, steps, hook=None, active=None):

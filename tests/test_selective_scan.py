@@ -73,20 +73,62 @@ def test_prefill_in_two_calls_is_the_prefill_in_one(monkeypatch):
     np.testing.assert_allclose(s, whole_s, atol=1e-5)
 
 
+# the rows are the slots; a permutation of them; a strict subset (a step over
+# fewer rows than the leaf has slots reads what a row brings by row, its state by slot)
+@pytest.mark.parametrize("slots", [None, (3, 0, 4, 1, 2), (4, 1, 2)])
 @pytest.mark.parametrize("active", [(True, False, True, True, False), (False,) * 5, (True,) * 5])
-def test_decode_kernel_in_interpret_mode_equals_the_jnp_step(active):
-    u, dt, _z, A, B, C, _D, _ = _inputs(5, seed=3)
+def test_decode_kernel_in_interpret_mode_equals_the_jnp_step(active, slots):
+    R = 5 if slots is None else len(slots)
+    u, dt, _z, A, B, C, _D, _ = _inputs(R, seed=3)
     state = jax.random.normal(jax.random.key(5), (5, 3, N, CH))
-    active = jnp.asarray(active)
-    want_y, want_s = ssm.ssm_step(u, dt, A.T, B, C, state, 1, active)
-    got_y, got_s = ssm.ssm_decode(u, dt, A.T, B, C, state, jnp.int32(1), active)
+    active = jnp.asarray(active[:R])
+    slot_of = np.arange(R) if slots is None else np.asarray(slots)
+    slots = None if slots is None else jnp.asarray(slots, jnp.int32)
+    want_y, want_s = ssm.ssm_step(u, dt, A.T, B, C, state, 1, active, slots)
+    got_y, got_s = ssm.ssm_decode(u, dt, A.T, B, C, state, jnp.int32(1), active, slots)
     np.testing.assert_allclose(got_y, want_y, atol=1e-5)
     np.testing.assert_allclose(got_s, want_s, atol=1e-5)
-    # other layers and the slots nobody holds are bit for bit what they were
-    idle = np.nonzero(~np.asarray(active))[0]
+    # the step by slot is the step by row over the rows' slots of the state
+    rows_y, rows_s = ssm.ssm_step(u, dt, A.T, B, C, state[slot_of], 1, active)
+    np.testing.assert_array_equal(np.asarray(want_y), np.asarray(rows_y))
+    np.testing.assert_array_equal(np.asarray(want_s)[slot_of], np.asarray(rows_s))
+    # other layers, the slots nobody holds and the slots no row names are bit
+    # for bit what they were
+    idle = np.setdiff1d(np.arange(5), slot_of[np.asarray(active)])
     np.testing.assert_array_equal(np.asarray(got_s)[idle], np.asarray(state)[idle])
     np.testing.assert_array_equal(np.asarray(got_s)[:, [0, 2]], np.asarray(state)[:, [0, 2]])
-    np.testing.assert_array_equal(np.asarray(got_y)[idle], 0.0)
+    np.testing.assert_array_equal(np.asarray(got_y)[~np.asarray(active)], 0.0)
+
+
+@pytest.mark.parametrize("slots", [None, (3, 0, 4, 1, 2), (4, 1, 2)])
+@pytest.mark.parametrize("active", [(True, False, True, True, False), (False,) * 5, (True,) * 5])
+def test_tail_write_kernel_in_interpret_mode_equals_the_jnp_step(active, slots):
+    """The active rows' new tails land at their slots' blocks of the layer;
+    every other block of the leaf is bit for bit what it was."""
+    R = 5 if slots is None else len(slots)
+    conv = jax.random.normal(jax.random.key(6), (5, 3, 3 * CH // 128, 128))
+    new = jax.random.normal(jax.random.key(7), (R, 3 * CH // 128, 128))
+    active = jnp.asarray(active[:R])
+    slot_of = np.arange(R) if slots is None else np.asarray(slots)
+    slots = None if slots is None else jnp.asarray(slots, jnp.int32)
+    want = ssm.tail_step(conv, new, 1, active, slots)
+    got = ssm.conv_tail_write(conv, new, jnp.int32(1), active, slots)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    written = slot_of[np.asarray(active)]
+    np.testing.assert_array_equal(np.asarray(got)[written, 1], np.asarray(new)[np.asarray(active)])
+    idle = np.setdiff1d(np.arange(5), written)
+    np.testing.assert_array_equal(np.asarray(got)[idle], np.asarray(conv)[idle])
+    np.testing.assert_array_equal(np.asarray(got)[:, [0, 2]], np.asarray(conv)[:, [0, 2]])
+
+
+def test_more_active_rows_than_copies_in_flight_are_all_written():
+    conv = jnp.zeros((40, 2, 6, 128))
+    new = jax.random.normal(jax.random.key(8), (23, 6, 128))
+    slots = jnp.asarray(np.random.default_rng(0).permutation(40)[:23], jnp.int32)
+    active = jnp.arange(23) != 11
+    got = ssm.conv_tail_write(conv, new, 0, active, slots)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ssm.tail_step(conv, new, 0, active, slots)))
+    assert int((np.asarray(got) != 0).any(axis=(1, 2, 3)).sum()) == 22
 
 
 def test_the_jnp_step_is_the_recurrence_transposed():
@@ -106,3 +148,10 @@ def test_shapes_off_the_lanes_are_refused_by_name():
         ssm.ssm_decode(u, dt, A.T, B, C, jnp.zeros((8, 1, N, 192)), 0, jnp.ones((8,), bool))
     with pytest.raises(ValueError, match="ssm_prefill"):
         ssm.ssm_prefill(u, dt, z, A.T, B, C, D, interpret=False)
+    u, dt, z, A, B, C, D, _ = _inputs(4)
+    with pytest.raises(ValueError, match="each row's slot"):  # fewer rows than slots, no map
+        ssm.ssm_decode(u, dt, A.T, B, C, jnp.zeros((8, 1, N, CH)), 0, jnp.ones((4,), bool))
+    with pytest.raises(ValueError, match="conv_tail_write wants"):
+        ssm.conv_tail_write(jnp.zeros((8, 1, 3, 256)), jnp.zeros((8, 3, 256)), 0, jnp.ones((8,), bool))
+    with pytest.raises(ValueError, match="each row's slot"):
+        ssm.conv_tail_write(jnp.zeros((8, 1, 6, 128)), jnp.zeros((4, 6, 128)), 0, jnp.ones((4,), bool))

@@ -365,9 +365,10 @@ class ContinuousBatchingEngine:
         # prefill's device output (its copy to the host under way) and the
         # prompt's length.  The next ``step()`` reads them.
         self._first: Dict[int, Tuple[jax.Array, int]] = {}
-        # The step dispatched but not yet booked: its packet, and the slots
-        # the mirrors expect it to advance ([S] bool).
-        self._flight: Optional[Tuple[jax.Array, np.ndarray]] = None
+        # The step dispatched but not yet booked: its packet, the slots the
+        # mirrors expect it to advance ([S] bool), and which dispatch of the
+        # decode program it was (its ``seq``).
+        self._flight: Optional[Tuple[jax.Array, np.ndarray, int]] = None
         self._stats = {
             "joins": 0, "joins_ahead": 0, "retires": 0, "decode_tokens": 0,
             "prefill_tokens": 0, "prefill_pad_tokens": 0, "steps": 0,
@@ -375,25 +376,21 @@ class ContinuousBatchingEngine:
             "steps_by_rows": {rows: 0 for rows in self._row_counts},
         }
 
-        # devmon wrappers: the decode step must stay ONE compile A ROW COUNT
-        # for the engine's lifetime (``rows`` is static; tests assert
-        # _cache_size, which forwards through the wrapper); prefill/join
-        # legitimately compile per bucket, and the detector's flight events
-        # name any trace beyond that contract.
-        self._step_jit = devmon.instrument_jit(
-            jax.jit(self._step_impl, donate_argnums=(1, 2, 3, 4, 5, 6),
-                    static_argnums=(7,)),
-            "engine.step",
-        )
+        # The engine's three device programs, each named once (devmon.jit_program:
+        # the profiler's program line, ``jit_compiles_total{fn}`` and the
+        # ``program`` of the dispatching span are one string).  The decode
+        # step must stay ONE compile A ROW COUNT for the engine's lifetime
+        # (``rows`` is static; tests assert _cache_size, which forwards
+        # through the wrapper); prefill/join legitimately compile per bucket,
+        # and the detector's flight events name any trace beyond that.
+        self._step_jit = devmon.jit_program(
+            self._step_impl, "engine_decode",
+            donate_argnums=(1, 2, 3, 4, 5, 6), static_argnums=(7,))
         # Prefill/join jits cache by shape: one trace per prompt bucket
         # (and per block-count bucket for join) — never per request.
-        self._prefill_jit = devmon.instrument_jit(
-            jax.jit(self._prefill_impl), "engine.prefill"
-        )
-        self._join_jit = devmon.instrument_jit(
-            jax.jit(self._join_impl, donate_argnums=(0, 1, 2, 3, 4, 5)),
-            "engine.join",
-        )
+        self._prefill_jit = devmon.jit_program(self._prefill_impl, "engine_prefill")
+        self._join_jit = devmon.jit_program(
+            self._join_impl, "engine_join", donate_argnums=(0, 1, 2, 3, 4, 5))
 
     def set_params(self, params) -> None:
         """Install new weights (host or device pytree).  Called between
@@ -557,8 +554,9 @@ class ContinuousBatchingEngine:
             with telemetry.span("engine.join_backpressure"):
                 # mtlint: allow-host-sync(backpressure on joins dispatched ahead, where a slot's state is hundreds of MB: bounds the rows alive on the device; never reached with a step() between two admissions)
                 next(itertools.islice(self._first.values(), back, None))[0].block_until_ready()
-        with telemetry.span("engine.prefill_dispatch"):
-            lb = self._bucket(tp)
+        lb = self._bucket(tp)
+        with telemetry.span("engine.prefill_dispatch", program=self._prefill_jit.name,
+                            seq=self._prefill_jit.seq, bucket=lb, tokens=tp):
             pad = lb - tp
             toks = np.pad(prompt, (0, pad))[None]
             if pad:
@@ -571,10 +569,14 @@ class ContinuousBatchingEngine:
         if max_new == 1:
             # No slot to join, and known before the prefill: the one
             # admission that waits for its token.
-            return None, [self._read_first(first, tp)]
+            return None, [self._read_first(first, tp, -1)]
         if not self._free_slots:
             raise NoFreeSlot(f"all {self.slots} slots occupied")
-        with telemetry.span("engine.join"):
+        # The slot the join will take: peeked, and popped once the blocks are
+        # held (PoolExhausted leaves it free).  The join's spans carry it.
+        slot = self._free_slots[-1]
+        launch = dict(program=self._join_jit.name, seq=self._join_jit.seq, slot=slot)
+        with telemetry.span("engine.join", **launch):
             block_ids, row, written = [], None, None
             if self.pool is not None:
                 n_alloc = self.pool.blocks_for(max(lb, total))
@@ -582,10 +584,10 @@ class ContinuousBatchingEngine:
                 row = np.zeros(self.max_blocks_per_seq, np.int32)
                 row[:n_alloc] = block_ids
                 written = np.asarray(block_ids[:self.pool.blocks_for(lb)], np.int32)  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
-            slot = self._free_slots.pop()
+            self._free_slots.pop()
             # Where the model keeps a state a slot, the join's dispatch is
             # also the state's write: a span of its own says what it costs.
-            with (telemetry.span("engine.state_write") if self._slot_state
+            with (telemetry.span("engine.state_write", **launch) if self._slot_state
                   else contextlib.nullcontext()):
                 (self._cache, self._tables, self._lengths, self._active,
                  self._tokens, self._remaining) = self._join_jit(
@@ -613,10 +615,11 @@ class ContinuousBatchingEngine:
         self._update_gauges()
         return slot, emitted
 
-    def _read_first(self, first: jax.Array, tp: int) -> int:
+    def _read_first(self, first: jax.Array, tp: int, slot: int) -> int:
         """A prefill's first token, on the host; the model's prefill counters
-        ride the same vector and are handed back to it here."""
-        with telemetry.span("engine.first_token_fetch"):
+        ride the same vector and are handed back to it here.  ``slot`` is the
+        one the request joined, -1 where it joined none (a budget of 1)."""
+        with telemetry.span("engine.first_token_fetch", slot=slot):
             # mtlint: allow-host-sync(the prefill's one D2H: its first token, and the model's prefill counters in the same vector; its copy started at the prefill's dispatch, and but for a budget of 1 the next step is already queued behind the join)
             first = np.asarray(first)
         if self._n_prefill_counters:
@@ -630,7 +633,7 @@ class ContinuousBatchingEngine:
         pending = self._first.pop(slot, None)
         if pending is None:
             return False
-        tok0 = self._read_first(*pending)
+        tok0 = self._read_first(*pending, slot)
         self._emitted[slot].append(tok0)
         if self.eos_id is None or tok0 != self.eos_id:
             return False
@@ -673,9 +676,10 @@ class ContinuousBatchingEngine:
         """Put a step in flight that the mirrors expect to advance
         ``stepping``."""
         t0 = time.monotonic()
-        with telemetry.span("engine.step_dispatch"):
-            rows = self._rows_for(stepping)
-            self._flight = self._launch(rows), stepping
+        rows, seq = self._rows_for(stepping), self._step_jit.seq
+        with telemetry.span("engine.step_dispatch", program=self._step_jit.name,
+                            seq=seq, rows=rows):
+            self._flight = self._launch(rows), stepping, seq
         self._stats["steps_by_rows"][rows] += 1
         _M_DECODE_ROWS.observe(rows)
         _M_PHASE.observe(time.monotonic() - t0, phase="dispatch")
@@ -684,7 +688,7 @@ class ContinuousBatchingEngine:
         """``step`` with a step to book, under its span."""
         if self._flight is None:
             self._dispatch(self._active_host.copy())
-        (packet, stepping), self._flight = self._flight, None
+        (packet, stepping, seq), self._flight = self._flight, None
         # Slots the step after this one would advance, by the mirrors: still
         # budgeted once this one is counted.  (A finish by EOS is not seen.)
         ahead = self._active_host & (self._remaining_host - stepping > 0)
@@ -694,7 +698,7 @@ class ContinuousBatchingEngine:
             _M_STEPS_AHEAD.inc()
         t1 = time.monotonic()
         # The decode loop's D2H wait.
-        with telemetry.span("engine.decode_fetch"):
+        with telemetry.span("engine.decode_fetch", seq=seq):
             # mtlint: allow-host-sync(the decode loop's one intentional D2H: a step's packet of emitted tokens, was-active and done flags must reach the host to answer requests; its copy started at the dispatch and the next step is already queued)
             packet = np.asarray(packet)
         nxt, was_active, done = packet[:3]

@@ -52,6 +52,11 @@ _M_SYNC_COLLECTIVE = telemetry.get_registry().gauge(
     "device's operation line (devmon.sync_collectives): what no compute hides",
 )
 
+# The train step's one name (devmon.jit_program): ``jit_lm_train_step`` on the
+# profiler's program line, the ``fn`` of ``jit_compiles_total`` and
+# ``step_mfu``, the ``program`` of the ``train_step`` span that dispatches it.
+STEP_PROGRAM = "lm_train_step"
+
 # What jit_step compiles the dp > 1 step with on a TPU.  Left to itself this
 # compiler (jax 0.9.0, libtpu 0.0.34) runs every gradient's reduce-scatter as
 # one synchronous fusion on the operation line.  The first two, only
@@ -381,7 +386,7 @@ def jit_step(step, params, opt_state, flags, mesh=None):
     TPUs, the step is compiled with ``_DP_COMPILER_OPTIONS`` (another
     backend knows none of them)."""
     if mesh is None:
-        return jax.jit(step, donate_argnums=(0, 1)), lambda x: x
+        return telemetry.devmon.jit_program(step, STEP_PROGRAM, donate_argnums=(0, 1)), lambda x: x
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rep = parallel.replicated(mesh)
@@ -389,8 +394,8 @@ def jit_step(step, params, opt_state, flags, mesh=None):
     tok_sharding = NamedSharding(mesh, P("dp", None) if dp > 1 else P())
     p_sh, o_sh = state_shardings(params, opt_state, flags, mesh)
     on_tpu = mesh.devices.flat[0].platform == "tpu"
-    jstep = jax.jit(
-        step,
+    jstep = telemetry.devmon.jit_program(
+        step, STEP_PROGRAM,
         in_shardings=(p_sh, o_sh, tok_sharding),
         out_shardings=(p_sh, o_sh, rep, rep),
         donate_argnums=(0, 1),
@@ -541,7 +546,6 @@ def train(flags, on_stats=None) -> dict:
             (params, opt_state), state_shardings(params, opt_state, flags, mesh)
         )
     jstep, put = jit_step(step, params, opt_state, flags, mesh)
-    jstep = telemetry.devmon.instrument_jit(jstep, "lm.step")
     state_bytes = state_device_bytes(params, opt_state)
     _M_STATE.set(state_bytes)
 
@@ -553,7 +557,7 @@ def train(flags, on_stats=None) -> dict:
     # the MFU/roofline numbers in the log line and out["mfu"], and the bytes
     # of donated state the outputs reuse (0: a donation XLA could not use).
     step_cost = telemetry.devmon.step_cost(
-        "lm.step", jstep, params, opt_state, put(tokens0)
+        STEP_PROGRAM, jstep, params, opt_state, put(tokens0)
     )
     donated = None if step_cost is None else step_cost.donated_bytes
     if step_cost is not None:
@@ -561,7 +565,7 @@ def train(flags, on_stats=None) -> dict:
         _M_SYNC_COLLECTIVE.set(held_bytes)
         if not flags.quiet:
             print(
-                f"lm.step: {held} collectives hold the operation line, "
+                f"{STEP_PROGRAM}: {held} collectives hold the operation line, "
                 f"{held_bytes / 1e6:.1f} MB",
                 flush=True,
             )
@@ -587,7 +591,8 @@ def train(flags, on_stats=None) -> dict:
                 tokens = put(jnp.asarray(make_batch(rng, flags)))
             if batch_placement is None:
                 batch_placement = common.placement_of(tokens)
-            with timer.section("train_step"), wd.section("train_step"):
+            with timer.section("train_step", program=STEP_PROGRAM, seq=jstep.seq), \
+                    wd.section("train_step"):
                 params, opt_state, loss, acc = jstep(params, opt_state, tokens)
             steps_done = i + 1
             if steps_done % flags.log_interval == 0:
@@ -601,7 +606,7 @@ def train(flags, on_stats=None) -> dict:
                 mfu_info = None
                 if step_cost is not None:
                     mfu_info = telemetry.devmon.publish_step(
-                        "lm.step", step_cost, step_s
+                        STEP_PROGRAM, step_cost, step_s
                     )
                 if not flags.quiet:
                     mfu_s = (
@@ -644,7 +649,7 @@ def train(flags, on_stats=None) -> dict:
     mfu_v = None
     if step_cost is not None and steps_done > start_step:
         fin = telemetry.devmon.publish_step(
-            "lm.step", step_cost, elapsed / (steps_done - start_step)
+            STEP_PROGRAM, step_cost, elapsed / (steps_done - start_step)
         )
         if fin is not None:
             mfu_v = fin["mfu"]

@@ -8,7 +8,9 @@ registry (docs/TELEMETRY.md "Device performance plane"):
   to ``jax.monitoring`` (backend compile durations, persistent-cache
   hits/misses) and :func:`instrument_jit` wraps a jitted callable with a
   recompile detector that asks the jit's own cache whether a call compiled
-  (O(1) a call): every *new* abstract input signature increments
+  (O(1) a call; :func:`jit_program` names, jits and wraps in one step, so
+  that a program has ONE name from the profiler's program line to the
+  counter's label): every *new* abstract input signature increments
   ``jit_compiles_total{fn}``, and a signature change after the first compile
   emits one ``devmon.recompile`` flight event carrying the signature diff
   plus a stderr WARN — the dynamic counterpart of the static
@@ -37,6 +39,7 @@ deferred into the functions that need them.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import re
@@ -55,6 +58,7 @@ __all__ = [
     "install_compile_listeners",
     "install_from_env",
     "instrument_jit",
+    "jit_program",
     "last_recompile",
     "observe_call",
     "peak_bandwidth",
@@ -256,26 +260,31 @@ class _InstrumentedJit:
     signatures.  Attribute access (``lower``, ``_cache_size``, ...) forwards
     to the wrapped jit so AOT paths and tests see the real object.
 
-    A call costs two reads of the jit's cache size.  The signature (a
-    flatten of every argument and one string a leaf) is computed only when
-    this call compiled: the first call, and then whenever the cache grew,
-    from the arguments after the call (a donated array keeps its shape and
-    dtype).  A wrapped callable that has no ``_cache_size`` pays for the
-    signature at every call, as before."""
+    A call costs two reads of the jit's cache size and one integer add:
+    ``seq`` is how many times this process has called the program, so read
+    BEFORE a call it is the number of the dispatch about to be made (what the
+    dispatching span hands the profiler, docs/TELEMETRY.md "Device
+    programs").  The signature (a flatten of every argument and one string a
+    leaf) is computed only when this call compiled: the first call, and then
+    whenever the cache grew, from the arguments after the call (a donated
+    array keeps its shape and dtype).  A wrapped callable that has no
+    ``_cache_size`` pays for the signature at every call, as before."""
 
-    __slots__ = ("_fn", "_name", "_primed")
+    __slots__ = ("_fn", "name", "seq", "_primed", "__weakref__")  # jax keys caches on weak references
 
     def __init__(self, fn, name: str):
         self._fn = fn
-        self._name = name
+        self.name = name
+        self.seq = 0
         self._primed = False
 
     def __call__(self, *args, **kwargs):
+        self.seq += 1
         before = _cache_size(self._fn)
         out = self._fn(*args, **kwargs)
         if not self._primed or before is None or _cache_size(self._fn) != before:
             self._primed = True
-            observe_call(self._name, args, kwargs)
+            observe_call(self.name, args, kwargs)
         return out
 
     def __getattr__(self, item):
@@ -288,6 +297,26 @@ def instrument_jit(fn, name: str):
     if isinstance(fn, _InstrumentedJit):
         return fn
     return _InstrumentedJit(fn, name)
+
+
+def jit_program(fn, name: str, **jit_kwargs):
+    """Name, jit and instrument ``fn``: the one place a device program gets
+    its name.  ``name`` (letters, digits and ``_``) is then the same string
+    everywhere the program shows: ``jit_<name>(...)`` on the profiler's
+    ``XLA Modules`` line and in the compiled module's text, the ``fn`` label
+    of ``jit_compiles_total`` and of the recompile flight event, and the
+    ``program`` argument of the span that dispatches it (the caller passes
+    ``program=jitted.name, seq=jitted.seq`` when it opens that span).
+    ``jit_kwargs`` go to ``jax.jit``; argument numbers count ``fn``'s own
+    parameters (a bound method's ``self`` is not one)."""
+    import jax
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return instrument_jit(jax.jit(program, **jit_kwargs), name)
 
 
 def record_signature(name: str, sig) -> bool:

@@ -8,7 +8,13 @@ can sit next to a ``jax.profiler`` capture — and wherever jax is already
 imported each span also enters a ``jax.profiler.TraceAnnotation``, so a
 ``jax.profiler.start_trace`` from anyone in the process (an operator's
 SIGUSR2 profile, a benchmark's traced window) shows the program's spans on
-the device events' clock with no call into the program.
+the device events' clock with no call into the program.  The annotation
+carries the scalar args the span was OPENED with (``span("engine.join",
+program="engine_join", seq=7, slot=3)``): that is how a trace's reader ties a
+run of a device program to the span that dispatched it.  Args attached later
+with ``set()`` stay in this tracer's ring.  While no profile is open nothing
+is formatted or filtered: the args cost their keyword dict and one question
+to the profiler.
 
 Recording is bounded (a ring of the newest ``capacity`` spans) and cheap:
 one ``perf_counter_ns`` pair plus a deque append per span; nesting depth is
@@ -266,7 +272,9 @@ class _ActiveSpan:
 
     def set(self, **args) -> None:
         """Attach args that are known only once the body has run (how many
-        requests an iteration joined); they land on the recorded span."""
+        requests an iteration joined); they land on the recorded span, in
+        this tracer's ring only: the profiler's annotation was made when the
+        span opened and keeps the args given then."""
         self._args = {**(self._args or {}), **args}
 
     def __enter__(self):
@@ -285,11 +293,10 @@ class _ActiveSpan:
         if self._ctx is not None:
             _ctx_stack().append(self._ctx)
             self._pushed = True
-        if self._tracer._annotate:
-            ann = _jax_annotation(self._name)
-            if ann is not None:
-                ann.__enter__()
-                self._annotation = ann
+        ann = _jax_annotation(self._name, self._args)
+        if ann is not None:
+            ann.__enter__()
+            self._annotation = ann
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -325,14 +332,24 @@ class _ActiveSpan:
         return False
 
 
-def _jax_annotation(name: str):
+_SCALARS = (int, float, str, bool)
+_ALWAYS = lambda: True  # noqa: E731 — a profiler that cannot say whether it is open
+
+
+def _jax_annotation(name: str, args: Optional[dict] = None):
     """A jax TraceAnnotation when jax is already imported; never imports it
-    (the tracer must stay usable in env workers that never touch jax)."""
+    (the tracer must stay usable in env workers that never touch jax).  While
+    a profile is open the span's scalar args go with it; while none is, the
+    args are not even looked at."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
     try:
-        return jax.profiler.TraceAnnotation(name)
+        annotation = jax.profiler.TraceAnnotation
+        if args and getattr(annotation, "is_enabled", _ALWAYS)():
+            return annotation(
+                name, **{k: v for k, v in args.items() if isinstance(v, _SCALARS)})
+        return annotation(name)
     except Exception:  # noqa: BLE001 — annotation is best-effort decoration
         return None
 
@@ -342,7 +359,6 @@ class Tracer:
 
     def __init__(self, capacity: int = 65536):
         self._spans: deque = deque(maxlen=capacity)
-        self._annotate = True
         # Anchor pairing the monotonic span clock to wall time, captured
         # once: lets trace_merge rebase every process onto one unix-time
         # axis (perf_counter origins are arbitrary per process).
@@ -415,12 +431,6 @@ class Tracer:
                 None,
             )
         )
-
-    def enable_jax_annotations(self, enabled: bool = True) -> None:
-        """Mirror every span into ``jax.profiler.TraceAnnotation`` so host
-        phases appear inside device traces.  On by default wherever jax is
-        imported; ``False`` is for a loop that would flood a trace."""
-        self._annotate = bool(enabled)
 
     def clear(self) -> None:
         self._spans.clear()
@@ -509,8 +519,9 @@ def get_tracer() -> Tracer:
 
 
 def span(name: str, **args) -> _ActiveSpan:
-    """``with telemetry.span("act"): ...`` against the default tracer."""
-    return get_tracer().span(name, **args)
+    """``with telemetry.span("act"): ...`` against the default tracer (as
+    :meth:`Tracer.span`, without packing the args a second time)."""
+    return _ActiveSpan(get_tracer(), name, args or None)
 
 
 def root_span(name: str, **args) -> _ActiveSpan:

@@ -50,8 +50,10 @@ class StepTimer:
             self._tracer = tracer or telemetry.get_tracer()
 
     @contextlib.contextmanager
-    def section(self, name: str) -> Iterator[None]:
-        span = self._tracer.span(name) if self._tracer is not None else None
+    def section(self, name: str, **args) -> Iterator[None]:
+        """``args`` go to the section's span as given (and with it to an open
+        profiler: ``telemetry.span``)."""
+        span = self._tracer.span(name, **args) if self._tracer is not None else None
         if span is not None:
             span.__enter__()
         t0 = time.perf_counter()

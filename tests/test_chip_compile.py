@@ -1036,10 +1036,11 @@ def test_ssm_prefill_kernel_compiles_through_mosaic_at_the_cells_buckets(chip, b
 # ---------------------------------------------------------------------------
 def _step_of_before(eng):
     """``ContinuousBatchingEngine._step_impl`` as PR 51 had it: every slot a
-    row, the packet the three rows and the model's counters."""
+    row, the packet the three rows and the model's counters.  Named as the
+    engine names its program since PR 53, so that the module names agree."""
     from moolib_tpu.ops.paged_attention import PagedState
 
-    def _step_impl(params, cache, tables, lengths, active, tokens, remaining):
+    def engine_decode(params, cache, tables, lengths, active, tokens, remaining):
         logits, cache, counters = eng.model.decode(
             params, cache, tokens, PagedState(tables, lengths, active))
         act = active.astype(jnp.int32)
@@ -1059,7 +1060,7 @@ def _step_of_before(eng):
         active = active & ~done
         return cache, tables, lengths, active, nxt, remaining, packet
 
-    return jax.jit(_step_impl, donate_argnums=(1, 2, 3, 4, 5, 6))
+    return jax.jit(engine_decode, donate_argnums=(1, 2, 3, 4, 5, 6))
 
 
 # the cells' slot counts: lm_serve_* and glm 32, solar and laguna 64, brumby 24;
@@ -1093,4 +1094,4 @@ def test_an_engine_of_one_row_count_lowers_the_step_of_before(module, name, slot
              eng._remaining)
     text = eng._step_jit.lower(*state, slots).as_text()
     assert text == _step_of_before(eng).lower(*state).as_text()
-    assert "jit__step_impl" in text  # the same module name, so the texts can be equal
+    assert "jit_engine_decode" in text  # the program's one name (devmon.jit_program), so the texts can be equal

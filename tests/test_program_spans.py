@@ -482,16 +482,19 @@ def tiny_train():
 
     def keep(fn, name):
         wrapped = real(fn, name)
-        if name != "lm.step":
+        if name != "lm_train_step":
             return wrapped
 
-        def call(*args):
-            kept.setdefault("args", args)
-            return wrapped(*args)
+        class Call:
+            lower = staticmethod(fn.lower)  # devmon.step_cost lowers through the wrapper
+            seq = property(lambda self: wrapped.seq)  # the train_step span reads it
+
+            def __call__(self, *args):
+                kept.setdefault("args", args)
+                return wrapped(*args)
 
         kept["jit"] = fn
-        call.lower = fn.lower  # devmon.step_cost lowers through the wrapper
-        return call
+        return Call()
 
     flags = lm.make_flags([
         "--vocab", "32", "--d_model", "32", "--heads", "2", "--layers", "1",

@@ -119,7 +119,9 @@ and, for whichever leaves it has:
   rows as ``ceil(Lb / block_size)`` blocks, with the state after position
   ``tp - 1`` where the model keeps one, in whatever pytree the model's
   ``write_rows`` and ``write_state`` take; logits [V] at ``tp - 1``; int32
-  counters or None);
+  counters or None).  ``tp`` is data: one program a bucket, and a model may
+  skip its bucket's padding inside it and say in its counters what it still
+  computed (``models/retention_lm.py``);
 - with ``cache_spec``, ``write_rows(cache, rows, block_ids)`` -> cache: a
   join's scatter of those blocks into the pools (few, stacked arrays keep a
   join's jit call cheap: a row pytree of one array a layer cost ``submit``
@@ -169,18 +171,11 @@ from ..telemetry import devmon
 from ..models.decoder_parts import SlotCache
 from ..models.transformer import PagedTransformerLM, TransformerLM
 from ..ops.paged_attention import PagedState
-from ..serving import _M_PHASE, bucket, bucket_shapes
+# _M_PAD_TOKENS: the batch-synchronous arm's counter; both arms feed one series.
+from ..serving import _M_PAD_TOKENS, _M_PHASE, bucket, bucket_shapes
 from .kv_pool import BlockPool, PoolExhausted
 
 _REG = telemetry.get_registry()
-# Registration is idempotent: serving.py declares the same counter for the
-# batch-synchronous arm — both arms feed one series.
-_M_PAD_TOKENS = _REG.counter(
-    "serve_pad_tokens_total",
-    "tokens of padding waste: bucket pad rows and decode overrun in the "
-    "batch-synchronous arm, prompt-bucket padding in the engine arm — "
-    "subtract from gross throughput to get REAL tokens/s",
-)
 _M_TOKENS = _REG.counter(
     "serve_engine_tokens_total", "tokens emitted by engine decode steps"
 )

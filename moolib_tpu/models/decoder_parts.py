@@ -44,6 +44,12 @@ _M_STATE_LIVE = _REG.histogram(
     "the states the model's decode kernel reads and writes, a layer)",
     buckets=(1, 2, 4, 8, 16, 24, 32, 48, 64, 96, 128, 256),
 )
+_M_PREFILL_ROWS = _REG.counter(
+    "serve_prefill_rows_computed_total",
+    "bucket positions whose position-wise work a prefill program ran (whole "
+    "row tiles up to the prompt's length: over serve_engine_prefill_tokens_total "
+    "+ the engine arm's serve_pad_tokens_total, the share of the buckets still paid for)",
+)
 
 
 class SlotCache(NamedTuple):
@@ -177,10 +183,58 @@ def rows_to_blocks(x, block_size: int, axis: int):
         x.shape[:axis] + (nbw, block_size) + x.shape[axis + 1:])
 
 
+def over_live_rows(fn, xs, live, tile: int, held=()):
+    """A row-wise ``fn(xs, *held)`` (``xs`` a pytree of [rows, ...] -> a pytree
+    of [rows, ...]: row i of every output follows from row i of every input)
+    over the leading-axis tiles of ``xs`` that hold one of the first ``live``
+    rows, and over no other: a prefill's position-wise work without its
+    bucket's padding.  ``live`` is a traced scalar, 0 < live <= rows, so the
+    loop's trip count ``ceil(live / tile)`` is data and the tiles past it are
+    never computed; their rows of every output are 0.  Inside the last live
+    tile ``fn`` sees padding like any row.  ``held``: small values that do not
+    change from tile to tile (a layer's index) and that a barrier ties to the
+    tile all the same, so that what ``fn`` makes of them stays inside the
+    loop: a layer's weights sliced out of their stacks stay operands of the
+    products, where XLA would else slice them once in front of the loop and
+    copy them whole.  ``live`` None, or at most one tile of rows: ``fn`` once
+    over the whole arrays, no loop and no barrier."""
+    rows = jax.tree.leaves(xs)[0].shape[0]
+    if live is None or rows <= tile:
+        return fn(xs, *held)
+    if rows % tile:
+        raise ValueError(f"over_live_rows: {rows} rows are not whole tiles of {tile}")
+
+    def tile_of(i):
+        return jax.tree.map(lambda x: jax.lax.dynamic_slice_in_dim(x, i * tile, tile), xs)
+
+    def body(i, out):
+        ys = fn(*jax.lax.optimization_barrier((tile_of(i), *held)))
+        return jax.tree.map(
+            lambda o, y: jax.lax.dynamic_update_slice_in_dim(o, y, i * tile, 0), out, ys)
+
+    out = jax.tree.map(lambda s: jnp.zeros((rows,) + s.shape[1:], s.dtype),
+                       jax.eval_shape(fn, tile_of(0), *held))
+    return jax.lax.fori_loop(0, rows_over_live_tiles(rows, live, tile) // tile, body, out)
+
+
+def rows_over_live_tiles(rows: int, live, tile: int):
+    """The rows :func:`over_live_rows` computes: all where it does not loop,
+    else whole tiles up to ``live``."""
+    if live is None or rows <= tile:
+        return rows
+    return (live + tile - 1) // tile * tile
+
+
 def observe_state_live(live) -> None:
     """A decode step's count of slots holding live state, back on the host
     (a model that keeps a state a slot hands it back in its step counters)."""
     _M_STATE_LIVE.observe(int(live))
+
+
+def observe_prefill_rows(rows) -> None:
+    """The rows a prefill's :func:`over_live_rows` loops computed, back on the
+    host (the model hands them back in its prefill counters)."""
+    _M_PREFILL_ROWS.inc(int(rows))
 
 
 def step_bias(key, shape, low: float = 0.001, high: float = 0.1):

@@ -15,9 +15,12 @@ that grows, so the model offers the serving engine (``engine/engine.py``)
   decode kernel reads) and its normaliser ``[slots, layers, kv_heads, 72, d]``
   (the d/2 + 1 rows in whole tiles of 8);
 - :meth:`prefill` hands back the state and the normaliser after position
-  ``tp - 1`` (a bucket's padding has its keys and log-decay zeroed, so it
-  moves neither); :meth:`write_state` overwrites the slot's row with them,
-  whatever the slot's last holder left there;
+  ``tp - 1`` and does no work for its bucket's padding: the true length,
+  which the program receives as data, bounds the position-wise work to the
+  row tiles that hold a real position (``decoder_parts.over_live_rows``) and
+  the two retention kernels to the blocks below it, so the padding moves
+  neither and costs a part of one tile; :meth:`write_state` overwrites the
+  slot's row with them, whatever the slot's last holder left there;
 - :meth:`decode`: one token a slot; the state and the normaliser of ACTIVE
   slots advance in place, the others' are left as they are.  ``paged.lengths``
   are the positions (RoPE); ``paged.block_tables`` is None.
@@ -40,6 +43,11 @@ from ..ops.paged_attention import PagedState
 from ..parallel.moe import swiglu
 from . import decoder_parts as parts
 
+# Rows a pass of a prefill's position-wise work takes (``over_live_rows``): a
+# layer's 0.66 GB of weights are read again for every tile, which 512 rows of
+# products outlast on a v5e (PERF.md section 6, PR 54, has the chip's readings).
+_ROW_TILE = 512
+
 
 @dataclasses.dataclass(frozen=True)
 class PowerRetentionLM:
@@ -58,7 +66,7 @@ class PowerRetentionLM:
     dtype: Any = jnp.bfloat16
 
     step_counters = 1  # slots holding live state
-    prefill_counters = 0
+    prefill_counters = 1  # bucket rows whose position-wise work the program ran
 
     @classmethod
     def from_config(cls, config, **overrides) -> "PowerRetentionLM":
@@ -93,6 +101,10 @@ class PowerRetentionLM:
         """A decode step's counters, back on the host (the engine fetched
         them with the step's packet)."""
         parts.observe_state_live(counters[0])
+
+    def observe_prefill(self, counters, prompt_len: int) -> None:
+        """A prefill's counters, back on the host with its first token."""
+        parts.observe_prefill_rows(counters[0])
 
     def state_spec(self, slots: int):
         lead, d = (slots, self.num_hidden_layers, self.num_key_value_heads), self.head_dim
@@ -176,30 +188,49 @@ class PowerRetentionLM:
     def _forward(self, params, toks, tp):
         """The whole prompt toks [T] of which the first ``tp`` are real (None:
         all).  Returns (h [T, D], every layer's state [L, G, D', d, d] and
-        normaliser [L, G, D', d] after position tp - 1)."""
-        T = toks.shape[0]
-        pos = jnp.arange(T)
+        normaliser [L, G, D', d] after position tp - 1).  Where the bucket is
+        more than one row tile, only the tiles that hold a real position are
+        computed, a tile a pass; the rows of ``h`` past them are 0 and those
+        between ``tp`` and the last live tile's end mean nothing: nothing
+        reads a row past ``tp - 1``, whatever token ids lie there."""
+        pos = jnp.arange(toks.shape[0])
         h = params["embed"][toks].astype(jnp.float32)
 
-        def body(h, p):
-            q, k, v, lam = self._mixer_inputs(p, self._norm(h, p["attn_norm"]), pos)
-            if tp is not None:  # padding moves neither state nor normaliser
-                valid = pos < tp
-                k = jnp.where(valid[:, None, None], k, 0.0)
-                lam = jnp.where(valid[:, None], lam, 0.0)
-            o, state, norm = retention.retention_prefill(q, k, v, lam, dtype=self.dtype)
-            return self._close(p, h, o), (state, norm)
+        # A layer's weights are taken out of the stacks where the rows are:
+        # inside a tile's pass they stay operands of the products (sliced in
+        # front of the loop over the tiles, 0.66 GB would be copied a layer).
+        layer = lambda l: jax.tree.map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, l, keepdims=False), params["layers"])
 
-        return jax.lax.scan(body, h, params["layers"])
+        def mixer_inputs(rows, l):
+            p, (h, pos) = layer(l), rows
+            return self._mixer_inputs(p, self._norm(h, p["attn_norm"]), pos)
+
+        def close(rows, l):
+            return self._close(layer(l), *rows)
+
+        def body(h, l):
+            q, k, v, lam = parts.over_live_rows(mixer_inputs, (h, pos), tp, _ROW_TILE, (l,))
+            # with a length, the padding's k, v and lam are not read: it moves
+            # neither state nor normaliser
+            o, state, norm = retention.retention_prefill(
+                q, k, v, lam, length=tp, dtype=self.dtype)
+            h = parts.over_live_rows(close, (h, o), tp, _ROW_TILE, (l,))
+            return h, (state, norm)
+
+        return jax.lax.scan(body, h, jnp.arange(self.num_hidden_layers))
 
     def prefill(self, params, toks, tp, block_size: int):
         """toks [1, Lb] (the prompt padded to its bucket), tp the true
         length; ``block_size`` is the engine's and is not read (nothing here
         is paged).  Returns (the rows :meth:`write_state` takes, logits [V]
-        float32 at position tp - 1, None: no counters)."""
+        float32 at position tp - 1, counters: the bucket rows whose
+        position-wise work ran, ``ceil(tp / tile)`` tiles, or the whole
+        bucket where it is at most one)."""
         h, (state, norm) = self._forward(params, toks[0], tp)
         logits = self._head(params, jnp.take(h, tp - 1, axis=0))
-        return {"state": state, "norm": norm}, logits, None
+        rows = parts.rows_over_live_tiles(toks.shape[1], tp, _ROW_TILE)
+        return {"state": state, "norm": norm}, logits, jnp.asarray(rows, jnp.int32)[None]
 
     # -------------------------------------------------------------- decode
     def decode(self, params, cache, tokens, paged: PagedState, mesh=None):

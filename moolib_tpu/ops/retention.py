@@ -50,6 +50,12 @@ block of positions and a diagonal at a time in float32 at the highest matmul
 precision.  The quadratic sum costs ``4 T d`` operations a position a head and
 the state ``4 d_v d (d/2 + 1)``: below some thousands of positions the first
 is the cheaper, and the engine's prompts are bounded by its largest bucket.
+The prompt's true ``length`` bounds both grids, as data: the quadratic kernel
+visits the block pairs of the causal triangle up to the last block that holds
+a real position (the pairs in order, from two prefetched tables), the state
+kernel the blocks up to it, so a block wholly past the length (a bucket's
+padding) is neither copied in nor computed and costs no grid step; inside the
+last live block the padding's k, v and log-decay are zeroed and move nothing.
 """
 
 from __future__ import annotations
@@ -296,26 +302,28 @@ def retention_decode(q, k, v, lam, state, norm, layer, active, *, interpret=None
 # --------------------------------------------------------------------------
 
 
-def _retention_prefill_kernel(q_ref, k_ref, v_ref, cq_ref, ck_ref, o_ref, acc_ref, den_ref,
+def _retention_prefill_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, cq_ref, ck_ref, o_ref, acc_ref, den_ref,
                               *, block):
-    i, j = pl.program_id(1), pl.program_id(2)
+    """Query block i against key block j <= i, the pair this grid step's
+    entry of the prefetched tables names: a query block's pairs follow one
+    another from j = 0 to j = i."""
+    step = pl.program_id(1)
+    i, j = i_ref[step], j_ref[step]
 
     @pl.when(j == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
 
-    @pl.when(j <= i)
-    def _():
-        s = jax.lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [Bq, Bk]
-        row = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        decay = jnp.exp(jnp.minimum(cq_ref[...] - ck_ref[...], 0.0))
-        a = jnp.where(row >= col, s * s * decay, 0.0)
-        acc_ref[...] += jnp.dot(a.astype(v_ref.dtype), v_ref[...],
-                                preferred_element_type=jnp.float32)
-        den_ref[...] += jnp.sum(a, axis=1, keepdims=True)
+    s = jax.lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [Bq, Bk]
+    row = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    decay = jnp.exp(jnp.minimum(cq_ref[...] - ck_ref[...], 0.0))
+    a = jnp.where(row >= col, s * s * decay, 0.0)
+    acc_ref[...] += jnp.dot(a.astype(v_ref.dtype), v_ref[...],
+                            preferred_element_type=jnp.float32)
+    den_ref[...] += jnp.sum(a, axis=1, keepdims=True)
 
     @pl.when(j == i)
     def _():
@@ -345,14 +353,21 @@ def _retention_state_kernel(k_ref, v_ref, s_ref, z_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
-def retention_prefill(q, k, v, lam, *, dtype=jnp.bfloat16, interpret=None):
+def retention_prefill(q, k, v, lam, *, length=None, dtype=jnp.bfloat16, interpret=None):
     """The first form over a whole sequence from an empty state.  q: [T, H,
     d]; k, v: [T, G, d]; lam: [T, G]; T a power of two or a multiple of 256.
-    A position with ``k`` 0 and ``lam`` 0 moves neither state nor normaliser
-    (a prompt's bucket padding, which lies behind every real position).
-    Products in ``dtype``, sums in float32.  Returns (o [T, H, d_v] float32,
-    the state [G, D, d_v, d] and the normaliser [G, norm_rows, d] after the
-    last position, float32)."""
+    ``length`` (int32 scalar, traced, 0 < length <= T; None: T): the first
+    ``length`` positions are real and the rest a bucket's padding, which is
+    not read (whatever lies there, NaN too) and moves neither state nor
+    normaliser: both kernels' grids end with the last block of 256 positions
+    that holds a real one, so a block wholly past ``length`` is neither
+    copied nor computed, and ITS ROWS OF ``o`` MEAN NOTHING (they are
+    whatever the buffer held: the caller keeps rows below ``length`` only, a
+    prefill the one at ``length - 1``).  Without ``length`` a position with
+    ``k`` 0 and ``lam`` 0 moves nothing either.  Products in ``dtype``, sums
+    in float32.  Returns (o [T, H, d_v] float32, the state [G, D, d_v, d] and
+    the normaliser [G, norm_rows, d] after position ``length - 1``,
+    float32)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     T, H, d = q.shape
@@ -362,30 +377,44 @@ def retention_prefill(q, k, v, lam, *, dtype=jnp.bfloat16, interpret=None):
         raise ValueError(f"retention_prefill: {T} positions do not tile by {block}")
     group, n = H // G, T // block
     lam = lam.astype(jnp.float32)
+    live = n  # blocks that hold a real position: the grids' bounds, data where the length is
+    if length is not None:
+        real = jnp.arange(T) < length  # inside the last live block the padding holds still
+        k, v = (jnp.where(real[:, None, None], x, 0.0) for x in (k, v))
+        lam = jnp.where(real[:, None], lam, 0.0)
+        live = (jnp.clip(jnp.asarray(length, jnp.int32), 1, T) - 1) // block + 1
     cum = jnp.cumsum(lam, axis=0).T  # [G, T]: the log-decay from the start, inclusive
     heads = lambda x: x.transpose(1, 0, 2)
     qs = heads(q.astype(jnp.float32) * d ** -0.5).astype(dtype)  # the 1 / sqrt(d) inside the square
-    # a block above the diagonal is never read: name the diagonal's again
-    kv_block = pl.BlockSpec((None, block, d), lambda h, i, j: (h // group, jnp.minimum(i, j), 0))
+    # The causal triangle's block pairs, query block by query block: the first
+    # live (live + 1) / 2 of them are the live query blocks', and the grid
+    # visits those and no other (no step for a pair above the diagonal).
+    query_of, key_of = (jnp.asarray(x, jnp.int32) for x in zip(
+        *((i, j) for i in range(n) for j in range(i + 1))))
+    q_block = pl.BlockSpec((None, block, d), lambda h, s, i, j: (h, i[s], 0))
+    kv_block = pl.BlockSpec((None, block, d), lambda h, s, i, j: (h // group, j[s], 0))
     with jax.named_scope("retention_prefill"):
         o = pl.pallas_call(
             functools.partial(_retention_prefill_kernel, block=block),
             out_shape=jax.ShapeDtypeStruct((H, T, d), jnp.float32),
-            grid=(H, n, n),
-            in_specs=[
-                pl.BlockSpec((None, block, d), lambda h, i, j: (h, i, 0)),
-                kv_block, kv_block,
-                pl.BlockSpec((None, block, 1), lambda h, i, j: (h // group, i, 0)),
-                pl.BlockSpec((None, 1, block), lambda h, i, j: (h // group, 0, jnp.minimum(i, j))),
-            ],
-            out_specs=pl.BlockSpec((None, block, d), lambda h, i, j: (h, i, 0)),
-            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
-                            pltpu.VMEM((block, 1), jnp.float32)],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(H, live * (live + 1) // 2),
+                in_specs=[
+                    q_block, kv_block, kv_block,
+                    pl.BlockSpec((None, block, 1), lambda h, s, i, j: (h // group, i[s], 0)),
+                    pl.BlockSpec((None, 1, block), lambda h, s, i, j: (h // group, 0, j[s])),
+                ],
+                out_specs=q_block,
+                scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                                pltpu.VMEM((block, 1), jnp.float32)],
+            ),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
             name="retention_prefill",
-        )(qs, heads(k).astype(dtype), heads(v).astype(dtype), cum[:, :, None], cum[:, None, :])
+        )(query_of, key_of, qs, heads(k).astype(dtype), heads(v).astype(dtype),
+          cum[:, :, None], cum[:, None, :])
     # phi is of degree 2: the square root of what is left of position j at
     # the end, put into k, leaves phi(k_j) times all of it.
     left = jnp.exp(0.5 * (cum[:, -1:] - cum))  # [G, T]
@@ -396,7 +425,7 @@ def retention_prefill(q, k, v, lam, *, dtype=jnp.bfloat16, interpret=None):
             _retention_state_kernel,
             out_shape=[jax.ShapeDtypeStruct((G, D, d, d), jnp.float32),
                        jax.ShapeDtypeStruct((G, norm_rows(d), d), jnp.float32)],
-            grid=(G, n),
+            grid=(G, live),
             in_specs=[pl.BlockSpec((None, block, d), lambda g, t: (g, t, 0)),
                       pl.BlockSpec((None, d, block), lambda g, t: (g, 0, t))],
             out_specs=[pl.BlockSpec((None, D, d, d), lambda g, t: (g, 0, 0, 0)),

@@ -127,9 +127,11 @@ _M_QWAIT = _REG.gauge(
 )
 _M_PAD_TOKENS = _REG.counter(
     "serve_pad_tokens_total",
-    "tokens of padding waste: bucket pad rows and decode overrun in the "
-    "batch-synchronous arm, prompt-bucket padding in the engine arm — "
-    "subtract from gross throughput to get REAL tokens/s",
+    "tokens of padding: bucket pad rows and decode overrun in the "
+    "batch-synchronous arm; in the engine arm the padding a prompt's bucket "
+    "HOLDS, which a model may skip (serve_prefill_rows_computed_total is what "
+    "its prefills still computed) — subtract from gross throughput to get "
+    "REAL tokens/s",
 )
 _M_PHASE = _REG.histogram(
     "serve_phase_seconds",

@@ -213,6 +213,49 @@ def test_write_slot_rows_overwrites_one_slot_whole_in_the_leafs_dtype():
         assert (np.asarray(out[name])[[0, 2]] == 7).all()
 
 
+def _row_wise(xs, scale=2.0):
+    """A pytree in, a pytree out, every row from its own row."""
+    a, b = xs
+    return {"sum": a * scale + b[:, None], "parts": (jnp.sum(a, axis=-1), b * 3)}
+
+
+@pytest.mark.parametrize("rows, tile, whiles", [(8, 8, 0), (8, 16, 0), (16, 8, 1), (32, 8, 1)])
+def test_over_live_rows_computes_the_live_tiles_and_no_other(rows, tile, whiles):
+    """A bucket of at most one tile is ``fn`` once, no loop in the lowered
+    text; of several, ONE ``while`` whose trip count is data: every ``live``
+    runs the same compiled program, the tiles past it come back 0 and the last
+    live tile whole (its padding is ``fn``'s to see)."""
+    rng = np.random.default_rng(rows + tile)
+    xs = (jnp.asarray(rng.standard_normal((rows, 3)), jnp.float32),
+          jnp.asarray(rng.integers(1, 9, rows), jnp.int32))
+    run = jax.jit(lambda xs, live: parts.over_live_rows(_row_wise, xs, live, tile))
+    assert run.lower(xs, jnp.int32(1)).as_text().count("stablehlo.while") == whiles
+    whole = _row_wise(xs)
+    for live in range(1, rows + 1):
+        computed = rows if rows <= tile else -(-live // tile) * tile
+        got = run(xs, jnp.int32(live))
+        assert jax.tree.structure(got) == jax.tree.structure(whole)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(whole)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.array_equal(np.asarray(g[:computed]), np.asarray(w[:computed]))
+            assert not np.asarray(g[computed:]).any()
+    assert run._cache_size() == 1
+
+
+def test_over_live_rows_without_a_length_or_with_a_ragged_bucket():
+    xs = (jnp.ones((24, 3)), jnp.arange(24, dtype=jnp.int32))
+    once = jax.jit(lambda xs: parts.over_live_rows(_row_wise, xs, None, 8))  # logits(): all rows
+    assert once.lower(xs).as_text().count("stablehlo.while") == 0
+    assert np.array_equal(np.asarray(once(xs)["sum"]), np.asarray(_row_wise(xs)["sum"]))
+    # what is held from tile to tile reaches ``fn`` behind the tiles, with or without a loop
+    for tile in (8, 24):
+        held = jax.jit(lambda xs, s: parts.over_live_rows(_row_wise, xs, jnp.int32(24), tile, (s,)))
+        assert np.array_equal(np.asarray(held(xs, jnp.float32(5.0))["sum"]),
+                              np.asarray(_row_wise(xs, 5.0)["sum"]))
+    with pytest.raises(ValueError, match="24 rows are not whole tiles of 16"):
+        parts.over_live_rows(_row_wise, xs, jnp.int32(3), 16)
+
+
 if __name__ == "__main__":
     for name in sorted(DECODERS):
         print(f"    {name!r}: {_draws_and_tokens(name)!r},")

@@ -55,7 +55,7 @@ def _highest(fn, *args):
 # ------------------------------------------------------------------ the file
 def test_builds_from_the_published_keys(model):
     assert (model.num_attention_heads, model.num_key_value_heads, model.head_dim) == (4, 2, 128)
-    assert model.step_counters == 1 and model.prefill_counters == 0
+    assert model.step_counters == 1 and model.prefill_counters == 1
     assert not hasattr(model, "cache_spec") and not hasattr(model, "write_rows")
     spec = model.state_spec(3)
     assert spec["state"].shape == (3, 2, 2, 65, 128, 128) and spec["state"].dtype == jnp.float32
@@ -137,6 +137,36 @@ def test_prefill_equals_the_recurrence_and_padding_moves_nothing(T, real):
     assert not np.asarray(got_z)[:, retention.diagonals(128):].any()  # the tiles' spare rows
 
 
+# a block edge of the kernels (256) from below, at and above; the whole bucket
+@pytest.mark.parametrize("T,real", [
+    (512, 255), (512, 256), (512, 257), (512, 512),
+    (1024, 511), (1024, 512), (1024, 513), (1024, 769), (1024, 1024)])
+def test_prefill_with_a_length_neither_reads_nor_counts_what_lies_past_it(T, real):
+    """``length`` in place of zeroed keys and log-decay: the rows past it are
+    NaN in q, k and v and reach neither the live rows of ``o`` nor state nor
+    normaliser; the blocks wholly past it are not visited at all."""
+    q, k, v, lam = _inputs(T, seed=T + real)
+    valid = jnp.arange(T) < real
+    _o, want_S, want_z = retention.recurrent_retention(
+        q[:real], k[:real], v[:real], lam[:real], *_empty())
+    # the read-outs against the first form itself: the recurrence's are 8,320 terms of
+    # either sign, a hundredth off where a position's weights sum to 1e-4
+    want_o = _quadratic(q[:real], k[:real], v[:real], lam[:real])
+    masked_o, masked_S, masked_z = retention.retention_prefill(  # the call of before
+        q, jnp.where(valid[:, None, None], k, 0.0), v, jnp.where(valid[:, None], lam, 0.0),
+        dtype=jnp.float32)
+    poison = lambda x: jnp.where(valid[:, None, None], x, jnp.nan)
+    got_o, got_S, got_z = retention.retention_prefill(
+        poison(q), poison(k), poison(v), lam, length=jnp.int32(real), dtype=jnp.float32)
+    np.testing.assert_allclose(got_o[:real], want_o, atol=1e-4)
+    np.testing.assert_allclose(got_S, want_S, rtol=1e-4, atol=1e-4 * float(jnp.abs(want_S).max()))
+    np.testing.assert_allclose(got_z, want_z, rtol=1e-4, atol=1e-4 * float(jnp.abs(want_z).max()))
+    # the same blocks in the same order: the masked call's sums, to the last bit
+    np.testing.assert_array_equal(got_o[:real], masked_o[:real])
+    np.testing.assert_array_equal(got_S, masked_S)
+    np.testing.assert_array_equal(got_z, masked_z)
+
+
 @pytest.mark.parametrize("active", [(True, False, True, True), (False,) * 4, (True,) * 4])
 def test_decode_kernel_in_interpret_mode_equals_the_jnp_step(active):
     q, k, v, lam = _inputs(4, seed=3)
@@ -192,7 +222,7 @@ def _decode_against_reference(model, params, lengths=(127, 129, 40), steps=6, ho
     for s, n in enumerate(lengths):
         rows, _logits, counters = _highest(
             prefill, params, jnp.pad(seqs[s][:n], (0, bucket(n) - n))[None], jnp.int32(n), 16)
-        assert counters is None
+        assert counters.shape == (1,) and int(counters[0]) == bucket(n)  # one row tile or less
         cache = model.write_state(cache, rows, s)
     want = [_highest(ref.logits, params, seq, CFG) for seq in seqs]
     decode = jax.jit(model.decode)
@@ -219,6 +249,36 @@ def test_a_bfloat16_state_fails_the_tolerance(model, params):
     state: rounded to bfloat16 after every step."""
     rounded = lambda cache: {**cache, "state": cache["state"].astype(jnp.bfloat16).astype(jnp.float32)}
     assert _decode_against_reference(model, params, hook=rounded) > 10 * TOL
+
+
+# ------------------------------------------- a prefill without its padding
+@pytest.mark.parametrize("bucket,lengths", [
+    (128, (1, 63, 64, 65, 128)),                           # two row tiles of 64
+    (256, (64, 65, 128, 129, 192, 193, 255, 256)),         # four
+])
+def test_prefill_over_live_row_tiles_equals_the_one_call_path(model, params, monkeypatch, bucket, lengths):
+    """The position-wise work a tile of 64 rows at a time, up to the prompt's
+    length: state, normaliser and logits are the whole bucket's in one call
+    (the module's own tile holds it), whatever token ids lie past ``tp``."""
+    from moolib_tpu.models import retention_lm
+
+    whole, tiled = (jax.jit(lambda p, toks, tp: model.prefill(p, toks, tp, 16)) for _ in range(2))
+
+    def call(fn, tile, toks, tp):
+        monkeypatch.setattr(retention_lm, "_ROW_TILE", tile)  # read where the call is traced
+        return _highest(fn, params, toks[None], jnp.int32(tp))
+
+    seq = _tokens(bucket, seed=bucket)
+    for tp in lengths:
+        pad = jnp.arange(bucket) >= tp
+        want_rows, want_logits, want_count = call(whole, 512, jnp.where(pad, 0, seq), tp)
+        got_rows, got_logits, got_count = call(tiled, 64, jnp.where(pad, 383 - seq, seq), tp)
+        assert int(want_count[0]) == bucket and int(got_count[0]) == -(-tp // 64) * 64
+        assert float(jnp.max(jnp.abs(got_logits - want_logits))) < TOL
+        for name in ("state", "norm"):
+            scale = float(jnp.abs(want_rows[name]).max())
+            np.testing.assert_allclose(got_rows[name], want_rows[name], rtol=1e-5, atol=1e-5 * scale)
+    assert whole._cache_size() == 1 and tiled._cache_size() == 1  # the length is data
 
 
 # -------------------------------------------------------- through the engine
@@ -280,3 +340,33 @@ def test_a_slot_is_reused_and_a_join_that_writes_no_state_is_seen(model, params)
         worst[name] = max(_gaps(params, requests[0][0], first).max(),
                           _gaps(params, requests[1][0], second).max())
     assert worst["sound"] < TOL < 100 * TOL < worst["planted"]
+
+
+def test_the_engine_hands_the_rows_computed_to_observe_prefill(params, monkeypatch):
+    """``prefill_counters`` = 1: whole row tiles up to the prompt, riding the
+    first token's vector home, into ``serve_prefill_rows_computed_total``."""
+    from moolib_tpu import telemetry
+    from moolib_tpu.models import retention_lm
+
+    monkeypatch.setattr(retention_lm, "_ROW_TILE", 32)  # buckets of 64 and 128: two and four tiles
+    seen = []
+
+    class Spy(PowerRetentionLM):
+        def observe_prefill(self, counters, prompt_len):
+            seen.append((int(counters[0]), prompt_len))
+            super().observe_prefill(counters, prompt_len)
+
+    rows = lambda: telemetry.get_registry().counter_values().get(
+        "serve_prefill_rows_computed_total", 0)
+    before = rows()
+    with jax.default_matmul_precision("highest"):
+        eng = _engine(Spy.from_config(CFG, dtype=jnp.float32, max_len=512), params)
+        requests = [(np.asarray(_tokens(n, seed=40 + n)), b)
+                    for n, b in ((63, 3), (65, 2), (97, 2), (20, 2))]
+        out = _run(eng, requests[:3]) | {3: _run(eng, requests[3:])[0]}
+    assert sorted(seen) == [(32, 20), (64, 63), (96, 65), (128, 97)]
+    assert rows() - before == 32 + 64 + 96 + 128
+    stats = eng.stats()
+    assert stats["prefill_tokens"] + stats["prefill_pad_tokens"] == 64 + 128 + 128 + 64
+    for i, (prompt, budget) in enumerate(requests):
+        assert len(out[i]) == budget and _gaps(params, prompt, out[i]).max() < TOL

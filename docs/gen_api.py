@@ -65,10 +65,12 @@ MODULES = [
     ("moolib_tpu.models.latent_moe", "Models: latent-attention decoder with dropless experts"),
     ("moolib_tpu.models.retention_lm", "Models: power-retention decoder (a state a slot, no paged cache)"),
     ("moolib_tpu.models.jamba", "Models: Mamba-1 / multi-query decoder (a scan state a slot beside paged K/V)"),
+    ("moolib_tpu.models.ssd_moe", "Models: Mamba-2 / NoPE grouped-query decoder with experts behind every layer"),
     ("moolib_tpu.ops.vtrace", "Ops: V-trace"),
     ("moolib_tpu.ops.flash_attention", "Ops: Flash attention (pallas)"),
     ("moolib_tpu.ops.retention", "Ops: power retention, degree 2 (pallas)"),
     ("moolib_tpu.ops.selective_scan", "Ops: selective state-space scan, Mamba-1 (pallas)"),
+    ("moolib_tpu.ops.ssd", "Ops: state-space recurrence with a scalar decay a head, Mamba-2 (pallas)"),
     ("moolib_tpu.ops.returns", "Ops: returns / losses"),
     ("moolib_tpu.ops.xent", "Ops: chunked cross-entropy (LM head)"),
     ("moolib_tpu.telemetry", "Telemetry (package)"),

@@ -1,8 +1,11 @@
 """What the decoders built from a published configuration file share
-(``latent_moe``, ``hybrid_kda``, ``retention_lm``, ``swa_moe``, ``jamba``): everything
-that is not a mixer, as plain functions, and the grouped-query layer's pieces
-that two of them run (its projections, its paged decode, the counters of an
-expert layer of which this chip holds a share).  Nothing here knows the serving engine: ``engine/``
+(``latent_moe``, ``hybrid_kda``, ``retention_lm``, ``swa_moe``, ``jamba``, ``ssd_moe``):
+everything that is not a mixer, as plain functions, and the pieces that two
+of them run: the grouped-query layer's (its projections, its paged decode),
+the counters of an expert layer of which this chip holds a share, and what
+the two decoders with state-space layers have in common (the runs of such
+layers between attention layers, the short convolution over a bucket and
+over a step with its tail, the prefill scan's count of real positions).  Nothing here knows the serving engine: ``engine/``
 imports ``models/``, never the other way.  Matmul inputs are in the model's
 ``dtype`` with float32 accumulation; RMSNorm, RoPE and the logits float32.
 """
@@ -17,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
+from ..ops import selective_scan as ssm
 from ..ops.paged_attention import paged_attention, paged_kv_write
 
 _REG = telemetry.get_registry()
@@ -43,6 +47,13 @@ _M_STATE_LIVE = _REG.histogram(
     "per decode step: slots holding live recurrent state (the active ones: "
     "the states the model's decode kernel reads and writes, a layer)",
     buckets=(1, 2, 4, 8, 16, 24, 32, 48, 64, 96, 128, 256),
+)
+_M_SCAN_POSITIONS = _REG.histogram(
+    "serve_engine_scan_prefill_positions",
+    "per prefill of a model with state-space layers: the prompt's real "
+    "positions, the `length` its prefill scan was told (the bucket's padding "
+    "past them holds the state still; chunks wholly in it do not run)",
+    buckets=(32, 64, 128, 256, 512, 1024, 2048, 4096),
 )
 _M_PREFILL_ROWS = _REG.counter(
     "serve_prefill_rows_computed_total",
@@ -231,6 +242,13 @@ def observe_state_live(live) -> None:
     _M_STATE_LIVE.observe(int(live))
 
 
+def observe_scan_positions(positions) -> None:
+    """The ``length`` a prefill's scan was told, back on the host beside the
+    prompt's first token (a model with state-space layers hands it back in its
+    prefill counters)."""
+    _M_SCAN_POSITIONS.observe(int(positions))
+
+
 def observe_prefill_rows(rows) -> None:
     """The rows a prefill's :func:`over_live_rows` loops computed, back on the
     host (the model hands them back in its prefill counters)."""
@@ -242,6 +260,92 @@ def step_bias(key, shape, low: float = 0.001, high: float = 0.1):
     the inverse softplus of a step log-uniform in ``low`` .. ``high``, float32."""
     step = jnp.exp(jax.random.uniform(key, shape, jnp.float32, jnp.log(low), jnp.log(high)))
     return step + jnp.log(-jnp.expm1(-step))
+
+
+def runs_between(kinds, marker: str):
+    """The lengths of the runs of layers of another kind than ``marker`` in
+    ``kinds`` (a list of a layer's kind by depth): before the first ``marker``
+    layer, between two, after the last.  Each run's weights are a stack of
+    their own under one scan; the ``marker`` layers are a Python loop between
+    them."""
+    runs = [0]
+    for kind in kinds:
+        if kind == marker:
+            runs.append(0)
+        else:
+            runs[-1] += 1
+    return tuple(runs)
+
+
+CONV_TAPS = 4  # the state-space layers' short convolution; its tail is the last 3 inputs
+
+
+def conv_prefill(raw, taps, bias, last):
+    """The causal depthwise convolution in front of a state-space scan over a
+    whole bucket: raw [T, C] float32 (zeros before the prompt), taps [4, C]
+    (tap i weighs the input 3 - i positions back), bias [C].  Returns
+    (silu(convolution + bias) [T, C], the TAIL [3, C]: the three inputs up to
+    position ``last`` - 1, what a decode step at position ``last`` convolves
+    with its own)."""
+    T = raw.shape[0]
+    raw = jnp.pad(raw, ((CONV_TAPS - 1, 0), (0, 0)))
+    u = jax.nn.silu(sum(raw[i:i + T] * taps[i] for i in range(CONV_TAPS)) + bias)
+    return u, jax.lax.dynamic_slice_in_dim(raw, last, CONV_TAPS - 1, axis=0)
+
+
+def conv_step(conv, wide, first: int, taps, bias, layer, active, slots=None):
+    """The same convolution for one token a row: conv the tails' leaf [slots,
+    layers, 3 C / 128, 128] float32 whole (``ops.selective_scan``: tap t in rows
+    ``t C / 128`` onwards); this step's inputs are columns ``first .. first +
+    C`` of wide [R, ..] (the in-projection's output, C the taps' width);
+    ``layer`` the index into the leaf, ``active`` [R] the rows that step,
+    ``slots`` [R] each row's slot (None: row i is slot i).  Returns
+    (silu(convolution + bias) [R, C], the leaf with the active rows' tails
+    shifted by this input, written back in place by ``conv_tail_write``)."""
+    R, C = wide.shape[0], taps.shape[-1]
+    # The tails the step needs, in the leaf's own tiles ([.., 3, channels /
+    # 128, 128]: nothing of them is laid out anew): the layer's, or with
+    # fewer rows than slots the rows' slots' alone.
+    if slots is None:
+        tail = jax.lax.dynamic_index_in_dim(conv, layer, 1, keepdims=False)
+    else:
+        tail = conv[slots, layer]
+    rows = C // ssm.LANES  # of a tap
+    tiles = lambda x, *lead: x.reshape(lead + (rows, ssm.LANES))
+    if tail.shape[1] != (CONV_TAPS - 1) * rows:
+        # The leaf has spare rows (conv_tail_spec: a tap's rows are not whole
+        # (8, 128) tiles, 66 at 8,448 channels), so [R, 3 x rows] -> [R, 3,
+        # rows] is no bitcast: the taps stay side by side in one row axis, tap
+        # t a slice of it, in front of the spare rows.
+        held = (CONV_TAPS - 1) * rows
+        window = jnp.concatenate([tail[:, :held], tiles(wide[:, first:first + C], R)], axis=1)
+        u = sum(window[:, t * rows:(t + 1) * rows] * tiles(taps[t]) for t in range(CONV_TAPS))
+        u = jax.nn.silu(u + tiles(bias)).reshape(R, C)
+        return u, ssm.conv_tail_write(conv, tail_rows(window[:, rows:]), layer, active, slots)
+    window = jnp.concatenate(  # [R, 4, channels / 128, 128]
+        [tiles(tail, R, CONV_TAPS - 1), tiles(wide[:, first:first + C], R, 1)], axis=1)
+    u = jax.nn.silu(jnp.sum(window * tiles(taps, CONV_TAPS), axis=1) + tiles(bias))
+    u = u.reshape(R, C)
+    return u, ssm.conv_tail_write(conv, window[:, 1:].reshape(tail.shape), layer, active, slots)
+
+
+def conv_tail_spec(slots: int, layers: int, channels: int):
+    """The tails' leaf of ``state_spec``: ``[slots, layers, 3 channels / 128,
+    128]`` float32, the rows rounded up to whole (8, 128) tiles (198 -> 200 at
+    8,448 channels).  Why the spare rows: the chip lays an array out so that
+    its tiles hold the least padding, and for 198 rows that puts the SLOTS on
+    the sublanes; ``conv_tail_write`` wants a slot's tail contiguous, and the
+    compiler then laid the whole leaf out anew on either side of every decode
+    step (two copies of 117 MB; ``tests/test_chip_compile.py`` holds it)."""
+    rows = (CONV_TAPS - 1) * channels // ssm.LANES
+    return jax.ShapeDtypeStruct((slots, layers, -(-rows // 8) * 8, ssm.LANES), jnp.float32)
+
+
+def tail_rows(tail):
+    """Tails [.., 3 channels / 128, 128] (or a prefill's [.., 3, channels]) as
+    the rows of :func:`conv_tail_spec`'s leaf: the spare rows are 0."""
+    tail = tail.reshape(tail.shape[0], -1, ssm.LANES)
+    return jnp.pad(tail, ((0, 0), (0, -tail.shape[1] % 8), (0, 0)))
 
 
 def write_slot_rows(leaves, rows, slot):

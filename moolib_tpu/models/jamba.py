@@ -53,22 +53,13 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import telemetry
 from ..ops import selective_scan as ssm
 from ..ops.flash_attention import flash_attention
 from ..ops.paged_attention import PagedState
 from ..parallel.moe import swiglu
 from . import decoder_parts as parts
 
-_CONV = 4  # the convolution's taps; the tail is the last _CONV - 1 inputs
-
-_M_SCAN_POSITIONS = telemetry.get_registry().histogram(
-    "serve_engine_scan_prefill_positions",
-    "per prefill of a model with state-space layers: the prompt's real "
-    "positions, the `length` its prefill scan was told (the bucket's padding "
-    "past them holds the state still; chunks wholly in it do not run)",
-    buckets=(32, 64, 128, 256, 512, 1024, 2048, 4096),
-)
+_CONV = parts.CONV_TAPS  # the convolution's taps; the tail is the last _CONV - 1 inputs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,9 +137,9 @@ class JambaLM:
     def runs(self) -> Tuple[int, ...]:
         """Mamba layers before the first attention layer, between two, and
         after the last."""
-        between = self.attn_layer_period - 1
-        return ((self.attn_layer_offset,) + (between,) * (self.attn_layers - 1)
-                + (between - self.attn_layer_offset,))
+        return parts.runs_between(
+            ["attention" if l % self.attn_layer_period == self.attn_layer_offset else "mamba"
+             for l in range(self.num_hidden_layers)], "attention")
 
     @property
     def mamba_layers(self) -> int:
@@ -162,7 +153,7 @@ class JambaLM:
 
     def observe_prefill(self, counters, prompt_len: int) -> None:
         """A prefill's counter, back on the host beside its first token."""
-        _M_SCAN_POSITIONS.observe(int(counters[0]))
+        parts.observe_scan_positions(counters[0])
 
     def cache_spec(self, num_blocks: int, block_size: int):
         pool = jax.ShapeDtypeStruct((num_blocks, block_size, 1, self.head_dim), self.dtype)
@@ -285,11 +276,9 @@ class JambaLM:
         the convolution's tail [3, d_inner] after position last - 1).  The
         kernel holds the state still over the padding and runs no chunk that
         lies wholly in it."""
-        T, Ci = h.shape[0], self.d_inner
+        Ci = self.d_inner
         uz = self._dot(self._norm(h, p["mixer_norm"]), p["w_in"])
-        raw = jnp.pad(uz[:, :Ci], ((_CONV - 1, 0), (0, 0)))
-        u = jax.nn.silu(sum(raw[i:i + T] * p["conv"][i] for i in range(_CONV)) + p["conv_bias"])
-        tail = jax.lax.dynamic_slice_in_dim(raw, last, _CONV - 1, axis=0)
+        u, tail = parts.conv_prefill(uz[:, :Ci], p["conv"], p["conv_bias"], last)
         dt, B, C, A = self._scan_inputs(p, u)
         y, state = ssm.ssm_prefill(u, dt, uz[:, Ci:], A, B, C, p["d"], length=last)
         return h + self._dot(y, p["w_out"]), state, tail
@@ -340,22 +329,10 @@ class JambaLM:
         row's slot in the leaves (None: row i is slot i).  (The step's
         ``PagedState`` whole, not its two fields: a subclass that wraps this
         method hands it on unread.)"""
-        Ci, R = self.d_inner, h.shape[0]
+        Ci = self.d_inner
         active, slots = paged.active, paged.slots
         uz = self._dot(self._norm(h, p["mixer_norm"]), p["w_in"])
-        # The tails the step needs, in the leaf's own tiles ([.., 3, channels /
-        # 128, 128]: nothing of them is laid out anew): the layer's, or with
-        # fewer rows than slots the rows' slots' alone.
-        if slots is None:
-            tail = jax.lax.dynamic_index_in_dim(conv, layer, 1, keepdims=False)
-        else:
-            tail = conv[slots, layer]
-        tiles = lambda x, *lead: x.reshape(lead + (Ci // ssm.LANES, ssm.LANES))
-        window = jnp.concatenate(  # [R, 4, channels / 128, 128]
-            [tiles(tail, R, _CONV - 1), tiles(uz[:, :Ci], R, 1)], axis=1)
-        u = jax.nn.silu(jnp.sum(window * tiles(p["conv"], _CONV), axis=1) + tiles(p["conv_bias"]))
-        u = u.reshape(R, Ci)
-        conv = ssm.conv_tail_write(conv, window[:, 1:].reshape(tail.shape), layer, active, slots)
+        u, conv = parts.conv_step(conv, uz, 0, p["conv"], p["conv_bias"], layer, active, slots)
         dt, B, C, A = self._scan_inputs(p, u)
         y, state = ssm.ssm_decode(u, dt, A, B, C, state, layer, active, slots)
         y = (y + p["d"] * u) * jax.nn.silu(uz[:, Ci:])

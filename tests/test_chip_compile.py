@@ -1037,6 +1037,145 @@ def test_ssm_prefill_kernel_compiles_through_mosaic_at_the_cells_buckets(chip, b
 
 
 # ---------------------------------------------------------------------------
+# granite-4.0-h-small in the engine (PR 56): the decode step of 128 slots and the
+# largest prefill of granite_serve_sessions, one period at the published widths
+# ---------------------------------------------------------------------------
+def _granite(monkeypatch):
+    import json
+    import os
+
+    from moolib_tpu.models.ssd_moe import SsdGqaMoELM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "granite-4.0-h-small.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "chipbench", "traffic", "serve_sessions.json")) as f:
+        traffic = json.load(f)
+    model = SsdGqaMoELM.from_config(
+        config, max_len=traffic["positions_per_slot"], **config["uses"]["serve"])
+    return model, jax.eval_shape(model.init, jax.random.key(0)), traffic
+
+
+def test_granite_decode_step_updates_its_state_in_place_and_copies_no_table(chip, monkeypatch):
+    """5.92 GB of weights (one period, 18 of 72 experts a layer, a quarter of
+    the tied table once), 4.83 GB of recurrent state, 0.12 GB of convolution
+    tails and 1.61 GB of K/V pools: 12.5 GB of arguments.  The step aliases all
+    6.56 GB of cache to its outputs and copies no leaf of it: the state leaf
+    ``[128, 9, 128, 64, 128]`` float32 lies at its unpadded bytes (a head's 64
+    channels on sublanes, the states on lanes), goes through the scans over the
+    runs of 5 and 4 Mamba-2 layers as a carry and is the kernel's operand and
+    result whole; so the tails' leaf (200 rows of lanes a layer a slot: 198 in
+    whole tiles, else the chip lays the SLOTS on the sublanes and the leaf is
+    copied on either side of every step).  The head contracts against the
+    [25088, 4096] table where it lies; no run's weights are sliced out of a
+    stack (the scans' own per-layer slices aside: operands of the products)."""
+    from moolib_tpu.models.decoder_parts import SlotCache
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    model, params, traffic = _granite(monkeypatch)
+    S, bs = traffic["slots"], traffic["block_size"]
+    per = traffic["positions_per_slot"] // bs
+    cache = SlotCache(model.cache_spec(1 + S * per, bs), model.state_spec(S))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    paged = PagedState(i32(S, per), i32(S), jax.ShapeDtypeStruct((S,), jnp.bool_))
+    compiled, text = _compile(
+        jax.jit(model.decode, donate_argnums=(1,)), *_on(chip, (params, cache, i32(S), paged)))
+    nbytes = lambda tree: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    assert nbytes(params) == 5918501376
+    assert cache.slots["ssd"].shape == (128, 9, 128, 64, 128) and cache.slots["ssd"].dtype == jnp.float32
+    assert cache.slots["conv"].shape == (128, 9, 200, 128)
+    assert nbytes(cache.slots["ssd"]) == 4831838208 and nbytes(cache.slots["conv"]) == 117964800
+    assert nbytes(cache.blocks) == 2 * 3073 * 128 * 8 * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(cache)  # every cache leaf is updated where it lies
+    # the leaves at their unpadded bytes: arguments are the weights, the cache and a few KB of tables
+    assert mem.argument_size_in_bytes < nbytes(params) + nbytes(cache) + (1 << 20)
+    assert mem.argument_size_in_bytes < 12.5e9 and mem.temp_size_in_bytes < 64 << 20
+    # one call under each run's scan, the leaf whole in and out, in its row-major tiles
+    state, tail = r"f32\[128,9,128,64,128\]", r"f32\[128,9,200,128\]"
+    assert len(re.findall(rf"%ssd_decode[.\d]* = \(f32\[128,8,8,128\]\S* {state}\{{4,3,2,1,0:T\(8,128\)\}}", text)) == 2
+    assert len(re.findall(rf"%conv_tail_write[.\d]* = {tail}\{{3,2,1,0:T\(8,128\)\}}", text)) == 2
+    assert set(re.findall(rf"{state}(\{{[^}}]*\}})", text)) <= {"{4,3,2,1,0:T(8,128)}", "{4,3,2,1,0}"}
+    assert set(re.findall(rf"{tail}(\{{[^}}]*\}})", text)) <= {"{3,2,1,0:T(8,128)}", "{3,2,1,0}"}
+    assert len(re.findall(r"%paged_attention[.\d]* = f32\[128,32,128\]", text)) == 1
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[1280,", text)) == 6  # 2 x (two scan bodies + the attention layer)
+    copies = re.findall(r"= (\w+\[[\d,]*\])[^ ]* copy\(", text)
+    assert not {"f32[128,9,128,64,128]", "f32[128,9,200,128]", "bf16[3073,128,8,128]",
+                "bf16[25088,4096]"} & set(copies)
+    sizes = lambda found: [int(np.prod([int(d) for d in s.split("[")[1][:-1].split(",") if d]))
+                           for s in found]
+    assert max(sizes(copies)) <= 128 * 8448  # a step's rows, nothing of a weight's size
+    for op in ("transpose", "convert"):
+        found = re.findall(rf"= (\w+\[[\d,]*\])[^ ]* {op}\(", text)
+        assert max(sizes(found), default=0) <= 1280 * 4096, op  # the (token, expert) rows
+    # a run's weights: the scan's own slice of ONE layer, an operand of its product, never a run's
+    sliced = re.findall(r"= (\w+\[[\d,]*\])[^ ]* dynamic-slice\(", text)
+    assert max(sizes(sliced)) == 4096 * 16768 and "bf16[5,4096,16768]" not in sliced
+
+
+def test_granite_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
+    """A prompt of 2,048 positions (the mix's largest bucket): the chunked
+    recurrence as ONE kernel under each run's scan over layers (what it makes
+    of a chunk stays in VMEM: the decay matrices and ``C B^T`` are never written
+    to HBM, and nothing of size [positions, heads, d_head, d_state] exists),
+    flash attention over 32 heads in the one attention layer, 20,480 (token,
+    expert) rows through the grouped matmul.  Weights, temporaries and the
+    engine's 6.56 GB of cache stay under the chip's 16 GB."""
+    model, params, traffic = _granite(monkeypatch)
+    Lb = traffic["prompt_tokens"]["max"]
+    compiled, text = _compile(
+        jax.jit(lambda p, toks, tp: model.prefill(p, toks, tp, traffic["block_size"])),
+        *_on(chip, (params, jax.ShapeDtypeStruct((1, Lb), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 6.57e9 < 15.0e9
+    # one call under each run's scan over layers: y [positions, channels], the state [heads x d_head, d_state]
+    assert len(re.findall(r"%ssd_prefill[.\d]* = \(f32\[2048,8192\]\S* f32\[8192,128\]\S* custom-call", text)) == 2
+    assert len(re.findall(r"%flash_attention[\w.]* = \(bf16\[1,2048,4096\]", text)) == 1
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[20480,", text)) == 6
+    # no [T, 128, 64, 128] array, in any order of its axes, and no [T, T] one a head
+    assert not re.findall(r"\[2048,128,64,128\]|\[2048,128,128,64\]|\[128,2048,64,128\]", text)
+    assert not re.findall(r"f32\[128,2048,2048\]|f32\[2048,2048,128\]|f32\[2048,128,64\]", text)
+
+
+@pytest.mark.parametrize("bucket", [2048, 128])
+def test_ssd_kernels_compile_through_mosaic_at_the_cells_sizes(chip, bucket):
+    """The two kernels alone at the published widths (128 heads of 64 channels
+    and 128 states): the prefill at the mix's largest and smallest bucket with
+    a length and a state to start from, the decode step over 128 slots of 9
+    layers: a block that does not tile or a kernel over its VMEM is refused
+    here."""
+    from moolib_tpu.ops import ssd
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    compiled, text = _compile(
+        lambda x, dt, A, B, C, length, state: ssd.ssd_prefill(
+            x, dt, A, B, C, length=length, state=state, interpret=False),
+        f32(bucket, 128, 64), f32(bucket, 128), f32(128), f32(bucket, 128), f32(bucket, 128),
+        i32, f32(128, 64, 128))
+    assert re.findall(r"%ssd_prefill[.\d]* = \((f32\[[\d,]*\])", text) == [f"f32[{bucket},8192]"]
+    # the step's columns by head block and the running sums twice, beside ONE
+    # relayout of x: a bare [positions, 128, 64] argument lies with its 64
+    # channels on half-empty lanes; in the model's program x is the projection's
+    # [positions, 8192] and the two reshapes cancel (the prefill test above
+    # holds that no [2048, 128, 64] array exists there)
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        bucket * 8192 * 4 + 6 * bucket * 128 * 4 + (1 << 20))
+    if bucket == 128:
+        S = 128
+        compiled, text = _compile(
+            jax.jit(lambda x, dt, A, B, C, state, layer, active: ssd.ssd_decode(
+                x, dt, A, B, C, state, layer, active, interpret=False), donate_argnums=(5,)),
+            f32(S, 128, 64), f32(S, 128), f32(128), f32(S, 128), f32(S, 128),
+            f32(S, 9, 128, 64, 128), i32, jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=chip))
+        assert compiled.memory_analysis().alias_size_in_bytes == S * 9 * 128 * 64 * 128 * 4
+        assert "f32[128,9,128,64,128]" not in re.findall(r"= (\w+\[[\d,]*\])[^ ]* copy\(", text)
+
+
+# ---------------------------------------------------------------------------
 # the engine's decode step where there is ONE row count (PR 52): the text of
 # before there were any.  Lowered here, on the CPU, at the tiny sizes of the
 # models' own tests: what is held is the program's text, not a compile.
@@ -1071,11 +1210,12 @@ def _step_of_before(eng):
 
 
 # the cells' slot counts: lm_serve_* and glm 32, solar and laguna 64, brumby 24;
-# the Mamba decoder, which decodes rows, at the one tile it has no cell at
+# the Mamba decoder, which decodes rows, at the one tile it has no cell at; granite 128
 @pytest.mark.parametrize("module,name,slots,eos", [
     ("transformer", "TransformerLM", 32, None), ("latent_moe", "LatentMoELM", 32, 3),
     ("hybrid_kda", "HybridKdaMoELM", 64, None), ("swa_moe", "SlidingGqaMoELM", 64, 3),
     ("retention_lm", "PowerRetentionLM", 24, None), ("jamba", "JambaLM", 128, 3),
+    ("ssd_moe", "SsdGqaMoELM", 128, 3),
 ])
 def test_an_engine_of_one_row_count_lowers_the_step_of_before(module, name, slots, eos):
     """No gather of rows, no scatter of tokens, no counter of the engine's

@@ -87,6 +87,44 @@ budget of 1 needs no slot, is known before the prefill, and is the one
 admission that waits: it is answered at once.  ``joins_ahead`` counts the
 joins dispatched without a wait, beside ``joins``.
 
+**An admission that rides a step.**  A prefill of a few hundred rows and a
+decode step of a few dozen are both bound by reading the weights (float32
+weights multiplied in bfloat16 keep a v5e's MXU under the read up to some 480
+rows), so a prefill in a program of its own is a second reading of every
+matrix that the step behind it repeats.  Where the model offers
+``decode_with_prompt`` (below), ``submit`` takes the slot and the blocks and
+lights the mirrors as above but launches nothing: it RECORDS the admission,
+and the next dispatch is ``engine_admit_step`` in place of ``engine_decode``:
+the step over the slots active on the device and the prompt's forward in the
+same products (the rows laid end to end, attention alone apart), then the
+join's writes, in one donated chain with one packet.  The device's queue reads
+step N+1, admit-step N+2, step N+3.  The new slot does not decode in the
+program that carries it (its first decode token hangs on the prompt's argmax):
+the step is dispatched with the slot NOT among the slots the mirrors expect it
+to advance, so the invariant above holds as for a join, and the slot is active
+from the next step on.  Its first token is the packet's token row at the slot,
+booked when that packet is booked, before any decode token of the slot: no
+copy and no wait of its own, and one ``step()`` later than programs of its own
+would have had it.  A step over no active slot carries an admission as well as
+any, and a budget of 1 rides like any other (it holds its slot for that one
+step: the program leaves it dark, the booking reports it finished): such a
+model's admissions take no other program, and warm-up builds
+``engine_prefill`` and ``engine_join`` for none of its buckets.  A prompt's
+bucket is then at least ``_PROMPT_TILE`` rows.  **One admission a dispatch,
+and no first token later than that one ``step()``:** a second ``submit``
+before the next dispatch sends the recorded admission's step out at once,
+behind those in flight (it is a decode step like any other: nothing in it is
+work the slots would not have had), and is recorded in its turn; ``step()``
+then books every step in flight but the newest, so of k admissions in one
+pass the first k - 1 have their tokens in the ``step()`` that follows, where
+their own prefills' tokens would have been read, and the last in the one
+after.  The mirrors count every step in flight (``_expected``).  A ``retire``
+ahead of the booking still returns the token: an admission recorded and not
+yet carried goes out at once, and the packet of the step that carries it is
+read there.  ``serve_engine_admissions_total{path}`` and
+``stats()["admissions_by_path"]`` count the joins by ``step`` and ``own``, at
+their dispatch: all of the one kind under one model.
+
 **What a model offers.**  The engine knows no architecture.  It takes a
 ``models.TransformerLM`` (wrapped by ``models.transformer.PagedTransformerLM``)
 or any object with ``max_len`` (the positions it can address) and one of the
@@ -145,6 +183,18 @@ and, for whichever leaves it has:
   and for no other: a step dispatched ahead may find a slot inactive, and a
   freed slot's row must stay whatever it is until the next join replaces it.
 
+and, optionally, where the model holds pools alone and reports no counters:
+
+- ``decode_with_prompt(params, cache, tokens [R], paged, toks [1, Lb], tp,
+  block_size)`` -> (logits [R, V] of the decode rows, logits [V] of the prompt
+  at ``tp - 1``, the cache with this step's K/V written, the prompt's rows as
+  ``prefill`` hands them to ``write_rows``): ``decode`` and ``prefill`` of one
+  prompt in ONE pass over ``R + Lb`` rows, every weight matrix the operand of
+  one product.  What it returns must be what the two return run apart (to
+  rounding: a product over more rows may round otherwise), for rows active
+  or not.  A model that offers it has its admissions carried by decode steps
+  (above); the engine asks nothing else of it, and no model's name.
+
 Counters ride what the host fetches anyway (extra rows of a step's packet,
 extra entries beside a prefill's first token): no copy is added.  What they
 mean is the model's: it receives them back through ``observe_step(counters)``
@@ -159,7 +209,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -190,6 +240,13 @@ _M_JOINS_AHEAD = _REG.counter(
     "joins dispatched behind their prefill without a device wait: the first "
     "token is read in the next step(), with a step queued behind the join "
     "(over the joins: the share of admissions that the host did not wait for)",
+)
+_M_ADMISSIONS = _REG.counter(
+    "serve_engine_admissions_total",
+    "joins by the programs that carried them: path=step rode a decode step "
+    "(engine_admit_step: the prompt's rows beside the occupied slots' in one "
+    "pass over the weights), path=own took engine_prefill and engine_join",
+    labelnames=("path",),
 )
 _M_RETIRES = _REG.counter(
     "serve_engine_retires_total", "sequences retired (EOS or budget)"
@@ -249,17 +306,34 @@ _M_KV_LIVE = _REG.histogram(
 
 _ROWS_AHEAD_BYTES = 1 << 30  # a state's rows that joins dispatched ahead may hold
 _ROW_TILE = 128  # a decode step's rows come in tiles of the MXU's 128 (module docstring)
+# A prompt carried by a step brings at least a bfloat16 tile's 16 rows: fewer
+# are padded to it by the product anyway, and a bucket is a program of every
+# layer to build at warm-up.
+_PROMPT_TILE = 16
 
 
 class NoFreeSlot(RuntimeError):
     """Every decode slot is occupied — the request should stay queued."""
 
 
+class _Admission(NamedTuple):
+    """A join ``submit`` recorded for the next dispatch to carry: what the
+    program needs of it, as the host holds it."""
+
+    slot: int
+    toks: np.ndarray  # [1, Lb], the prompt padded to its bucket
+    tp: int
+    rem0: int  # the budget past the first token
+    row: np.ndarray  # the slot's row of the block table
+    written: np.ndarray  # the blocks the prompt's rows go to
+
+
 class ContinuousBatchingEngine:
     """See module docstring.  Host-side driver owning the device state
-    (KV pools, block tables, per-slot lengths/tokens/budgets) and the three
+    (KV pools, block tables, per-slot lengths/tokens/budgets) and the four
     jitted paths: bucketed prefill, donated join, fixed-shape decode step (one
-    program a row count).
+    program a row count), and the decode step that carries an admission (one a
+    row count and a prompt bucket).
 
     Single-threaded by contract: one loop (``EngineService``) calls
     ``submit``/``step``/``retire``; only ``set_params`` and the read-only
@@ -311,6 +385,10 @@ class ContinuousBatchingEngine:
         # With more than one, the engine's own counter rides the packet behind
         # the model's: did the step find more active slots than it had rows.
         self._n_packet_counters = self._n_step_counters + (len(self._row_counts) > 1)
+        # Does a decode step carry an admission (module docstring).
+        self._rides_step = hasattr(model, "decode_with_prompt")
+        if self._rides_step:
+            self.min_prompt_len = max(self.min_prompt_len, min(_PROMPT_TILE, self.max_prompt_len))
 
         self.set_params(params)
 
@@ -360,18 +438,24 @@ class ContinuousBatchingEngine:
         # prefill's device output (its copy to the host under way) and the
         # prompt's length.  The next ``step()`` reads them.
         self._first: Dict[int, Tuple[jax.Array, int]] = {}
-        # The step dispatched but not yet booked: its packet, the slots the
-        # mirrors expect it to advance ([S] bool), and which dispatch of the
-        # decode program it was (its ``seq``).
-        self._flight: Optional[Tuple[jax.Array, np.ndarray, int]] = None
+        # The join the next dispatch will carry, where one is recorded.
+        self._admission: Optional[_Admission] = None
+        # The steps dispatched and not yet booked, oldest first; of each its
+        # packet, the slots the mirrors expect it to advance ([S] bool), which
+        # dispatch of its program it was (its ``seq``), and the slot whose
+        # admission it carries and whose first token its packet therefore
+        # holds, or None.  One between two calls, and as many more as
+        # admissions of one pass dispatched behind it (module docstring).
+        self._flights: List[Tuple[jax.Array, np.ndarray, int, Optional[int]]] = []
         self._stats = {
-            "joins": 0, "joins_ahead": 0, "retires": 0, "decode_tokens": 0,
+            "joins": 0, "joins_ahead": 0, "admissions_by_path": {"step": 0, "own": 0},
+            "retires": 0, "decode_tokens": 0,
             "prefill_tokens": 0, "prefill_pad_tokens": 0, "steps": 0,
             "steps_ahead": 0, "empty_steps": 0, "row_overflows": 0,
             "steps_by_rows": {rows: 0 for rows in self._row_counts},
         }
 
-        # The engine's three device programs, each named once (devmon.jit_program:
+        # The engine's four device programs, each named once (devmon.jit_program:
         # the profiler's program line, ``jit_compiles_total{fn}`` and the
         # ``program`` of the dispatching span are one string).  The decode
         # step must stay ONE compile A ROW COUNT for the engine's lifetime
@@ -386,6 +470,11 @@ class ContinuousBatchingEngine:
         self._prefill_jit = devmon.jit_program(self._prefill_impl, "engine_prefill")
         self._join_jit = devmon.jit_program(
             self._join_impl, "engine_join", donate_argnums=(0, 1, 2, 3, 4, 5))
+        # The step that carries an admission: one trace a row count and a
+        # prompt bucket (``rows`` static, the bucket the prompt's shape).
+        self._admit_jit = devmon.jit_program(
+            self._admit_step_impl, "engine_admit_step",
+            donate_argnums=(1, 2, 3, 4, 5, 6), static_argnums=(13,))
 
     def set_params(self, params) -> None:
         """Install new weights (host or device pytree).  Called between
@@ -397,10 +486,18 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------ jit bodies
     def _step_impl(self, params, cache, tables, lengths, active, tokens,
                    remaining, rows):
+        return self._step_over(
+            lambda cache, row_tokens, paged: self.model.decode(
+                params, cache, row_tokens, paged),
+            cache, tables, lengths, active, tokens, remaining, rows)
+
+    def _step_over(self, decode, cache, tables, lengths, active, tokens,
+                   remaining, rows):
         """The step over ``rows`` rows (static): every slot, or the first
         ``rows`` of the active slots first (module docstring).  ``stepping``
         [S] are the slots this step advances: the active ones, all of them
-        unless the mirrors' invariant failed."""
+        unless the mirrors' invariant failed.  ``decode(cache, tokens [R],
+        paged)`` is the model's, with whatever else it runs in the pass."""
         S = self.slots
         slot, stepping, row_tokens = None, active, tokens
         paged = PagedState(tables, lengths, active)
@@ -410,7 +507,7 @@ class ContinuousBatchingEngine:
             paged = PagedState(None if tables is None else tables[slot],
                                lengths[slot], active[slot], slot)
             stepping = jnp.zeros_like(active).at[slot].set(paged.active, unique_indices=True)
-        logits, cache, counters = self.model.decode(params, cache, row_tokens, paged)
+        logits, cache, counters = decode(cache, row_tokens, paged)
         act = stepping.astype(jnp.int32)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         nxt = jnp.where(paged.active, nxt, row_tokens)
@@ -437,6 +534,32 @@ class ContinuousBatchingEngine:
             packet = jnp.concatenate([packet, counters.reshape(extra, S)])
         active = active & ~done
         return cache, tables, lengths, active, nxt, remaining, packet
+
+    def _admit_step_impl(self, params, cache, tables, lengths, active, tokens,
+                         remaining, toks, tp, slot, row, rem0, block_ids, rows):
+        """The step over ``rows`` rows AND the prefill and join of one prompt
+        (``toks`` [1, Lb], true length ``tp``) in the same products, then
+        ``_join_impl``'s writes: one donated chain, one packet.  ``slot`` is
+        inactive on entry, so it does not decode here; the packet's token row
+        holds its first token at ``slot``."""
+        prompt = []
+
+        def decode(cache, row_tokens, paged):
+            logits, at_last, cache, kv = self.model.decode_with_prompt(
+                params, cache, row_tokens, paged, toks, tp, self.block_size)
+            prompt.append((at_last, kv))
+            return logits, cache, None
+
+        *state, packet = self._step_over(
+            decode, cache, tables, lengths, active, tokens, remaining, rows)
+        (at_last, kv), = prompt
+        first = jnp.argmax(at_last, axis=-1).astype(jnp.int32)
+        cache, tables, lengths, active, tokens, remaining = self._join_impl(
+            *state, slot, row, tp, first, rem0, kv, block_ids)
+        # A budget of 1 is spent with the first token: the slot stays dark.
+        active = active.at[slot].set(active[slot] & (rem0 > 0))
+        return (cache, tables, lengths, active, tokens, remaining,
+                packet.at[0, slot].set(first))
 
     def _prefill_impl(self, params, toks, tp):
         """toks [1, Lb] (bucket-padded prompt), tp the true length.  Returns
@@ -509,13 +632,16 @@ class ContinuousBatchingEngine:
     def submit(self, prompt, max_new: int) -> Tuple[Optional[int], List[int]]:
         """Prefill ``prompt`` (1-D int tokens) and join a decode slot.
 
-        Returns ``(slot, emitted)``.  With a budget of 1 ``slot`` is None
+        Returns ``(slot, emitted)``.  With a budget of 1, under a model whose
+        admissions take programs of their own, ``slot`` is None
         and ``emitted`` holds the one token: the request is answered and
         never occupied a slot.  Otherwise the prefill and the join are
-        dispatched and nothing is waited for: ``emitted`` is the slot's own
-        list, empty until the first token has been read, which the next
-        ``step()`` does (module docstring); a first token equal to
-        ``eos_id`` comes back in that ``step()``'s ``finished``.  Raises :class:`NoFreeSlot` / :class:`PoolExhausted`
+        dispatched, or the admission is recorded for the next step to carry,
+        and nothing is waited for: ``emitted`` is the slot's own list, empty
+        until a ``step()`` has booked the first token (the next, or the one
+        after where a step carries the admission: module docstring); a first
+        token equal to ``eos_id`` comes back in that ``step()``'s
+        ``finished``.  Raises :class:`NoFreeSlot` / :class:`PoolExhausted`
         when full (the caller keeps the request queued) and ``ValueError``
         for oversized prompts.
         """
@@ -541,7 +667,70 @@ class ContinuousBatchingEngine:
     def _submit(self, prompt, tp: int, max_new: int):
         """``submit`` past its checks, under its span: one child span for
         each of the host's dispatches."""
-        total = tp + max_new
+        lb = self._bucket(tp)
+        toks = np.pad(prompt, (0, lb - tp))[None]
+        if lb > tp:
+            self._stats["prefill_pad_tokens"] += lb - tp
+            _M_PAD_TOKENS.inc(lb - tp)
+        if max_new == 1 and not self._rides_step:
+            # No slot to join, and known before the prefill: the one
+            # admission that waits for its token.
+            _, first = self._prefill(toks, tp)
+            return None, [self._read_first(first, tp, -1)]
+        if not self._free_slots:
+            raise NoFreeSlot(f"all {self.slots} slots occupied")
+        slot = self._free_slots[-1]
+        block_ids, row, written = [], None, None
+        if self.pool is not None:
+            n_alloc = self.pool.blocks_for(max(lb, tp + max_new))
+            block_ids = self.pool.alloc(n_alloc)  # PoolExhausted -> stay queued
+            row = np.zeros(self.max_blocks_per_seq, np.int32)
+            row[:n_alloc] = block_ids
+            written = np.asarray(block_ids[:self.pool.blocks_for(lb)], np.int32)  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
+        self._free_slots.pop()
+        admission = _Admission(slot, toks, tp, max_new - 1, row, written)
+        if self._rides_step:
+            # The next dispatch carries it: nothing is launched for it here.
+            # A dispatch carries one, so the admission recorded before it in
+            # this pass goes out now, in a step behind those in flight.
+            if self._admission is not None:
+                self._dispatch(self._expected())
+            self._admission = admission
+        else:
+            self._launch_own(admission)
+        self._slot_blocks[slot] = block_ids
+        emitted = self._emitted[slot] = []
+        self._remaining_host[slot] = max_new - 1
+        self._lengths_host[slot] = tp
+        # By the mirrors the slot is lit; a first token that is EOS left it
+        # dark on the device, which the host learns where it reads the token.
+        self._active_host[slot] = True
+        for _, stepping, _, _ in self._flights:
+            # The join lands behind the steps in flight, which saw the slot
+            # inactive whatever the mirrors expected of its last occupant.
+            stepping[slot] = False
+        self._stats["joins"] += 1
+        _M_JOINS.inc()
+        self._stats["joins_ahead"] += 1
+        _M_JOINS_AHEAD.inc()
+        self._update_gauges()
+        return slot, emitted
+
+    def _prefill(self, toks: np.ndarray, tp: int):
+        """Dispatch the prefill of a bucket-padded prompt; its first token's
+        copy to the host starts at once.  Returns (rows, first)."""
+        with telemetry.span("engine.prefill_dispatch", program=self._prefill_jit.name,
+                            seq=self._prefill_jit.seq, bucket=toks.shape[1], tokens=tp):
+            rows, first = self._prefill_jit(self._params, toks, np.int32(tp))
+            first.copy_to_host_async()
+        self._stats["prefill_tokens"] += tp
+        _M_PREFILL_TOKENS.inc(tp)
+        return rows, first
+
+    def _launch_own(self, adm: _Admission) -> None:
+        """An admission through programs of its own: the prefill and the join
+        back to back, its first token left in ``_first`` for the next
+        ``step()`` to read."""
         if len(self._first) >= self._joins_unread_max:
             # That many admissions back, the prefill has to be done before one
             # more is queued (its join, right behind it, then frees its rows).
@@ -549,66 +738,26 @@ class ContinuousBatchingEngine:
             with telemetry.span("engine.join_backpressure"):
                 # mtlint: allow-host-sync(backpressure on joins dispatched ahead, where a slot's state is hundreds of MB: bounds the rows alive on the device; never reached with a step() between two admissions)
                 next(itertools.islice(self._first.values(), back, None))[0].block_until_ready()
-        lb = self._bucket(tp)
-        with telemetry.span("engine.prefill_dispatch", program=self._prefill_jit.name,
-                            seq=self._prefill_jit.seq, bucket=lb, tokens=tp):
-            pad = lb - tp
-            toks = np.pad(prompt, (0, pad))[None]
-            if pad:
-                self._stats["prefill_pad_tokens"] += pad
-                _M_PAD_TOKENS.inc(pad)
-            rows, first = self._prefill_jit(self._params, toks, np.int32(tp))
-            first.copy_to_host_async()
-        self._stats["prefill_tokens"] += tp
-        _M_PREFILL_TOKENS.inc(tp)
-        if max_new == 1:
-            # No slot to join, and known before the prefill: the one
-            # admission that waits for its token.
-            return None, [self._read_first(first, tp, -1)]
-        if not self._free_slots:
-            raise NoFreeSlot(f"all {self.slots} slots occupied")
-        # The slot the join will take: peeked, and popped once the blocks are
-        # held (PoolExhausted leaves it free).  The join's spans carry it.
-        slot = self._free_slots[-1]
-        launch = dict(program=self._join_jit.name, seq=self._join_jit.seq, slot=slot)
-        with telemetry.span("engine.join", **launch):
-            block_ids, row, written = [], None, None
-            if self.pool is not None:
-                n_alloc = self.pool.blocks_for(max(lb, total))
-                block_ids = self.pool.alloc(n_alloc)  # PoolExhausted -> stay queued
-                row = np.zeros(self.max_blocks_per_seq, np.int32)
-                row[:n_alloc] = block_ids
-                written = np.asarray(block_ids[:self.pool.blocks_for(lb)], np.int32)  # mtlint: allow-host-sync(block_ids is the pool's host-side free list)
-            self._free_slots.pop()
-            # Where the model keeps a state a slot, the join's dispatch is
-            # also the state's write: a span of its own says what it costs.
-            with (telemetry.span("engine.state_write", **launch) if self._slot_state
-                  else contextlib.nullcontext()):
-                (self._cache, self._tables, self._lengths, self._active,
-                 self._tokens, self._remaining) = self._join_jit(
-                    self._cache, self._tables, self._lengths, self._active,
-                    self._tokens, self._remaining,
-                    np.int32(slot), row, np.int32(tp), first,
-                    np.int32(max_new - 1), rows, written,
-                )
-        self._slot_blocks[slot] = block_ids
-        emitted = self._emitted[slot] = []
-        self._first[slot] = first, tp
-        self._remaining_host[slot] = max_new - 1
-        self._lengths_host[slot] = tp
-        # By the mirrors the slot is lit; a first token that is EOS left it
-        # dark on the device, which the host learns where it reads the token.
-        self._active_host[slot] = True
-        if self._flight is not None:
-            # The join landed behind the step in flight, which saw the slot
-            # inactive whatever the mirrors expected of its last occupant.
-            self._flight[1][slot] = False
-        self._stats["joins"] += 1
-        _M_JOINS.inc()
-        self._stats["joins_ahead"] += 1
-        _M_JOINS_AHEAD.inc()
-        self._update_gauges()
-        return slot, emitted
+        rows, first = self._prefill(adm.toks, adm.tp)
+        launch = dict(program=self._join_jit.name, seq=self._join_jit.seq, slot=adm.slot)
+        # Where the model keeps a state a slot, the join's dispatch is
+        # also the state's write: a span of its own says what it costs.
+        with telemetry.span("engine.join", **launch), (
+                telemetry.span("engine.state_write", **launch) if self._slot_state
+                else contextlib.nullcontext()):
+            (self._cache, self._tables, self._lengths, self._active,
+             self._tokens, self._remaining) = self._join_jit(
+                self._cache, self._tables, self._lengths, self._active,
+                self._tokens, self._remaining,
+                np.int32(adm.slot), adm.row, np.int32(adm.tp), first,
+                np.int32(adm.rem0), rows, adm.written,
+            )
+        self._first[adm.slot] = first, adm.tp
+        self._count_admission("own")
+
+    def _count_admission(self, path: str) -> None:
+        self._stats["admissions_by_path"][path] += 1
+        _M_ADMISSIONS.inc(path=path)
 
     def _read_first(self, first: jax.Array, tp: int, slot: int) -> int:
         """A prefill's first token, on the host; the model's prefill counters
@@ -628,20 +777,28 @@ class ContinuousBatchingEngine:
         pending = self._first.pop(slot, None)
         if pending is None:
             return False
-        tok0 = self._read_first(*pending, slot)
+        return self._book_token(slot, self._read_first(*pending, slot))
+
+    def _book_token(self, slot: int, tok0: int) -> bool:
+        """``tok0`` is the slot's first token; True if the request is finished
+        with it: it is EOS, or the budget was 1 (which only a step carries
+        into a slot)."""
         self._emitted[slot].append(tok0)
-        if self.eos_id is None or tok0 != self.eos_id:
+        if self._remaining_host[slot] > 0 and (self.eos_id is None or tok0 != self.eos_id):
             return False
         self._active_host[slot] = False
         return True
 
     def step(self) -> Tuple[Dict[int, int], List[int]]:
-        """Book ONE fixed-shape decode step, the oldest not
-        yet booked.  Returns the tokens it emitted (slot -> token) and the
-        slots that finished; ``{}, []`` when nothing is active.  The step
-        after it is dispatched before this one's tokens are waited for, and
-        stays in flight until the next call (module docstring)."""
-        if self._flight is None and not self._active_host.any():
+        """Book the oldest step in flight (dispatching one first where none
+        is).  Returns the tokens it emitted (slot -> token) and the slots that
+        finished; ``{}, []`` when nothing is active.  The step after it is
+        dispatched before this one's tokens are waited for, and stays in
+        flight until the next call (module docstring).  Where admissions of
+        one pass put further steps in flight, every step but the newest is
+        booked: ``finished`` is theirs together, and a slot that emitted in
+        several reads its newest token here (its ``emitted`` list has all)."""
+        if not self._flights and self._admission is None and not self._active_host.any():
             return {}, []
         with telemetry.span("engine.step"):
             return self._step()
@@ -667,30 +824,73 @@ class ContinuousBatchingEngine:
         need = int(stepping.sum())  # mtlint: allow-host-sync(host-side numpy mirror)
         return next(rows for rows in self._row_counts if rows >= need)
 
+    def _launch_admit(self, adm: _Admission, rows: int) -> jax.Array:
+        """``_launch`` for the step that carries ``adm``."""
+        (self._cache, self._tables, self._lengths, self._active,
+         self._tokens, self._remaining, packet) = self._admit_jit(
+            self._params, self._cache, self._tables, self._lengths,
+            self._active, self._tokens, self._remaining,
+            adm.toks, np.int32(adm.tp), np.int32(adm.slot), adm.row,
+            np.int32(adm.rem0), adm.written, rows,
+        )
+        packet.copy_to_host_async()
+        return packet
+
+    def _expected(self) -> np.ndarray:
+        """The slots a step dispatched now would advance, by the mirrors:
+        lit, and still budgeted once every step in flight is counted.  (A
+        finish by EOS is not seen.)"""
+        pending = sum(stepping.astype(np.int64) for _, stepping, _, _ in self._flights)
+        return self._active_host & (self._remaining_host - pending > 0)
+
     def _dispatch(self, stepping: np.ndarray) -> None:
         """Put a step in flight that the mirrors expect to advance
-        ``stepping``."""
+        ``stepping``; it carries the recorded admission, where there is one,
+        whose slot it does not advance."""
         t0 = time.monotonic()
-        rows, seq = self._rows_for(stepping), self._step_jit.seq
-        with telemetry.span("engine.step_dispatch", program=self._step_jit.name,
-                            seq=seq, rows=rows):
-            self._flight = self._launch(rows), stepping, seq
+        if self._flights:
+            self._stats["steps_ahead"] += 1
+            _M_STEPS_AHEAD.inc()
+        adm, self._admission = self._admission, None
+        jit, name, said = self._step_jit, "engine.step_dispatch", {}
+        if adm is not None:
+            stepping[adm.slot] = False
+            jit, name = self._admit_jit, "engine.admit_step_dispatch"
+            said = dict(bucket=adm.toks.shape[1], tokens=adm.tp, slot=adm.slot)
+        rows, seq = self._rows_for(stepping), jit.seq
+        with telemetry.span(name, program=jit.name, seq=seq, rows=rows, **said):
+            packet = self._launch(rows) if adm is None else self._launch_admit(adm, rows)
+        self._flights.append((packet, stepping, seq, None if adm is None else adm.slot))
+        if adm is not None:
+            self._count_admission("step")
+            self._stats["prefill_tokens"] += adm.tp
+            _M_PREFILL_TOKENS.inc(adm.tp)
         self._stats["steps_by_rows"][rows] += 1
         _M_DECODE_ROWS.observe(rows)
         _M_PHASE.observe(time.monotonic() - t0, phase="dispatch")
 
     def _step(self):
         """``step`` with a step to book, under its span."""
-        if self._flight is None:
-            self._dispatch(self._active_host.copy())
-        (packet, stepping, seq), self._flight = self._flight, None
-        # Slots the step after this one would advance, by the mirrors: still
-        # budgeted once this one is counted.  (A finish by EOS is not seen.)
-        ahead = self._active_host & (self._remaining_host - stepping > 0)
-        if ahead.any():
-            self._dispatch(ahead)
-            self._stats["steps_ahead"] += 1
-            _M_STEPS_AHEAD.inc()
+        if not self._flights:
+            self._dispatch(self._expected())
+        if self._admission is not None or len(self._flights) == 1:
+            # One step ahead of the one about to be booked, and the step that
+            # carries what this pass recorded.
+            ahead = self._expected()
+            if self._admission is not None or ahead.any():
+                self._dispatch(ahead)
+        emissions: Dict[int, int] = {}
+        finished: List[int] = []
+        # All but the newest: one, unless admissions of this pass put steps
+        # in flight of their own.
+        for _ in range(max(1, len(self._flights) - 1)):
+            self._book(self._flights.pop(0), emissions, finished)
+        return emissions, finished
+
+    def _book(self, flight, emissions: Dict[int, int], finished: List[int]) -> None:
+        """Wait for one step's packet and book it into ``emissions`` and
+        ``finished``."""
+        packet, _, seq, admitted = flight
         t1 = time.monotonic()
         # The decode loop's D2H wait.
         with telemetry.span("engine.decode_fetch", seq=seq):
@@ -698,11 +898,13 @@ class ContinuousBatchingEngine:
             packet = np.asarray(packet)
         nxt, was_active, done = packet[:3]
         _M_PHASE.observe(time.monotonic() - t1, phase="fetch")
-        emissions: Dict[int, int] = {}
-        # The slots joined since the last call: the step just dispatched lies
-        # behind their joins, so the wait for a prefill leaves the device busy;
-        # no packet booked before this one holds a token of theirs.
-        finished: List[int] = [s for s in list(self._first) if self._book_first(s)]
+        # The slots joined by programs of their own since the last call: the
+        # step just dispatched lies behind their joins, so the wait for a
+        # prefill leaves the device busy; no packet booked before this one
+        # holds a token of theirs.
+        finished += [s for s in list(self._first) if self._book_first(s)]
+        if admitted is not None and self._book_token(admitted, int(nxt[admitted])):
+            finished.append(admitted)
         with telemetry.span("engine.step_host"):
             stepped = np.nonzero(was_active)[0]
             if self.pool is not None:
@@ -728,12 +930,11 @@ class ContinuousBatchingEngine:
                     finished.append(int(s))
                     self._active_host[s] = False
             self._stats["steps"] += 1
-            self._stats["decode_tokens"] += len(emissions)
-            _M_TOKENS.inc(len(emissions))
-            if not emissions:
+            self._stats["decode_tokens"] += len(stepped)
+            _M_TOKENS.inc(len(stepped))
+            if not len(stepped) and admitted is None:
                 self._stats["empty_steps"] += 1
                 _M_EMPTY_STEPS.inc()
-        return emissions, finished
 
     def retire(self, slot: int) -> List[int]:
         """Free the slot and its blocks, where it holds any, and return its
@@ -741,7 +942,18 @@ class ContinuousBatchingEngine:
         cleared by the step that finished the slot (donated in-place), nothing
         round-trips; a state a slot owns stays where it is until the next join
         overwrites it."""
-        self._book_first(slot)  # a slot retired before any step() came
+        # A slot retired before any step() booked its first token: recorded
+        # and not yet carried, its step goes out now;
+        if self._admission is not None and self._admission.slot == slot:
+            self._dispatch(self._expected())
+        for i, (packet, stepping, seq, admitted) in enumerate(self._flights):
+            if admitted == slot:
+                # carried by a step in flight, whose packet holds the token
+                # (and is booked later without it);
+                # mtlint: allow-host-sync(a caller that retires a slot ahead of the step() that would book it: outside the decode loop)
+                self._book_token(slot, int(np.asarray(packet)[0, slot]))
+                self._flights[i] = packet, stepping, seq, None
+        self._book_first(slot)  # or joined by programs of its own and unread.
         toks = self._emitted[slot]
         if self.pool is not None:
             self.pool.free(self._slot_blocks[slot])
@@ -766,14 +978,26 @@ class ContinuousBatchingEngine:
         """Compile every shape serving can hit: the decode step at every row
         count, one prefill
         per prompt bucket, one join per block-count bucket (one join in all
-        without pools: a state's shape does not follow the prompt's).  Warmup
+        without pools: a state's shape does not follow the prompt's), and
+        where admissions ride steps that step at every bucket and row count.  Warmup
         joins target the null block with a zero budget, so the single decode
         step that follows retires them without touching real state (slot 0's
         row of a state is overwritten: the next join replaces it whole).
         Returns the number of distinct compiled shapes."""
         shapes = 0
         seen_nbw = set()
-        for lb in sorted({self._bucket(b) for b in bucket_shapes(self.max_prompt_len)}):
+        buckets = sorted({self._bucket(b) for b in bucket_shapes(self.max_prompt_len)})
+        if self._rides_step:
+            # The step that carries an admission, a bucket and a row count: a
+            # join of the same kind as below (slot 0, the null block, a zero
+            # budget).  No admission of such a model takes another program.
+            for lb, rows in itertools.product(buckets, self._row_counts):
+                self._launch_admit(_Admission(
+                    0, np.zeros((1, lb), np.int32), lb, 0,
+                    np.zeros(self.max_blocks_per_seq, np.int32),
+                    np.zeros(self.pool.blocks_for(lb), np.int32)), rows)
+                shapes += 1
+        for lb in ([] if self._rides_step else buckets):
             rows, first = self._prefill_jit(
                 self._params, np.zeros((1, lb), np.int32), np.int32(lb))
             shapes += 1
@@ -802,11 +1026,12 @@ class ContinuousBatchingEngine:
         """Drop the step in flight, if any, unbooked: its tokens are never
         emitted, so the sequences in the slots cannot continue.  For a
         service on its way out, before it fails what is in its slots."""
-        self._flight = None
+        self._flights, self._admission = [], None
 
     # ----------------------------------------------------------------- stats
     def stats(self) -> Dict[str, Any]:
-        out = dict(self._stats, steps_by_rows=dict(self._stats["steps_by_rows"]))
+        out = dict(self._stats, steps_by_rows=dict(self._stats["steps_by_rows"]),
+                   admissions_by_path=dict(self._stats["admissions_by_path"]))
         if self.pool is not None:
             out.update(self.pool.stats())
         if self._slot_state:

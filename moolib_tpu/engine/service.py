@@ -5,7 +5,8 @@ is inherited unchanged — bounded admission with typed overload rejects,
 req-id dedup, per-request deadlines, ``{name}_stats``, and staged weights —
 while the service loop is replaced: instead of take-a-batch / run-to-the-
 longest, each iteration drains admitted requests into free decode slots
-(prefill + join, both dispatched and neither waited for) and advances ALL
+(prefill + join, both dispatched and neither waited for, or recorded for the
+step to carry where the model lets it) and advances ALL
 occupied slots by one fixed-shape decode step.  Hot swaps still land
 between iterations (here: between decode steps); in-flight sequences
 continue under the new weights.
@@ -63,9 +64,11 @@ class EngineService(ServeService):
         )
         self._engine = engine
         self._slot_req: Dict[int, _Request] = {}
-        # Requests joined since the last decode step: their first tokens are
-        # not on the host yet; the engine's next ``step()`` reads them.
-        self._first_due: List[_Request] = []
+        # Requests joined whose first token no ``step()`` has booked yet, each
+        # with its slot's ``emitted`` list: empty until one does (the next
+        # where the admission took programs of its own, the one after where
+        # it rode a step).
+        self._first_due: List[Tuple[_Request, List[int]]] = []
         # Per-token admission: pending_tokens is called under self._lock
         # (from admit/estimate_wait inside _on_request) — it only reads.
         self.admission = AdmissionController(
@@ -164,7 +167,7 @@ class EngineService(ServeService):
                 answered += 1
                 continue
             now = time.monotonic()
-            # Host time of ``submit``: two dispatches, no device wait.
+            # Host time of ``submit``: two dispatches or none, no device wait.
             _M_PHASE.observe(now - t0, phase="prefill")
             if slot is None:
                 # Finished at prefill (budget 1): the one submit that waits.
@@ -174,7 +177,7 @@ class EngineService(ServeService):
                 answered += 1
             else:
                 self._slot_req[slot] = req
-                self._first_due.append(req)
+                self._first_due.append((req, emitted))
                 joined += 1
 
     def _count_answered(self, n: int) -> None:
@@ -270,11 +273,12 @@ class EngineService(ServeService):
         emissions, finished = eng.step()
         now = time.monotonic()
         dt = now - t0
-        # Server-side time to first token: enqueue to the prefill's token on
-        # the host (queue wait included); that ``step()`` read it.
-        for req in self._first_due:
-            _M_PHASE.observe(now - req.t_enq, phase="first_token")
-        self._first_due.clear()
+        # Server-side time to first token: enqueue to the first token on the
+        # host (queue wait included), observed at the ``step()`` that booked it.
+        for req, emitted in self._first_due:
+            if emitted:
+                _M_PHASE.observe(now - req.t_enq, phase="first_token")
+        self._first_due = [due for due in self._first_due if not due[1]]
         if emissions:
             self.admission.note_service(dt, tokens=len(emissions))
             _M_PHASE.observe(dt, phase="device")

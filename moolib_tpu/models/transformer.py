@@ -103,6 +103,13 @@ class Block(nn.Module):
     # the PagedState passed at apply time.
     kv_num_blocks: int = 0
     kv_block_size: int = 16
+    # Paged decode with ONE prompt in the same pass (the engine's admission
+    # that rides a step): x is [R + prompt_rows, 1, D], the decode rows, then
+    # the rows of a bucket-padded prompt in order.  Everything row-wise runs
+    # once over all of them, so a weight matrix is read once; attention alone
+    # splits: the first R rows as in paged decode, the rest as one causal
+    # sequence, sowing its K/V as ``collect_kv`` does.
+    prompt_rows: int = 0
 
     @nn.compact
     def __call__(self, x, mesh=None, paged=None):
@@ -123,13 +130,11 @@ class Block(nn.Module):
         qkv = packed.reshape(B, T, H + 2 * Hk, hd)
         q, k, v = qkv[:, :, :H], qkv[:, :, H : H + Hk], qkv[:, :, H + Hk :]
 
-        if self.decode:
-            # Autoregressive step: x is [B, 1, D]; append this position's
+        def cached(q, k, v):
+            # Autoregressive step: one position a row; append this position's
             # K/V to the cache and attend over everything cached so far.
             # The cache holds Hk heads; query heads address their group's
             # KV head through a grouped einsum — no repeat materializes.
-            if T != 1:
-                raise ValueError(f"decode mode steps one token at a time, got T={T}")
             from ..ops.paged_attention import (
                 gathered_decode_attention,
                 paged_attention,
@@ -162,34 +167,35 @@ class Block(nn.Module):
                 pv.value = paged_kv_write(
                     pv.value, v[:, 0], paged.block_tables, t, paged.active
                 )
-                att = paged_attention(
+                return paged_attention(
                     q, pk.value, pv.value, paged.block_tables, t, paged.active,
                 ).astype(x.dtype)
-            else:
-                ck = self.variable(
-                    "cache", "k", jnp.zeros, (B, self.max_len, Hk, hd), self.dtype
-                )
-                cv = self.variable(
-                    "cache", "v", jnp.zeros, (B, self.max_len, Hk, hd), self.dtype
-                )
-                idx = self.variable(
-                    "cache", "idx", lambda: jnp.zeros((), jnp.int32)
-                )
-                t = idx.value
-                if self.rotary:
-                    q = apply_rotary(q, offset=t)
-                    k = apply_rotary(k, offset=t)
-                ck.value = jax.lax.dynamic_update_slice(
-                    ck.value, k.astype(self.dtype), (0, t, 0, 0)
-                )
-                cv.value = jax.lax.dynamic_update_slice(
-                    cv.value, v.astype(self.dtype), (0, t, 0, 0)
-                )
-                idx.value = t + 1
-                att = gathered_decode_attention(q, ck.value, cv.value, t).astype(
-                    x.dtype
-                )
-        else:
+            ck = self.variable(
+                "cache", "k", jnp.zeros, (B, self.max_len, Hk, hd), self.dtype
+            )
+            cv = self.variable(
+                "cache", "v", jnp.zeros, (B, self.max_len, Hk, hd), self.dtype
+            )
+            idx = self.variable(
+                "cache", "idx", lambda: jnp.zeros((), jnp.int32)
+            )
+            t = idx.value
+            if self.rotary:
+                q = apply_rotary(q, offset=t)
+                k = apply_rotary(k, offset=t)
+            ck.value = jax.lax.dynamic_update_slice(
+                ck.value, k.astype(self.dtype), (0, t, 0, 0)
+            )
+            cv.value = jax.lax.dynamic_update_slice(
+                cv.value, v.astype(self.dtype), (0, t, 0, 0)
+            )
+            idx.value = t + 1
+            return gathered_decode_attention(q, ck.value, cv.value, t).astype(
+                x.dtype
+            )
+
+        def causal(packed, q, k, v):
+            # Whole sequences, positions from 0: training, and a prefill.
             if self.rotary:
                 q, k = apply_rotary(q), apply_rotary(k)
             if self.collect_kv:
@@ -207,27 +213,44 @@ class Block(nn.Module):
                 )
 
                 if self.rotary:
-                    att = flash_attention(q, k, v, causal=True, mesh=mesh)
-                else:
-                    att = flash_attention_packed(
-                        packed, H, Hk, causal=True, mesh=mesh)
-            else:
-                if group > 1:
-                    # Ring and dense attention take equal head counts —
-                    # repeat KV across each group (transient; the cache and
-                    # the params stay at Hk heads).
-                    k = jnp.repeat(k, group, axis=2)
-                    v = jnp.repeat(v, group, axis=2)
-                if self.attention == "ring":
-                    from ..parallel.ring_attention import ring_attention
+                    return flash_attention(q, k, v, causal=True, mesh=mesh)
+                return flash_attention_packed(
+                    packed, H, Hk, causal=True, mesh=mesh)
+            if group > 1:
+                # Ring and dense attention take equal head counts —
+                # repeat KV across each group (transient; the cache and
+                # the params stay at Hk heads).
+                k = jnp.repeat(k, group, axis=2)
+                v = jnp.repeat(v, group, axis=2)
+            if self.attention == "ring":
+                from ..parallel.ring_attention import ring_attention
 
-                    if mesh is None:
-                        raise ValueError("attention='ring' needs mesh= at apply time")
-                    att = ring_attention(q, k, v, mesh, axis_name="sp", causal=True)
-                else:
-                    from ..parallel.ring_attention import full_attention
+                if mesh is None:
+                    raise ValueError("attention='ring' needs mesh= at apply time")
+                return ring_attention(q, k, v, mesh, axis_name="sp", causal=True)
+            from ..parallel.ring_attention import full_attention
 
-                    att = full_attention(q, k, v, causal=True)
+            return full_attention(q, k, v, causal=True)
+
+        if self.decode and T != 1:
+            raise ValueError(f"decode mode steps one token at a time, got T={T}")
+        if self.prompt_rows:
+            if not (self.decode and self.kv_num_blocks and self.collect_kv):
+                raise ValueError("prompt_rows is a mode of paged decode with collect_kv")
+            R, Lb = B - self.prompt_rows, self.prompt_rows
+
+            def sequence(a):  # the prompt's rows [Lb, 1, ...] as ONE sequence
+                return a[R:].reshape(1, Lb, *a.shape[2:])
+
+            att = jnp.concatenate([
+                cached(q[:R], k[:R], v[:R]).reshape(R, 1, D),
+                causal(sequence(packed), sequence(q), sequence(k), sequence(v))
+                .astype(x.dtype).reshape(Lb, 1, D),
+            ])
+        elif self.decode:
+            att = cached(q, k, v)
+        else:
+            att = causal(packed, q, k, v)
         att = att.reshape(B, T, D)
         x = x + nn.Dense(D, dtype=self.dtype, name="proj")(att)
 
@@ -308,6 +331,9 @@ class TransformerLM(nn.Module):
     # cache a shared pool addressed by the PagedState passed via paged=.
     kv_num_blocks: int = 0
     kv_block_size: int = 16
+    # Paged decode with ONE prompt prefilled in the same pass (``Block``):
+    # tokens are [R + prompt_rows, 1], the decode rows then the prompt's.
+    prompt_rows: int = 0
     remat: bool = False  # checkpoint each block: O(L) -> O(1) activations
     # What the per-block checkpoint SAVES (only meaningful with remat=True):
     #   "full"          — save nothing: every op recomputed in the backward
@@ -324,13 +350,16 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(
         self, tokens: jax.Array, mesh=None, return_features: bool = False,
-        paged=None,
+        paged=None, prompt_last=None,
     ) -> jax.Array:
         """Logits [B, T, V] — or pre-head features [B, T, D] with
         ``return_features=True``, for ``ops.xent.lm_head_xent``'s chunked
         loss (the lm_head params still come from the same init: flax only
         materializes params on the default path, and ``apply`` ignores the
-        unused head when features are requested)."""
+        unused head when features are requested).  With ``prompt_rows`` the
+        logits are [R + 1, 1, V]: the decode rows', then the prompt's at its
+        row ``prompt_last`` (a traced scalar; the row is taken before the
+        final norm and the head, which never see the rest of the bucket)."""
         B, T = tokens.shape
         # Validate even when remat/decode makes the policy a no-op: bench
         # rows are keyed by this string, so a typo must never run silently.
@@ -343,7 +372,10 @@ class TransformerLM(nn.Module):
             if self.decode and paged is not None:
                 # Paged decode: each slot sits at its own position — the
                 # per-slot lengths ARE the position counter.
-                pos_idx = pos_idx + paged.lengths[:, None]
+                pos = paged.lengths
+                if self.prompt_rows:
+                    pos = jnp.concatenate([pos, jnp.arange(self.prompt_rows)])
+                pos_idx = pos_idx + pos[:, None]
             elif self.decode:
                 # The LM owns its position counter (how many tokens have
                 # been decoded) rather than peeking at a child block's cache.
@@ -384,11 +416,16 @@ class TransformerLM(nn.Module):
                 num_kv_heads=self.num_kv_heads,
                 kv_num_blocks=self.kv_num_blocks,
                 kv_block_size=self.kv_block_size,
+                prompt_rows=self.prompt_rows,
                 name=f"block{i}",
             )
             # paged stays out of the remat-wrapped call (remat only wraps
             # the non-decode path, where paged is always None).
             x = block(x, mesh) if paged is None else block(x, mesh, paged)
+        if self.prompt_rows:
+            R = B - self.prompt_rows
+            x = jnp.concatenate(
+                [x[:R], jax.lax.dynamic_slice_in_dim(x, R + prompt_last, 1)])
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
         head = nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")
         if return_features:
@@ -438,18 +475,20 @@ class PagedTransformerLM:
         return {f"block{i}": {"pool_k": pool, "pool_v": pool}
                 for i in range(m.num_layers)}
 
-    def prefill(self, params, toks, tp, block_size: int):
-        """Rows: K and V of all layers stacked, ``[L, nbw, block_size, Hk,
-        hd]`` each."""
-        logits, col = self._pre.apply(
-            {"params": params["params"]}, toks, mutable=["kv"])
-
+    def _rows(self, kv, block_size: int):
+        """A prompt's sown K/V as ``write_rows`` takes them: K and V of all
+        layers stacked, ``[L, nbw, block_size, Hk, hd]`` each."""
         def blocks(which):
-            x = jnp.stack([col["kv"][f"block{i}"][which][0][0]
+            x = jnp.stack([kv[f"block{i}"][which][0][0]
                            for i in range(self.model.num_layers)])
             return rows_to_blocks(x, block_size, axis=1).astype(self.model.dtype)
 
-        return (blocks("k"), blocks("v")), jnp.take(logits[0], tp - 1, axis=0), None
+        return blocks("k"), blocks("v")
+
+    def prefill(self, params, toks, tp, block_size: int):
+        logits, col = self._pre.apply(
+            {"params": params["params"]}, toks, mutable=["kv"])
+        return self._rows(col["kv"], block_size), jnp.take(logits[0], tp - 1, axis=0), None
 
     def write_rows(self, cache, rows, block_ids):
         ks, vs = rows
@@ -471,6 +510,24 @@ class PagedTransformerLM:
             {"params": params["params"], "cache": cache}, tokens[:, None],
             paged=paged, mutable=["cache"])
         return logits[:, 0], upd["cache"], None
+
+    def decode_with_prompt(self, params, cache, tokens, paged, toks, tp, block_size: int):
+        """``decode`` of the ``R`` rows and ``prefill`` of ONE prompt in one
+        pass over ``R + Lb`` rows (``Block.prompt_rows``): every weight matrix
+        is an operand of one product.  Returns (the decode rows' logits [R, V],
+        the prompt's logits [V] at ``tp - 1``, the cache with this step's K/V
+        written, the prompt's rows as ``prefill`` hands them to
+        ``write_rows``)."""
+        pool = cache["block0"]["pool_k"]
+        both = self._twin(
+            attention=self._pre.attention, decode=True, collect_kv=True,
+            prompt_rows=toks.shape[1], kv_num_blocks=pool.shape[0],
+            kv_block_size=pool.shape[1])
+        logits, upd = both.apply(
+            {"params": params["params"], "cache": cache},
+            jnp.concatenate([tokens, toks[0]])[:, None],
+            paged=paged, prompt_last=tp - 1, mutable=["cache", "kv"])
+        return logits[:-1, 0], logits[-1, 0], upd["cache"], self._rows(upd["kv"], block_size)
 
 
 def generate(

@@ -52,3 +52,22 @@ def subprocess_env(root: str) -> dict:
         PYTHONPATH=root + os.pathsep + os.environ.get("PYTHONPATH", ""),
         JAX_PLATFORMS="cpu",
     )
+
+
+def own_programs(model):
+    """``model`` (a ``TransformerLM``) as the engine wraps it, hiding the form
+    that lets an admission ride a decode step (``decode_with_prompt``): every
+    join takes ``engine_prefill`` and ``engine_join``, as under every decoder
+    that does not offer the form."""
+    from moolib_tpu.models.transformer import PagedTransformerLM
+
+    class OwnPrograms:
+        def __init__(self, inner):
+            self._inner = inner
+
+        def __getattr__(self, name):
+            if name == "decode_with_prompt":
+                raise AttributeError(name)
+            return getattr(self._inner, name)
+
+    return OwnPrograms(PagedTransformerLM(model))

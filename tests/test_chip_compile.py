@@ -1242,3 +1242,82 @@ def test_an_engine_of_one_row_count_lowers_the_step_of_before(module, name, slot
     text = eng._step_jit.lower(*state, slots).as_text()
     assert text == _step_of_before(eng).lower(*state).as_text()
     assert "jit_engine_decode" in text  # the program's one name (devmon.jit_program), so the texts can be equal
+
+
+# ---------------------------------------------------------------------------
+# the step that carries an admission (PR 57): engine_admit_step at
+# lm_serve_knee's sizes, and the texts of the programs it must leave alone
+# ---------------------------------------------------------------------------
+def _dense_engine(chip, monkeypatch):
+    """``lm_serve_steady``'s and ``lm_serve_knee``'s engine (24 layers, d 2,048
+    as 16 heads of 128, 32 slots x 64 blocks of 16, float32 weights, bfloat16
+    products and pools) over shapes, and the arguments of a step on the
+    described device.  The engine's own pools are a block a slot: what is
+    lowered takes the cells' 2,049 blocks as shapes."""
+    from moolib_tpu.engine import ContinuousBatchingEngine
+    from moolib_tpu.models.transformer import TransformerLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the paged kernel through Mosaic
+    slots, per, block = 32, 64, 16
+    lm = TransformerLM(vocab_size=50257, d_model=2048, num_heads=16, num_layers=24,
+                       max_len=2048, attention="dense", pos_embedding="learned")
+    params = jax.eval_shape(lambda: lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    eng = ContinuousBatchingEngine(lm, params, slots=slots, block_size=block, num_blocks=1 + slots,
+                                   max_seq_len=per * block, max_prompt_len=512)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    state = (params, eng.model.cache_spec(1 + slots * per, block), i32(slots, per), i32(slots),
+             jax.ShapeDtypeStruct((slots,), jnp.bool_), i32(slots), i32(slots))
+    return eng, _on(chip, state), lambda *shapes: _on(chip, tuple(i32(*s) for s in shapes))
+
+
+@pytest.mark.parametrize("bucket,most_mb", [(16, 64), (512, 320)])
+def test_engine_admit_step_reads_each_weight_matrix_once(chip, monkeypatch, bucket, most_mb):
+    """32 decode rows and a prompt's 16 or 512 rows in one pass: each of a
+    block's four weight matrices is the operand of ONE product over ``32 +
+    bucket`` rows (a program that called ``prefill`` then ``decode`` would hold
+    two, over 32 and over ``bucket`` rows), the paged kernel runs once a layer
+    over the 32, the head over 33 rows, the pools are updated where they lie,
+    and the temporaries (the prompt's K/V rows of 24 layers, 100 MB at 512,
+    and a block's activations) stay under ``most_mb``: 47 and 229 MB read."""
+    eng, state, ints = _dense_engine(chip, monkeypatch)
+    per, rows = 64, 32 + bucket
+    lowered = eng._admit_jit.lower(
+        *state, *ints((1, bucket), (), (), (per,), (), (bucket // 16,)), 32)
+    text = lowered.as_text()
+    products = re.findall(r"stablehlo\.dot_general .*: \(tensor<(\w+)>, tensor<(\w+)>\)", text)
+    for k, n in ((2048, 6144), (2048, 2048), (2048, 8192), (8192, 2048)):
+        mine = [lhs for lhs, rhs in products if rhs == f"{k}x{n}xbf16"]
+        assert mine == [f"{rows}x1x{k}xbf16"] * 24, (k, n)
+    assert [lhs for lhs, rhs in products if rhs == "2048x50257xf32"] == ["33x1x2048xf32"]
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 24
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state[1]))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < most_mb << 20
+
+
+def _sha(text):
+    """The hash of a lowered program's text without the serialized Mosaic
+    kernels, which hold the source locations (paths and lines) of the calls
+    that built them: everything the program's own code decides stays."""
+    import hashlib
+
+    return hashlib.sha256(
+        re.sub(r'\\22body\\22: \\22[^\\]*\\22', "", text).encode()).hexdigest()
+
+
+def test_the_train_step_and_the_decode_step_lower_to_the_text_of_before(chip, monkeypatch):
+    """``Block`` has a third mode (PR 57) that neither program takes: the one-chip
+    train step at the cells' B=4 and the engine's decode step at the dense
+    cells' sizes lower to the text they lowered to at the parent commit,
+    7c3db09 (PR 56), byte for byte outside the kernels' bodies.  The hashes
+    were recorded there by these same lines in a clone of that commit; a PR
+    that means to change either program records its own."""
+    jstep, state, _ = _lm_train_step(monkeypatch, chip, 4)
+    tokens = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=chip)
+    assert _sha(jstep.lower(*state, tokens).as_text()) == (
+        "2c3f06e617fc16d8170d9d24657c3354583759af21ac007bb4c1911b8d528012")
+    eng, state, _ = _dense_engine(chip, monkeypatch)
+    assert _sha(eng._step_jit.lower(*state, 32).as_text()) == (
+        "15b0af7d3bd900695d9338e80553e35d556bd1763031076b863c93528b76b799")

@@ -22,6 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import own_programs
 from moolib_tpu import telemetry
 from moolib_tpu.engine import ContinuousBatchingEngine
 from moolib_tpu.models.transformer import TransformerLM
@@ -47,7 +48,8 @@ def lm():
 
 def _engine(lm):
     model, params = lm
-    return ContinuousBatchingEngine(model, params, slots=3, block_size=4,
+    # every join by programs of its own: the spans and counts here are theirs
+    return ContinuousBatchingEngine(own_programs(model), params, slots=3, block_size=4,
                                     max_seq_len=64, max_prompt_len=16)
 
 
@@ -241,15 +243,15 @@ def test_a_join_that_finds_no_blocks_leaves_the_slot_free(lm):
     from moolib_tpu.engine.kv_pool import PoolExhausted
 
     model, params = lm
-    eng = ContinuousBatchingEngine(model, params, slots=3, block_size=4, num_blocks=6,
+    eng = ContinuousBatchingEngine(own_programs(model), params, slots=3, block_size=4, num_blocks=6,
                                    max_seq_len=64, max_prompt_len=16)
     free = list(eng._free_slots)
     telemetry.get_tracer().clear()
     with pytest.raises(PoolExhausted):
         eng.submit(np.arange(1, 6, dtype=np.int32), 40)
-    assert eng._free_slots == free and eng._join_jit.seq == 0
-    join, = _spans("engine.join")  # the span says the slot it would have taken
-    assert join.args == {"program": "engine_join", "seq": 0, "slot": free[-1]}
+    # the blocks are asked for before anything is launched: no program, no span
+    assert eng._free_slots == free and eng._join_jit.seq == eng._prefill_jit.seq == 0
+    assert not _spans("engine.join") and not _spans("engine.prefill_dispatch")
 
 
 def test_a_budget_of_one_reads_its_token_for_no_slot(lm):

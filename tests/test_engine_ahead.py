@@ -19,6 +19,12 @@ The step's rows (ISSUE 52): an engine of more than 128 slots over a model that
 says ``decodes_rows`` compiles the step once a row count and picks, a
 dispatch, the smallest that holds the slots its mirrors expect to advance;
 the last section holds it to an engine pinned to every slot, token for token.
+
+Every engine here is built over ``conftest.own_programs``: its model does not
+offer the form that lets an admission ride a decode step (ISSUE 57), so each
+join takes ``engine_prefill`` and ``engine_join``, as under the six decoders
+without the form; ``tests/test_engine_admit_step.py`` holds the other path to
+this one, token for token.
 """
 
 import numpy as np
@@ -27,6 +33,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from conftest import own_programs
 from moolib_tpu import telemetry
 from moolib_tpu.engine import ContinuousBatchingEngine
 from moolib_tpu.models.transformer import TransformerLM, generate
@@ -45,7 +52,7 @@ def lm():
 
 def _engine(lm, **kw):
     model, params = lm
-    return ContinuousBatchingEngine(model, params, slots=3, block_size=4,
+    return ContinuousBatchingEngine(own_programs(model), params, slots=3, block_size=4,
                                     max_seq_len=64, max_prompt_len=16, **kw)
 
 
@@ -79,7 +86,7 @@ def test_a_lone_request_runs_one_step_ahead_and_leaves_none_in_flight(lm, budget
         emissions, finished = eng.step()
         assert list(emissions) == [slot]
         # One step stays in flight between calls, until the last.
-        assert (eng._flight is not None) == (i < budget - 2)
+        assert (bool(eng._flights)) == (i < budget - 2)
     assert finished == [slot]
     assert eng.step() == ({}, [])  # nothing active, nothing in flight
     after = eng.stats()
@@ -99,7 +106,7 @@ def test_a_join_behind_the_step_in_flight_equals_generate(lm):
     program is never compiled again."""
     eng = _engine(lm)
     eng.warmup()
-    assert eng._flight is None and eng._step_jit._cache_size() == 1
+    assert not eng._flights and eng._step_jit._cache_size() == 1
     rng = np.random.default_rng(11)
     reqs = [(rng.integers(1, V, size=n).astype(np.int32), mn)
             for n, mn in ((5, 9), (9, 4), (3, 6), (12, 3), (7, 5))]
@@ -117,17 +124,17 @@ def test_a_join_behind_the_step_in_flight_equals_generate(lm):
 
     submit(0)
     step()
-    assert eng._flight is not None
+    assert bool(eng._flights)
     submit(1)  # behind the step in flight
     step()
-    assert eng._flight is not None
+    assert bool(eng._flights)
     submit(2)
     pending, behind_a_flight = [3, 4], 2
     for _ in range(40):
         step()
         if pending and len(slot_of) < 3:
             # A slot freed at this fetch is rejoined while the next step flies.
-            behind_a_flight += eng._flight is not None
+            behind_a_flight += bool(eng._flights)
             submit(pending.pop(0))
         if len(outs) == len(reqs):
             break
@@ -135,7 +142,7 @@ def test_a_join_behind_the_step_in_flight_equals_generate(lm):
     for i, (prompt, budget) in enumerate(reqs):
         np.testing.assert_array_equal(outs[i], _reference(lm, prompt, budget),
                                       err_msg=f"request {i}")
-    assert eng._flight is None
+    assert not eng._flights
     assert eng._step_jit._cache_size() == 1
     eng.pool.check_invariants()
     assert eng.pool.available() == eng.pool.num_blocks - 1
@@ -164,7 +171,7 @@ def test_an_unforeseen_eos_costs_one_empty_step_and_the_slot_is_reused(lm):
     while not finished:
         emissions, finished = eng.step()
     assert emissions == {slot: eos} and finished == [slot]
-    assert eng._flight is not None  # dispatched before the EOS was known
+    assert bool(eng._flights)  # dispatched before the EOS was known
     assert eng.active_count() == 0
     empty0 = eng.stats()["empty_steps"]
     counter0 = _counter("serve_engine_empty_steps_total")
@@ -214,7 +221,7 @@ def test_eos_in_one_slot_leaves_its_neighbour_untouched(lm):
             break
     assert got[a] == [int(t) for t in emitted_ref[:3]]
     assert got[b] == [int(t) for t in other_ref]
-    assert eng.stats()["empty_steps"] == 0 and eng._flight is None
+    assert eng.stats()["empty_steps"] == 0 and not eng._flights
     eng.pool.check_invariants()
 
 
@@ -229,7 +236,7 @@ def test_new_weights_apply_from_the_next_dispatch(lm):
     eng = _engine(lm)
     slot, _ = eng.submit(prompt, 6)
     emissions, _ = eng.step()  # books step 1; step 2 flies under the old
-    assert eng._flight is not None
+    assert bool(eng._flights)
     eng.set_params(jax.tree.map(jnp.zeros_like, params))
     tokens = [int(old[0]), emissions[slot]]
     finished = []
@@ -237,7 +244,7 @@ def test_new_weights_apply_from_the_next_dispatch(lm):
         emissions, finished = eng.step()
         tokens.append(emissions[slot])
     assert tokens == [int(old[0]), int(old[1]), int(old[2]), 0, 0, 0]
-    assert eng.retire(slot) == tokens and eng._flight is None
+    assert eng.retire(slot) == tokens and not eng._flights
 
 
 # ------------------------------------------- an admission nobody waits for
@@ -274,7 +281,7 @@ def test_a_join_reads_its_first_token_in_the_next_step(lm, in_flight):
         slot_a, _ = eng.submit(a, 9)
         live[slot_a] = a
         eng.step()
-        assert eng._flight is not None
+        assert bool(eng._flights)
     telemetry.get_tracer().clear()
     slot, emitted = eng.submit(b, 5)
     assert [s.name for s in telemetry.get_tracer().spans()
@@ -373,7 +380,7 @@ def test_a_first_token_that_is_eos_finishes_the_slot_once(lm, in_flight):
         reported += [s for s in finished if s == slot]
         for s in finished:
             got[s] = eng.retire(s)
-        if not eng.active_count() and eng._flight is None:
+        if not eng.active_count() and not eng._flights:
             break
     assert reported == [slot] and got[slot] == [eos]
     if in_flight:
@@ -412,9 +419,7 @@ class _CountingLM:
     prefill_counters = 2
 
     def __init__(self, model):
-        from moolib_tpu.models.transformer import PagedTransformerLM
-
-        self._inner = PagedTransformerLM(model)
+        self._inner = own_programs(model)
         self.max_len = model.max_len
         self.seen = []
 
@@ -468,9 +473,7 @@ class _RowsLM:
     decodes_rows = True
 
     def __init__(self, model):
-        from moolib_tpu.models.transformer import PagedTransformerLM
-
-        self._inner = PagedTransformerLM(model)
+        self._inner = own_programs(model)
         self.max_len = model.max_len
 
     def __getattr__(self, name):
@@ -497,7 +500,7 @@ def _watch(eng):
 
     def checked(rows):
         device = np.asarray(eng._active)
-        stepping = eng._active_host if eng._flight is None else None
+        stepping = eng._active_host if not eng._flights else None
         assert int(device.sum()) <= rows, (int(device.sum()), rows)
         if stepping is not None:
             assert not (device & ~stepping).any()
@@ -554,7 +557,7 @@ def test_the_rows_follow_the_occupied_slots_and_no_token_changes(lm, eos):
     # warm-up compiles both row counts, and its return counts them
     assert eng.warmup() == (eng._prefill_jit._cache_size() + eng._join_jit._cache_size()
                             + eng._step_jit._cache_size())
-    assert eng._step_jit._cache_size() == 2 and eng._flight is None
+    assert eng._step_jit._cache_size() == 2 and not eng._flights
     before = _histogram("serve_engine_decode_rows")
     chosen = _watch(eng)
     got = _waves(eng, waves, until=51)
@@ -565,7 +568,7 @@ def test_the_rows_follow_the_occupied_slots_and_no_token_changes(lm, eos):
     assert st["row_overflows"] == 0 and _counter("serve_engine_row_overflows_total") == 0
     assert st["steps_by_rows"] == {r: chosen.count(r) for r in (128, 256)}
     # every step dispatched is booked, but one still in flight behind an EOS
-    assert sum(st["steps_by_rows"].values()) == st["steps"] + (eng._flight is not None)
+    assert sum(st["steps_by_rows"].values()) == st["steps"] + (bool(eng._flights))
     after = _histogram("serve_engine_decode_rows")
     assert after["count"] - before["count"] == len(chosen)
     assert after["sum"] - before["sum"] == sum(chosen)

@@ -19,6 +19,7 @@ import types
 import numpy as np
 import pytest
 
+from conftest import own_programs
 from moolib_tpu import telemetry
 from moolib_tpu.telemetry import devmon, tracing
 
@@ -175,7 +176,10 @@ def _service(slots=3):
                           num_layers=2, max_len=64, attention="dense",
                           dtype=jnp.float32, pos_embedding="rotary")
     params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
-    engine = ContinuousBatchingEngine(model, params, slots=slots, block_size=4,
+    # Every join by programs of its own (conftest.own_programs): the tree and
+    # the counts below are that path's; tests/test_engine_admit_step.py has
+    # the admission that rides a step.
+    engine = ContinuousBatchingEngine(own_programs(model), params, slots=slots, block_size=4,
                                       max_seq_len=64, max_prompt_len=8)
     return EngineService(_Rpc(), engine, default_max_new=4)
 
@@ -456,13 +460,13 @@ def test_close_with_a_step_in_flight_answers_every_request_once():
 
     def step_then_close():
         out = engine_step()
-        assert eng._flight is not None
+        assert bool(eng._flights)
         service.close()  # the loop runs: it answers on its way out
         return out
 
     eng.step = step_then_close
     asyncio.run(asyncio.wait_for(service.loop(), 120))
-    assert eng._flight is None and service._slot_req == {}
+    assert not eng._flights and service._slot_req == {}
     assert [len(r.answers) for r in rets] == [1, 1, 1, 1]
     kind, out = rets[0].answers[0]
     assert kind == "ok" and len(out) == len(prompt) + 2

@@ -232,6 +232,13 @@ def result_line(bench: Dict, cell: Dict, measured: Measured, devices: List[Any],
     return line
 
 
+def within(compared: Dict[str, list]) -> bool:
+    """``correct``: every number a runner compared was read and lies at or
+    under its limit.  ``compared`` maps a short name to ``[value, limit]`` and
+    is printed whole, in the result's line and on standard error (run.py)."""
+    return all(value is not None and value <= limit for value, limit in compared.values())
+
+
 def say(tag: str, payload: Any) -> None:
     """A line before the last: facts for the builder, never read by the driver."""
     print(f"{tag} {json.dumps(payload, default=str)}", flush=True)

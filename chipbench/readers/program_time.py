@@ -17,17 +17,30 @@ file says which program and which figure:
 ``{"figure": "mean_ms", "program": P}``
     mean device duration, in ms, of the runs of P that lie wholly inside the
     window, over all chips.
-``{"figure": "queue_delay_mean_ms", "program": P, "programs": {...}}``
+``{"figure": "queue_delay_mean_ms", "program": P}``
     over P's MATCHED runs that start inside the window: device start minus
     the start of the host span that dispatched the run, mean, in ms.
-``{"figure": "clock_lead_ms", "programs": {...}}``
+``{"figure": "clock_lead_ms"}``
     over ALL matched runs that start inside the window: the largest of span
     start minus run start, in ms, 0 at the least.  No run starts before its
     own dispatch began, so what is above 0 is how far the device events' clock
     leads the host spans' in this trace, at the least.
 
-``programs`` maps each program to the span that dispatches it
-(``{"engine_prefill": "engine.prefill_dispatch", ...}``).  A run is tied to
+``{"figure": "mfu", "program": P, "flops": F, "args": [...]}``
+    over P's MATCHED runs that lie wholly inside the window: the operations
+    the runs WERE ASKED for, over their device seconds, over the chip's peak
+    (``peaks.json``), in percent.  A run's operations are
+    ``chipbench.flops.F(config, n_layer, **{a: the span's own argument a})``:
+    what the dispatching span hands the profiler says what the launch was
+    for (a prompt's REAL tokens, the rows stepped), whatever shape the program
+    padded it to.  ``None`` with no such run, or when under 95% of P's runs
+    inside the window are tied (the rest would go uncounted).  Nothing clamps
+    it: operations counted too high, or seconds that leave work out, must
+    show as a reading over 100.
+
+``programs/<program>.json`` names, for each program, the span that dispatches
+it (``engine_prefill``: ``engine.prefill_dispatch``, ...): one map for every
+metric of this reader, so one load of the trace a line.  A run is tied to
 its span by what the trace itself records of the launch, never by nearness in
 time: the run consumes a flow (``_ct``/``_c``) that a host event produced
 (``_pt``/``_p``: ``DoEnqueueProgram``); that event lies inside one that
@@ -53,7 +66,7 @@ import glob
 import os
 import re
 
-from chipbench import harness
+from chipbench import flops, harness
 from chipbench import trace_reduce as tr
 
 MODULES_LINE = "XLA Modules"
@@ -183,7 +196,17 @@ def matches(runs, host, programs):
     return out
 
 
-def figure(spec, runs, host, busy_s, n_devices):
+def programs():
+    """The one map every metric of this reader shares: ``programs/<program>.json``
+    names the span that dispatches the program.  A later PR's program is one
+    file more.  (A ``spec`` with a ``programs`` of its own is a test's.)"""
+    return {os.path.basename(path)[:-len(".json")]: harness.load_json(path)["span"]
+            for path in sorted(glob.glob(os.path.join(harness.BENCH_DIR, "programs", "*.json")))}
+
+
+def figure(spec, runs, host, busy_s, n_devices, model=None):
+    """``model``: ``(config, n_layer, peak operations a second)``, for the
+    one figure that counts operations."""
     window = host[3]
     runs = dict(sorted(runs.items())[:n_devices])
     if window is None or not runs:
@@ -199,15 +222,25 @@ def figure(spec, runs, host, busy_s, n_devices):
     if kind == "mean_ms":
         inside = [r["end"] - r["start"] for r in mine if r["start"] >= lo and r["end"] <= hi]
         return sum(inside) / len(inside) / 1e6 if inside else None
-    if kind in ("queue_delay_mean_ms", "clock_lead_ms", "matched_share"):
-        pairs = [(r, s) for r, s in matches(runs, host, spec["programs"]) if lo <= r["start"] < hi]
+    if kind in ("queue_delay_mean_ms", "clock_lead_ms", "matched_share", "mfu"):
+        tied = matches(runs, host, spec.get("programs") or programs())
+        if kind == "mfu":  # P's runs wholly inside the window: operations asked over seconds taken
+            pairs = [(r, s) for r, s in tied
+                     if r["program"] == program and r["start"] >= lo and r["end"] <= hi]
+        else:
+            pairs = [(r, s) for r, s in tied if lo <= r["start"] < hi]
         matched = [(r, s) for r, s in pairs if s is not None]
         if kind == "matched_share":
             return 100.0 * len(matched) / len(pairs) if pairs else None
-        if not pairs or len(matched) < MATCHED_AT_LEAST * len(pairs):
+        if not matched or len(matched) < MATCHED_AT_LEAST * len(pairs):
             return None
         if kind == "clock_lead_ms":
             return max(0.0, max(s[0] - r["start"] for r, s in matched)) / 1e6
+        if kind == "mfu":
+            config, n_layer, peak = model
+            count = getattr(flops, spec["flops"])
+            asked = sum(count(config, n_layer, **{a: s[3][a] for a in spec["args"]}) for _r, s in matched)
+            return 100.0 * asked / (sum(r["end"] - r["start"] for r, _s in matched) / 1e9) / peak
         delays = [r["start"] - s[0] for r, s in matched if r["program"] == program]
         return sum(delays) / len(delays) / 1e6 if delays else None
     raise ValueError(f"unknown program figure {kind!r}")
@@ -221,7 +254,12 @@ def read(spec, ctx):
         harness.TRACE_DIR, ctx["cell"]["name"], "plugins/profile/*/*.xplane.pb")))
     if not paths:
         return None
-    if "program_runs" not in ctx:  # the cell's metrics share one ctx and one ``programs``
-        ctx["program_runs"] = extract(tr.load(paths[-1]), set(spec["programs"].values()))
+    if "program_runs" not in ctx:  # the cell's metrics share one ctx and one map: one load
+        ctx["program_runs"] = extract(tr.load(paths[-1]), set(programs().values()))
+    model = None
+    if spec["figure"] == "mfu":
+        config = ctx["config"]
+        model = (config, config["uses"][ctx["traffic"]["use"]]["n_layer"],
+                 ctx["peaks"]["device_kinds"][ctx["device"]["kind"]]["bf16_flops_per_s"])
     return figure(spec, *ctx["program_runs"], busy_s=trace["busy_s"],
-                  n_devices=ctx["device"]["count"])
+                  n_devices=ctx["device"]["count"], model=model)

@@ -1,12 +1,18 @@
-"""Two figures of one family of the program's registry over the measured
-window (``counters_before`` / ``counters_after``, as ``histogram_mean`` reads
-them).  Neither is a mean: a share says where a total went, and a level says
-how bad the worst moment was, which a mean over 7,000 observations hides.
+"""Three figures of the program's registry over the measured window
+(``counters_before`` / ``counters_after``, as ``histogram_mean`` reads them).
+None is a mean: a share says where a total went, and a level says how bad the
+worst moment was, which a mean over 7,000 observations hides.
 
 ``{"figure": "counter_share", "metric": name, "labels": {...}}``
     the rise, over the window, of the series the labels select, over the
     rise of every series of the family, in percent.  ``None`` where the
     family is not there (an older program) or none of it rose.
+``{"figure": "family_share", "metric": name, "among": [name, ...]}``
+    a share ACROSS families, where the program counts the parts of one total
+    under several names: the rise of every series of ``metric`` over the rise
+    of every series of every family of ``among`` (which names ``metric``
+    too), in percent.  ``None`` where a family of ``among`` is not there (a
+    part is not counted: the share would read too high) or none of them rose.
 ``{"figure": "histogram_longest_le", "metric": name, "labels": {...},
 "scale": s}``
     the upper edge, times ``scale``, of the highest bucket whose count rose
@@ -38,6 +44,17 @@ def counter_share(spec, before, after):
     return 100.0 * sum(rises[key] for key in mine) / total if total > 0 else None
 
 
+def family_share(spec, before, after):
+    if spec["metric"] not in spec["among"]:
+        raise ValueError(f"a share of its own total: {spec['metric']!r} is not among {spec['among']}")
+    if any(name not in after for name in spec["among"]):
+        return None
+    rise = lambda name: (sum(selected(after, name, None).values())
+                         - sum(selected(before, name, None).values()))
+    total = sum(rise(name) for name in spec["among"])
+    return 100.0 * rise(spec["metric"]) / total if total > 0 else None
+
+
 def histogram_longest_le(spec, before, after):
     edges = (after.get(spec["metric"]) or {}).get("buckets")
     if not edges:
@@ -54,7 +71,8 @@ def histogram_longest_le(spec, before, after):
     return edge * spec.get("scale", 1.0)
 
 
-FIGURES = {"counter_share": counter_share, "histogram_longest_le": histogram_longest_le}
+FIGURES = {"counter_share": counter_share, "family_share": family_share,
+           "histogram_longest_le": histogram_longest_le}
 
 
 def read(spec, ctx):

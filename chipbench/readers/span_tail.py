@@ -1,5 +1,5 @@
-"""What the traced tail held at its worst: figures of the PROGRAM's spans in
-the traced window that are neither a mean nor a share (``span_time`` has
+"""What the traced tail held at its worst: a figure of the PROGRAM's spans in
+the traced window that is neither a mean nor a share (``span_time`` has
 those).  The trace is the cell's newest, found and loaded as ``span_time``
 finds and loads it (once a line: the cell's metrics share one ``ctx``).
 
@@ -9,21 +9,7 @@ finds and loads it (once a line: the cell's metrics share one ``ctx``).
     0 where a span of ``witness`` is in the trace (the program was watching
     and nothing happened: no collection in the tail), else ``None`` (an older
     program, and the metric is left out of the line).
-``{"figure": "clock_lead_ms", "spans": [...], "dispatch": [...]}``
-    a lower bound, in ms, on how far device events LEAD host spans in this
-    trace.  ``spans`` are those at whose end the device has nothing in
-    flight (the engine was empty; a prefill's token has come back);
-    ``dispatch`` is every span in which the host hands the device work.  For
-    each span of ``spans`` that ends inside the window with chip 0 idle at
-    its end: ``h`` = start of the first ``dispatch`` span at or after that
-    end, ``d`` = start of the first device operation after the chip went
-    idle.  A device cannot start what the host has not dispatched, so ``h -
-    d`` above 0 is how much the two clocks disagree, at the least (the launch
-    takes time too).  The figure is the largest over the window's wake-ups;
-    ``None`` with none.  Reported only: ``span_time`` is not corrected by it.
 """
-
-import bisect
 
 from chipbench import trace_reduce as tr
 from chipbench.readers import span_time
@@ -45,27 +31,7 @@ def longest_ms(spec, devices, host):
     return 0.0 if any(host.get(n) for n in spec.get("witness", ())) else None
 
 
-def clock_lead_ms(spec, devices, host):
-    window = _window(host)
-    if window is None or not devices or not devices[min(devices)]:
-        return None
-    lo, hi = window
-    busy = tr.busy_intervals(devices[min(devices)])
-    busy_starts = [a for a, _b in busy]
-    dispatches = sorted(s for n in spec["dispatch"] for s, _d in host.get(n, ()))
-    leads = []
-    for name in spec["spans"]:
-        for start, dur in host.get(name, ()):
-            end = start + dur
-            k = bisect.bisect_right(busy_starts, end)  # busy[k] is the first to start after end
-            j = bisect.bisect_left(dispatches, end)
-            idle = k == 0 or busy[k - 1][1] <= end
-            if lo <= end <= hi and idle and k < len(busy) and j < len(dispatches):
-                leads.append((dispatches[j] - busy_starts[k]) / 1e6)
-    return max(leads) if leads else None
-
-
-FIGURES = {"longest_ms": longest_ms, "clock_lead_ms": clock_lead_ms}
+FIGURES = {"longest_ms": longest_ms}
 
 
 def read(spec, ctx):

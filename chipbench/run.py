@@ -61,6 +61,13 @@ def main(argv=None) -> int:
     if measured.trace is not None:
         measured.notes["idle_gap_sizes"] = measured.trace["idle_gap_sizes"]
     harness.say("NOTES", measured.notes)
+    # each number that decided ``correct`` beside its limit: the result's last key, and the
+    # last lines of standard error (what the driver's record keeps of a run that is not correct)
+    line["compared"] = {name: {"value": value, "limit": limit}
+                        for name, (value, limit) in measured.notes.get("compared", {}).items()}
+    for name, pair in line["compared"].items():
+        print(f"chipbench: compared {name} = {pair['value']} (limit {pair['limit']})", file=sys.stderr)
+    print(f"chipbench: correct = {line['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

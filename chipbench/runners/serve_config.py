@@ -254,7 +254,9 @@ def run(*, cell, config, traffic, seed, seconds, traced, devices, setup) -> harn
     failed += attempted - len(records)  # never sent: the generator was stopped
     in_window = state["compiles_after"]["programs"] - state["compiles_before"]["programs"]
     tol = config["tolerance"]["serve_not_argmax_share"]
-    correct = failed == 0 and in_window == 0 and bool(gaps) and not_argmax <= tol
+    compared = {"reference_not_argmax_share": [not_argmax if gaps else None, tol],
+                "failed": [failed, 0], "compiles_in_window": [in_window, 0]}
+    correct = harness.within(compared)
     slow = sorted(zip(ms_per_token, records), key=lambda p: -p[0])[:10]
     phases = {}
     for after in state["after"].get("serve_phase_seconds", {"series": []})["series"]:
@@ -271,7 +273,7 @@ def run(*, cell, config, traffic, seed, seconds, traced, devices, setup) -> harn
         lists={"req_ms_per_token": ms_per_token, "gen_lateness_ms": lateness},
         counters_before=state["before"], counters_after=state["after"],
         samples={"serve_engine_slot_occupancy": state["samples"]},
-        notes={"reference_not_argmax_share": not_argmax,
+        notes={"compared": compared, "reference_not_argmax_share": not_argmax,
                "reference_gap_sigma_max": max(gaps) if gaps else None,
                "reference_gap_sigma_max_long": max(checked) if checked else None,
                "reference_gap_sigma_mean": sum(gaps) / max(1, len(gaps)),

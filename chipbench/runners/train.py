@@ -170,15 +170,18 @@ def run(*, cell, config, traffic, seed, seconds, traced, devices, setup) -> harn
     tol = config["tolerance"]
     in_window = window.compiles_at[1]["programs"] - window.compiles_at[0]["programs"]
     finite = all(math.isfinite(x) for x in window.losses)
-    correct = (finite and in_window == 0 and rel[0] <= tol["train_loss_rel"]
-               and max(rel[1:]) <= tol["train_loss_after_updates_rel"])
+    compared = {"loss_rel_first": [rel[0], tol["train_loss_rel"]],
+                "loss_rel_after_updates_max": [max(rel[1:]), tol["train_loss_after_updates_rel"]],
+                "losses_not_finite": [sum(not math.isfinite(x) for x in window.losses), 0],
+                "compiles_in_window": [in_window, 0]}
+    correct = harness.within(compared)
     harness.say("SETUP", setup.report(setup_s, compiles))
     measured = harness.Measured(
         attempted=steps, failed=0 if finite else 1, correct=correct,
         values={"tokens": float(tokens), "window_s": window_s, "steps": float(steps),
                 "setup_s": setup_s, "n_layer": flags.layers, "seq_len": flags.seq_len},
         lists={"step_period_s": periods},
-        notes={"first_losses": got, "reference_losses": want, "loss_rel_err": rel,
+        notes={"compared": compared, "first_losses": got, "reference_losses": want, "loss_rel_err": rel,
                "reference_update_effect_rel": [abs(w - i) / abs(w) for w, i in zip(want, want_initial)],
                "last_loss": window.losses[-1], "compiles_in_window": in_window,
                "steps": steps, "window_s": window_s,

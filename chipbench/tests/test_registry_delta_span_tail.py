@@ -21,9 +21,9 @@ def hist(**by_phase):
         for k, v in by_phase.items()]}
 
 
-BEFORE = {"loop": counter(busy=10.0, empty=2.0),
+BEFORE = {"loop": counter(busy=10.0, empty=2.0), "pad": counter(all=3.0), "still": counter(all=1.0),
           "h": hist(iteration=[5, 1, 0, 0, 0], queue=[0, 0, 0, 1, 0])}
-AFTER = {"loop": counter(busy=50.0, empty=7.0, blocked=5.0),
+AFTER = {"loop": counter(busy=50.0, empty=7.0, blocked=5.0), "pad": counter(all=8.0), "still": counter(all=1.0),
          "h": hist(iteration=[9, 4, 1, 0, 0], queue=[0, 0, 0, 1, 0], reply=[0, 0, 0, 0, 2])}
 
 
@@ -34,6 +34,13 @@ AFTER = {"loop": counter(busy=50.0, empty=7.0, blocked=5.0),
     ({"figure": "counter_share", "metric": "loop"}, 100.0),
     ({"figure": "counter_share", "metric": "loop", "labels": {"state": "other"}}, 0.0),
     ({"figure": "counter_share", "metric": "absent", "labels": {"state": "empty"}}, None),
+    # a share ACROSS families: blocked rose 5 of the 5 + (40 + 5 + 5) the two families rose
+    ({"figure": "family_share", "metric": "pad", "among": ["pad", "loop"]}, 100.0 * 5 / 55),
+    ({"figure": "family_share", "metric": "loop", "among": ["pad", "loop"]}, 100.0 * 50 / 55),
+    ({"figure": "family_share", "metric": "pad", "among": ["pad"]}, 100.0),
+    # a part that the program does not count: no reading, not a share that reads too high
+    ({"figure": "family_share", "metric": "pad", "among": ["pad", "absent"]}, None),
+    ({"figure": "family_share", "metric": "still", "among": ["still"]}, None),  # none rose
     # the highest bucket that ROSE: 0.1, though 1.0 holds a count from before the window
     ({"figure": "histogram_longest_le", "metric": "h", "labels": {"phase": "iteration"},
       "scale": 1000.0}, 100.0),
@@ -61,6 +68,23 @@ def test_registry_delta_without_a_window_reads_nothing():
     assert registry_delta.read(spec, {"measured": same}) is None  # nothing rose
     with pytest.raises(ValueError):
         registry_delta.read({"figure": "other", "metric": "loop"}, {"measured": m})
+    with pytest.raises(ValueError):  # a share of a total it is no part of
+        registry_delta.read({"figure": "family_share", "metric": "loop", "among": ["pad"]}, {"measured": m})
+
+
+def test_the_pad_share_is_the_buckets_empty_positions_of_all_it_was_handed():
+    """``prefill_pad_share`` through its own file: 1,984-row buckets around
+    prompts of 1,025 and 1,984 tokens, and one prompt of 1,024 in its own."""
+    snap = lambda pad, real: {
+        "serve_pad_tokens_total": {"kind": "counter", "series": [{"labels": {}, "value": pad}]},
+        "serve_engine_prefill_tokens_total": {"kind": "counter", "series": [{"labels": {}, "value": real}]}}
+    m = harness.Measured(attempted=3, failed=0, correct=True, counters_before=snap(100.0, 5000.0),
+                         counters_after=snap(100.0 + 959, 5000.0 + 1025 + 1984 + 1024))
+    got = harness.read_metric({"name": "prefill_pad_share"}, {"measured": m})
+    assert got == pytest.approx(100.0 * 959 / (959 + 4033))
+    older = harness.Measured(attempted=1, failed=0, correct=True, counters_before={},
+                             counters_after={"serve_pad_tokens_total": snap(1.0, 1.0)["serve_pad_tokens_total"]})
+    assert harness.read_metric({"name": "prefill_pad_share"}, {"measured": older}) is None
 
 
 OP = "%fusion.1 = bf16[8,8]{1,0} fusion(%a)"
@@ -84,46 +108,6 @@ def test_longest_is_clipped_to_the_window():
     assert longest(host, ["host.gc"]) is None
     assert longest({}, ["host.gc"], witness=["host.tick"]) is None
     assert span_tail.longest_ms({"spans": ["host.tick"]}, {}, {"host.tick": [(0.0, MS)]}) is None  # no window
-
-
-def lead(host, ops, spans=("serve.empty",)):
-    spec = {"figure": "clock_lead_ms", "spans": list(spans),
-            "dispatch": ["engine.prefill_dispatch", "engine.join"]}
-    return span_tail.clock_lead_ms(spec, {0: ops}, {**WINDOW, **host})
-
-
-def test_clock_lead_positive_negative_none():
-    # busy to 20, empty spans end at 30 and 40 (one long quiet spell), the host
-    # dispatches the prefill at 41.5, the device's next operation starts at 40:
-    # its events lie at least 1.5 ms early
-    ops = [(OP, 12 * MS, 8 * MS), (OP, 40 * MS, 5 * MS)]
-    host = {"serve.empty": [(20 * MS, 10 * MS), (30 * MS, 10 * MS)],
-            "engine.prefill_dispatch": [(41.5 * MS, 1 * MS)]}
-    assert lead(host, ops) == pytest.approx(1.5)
-    # the device follows the host, as clocks that agree have it: 0.3 ms of launch
-    late = [(OP, 12 * MS, 8 * MS), (OP, 41.8 * MS, 5 * MS)]
-    assert lead(host, late) == pytest.approx(-0.3)
-    # the largest over the wake-ups; a second kind of quiet end (a prefill's
-    # token is back) is followed by whichever dispatch comes first, here the join
-    two = {"serve.empty": [(30 * MS, 10 * MS)], "engine.first_token_fetch": [(60 * MS, 3.5 * MS)],
-           "engine.prefill_dispatch": [(41.5 * MS, 1 * MS), (90 * MS, 1 * MS)],
-           "engine.join": [(66.2 * MS, 1 * MS)]}
-    both = late + [(OP, 64 * MS, 3 * MS)]
-    assert lead(two, both, spans=("serve.empty", "engine.first_token_fetch")) == pytest.approx(2.2)
-    assert lead(two, both, spans=("serve.empty",)) == pytest.approx(-0.3)
-
-
-def test_clock_lead_reads_nothing_without_a_wake_up():
-    ops = [(OP, 12 * MS, 8 * MS), (OP, 40 * MS, 5 * MS)]
-    dispatch = {"engine.prefill_dispatch": [(41.5 * MS, 1 * MS)]}
-    assert lead(dispatch, ops) is None                                         # never empty
-    assert lead({"serve.empty": [(20 * MS, 15 * MS)]}, ops) is None           # nothing dispatched after
-    assert lead({**dispatch, "serve.empty": [(10 * MS, 5 * MS)]}, ops) is None  # the chip busy at its end
-    assert lead({**dispatch, "serve.empty": [(1 * MS, 4 * MS)]}, ops) is None   # ends before the window
-    assert lead({**dispatch, "serve.empty": [(20 * MS, 10 * MS)]}, ops[:1]) is None  # no operation after
-    assert lead({**dispatch, "serve.empty": [(20 * MS, 10 * MS)]}, []) is None       # no device plane
-    spec = {"figure": "clock_lead_ms", "spans": ["serve.empty"], "dispatch": []}
-    assert span_tail.clock_lead_ms(spec, {0: ops}, {"serve.empty": [(20 * MS, 10 * MS)]}) is None
 
 
 def test_span_tail_read_uses_the_trace_the_cell_already_loaded():

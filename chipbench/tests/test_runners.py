@@ -13,6 +13,11 @@ from chipbench import harness
 from chipbench import run as bench_run
 
 
+# the long-prompt cell's own limits at the tiny size (64 wide, a vocabulary of 97): over the
+# program's readings and under the float8 control's on the seeds the tests use
+TINY_LIMITS = {"gap_sigma_max": 0.05, "gap_sigma_mean": 0.004}
+
+
 @pytest.fixture
 def tiny(monkeypatch):
     config = harness.load_json(harness.BENCH_DIR, "configs", "cerebras-gpt-1.3b.json")
@@ -36,6 +41,18 @@ def tiny(monkeypatch):
     traffic["serve_steady"] = serve
     knee = harness.load_json(harness.BENCH_DIR, "traffic", "serve_knee.json")
     traffic["serve_knee"] = {**serve, "schedule_seed": knee["schedule_seed"], "rate_per_s": 12.0}
+    # the long-prompt cell in small: the longest prompt decodes to the model's last position
+    # (112 + 16 = 128), one prompt lies one past a bucket's edge (65 in the capped bucket of
+    # 112), the fillers keep the other slots in use, and every counted prompt is half a
+    # context or more
+    long = harness.load_json(harness.BENCH_DIR, "traffic", "serve_longprompt.json")
+    traffic["serve_longprompt"] = {
+        **serve, "schedule_seed": long["schedule_seed"], "rate_per_s": 6.0, "positions_per_slot": 128,
+        "reference_requests": [[112, 16], [65, 4]],
+        "reference_fillers": {"count": 2, "prompt_tokens": 70, "budget_tokens": 6},
+        "reference_limits": TINY_LIMITS,
+        "prompt_tokens": {"median": 80, "sigma": 0.15, "min": 65, "max": 112},
+        "budget_tokens": {"median": 4, "sigma": 0.5, "min": 2, "max": 8}}
     real = harness.load_json
 
     def load_json(*parts):
@@ -66,7 +83,7 @@ SERVE_TRACED_ON_CPU = {
     "iteration_period_mean_ms", "first_token_mean_ms", "decode_dispatch_mean_ms",
     "decode_fetch_mean_ms", "kv_live_block_share", "engine_empty_share.serve",
     "iteration_longest_le_ms", "host_stall_longest_le_ms.serve",
-    "host_gc_pause_longest_le_ms.serve"}
+    "host_gc_pause_longest_le_ms.serve", "prefill_pad_share", "admit_rode_step_share"}
 
 
 @pytest.mark.parametrize("workload,traced,expect", [
@@ -77,6 +94,8 @@ SERVE_TRACED_ON_CPU = {
     ("lm_serve_steady", 1, SERVE_TRACED_ON_CPU),
     ("lm_serve_knee", 0, {"req_ms_per_token_p50", "setup_s"}),
     ("lm_serve_knee", 1, SERVE_TRACED_ON_CPU),
+    ("lm_serve_longprompt", 0, {"req_ms_per_token_p50", "setup_s"}),
+    ("lm_serve_longprompt", 1, SERVE_TRACED_ON_CPU),
 ])
 def test_runner_end_to_end(tiny, capsys, workload, traced, expect):
     bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
@@ -99,6 +118,98 @@ def test_runner_end_to_end(tiny, capsys, workload, traced, expect):
     assert all(m["value"] > 0 if not traced else m["value"] >= 0
                for m in line["metrics"].values())
     assert line["device"]["platform"] == "cpu" and line["device"]["count"] == chips
+    # each number that decided ``correct`` beside its limit, under the line's LAST key
+    assert list(line)[-1] == "compared" and len(line["compared"]) >= 3
+    assert all(set(pair) == {"value", "limit"} and pair["value"] <= pair["limit"]
+               for pair in line["compared"].values())
+
+
+def test_the_long_prompt_cell_is_its_files():
+    """``serve_knee.json`` key for key but for what makes the prompt do the
+    work; the traffic's own draw says what ``prefill_pad_share`` should read."""
+    from chipbench import traffic as traffic_mod
+
+    long, knee = (harness.load_json(harness.BENCH_DIR, "traffic", name + ".json")
+                  for name in ("serve_longprompt", "serve_knee"))
+    assert set(long) == set(knee) | {"reference_limits"} and long["runner"] == long["use"] == "serve"
+    same = {"arrivals", "pairing_seed", "lead_s", "drain_limit_s", "block_size", "max_queue", "trace_seconds"}
+    assert all(long[k] == knee[k] for k in same)
+    # the issue's median and bound; the deviation and the lower end so that 3% of the prompts are
+    # clipped to an end (2.0% and 1.1%) where the issue's 0.3 and 1,024 clipped 27% to two lengths
+    assert long["prompt_tokens"] == {"median": 1400, "sigma": 0.15, "min": 1025, "max": 1984}
+    assert long["budget_tokens"] == {"median": 32, "sigma": 0.5, "min": 16, "max": 64}
+    assert (long["slots"], long["positions_per_slot"]) == (16, 2048)  # the model's n_positions
+    assert long["reference_requests"] == [[1984, 64], [1025, 16]]
+    assert long["reference_fillers"] == {"count": 14, "prompt_tokens": 1100, "budget_tokens": 24}
+    assert len(long["reference_requests"]) + long["reference_fillers"]["count"] == long["slots"]
+    assert set(long["reference_limits"]) == {"gap_sigma_max", "gap_sigma_mean"}
+    n = round(long["rate_per_s"] * 50)
+    pairs = traffic_mod.pairs(long, n)
+    assert all(1025 <= p <= 1984 and 16 <= b <= 64 and p + b <= 2048 for p, b in pairs)
+    at_an_end = sum(p in (1025, 1984) for p, _b in pairs) / n
+    assert 0.02 < at_an_end < 0.04
+    # every prompt takes ONE bucket, of 1,984 rows (the next power of two, capped at the traffic's
+    # longest prompt), and warm-up compiles no admit step below it
+    from chipbench.runners import serve
+    assert serve.min_prompt_len(long) == 1025
+    pad, real_tokens = sum(1984 - p for p, _b in pairs), sum(p for p, _b in pairs)
+    assert 100.0 * pad / (pad + real_tokens) == pytest.approx(28.6, abs=0.2)
+    assert 1410 < real_tokens / n < 1422
+
+
+@pytest.mark.parametrize("workload", ["lm_serve_longprompt", "lm_serve_knee"])
+def test_a_token_altered_where_it_is_produced_is_not_correct(tiny, capsys, monkeypatch, workload):
+    """The rest of a run with the timed path broken underneath: the decoder the
+    engine steps hands back each row's logits moved one place along the
+    vocabulary, so every token the device picks is its neighbour.  Nothing
+    fails, nothing compiles in the window, and ``correct`` comes out false by
+    the configuration's own limit."""
+    import jax.numpy as jnp
+
+    from moolib_tpu.models.transformer import PagedTransformerLM
+
+    real_decode, real_both = PagedTransformerLM.decode, PagedTransformerLM.decode_with_prompt
+
+    def decode(self, *a, **kw):
+        logits, *rest = real_decode(self, *a, **kw)
+        return (jnp.roll(logits, 1, axis=-1), *rest)
+
+    def decode_with_prompt(self, *a, **kw):
+        logits, first, *rest = real_both(self, *a, **kw)
+        return (jnp.roll(logits, 1, axis=-1), jnp.roll(first, 1, axis=-1), *rest)
+
+    monkeypatch.setattr(PagedTransformerLM, "decode", decode)
+    monkeypatch.setattr(PagedTransformerLM, "decode_with_prompt", decode_with_prompt)
+    rc = bench_run.main(["--workload", workload, "--seed", str(2**31 + 12), "--seconds", "1.5", "--trace", "0"])
+    out = capsys.readouterr().out.strip().splitlines()
+    notes = next(json.loads(t[len("NOTES "):]) for t in out if t.startswith("NOTES "))
+    line = json.loads(out[-1])
+    assert rc == 0 and line["failed"] == 0 and notes["compiles_in_window"] == 0
+    assert notes["reference_gap_sigma_max"] > 10 * harness.load_json(
+        harness.BENCH_DIR, "configs", "cerebras-gpt-1.3b.json")["tolerance"]["serve_gap_sigma"]
+    assert line["correct"] is False
+
+
+def test_the_precision_control_is_not_correct(tiny, capsys, tmp_path):
+    """``tools/gap_control.py`` whole, at a size a test run holds: the engine
+    serves the cell's checked requests and fillers with every slot in use, and
+    the runner's own comparison finds the program correct and the control (the
+    reference's forward pass with every product's operands at float8's 3 bits
+    of mantissa, at the same prompts and served tokens) not, on every seed.
+    What the cell's own limits refuse is a reading of the chip at the cell's
+    own size (PERF.md)."""
+    from chipbench.tools import gap_control
+
+    out = tmp_path / "gaps.jsonl"
+    seeds = [2**31 + 21, 2**31 + 22, 2**31 + 23]
+    assert gap_control.main(["lm_serve_longprompt", "--out", str(out), "--seeds", *map(str, seeds)]) == 0
+    last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert last == {"seeds": 3, "program_correct": 3, "control_not_correct": 3}
+    lines = [json.loads(t) for t in out.read_text().splitlines()]
+    assert [ln["seed"] for ln in lines] == seeds
+    for ln in lines:  # the two checked requests and the two fillers, every token of each
+        assert ln["tokens"] == len(ln["program_gaps"]) == len(ln["control_gaps"]) == 16 + 4 + 2 * 6
+        assert set(ln["compared"]["control"]) == {"reference_gap_sigma_max", "reference_gap_sigma_mean"}
 
 
 def test_a_configuration_names_its_model():

@@ -134,5 +134,10 @@ def test_serving_cell_reports_the_registry_metrics(tiny, capsys):  # noqa: F811
     assert set(line["metrics"]) >= SERVE_TRACED_ON_CPU
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert m["first_token_mean_ms"] >= m["queue_wait_mean_ms"] + 0.9 * m["prefill_mean_ms"]
-    assert m["decode_dispatch_mean_ms"] + m["decode_fetch_mean_ms"] <= m["decode_step_mean_ms"]
+    # until PR 57 a step() held one dispatch and one fetch, and dispatch + fetch <= step held
+    # of the means.  Now a step() may dispatch twice (the step ahead, the step that carries this
+    # pass's admission), submit dispatches too, and a step() that books two steps waits twice:
+    # what still holds call for call is that every fetch lies inside a step()
+    assert 0.0 < m["decode_fetch_mean_ms"] <= m["decode_step_mean_ms"]
+    assert m["decode_dispatch_mean_ms"] > 0.0
     assert m["iteration_period_mean_ms"] >= m["decode_step_mean_ms"]

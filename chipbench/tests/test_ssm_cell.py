@@ -101,15 +101,21 @@ def test_the_cell_reports_the_expert_median_and_its_own_layers():
     assert OWN | {"paged_attn_share.moe", "kv_live_block_share.moe", "state_write_mean_ms",
                   "decode_step_mean_ms.moe", "hbm_peak_GB.serve.moe",
                   "device_idle_share.serve.moe", "slot_occupancy_mean.moe"} <= layer
-    assert len(layer) == 23 + 3 + 7
+    # every serving entry under a ``.moe`` name that all the expert cells share, its own seven,
+    # and what it shares with some of them: counted from the file, which later PRs append to
+    cells = {w["name"] for w in BENCH["workloads"] if w["name"] != CELL["name"]}
+    shared = {m["name"] for m in BENCH["per_layer"]
+              if CELL["name"] in m["workloads"] and cells & set(m["workloads"])}
+    assert layer == OWN | shared and len(OWN) == 7 and len(shared) >= 23 + 3
     # another cell's geometry stays that cell's, and the silent clock metric a benchmark PR's
     assert not layer & {"state_live_slot_share", "retention_live_slot_share", "kda_decode_share",
                         "paged_attn_roofline", "full_decode_attn_share",
                         "device_clock_lead_ms.serve.moe", "moe_held_pair_share"}
-    # entries at the END of their lists
-    assert BENCH["workloads"][-1] is CELL and BENCH["configs"][-1]["name"] == "ai21-jamba2-3b"
-    assert {m["name"] for m in BENCH["per_layer"][-7:]} == OWN
-    for m in BENCH["per_layer"][-7:]:
+    # its own entries, wherever they stand (at the END of their lists when PR 51 appended
+    # them; later PRs appended theirs behind)
+    own = [m for m in BENCH["per_layer"] if m["name"] in OWN]
+    assert len(own) == 7 and "device_clock_lead_ms.serve.moe" not in {m["name"] for m in BENCH["per_layer"]}
+    for m in own:
         assert m["workloads"] == ["jamba_serve_reasoning"] and m["unit"] == "%"
         assert m["moves"] == "req_ms_per_token_p50.moe" and m["layer"] == "kernels, serving"
 

@@ -39,8 +39,7 @@ def main(where: str, chips: int = 1) -> int:
             print(f"program_runs: no trace of {where!r}", file=sys.stderr)
             return 1
         path = paths[-1]
-    programs = harness.load_json(
-        harness.BENCH_DIR, "metrics", "prefill_device_share.json")["programs"]
+    programs = program_time.programs()
     data = tr.load(path)
     runs, host = program_time.extract(data, set(programs.values()))
     busy_s = tr.reduce(tr.extract(data), chips)["busy_s"]

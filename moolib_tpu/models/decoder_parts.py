@@ -42,6 +42,20 @@ _M_HELD_PREFILL_LOAD = _REG.histogram(
     "mean (prompt tokens x experts a token / the router's experts)",
     buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0),
 )
+_M_PREFILL_ROWS_PER_EXPERT = _REG.histogram(
+    "serve_moe_prefill_rows_per_expert",
+    "per prefill and expert layer: the prompt's (token, expert) pairs whose "
+    "expert is here, over the experts here that hold rows: the rows a grouped "
+    "matmul multiplies by one expert's matrix",
+    buckets=(1, 2, 4, 8, 16, 32, 64, 128, 192, 256, 384, 512, 768, 1024, 2048, 4096),
+)
+_M_PREFILL_PAIRS = _REG.histogram(
+    "serve_moe_prefill_pairs",
+    "per prefill and expert layer: the prompt's (token, expert) pairs whose "
+    "expert is here (pad tokens not counted): the live rows of the layer's two "
+    "grouped matmuls",
+    buckets=(64, 256, 1024, 2048, 4096, 8192, 12288, 16384, 24576, 32768, 65536),
+)
 _M_STATE_LIVE = _REG.histogram(
     "serve_engine_state_live_slots",
     "per decode step: slots holding live recurrent state (the active ones: "
@@ -358,16 +372,23 @@ def write_slot_rows(leaves, rows, slot):
 
 
 # ------------------------------------------------- the grouped-query layer
-def gqa_qkv(xn, w_q, w_kv, heads: int, kv_heads: int, head_dim: int, dtype):
+def gqa_qkv(xn, w_q, w_kv, heads: int, kv_heads: int, head_dim: int, dtype,
+            q_norm=None, k_norm=None, eps: float = 1e-6):
     """Normed inputs xn [T, D] through W_q and W_k | W_v (side by side in
-    ``w_kv``): q [T, heads, hd], k and v [T, kv_heads, hd], float32."""
+    ``w_kv``): q [T, heads, hd], k and v [T, kv_heads, hd], float32.
+    ``q_norm`` / ``k_norm`` [hd]: the learned scales of an RMSNorm over each
+    head of q and of k (the qwen3 lineage's, in front of the rotation); None:
+    no such norm."""
     T = xn.shape[0]
     # The barrier keeps the products as [T, heads x hd]: left to itself XLA
     # folds the attention kernels' head-major reshapes into the dots and
     # transposes W_q and W_kv (84 MB) in every decode step instead.
     q, kv = jax.lax.optimization_barrier((dot(xn, w_q, dtype), dot(xn, w_kv, dtype)))
     k, v = jnp.split(kv.reshape(T, 2 * kv_heads, head_dim), 2, axis=1)
-    return q.reshape(T, heads, head_dim), k, v
+    q = q.reshape(T, heads, head_dim)
+    if q_norm is not None:
+        q, k = rms_norm(q, q_norm, eps), rms_norm(k, k_norm, eps)
+    return q, k, v
 
 
 def paged_gqa_decode(pool_k, pool_v, q, k, v, paged):
@@ -420,6 +441,17 @@ def observe_held_step(counters, live: int, top_k: int) -> None:
     for n_pairs, n_touched in zip(pairs, touched):
         _M_HELD_PAIRS.observe(int(n_pairs) / max(1, live * top_k))
         _M_HELD_TOUCHED.observe(int(n_touched))
+
+
+def observe_prefill_rows_per_expert(pairs, touched) -> None:
+    """By expert layer of one prefill: its (token, expert) pairs here, and
+    those over the experts here that hold rows: the rows a grouped matmul
+    multiplies by one expert's matrix (which side of the kernel's ridge the
+    call ran on)."""
+    for n_pairs, n_touched in zip(pairs, touched):
+        _M_PREFILL_PAIRS.observe(int(n_pairs))
+        if n_touched:
+            _M_PREFILL_ROWS_PER_EXPERT.observe(int(n_pairs) / int(n_touched))
 
 
 def observe_held_prefill(counters, prompt_len: int, top_k: int, router_experts: int) -> None:

@@ -1,20 +1,33 @@
 """A decoder built from a published configuration file whose attention layers
-are of two kinds that differ in SHAPE (``model_type`` ``laguna``): sliding-window
-grouped-query attention (a query sees itself and the ``sliding_window - 1``
-keys before it; whole heads rotated at one base) and, in one layer of four,
-full causal attention with FEWER query heads whose first half a head is rotated
-by a YaRN table; both with one sigmoid gate a head on the attention's output.
-A leading dense SwiGLU layer, then dropless softmax-routed experts with a shared
-expert, of which this chip may hold a share (``parallel.moe.dropless_moe``'s
-``held_from``).
+are of two kinds: sliding-window grouped-query attention (a query sees itself
+and the ``sliding_window - 1`` keys before it) on a RING a slot, and full causal
+attention on the paged pools, each kind rotated by its own group of the file's
+``rope_parameters`` (the sliding layers at one base, the full layers by a YaRN
+table); behind every mixer dropless softmax-routed experts
+(``parallel.moe.dropless_moe``), of which this chip may hold a share
+(``held_from``) or all.  Two published files build it today (``model_type``
+``laguna`` and ``mellum``), and the layer plan is the FILE's:
 
-Plain functions over a parameter pytree.  The pattern comes from the file's
-``layer_types``: a leading full layer (the dense one), then **periods** of the
-sliding layers up to the next full layer and that layer (three and one, as
-published).  A period's sliding layers are stacked and run under one
-``jax.lax.scan``; the periods are a Python loop, so that each full layer's K/V
-pools are operands of their own.  The model offers the serving engine both
-kinds of cache leaf (``engine/engine.py``), and BOTH hold keys and values:
+- ``mlp_layer_types``: the leading ``dense`` layers (none, or one full-attention
+  layer with a dense SwiGLU), every layer behind them ``sparse``;
+- ``layer_types``: behind the leading layers, whole **periods** (the shortest
+  repeat of the published list), each with at least one full layer wherever in
+  the period it stands (``s s s f`` in both files).  The RUNS of sliding layers
+  between full layers (``decoder_parts.runs_between``) are each a stack of
+  weights under one ``jax.lax.scan``; the full layers are a Python loop between
+  them, so that each full layer's K/V pools are operands of their own;
+- one head count for both kinds (``num_attention_heads``) or one a kind
+  (``num_attention_heads_per_layer``); a sigmoid gate a head on the attention's
+  output where the file says ``gating`` ``per-head``, none without the key; a
+  shared expert where ``shared_expert_intermediate_size`` is there; a learned
+  RMSNorm over each head of q and k before the rotation where ``qk_norm`` is
+  true; part of a head rotated where a rope group has ``partial_rotary_factor``;
+  the routed sum scaled where ``moe_routed_scaling_factor`` is there.
+
+What the module cannot honour is refused by the key's name (``from_config``).
+
+Plain functions over a parameter pytree.  The model offers the serving engine
+both kinds of cache leaf (``engine/engine.py``), and BOTH hold keys and values:
 
 - :meth:`cache_spec`: the paged pools of the FULL layers, block axis first:
   K and V of each, ``[num_blocks, block_size, kv_heads, head_dim]``;
@@ -39,8 +52,8 @@ kinds of cache leaf (``engine/engine.py``), and BOTH hold keys and values:
   is slot 0's ring).
 
 Precision: weights and matmul inputs in ``dtype`` (bfloat16), products
-accumulated in float32; the residual stream, RMSNorm, rotation, router, softmax
-and logits in float32; pools and rings in ``dtype``.
+accumulated in float32; the residual stream, RMSNorm (the q/k norm too),
+rotation, router, softmax and logits in float32; pools and rings in ``dtype``.
 """
 
 from __future__ import annotations
@@ -52,7 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention, window_key_blocks
 from ..ops.paged_attention import PagedState, paged_attention
 from ..parallel.moe import dropless_moe, softmax_topk_route, swiglu
 from . import decoder_parts as parts
@@ -61,7 +74,8 @@ _M_RING_ROWS = telemetry.get_registry().histogram(
     "serve_engine_ring_live_rows",
     "per decode step: rows of a sliding layer's ring that an active slot "
     "attends over, min(position + 1, window), mean over the active slots",
-    buckets=(1, 8, 32, 64, 128, 192, 256, 320, 384, 448, 512, 1024, 4096),
+    buckets=(1, 8, 32, 64, 128, 192, 256, 320, 384, 448, 512, 640, 768, 896, 1024,
+             1536, 2048, 4096),
 )
 
 _M_RING_READ = telemetry.get_registry().histogram(
@@ -72,6 +86,17 @@ _M_RING_READ = telemetry.get_registry().histogram(
     buckets=(64, 256, 1024, 2048, 4096, 8192, 12288, 16384, 24576, 32768, 65536),
 )
 
+_M_WINDOW_BLOCKS = telemetry.get_registry().counter(
+    "serve_engine_window_key_blocks",
+    "per prefill, by sliding layer: (query block, key block) pairs of the "
+    "windowed flash forward over the prompt's bucket; blocks=visited the pairs "
+    "it copies and multiplies, blocks=skipped the further pairs a causal forward "
+    "of the same length and blocks would visit (visited + skipped: the causal "
+    "count).  From the bucket, the window and the kernel's block sizes, on the "
+    "host; a bucket that takes no kernel counts nothing",
+    labelnames=("blocks",),
+)
+
 _RING_BLOCK = 128  # rows of a ring that the paged kernel copies at once
 # ``init`` draws W_q at this many times the fan-in deviation, so that scores
 # have this deviation and a query's weight lies on a few keys, as a trained
@@ -79,7 +104,17 @@ _RING_BLOCK = 128  # rows of a ring that the paged kernel copies at once
 # uniform, every mixer's output is a mean of values (a 25th of the residual
 # stream's deviation), and a fault in WHICH keys a query sees moves no token:
 # the cell's check read a prefill without its window mask as sound (PERF.md).
+# Under a q/k norm the scale sits on the q norm's weights instead (the norm
+# would undo it on W_q).
 _Q_SCALE = 4.0
+_FULL, _SLIDING = "full_attention", "sliding_attention"
+
+
+def _list_period(kinds) -> int:
+    """The shortest repeat of a per-layer list (its own length where it does
+    not repeat)."""
+    return next(p for p in range(1, len(kinds) + 1)
+                if all(a == b for a, b in zip(kinds, kinds[p:])))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +123,13 @@ class SlidingGqaMoELM:
     ``num_experts`` counts the experts HELD here, ids ``held_from ..``;
     ``router_experts`` (a key of the file under that name; without it the
     held count) is the router's width, the published count.  ``full_rope`` and
-    ``sliding_rope`` are ``rope_parameters``' two groups as sorted items."""
+    ``sliding_rope`` are ``rope_parameters``' two groups as sorted items.  The
+    plan: ``lead_layers`` leading full-attention layers with a dense
+    feed-forward (0 or 1), then ``runs``: the sliding layers before the first
+    full layer behind them, between two, and after the last.  ``gated``: a
+    sigmoid gate a head; ``qk_norm``: an RMSNorm a head on q and k;
+    ``shared_expert_intermediate_size`` 0: no shared expert.  ``embed_scale``
+    (the file's own key ``embed_init_scale``) is ``init``'s alone."""
 
     vocab_size: int
     hidden_size: int
@@ -107,8 +148,12 @@ class SlidingGqaMoELM:
     full_rope: Tuple
     sliding_rope: Tuple
     moe_routed_scaling_factor: float = 1.0
-    period: int = 4
+    lead_layers: int = 1
+    runs: Tuple[int, ...] = (3, 0)
+    gated: bool = True
+    qk_norm: bool = False
     held_from: int = 0
+    embed_scale: float = 1.0
     rms_norm_eps: float = 1e-6
     max_len: int = 8192  # positions the engine may ask for
     dtype: Any = jnp.bfloat16
@@ -123,24 +168,31 @@ class SlidingGqaMoELM:
         is refused by name."""
         config, dtype = parts.load_config(config, overrides)
         depth = config["num_hidden_layers"]
-        kinds = list(config["layer_types"][:depth])
-        heads = list(config["num_attention_heads_per_layer"][:depth])
-        period = kinds.index("full_attention", 1) if "full_attention" in kinds[1:] else 0
-        pattern = ["full_attention"] + (
-            ["sliding_attention"] * (period - 1) + ["full_attention"]) * (
-                (depth - 1) // max(period, 1))
-        by_kind = {k: {h for kind, h in zip(kinds, heads) if kind == k} for k in set(kinds)}
+        kinds = list(config["layer_types"])
+        ffns = list(config["mlp_layer_types"][:depth])
+        lead = next((i for i, f in enumerate(ffns) if f != "dense"), len(ffns))
+        # The period is the published list's, behind the leading layers; the
+        # depth's share of it must hold whole periods with a full layer each.
+        period = _list_period(kinds[lead:]) if len(kinds) > lead else 0
+        body = kinds[lead:depth]
+        heads = list(config.get("num_attention_heads_per_layer",
+                                [config["num_attention_heads"]] * len(kinds))[:depth])
+        by_kind = {k: {h for kind, h in zip(kinds, heads) if kind == k}
+                   for k in (_FULL, _SLIDING)}
         rope = config["rope_parameters"]
+        gating = config.get("gating")
         refused = {
-            "layer_types": period < 2 or kinds[:len(pattern)] != pattern,
-            # whole periods behind the leading layer
-            "num_hidden_layers": depth < 2 or (depth - 1) % max(period, 1) != 0,
-            "num_attention_heads_per_layer": any(len(h) != 1 for h in by_kind.values()),
-            "mlp_layer_types": list(config["mlp_layer_types"][:depth])
-            != ["dense"] + ["sparse"] * (depth - 1),
-            "mlp_only_layers": list(config.get("mlp_only_layers", [0])) != [0],
+            "layer_types": not set(kinds[:depth]) <= {_FULL, _SLIDING}
+            or _FULL not in kinds[lead:lead + period] or _SLIDING not in body
+            or any(k != _FULL for k in kinds[:lead]),
+            # whole periods behind the leading layers
+            "num_hidden_layers": depth <= lead or (depth - lead) % max(period, 1) != 0,
+            "num_attention_heads_per_layer": any(len(h) > 1 for h in by_kind.values()),
+            "mlp_layer_types": lead > 1 or any(f != "sparse" for f in ffns[lead:]),
+            "mlp_only_layers": list(config.get("mlp_only_layers", range(lead)))
+            != list(range(lead)),
             "decoder_sparse_step": config.get("decoder_sparse_step", 1) != 1,
-            "gating": config.get("gating") != "per-head",
+            "gating": gating not in (None, "per-head"),
             "gating_types": any(g != "per_head" for g in config.get("gating_types", [])[:depth]),
             "moe_router_logit_softcapping": config.get("moe_router_logit_softcapping", 0) != 0,
             "moe_apply_router_weight_on_input":
@@ -148,6 +200,7 @@ class SlidingGqaMoELM:
             "norm_topk_prob": config.get("norm_topk_prob", True) is not True,
             "attention_bias": config.get("attention_bias", False) is not False,
             "tie_word_embeddings": config.get("tie_word_embeddings", False) is not False,
+            "hidden_act": config.get("hidden_act", "silu") != "silu",
             "rope_parameters.full_attention.rope_type":
                 rope["full_attention"].get("rope_type") != "yarn",
             "rope_parameters.sliding_attention.rope_type":
@@ -159,19 +212,21 @@ class SlidingGqaMoELM:
         return cls(
             vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
             intermediate_size=config["intermediate_size"], num_hidden_layers=depth,
-            full_heads=by_kind["full_attention"].pop(),
-            sliding_heads=by_kind["sliding_attention"].pop(),
+            full_heads=by_kind[_FULL].pop(), sliding_heads=by_kind[_SLIDING].pop(),
             num_key_value_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
             sliding_window=config["sliding_window"],
             moe_intermediate_size=config["moe_intermediate_size"],
-            shared_expert_intermediate_size=config["shared_expert_intermediate_size"],
+            shared_expert_intermediate_size=config.get("shared_expert_intermediate_size", 0),
             num_experts=config["num_experts"],
             router_experts=config.get("router_experts", config["num_experts"]),
             num_experts_per_tok=config["num_experts_per_tok"],
             full_rope=tuple(sorted(rope["full_attention"].items())),
             sliding_rope=tuple(sorted(rope["sliding_attention"].items())),
             moe_routed_scaling_factor=config.get("moe_routed_scaling_factor", 1.0),
-            period=period, held_from=config.get("held_from", 0),
+            lead_layers=lead, runs=parts.runs_between(body, _FULL),
+            gated=gating == "per-head", qk_norm=bool(config.get("qk_norm", False)),
+            held_from=config.get("held_from", 0),
+            embed_scale=config.get("embed_init_scale", 1.0),
             rms_norm_eps=config.get("rms_norm_eps", 1e-6),
             max_len=config.get("max_len", min(config.get("max_position_embeddings", 8192), 8192)),
             dtype=dtype,
@@ -179,20 +234,16 @@ class SlidingGqaMoELM:
 
     # ------------------------------------------------------------ geometry
     @property
-    def periods(self) -> int:
-        return (self.num_hidden_layers - 1) // self.period
-
-    @property
     def sliding_layers(self) -> int:
-        return self.periods * (self.period - 1)
+        return sum(self.runs)
 
     @property
     def full_layers(self) -> int:
-        return 1 + self.periods
+        return self.lead_layers + len(self.runs) - 1
 
     @property
     def expert_layers(self) -> int:
-        return self.num_hidden_layers - 1
+        return self.num_hidden_layers - self.lead_layers
 
     @property
     def ring_block(self) -> int:
@@ -207,8 +258,10 @@ class SlidingGqaMoELM:
 
     @property
     def prefill_counters(self) -> int:
-        """The fullest held expert's tokens, by expert layer."""
-        return self.expert_layers
+        """By expert layer the fullest held expert's tokens, then the held
+        (token, expert) pairs, then the held experts that hold rows; last the
+        prompt's bucket."""
+        return 3 * self.expert_layers + 1
 
     def observe_step(self, counters) -> None:
         live = int(counters[0])
@@ -217,8 +270,13 @@ class SlidingGqaMoELM:
         parts.observe_held_step(counters[2:], live, self.num_experts_per_tok)
 
     def observe_prefill(self, counters, prompt_len: int) -> None:
+        L = self.expert_layers
         parts.observe_held_prefill(
-            counters, prompt_len, self.num_experts_per_tok, self.router_experts)
+            counters[:L], prompt_len, self.num_experts_per_tok, self.router_experts)
+        parts.observe_prefill_rows_per_expert(counters[L:2 * L], counters[2 * L:3 * L])
+        visited, causal = window_key_blocks(int(counters[3 * L]), self.sliding_window)
+        _M_WINDOW_BLOCKS.inc(visited * self.sliding_layers, blocks="visited")
+        _M_WINDOW_BLOCKS.inc((causal - visited) * self.sliding_layers, blocks="skipped")
 
     def cache_spec(self, num_blocks: int, block_size: int):
         pool = jax.ShapeDtypeStruct(
@@ -241,42 +299,55 @@ class SlidingGqaMoELM:
     # -------------------------------------------------------------- weights
     def init(self, key) -> Dict:
         """Random weights from ``key``: normal with standard deviation
-        fan_in ** -0.5 (embedding 1.0; W_q ``_Q_SCALE`` times it), norms 1, a
-        zero selection bias.  Jit it: the weights are made on the device."""
+        fan_in ** -0.5 (embedding ``embed_scale``; W_q ``_Q_SCALE`` times it, or
+        the q norm's weights where there is one), norms 1, a zero selection bias.
+        Jit it: the weights are made on the device.  ``lead`` is there with a
+        leading dense layer; ``swa`` holds one stack a run of sliding layers
+        (an empty run has none) and ``full`` the full layers behind the
+        leading ones."""
         D, hd, Hk = self.hidden_size, self.head_dim, self.num_key_value_heads
         F, Fs, E = self.moe_intermediate_size, self.shared_expert_intermediate_size, \
             self.router_experts
-        P, K = self.periods, self.period - 1
         keys, w = parts.weight_drawer(key, 64, self.dtype)
 
         def mixer(lead, H):
-            return {
+            p = {
                 "attn_norm": jnp.ones(lead + (D,), jnp.float32),
-                "w_q": w(lead + (D, H * hd), D, scale=_Q_SCALE),
+                "w_q": w(lead + (D, H * hd), D, scale=1.0 if self.qk_norm else _Q_SCALE),
                 "w_kv": w(lead + (D, 2 * Hk * hd), D),
-                "w_gate": w(lead + (D, H), D),
                 "w_o": w(lead + (H * hd, D), H * hd),
                 "ffn_norm": jnp.ones(lead + (D,), jnp.float32),
             }
+            if self.gated:
+                p["w_gate"] = w(lead + (D, H), D)
+            if self.qk_norm:
+                p["q_norm"] = jnp.full(lead + (hd,), _Q_SCALE, jnp.float32)
+                p["k_norm"] = jnp.ones(lead + (hd,), jnp.float32)
+            return p
 
         def ffn(lead):
-            return {
-                "router": w(lead + (D, E), D, jnp.float32),
-                "router_bias": jnp.zeros(lead + (E,), jnp.float32),
-                "shared_gu": w(lead + (D, 2 * Fs), D),
-                "shared_down": w(lead + (Fs, D), Fs),
-            }
+            p = {"router": w(lead + (D, E), D, jnp.float32),
+                 "router_bias": jnp.zeros(lead + (E,), jnp.float32)}
+            if Fs:
+                p["shared_gu"] = w(lead + (D, 2 * Fs), D)
+                p["shared_down"] = w(lead + (Fs, D), Fs)
+            return p
 
         G = self.num_experts
+        params = {"embed": w((self.vocab_size, D), 1.0, scale=self.embed_scale)}
+        if self.lead_layers:
+            params["lead"] = {
+                **mixer((), self.full_heads),
+                "dense_gu": w((D, 2 * self.intermediate_size), D),
+                "dense_down": w((self.intermediate_size, D), self.intermediate_size)}
         return {
-            "embed": w((self.vocab_size, D), 1.0),
-            "lead": {**mixer((), self.full_heads),
-                     "dense_gu": w((D, 2 * self.intermediate_size), D),
-                     "dense_down": w((self.intermediate_size, D), self.intermediate_size)},
-            # A tuple over the periods, not a leading axis: a period's slice of
+            **params,
+            # A tuple over the runs, not a leading axis: a run's slice of
             # one stacked array would be a copy of its weights in every step.
-            "swa": tuple({**mixer((K,), self.sliding_heads), **ffn((K,))} for _ in range(P)),
-            "full": tuple({**mixer((), self.full_heads), **ffn(())} for _ in range(P)),
+            "swa": tuple({**mixer((K,), self.sliding_heads), **ffn((K,))}
+                         for K in self.runs if K),
+            "full": tuple({**mixer((), self.full_heads), **ffn(())}
+                          for _ in self.runs[1:]),
             "experts_gu": w((self.expert_layers, G, D, 2 * F), D),
             "experts_down": w((self.expert_layers, G, F, D), F),
             "final_norm": jnp.ones((D,), jnp.float32),
@@ -296,7 +367,8 @@ class SlidingGqaMoELM:
 
     def _rotate_full(self, x, pos):
         """A full layer's rotation: the first ``partial_rotary_factor`` of a
-        head by the YaRN table, cos and sin times ``attention_factor``."""
+        head (all of it without the key) by the YaRN table, cos and sin times
+        ``attention_factor``."""
         r = dict(self.full_rope)
         rotated = int(self.head_dim * r.get("partial_rotary_factor", 1.0))
         table = parts.yarn_inv_freq(
@@ -311,17 +383,21 @@ class SlidingGqaMoELM:
         return parts.rope_table(x, pos, table)
 
     def _qkv(self, p, xn, pos, heads, rotate):
-        """q [T, heads, hd] and k, v [T, Hk, hd], q and k rotated at ``pos``
-        [T] (which broadcasts over their heads), float32."""
+        """q [T, heads, hd] and k, v [T, Hk, hd], q and k normed a head where
+        the layer has the scales, then rotated at ``pos`` [T] (which
+        broadcasts over their heads), float32."""
         q, k, v = parts.gqa_qkv(xn, p["w_q"], p["w_kv"], heads,
-                                self.num_key_value_heads, self.head_dim, self.dtype)
+                                self.num_key_value_heads, self.head_dim, self.dtype,
+                                p.get("q_norm"), p.get("k_norm"), self.rms_norm_eps)
         return rotate(q, pos[:, None]), rotate(k, pos[:, None]), v
 
     def _output(self, p, xn, att):
-        """att [T, H, hd] under its gate, one sigmoid a head, through W_o."""
-        gate = jax.nn.sigmoid(self._dot(xn, p["w_gate"]))  # [T, H]
-        return self._dot((att.astype(jnp.float32) * gate[..., None]).reshape(xn.shape[0], -1),
-                         p["w_o"])
+        """att [T, H, hd], under its gate (one sigmoid a head) where the
+        layer has one, through W_o."""
+        if "w_gate" in p:
+            gate = jax.nn.sigmoid(self._dot(xn, p["w_gate"]))  # [T, H]
+            att = att.astype(jnp.float32) * gate[..., None]
+        return self._dot(att.reshape(xn.shape[0], -1), p["w_o"])
 
     def _dense(self, p, h):
         x = self._norm(h, p["ffn_norm"]).astype(self.dtype)
@@ -335,6 +411,23 @@ class SlidingGqaMoELM:
             top_k=self.num_experts_per_tok, scale=self.moe_routed_scaling_factor,
             valid=valid, layer=layer, held_from=self.held_from, route=softmax_topk_route)
         return h + y, load
+
+    def _plan(self, params):
+        """The layers behind the leading ones, in order: ("swa", the run's
+        stack, its expert layers' indices, its ring layers' indices) for a run
+        of sliding layers, ("full", the layer's weights, its expert layer, its
+        pool) for a full layer."""
+        plan, stacks = [], iter(params["swa"])
+        layer = ring = 0
+        for i, run in enumerate(self.runs):
+            if run:
+                steps = jnp.arange(run, dtype=jnp.int32)
+                plan.append(("swa", next(stacks), layer + steps, ring + steps))
+                layer, ring = layer + run, ring + run
+            if i < len(self.runs) - 1:
+                plan.append(("full", params["full"][i], layer, self.lead_layers + i))
+                layer += 1
+        return plan
 
     # ------------------------------------------------------------- prefill
     def _attend_prompt(self, p, h, pos, heads, rotate, window):
@@ -358,44 +451,47 @@ class SlidingGqaMoELM:
             return self._attend_prompt(
                 p, h, pos, self.sliding_heads, self._rotate_sliding, self.sliding_window)
 
+    def _ffn_prefill(self, p, experts, h, layer, valid):
+        with jax.named_scope("moe_prefill"):
+            return self._ffn(p, experts, h, layer, valid)
+
     def _forward(self, params, toks, tp):
         """The whole prompt toks [T] of which the first ``tp`` are real (None:
         all).  Returns (h [T, D], K and V of the full layers [T, Hk, hd] a
         layer, K and V of the sliding layers [sliding_layers, T, Hk, hd],
-        tokens a held expert by expert layer [L - 1, G])."""
+        tokens a held expert by expert layer [expert layers, G])."""
         T = toks.shape[0]
         pos = jnp.arange(T)
         valid = None if tp is None else pos < tp
         h = params["embed"][toks].astype(jnp.float32)
         experts = parts.held_experts(params)
-        h, k, v = self._full_prefill(params["lead"], h, pos)
-        h = self._dense(params["lead"], h)
-        ks, vs, ring_k, ring_v, loads = [k], [v], [], [], []
-        for period in range(self.periods):
-            first = period * self.period  # of the period's expert layers
+        ks, vs, ring_k, ring_v, loads = [], [], [], [], []
+        if self.lead_layers:
+            h, k, v = self._full_prefill(params["lead"], h, pos)
+            h = self._dense(params["lead"], h)
+            ks.append(k), vs.append(v)
+        for kind, p, layer, _cache in self._plan(params):
+            if kind == "swa":
+                def body(h, xs):
+                    p, layer = xs
+                    h, k, v = self._swa_prefill(p, h, pos)
+                    h, load = self._ffn_prefill(p, experts, h, layer, valid)
+                    return h, (k, v, load)
 
-            def body(h, xs):
-                p, layer = xs
-                h, k, v = self._swa_prefill(p, h, pos)
-                h, load = self._ffn(p, experts, h, layer, valid)
-                return h, (k, v, load)
-
-            h, (k, v, load) = jax.lax.scan(
-                body, h, (params["swa"][period],
-                          first + jnp.arange(self.period - 1, dtype=jnp.int32)))
-            ring_k.append(k), ring_v.append(v), loads.append(load)
-            p = params["full"][period]
-            h, k, v = self._full_prefill(p, h, pos)
-            h, load = self._ffn(p, experts, h, first + self.period - 1, valid)
-            ks.append(k), vs.append(v), loads.append(load[None])
+                h, (k, v, load) = jax.lax.scan(body, h, (p, layer))
+                ring_k.append(k), ring_v.append(v), loads.append(load)
+            else:
+                h, k, v = self._full_prefill(p, h, pos)
+                h, load = self._ffn_prefill(p, experts, h, layer, valid)
+                ks.append(k), vs.append(v), loads.append(load[None])
         cat = lambda xs: jnp.concatenate(xs, axis=0)
         return h, ks, vs, cat(ring_k), cat(ring_v), cat(loads)
 
     def prefill(self, params, toks, tp, block_size: int):
         """toks [1, Lb] (the prompt padded to its bucket), tp the true
         length.  Returns (rows for :meth:`write_rows` and :meth:`write_state`,
-        logits [V] float32 at position tp - 1, counters [expert layers] int32:
-        the fullest held expert's tokens by layer, pad tokens not counted)."""
+        logits [V] float32 at position tp - 1, counters int32
+        (:attr:`prefill_counters`; pad tokens not counted))."""
         h, ks, vs, ring_k, ring_v, load = self._forward(params, toks[0], tp)
         # Row r of a ring holds the last position before tp that is r modulo
         # the window (rows past tp, where the prompt is shorter than the
@@ -410,7 +506,10 @@ class SlidingGqaMoELM:
         rows = {"blocks": {"k": blocks(ks), "v": blocks(vs)},
                 "slots": {"k": jnp.take(ring_k, at, axis=1), "v": jnp.take(ring_v, at, axis=1)}}
         logits = self._head(params, jnp.take(h, tp - 1, axis=0))
-        return rows, logits, jnp.max(load, axis=-1).astype(jnp.int32)
+        counters = jnp.concatenate([
+            jnp.max(load, axis=-1).astype(jnp.int32), parts.held_step_counters(load),
+            jnp.full((1,), toks.shape[1], jnp.int32)])
+        return rows, logits, counters
 
     # -------------------------------------------------------------- decode
     def _ring_write(self, ring, x, layer, row, active):
@@ -458,38 +557,36 @@ class SlidingGqaMoELM:
         experts = parts.held_experts(params)
         pools_k, pools_v = list(cache.blocks["k"]), list(cache.blocks["v"])
         ring_k, ring_v = cache.slots["k"], cache.slots["v"]
-        h, pools_k[0], pools_v[0] = self._full_decode(
-            params["lead"], h, pools_k[0], pools_v[0], paged)
-        h = self._dense(params["lead"], h)
+        if self.lead_layers:
+            h, pools_k[0], pools_v[0] = self._full_decode(
+                params["lead"], h, pools_k[0], pools_v[0], paged)
+            h = self._dense(params["lead"], h)
         loads = []
-        for period in range(self.periods):
-            first = period * self.period  # of the period's expert layers
+        for kind, p, layer, at in self._plan(params):
+            if kind == "swa":
+                def body(carry, xs):
+                    h, ring_k, ring_v = carry
+                    p, layer, ring_layer = xs
+                    xn = self._norm(h, p["attn_norm"])
+                    q, k, v = self._qkv(
+                        p, xn, position, self.sliding_heads, self._rotate_sliding)
+                    with jax.named_scope("swa_decode"):
+                        row = self._ring_row(position)
+                        ring_k = self._ring_write(ring_k, k, ring_layer, row, active)
+                        ring_v = self._ring_write(ring_v, v, ring_layer, row, active)
+                        att = self._ring_attend(q, ring_k, ring_v, ring_layer, position, active)
+                    h = h + self._output(p, xn, att)
+                    h, load = self._ffn(p, experts, h, layer, active)
+                    return (h, ring_k, ring_v), load
 
-            def body(carry, xs):
-                h, ring_k, ring_v = carry
-                p, layer, ring_layer = xs
-                xn = self._norm(h, p["attn_norm"])
-                q, k, v = self._qkv(p, xn, position, self.sliding_heads, self._rotate_sliding)
-                with jax.named_scope("swa_decode"):
-                    row = self._ring_row(position)
-                    ring_k = self._ring_write(ring_k, k, ring_layer, row, active)
-                    ring_v = self._ring_write(ring_v, v, ring_layer, row, active)
-                    att = self._ring_attend(q, ring_k, ring_v, ring_layer, position, active)
-                h = h + self._output(p, xn, att)
+                (h, ring_k, ring_v), load = jax.lax.scan(
+                    body, (h, ring_k, ring_v), (p, layer, at))
+                loads.append(load)
+            else:
+                h, pools_k[at], pools_v[at] = self._full_decode(
+                    p, h, pools_k[at], pools_v[at], paged)
                 h, load = self._ffn(p, experts, h, layer, active)
-                return (h, ring_k, ring_v), load
-
-            K = self.period - 1
-            steps = jnp.arange(K, dtype=jnp.int32)
-            (h, ring_k, ring_v), load = jax.lax.scan(
-                body, (h, ring_k, ring_v),
-                (params["swa"][period], first + steps, period * K + steps))
-            loads.append(load)
-            p = params["full"][period]
-            h, pools_k[period + 1], pools_v[period + 1] = self._full_decode(
-                p, h, pools_k[period + 1], pools_v[period + 1], paged)
-            h, load = self._ffn(p, experts, h, first + K, active)
-            loads.append(load[None])
+                loads.append(load[None])
         counters = jnp.concatenate([
             jnp.sum(active, dtype=jnp.int32)[None],
             jnp.sum(jnp.where(active, jnp.minimum(position + 1, W), 0), dtype=jnp.int32)[None],
@@ -507,12 +604,12 @@ class SlidingGqaMoELM:
 
 
 def tiny_config() -> Dict:
-    """The published SHAPE at a size the CPU tests run: a leading dense full
+    """``laguna``'s published SHAPE at a size the CPU tests run: a leading dense full
     layer and one period of three sliding layers and a full one, two head
     counts (6 sliding, 4 full) over 2 K/V heads of 128 (the kernels' lanes),
     full layers half rotated by a YaRN table, a window of 8, 8 of 16 experts
     held, 3 a token."""
-    period = ["sliding_attention"] * 3 + ["full_attention"]
+    period = [_SLIDING] * 3 + [_FULL]
     return {
         "model_type": "laguna", "vocab_size": 384, "hidden_size": 256,
         "intermediate_size": 512, "num_hidden_layers": 5, "num_attention_heads": 4,
@@ -530,10 +627,37 @@ def tiny_config() -> Dict:
             "sliding_attention": {
                 "rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1}},
         # two periods' worth: a test may run a depth of 9
-        "layer_types": ["full_attention"] + period * 2,
+        "layer_types": [_FULL] + period * 2,
         "moe_apply_router_weight_on_input": False,
         "mlp_layer_types": ["dense"] + ["sparse"] * 8,
         "gating_types": ["per_head"] * 9, "moe_routed_scaling_factor": 2.5,
         "num_attention_heads_per_layer": [4] + [6, 6, 6, 4] * 2,
         "moe_router_logit_softcapping": 0,
+    }
+
+
+def tiny_mellum_config() -> Dict:
+    """``mellum``'s published SHAPE at a size the CPU tests run: two periods of
+    three sliding layers and a full one with nothing in front, every layer
+    sparse, ONE head count (4 over 2 K/V heads of 128), whole heads rotated
+    (the full layers by a YaRN table), an RMSNorm a head on q and k, no gate,
+    no shared expert, no scale on the routed sum, a window of 8, all 8 experts
+    held, 2 a token."""
+    period = [_SLIDING] * 3 + [_FULL]
+    return {
+        "model_type": "mellum", "vocab_size": 384, "hidden_size": 256,
+        "intermediate_size": 512, "num_hidden_layers": 8, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 128, "max_position_embeddings": 1024,
+        "attention_bias": False, "hidden_act": "silu", "rms_norm_eps": 1e-6,
+        "num_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 128,
+        "norm_topk_prob": True, "tie_word_embeddings": False, "sliding_window": 8,
+        "max_window_layers": 0, "use_sliding_window": True, "qk_norm": True,
+        "rope_parameters": {
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 16,
+                "original_max_position_embeddings": 64, "beta_slow": 1, "beta_fast": 32,
+                "attention_factor": 1.2772588722239782},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 500000}},
+        # three periods' worth: a test may run a depth of 4 or 12
+        "layer_types": period * 3, "mlp_layer_types": ["sparse"] * 12,
     }

@@ -689,6 +689,22 @@ def _auto_blocks(Tq, Tk, window=None):
     return _largest_divisor(Tq, 512), _largest_divisor(Tk, cap_k)
 
 
+def window_key_blocks(T: int, window: int):
+    """(visited, causal): the (query block, key block) pairs that a windowed
+    causal call over ``T`` positions copies and multiplies a head at its own
+    blocks (:func:`_auto_blocks`), and the pairs a causal forward at those
+    blocks would.  A window no shorter than ``T`` is no window (every span
+    starts at block 0: both counts are the causal one); (0, 0) where ``T``
+    takes no kernel.  Plain integers:
+    for a host that counts what a prefill's kernel skipped."""
+    block_q, block_k = _auto_blocks(T, T, window if window < T else None)
+    if block_q < 128 or block_k < 128:
+        return 0, 0
+    spans = [_window_blocks(i, window, block_q, block_k) for i in range(T // block_q)]
+    return (sum(last - first + 1 for first, last in spans),
+            sum(last + 1 for _first, last in spans))
+
+
 def _interpret(interpret):
     if interpret is not None:
         return interpret

@@ -346,11 +346,11 @@ def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
 
     ``p``: ``router`` [D, E] and ``router_bias`` [E] (float32), ``experts_gu``
     [E, D, 2F], ``experts_down`` [E, F, D] (or both stacked over layers, with
-    ``layer`` the index: see :func:`grouped_matmul`), ``shared_gu`` [D, 2F],
-    ``shared_down`` [F, D].  Returns (y [T, D] float32, tokens an expert
+    ``layer`` the index: see :func:`grouped_matmul`), and where the layer has a
+    shared expert ``shared_gu`` [D, 2F] and ``shared_down`` [F, D].  Returns (y [T, D] float32, tokens an expert
     [E] int32).  ``valid`` [T] bool leaves pad tokens (a prompt's bucket past
     its length, a decode slot nobody holds) out of the count AND out of the
-    groups: their rows take the shared expert alone, so the experts only a pad
+    groups: their rows take the shared expert alone (nothing without one), so the experts only a pad
     token chose are not read (an idle slot keeps its last token: at half
     occupancy a decode step read 46 experts a layer where its active slots'
     tokens had chosen 27).
@@ -392,4 +392,6 @@ def dropless_moe(x32, p, *, top_k: int, scale: float, valid=None,
     elif valid is not None:
         pairs = jnp.where(valid[:, None, None], pairs, 0.0)
     routed = jnp.sum(pairs * weights[..., None], axis=1)
-    return routed + swiglu(x, p["shared_gu"], p["shared_down"]), load
+    if "shared_gu" in p:
+        routed = routed + swiglu(x, p["shared_gu"], p["shared_down"])
+    return routed, load

@@ -917,6 +917,88 @@ def test_sliding_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# mellum2-12b-a2.5b-instruct in the engine (PR 59): the SAME class from a
+# second file's plan; the decode step of 32 slots and the largest prefill of
+# mellum_serve_completion, at the published widths, every expert held
+# ---------------------------------------------------------------------------
+def _mellum(monkeypatch):
+    import json
+    import os
+
+    from moolib_tpu.models.swa_moe import SlidingGqaMoELM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "mellum2-12b-a2.5b-instruct.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "chipbench", "traffic", "serve_completion.json")) as f:
+        traffic = json.load(f)
+    model = SlidingGqaMoELM.from_config(
+        config, max_len=traffic["positions_per_slot"], **config["uses"]["serve"])
+    return model, jax.eval_shape(model.init, jax.random.key(0)), traffic
+
+
+def test_mellum_decode_step_holds_rings_of_eight_blocks_as_aliased_leaves(chip, monkeypatch):
+    """7.59 GB of weights (all 64 experts a layer, the whole vocabulary), 0.55
+    GB of the two full layers' pools and 0.40 GB of the six sliding layers'
+    rings of 1,024 rows, 8 blocks of 128 a slot a layer: the step aliases the
+    whole cache to its outputs and copies no leaf of it, reads both kinds of
+    layer through the paged kernel at 8 query heads a K/V head, and runs its
+    grouped matmuls at 256 rows with a group's whole matrix a grid step (K
+    2,304 = 18 x 128, N 1,792 = 14 x 128, K 896 = 7 x 128)."""
+    from moolib_tpu.models.decoder_parts import SlotCache
+    from moolib_tpu.ops.paged_attention import PagedState
+
+    model, params, traffic = _mellum(monkeypatch)
+    S, bs = traffic["slots"], traffic["block_size"]
+    per = traffic["positions_per_slot"] // bs
+    cache = SlotCache(model.cache_spec(1 + S * per, bs), model.state_spec(S))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    paged = PagedState(i32(S, per), i32(S), jax.ShapeDtypeStruct((S,), jnp.bool_))
+    compiled, text = _compile(
+        jax.jit(model.decode, donate_argnums=(1,)), *_on(chip, (params, cache, i32(S), paged)))
+    nbytes = lambda tree: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    assert nbytes(params) == 7592381440
+    assert nbytes(cache.blocks) == 554172416 and nbytes(cache.slots) == 402653184
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(cache)  # every cache leaf is updated where it lies
+    assert mem.temp_size_in_bytes < 64 << 20
+    # one ring call under each run's scan and the two full layers', all at 32 heads
+    assert len(re.findall(r"%paged_attention[.\d]* = f32\[32,32,128\]", text)) == 4
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[256,1792\]", text)) == 4
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[256,2304\]", text)) == 4
+    copies = re.findall(r"= (\w+\[[\d,]*\])[^ ]* copy\(", text)
+    ring, blocks, pool = "bf16[32,6,1024,4,128]", "bf16[1536,128,4,128]", "bf16[1057,128,4,128]"
+    assert not {ring, blocks, pool} & set(copies)
+    sizes = lambda found: [int(np.prod([int(d) for d in s.split("[")[1][:-1].split(",") if d]))
+                           for s in found]
+    assert max(sizes(copies)) <= 2304 * 128  # nothing of a weight's size
+    converts = re.findall(r"= (\w+\[[\d,]*\])[^ ]* convert\(", text)
+    assert max(sizes(converts), default=0) <= 256 * 2304  # the step's rows, no matrix
+
+
+def test_mellum_largest_prefill_fits_beside_the_engine(chip, monkeypatch):
+    """A prompt of 4,096 positions: the windowed flash kernel at 32 heads and
+    a window of 1,024 under each run's scan (head-major, one result), the
+    causal one in place in the two full layers, 32,768 (token, expert) rows
+    through the grouped matmul against all 64 experts.  Weights, temporaries
+    and the engine's 0.96 GB of cache stay under the chip's 16 GB with room
+    for the set-up's reference check."""
+    model, params, traffic = _mellum(monkeypatch)
+    Lb = traffic["prompt_tokens"]["max"]
+    compiled, text = _compile(
+        jax.jit(lambda p, toks, tp: model.prefill(p, toks, tp, traffic["block_size"])),
+        *_on(chip, (params, jax.ShapeDtypeStruct((1, Lb), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.7e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 0.96e9 < 10e9
+    assert len(re.findall(r"%flash_attention[\w.]* = bf16\[32,4096,128\]", text)) == 2
+    assert len(re.findall(r"%flash_attention[\w.]* = \(bf16\[1,4096,4096\]", text)) == 2
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = bf16\[32768,", text)) == 8
+
+
+# ---------------------------------------------------------------------------
 # ai21-jamba2-3b in the engine (PR 51): the decode step of 256 slots and the
 # largest prefill of jamba_serve_reasoning, the whole model at the published widths
 # ---------------------------------------------------------------------------

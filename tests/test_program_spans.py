@@ -300,12 +300,14 @@ def one_ring_spell():
     from moolib_tpu.engine import ContinuousBatchingEngine
     from moolib_tpu.models.swa_moe import SlidingGqaMoELM, tiny_config
 
-    model = SlidingGqaMoELM.from_config(tiny_config(), dtype=jnp.float32, max_len=128)
+    model = SlidingGqaMoELM.from_config(tiny_config(), dtype=jnp.float32, max_len=160)
     params = jax.jit(model.init)(jax.random.key(0))
     engine = ContinuousBatchingEngine(model, params, slots=2, block_size=16,
-                                      max_seq_len=128, max_prompt_len=64)
+                                      max_seq_len=160, max_prompt_len=128)
     before = telemetry.get_registry().snapshot()
-    slot, _ = engine.submit(np.arange(2, 40, dtype=np.int32), 3)
+    # a bucket of 128 positions: the shortest that takes the flash kernel, so
+    # that the windowed forward's key blocks are counted
+    slot, _ = engine.submit(np.arange(2, 100, dtype=np.int32), 3)
     while not engine.step()[1]:
         pass
     engine.retire(slot)

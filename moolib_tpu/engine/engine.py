@@ -193,7 +193,11 @@ and, optionally, where the model holds pools alone and reports no counters:
   one product.  What it returns must be what the two return run apart (to
   rounding: a product over more rows may round otherwise), for rows active
   or not.  A model that offers it has its admissions carried by decode steps
-  (above); the engine asks nothing else of it, and no model's name.
+  (above); the engine asks nothing else of it, and no model's name;
+- ``prompt_attention_kernel(bucket)`` -> a name: the kernel that the rows of a
+  prompt padded to ``bucket`` attend through.  The host counts each prompt's
+  real tokens under it (``serve_prompt_attention_rows_total{kernel}``), so a
+  run can say what share of its prompts' rows took which.
 
 Counters ride what the host fetches anyway (extra rows of a step's packet,
 extra entries beside a prefill's first token): no copy is added.  What they
@@ -247,6 +251,15 @@ _M_ADMISSIONS = _REG.counter(
     "(engine_admit_step: the prompt's rows beside the occupied slots' in one "
     "pass over the weights), path=own took engine_prefill and engine_join",
     labelnames=("path",),
+)
+_M_PROMPT_ATTN_ROWS = _REG.counter(
+    "serve_prompt_attention_rows_total",
+    "prompt tokens prefilled (unpadded), by the kernel their bucket's rows "
+    "attend through as the model names it (``prompt_attention_kernel``: "
+    "kernel=flash the blockwise kernel, which writes no scores and does no "
+    "work for the padding; kernel=dense scores of bucket x bucket a head); "
+    "counted at the dispatch, where the model offers the name",
+    labelnames=("kernel",),
 )
 _M_RETIRES = _REG.counter(
     "serve_engine_retires_total", "sequences retired (EOS or budget)"
@@ -387,6 +400,7 @@ class ContinuousBatchingEngine:
         self._n_packet_counters = self._n_step_counters + (len(self._row_counts) > 1)
         # Does a decode step carry an admission (module docstring).
         self._rides_step = hasattr(model, "decode_with_prompt")
+        self._prompt_kernel = getattr(model, "prompt_attention_kernel", None)
         if self._rides_step:
             self.min_prompt_len = max(self.min_prompt_len, min(_PROMPT_TILE, self.max_prompt_len))
 
@@ -723,9 +737,15 @@ class ContinuousBatchingEngine:
                             seq=self._prefill_jit.seq, bucket=toks.shape[1], tokens=tp):
             rows, first = self._prefill_jit(self._params, toks, np.int32(tp))
             first.copy_to_host_async()
+        self._count_prefill(tp, toks.shape[1])
+        return rows, first
+
+    def _count_prefill(self, tp: int, lb: int) -> None:
+        """A prompt of ``tp`` tokens in a bucket of ``lb`` went to the device."""
         self._stats["prefill_tokens"] += tp
         _M_PREFILL_TOKENS.inc(tp)
-        return rows, first
+        if self._prompt_kernel is not None:
+            _M_PROMPT_ATTN_ROWS.inc(tp, kernel=self._prompt_kernel(lb))
 
     def _launch_own(self, adm: _Admission) -> None:
         """An admission through programs of its own: the prefill and the join
@@ -863,8 +883,7 @@ class ContinuousBatchingEngine:
         self._flights.append((packet, stepping, seq, None if adm is None else adm.slot))
         if adm is not None:
             self._count_admission("step")
-            self._stats["prefill_tokens"] += adm.tp
-            _M_PREFILL_TOKENS.inc(adm.tp)
+            self._count_prefill(adm.tp, adm.toks.shape[1])
         self._stats["steps_by_rows"][rows] += 1
         _M_DECODE_ROWS.observe(rows)
         _M_PHASE.observe(time.monotonic() - t0, phase="dispatch")

@@ -112,7 +112,11 @@ class Block(nn.Module):
     prompt_rows: int = 0
 
     @nn.compact
-    def __call__(self, x, mesh=None, paged=None):
+    def __call__(self, x, mesh=None, paged=None, length=None):
+        """``length``: the real positions of a bucket-padded prompt (a traced
+        scalar; the serving forms pass it): the flash kernel does no work past
+        it and writes the padding's rows as zeros (dense and ring attention do
+        not read it)."""
         B, T, D = x.shape
         H = self.num_heads
         hd = D // H
@@ -213,9 +217,10 @@ class Block(nn.Module):
                 )
 
                 if self.rotary:
-                    return flash_attention(q, k, v, causal=True, mesh=mesh)
+                    return flash_attention(
+                        q, k, v, causal=True, mesh=mesh, length=length)
                 return flash_attention_packed(
-                    packed, H, Hk, causal=True, mesh=mesh)
+                    packed, H, Hk, causal=True, mesh=mesh, length=length)
             if group > 1:
                 # Ring and dense attention take equal head counts —
                 # repeat KV across each group (transient; the cache and
@@ -350,16 +355,19 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(
         self, tokens: jax.Array, mesh=None, return_features: bool = False,
-        paged=None, prompt_last=None,
+        paged=None, length=None,
     ) -> jax.Array:
         """Logits [B, T, V] — or pre-head features [B, T, D] with
         ``return_features=True``, for ``ops.xent.lm_head_xent``'s chunked
         loss (the lm_head params still come from the same init: flax only
         materializes params on the default path, and ``apply`` ignores the
-        unused head when features are requested).  With ``prompt_rows`` the
-        logits are [R + 1, 1, V]: the decode rows', then the prompt's at its
-        row ``prompt_last`` (a traced scalar; the row is taken before the
-        final norm and the head, which never see the rest of the bucket)."""
+        unused head when features are requested).  ``length`` (a traced
+        scalar) is the number of real tokens of ONE bucket-padded prompt, which
+        the serving forms pass: flash attention does no work past it
+        (``Block``).  With ``prompt_rows`` the logits are [R + 1, 1, V]: the
+        decode rows', then the prompt's at its row ``length - 1`` (the row is
+        taken before the final norm and the head, which never see the rest of
+        the bucket)."""
         B, T = tokens.shape
         # Validate even when remat/decode makes the policy a no-op: bench
         # rows are keyed by this string, so a typo must never run silently.
@@ -421,11 +429,14 @@ class TransformerLM(nn.Module):
             )
             # paged stays out of the remat-wrapped call (remat only wraps
             # the non-decode path, where paged is always None).
-            x = block(x, mesh) if paged is None else block(x, mesh, paged)
+            if paged is None and length is None:
+                x = block(x, mesh)
+            else:
+                x = block(x, mesh, paged, length)
         if self.prompt_rows:
             R = B - self.prompt_rows
             x = jnp.concatenate(
-                [x[:R], jax.lax.dynamic_slice_in_dim(x, R + prompt_last, 1)])
+                [x[:R], jax.lax.dynamic_slice_in_dim(x, R + length - 1, 1)])
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
         head = nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")
         if return_features:
@@ -438,12 +449,33 @@ class TransformerLM(nn.Module):
         return head(x.astype(jnp.float32))
 
 
+# The largest bucket whose rows attend through XLA's dense scores in the
+# serving forms; a larger one takes the flash kernel.  Read on a TPU v5e (PR
+# 60; 16 heads of 128, the whole admit step of 24 layers beside 32 decode
+# rows, dense against kernel): 128 rows 11.82 -> 12.07 ms, 256 12.21 -> 12.63,
+# 512 14.39 -> 14.80, 1,024 22.87 -> 23.14 (up to 64 MB of scores a layer,
+# which XLA's fusions pass over about once: they beat a kernel launch and the
+# slice of the prompt's rows out of the projection); 1,984 rows 64.1 -> 41.7
+# (252 MB of scores a layer, passed over three and a half times).
+_DENSE_PROMPT_ROWS = 1024
+
+
 class PagedTransformerLM:
     """What ``engine.ContinuousBatchingEngine`` asks of a model, for a
     :class:`TransformerLM`: pools ``pool_k`` / ``pool_v`` of ``[num_blocks,
     block_size, Hk, hd]`` a layer, prefill through the ``collect_kv`` twin
     (the whole prompt, teacher-forced), one-token decode through the
-    ``decode=True`` twin over the paged pools."""
+    ``decode=True`` twin over the paged pools.
+
+    What the prompt's rows attend through, in ``prefill`` and in
+    ``decode_with_prompt``, is the serving form's own choice, whatever
+    ``model.attention`` says (that names the attention of training,
+    ``generate()`` and ``sharded_generator``): it is picked from the bucket's
+    static row count (:meth:`prompt_attention_kernel`).  A bucket of more
+    than ``_DENSE_PROMPT_ROWS`` rows, whether or not it tiles, takes the flash
+    kernel with the prompt's true length as data, so the bucket's padding
+    costs the attention nothing and no ``[H, Lb, Lb]`` scores are written; a
+    smaller one takes XLA's dense scores, which are cheaper there."""
 
     step_counters = 0
     prefill_counters = 0
@@ -455,9 +487,6 @@ class PagedTransformerLM:
                 "form; models.latent_moe holds the dropless expert layer")
         self.model = model
         self.max_len = model.max_len
-        self._pre = self._twin(
-            attention="flash" if model.attention == "ring" else model.attention,
-            collect_kv=True)
 
     def _twin(self, **kw):
         m = self.model
@@ -485,9 +514,21 @@ class PagedTransformerLM:
 
         return blocks("k"), blocks("v")
 
+    @staticmethod
+    def prompt_attention_kernel(bucket: int) -> str:
+        """``"flash"`` or ``"dense"``: what the rows of a prompt padded to
+        ``bucket`` attend through: the twins' ``attention``, and the label the
+        engine counts the prompt's tokens under."""
+        from ..ops.flash_attention import length_call_rides_kernel
+
+        rides = bucket > _DENSE_PROMPT_ROWS and length_call_rides_kernel(bucket)
+        return "flash" if rides else "dense"
+
     def prefill(self, params, toks, tp, block_size: int):
-        logits, col = self._pre.apply(
-            {"params": params["params"]}, toks, mutable=["kv"])
+        pre = self._twin(
+            attention=self.prompt_attention_kernel(toks.shape[1]), collect_kv=True)
+        logits, col = pre.apply(
+            {"params": params["params"]}, toks, length=tp, mutable=["kv"])
         return self._rows(col["kv"], block_size), jnp.take(logits[0], tp - 1, axis=0), None
 
     def write_rows(self, cache, rows, block_ids):
@@ -520,13 +561,13 @@ class PagedTransformerLM:
         ``write_rows``)."""
         pool = cache["block0"]["pool_k"]
         both = self._twin(
-            attention=self._pre.attention, decode=True, collect_kv=True,
-            prompt_rows=toks.shape[1], kv_num_blocks=pool.shape[0],
+            attention=self.prompt_attention_kernel(toks.shape[1]), decode=True,
+            collect_kv=True, prompt_rows=toks.shape[1], kv_num_blocks=pool.shape[0],
             kv_block_size=pool.shape[1])
         logits, upd = both.apply(
             {"params": params["params"], "cache": cache},
             jnp.concatenate([tokens, toks[0]])[:, None],
-            paged=paged, prompt_last=tp - 1, mutable=["cache", "kv"])
+            paged=paged, length=tp, mutable=["cache", "kv"])
         return logits[:-1, 0], logits[-1, 0], upd["cache"], self._rows(upd["kv"], block_size)
 
 

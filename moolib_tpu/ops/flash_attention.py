@@ -36,10 +36,20 @@ windowed forward does at every head size.
 row logsumexp leaves the forward, and enters the backward with ``delta``, as
 rows: [B * H, 1, T] float32.
 
-Shapes that don't tile (T without a 128-multiple divisor) take the XLA dense
-path, counted in ``flash_dense_reroutes_total``.  The kernels lower through
-Mosaic on the ``tpu`` platform and run in Pallas interpret mode on ``cpu``
-(tests); any other platform raises.
+``length=n`` (a traced int32 scalar: the first ``n`` of the ``T`` positions
+are real, the rest a serving bucket's padding) is a forward of its own too,
+:func:`_flash_length_kernel`: the scalar reaches it as data (scalar prefetch),
+so ONE program serves every prompt of a bucket.  Blocks wholly at or past
+``n`` are neither copied nor multiplied, the rows at or past it come out as
+zeros, and ``T`` need not tile (the grid is ``cdiv``, the last block partial):
+any ``T`` of 128 rows or more rides it (:func:`length_call_rides_kernel`).  No
+backward either.
+
+Without ``length``, shapes that don't tile (T without a 128-multiple divisor)
+take the XLA dense path, counted in ``flash_dense_reroutes_total``; with it,
+only ``T < 128`` does.  The kernels lower through Mosaic on the ``tpu``
+platform and run in Pallas interpret mode on ``cpu`` (tests); any other
+platform raises.
 """
 
 from __future__ import annotations
@@ -60,7 +70,8 @@ _NEG_INF = -1e30
 _M_DENSE_REROUTES = telemetry.get_registry().counter(
     "flash_dense_reroutes_total",
     "flash_attention calls traced onto the O(T^2) dense path because the "
-    "sequence length has no 128-multiple block divisor",
+    "sequence length has no 128-multiple block divisor (a call with length=: "
+    "because it is under 128 rows)",
 )
 _M_TRACES = telemetry.get_registry().counter(
     "flash_attention_traces_total",
@@ -199,6 +210,66 @@ def _flash_window_kernel(
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _flash_length_kernel(
+    len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+    *, scale, block_q, block_k,
+):
+    """Causal attention over the first ``len_ref[0]`` of ``T`` positions: the
+    causal kernel's grid (``cdiv``: the last blocks may be partial) and sweep,
+    the length as data.  A row below the length gets what the causal kernel
+    gives it (it never saw a key past itself); a row at or past it is written
+    as zeros, whatever q, k or v hold there or past ``T`` (NaN too); a block
+    wholly at or past the length runs nothing, and the index maps
+    (:func:`_flash_length_forward`) copied nothing for it."""
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    length = len_ref[0]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # Key blocks up to the diagonal's and up to the last real key's, of a
+    # query block that holds a real row.
+    @pl.when((qi * block_q < length)
+             & (ki * block_k < jnp.minimum((qi + 1) * block_q, length)))
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [bq, bk] f32
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # One compare, as the causal kernel's: the keys of a row are those up
+        # to itself and below the length.
+        s = jnp.where(k_pos <= jnp.minimum(q_pos, length - 1), s, _NEG_INF)
+        # A probability of 0 times a V row of padding (or past T) is NaN where
+        # the row holds NaN: the row is taken as zero.
+        v_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+        v = jnp.where(v_pos < length, v_ref[0], jnp.zeros_like(v_ref[0]))
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        # A block that ran nothing divides its zeros; a padding row inside a
+        # live block attended over the real keys (or holds NaN): zeros too.
+        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        out = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
+        o_ref[0] = jnp.where(row < length, out, 0.0).astype(o_ref.dtype)
 
 
 def _blockwise_attention(q, k, v, causal, block_q, block_k, return_lse=False,
@@ -460,6 +531,23 @@ def _flash_window_no_vjp(*_):
 _flash_window.defvjp(_flash_window_no_vjp, _flash_window_no_vjp)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _flash_length(operands, length, c):
+    """``_flash`` of the first ``length`` ([1] int32, data) of ``c.Tq``
+    positions; the result in the operands' form, no logsumexp."""
+    return _flash_length_forward(operands, length, c)
+
+
+def _flash_length_no_vjp(*_):
+    raise NotImplementedError(
+        "flash_attention(length=...) has no backward: the kernel that takes a "
+        "bucket's true length is a forward for serving prefill (differentiate "
+        "length=None)")
+
+
+_flash_length.defvjp(_flash_length_no_vjp, _flash_length_no_vjp)
+
+
 def _flash_bwd_dq_kernel(
     k_ref, q_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
     *, scale, causal, block_q, block_k,
@@ -705,6 +793,15 @@ def window_key_blocks(T: int, window: int):
             sum(last + 1 for _first, last in spans))
 
 
+def length_call_rides_kernel(T: int) -> bool:
+    """Whether a call with ``length`` over ``T`` positions rides the kernel
+    (any ``T`` of a block's 128 rows or more: it need not tile) or takes the
+    dense path.  A plain integer's question: :func:`flash_attention` chooses
+    its path by it, and so does a host that counts which of the two each of
+    its prompts' buckets took."""
+    return T >= 128
+
+
 def _interpret(interpret):
     if interpret is not None:
         return interpret
@@ -733,6 +830,7 @@ def flash_attention(
     return_lse: bool = False,
     mesh=None,
     window: int | None = None,
+    length=None,
 ):
     """Blockwise attention; q: [B, T, H, D], k/v: [B, T, Hk, D] → [B, T, H, D].
 
@@ -752,6 +850,17 @@ def flash_attention(
     every window of a query block are skipped, not masked.  Forward only:
     differentiating a windowed call raises, and ``return_lse`` and ``mesh``
     are not offered with it.
+
+    ``length``: a traced int32 scalar, ``0 < length <= T``: the first
+    ``length`` positions are real and the rest a bucket's padding (needs
+    ``causal`` and Tq == Tk; every batch row has the one length).  A real row
+    gets what it gets without it (it never saw a key past itself); a row at or
+    past it comes out as ZEROS, whatever the operands hold there (NaN too);
+    blocks wholly past it are neither copied nor multiplied.  The scalar is
+    data, so one program serves every length, and ``T`` need not tile: any
+    ``T`` of 128 or more rides the kernel (:func:`length_call_rides_kernel`),
+    in the blocks of ``T`` rounded up to 128, the last block partial.  Forward
+    only, as a window is, and without ``return_lse``, ``mesh`` or ``window``.
 
     ``mesh``: pass the mesh when calling from a program XLA partitions over
     one (a jitted step with sharded inputs).  XLA cannot partition a Mosaic
@@ -782,6 +891,23 @@ def flash_attention(
                 "sequence (Tq == Tk, window >= 1), without return_lse or mesh")
         if window >= Tq:
             window = None  # every key a query may see is inside its window
+    if length is not None:
+        if (not causal or Tq != Tk or return_lse or mesh is not None
+                or window is not None):
+            raise ValueError(
+                "flash_attention(length=...) is causal self-attention over one "
+                "sequence (Tq == Tk), without return_lse, mesh or window")
+        if length_call_rides_kernel(Tq):
+            return _length_call((q, k, v), length, H, Hk, D, block_q, block_k, interpret)
+        from ..parallel.ring_attention import full_attention
+
+        _M_DENSE_REROUTES.inc()
+        real = (jnp.arange(Tq) < length)[None, :, None, None]
+        # 0 x NaN is NaN: the padding's K and V rows are taken as zeros, as
+        # the kernel takes them.
+        k, v = (jnp.where(real, x, jnp.zeros_like(x)) for x in _repeat_kv(k, v, H // Hk))
+        out = full_attention(q, k, v, causal=True)
+        return jnp.where(real, out, jnp.zeros_like(out))
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
@@ -847,6 +973,7 @@ def flash_attention_packed(
     causal: bool = True,
     interpret: bool | None = None,
     mesh=None,
+    length=None,
 ):
     """Self-attention over a packed projection: qkv [B, T, (H + 2 Hk) * D],
     columns ``[q heads | k heads | v heads]`` (``Block``'s ``qkv`` Dense as
@@ -858,7 +985,8 @@ def flash_attention_packed(
     slice, transpose or repeat of the projection is made.  Anything else — a
     head size off the lanes, a T that takes the dense path, a mesh whose
     ``tp`` cuts the heads (the packed columns are not one head axis) — is
-    :func:`flash_attention` of the three slices.  ``mesh`` as there.
+    :func:`flash_attention` of the three slices.  ``mesh`` and ``length`` as
+    there: with ``length`` any ``T`` of 128 or more is indexed in place.
     """
     B, T, C = qkv.shape
     H, Hk = num_heads, num_kv_heads or num_heads
@@ -866,11 +994,15 @@ def flash_attention_packed(
     if H % Hk or D * (H + 2 * Hk) != C:
         raise ValueError(
             f"flash_attention_packed: {C} columns are not {H} + 2 x {Hk} heads")
+    if (length is not None and causal and mesh is None and D % 128 == 0
+            and length_call_rides_kernel(T)):
+        return _length_call((qkv,), length, H, Hk, D, None, None, interpret)
     block_q, block_k = _auto_blocks(T, T)
     cut_heads = mesh is not None and _mesh_axis(mesh, "tp", Hk) and mesh.shape["tp"] > 1
     if D % 128 or not (block_q and block_k) or cut_heads:
         return flash_attention(
             *_unpack(qkv, H, Hk), causal=causal, interpret=interpret, mesh=mesh,
+            length=length,
         ).reshape(B, T, H * D)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
@@ -885,6 +1017,72 @@ def flash_attention_packed(
     _M_TRACES.inc(path="in_place")
     return _flash((qkv,), _Call(B, T, T, H, Hk, D, True, causal, block_q, block_k,
                                 _interpret(interpret), False))
+
+
+def _length_call(operands, length, H, Hk, D, block_q, block_k, interpret):
+    """The call with ``length`` onto its kernel (``operands``: (q, k, v), or
+    the packed (qkv,)): the blocks of ``T`` rounded up to 128 (1,984 rows take
+    2,048's), or the caller's, multiples of 128."""
+    B, T = operands[0].shape[:2]
+    tiled = -(-T // 128) * 128
+    auto_q, auto_k = _auto_blocks(tiled, tiled)
+    block_q, block_k = block_q or auto_q, block_k or auto_k
+    if block_q % 128 or block_k % 128:
+        raise ValueError(
+            f"flash_attention(length=...) block_q={block_q}, block_k={block_k}: "
+            "blocks are multiples of 128 (they need not divide T)")
+    c = _Call(B, T, T, H, Hk, D, len(operands) == 1, True, block_q, block_k,
+              _interpret(interpret), False)
+    _M_TRACES.inc(path="in_place" if c.in_place else "head_major")
+    return _flash_length(
+        operands, jnp.clip(jnp.asarray(length, jnp.int32), 1, T).reshape(1), c)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+@jax.named_scope("flash_attention")
+def _flash_length_forward(operands, length, c):
+    """The causal forward's grid over ``cdiv`` blocks, ``length`` ([1] int32)
+    prefetched: the index maps read it, so a query block past the last real
+    row and a key block past the last real key (or above the diagonal) name a
+    block already in VMEM and nothing is copied for them.  Jitted, so that the
+    24 layers of a serving program trace and lower ONE kernel between them
+    (40 ms a layer otherwise: a second a program, five at a warm-up)."""
+    arrays, (q0, k0, v0) = _kernel_arrays(operands, c)
+    H, group, bq, bk = c.H, c.H // c.Hk, c.block_q, c.block_k
+
+    def q_at(n, i, j, len_ref):
+        return (jax.lax.div(n, H), jax.lax.rem(n, H),
+                jnp.minimum(i, jax.lax.div(len_ref[0] - 1, bq)))
+
+    def kv_at(n, i, j, len_ref):
+        last = jnp.minimum((i + 1) * bq, len_ref[0]) - 1  # the last key block i reads
+        return (jax.lax.div(n, H), jax.lax.div(jax.lax.rem(n, H), group),
+                jnp.minimum(j, jax.lax.div(last, bk)))
+
+    def out_at(n, i, j, len_ref):  # every block is written, a dead one as zeros
+        return jax.lax.div(n, H), jax.lax.rem(n, H), i
+
+    out = pl.pallas_call(
+        functools.partial(_flash_length_kernel, scale=c.D**-0.5, block_q=bq, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(c.B * H, pl.cdiv(c.Tq, bq), pl.cdiv(c.Tk, bk)),
+            in_specs=[
+                _tile(c, H, q0, bq, q_at),
+                _tile(c, c.Hk, k0, bk, kv_at),
+                _tile(c, c.Hk, v0, bk, kv_at),
+            ],
+            out_specs=_tile(c, H, 0, bq, out_at),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, c.D), jnp.float32),
+            ],
+        ),
+        out_shape=_out_struct(_heads_shape(c, H, c.Tq), arrays[0].dtype, *arrays),
+        interpret=c.interpret,
+    )(length, *arrays)
+    return _of_kernel(out, H, c)
 
 
 @jax.named_scope("flash_attention")

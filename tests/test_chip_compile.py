@@ -1330,49 +1330,65 @@ def test_an_engine_of_one_row_count_lowers_the_step_of_before(module, name, slot
 # the step that carries an admission (PR 57): engine_admit_step at
 # lm_serve_knee's sizes, and the texts of the programs it must leave alone
 # ---------------------------------------------------------------------------
-def _dense_engine(chip, monkeypatch):
+def _dense_engine(chip, monkeypatch, slots=32, per=64, max_prompt_len=512):
     """``lm_serve_steady``'s and ``lm_serve_knee``'s engine (24 layers, d 2,048
     as 16 heads of 128, 32 slots x 64 blocks of 16, float32 weights, bfloat16
     products and pools) over shapes, and the arguments of a step on the
-    described device.  The engine's own pools are a block a slot: what is
-    lowered takes the cells' 2,049 blocks as shapes."""
+    described device; with 16 slots x 128 blocks and prompts up to 1,984,
+    ``lm_serve_longprompt``'s.  The engine's own pools are a block a slot: what
+    is lowered takes the cells' 2,049 blocks as shapes."""
     from moolib_tpu.engine import ContinuousBatchingEngine
     from moolib_tpu.models.transformer import TransformerLM
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the paged kernel through Mosaic
-    slots, per, block = 32, 64, 16
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels through Mosaic
+    block = 16
     lm = TransformerLM(vocab_size=50257, d_model=2048, num_heads=16, num_layers=24,
                        max_len=2048, attention="dense", pos_embedding="learned")
     params = jax.eval_shape(lambda: lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     eng = ContinuousBatchingEngine(lm, params, slots=slots, block_size=block, num_blocks=1 + slots,
-                                   max_seq_len=per * block, max_prompt_len=512)
+                                   max_seq_len=per * block, max_prompt_len=max_prompt_len)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     state = (params, eng.model.cache_spec(1 + slots * per, block), i32(slots, per), i32(slots),
              jax.ShapeDtypeStruct((slots,), jnp.bool_), i32(slots), i32(slots))
     return eng, _on(chip, state), lambda *shapes: _on(chip, tuple(i32(*s) for s in shapes))
 
 
-@pytest.mark.parametrize("bucket,most_mb", [(16, 64), (512, 320)])
-def test_engine_admit_step_reads_each_weight_matrix_once(chip, monkeypatch, bucket, most_mb):
-    """32 decode rows and a prompt's 16 or 512 rows in one pass: each of a
-    block's four weight matrices is the operand of ONE product over ``32 +
+# bucket, slots, blocks a slot, kernels a layer, most MB of temporaries
+@pytest.mark.parametrize("bucket,slots,per,kernels,most_mb", [
+    (16, 32, 64, 1, 64), (512, 32, 64, 1, 320), (1984, 16, 128, 2, 800)])
+def test_engine_admit_step_reads_each_weight_matrix_once(
+        chip, monkeypatch, bucket, slots, per, kernels, most_mb):
+    """The slots' decode rows and a prompt's 16, 512 or 1,984 rows (the last
+    at ``lm_serve_longprompt``'s 16 slots x 128 blocks) in one pass: each of a
+    block's four weight matrices is the operand of ONE product over ``slots +
     bucket`` rows (a program that called ``prefill`` then ``decode`` would hold
-    two, over 32 and over ``bucket`` rows), the paged kernel runs once a layer
-    over the 32, the head over 33 rows, the pools are updated where they lie,
-    and the temporaries (the prompt's K/V rows of 24 layers, 100 MB at 512,
-    and a block's activations) stay under ``most_mb``: 47 and 229 MB read."""
-    eng, state, ints = _dense_engine(chip, monkeypatch)
-    per, rows = 64, 32 + bucket
+    two, over the slots and over ``bucket`` rows), the paged kernel runs once a
+    layer over the slots' rows, the head over ``slots + 1`` rows, the pools are
+    updated where they lie, and the temporaries (the prompt's K/V rows of 24
+    layers, 100 MB at 512 and 390 MB at 1,984, and a block's activations) stay
+    under ``most_mb``: 47, 229 and 773 MB read (at 1,984 the parent, with its
+    scores, read 813).  A bucket of more than 1,024 rows attends through the
+    flash kernel (24 paged + 24 flash custom calls, though 1,984 = 31 x 64 does
+    not tile), and no float32 scores of heads x bucket x bucket are anywhere in
+    the compiled program: at 1,984 they were 252 MB a layer, written, masked,
+    reduced and read again.  Below it (512: 16 MB of scores a layer) the dense
+    fusions stay, which are faster there (``_DENSE_PROMPT_ROWS``)."""
+    eng, state, ints = _dense_engine(chip, monkeypatch, slots, per, max(512, bucket))
+    rows = slots + bucket
     lowered = eng._admit_jit.lower(
-        *state, *ints((1, bucket), (), (), (per,), (), (bucket // 16,)), 32)
+        *state, *ints((1, bucket), (), (), (per,), (), (bucket // 16,)), slots)
     text = lowered.as_text()
     products = re.findall(r"stablehlo\.dot_general .*: \(tensor<(\w+)>, tensor<(\w+)>\)", text)
     for k, n in ((2048, 6144), (2048, 2048), (2048, 8192), (8192, 2048)):
         mine = [lhs for lhs, rhs in products if rhs == f"{k}x{n}xbf16"]
         assert mine == [f"{rows}x1x{k}xbf16"] * 24, (k, n)
-    assert [lhs for lhs, rhs in products if rhs == "2048x50257xf32"] == ["33x1x2048xf32"]
+    assert [lhs for lhs, rhs in products if rhs == "2048x50257xf32"] == [
+        f"{slots + 1}x1x2048xf32"]
     compiled = lowered.compile()
-    assert compiled.as_text().count("tpu_custom_call") == 24
+    assert compiled.as_text().count("tpu_custom_call") == 24 * kernels
+    if kernels == 2:
+        assert f"f32[16,{bucket},{bucket}]" not in compiled.as_text()
+        assert f"f32[1,16,{bucket},{bucket}]" not in compiled.as_text()
     mem = compiled.memory_analysis()
     cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state[1]))
     assert mem.alias_size_in_bytes >= cache_bytes

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from conftest import own_programs
 from moolib_tpu import telemetry
+from moolib_tpu.models import transformer
 from moolib_tpu.engine import ContinuousBatchingEngine, EngineService
 from moolib_tpu.engine.engine import NoFreeSlot
 from moolib_tpu.engine.kv_pool import PoolExhausted
@@ -35,9 +36,9 @@ from moolib_tpu.ops.paged_attention import PagedState
 V = 64
 
 
-def _lm(pos="rotary", attention="dense", kv_heads=2):
-    model = TransformerLM(vocab_size=V, d_model=32, num_heads=4, num_kv_heads=kv_heads,
-                          num_layers=2, max_len=64, attention=attention,
+def _lm(pos="rotary", attention="dense", kv_heads=2, d_model=32, heads=4, max_len=64):
+    model = TransformerLM(vocab_size=V, d_model=d_model, num_heads=heads, num_kv_heads=kv_heads,
+                          num_layers=2, max_len=max_len, attention=attention,
                           dtype=jnp.float32, pos_embedding=pos)
     return model, model.init(jax.random.key(1), jnp.zeros((1, 8), jnp.int32))
 
@@ -48,30 +49,57 @@ def lm():
 
 
 # ------------------------------------------------------------------ the model
-@pytest.mark.parametrize("pos,attention,kv_heads,tp", [
-    ("learned", "dense", None, 11),  # learned positions, every head its own K/V, a padded bucket
-    ("learned", "dense", 2, 16),     # Hk < H, a full bucket
-    ("rotary", "dense", 2, 11),
-    ("rotary", "dense", None, 1),    # a prompt of one token
-    ("learned", "flash", 2, 11),     # the prefill twin's attention, as the cells run it
-    ("rotary", "flash", 2, 16),
-])
-def test_decode_with_prompt_equals_decode_and_prefill_run_apart(pos, attention, kv_heads, tp):
+def _three_slots(paged_lm, bucket, block):
     """Three slots, one of them inactive, at their own lengths in shuffled
-    blocks of a pool that holds noise; a prompt of ``tp`` tokens in a bucket
-    of 16."""
-    model, params = _lm(pos, attention, kv_heads)
-    paged_lm = PagedTransformerLM(model)
-    slots, block, per = 3, 4, 16
+    blocks of a pool that holds noise; a prompt's ``bucket`` tokens and the
+    blocks they are written to."""
+    slots, per = 3, max(16, bucket // block)
     cache = jax.tree.map(lambda leaf: jax.random.normal(jax.random.key(2), leaf.shape, leaf.dtype),
                          paged_lm.cache_spec(1 + slots * per, block))
     rng = np.random.default_rng(0)
     tables = jnp.asarray(1 + rng.permutation(slots * per).reshape(slots, per), jnp.int32)
     paged = PagedState(tables, jnp.asarray([5, 0, 9], jnp.int32),
                        jnp.asarray([True, False, True]))
-    tokens = jnp.asarray([3, 7, 9], jnp.int32)
-    toks = jnp.asarray(rng.integers(1, V, (1, 16)), jnp.int32)
-    written = jnp.asarray([40, 41, 42, 43], jnp.int32)
+    toks = jnp.asarray(rng.integers(1, V, (1, bucket)), jnp.int32)
+    written = jnp.asarray(slots * per - np.arange(bucket // block), jnp.int32)
+    return cache, paged, jnp.asarray([3, 7, 9], jnp.int32), toks, written
+
+
+@pytest.mark.parametrize("pos,attention,kv_heads,tp,bucket,heads", [
+    ("learned", "dense", None, 11, 16, 4),  # learned positions, every head its own K/V, a padded bucket
+    ("learned", "dense", 2, 16, 16, 4),     # Hk < H, a full bucket
+    ("rotary", "dense", 2, 11, 16, 4),
+    ("rotary", "dense", None, 1, 16, 4),    # a prompt of one token
+    ("learned", "flash", 2, 11, 16, 4),
+    ("rotary", "flash", 2, 16, 16, 4),
+    # Buckets that ride the flash kernel under a model built with
+    # attention="dense", as the cells build theirs (the adapter's threshold set
+    # to the kernel's own 128 rows for all but the last): heads of 8 through
+    # head-major copies, heads of 128 (d_model 256) where the operands lie
+    # (learned positions: the packed projection itself); 192 does not tile.
+    ("learned", "dense", None, 100, 128, 4),
+    ("learned", "dense", 2, 128, 128, 4),    # tp equal to the bucket
+    ("rotary", "dense", 2, 150, 192, 4),
+    ("rotary", "dense", None, 192, 192, 4),
+    ("learned", "dense", 1, 37, 192, 2),     # in place, packed
+    ("rotary", "dense", 2, 130, 192, 2),     # in place, three operands
+    ("learned", "dense", 2, 1, 128, 2),
+    ("learned", "dense", 2, 700, 1104, 4),   # the threshold as shipped: more than 1,024 rows
+])
+def test_decode_with_prompt_equals_decode_and_prefill_run_apart(
+        monkeypatch, pos, attention, kv_heads, tp, bucket, heads):
+    """A prompt of ``tp`` tokens in a bucket of ``bucket`` beside three slots:
+    one pass returns what ``decode`` and ``prefill`` return run apart, and
+    the prompt's logits are the model's own (``model.attention``'s dense
+    scores) over the ``tp`` real tokens alone."""
+    if bucket <= 1024:
+        monkeypatch.setattr(transformer, "_DENSE_PROMPT_ROWS", 127)
+    model, params = _lm(pos, attention, kv_heads, d_model=32 if heads == 4 else 256,
+                        heads=heads, max_len=max(256, bucket))
+    paged_lm = PagedTransformerLM(model)
+    assert paged_lm.prompt_attention_kernel(bucket) == ("dense" if bucket == 16 else "flash")
+    block = 4 if bucket == 16 else 16
+    cache, paged, tokens, toks, written = _three_slots(paged_lm, bucket, block)
 
     logits, stepped, _ = paged_lm.decode(params, cache, tokens, paged)
     rows, at_last, _ = paged_lm.prefill(params, toks, jnp.int32(tp), block)
@@ -83,6 +111,32 @@ def test_decode_with_prompt_equals_decode_and_prefill_run_apart(pos, attention, 
                          jax.tree.leaves(paged_lm.write_rows(stepped, rows, written))):
         np.testing.assert_allclose(got, want, **close)
     assert int(jnp.argmax(both[1])) == int(jnp.argmax(at_last))
+    own = model.apply(params, toks[:, :tp])[0, tp - 1]
+    np.testing.assert_allclose(both[1], own, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("form", ["decode_with_prompt", "prefill"])
+@pytest.mark.parametrize("pos", ["learned", "rotary"])
+def test_the_rows_written_for_a_buckets_padding_are_finite(monkeypatch, form, pos):
+    """The flash kernel writes the padding's rows as zeros, so what flows on
+    from them (the projection, the feed-forward, the next layer's K/V, which
+    ``write_rows`` puts into the pool's blocks) is finite at every position
+    of the bucket, and the real positions' K/V are what a prompt in a bucket
+    of its own length gives."""
+    monkeypatch.setattr(transformer, "_DENSE_PROMPT_ROWS", 127)
+    model, params = _lm(pos, "dense", 2, max_len=256)
+    paged_lm = PagedTransformerLM(model)
+    cache, paged, tokens, toks, _ = _three_slots(paged_lm, 192, 16)
+    tp = 37
+    if form == "prefill":
+        rows = paged_lm.prefill(params, toks, jnp.int32(tp), 16)[0]
+    else:
+        rows = paged_lm.decode_with_prompt(params, cache, tokens, paged, toks, jnp.int32(tp), 16)[3]
+    tight = paged_lm.prefill(params, toks[:, :tp], jnp.int32(tp), 1)[0]
+    for got, want in zip(rows, tight):  # K, then V: [layers, blocks, block, Hk, hd]
+        got = np.asarray(got).reshape(2, 192, 2, 8)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got[:, :tp], np.asarray(want)[:, :, 0], rtol=1e-5, atol=1e-5)
 
 
 def test_the_forms_mode_is_a_mode_of_paged_decode():
@@ -359,6 +413,35 @@ def test_the_counter_family_has_one_series_a_path(lm):
     after = series()
     assert set(after) == {"step", "own"}
     assert after["step"] - before.get("step", 0) == 3 and after["own"] - before.get("own", 0) == 2
+
+
+@pytest.mark.parametrize("form", [True, False])
+def test_prompt_rows_are_counted_under_the_kernel_their_bucket_takes(monkeypatch, form):
+    """``serve_prompt_attention_rows_total{kernel}``: each prompt's REAL
+    tokens, under ``flash`` where its bucket has the rows from which the
+    adapter takes the kernel (more than 1,024 as shipped: 128, the least the
+    kernel takes, here) and ``dense`` below, whichever program carried the
+    admission."""
+    kernel = PagedTransformerLM.prompt_attention_kernel
+    assert [kernel(b) for b in (16, 128, 512, 1024, 1104, 1984, 2048)] == [
+        "dense", "dense", "dense", "dense", "flash", "flash", "flash"]
+    monkeypatch.setattr(transformer, "_DENSE_PROMPT_ROWS", 127)
+    def series():
+        family = telemetry.get_registry().snapshot().get(
+            "serve_prompt_attention_rows_total", {"series": []})
+        return {s["labels"]["kernel"]: s["value"] for s in family["series"]}
+
+    model, params = _lm(max_len=256)
+    eng = ContinuousBatchingEngine(model if form else own_programs(model), params, slots=3,
+                                   block_size=16, max_seq_len=256, max_prompt_len=192)
+    before = series()
+    rng = np.random.default_rng(5)
+    # buckets of 16, 128 and 192 (the cap: it does not tile) rows
+    outs = _play(eng, [(rng.integers(1, V, n), 2) for n in (5, 100, 150)], [[0, 1, 2]])[0]
+    assert [len(outs[i]) for i in range(3)] == [2, 2, 2]
+    after = series()
+    assert after["dense"] - before.get("dense", 0) == 5
+    assert after["flash"] - before.get("flash", 0) == 250
 
 
 # ---------------------------------------------------------------- the service

@@ -230,3 +230,45 @@ def test_flash_packed_in_place_at_the_train_cells_shape_on_chip(kv_heads):
                        (got_lse, want_lse, "dqkv with a cotangent on lse")):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("t", [1984, 2048])
+@pytest.mark.parametrize("length", [1025, 1416, 1984])
+@pytest.mark.parametrize("entry", ["packed", "arrays"])
+def test_flash_with_a_length_on_chip(t, length, entry):
+    """``lm_serve_longprompt``'s call: ONE sequence in a bucket of 1,984 rows
+    (31 x 64: it does not tile, the last query block holds 448 rows and the
+    last key block 960) or of 2,048, 16 heads of 128, bfloat16, as the packed
+    projection and as three operands over 4 K/V heads.  Rows below ``length``
+    against dense attention over the first ``length`` positions, rows at or
+    past it exactly zero, though the operands there hold NaN; one program for
+    the three lengths (the length is data)."""
+    import jax
+    import jax.numpy as jnp
+
+    from moolib_tpu.ops.flash_attention import flash_attention, flash_attention_packed
+    from moolib_tpu.parallel.ring_attention import full_attention
+
+    dev = _tpu_device()
+    H, D = 16, 128
+    Hk = 16 if entry == "packed" else 4
+    rng = np.random.default_rng(t + length)
+    qkv = jnp.asarray(rng.normal(size=(1, t, H + 2 * Hk, D)).astype(np.float32) * 0.5)
+    real = (jnp.arange(t) < length)[None, :, None, None]
+    q, k, v = (jax.device_put(x.astype(jnp.bfloat16), dev)
+               for x in (qkv[:, :, :H], qkv[:, :, H:H + Hk], qkv[:, :, H + Hk:]))
+    poisoned = jax.device_put(jnp.where(real, qkv, jnp.nan).astype(jnp.bfloat16), dev)
+    if entry == "packed":
+        call = jax.jit(lambda x, n: flash_attention_packed(
+            x.reshape(1, t, -1), H, Hk, length=n).reshape(1, t, H, D))
+    else:
+        call = jax.jit(lambda x, n: flash_attention(
+            x[:, :, :H], x[:, :, H:H + Hk], x[:, :, H + Hk:], length=n))
+    out = np.asarray(call(poisoned, jnp.int32(length)), np.float32)
+    assert call._cache_size() == 1
+    ref = jax.jit(lambda q, k, v: full_attention(
+        q, *(jnp.repeat(x, H // Hk, axis=2) for x in (k, v)), causal=True))(
+            q[:, :length], k[:, :length], v[:, :length])
+    assert not np.isnan(out).any()
+    np.testing.assert_allclose(out[:, :length], np.asarray(ref, np.float32), rtol=2e-2, atol=2e-2)
+    assert (out[:, length:] == 0).all()

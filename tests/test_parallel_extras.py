@@ -274,6 +274,73 @@ def test_flash_in_place_matches_the_blockwise_oracle(B, H, T, causal):
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
 
 
+# ``length``: the first positions of a bucket are real (ISSUE 60).  T = 256
+# tiles, 320 and 200 do not (one partial block of 384 and of 256 rows; with
+# blocks of 128, three blocks the last partial); a length of 1, a block's edge,
+# one past it, and T.
+@pytest.mark.parametrize("entry,T,length,H,Hk,blocks", [
+    ("packed", 256, 1, 2, 2, None), ("packed", 256, 128, 2, 2, None),
+    ("packed", 256, 129, 2, 2, None), ("packed", 256, 256, 2, 2, None),
+    ("packed", 320, 128, 2, 2, None), ("packed", 320, 320, 2, 2, None),
+    ("packed", 200, 129, 4, 2, None), ("packed", 200, 200, 2, 2, None),
+    ("arrays", 256, 129, 2, 2, None), ("arrays", 320, 1, 2, 2, None),
+    ("arrays", 320, 129, 4, 2, 128), ("arrays", 320, 320, 2, 2, 128),
+    ("arrays", 200, 128, 2, 2, 128), ("arrays", 200, 200, 2, 1, None),
+])
+def test_flash_with_a_length_attends_over_the_real_rows_and_writes_zeros_past_them(
+        entry, T, length, H, Hk, blocks):
+    """Rows below ``length`` equal dense attention over the first ``length``
+    positions; rows at or past it are exactly zero and nothing is NaN, though
+    the operands past ``length`` hold NaN; the length is data (a traced scalar
+    of one jitted program); no gradient is offered."""
+    q, k, v, _, _ = _attention_case(1, T, H, Hk, 128, seed=T + length)
+    real = (jnp.arange(T) < length)[None, :, None, None]
+    qn, kn, vn = (jnp.where(real, x, jnp.nan) for x in (q, k, v))
+    if entry == "packed":
+        call = lambda q, k, v, n: flash_attention_packed(
+            _packed(q, k, v), H, Hk, length=n).reshape(1, T, H, 128)
+    else:
+        call = lambda q, k, v, n: flash_attention(
+            q, k, v, block_q=blocks, block_k=blocks, length=n)
+    before = _traces()
+    out = np.asarray(jax.jit(call)(qn, kn, vn, jnp.int32(length)))
+    assert _traces()["in_place"] == before["in_place"] + 1
+    want = parallel.full_attention(
+        q[:, :length], *(jnp.repeat(x[:, :length], H // Hk, axis=2) for x in (k, v)),
+        causal=True)
+    assert not np.isnan(out).any()
+    np.testing.assert_allclose(out[:, :length], np.asarray(want), rtol=2e-5, atol=2e-5)
+    assert (out[:, length:] == 0).all()
+    with pytest.raises(NotImplementedError, match="length=...\\) has no backward"):
+        jax.grad(lambda q: call(q, k, v, jnp.int32(length)).sum())(q)
+
+
+def test_flash_with_a_length_takes_the_dense_path_under_128_rows_and_refuses_the_rest():
+    """A bucket of 16-64 rows keeps the dense path (counted), with the same
+    contract: zeros past the length, NaN there unread.  ``length`` comes
+    without ``return_lse``, ``window``, a mesh, or a mask that is not causal."""
+    from moolib_tpu import telemetry
+    from moolib_tpu.ops.flash_attention import length_call_rides_kernel
+
+    assert [length_call_rides_kernel(t) for t in (16, 64, 127, 128, 192, 1984)] == [
+        False, False, False, True, True, True]
+    reroutes = lambda: telemetry.get_registry().counter_values().get(
+        "flash_dense_reroutes_total", 0.0)
+    q, k, v, _, _ = _attention_case(2, 64, 4, 2, 8, seed=3)
+    real = (jnp.arange(64) < 11)[None, :, None, None]
+    before = reroutes()
+    out = np.asarray(flash_attention(
+        *(jnp.where(real, x, jnp.nan) for x in (q, k, v)), length=jnp.int32(11)))
+    assert reroutes() == before + 1
+    want = parallel.full_attention(
+        q[:, :11], *(jnp.repeat(x[:, :11], 2, axis=2) for x in (k, v)), causal=True)
+    np.testing.assert_allclose(out[:, :11], np.asarray(want), rtol=2e-5, atol=2e-5)
+    assert (out[:, 11:] == 0).all()
+    for kw in (dict(return_lse=True), dict(window=8), dict(causal=False)):
+        with pytest.raises(ValueError, match="length=...\\) is causal self-attention"):
+            flash_attention(q, k, v, length=jnp.int32(11), **kw)
+
+
 @pytest.mark.parametrize("Tq,Tk", [(256, 512), (512, 256), (384, 384)])
 def test_flash_causal_copies_no_block_it_skips_at_unequal_lengths(Tq, Tk):
     """Causal, blocks of 128: a key block above the diagonal is neither

@@ -24,7 +24,7 @@ with the env on device, batch size costs no host bytes).
 ``host_boundary_bytes_per_frame`` comes from the actor-path telemetry
 counters (``actor_h2d/d2h_bytes_total``, ``batcher_h2d/d2h_bytes_total``
 over ``actor_frames_total``), read as per-run deltas — the one-crossing
-uint8 contract as a committed artifact, not a narrative.
+uint8 contract as a counted fact, not a narrative.
 
 Scales:
 
@@ -32,7 +32,7 @@ Scales:
   actor_batch 128 x 2 buffers, unroll 20, learner batch 32) for the chip —
   there the learner is fast and per-dispatch RTT dominates acting, the
   regime the device pipeline exists for.
-- ``--scale small``: CPU smoke row for BENCH_LOCAL.json.  Uses the
+- ``--scale small``: the CPU smoke ``scripts/ci.sh`` runs.  Uses the
   ``catch_flat`` MLP env so per-frame model FLOPs are negligible and
   whole-agent SPS measures the actor data plane itself (on a CPU box the
   conv learner would otherwise drown the actor plane it is probing);

@@ -475,9 +475,8 @@ class _AccumCohort:
 
 def bench_sharded(args):
     """A/B rows: legacy full-tree vs sharded hierarchical gradient rounds
-    over a real Accumulator cohort, plus a ratio section pinning the
-    per-host byte claim as data rows (banner-keyed so fold_capture merges
-    fresh captures over stale ones instead of accumulating duplicates)."""
+    over a real Accumulator cohort, plus a ratio section stating the
+    per-host byte claim as data rows (each section under its own banner)."""
     import moolib_tpu.buckets as buckets
 
     if args.bucket_bytes:
@@ -718,9 +717,8 @@ def bench_overlap(args):
     §6e).  The claim is the exposed_ms column: the streaming arm launches
     each bucket's inter-host reduce as soon as backward fills it, so only
     the tail of the allreduce remains after the last gradient is ready,
-    where the barrier arm pays the whole allreduce after backward.  Rows
-    are banner-keyed so fold_capture merges fresh captures over stale ones
-    without clobbering the tree/ring/sharded sections."""
+    where the barrier arm pays the whole allreduce after backward.  Each
+    section prints under its own banner."""
     import moolib_tpu.buckets as buckets
 
     buckets.set_bucket_bytes(args.bucket_bytes or (1 << 20))
@@ -764,8 +762,7 @@ def bench_overlap_smoke(args):
     non-final bucket launched with positive lead —
     ``accum_bucket_launch_lead_seconds`` > 0); and the exposed comm per
     step must come in at <= 0.5x the barrier arm.  Prints the measured A/B
-    rows banner-keyed (same shape as the sweep) so the smoke log folds and
-    gates like every other capture.  In multi-process mode every rank gates
+    rows under the sweep's banners.  In multi-process mode every rank gates
     its OWN exposure and leads, so the 2-process form proves the cut across
     real process boundaries."""
     import moolib_tpu.buckets as buckets

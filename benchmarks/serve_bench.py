@@ -21,8 +21,6 @@ self-throttles instead of overrunning admission).  One JSON line per
 target:
     {"metric": "serve_qps", "qps_target": 50, "p50_ms": ..., "p99_ms": ...,
      "achieved_qps": ..., "reject_rate": ..., ...}
-``fold_capture.py --local`` folds these into BENCH_LOCAL.json
-(``serve_qps`` section).
 """
 
 from __future__ import annotations

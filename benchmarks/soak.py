@@ -8,9 +8,9 @@ peers train against one broker while a killer SIGKILLs a random peer every
 
 - **progress**: the cohort-max MODEL VERSION keeps advancing.  Version is
   monotone per epoch and restarted peers re-sync to the cohort's version,
-  so this metric is immune to the counter resets that made round 4's
-  global-steps stall metric nearly trip its bound on an artifact
-  (SOAK_r04: max_stall 179.5 s explained by stats resets, not stalls);
+  so this metric is immune to the counter resets that made an earlier
+  global-steps stall metric nearly trip its bound on an artifact (a stall
+  reading explained by stats resets, not stalls);
 - **recovery**: each killed+restarted peer re-reports a model version
   within ``--version_window`` of the cohort max, within
   ``--recovery_bound_s`` seconds — a breach FAILS the soak (the prose

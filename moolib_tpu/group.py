@@ -953,8 +953,8 @@ class Group:
           per peer and the tree is simpler;
         - the cohort spans more than one machine: same-host frames ride
           memfd zero-copy where wire bytes are nearly free, and the tree
-          wins wall-clock (BENCH_LOCAL round 4); the ring's even per-peer
-          load only pays on real NIC/DCN links.
+          needs fewer rounds; the ring's even per-peer load only pays on
+          real NIC/DCN links.
 
         Deterministic cohort-wide: every input (threshold env, member list,
         host map) comes from the same broker epoch push, so peers at the

@@ -400,7 +400,7 @@ class TransformerLM(nn.Module):
         # remat trades ~1/3 extra FLOPs for O(1)-in-depth activation memory
         # (HBM is the usual TPU bottleneck): each block's activations are
         # recomputed during the backward instead of stored.  Bigger batches
-        # then fit at long T, which is how lm_bench pushes MFU.  mesh is a
+        # then fit at long T (``examples/lm.py --remat``).  mesh is a
         # static argument (index 2 counting self), not a traced operand.
         if self.remat and not self.decode:
             block_cls = nn.remat(

@@ -663,9 +663,9 @@ def _flash_backward(operands, out, lse, g, g_lse, c):
     group = H // Hk
     # Backward blocks capped at 512x512 (env-tunable for on-chip sweeps;
     # read at TRACE time — the jit cache does not key on env vars, so a
-    # sweep must re-trace per value: fresh process, cleared caches, or AOT
-    # .lower().compile() while the var is set, as flash_bench does for
-    # MOOLIB_TPU_FLASH_BWD.  Values clamp up to the 128 tile minimum.):
+    # sweep must re-trace per value: a fresh process each, as
+    # benchmarks/flash_bwd_tune.py does.  Values clamp up to the 128 tile
+    # minimum.):
     # the transposed-score intermediates (st, pt, dpt — all [bk, bq] f32)
     # plus two f32 output scratches are live at once, so the forward's
     # 512x1024 tiles would crowd VMEM.  The cap must preserve divisibility

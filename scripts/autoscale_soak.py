@@ -26,13 +26,12 @@ the real elastic LM trainer:
    ``vbatch_violation`` lines (the virtual batch stayed semantically stable
    across every resize) and the final cohort back at the target size.
 
-Exit 0 only when all four hold; the JSON verdict goes to ``--out`` (the
-committed ``SOAK_r06.json`` capture) or stdout.
+Exit 0 only when all four hold; the JSON verdict goes to ``--out`` or stdout.
 
 Usage::
 
     python scripts/autoscale_soak.py --smoke                 # ~3 min CI profile
-    python scripts/autoscale_soak.py --seed 7 --out SOAK.json
+    python scripts/autoscale_soak.py --seed 7 --out /tmp/autoscale_soak.json
 """
 
 from __future__ import annotations

@@ -28,13 +28,12 @@ processes:
    path), client discovery fails over to the standby's address, and the
    roster survives the takeover.
 
-Exit 0 only when every gate holds; the JSON verdict goes to ``--out`` (the
-committed ``SOAK_r08_broker.json`` capture) or stdout.
+Exit 0 only when every gate holds; the JSON verdict goes to ``--out`` or stdout.
 
 Usage::
 
     python scripts/broker_soak.py --smoke                   # ~45 s CI profile
-    python scripts/broker_soak.py --seed 10 --out SOAK_r08_broker.json
+    python scripts/broker_soak.py --seed 10 --out /tmp/broker_soak.json
 """
 
 from __future__ import annotations

@@ -21,8 +21,7 @@ docs/RESILIENCE.md "Serving") end to end with real processes:
    (``hot_swaps >= 1``, ``serve_swap_seconds`` recorded) and the swap must
    cause **no admission rejects** (rejects delta over the swap window = 0).
 
-Exit 0 only when every gate holds; the JSON verdict goes to ``--out`` (the
-committed ``SOAK_r07_serve.json`` capture) or stdout.
+Exit 0 only when every gate holds; the JSON verdict goes to ``--out`` or stdout.
 
 ``--engine`` serves every replica through the continuous-batching engine
 (``lm_serve --engine``) under the same kill + hot-swap gates — the engine
@@ -35,7 +34,7 @@ graceful shrink, and zero lost requests.
 Usage::
 
     python scripts/serve_soak.py --smoke                  # ~1 min CI profile
-    python scripts/serve_soak.py --seed 7 --out SOAK_r07_serve.json
+    python scripts/serve_soak.py --seed 7 --out /tmp/serve_soak.json
     python scripts/serve_soak.py --smoke --engine         # engine arm
     python scripts/serve_soak.py --smoke --swing          # elasticity swing
 """

@@ -14,6 +14,7 @@ import gzip
 import os
 import shutil
 import sys
+import threading
 import types
 
 import numpy as np
@@ -138,9 +139,13 @@ def test_no_metric_file_holds_a_private_methods_name():
 class _Annotation:
     log = []
     enabled = True
+    thread = None
 
     def __init__(self, name, **kwargs):
-        self.log.append((name, kwargs))
+        # the test's own spans: a host monitor that an earlier test of this
+        # process started ticks under a span too, on its own thread
+        if threading.get_ident() == self.thread:
+            self.log.append((name, kwargs))
 
     @classmethod
     def is_enabled(cls):
@@ -156,6 +161,7 @@ class _Annotation:
 @pytest.fixture
 def annotations(monkeypatch):
     _Annotation.log, _Annotation.enabled = [], True
+    _Annotation.thread = threading.get_ident()
     monkeypatch.setitem(sys.modules, "jax", types.SimpleNamespace(
         profiler=types.SimpleNamespace(TraceAnnotation=_Annotation)))
     return _Annotation

@@ -1,22 +1,11 @@
-"""Device performance plane (telemetry.devmon + CohortAggregator.step_skew +
-scripts/bench_gate.py): recompile detection, memory gauges, XLA step cost /
-MFU, cohort straggler attribution, and the bench regression gate."""
-
-import json
-import os
-import subprocess
-import sys
+"""Device performance plane (telemetry.devmon + CohortAggregator.step_skew):
+recompile detection, memory gauges, XLA step cost / MFU and cohort straggler
+attribution."""
 
 import pytest
 
 from moolib_tpu import telemetry
 from moolib_tpu.telemetry import devmon
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-GATE = os.path.join(ROOT, "scripts", "bench_gate.py")
-
-sys.path.insert(0, os.path.join(ROOT, "scripts"))
-import bench_gate  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -433,161 +422,6 @@ def test_peer_samples_prunes_departed_peers():
     # A departed peer's baseline must not outlive it (a respawn reusing the
     # name would inherit a stale delta).
     assert set(agg._last_steps) == {"p1"}
-
-
-# --------------------------------------------------------------- bench gate
-def _baseline_capture():
-    return {
-        "agent_small": {"stdout": [
-            json.dumps({"metric": "impala_agent_sps", "rollout": "device",
-                        "scale": "small", "steady_sps": 1000.0}),
-            json.dumps({"metric": "impala_agent_sps", "rollout": "jax",
-                        "scale": "small", "steady_sps": 2000.0}),
-        ]},
-        "serve_qps": {"stdout": [
-            json.dumps({"metric": "serve_qps", "engine": True,
-                        "qps_target": 8, "achieved_qps": 8.0,
-                        "tokens_per_s": 160.0, "p99_ms": 50.0}),
-        ]},
-    }
-
-
-def test_gate_passes_on_identical_capture():
-    base = _baseline_capture()
-    failures, report = bench_gate.gate(base, base)
-    assert not failures
-    assert all(r["ratio"] == pytest.approx(1.0)
-               for r in report if "ratio" in r)
-
-
-def test_gate_fails_on_throughput_regression():
-    base = _baseline_capture()
-    fresh = json.loads(json.dumps(base))
-    row = json.loads(fresh["agent_small"]["stdout"][0])
-    row["steady_sps"] = 800.0  # 20% down: ratio 0.8 < floor 0.85
-    fresh["agent_small"]["stdout"][0] = json.dumps(row)
-    failures, _ = bench_gate.gate(base, fresh)
-    assert len(failures) == 1
-    f = failures[0]
-    assert f["section"] == "agent_small"
-    assert "device" in f["key"]
-    assert f["field"] == "steady_sps"
-    assert "0.80" in f["reason"]
-
-
-def test_gate_fails_on_latency_regression():
-    base = _baseline_capture()
-    fresh = json.loads(json.dumps(base))
-    row = json.loads(fresh["serve_qps"]["stdout"][0])
-    row["p99_ms"] = 75.0  # ratio 1.5 > ceiling 1.3
-    fresh["serve_qps"]["stdout"][0] = json.dumps(row)
-    failures, _ = bench_gate.gate(base, fresh)
-    assert len(failures) == 1
-    assert failures[0]["field"] == "p99_ms"
-    assert "1.50" in failures[0]["reason"]
-
-
-def test_gate_new_section_needs_allow_list():
-    base = _baseline_capture()
-    fresh = json.loads(json.dumps(base))
-    fresh["brand_new"] = {"stdout": ["whatever"]}
-    failures, _ = bench_gate.gate(base, fresh)
-    assert any(f["section"] == "brand_new" for f in failures)
-    failures, report = bench_gate.gate(
-        base, fresh, allow_new_sections=("brand_new",)
-    )
-    assert not failures
-    assert any(r.get("verdict") == "NEW (allowed)" for r in report)
-    failures, _ = bench_gate.gate(base, fresh, allow_new_sections=("all",))
-    assert not failures
-
-
-def test_gate_zero_parsed_rows_is_a_failure():
-    base = _baseline_capture()
-    fresh = json.loads(json.dumps(base))
-    fresh["agent_small"]["stdout"] = ["not json at all"]
-    failures, _ = bench_gate.gate(base, fresh)
-    assert any("zero gateable rows" in f["reason"] for f in failures)
-
-
-def test_gate_cli_smoke_and_regression(tmp_path):
-    base = _baseline_capture()
-    bpath = tmp_path / "base.json"
-    bpath.write_text(json.dumps(base))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, GATE, "--smoke", "--baseline", str(bpath)],
-        capture_output=True, text=True, env=env,
-    )
-    assert r.returncode == 0, r.stderr
-    assert "bench_gate: OK" in r.stdout
-    # Degraded capture: non-zero exit, stderr names the failing row.
-    fresh = json.loads(json.dumps(base))
-    row = json.loads(fresh["agent_small"]["stdout"][1])
-    row["steady_sps"] = 100.0
-    fresh["agent_small"]["stdout"][1] = json.dumps(row)
-    cpath = tmp_path / "fresh.json"
-    cpath.write_text(json.dumps(fresh))
-    r = subprocess.run(
-        [sys.executable, GATE, "--baseline", str(bpath),
-         "--capture", str(cpath)],
-        capture_output=True, text=True, env=env,
-    )
-    assert r.returncode == 1
-    assert "REGRESSION" in r.stderr and "jax" in r.stderr
-
-
-def test_gate_cli_malformed_capture(tmp_path):
-    cpath = tmp_path / "weird.json"
-    cpath.write_text(json.dumps({"weird": 1}))
-    r = subprocess.run(
-        [sys.executable, GATE, "--capture", str(cpath)],
-        capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-    )
-    assert r.returncode == 2
-    assert "malformed" in r.stderr
-
-
-def test_gate_committed_record_passes_itself():
-    # The acceptance contract: the committed BENCH_LOCAL.json gates clean
-    # against itself (every ratio exactly 1.0).
-    baseline = bench_gate.load_capture(
-        os.path.join(ROOT, "BENCH_LOCAL.json")
-    )
-    failures, report = bench_gate.gate(baseline, baseline)
-    assert not failures
-    assert any(r.get("verdict") == "ok" for r in report)
-
-
-# ----------------------------------------------------------- fold integration
-def test_fold_merge_agent_rows_carries_mfu_forward():
-    import fold_capture
-
-    old = [
-        json.dumps({"metric": "impala_agent_sps", "rollout": "device",
-                    "scale": "small", "steady_sps": 1000.0, "mfu": 0.12}),
-        json.dumps({"metric": "impala_agent_sps", "rollout": "legacy",
-                    "scale": "small", "steady_sps": 500.0}),
-    ]
-    new = [
-        json.dumps({"metric": "impala_agent_sps", "rollout": "device",
-                    "scale": "small", "steady_sps": 1100.0, "mfu": None}),
-    ]
-    merged = [json.loads(l) for l in fold_capture.merge_agent_rows(old, new)]
-    by_mode = {r["rollout"]: r for r in merged}
-    # Legacy row untouched (single-mode re-run must not clobber it) ...
-    assert by_mode["legacy"]["steady_sps"] == 500.0
-    # ... fresh throughput wins, and the unmeasured mfu carries forward.
-    assert by_mode["device"]["steady_sps"] == 1100.0
-    assert by_mode["device"]["mfu"] == 0.12
-    assert by_mode["device"]["mfu_carried"] is True
-    # A fresh measured mfu replaces the stored one.
-    new2 = [json.dumps({"metric": "impala_agent_sps", "rollout": "device",
-                        "scale": "small", "steady_sps": 900.0, "mfu": 0.2})]
-    merged2 = [json.loads(l) for l in fold_capture.merge_agent_rows(old, new2)]
-    dev = next(r for r in merged2 if r["rollout"] == "device")
-    assert dev["mfu"] == 0.2 and "mfu_carried" not in dev
 
 
 # ------------------------------------------------------------------ summary

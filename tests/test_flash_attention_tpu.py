@@ -8,8 +8,9 @@ of tier 1 (all skipped on the CPU) and runs on the chip with one command —
 
     JAX_PLATFORMS=tpu python -m pytest tests/test_flash_attention_tpu.py -v
 
-The companion benchmark is ``benchmarks/flash_bench.py`` (pallas vs dense
-timing).
+The kernel's share of a training step is the benchmark's
+``flash_attn_share.train``; ``benchmarks/flash_bwd_tune.py`` sweeps the
+backward's blocks.
 """
 
 import os
@@ -72,8 +73,7 @@ def test_flash_backward_matches_dense_on_chip(t, causal):
     # early rows' concentrated probabilities (p ~ 1) turn single bf16-rounded
     # products into ~6e-3 absolute dv errors (dv only, causal only, 50-80
     # elements).  The pallas kernels accumulate through f32 dots, so the
-    # *reference* is the noisy side.  benchmarks/debug_flash_dv.py re-derives
-    # this against a float64 host oracle.
+    # *reference* is the noisy side.
     with jax.default_matmul_precision("highest"):
         _, vjp = jax.vjp(
             lambda q, k, v: flash_attention(q, k, v, causal=causal), q, k, v
